@@ -651,7 +651,10 @@ class ALSUpdate(MLUpdate):
         return "auc" if self.als.implicit else "neg_rmse"
 
     def evaluate(self, model: ModelArtifact, train, test) -> float:
-        users, items, vals, _ = parse_events(test)
+        # ids as strings: the native parser returns canonical-integer ids
+        # as int64, which would match none of the artifact's string ids
+        # and silently evaluate to NaN
+        users, items, vals, _ = self._parse_to_str(test)
         if len(vals) == 0:
             return float("nan")
         xids = model.get_extension_list("XIDs")

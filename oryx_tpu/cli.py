@@ -299,13 +299,13 @@ def cmd_import_pmml(config: Config, pmml_path: str | None = None) -> int:
 def _apply_platform_env(config: Config | None = None) -> None:
     """Make the platform choice authoritative for framework processes:
     oryx.compute.platform (when not "auto"), overridden by an explicit
-    JAX_PLATFORMS env var (the operator's escape hatch).
-
-    Site customizations that pre-register an accelerator PJRT plugin can
-    hijack backend resolution so the env var alone is ignored; re-applying
-    it through jax.config before any backend is touched restores the
-    documented semantics (operators rely on JAX_PLATFORMS=cpu to run a
-    layer off-accelerator, e.g. a serving replica on a CPU-only host)."""
+    JAX_PLATFORMS env var (the operator's escape hatch, e.g.
+    JAX_PLATFORMS=cpu to run a layer off the chip another process
+    holds). A named platform is PINNED: with "tpu", a TPU that fails to
+    initialise is a start-up error. "auto" leaves the choice to JAX,
+    which starts on the CPU when no accelerator initialises — say "tpu"
+    on a chip host. Applied through jax.config, which initialises no
+    backend."""
     import os
 
     platforms = os.environ.get("JAX_PLATFORMS")
@@ -636,9 +636,10 @@ def _supervise_serving_replicas(config: Config, n_procs: int, argv: list[str]) -
 
     Requires a fixed port and a cross-process broker (file:// or kafka://;
     mem:// is per-process). Replicas that die are restarted; SIGTERM/INT
-    fans out. NOTE: accelerator-backed scoring is per-process — replicas
-    on a single-chip host should run with JAX_PLATFORMS=cpu (one chip
-    cannot be opened by several processes)."""
+    fans out. A chip belongs to one process at a time: on a TPU host
+    replica i is pinned to chip i, and more replicas than chips are
+    refused before anything starts (executil.chip_process_envs) — run
+    them with JAX_PLATFORMS=cpu to serve from the host CPU instead."""
     import os
     import subprocess
     import time as _time
@@ -655,7 +656,15 @@ def _supervise_serving_replicas(config: Config, n_procs: int, argv: list[str]) -
     if not hasattr(_socket, "SO_REUSEPORT"):
         raise SystemExit("serving replicas require SO_REUSEPORT on this platform")
 
-    env = dict(os.environ, ORYX_SERVING_REPLICA="1")
+    from oryx_tpu.common.executil import NotEnoughChips, chip_process_envs
+
+    try:
+        envs = chip_process_envs(
+            n_procs, dict(os.environ, ORYX_SERVING_REPLICA="1"),
+            config.get_string("oryx.compute.platform", "auto"),
+        )
+    except NotEnoughChips as e:
+        raise SystemExit(f"serving: {e}")
     cmd = [sys.executable, "-m", "oryx_tpu.cli", "serving", *argv]
     procs: list[subprocess.Popen] = []
     stopping = False
@@ -663,10 +672,10 @@ def _supervise_serving_replicas(config: Config, n_procs: int, argv: list[str]) -
 
     spawn_at: dict[int, float] = {}  # pid -> spawn timestamp
 
-    def spawn() -> subprocess.Popen | None:
+    def spawn(i: int) -> subprocess.Popen | None:
         if stopping:
             return None
-        p = subprocess.Popen(cmd, env=env)
+        p = subprocess.Popen(cmd, env=envs[i])
         spawn_at[p.pid] = _time.monotonic()
         return p
 
@@ -677,8 +686,8 @@ def _supervise_serving_replicas(config: Config, n_procs: int, argv: list[str]) -
     old = signal.signal(signal.SIGTERM, shutdown)
     rc_out = 0
     try:
-        for _ in range(n_procs):
-            p = spawn()
+        for i in range(n_procs):
+            p = spawn(i)
             if p is not None:
                 procs.append(p)
         log_.info(
@@ -711,7 +720,7 @@ def _supervise_serving_replicas(config: Config, n_procs: int, argv: list[str]) -
                     )
                     _time.sleep(backoff)
                     backoff = min(backoff * 2, 30.0)
-                    np_ = spawn()
+                    np_ = spawn(i)  # same slot, same chip
                     if np_ is not None:
                         procs[i] = np_
             now = _time.monotonic()
@@ -836,7 +845,12 @@ def cmd_fleet(config: Config, args, raw_argv: list[str]) -> int:
         overlay["oryx.fleet.shards"] = args.shards
     if overlay:
         config = config.overlay(overlay)
-    sup = FleetSupervisor(config, argv=_fleet_child_flags(raw_argv))
+    from oryx_tpu.common.executil import NotEnoughChips
+
+    try:
+        sup = FleetSupervisor(config, argv=_fleet_child_flags(raw_argv))
+    except NotEnoughChips as e:
+        raise SystemExit(f"fleet: {e}")
     front = None
     controller = None
     prev_term = signal.signal(signal.SIGTERM, lambda *_: sup.request_stop())
@@ -897,8 +911,15 @@ def cmd_pod(config: Config, args, raw_argv: list[str]) -> int:
 
     Single-host default (no --local-*/--coordinator): all compute
     processes plus the optional tiers run here with an auto-picked
-    coordinator port — the smoke topology
-    (tests/test_pod_cli.py) and the single-TPU-host deployment.
+    coordinator port — the smoke topology (tests/test_pod_cli.py).
+
+    A chip belongs to one process at a time. With one child the child
+    drives every chip of this host (the deployment shape: --local-count
+    1 per host, speed and serving elsewhere). With several children on a
+    TPU host each is pinned to its own chip, and more children than chips
+    are refused before anything starts (executil.chip_process_envs); to
+    hold batch, speed and serving on ONE chip, run them in one process
+    as chip_smoke.py does.
     """
     import os
     import subprocess
@@ -933,11 +954,21 @@ def cmd_pod(config: Config, args, raw_argv: list[str]) -> int:
     # with the role substituted — so --conf/--set/env all carry through
     base_flags = _pod_child_flags(raw_argv)
 
+    from oryx_tpu.common.executil import NotEnoughChips, chip_process_envs
+
+    try:
+        envs = chip_process_envs(
+            local_count + bool(args.speed) + bool(args.serving),
+            platform=config.get_string("oryx.compute.platform", "auto"),
+        )
+    except NotEnoughChips as e:
+        raise SystemExit(f"pod: {e}")
+
     def spawn(role: str, extra_sets: list[str]) -> subprocess.Popen:
         cmd = [sys.executable, "-m", "oryx_tpu.cli", role, *base_flags]
         for kv in extra_sets:
             cmd += ["--set", kv]
-        return subprocess.Popen(cmd, env=dict(os.environ))
+        return subprocess.Popen(cmd, env=envs.pop(0))
 
     children: list[tuple[str, subprocess.Popen]] = []
     for pid_idx in range(local_start, local_start + local_count):

@@ -5,9 +5,9 @@ The in-memory observability built so far (tracing ring, perfstats
 dispatch ring, /metrics) dies with its process: when a replica is
 SIGKILLed mid update-storm, or an accel bench stage times out and the
 driver kills it, the evidence evaporates at exactly the moment it is
-needed (the still-unexplained ``_bench_http_body``/``_bench_train_body``
-failures of BENCH_TPU_WINDOW_r05 are a bare ``error:`` string because
-nothing survived the kill). This module keeps the last seconds of
+needed (round 5's ``_bench_http_body``/``_bench_train_body`` failures on
+the chip are a bare ``error:`` string because nothing survived the
+kill). This module keeps the last seconds of
 STRUCTURED lifecycle evidence on disk, where a supervisor — or the bench
 driver, or an operator — can harvest it from the corpse:
 

@@ -58,7 +58,7 @@ from oryx_tpu.common.tracing import get_tracer, wall_time_us
 DEFAULT_WINDOW_S = 60.0
 
 # Per-dispatch wall-clock: 100us (a warm small-batch CPU matmul) up to
-# ~26s (a cold remote-compile dispatch).
+# ~26s (a first dispatch that cold-compiles).
 DISPATCH_SECONDS_BUCKETS = exponential_buckets(1e-4, 4.0, 10)
 
 # Occupancy is a ratio in (0, 1]: linear buckets, 0.05 steps (rounded so

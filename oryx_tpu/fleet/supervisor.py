@@ -171,6 +171,18 @@ class FleetSupervisor:
         )
         self.argv = list(argv or [])
         self.env = dict(env if env is not None else os.environ)
+        # a chip belongs to one process at a time: on a TPU host replica
+        # slot i is pinned to chip i (restarts keep their chip) and a
+        # replica the host has no chip for is refused. Counted once, now,
+        # before any replica holds a chip; 0 = nothing to share out
+        # (replicas kept off the TPU, or no TPU here)
+        from oryx_tpu.common.executil import NotEnoughChips, host_tpu_chips
+
+        self._chips = host_tpu_chips(
+            self.env, config.get_string("oryx.compute.platform", "auto")
+        )
+        if self._chips and len(self.overlays) > self._chips:
+            raise NotEnoughChips(len(self.overlays), self._chips)
         self._stdout = stdout
         self._stderr = stderr
         # one lock serializes process-table mutation: poll()'s restart
@@ -223,8 +235,13 @@ class FleetSupervisor:
         cmd = [*prefix, sys.executable, "-m", "oryx_tpu.cli", "serving", *self.argv]
         for k, v in self.overlays[i].items():
             cmd += ["--set", f"{k}={v}"]
+        env = self.env
+        if self._chips:
+            from oryx_tpu.common.executil import one_chip_env
+
+            env = one_chip_env(env, i, self._chips)
         p = subprocess.Popen(
-            cmd, env=self.env, stdout=self._stdout, stderr=self._stderr
+            cmd, env=env, stdout=self._stdout, stderr=self._stderr
         )
         self._spawned_at[i] = time.monotonic()
         log.info(
@@ -410,6 +427,10 @@ class FleetSupervisor:
                 self._scaled_down.discard(idx)
             else:
                 idx = len(self.overlays)
+                if self._chips and idx >= self._chips:
+                    from oryx_tpu.common.executil import NotEnoughChips
+
+                    raise NotEnoughChips(idx + 1, self._chips)
                 self.overlays.append(
                     replica_overlays(
                         self.config, n=idx + 1,

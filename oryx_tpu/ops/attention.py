@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from oryx_tpu.parallel.mesh import DATA_AXIS, shard_map_compat
+from oryx_tpu.parallel.mesh import DATA_AXIS
 
 _NEG_INF = -1e30
 
@@ -109,7 +109,7 @@ def ring_attention(q, k, v, mesh: Mesh, *, causal: bool = False):
     for _ in range(q.ndim - 2):
         body = jax.vmap(body)
     fn = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(spec, spec, spec),
@@ -167,7 +167,7 @@ def ulysses_attention(q, k, v, mesh: Mesh, *, causal: bool = False):
             )
     spec = P(*([None] * (q.ndim - 2)), DATA_AXIS, None)
     fn = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             partial(_ulysses_local, causal=causal, axis_name=DATA_AXIS),
             mesh=mesh,
             in_specs=(spec, spec, spec),

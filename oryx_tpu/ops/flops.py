@@ -13,14 +13,15 @@ reported MFU slightly understates true utilization — never the reverse.
 from __future__ import annotations
 
 # Dense per-chip matmul peak in FLOP/s at bf16, from public spec sheets
-# (cloud.google.com/tpu/docs/system-architecture-tpu-vm). The f32 figure
+# (cloud.google.com/tpu/docs/system-architecture-tpu-vm; v5e: "TPU v5e",
+# 197 TFLOP/s bf16 and 393 TOP/s int8 per chip). The f32 figure
 # is taken as half the bf16 peak — the convention for chips that run f32
 # matmuls as multi-pass bf16 on the MXU.
 _PEAK_BF16 = {
     "v2": 45e12,
     "v3": 123e12,
     "v4": 275e12,
-    "v5e": 394e12,
+    "v5e": 197e12,
     "v5p": 459e12,
     "v6e": 918e12,
 }
@@ -34,7 +35,7 @@ _PEAK_INT8 = {
     "v2": 45e12,
     "v3": 123e12,
     "v4": 275e12,
-    "v5e": 788e12,
+    "v5e": 393e12,
     "v5p": 918e12,
     "v6e": 1836e12,
 }
@@ -64,8 +65,6 @@ def peak_flops_for_kind(device_kind: str, dtype: str = "bfloat16") -> float | No
         gen = "v5p"
     elif "v5 lite" in kind or "v5e" in kind or "v5litepod" in kind:
         gen = "v5e"
-    elif "v5" in kind:
-        gen = "v5p"
     elif "v4" in kind:
         gen = "v4"
     elif "v3" in kind:

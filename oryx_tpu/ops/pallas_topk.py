@@ -13,25 +13,34 @@ the score matrix never exists.
 Second generation (PR 8), three changes over the first kernel:
 
 - Selection: the first kernel ran k sequential argmax+mask sweeps over a
-  [Bb, k+Ib] candidate buffer — O(k·Ib) VPU work per block that exceeded
-  the MXU's matmul FLOPs at k=32 and capped the fused path at k<=32.
-  Now each block's scores are reduced by a BITONIC partial sort: the
-  block splits into 128-lane chunks, each chunk is bitonic-sorted
-  descending (28 compare-exchange stages), chunks pairwise-merge down a
-  tree (8 stages per level), and the block's top-128 merges into the
-  running top-128 (8 stages). ~36 vectorized stages per block total,
-  independent of k, exact for any k <= 128 — the comparisons order by
-  (value desc, index asc), the same total order as jax.lax.top_k, so
-  duplicate scores tie-break identically.
+  [Bb, k+Ib] candidate buffer — O(k·Ib) VPU work per block that capped
+  the fused path at k<=32. Now every 128-item chunk of a block is scored
+  and BITONIC-sorted ascending (28 compare-exchange stages), split
+  against the descending running top-128 and merged back (1 + 7
+  stages): 36 vectorized stages per chunk, independent of k, exact for
+  any k <= 128 — the comparisons order by (value desc, index asc), the
+  same total order as jax.lax.top_k, so duplicate scores tie-break
+  identically. The chunks of a block are a fori_loop, not an unrolled
+  merge tree: the unrolled form of PR 8 (a [Bb, block_i] score block,
+  sorted whole) took 377 s to compile under Mosaic at block_i=4096 —
+  longer than the batcher's cold-compile grace — against ~1 s for the
+  loop, and needed `rev` and lane-splitting reshapes that Mosaic does
+  not lower (PR 21).
 - Streaming: the item matrix stays in HBM (`memory_space=ANY`) and the
   kernel issues its own double-buffered `pltpu.make_async_copy` DMAs
   into a 2-slot VMEM scratch, starting block i+1's copy before computing
-  block i — the MXU never waits on the HBM stream.
+  block i.
 - Blocks: `(block_b, block_i)` come from a per-(feature-pad, dtype)
   table (`tuned_blocks`) sized against the VMEM budget and cached for
   the process; `autotune_blocks` measures candidates on real hardware
   and locks the winner into the same table (bench uses it; serving
   inherits whatever the table holds at dispatch time).
+
+Measured on one v5e chip (PR 21, 512 x 1.31M x 50f bf16): 236 ms at every
+k, against XLA's 41 ms (k=16), 72 ms (k=32) and 257 ms (k=128); at 4096
+rows 1876 ms, where XLA's 21 GB score matrix does not fit the chip at
+all. Time is linear in query rows and flat in features: the kernel is
+bound by the sort network, not by HBM or the MXU (ROADMAP S2).
 
 The kernel also scores QUANTIZED item matrices (int8 rows + per-row f32
 scales, ops/transfer.py QuantizedMatrix): the int8 stream halves the
@@ -68,39 +77,57 @@ _VMEM_BUDGET_BYTES = 12 << 20
 
 # ---------------------------------------------------------------------------
 # bitonic partial-sort selection (exact, index-carrying)
+#
+# Every array here is sorted along a 128-lane last axis. The only data
+# movement is a static lane rotate (pltpu.roll -> the XLU) plus a select:
+# Mosaic lowers no `rev`, and a reshape that splits the lane axis is a
+# relayout. No list is ever reversed in the kernel: a chunk is sorted
+# ASCENDING and the running top-k is kept DESCENDING, which is what the
+# bitonic split needs.
 # ---------------------------------------------------------------------------
 
-def _swap_xor(x, d):
-    """Partner values at lane XOR d along the last axis (reshape + flip of
-    the pair axis — lowers to lane shuffles, no gather)."""
-    shp = x.shape
-    l = shp[-1]
-    xr = x.reshape(shp[:-1] + (l // (2 * d), 2, d))
-    return jnp.flip(xr, axis=-2).reshape(shp)
+def _lane(x):
+    return jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+
+
+def _partner(x, d, is_lo):
+    """Values at lane XOR d (d a power of two below the lane count):
+    lanes with bit d clear (`is_lo`) read d to the right, the others d
+    to the left — two lane rotates and a select."""
+    n = x.shape[-1]
+    ax = x.ndim - 1
+    return jnp.where(
+        is_lo,
+        pltpu.roll(x, n - d, ax),  # out[j] = x[j + d]
+        pltpu.roll(x, d, ax),      # out[j] = x[j - d]
+    )
+
+
+def _before(v, i, v_o, i_o):
+    """The strict total order (value desc, index asc): equal values
+    resolve exactly like jax.lax.top_k's stable lowest-index-first."""
+    return (v > v_o) | ((v == v_o) & (i < i_o))
 
 
 def _cmp_exchange(v, i, d, desc):
     """One compare-exchange stage at XOR distance d, carrying indices.
-    desc: bool array over the last axis — True where the run containing
-    the lane sorts descending. Ordering is the strict total order
-    (value desc, index asc), so equal values resolve exactly like
-    jax.lax.top_k's stable lowest-index-first."""
-    v_o = _swap_xor(v, d)
-    i_o = _swap_xor(i, d)
-    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
-    is_lo = (lane & d) == 0
-    greater = (v > v_o) | ((v == v_o) & (i < i_o))
-    take_self = greater == (is_lo == desc)
+    desc: bool, or bool array over the lanes — True where the run
+    containing the lane sorts descending."""
+    is_lo = (_lane(v) & d) == 0
+    v_o = _partner(v, d, is_lo)
+    i_o = _partner(i, d, is_lo)
+    take_self = _before(v, i, v_o, i_o) == (is_lo == desc)
     return jnp.where(take_self, v, v_o), jnp.where(take_self, i, i_o)
 
 
-def _bitonic_sort_desc(v, i):
-    """Full descending sort of the (pow2-length) last axis, carrying i."""
+def _bitonic_sort(v, i, descending: bool):
+    """Full sort of the (pow2-length) lane axis, carrying i."""
     l = v.shape[-1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
+    lane = _lane(v)
     size = 2
     while size <= l:
-        desc = (lane & size) == 0
+        # runs of `size` lanes alternate direction until the last pass
+        desc = ((lane & size) == 0) == descending if size < l else descending
         d = size // 2
         while d >= 1:
             v, i = _cmp_exchange(v, i, d, desc)
@@ -109,47 +136,21 @@ def _bitonic_sort_desc(v, i):
     return v, i
 
 
-def _bitonic_merge_desc(v, i):
-    """Sort a bitonic (pow2-length) last axis descending: log2(L) stages."""
-    l = v.shape[-1]
-    desc = jnp.ones(v.shape, dtype=bool)
-    d = l // 2
+def _bitonic_merge(v, i, descending: bool):
+    """Sort a bitonic (pow2-length) lane axis: log2(L) stages."""
+    d = v.shape[-1] // 2
     while d >= 1:
-        v, i = _cmp_exchange(v, i, d, desc)
+        v, i = _cmp_exchange(v, i, d, descending)
         d //= 2
     return v, i
 
 
-def _merge_top(av, ai, bv, bi):
-    """Exact top-L of two sorted-descending length-L lists: the bitonic
-    split (elementwise a[j] vs b[L-1-j], keep the greater) leaves the L
-    largest of the union as a bitonic sequence, then one log-merge sorts
-    it descending. 1 + log2(L) stages total."""
-    rv = jnp.flip(bv, axis=-1)
-    ri = jnp.flip(bi, axis=-1)
-    greater = (av > rv) | ((av == rv) & (ai < ri))
-    return _bitonic_merge_desc(
-        jnp.where(greater, av, rv), jnp.where(greater, ai, ri)
-    )
-
-
-def _block_topk(scores, col):
-    """[Bb, block_i] scores + global column ids -> the block's exact
-    top-128 (vals, idx), sorted descending. block_i must be a pow2
-    multiple of 128: chunk sort once, then a pairwise merge tree."""
-    bb, bi = scores.shape
-    g = bi // _LANE
-    v = scores.reshape(bb, g, _LANE)
-    i = col.reshape(bb, g, _LANE)
-    v, i = _bitonic_sort_desc(v, i)
-    while g > 1:
-        v = v.reshape(bb, g // 2, 2, _LANE)
-        i = i.reshape(bb, g // 2, 2, _LANE)
-        v, i = _merge_top(
-            v[:, :, 0, :], i[:, :, 0, :], v[:, :, 1, :], i[:, :, 1, :]
-        )
-        g //= 2
-    return v.reshape(bb, _LANE), i.reshape(bb, _LANE)
+def _split_top(av, ai, bv, bi):
+    """The bitonic split of a descending list against an ASCENDING one:
+    the elementwise winner keeps the L largest of the union, as a
+    bitonic sequence one _bitonic_merge then sorts."""
+    first = _before(av, ai, bv, bi)
+    return jnp.where(first, av, bv), jnp.where(first, ai, bi)
 
 
 # ---------------------------------------------------------------------------
@@ -187,28 +188,45 @@ def _topk_kernel(
         dma(jax.lax.rem(i + 1, 2), i + 1).start()
 
     dma(slot, i).wait()
-    y_block = y_buf[slot]
 
     xs = xs_ref[:]
-    if scale_ref is not None:
-        # TRUE int8 path: queries arrive pre-quantized (wrapper, per-row
-        # scales), so the dot runs int8 x int8 -> int32 on the MXU — the
-        # 2x-rate mode the int8 MFU peak describes — exactly. Item scales
-        # multiply back in before selection (they reorder across rows);
-        # the QUERY scales do not: scaling a row by a positive constant
-        # never changes that row's top-k order, so the wrapper applies
-        # them to the returned values after the kernel.
-        scores = jnp.dot(
-            xs, y_block.T, preferred_element_type=jnp.int32
-        ).astype(jnp.float32) * scale_ref[0, :][None, :]
-    else:
-        # [Bb, K] x [K, Ib] on the MXU, f32 accumulation
-        scores = jnp.dot(xs, y_block.T, preferred_element_type=jnp.float32)
-    col = i * block_i + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    scores = jnp.where(col < n_items, scores, -jnp.inf)  # mask tail padding
+    lane = jax.lax.broadcasted_iota(jnp.int32, (xs.shape[0], _LANE), 1)
+    # [Bb, K] x [128, K]^T on the MXU, contracting the feature axis of
+    # both (no materialized transpose)
+    contract = (((1,), (1,)), ((), ()))
 
-    bv, bidx = _block_topk(scores, col)
-    nv, nidx = _merge_top(run_vals[:], run_idx[:], bv, bidx)
+    def fold_chunk(c, run):
+        """Score one 128-item chunk of the block, sort it ascending (28
+        stages) and fold it into the descending running top-128 (1 + 7
+        stages). A loop, not an unrolled tree: the program stays one
+        chunk long whatever block_i is, and the [Bb, block_i] score
+        block never exists."""
+        off = pl.multiple_of(c * _LANE, _LANE)
+        y_c = y_buf[slot, pl.ds(off, _LANE), :]
+        if scale_ref is not None:
+            # TRUE int8 path: queries arrive pre-quantized (wrapper,
+            # per-row scales), so the dot runs int8 x int8 -> int32 on
+            # the MXU — the 2x-rate mode the int8 MFU peak describes —
+            # exactly. Item scales multiply back in before selection
+            # (they reorder across rows); the QUERY scales do not:
+            # scaling a row by a positive constant never changes that
+            # row's top-k order, so the wrapper applies them to the
+            # returned values after the kernel.
+            scores = jax.lax.dot_general(
+                xs, y_c, contract, preferred_element_type=jnp.int32
+            ).astype(jnp.float32) * scale_ref[pl.ds(c, 1), :]
+        else:
+            scores = jax.lax.dot_general(
+                xs, y_c, contract, preferred_element_type=jnp.float32
+            )
+        col = i * block_i + off + lane
+        scores = jnp.where(col < n_items, scores, -jnp.inf)  # tail padding
+        cv, ci = _bitonic_sort(scores, col, descending=False)
+        return _bitonic_merge(*_split_top(*run, cv, ci), descending=True)
+
+    nv, nidx = jax.lax.fori_loop(
+        0, block_i // _LANE, fold_chunk, (run_vals[:], run_idx[:])
+    )
     run_vals[:] = nv
     run_idx[:] = nidx
     vals_ref[:] = nv
@@ -249,14 +267,17 @@ AUTOTUNE_BLOCK_I = (1024, 2048, 4096, 8192)
 def _working_set_bytes(
     block_b: int, block_i: int, feat_pad: int, y_itemsize: int
 ) -> int:
-    """Conservative scoped-VMEM estimate for one grid step: the 2-slot Y
-    stream buffer, the query block, the f32 score block plus the sort
-    network's value/index temporaries, and the running/output top-k."""
+    """Scoped-VMEM estimate for one grid step: the 2-slot Y stream
+    buffer, the (pipelined, so doubled) query, scale and output blocks,
+    the running top-k, and one chunk's sort network temporaries. The
+    score block is one 128-item chunk at a time, so block_i enters only
+    through the stream buffer and the scales."""
     return (
         2 * block_i * feat_pad * y_itemsize
-        + block_b * feat_pad * 4
-        + 3 * block_b * block_i * 4
-        + 4 * block_b * _LANE * 8
+        + 2 * block_b * feat_pad * 4
+        + 2 * block_i * 4
+        + 6 * block_b * _LANE * 8
+        + 8 * block_b * _LANE * 4
     )
 
 
@@ -280,7 +301,9 @@ def tuned_blocks(feat_pad: int, y_itemsize: int) -> tuple[int, int]:
             pass
     block_b = 128
     block_i = 8192
-    while block_i > 256 and _working_set_bytes(
+    # >= 1024: a quantized block's scales are a [block_i/128, 128] f32
+    # tile, whose sublane count must reach the (8, 128) minimum
+    while block_i > 1024 and _working_set_bytes(
         block_b, block_i, feat_pad, y_itemsize
     ) > _VMEM_BUDGET_BYTES:
         block_i //= 2
@@ -307,25 +330,27 @@ def autotune_blocks(
     for bi in candidates:
         if _working_set_bytes(block_b, bi, feat_pad, itemsize) > _VMEM_BUDGET_BYTES:
             continue
-        try:
-            fn = lambda: topk_dot_batch_pallas(
-                xs, y, k=k, scales=scales, block_b=block_b, block_i=bi
-            )
-            jax.block_until_ready(fn())  # compile
-            t0 = _time.perf_counter()
-            r = None
-            for _ in range(iters):
-                r = fn()
-            np.asarray(r[0])
-            ms = (_time.perf_counter() - t0) / iters * 1000
-        except Exception:  # noqa: BLE001 - a candidate that fails just loses
-            continue
+        # a candidate inside the VMEM budget that fails to compile or run
+        # is a kernel defect: it raises, it does not quietly lose
+        fn = lambda: topk_dot_batch_pallas(
+            xs, y, k=k, scales=scales, block_b=block_b, block_i=bi
+        )
+        jax.block_until_ready(fn())  # compile
+        t0 = _time.perf_counter()
+        r = None
+        for _ in range(iters):
+            r = fn()
+        np.asarray(r[0])
+        ms = (_time.perf_counter() - t0) / iters * 1000
         if best_ms is None or ms < best_ms:
             best, best_ms = bi, ms
-    if best is not None:
-        _BLOCK_TABLE[(feat_pad, itemsize)] = (block_b, best)
-        return block_b, best
-    return tuned_blocks(feat_pad, itemsize)
+    if best is None:
+        raise ValueError(
+            f"no block_i candidate of {tuple(candidates)} fits the "
+            f"{_VMEM_BUDGET_BYTES}-byte VMEM budget at feature pad {feat_pad}"
+        )
+    _BLOCK_TABLE[(feat_pad, itemsize)] = (block_b, best)
+    return block_b, best
 
 
 # ---------------------------------------------------------------------------
@@ -374,14 +399,18 @@ def _topk_pallas_jit(
         pl.BlockSpec((block_b, feat_pad), lambda b, i: (b, 0)),
         # the item matrix stays in HBM: the kernel streams its own
         # double-buffered DMA blocks out of it
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [xs_p, y_p]
     if quantized:
+        # one row of scales per 128-item chunk, so the kernel picks a
+        # chunk's scales with a sublane index
         scale_p = _pad_to(
-            jnp.asarray(scales, dtype=jnp.float32)[None, :], ni * block_i, 1
+            jnp.asarray(scales, dtype=jnp.float32), ni * block_i, 0
+        ).reshape(-1, _LANE)
+        in_specs.append(
+            pl.BlockSpec((block_i // _LANE, _LANE), lambda b, i: (i, 0))
         )
-        in_specs.append(pl.BlockSpec((1, block_i), lambda b, i: (0, i)))
         operands.append(scale_p)
     vals, idx = pl.pallas_call(
         kernel,
@@ -432,11 +461,10 @@ def topk_dot_batch_pallas(
     interpreter (CPU tests).
 
     block_b/block_i default to the tuned table (`tuned_blocks`): the
-    largest pow2 item block whose double-buffered stream + score block +
-    sort temporaries fit the scoped-VMEM budget. Measured on v5e at
-    4096 x 1M x 50f bf16 k=10: the gen-1 argmax-round kernel ran 94 ms vs
-    187 ms XLA (1.98x); the bitonic merge removes the O(k·Ib) selection
-    sweeps that dominated that 94 ms.
+    largest pow2 item block whose double-buffered stream + sort
+    temporaries fit the scoped-VMEM budget. A kernel that fails to
+    compile or run raises; ops.als.topk_path decides from shapes, before
+    the call, whether this kernel is used at all.
     """
     if k > _LANE:
         raise ValueError(f"k must be <= {_LANE}, got {k}")
@@ -449,7 +477,8 @@ def topk_dot_batch_pallas(
     if block_i is None:
         block_i = t_bi
     block_b = min(block_b, max(8, n_b))
-    # the merge tree needs a pow2 block_i >= one lane tile. Non-pow2
+    # the chunk loop needs a block_i that is a multiple of the lane tile;
+    # pow2 keeps the compiled-shape count small. Non-pow2
     # requests round DOWN — an operator shrinking the block to dodge a
     # VMEM overflow must get at most what they asked for, never a
     # silently larger block — and never past the next pow2 of the real

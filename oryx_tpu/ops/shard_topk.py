@@ -6,12 +6,14 @@ has them): each shard runs the EXISTING fused score+top-k over its own
 row slice — the gen-2 Pallas kernel on TPU, XLA elsewhere, quantized or
 bf16 per shard — producing per-shard (values, global-index) top-k
 partials. The cross-shard merge below is the gen-2 kernel's bitonic
-merge tree (ops/pallas_topk._merge_top) one level up: the same
-(value desc, index asc) total order that makes the in-kernel merge
-bit-identical to jax.lax.top_k makes the cross-shard merge bit-identical
-to scoring the unsharded matrix — duplicate-score tie-breaks included —
-which is what lets a CPU host_mesh(n) simulation PROVE the sharded path
-correct before a pod ever runs it.
+split + merge (ops/pallas_topk) one level up: the same (value desc,
+index asc) total order that makes the in-kernel merge agree with
+jax.lax.top_k makes the cross-shard merge select exactly the INDICES
+the unsharded dispatch selects — duplicate-score tie-breaks included —
+which is what lets a CPU host_mesh(n) simulation prove the sharded path
+correct before a pod ever runs it. Values agree to f32 rounding only:
+a shard's matmul has a different shape from the whole matrix's, XLA may
+accumulate it in a different order, and the last ulp can differ.
 
 The merge runs as a host-side reduce (partials are fetched and merged on
 the default device). At k <= 128 a partial is ~1 KB per shard per row —
@@ -30,7 +32,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from oryx_tpu.ops.pallas_topk import _merge_top
+from oryx_tpu.ops.pallas_topk import _bitonic_merge, _split_top
 
 # index value carried by merge padding slots: loses every (value desc,
 # index asc) comparison against any real candidate at equal value
@@ -77,6 +79,15 @@ def _pad_tail(a, width: int, value):
     )
 
 
+def _merge_top(av, ai, bv, bi):
+    """Exact top-L of two sorted-DESCENDING length-L lists: reverse one
+    (plain XLA here, where a flip is free; the kernel never has to, its
+    lists are produced in alternating directions), bitonic split, one
+    log-merge. 1 + log2(L) stages."""
+    v, i = _split_top(av, ai, jnp.flip(bv, axis=-1), jnp.flip(bi, axis=-1))
+    return _bitonic_merge(v, i, descending=True)
+
+
 def _merge_stacked(vals, idx, *, k: int):
     """Merge tree over stacked sorted-descending partials: vals/idx
     [S, B, L] (L pow2) -> exact top-k of the union per row, ordered by
@@ -107,7 +118,7 @@ def merge_topk_partials(partials, k: int):
     descending with GLOBAL indices (ties already index-ascending — what
     lax.top_k and the fused kernel both emit after index rebasing).
     Returns ([B, k] f32, [B, k] int32) in the same total order the
-    single-matrix kernel produces, bit-identical tie-breaks included.
+    single-matrix kernel produces, tie-breaks included.
     Padding slots carry (-inf, int32 max) so they lose every comparison
     against real candidates.
     """

@@ -19,7 +19,9 @@ single-process, and init is a no-op.
 from __future__ import annotations
 
 import logging
+import os
 from dataclasses import dataclass
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -53,75 +55,55 @@ class DistributedConfig:
         return self.num_processes > 1 or self.coordinator_address is not None
 
 
-def enable_repo_compile_cache(base_dir: str) -> bool:
-    """Point the persistent compile cache at
-    <base_dir>/.jax_cache/<backend> — the shared helper behind the
-    benchmark's and the multichip dryrun's repeat-run warm compiles.
-    Split per backend: entries AOT-compiled under one platform's target
-    features must never be offered to another (observed: CPU bodies
-    loading entries stamped with mismatched machine features, an XLA
-    SIGILL hazard). Returns False (never raises) when the cache cannot be
-    configured: it is an optimization only."""
-    import os
-
-    try:
-        from oryx_tpu.common.config import load_config
-
-        # backend name WITHOUT initializing a backend when the platform is
-        # already pinned (jax_platforms set, e.g. forced-CPU dryrun/bench
-        # bodies). Only unpinned callers fall through to default_backend(),
-        # which initializes — those callers (TPU bench bodies) touch the
-        # device immediately afterwards anyway, and run timeout-bounded.
-        pinned = jax.config.jax_platforms
-        backend = pinned.split(",")[0] if pinned else jax.default_backend()
-        return configure_compilation_cache(load_config(overlay={
-            "oryx.compute.compilation-cache-dir": os.path.join(
-                base_dir, ".jax_cache", backend
-            )
-        }))
-    except Exception:  # noqa: BLE001 - never fail the caller over a cache
-        log.info("compile cache unavailable", exc_info=True)
-        return False
+# <checkout>/.jax_cache: where the persistent compilation cache lives when
+# neither the environment nor the config places it. A fixed path on
+# purpose — the directory is part of the cache key, so one built from a
+# temp name, a pid or a time never hits.
+REPO_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def configure_compilation_cache(config: Config) -> bool:
-    """Point JAX's persistent compilation cache at
-    oryx.compute.compilation-cache-dir (off when empty/null). Cold XLA
-    compiles of the training scan cost tens of seconds on a
-    remote-compile TPU transport; the disk cache amortizes them across
-    processes, restarts, and repeat builds — the moral equivalent of the
-    reference reusing a warm Spark context across generations."""
-    d = config.get_string("oryx.compute.compilation-cache-dir", None)
-    if not d:
-        return False
-    d = str(d)
-    if "://" in d and not d.startswith("file://"):
-        # remote cache URI (e.g. gs://bucket/path): hand it to JAX
-        # verbatim — Path() would mangle the double slash into a bogus
-        # local directory and silently break cross-host cache sharing
-        target = d
-    else:
-        from pathlib import Path
+def configure_compilation_cache(config: Config | None = None) -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory. Called by `cli batch|speed|serving`,
+    chip_smoke.py and the bench stage bodies before first JAX use, so
+    every start after the first skips its cold XLA compiles — the moral
+    equivalent of the reference reusing a warm Spark context across
+    generations.
 
-        from oryx_tpu.common.ioutil import strip_scheme
+    Placement, first match wins:
+    - JAX_COMPILATION_CACHE_DIR is set: JAX reads it itself and this code
+      never assigns jax_compilation_cache_dir (an operator, or the
+      machine the program was sent to, owns the location);
+    - oryx.compute.compilation-cache-dir, when configured;
+    - REPO_CACHE_DIR.
+    No sub-directory is appended in any case and no backend is
+    initialised to name one: JAX's cache key already carries the
+    platform and device kind."""
+    target = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not target:
+        d = None
+        if config is not None:
+            d = config.get_string("oryx.compute.compilation-cache-dir", None)
+        d = str(d) if d else str(REPO_CACHE_DIR)
+        if "://" in d and not d.startswith("file://"):
+            # remote cache URI (e.g. gs://bucket/path): hand it to JAX
+            # verbatim — Path() would mangle the double slash into a bogus
+            # local directory and silently break cross-host cache sharing
+            target = d
+        else:
+            from oryx_tpu.common.ioutil import strip_scheme
 
-        p = Path(strip_scheme(d))
-        p.mkdir(parents=True, exist_ok=True)
-        target = str(p)
-    jax.config.update("jax_compilation_cache_dir", target)
+            p = Path(strip_scheme(d))
+            p.mkdir(parents=True, exist_ok=True)
+            target = str(p)
+        jax.config.update("jax_compilation_cache_dir", target)
     # default thresholds skip small/fast programs; serving's bucketed
     # top-k shapes are exactly those, and they are what recompiles on
     # every process start
-    for flag, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", 0),
-    ):
-        try:
-            jax.config.update(flag, val)
-        except AttributeError:  # older jax without the knob
-            pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     log.info("persistent compilation cache at %s", target)
-    return True
+    return target
 
 
 def init_distributed(config: Config) -> bool:

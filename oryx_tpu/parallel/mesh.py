@@ -102,30 +102,3 @@ def shard_array(x, mesh: Mesh, leading: bool = True):
 
 def pad_to_multiple(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
-
-
-def pcast_varying_compat(x, axes: tuple[str, ...]):
-    """jax.lax.pcast(x, axes, to="varying") where the running jax has
-    VMA typing (0.6+); identity elsewhere — the experimental shard_map
-    of older versions has no varying-manual-axes type to cast into, and
-    a replicated carry is accepted as-is."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axes, to="varying")
-
-
-def shard_map_compat(body, *, mesh: Mesh, in_specs, out_specs, **kw):
-    """jax.shard_map across the jax versions this repo meets: the public
-    `jax.shard_map` (0.6+) when it exists, else the experimental form —
-    whose replication-check kwarg is spelled `check_rep`, not
-    `check_vma`. One shim so every shard_map program in the tree (TP
-    trainer, ring/Ulysses attention, the sharded-serve collective) runs
-    on both."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # type: ignore
-
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-    return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)

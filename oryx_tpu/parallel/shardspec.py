@@ -114,10 +114,12 @@ class RowShards:
 
 def shard_devices(n_shards: int, devices=None) -> list:
     """One placement device per shard: the first n_shards local devices
-    when that many exist (each shard's scan then runs on its own chip),
-    else the available devices cycled — on a 1-device host every shard
-    shares the device and the sharded path degrades to a correctness
-    simulation, which is exactly what the CPU host_mesh(n) tests use."""
+    (each shard's scan then runs on its own chip). On an accelerator
+    fewer devices than shards is an error — a 4-shard view that sits
+    entirely on chip 0 has the memory and speed of one chip while every
+    layer above believes in four. On the CPU the devices are cycled: a
+    1-device host shares the device between the shards and the sharded
+    path is a correctness simulation, which is what the tests use."""
     import jax
 
     if devices is None:
@@ -125,4 +127,11 @@ def shard_devices(n_shards: int, devices=None) -> list:
     devices = list(devices)
     if not devices:
         raise ValueError("no devices to place shards on")
+    if n_shards > len(devices) and devices[0].platform != "cpu":
+        raise ValueError(
+            f"{n_shards} shards need {n_shards} {devices[0].platform} "
+            f"devices and this process sees {len(devices)}: lower "
+            "oryx.serving.api.sync.shard-count or give the process more "
+            "chips"
+        )
     return [devices[s % len(devices)] for s in range(n_shards)]

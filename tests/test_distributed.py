@@ -92,50 +92,117 @@ def test_trainer_picks_up_mesh_automatically():
     assert upd.mesh.shape[DATA_AXIS] * upd.mesh.shape[MODEL_AXIS] == len(jax.devices())
 
 
-def test_configure_compilation_cache(tmp_path):
-    """oryx.compute.compilation-cache-dir points JAX's persistent compile
-    cache at the given dir (created if absent); unset/null is a no-op."""
+@pytest.fixture
+def _restore_cache_config():
+    """configure_compilation_cache mutates process-global jax config:
+    put it back, or later tests see order-dependent caching."""
+    import jax
+
+    yield
+    jax.config.update("jax_compilation_cache_dir", None)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+def test_configure_compilation_cache(tmp_path, monkeypatch, _restore_cache_config):
+    """With JAX_COMPILATION_CACHE_DIR unset,
+    oryx.compute.compilation-cache-dir places JAX's persistent compile
+    cache (created if absent, no sub-directory appended); remote URIs
+    pass through verbatim."""
     import jax
 
     from oryx_tpu.common.config import load_config
     from oryx_tpu.parallel.distributed import configure_compilation_cache
 
-    assert configure_compilation_cache(load_config()) is False
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     d = tmp_path / "xla-cache"
     cfg = load_config(
         overlay={"oryx.compute.compilation-cache-dir": str(d)}
     )
-    try:
-        assert configure_compilation_cache(cfg) is True
-        assert d.is_dir()
-        import jax.numpy as jnp
+    assert configure_compilation_cache(cfg) == str(d)
+    assert jax.config.jax_compilation_cache_dir == str(d)
+    assert d.is_dir()
+    import jax.numpy as jnp
 
-        # unique shape so this compile isn't served from an in-memory cache
-        x = jnp.ones((173, 61))
-        jax.block_until_ready(jax.jit(lambda a: (a @ a.T).sum())(x))
-        assert any(d.iterdir()), "no cache entry written"
-        # remote URIs pass through verbatim (no local 'gs:/...' dir)
-        assert configure_compilation_cache(
-            load_config(
-                overlay={"oryx.compute.compilation-cache-dir": "gs://b/c"}
-            )
-        ) is True
-        assert jax.config.jax_compilation_cache_dir == "gs://b/c"
-        import os
+    # unique shape so this compile isn't served from an in-memory cache
+    x = jnp.ones((173, 61))
+    jax.block_until_ready(jax.jit(lambda a: (a @ a.T).sum())(x))
+    assert any(d.iterdir()), "no cache entry written"
+    # remote URIs pass through verbatim (no local 'gs:/...' dir)
+    assert configure_compilation_cache(
+        load_config(
+            overlay={"oryx.compute.compilation-cache-dir": "gs://b/c"}
+        )
+    ) == "gs://b/c"
+    assert jax.config.jax_compilation_cache_dir == "gs://b/c"
+    import os
 
-        assert not os.path.exists("gs:")
-    finally:
-        jax.config.update("jax_compilation_cache_dir", None)
-        # restore the threshold knobs configure_compilation_cache zeroed,
-        # or later tests in this process see order-dependent caching
-        for flag, default in (
-            ("jax_persistent_cache_min_compile_time_secs", 1.0),
-            ("jax_persistent_cache_min_entry_size_bytes", 0),
-        ):
-            try:
-                jax.config.update(flag, default)
-            except AttributeError:
-                pass
+    assert not os.path.exists("gs:")
+
+
+def test_compilation_cache_env_var_owns_the_location(
+    tmp_path, monkeypatch, _restore_cache_config
+):
+    """JAX_COMPILATION_CACHE_DIR set: our code never assigns
+    jax_compilation_cache_dir — not from the config key, not from the
+    in-checkout default — and still applies the thresholds."""
+    import jax
+
+    from oryx_tpu.common.config import load_config
+    from oryx_tpu.parallel import distributed
+
+    env_dir = str(tmp_path / "from-env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    assigned = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        assigned.append(name)
+        real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    cfg = load_config(
+        overlay={"oryx.compute.compilation-cache-dir": str(tmp_path / "cfg")}
+    )
+    assert distributed.configure_compilation_cache(cfg) == env_dir
+    assert distributed.configure_compilation_cache() == env_dir
+    assert "jax_compilation_cache_dir" not in assigned
+    assert "jax_persistent_cache_min_compile_time_secs" in assigned
+    assert not (tmp_path / "cfg").exists()
+
+
+def test_compilation_cache_default_is_fixed_in_checkout_path():
+    """Env and config both unset: <checkout>/.jax_cache, the same string
+    in two separate processes (the directory is part of the cache key —
+    a name built from a pid, a time or a temp dir never hits), reached
+    without initialising a backend."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parent.parent
+    code = (
+        "import jax\n"
+        "from jax._src import xla_bridge\n"
+        "from oryx_tpu.parallel.distributed import configure_compilation_cache\n"
+        "d = configure_compilation_cache()\n"
+        "assert d == jax.config.jax_compilation_cache_dir\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+        "print(d)\n"
+    )
+    env = {
+        k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"
+    }
+    env["PYTHONPATH"] = str(repo)
+    outs = [
+        subprocess.run(
+            [sys.executable, "-c", code], env=env, cwd=cwd, timeout=120,
+            capture_output=True, text=True, check=True,
+        ).stdout.strip()
+        for cwd in (str(repo), "/")
+    ]
+    assert outs[0] == outs[1] == str(repo / ".jax_cache")
 
 
 def test_host_broadcast_bytes_single_process():
@@ -147,22 +214,3 @@ def test_host_broadcast_bytes_single_process():
     assert host_broadcast_bytes(b"abc", 0) == b"abc"
     assert host_broadcast_bytes(None, 0) == b""
     assert host_broadcast_bytes(b"", 0) == b""
-
-
-def test_window_quality_key_ordering():
-    """bench._window_quality_key is the ONE ordering of banked TPU
-    windows (shared with tools/bank_window.py): stages first, then
-    vs_baseline, malformed fields rank lowest instead of raising."""
-    from bench import _window_quality_key as key  # repo root on sys.path
-    # via tests/conftest.py
-
-    assert key({"stages_done": 3, "vs_baseline": 1.0}) > key(
-        {"stages_done": 2, "vs_baseline": 99.0}
-    )
-    assert key({"stages_done": 2, "vs_baseline": 5.0}) > key(
-        {"stages_done": 2, "vs_baseline": 4.0}
-    )
-    # numeric strings coerce and order correctly; junk ranks lowest
-    assert key({"stages_done": "3", "vs_baseline": None}) == (3.0, 0.0)
-    assert key({"stages_done": "wedged", "vs_baseline": [1]}) == (0.0, 0.0)
-    assert key({}) == (0.0, 0.0)

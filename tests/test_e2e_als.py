@@ -274,3 +274,26 @@ def test_full_lambda_slice_explicit(tmp_path):
     assert int(recs[0][0][1:]) % 3 == 1, recs
 
     serving.close()
+
+
+def test_evaluate_with_integer_ids(tmp_path):
+    """Canonical-integer ids take the native parser's fast path, which
+    returns int64 ids; the held-out evaluation must still match them to
+    the artifact's string ids (it silently scored NaN — no test user
+    'found' — until chip_smoke.py, whose ids are integers, asked for the
+    AUC)."""
+    from oryx_tpu.bus.api import KeyMessage
+
+    cfg = _make_config(tmp_path, 0)
+    update = ALSUpdate(cfg)
+    rng = np.random.default_rng(5)
+    events = [
+        KeyMessage(None, f"{u},{4 * int(rng.integers(0, 8)) + u % 4},1,{1000 + j}")
+        for j, u in enumerate(rng.integers(0, 40, 600).tolist())
+    ]
+    train, test = events[:540], events[540:]
+    model = update.build_model(
+        train, {"features": 8, "lambda": 0.01, "alpha": 10.0}
+    )
+    auc = update.evaluate(model, train, test)
+    assert np.isfinite(auc) and auc > 0.6, auc

@@ -1,6 +1,7 @@
 """Fused streaming dot+top-k Pallas kernel vs the XLA reference, run in
-the Pallas interpreter on CPU (the kernel itself targets TPU; the driver's
-bench exercises it on real hardware)."""
+the Pallas interpreter on CPU, plus a TPU lowering of the serving shapes
+(jax.export needs no chip). Whether Mosaic compiles and the chip agrees
+is chip_smoke.py's job."""
 
 from __future__ import annotations
 
@@ -175,3 +176,68 @@ def test_tuned_block_table_and_env_override(monkeypatch):
     monkeypatch.setattr(pt, "_BLOCK_TABLE", {})
     monkeypatch.setenv("ORYX_PALLAS_BLOCKS", "64,1024")
     assert pt.tuned_blocks(128, 2) == (64, 1024)
+
+
+def test_kernel_error_propagates_instead_of_falling_back(monkeypatch):
+    # a kernel that fails to compile or run must raise out of
+    # topk_dot_batch — every time, not once and then XLA results under
+    # the same name (the pre-PR-21 `except Exception: fall back to XLA`
+    # hid that the kernel had never compiled on the chip)
+    from oryx_tpu.ops import als, pallas_topk
+
+    def refuse(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(als, "_on_tpu", lambda a: True)
+    monkeypatch.setattr(pallas_topk, "topk_dot_batch_pallas", refuse)
+    rng = np.random.default_rng(5)
+    xs = jnp.asarray(rng.normal(size=(4, 8)), dtype=jnp.float32)
+    y = jnp.asarray(
+        rng.normal(size=(als.PALLAS_TOPK_MIN_ITEMS, 8)), dtype=jnp.float32
+    )
+    assert als.topk_path(y, 10) == "pallas"
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="Mosaic"):
+            topk_dot_batch(xs, y, k=10)
+    # shapes the kernel does not serve are XLA by selection, not by rescue
+    assert als.topk_path(y, als.PALLAS_TOPK_MAX_K + 1) == "xla"
+    assert als.topk_path(y[:1000], 10) == "xla"
+    v, i = topk_dot_batch(xs, y[:1000], k=10)
+    assert np.array_equal(
+        np.asarray(i), np.asarray(topk_dot_batch_xla(xs, y[:1000], k=10)[1])
+    )
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_kernel_lowers_for_tpu_at_serving_shapes(quantized):
+    # every (row bucket, k bucket) the batcher dispatches to the fused
+    # kernel against the 1M x 50 serving view (capacity-padded rows),
+    # lowered for TPU on this CPU host: a primitive Pallas cannot lower
+    # (PR 8's jnp.flip -> `rev`) fails here in seconds instead of being
+    # discovered — or hidden — on the chip
+    from jax import export
+
+    from oryx_tpu.ops.als import PALLAS_TOPK_MAX_K
+    from oryx_tpu.ops.transfer import row_capacity
+    from oryx_tpu.serving.batcher import BATCH_BUCKETS_ACCEL, K_BUCKETS
+
+    items, feats = row_capacity(1_000_000, 0.125), 50
+    for rows in BATCH_BUCKETS_ACCEL:
+        for k in (kb for kb in K_BUCKETS if kb <= PALLAS_TOPK_MAX_K):
+            if quantized:
+                fn = lambda xs, q, sc: topk_dot_batch_pallas(  # noqa: E731
+                    xs, q, scales=sc, k=k
+                )
+                args = (
+                    jax.ShapeDtypeStruct((rows, feats), jnp.float32),
+                    jax.ShapeDtypeStruct((items, feats), jnp.int8),
+                    jax.ShapeDtypeStruct((items,), jnp.float32),
+                )
+            else:
+                fn = lambda xs, y: topk_dot_batch_pallas(xs, y, k=k)  # noqa: E731
+                args = (
+                    jax.ShapeDtypeStruct((rows, feats), jnp.bfloat16),
+                    jax.ShapeDtypeStruct((items, feats), jnp.bfloat16),
+                )
+            exported = export.export(jax.jit(fn), platforms=["tpu"])(*args)
+            assert "tpu_custom_call" in exported.mlir_module()

@@ -170,3 +170,47 @@ def test_pod_child_flags_keeps_pod_valued_flags():
         "--conf", "pod",
     ]
     assert _pod_child_flags(["--conf=pod", "pod", "--serving"]) == ["--conf=pod"]
+
+
+def test_chip_process_envs_one_process_per_chip(monkeypatch):
+    """Launchers hand each chip-needing child its own chip, refuse a host
+    with fewer chips than children BEFORE spawning, never pin a single
+    child (it may drive every chip), and count nothing when the children
+    are kept off the TPU."""
+    from oryx_tpu.common import executil
+
+    probes = []
+
+    def fake_count(env):
+        probes.append(env)
+        return chips
+
+    monkeypatch.setattr(executil, "_count_tpu_chips", fake_count)
+    base = {"PATH": "/bin"}
+
+    chips = 4
+    envs = executil.chip_process_envs(3, base)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2"]
+    assert len({e["TPU_MESH_CONTROLLER_PORT"] for e in envs}) == 3
+    assert all(e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+
+    chips = 1
+    with pytest.raises(executil.NotEnoughChips, match="3 processes .* has 1"):
+        executil.chip_process_envs(3, base)
+    assert executil.chip_process_envs(1, base) == [base]  # owns every chip
+
+    chips = 0  # no TPU on this host: nothing to share out
+    assert executil.chip_process_envs(2, base) == [base, base]
+
+    # children kept off the TPU: no probe at all
+    probes.clear()
+    cpu = {"JAX_PLATFORMS": "cpu"}
+    assert executil.chip_process_envs(4, cpu) == [cpu] * 4
+    assert executil.chip_process_envs(4, base, platform="cpu") == [base] * 4
+    assert executil.host_tpu_chips(cpu) == 0
+    assert probes == []
+    # "auto" is not a platform: the host is asked
+    chips = 2
+    assert executil.host_tpu_chips(base, platform="auto") == 2
+    with pytest.raises(executil.NotEnoughChips):
+        executil.one_chip_env(base, 2, chips=2)

@@ -124,8 +124,23 @@ def test_shard_devices_distinct_when_available():
     n_local = len(jax.local_devices())
     devs12 = shard_devices(12)
     assert len(devs12) == 12
-    # more shards than devices: deterministic cycling, never a crash
+    # more shards than CPU devices: deterministic cycling (the
+    # correctness simulation)
     assert devs12[n_local % 12] == devs12[0] or n_local >= 12
+
+
+def test_shard_devices_refuses_fewer_chips_than_shards():
+    # on an accelerator a 4-shard view must never sit on fewer than 4
+    # chips while every layer above believes in four
+    class Chip:
+        platform = "tpu"
+
+    one = [Chip()]
+    with pytest.raises(ValueError, match="4 shards need 4 tpu devices"):
+        shard_devices(4, one)
+    four = [Chip() for _ in range(4)]
+    assert shard_devices(4, four) == four
+    assert shard_devices(2, four) == four[:2]
 
 
 def test_make_mesh_model_axis():

@@ -76,13 +76,13 @@ def extract_current(raw: str) -> dict:
     """The run's metric dict from bench-style output: prefer the full
     `"detail": true` line, else the last parseable JSON object line (the
     compact final), else a whole-document JSON object (a saved artifact,
-    possibly the {final, detail} shape banked by tools/bank_window.py)."""
+    possibly wrapped as {final, detail})."""
     try:
         doc = json.loads(raw)
     except json.JSONDecodeError:
         doc = None
     if isinstance(doc, dict):
-        # a saved artifact: either the metric dict itself, or the banked
+        # a saved artifact: either the metric dict itself, or a
         # {final, detail} wrapper — detail carries the full vocabulary
         if isinstance(doc.get("detail"), dict):
             return doc["detail"]
@@ -134,9 +134,8 @@ def banked_artifacts(root: str = ROOT) -> list[tuple[int, str, dict]]:
             continue
         if not isinstance(doc, dict):
             continue
-        # banked artifact shapes: window artifacts wrap {final, detail}
-        # (detail carries the full vocabulary; final alone still counts —
-        # bank_window.py can bank a detail-less capture), and the driver's
+        # saved artifact shapes: some wrap {final, detail} (detail carries
+        # the full vocabulary; final alone still counts), and the driver's
         # round artifacts nest the same dicts under "parsed"
         parsed = doc.get("parsed") if isinstance(doc.get("parsed"), dict) else {}
         current: dict = {}
