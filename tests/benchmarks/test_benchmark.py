@@ -1,0 +1,335 @@
+"""The benchmark's own arithmetic, its files, and a CPU rehearsal of
+benchmarks/run.py end to end (ISSUE 24). No chip: nothing here is a device
+number."""
+
+import json
+import os
+import re
+import subprocess
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from benchmarks import latency, loadgen, xplane
+from benchmarks.kinds import als_serving
+from benchmarks.run import find, load_module, metrics_of
+
+REPO = Path(__file__).resolve().parents[2]
+HERE = Path(__file__).resolve().parent
+BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
+PATHS = BENCH["paths"]
+TRAFFIC_FILES = sorted((REPO / "benchmarks" / "traffic").glob("*.json"))
+RECORDED = HERE / "data" / "steady128-5s.xplane.pb.gz"
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+POPULATION = {"items": 5_000_000, "active_users": 20_000}
+
+
+# -- latency arithmetic ------------------------------------------------------
+
+@pytest.mark.parametrize("q", [0, 50, 95, 100])
+def test_percentile_is_numpys_linear_method(q):
+    values = np.random.default_rng(q).random(1001).tolist()
+    assert latency.percentile(values, q) == pytest.approx(np.percentile(values, q))
+
+
+def test_latency_is_taken_over_the_requests_due_in_the_window():
+    result = {
+        "due": [0.5, 1.0, 1.5, 2.0],
+        "in_window": [False, True, True, True],
+        "latency_ms": [10.0, 20.0, None, 40.0],  # None: failed, refused or wrong
+        "done_at": [0.51, 1.02, None, 2.04],
+    }
+    good, attempted, failed = latency.window_latencies(result)
+    assert (good, attempted, failed) == ([20.0, 40.0], 3, 1)
+    assert latency.in_flight_at(result, 2.01) == 2  # the failed one and the last
+
+
+def test_percentile_of_nothing_is_an_error():
+    with pytest.raises(ValueError):
+        latency.percentile([], 50)
+
+
+# -- the schedule is a pure function of the seed -------------------------------
+
+@pytest.mark.parametrize("traffic_file", TRAFFIC_FILES, ids=lambda p: p.stem)
+def test_schedule_is_a_pure_function_of_the_seed(traffic_file):
+    traffic = json.loads(traffic_file.read_text())
+    seed = 2**31 + 12345  # the driver's seeds do not fit 32 signed bits
+    a = loadgen.draw_schedule(seed, POPULATION, traffic, 6.0, 40.0)
+    b = loadgen.draw_schedule(seed, POPULATION, traffic, 6.0, 40.0)
+    c = loadgen.draw_schedule(seed + 1, POPULATION, traffic, 6.0, 40.0)
+    for key in ("due", "user", "in_window"):
+        assert np.array_equal(a[key], b[key])
+    assert not np.array_equal(a["due"], c["due"])
+    # every seed sends the same number of requests, in the window and before
+    rate = traffic["rate_per_s"]
+    assert int(a["in_window"].sum()) == int(c["in_window"].sum()) == round(rate * 40.0)
+    assert len(a["due"]) == round(rate * 6.0) + round(rate * 40.0)
+    assert np.all(np.diff(a["due"][a["in_window"]]) >= 0)
+    assert a["due"][a["in_window"]].min() >= 6.0 and a["due"].max() < 46.0
+    assert a["user"].min() >= 0 and a["user"].max() < POPULATION["active_users"]
+    # Zipf(1.0): the most popular user gets ~1/H(20000) = 9.6 % of the picks
+    top_share = np.bincount(a["user"]).max() / len(a["user"])
+    assert 0.07 < top_share < 0.13
+
+
+@pytest.mark.parametrize("traffic_file", TRAFFIC_FILES, ids=lambda p: p.stem)
+def test_every_traffic_file_yields_exactly_one_k_bucket(traffic_file):
+    from oryx_tpu.serving.batcher import k_bucket
+
+    traffic = json.loads(traffic_file.read_text())
+    small = {"items": 50_000, "active_users": 2_000}
+    known = loadgen.draw_known(99, small, traffic)
+    again = loadgen.draw_known(99, small, traffic)
+    assert all(np.array_equal(x, y) for x, y in zip(known, again))
+    lo, hi = traffic["known_items"]
+    counts = {len(rows) for rows in known}
+    assert min(counts) == lo and max(counts) == hi
+    assert all(len(set(rows.tolist())) == len(rows) for rows in known)  # distinct
+    # k = howMany + known + 8 (apps/als/serving.py _top_n_plan)
+    buckets = {k_bucket(traffic["how_many"] + n + 8) for n in range(lo, hi + 1)}
+    assert buckets == {traffic["k_bucket"]}
+
+
+def test_check_body_counts_items_and_refuses_known_ones():
+    body = json.dumps([[f"i{j}", 1.0] for j in range(10)]).encode()
+    assert loadgen.check_body(body, 10, {11, 12}) is None
+    assert loadgen.check_body(body, 10, {3}) == "known_item"
+    assert loadgen.check_body(body, 9, set()) == "wrong_count"
+    assert loadgen.check_body(b"<html>", 10, set()) == "unparsable"
+
+
+# -- the comparison that decides `correct` --------------------------------------
+
+def _served(scores, known, how_many=10):
+    s = scores.copy()
+    s[known] = -np.inf
+    top = np.argsort(-s, kind="stable")[:how_many]
+    return [[f"i{r}", float(scores[r])] for r in top]
+
+
+def test_agreement_with_the_float32_reference():
+    rng = np.random.default_rng(5)
+    y = rng.standard_normal((5000, 16), dtype=np.float32)
+    x = rng.standard_normal((2, 16), dtype=np.float32)
+    scores = als_serving.reference_scores(x, y)
+    assert scores.shape == (2, 5000)
+    assert np.allclose(scores, x @ y.T, rtol=1e-5, atol=1e-5)
+    known = np.asarray([int(np.argmax(scores[0]))])
+    good = _served(scores[0], known)
+    assert als_serving.agree(good, scores[0], known, 10) is None
+    # a known item served, scores computed in bf16, or a far-off item: refused
+    assert "known" in als_serving.agree(_served(scores[0], np.asarray([], int)), scores[0], known, 10)
+    import jax.numpy as jnp
+
+    low = [[i, float(jnp.asarray(s, dtype=jnp.bfloat16))] for i, s in good]
+    low.sort(key=lambda p: -p[1])
+    assert "rel" in als_serving.agree(low, scores[0], known, 10)
+    worst = int(np.argmin(scores[0]))
+    far = good[:9] + [[f"i{worst}", float(scores[0][worst])]]
+    assert "reference's last" in als_serving.agree(far, scores[0], known, 10)
+    assert "items served" in als_serving.agree(good[:9], scores[0], known, 10)
+
+
+# -- roofline arithmetic and the peaks table ------------------------------------
+
+@pytest.mark.parametrize(
+    "rows,items,features,k,ms_hbm,ms_mxu",
+    [
+        (113, 5_000_000, 250, 128, 3.05, 1.43),  # als-5m-250f at 100 req/s
+        (48, 1_000_000, 50, 32, 0.1221, 0.02437),   # the reference's headline point
+    ],
+)
+def test_topk_work_at_the_configurations_shapes(rows, items, features, k, ms_hbm, ms_mxu):
+    peaks = json.loads((REPO / "benchmarks" / "peaks.json").read_text())["TPU v5 lite"]
+    flops, moved = als_serving.topk_work(rows, items, features, k)
+    assert flops == 2.0 * rows * items * features
+    assert moved / peaks["hbm_bytes_per_s"] * 1e3 == pytest.approx(ms_hbm, rel=0.01)
+    assert flops / peaks["flops_per_s"]["bfloat16"] * 1e3 == pytest.approx(ms_mxu, rel=0.01)
+
+
+# -- the per-layer readers on synthetic sources ----------------------------------
+
+def _reader(name):
+    return load_module(find(PATHS, f"metrics/{name}.py")).read
+
+
+def test_readers_read_their_sources_and_return_nothing_when_there_is_nothing():
+    ph = 'oryx_request_phase_seconds_sum{phase="%s"}'
+    src = {
+        "counters": {
+            'oryx_request_phase_seconds_count{phase="device"}': 100.0,
+            ph % "parse": 0.2, ph % "auth": 0.1, ph % "write": 0.1,
+            ph % "serialize": 1.0, ph % "queue_wait": 50.0, ph % "batch_wait": 6.0,
+            "oryx_topk_coalesced": 452.0, "oryx_topk_dispatches": 4.0,
+        },
+        "dispatch_records": [
+            {"padded_rows": 512, "bytes_moved": 1.0}, {"padded_rows": 512, "bytes_moved": 1.0},
+            {"padded_rows": 4096, "bytes_moved": 2.0},
+        ],
+        "generator": {"late_ms": [float(j) for j in range(101)]},
+        "trace": {
+            "window_s": 10.0, "busy_s": 9.5,
+            "ops": {"%_topk_pallas_jit.1 = custom-call": [4, 4.0], "%pad.2 = pad": [4, 0.04]},
+            "idle_gaps": [],
+        },
+        "config": {"items": 5_000_000, "features": 250},
+        "traffic": {"k_bucket": 128},
+        "peaks": json.loads((REPO / "benchmarks" / "peaks.json").read_text())["TPU v5 lite"],
+    }
+    expect = {
+        "gen_late_p95_ms": 95.0, "frontend_ms_per_req": 4.0, "post_ms_per_req": 10.0,
+        "batcher_wait_ms_per_req": 560.0, "dispatch_rows": 113.0, "dispatch_shapes": 2.0,
+        "topk_kernel_ms": 1000.0, "topk_roofline": 0.305, "device_idle_share": 5.0,
+    }
+    assert set(expect) == {m["name"] for m in BENCH["per_layer"]}
+    for name, value in expect.items():
+        assert _reader(name)(src) == pytest.approx(value, rel=0.01), name
+        assert _reader(name)({}) is None, name
+
+
+# -- the trace reduction on a recorded trace --------------------------------------
+
+def test_trace_reduction_on_a_recorded_v5e_trace():
+    """A 5 s traced run of als-5m-250f.steady128 on one v5e (PR 24)."""
+    trace = xplane.reduce_trace(RECORDED)
+    assert trace["devices"] == 1
+    count, seconds = xplane.op_seconds(trace, "topk_pallas")
+    assert count >= 2
+    assert 1.0 < seconds / count < 1.3  # the 512-row scan of 6.29M rows: ~1.13 s
+    assert 0 < trace["busy_s"] <= trace["window_s"]
+    assert trace["busy_s"] / trace["window_s"] > 0.99  # back-to-back dispatches
+    bd = xplane.breakdown(trace)
+    assert "topk_pallas" in bd["device_ops"][0][0]
+    assert len(bd["device_ops"]) <= 10 and len(bd["idle_gaps"]) <= 10
+    assert all(isinstance(label, str) and s >= 0 for label, s in bd["idle_gaps"])
+
+
+def test_interval_union_and_gap_labels():
+    assert xplane._merge([(0, 2), (1, 3), (5, 6)]) == [(0, 3), (5, 6)]
+    host = [(0.0, 10.0, "main:outer"), (3.0, 5.0, "batcher:fetch")]
+    assert xplane._label_gap(host, (3.5, 4.5)) == "batcher:fetch"  # innermost of equals
+    assert xplane._label_gap(host, (20.0, 21.0)) == "no_host_event"
+
+
+# -- BENCHMARK.json against the contract and the files ------------------------------
+
+def test_benchmark_json_resolves_to_files_and_metrics_move_what_cells_report():
+    assert set(BENCH) == {
+        "command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer",
+    }
+    configs = {c["name"]: c for c in BENCH["configs"]}
+    end = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert "setup_s" in end
+    for c in BENCH["configs"]:
+        assert any(c["file"].startswith(p + "/") for p in PATHS)
+        on_disk = json.loads((REPO / c["file"]).read_text())
+        assert find(PATHS, f"kinds/{on_disk['kind'].replace('-', '_')}.py").is_file()
+        assert any(w["config"] == c["name"] for w in BENCH["workloads"])
+        assert not [k for k in c["reduced"] if k.endswith(("_dim", "_rank")) or k == "features"]
+    assert len(BENCH["workloads"]) == len({(w["config"], w["traffic"]) for w in BENCH["workloads"]})
+    for w in BENCH["workloads"]:
+        assert w["name"] == f"{w['config']}.{w['traffic']}" and w["config"] in configs
+        assert find(PATHS, f"configs/{w['config']}.json") == REPO / configs[w["config"]]["file"]
+        assert find(PATHS, f"traffic/{w['traffic']}.json").is_file()
+        assert w["chips"] in (1, 4) and 0 < len(w["why"]) <= 200
+        reported = {m["name"] for m in metrics_of(BENCH["end_to_end"], w["name"])}
+        assert "setup_s" in reported and len(reported) >= 2
+        layer = metrics_of(BENCH["per_layer"], w["name"])
+        assert layer
+        for m in layer:
+            assert m["moves"] in reported, (m["name"], w["name"])
+    for m in BENCH["per_layer"]:
+        assert find(PATHS, f"metrics/{m['name']}.py").is_file()
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
+    for m in BENCH["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace") and 0.01 <= m["bound"] <= 0.1
+
+
+def test_names_and_units_use_only_the_allowed_characters():
+    names = [x["name"] for key in ("configs", "workloads", "end_to_end", "per_layer") for x in BENCH[key]]
+    names += [w["traffic"] for w in BENCH["workloads"]]
+    for key in ("configs", "workloads"):
+        group = [x["name"] for x in BENCH[key]]
+        assert len(group) == len(set(group))
+    metrics = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]]
+    assert len(metrics) == len(set(metrics))
+    for n in names:
+        assert NAME.match(n), n
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in ("device_trace", "program_span", "program_counter", "host_clock")
+    for p in PATHS:
+        for f in (REPO / p).rglob("*"):
+            if f.is_file() and "__pycache__" not in f.parts:
+                assert re.fullmatch(r"[A-Za-z0-9_.\-/]+", str(f.relative_to(REPO))), f
+    assert len((REPO / "BENCHMARK.json").read_bytes()) <= 64 * 1024
+    assert isinstance(BENCH["run_seconds"], int) and 1 <= BENCH["run_seconds"] <= 51
+
+
+# -- the CPU rehearsal: run.py end to end, the generator a real subprocess -----------
+
+def _run(workload, trace, tmp_path, seconds=2):
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
+    return subprocess.run(
+        [sys.executable, str(REPO / "benchmarks" / "run.py"), "--workload", workload,
+         "--seed", str(2**31 + 7), "--seconds", str(seconds), "--trace", str(trace)],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=300,
+    )
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_cpu_rehearsal_of_a_test_only_cell_added_as_files(trace, tmp_path):
+    """als-tiny.tiny is not in BENCHMARK.json: its configuration and traffic
+    files under tests/benchmarks/ are found by name alone."""
+    proc = _run("als-tiny.tiny", trace, tmp_path)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    lines = proc.stdout.strip().splitlines()
+    last = json.loads(lines[-1])
+    assert set(last) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert last["failed"] == 0 and last["attempted"] == 40
+    # on the CPU the batcher pads rows to powers of two, so a burst may meet a
+    # row count the warm-up never saw: the run then says so and is not correct
+    assert last["correct"] is True or "inside the window" in proc.stderr
+    assert last["device"]["platform"] == "cpu"  # never a chip result
+    assert set(last["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+    if trace:
+        # host spans and counters are read; a CPU trace has no device plane,
+        # so the device metrics are left out and not written as zeros
+        assert set(last["metrics"]) == {
+            "gen_late_p95_ms", "frontend_ms_per_req", "post_ms_per_req",
+            "batcher_wait_ms_per_req", "dispatch_rows", "dispatch_shapes",
+        }
+    else:
+        assert set(last["metrics"]) == {"p50_ms", "p95_ms", "setup_s"}
+    for m in last["metrics"].values():
+        assert set(m) == {"value", "unit"} and m["value"] > 0
+    notes = [json.loads(ln)["info"] for ln in lines[:-1]]
+    assert any("peak_bytes_in_use" in n and "compile_cache" in n for n in notes)
+    assert any("in_flight_at_window_end" in n and n["generator_processes"] == 1 for n in notes)
+
+
+@pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
+def test_real_cells_refuse_to_run_without_a_tpu(workload, tmp_path):
+    proc = _run(workload, 0, tmp_path)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+
+
+def test_alone_with_the_benchmark_files_it_prints_no_result(tmp_path):
+    import shutil
+
+    shutil.copy(REPO / "BENCHMARK.json", tmp_path)
+    for p in PATHS:
+        shutil.copytree(REPO / p, tmp_path / p, ignore=shutil.ignore_patterns("__pycache__"))
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    proc = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--workload", BENCH["workloads"][0]["name"],
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0 and proc.stdout.strip() == ""
