@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from oryx_tpu.api import AbstractServingModelManager, ServingModel
 from oryx_tpu.common.config import Config
+from oryx_tpu.common.perfattr import current_ledger
 from oryx_tpu.common.tracing import current_span, get_tracer
 from oryx_tpu.ops.als import compute_updated_xu
 from oryx_tpu.apps.als.common import ALSConfig
@@ -1003,7 +1004,12 @@ class ALSServingModel(ServingModel):
             valid_rows=n, score_mode=self._effective_mode,
         )
 
+        # captured here, on the request's thread: _post runs on a post-pool
+        # thread, which has no thread-current ledger
+        ledger = current_ledger()
+
         def _post(result):
+            t_post = time.monotonic()
             pairs = _post_pairs(result)
             if rescorer is None and pairs:
                 # device-path live recall: the exact reference is the
@@ -1014,6 +1020,14 @@ class ALSServingModel(ServingModel):
                     self._effective_mode, trace_id,
                     lambda: (host_mat, ids, n),
                 )
+            if ledger is not None:
+                # the first two parts of `serialize` (perfattr.POST_STAGES):
+                # the wait between the device phase's end and this call,
+                # then this call; _render adds the third
+                tail = ledger.last_end()
+                if tail is not None:
+                    ledger.add_stage("handoff", max(0.0, t_post - tail))
+                    ledger.add_stage("rerank", time.monotonic() - t_post)
             return pairs
 
         def _post_pairs(result):

@@ -84,6 +84,17 @@ PHASES = (
     "write",          # payload bytes -> socket
 )
 
+# Stages of the deferred top-n path's host work after the device phase.
+# They are PARTS of `serialize` (which is anchored at the end of `device`
+# and so holds all three), not phases: they join no budget and do not
+# tile the request. Stamped by apps/als/serving.py `_post` and
+# serving/app.py `_render` into oryx_post_stage_seconds{stage}.
+POST_STAGES = (
+    "handoff",  # results on the host -> _post starts on a post-pool thread
+    "rerank",   # _post: pad filter, exact re-rank, trim, shadow-sample enqueue
+    "render",   # response object -> payload bytes (_render_body)
+)
+
 # Device idle-gap causes. `unattributed` is the honesty valve: time the
 # dispatcher cannot pin on a concrete cause is reported, not hidden.
 IDLE_CAUSES = (
@@ -129,7 +140,7 @@ class PhaseLedger:
     the batcher dispatcher; no lock needed. Flushed exactly once by the
     frontend after the response bytes hit the socket."""
 
-    __slots__ = ("t0", "trace", "trace_id", "_items", "_flushed")
+    __slots__ = ("t0", "trace", "trace_id", "_items", "_stages", "_flushed")
 
     def __init__(self, trace=None, trace_id: str | None = None):
         self.t0 = time.monotonic()
@@ -138,6 +149,7 @@ class PhaseLedger:
             getattr(trace, "trace_id", None) if trace is not None else None
         )
         self._items: list[tuple[str, float, float]] = []
+        self._stages: list[tuple[str, float]] = []
         self._flushed = False
 
     def add(self, phase: str, seconds: float, start: float | None = None) -> None:
@@ -147,8 +159,19 @@ class PhaseLedger:
             return
         self._items.append((phase, -1.0 if start is None else start, seconds))
 
+    def add_stage(self, stage: str, seconds: float) -> None:
+        """Stamp ``seconds`` of one POST_STAGES part of `serialize`. Kept
+        apart from the phases: it moves neither ``total`` nor
+        ``last_end``, so `serialize` reads what it read without it."""
+        if seconds < 0.0 or seconds != seconds:
+            return
+        self._stages.append((stage, seconds))
+
     def items(self) -> list[tuple[str, float, float]]:
         return list(self._items)
+
+    def stages(self) -> list[tuple[str, float]]:
+        return list(self._stages)
 
     def total(self) -> float:
         return sum(s for _, _, s in self._items)
@@ -266,6 +289,8 @@ class PerfAttr:
             self._h_phase.observe(
                 seconds, trace_id=ledger.trace_id, phase=phase
             )
+        for stage, seconds in ledger.stages():
+            self._h_post.observe(seconds, stage=stage)
         if self.enabled:
             with self._win_lock:
                 self._prune(self._phase_win, now)
@@ -454,6 +479,13 @@ class PerfAttr:
                 "queue_wait, batch_wait, pad, device, host_fallback, "
                 "serialize, write), by phase; carries metric->trace "
                 "exemplars when tracing is enabled",
+                buckets=PHASE_SECONDS_BUCKETS,
+            )
+            self._h_post = reg.histogram(
+                "oryx_post_stage_seconds",
+                "Per top-n answer, the parts of the serialize phase on the "
+                "deferred path (handoff to the post pool, rerank = _post, "
+                "render = payload bytes), by stage",
                 buckets=PHASE_SECONDS_BUCKETS,
             )
             self._h_gap = reg.histogram(

@@ -70,12 +70,16 @@ BYTES_BUCKETS = exponential_buckets(4096.0, 4.0, 12)
 
 
 class DispatchRecord:
-    """One device dispatch's cost accounting."""
+    """One device dispatch's cost accounting. A serving dispatch also
+    carries the batcher's number for it (``dispatch``, the same number its
+    ``batcher.*`` regions and the requests' ``batcher.device`` spans carry)
+    and its ``k_bucket``; kinds without them (train) leave both None."""
 
     __slots__ = (
         "kind", "t_start", "wall_s", "flops", "bytes_moved",
         "rows", "padded_rows", "valid_rows", "capacity_rows",
         "occupancy", "trace_id", "score_mode", "seq",
+        "dispatch", "k_bucket",
     )
 
     def __init__(
@@ -91,6 +95,8 @@ class DispatchRecord:
         capacity_rows: int,
         trace_id: str | None,
         score_mode: str | None = None,
+        dispatch: int | None = None,
+        k_bucket: int | None = None,
     ):
         self.kind = kind
         self.t_start = t_start
@@ -116,10 +122,12 @@ class DispatchRecord:
         # dispatcher labels it; None for unlabeled kinds (train)
         self.score_mode = score_mode
         self.seq = -1
+        self.dispatch = dispatch
+        self.k_bucket = k_bucket
 
     def chrome_event(self, pid: int) -> dict:
         """This record as a Chrome trace-event `X` slice (Perfetto)."""
-        return {
+        event = {
             "name": f"device.dispatch.{self.kind}",
             "cat": "oryx-perf",
             "ph": "X",
@@ -139,6 +147,10 @@ class DispatchRecord:
                 "score_mode": self.score_mode or "",
             },
         }
+        if self.dispatch is not None:
+            # joins the slice to the batcher.* regions of the same number
+            event["args"].update(dispatch=self.dispatch, k_bucket=self.k_bucket)
+        return event
 
 
 class PerfStats:
@@ -247,12 +259,15 @@ class PerfStats:
         trace_id: str | None = None,
         t_start: float | None = None,
         score_mode: str | None = None,
+        dispatch: int | None = None,
+        k_bucket: int | None = None,
     ) -> DispatchRecord:
         rec = DispatchRecord(
             kind,
             t_start if t_start is not None else time.monotonic() - wall_s,
             wall_s, flops, bytes_moved, rows, padded_rows, valid_rows,
             capacity_rows, trace_id, score_mode,
+            dispatch, k_bucket,
         )
         rec.seq = next(self._seq)
         buf = self._buf

@@ -35,7 +35,16 @@ a new shape signature — XLA trace+compile blocking the dispatcher; see
 common/perfattr.py), and ``phase.<name>`` children replayed from each
 request's phase ledger (``phase.parse`` … ``phase.write``) so the
 latency-budget phases line up under the request root even when a phase
-ran on another thread.
+ran on another thread. ``batcher.device`` carries ``dispatch=<n>``, the
+number of the coalesced dispatch the request waited for.
+
+The dispatcher thread's own timeline is a second family, opened through
+``Tracer.region`` and so ALSO written into any running jax.profiler
+session, on the device trace's clock: per dispatch
+``batcher.launch{dispatch, rows, padded, k_bucket}`` holding
+``batcher.issue``, and later ``batcher.fetch{dispatch}`` and
+``batcher.distribute{dispatch}`` (docs/observability.md says what each
+covers).
 """
 
 from __future__ import annotations
@@ -240,6 +249,17 @@ class Tracer:
             self._record(s)
         return s
 
+    def region(self, name: str, **attrs) -> "_Region":
+        """Context manager for a span bound to the calling thread, on both
+        clocks: it ALWAYS enters a ``jax.profiler.TraceAnnotation`` (which
+        costs well under a microsecond with no profiler session, and with
+        one puts the region into the xplane beside the device's ops), and
+        it records a ``Span`` into the ring only while tracing is enabled.
+        The span parents to the thread's current span and is the current
+        span inside the block, so nested regions form a tree. In a process
+        without jax only the ring half exists."""
+        return _Region(self, name, attrs)
+
     def _record(self, span: Span) -> None:
         span.seq = next(self._seq)
         buf = self._buf
@@ -307,6 +327,57 @@ def swap_current(span: Span | None) -> Span | None:
     prev = getattr(_tls, "span", None)
     _tls.span = span
     return prev
+
+
+# -- regions: thread-bound spans, also on the profiler's clock ---------------
+
+_annotation_cls = ...  # Ellipsis = jax not looked for yet; None = no jax
+
+
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation, imported once on first use (the fleet
+    front imports this module and needs no jax), or None without jax."""
+    global _annotation_cls
+    if _annotation_cls is ...:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = None
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
+
+class _Region:
+    """What ``Tracer.region`` returns; see there."""
+
+    __slots__ = ("_tracer", "_name", "_attrs", "_annotation", "_span", "_prev")
+
+    def __init__(self, tracer: Tracer, name: str, attrs: dict):
+        self._tracer = tracer
+        self._name = name
+        self._attrs = attrs
+        self._annotation = None
+        self._span = None
+        self._prev = None
+
+    def __enter__(self) -> "_Region":
+        cls = _trace_annotation()
+        if cls is not None:
+            self._annotation = cls(self._name, **self._attrs)
+            self._annotation.__enter__()
+        if self._tracer.enabled:
+            self._span = self._tracer.start(
+                self._name, parent=current_span(), **self._attrs
+            )
+            self._prev = swap_current(self._span)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._span is not None:
+            swap_current(self._prev)
+            self._tracer.finish(self._span)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
 
 
 # -- export -----------------------------------------------------------------

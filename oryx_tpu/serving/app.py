@@ -738,7 +738,12 @@ def _render(result: Any, req: Request) -> tuple[int, bytes, str]:
     tail = req.ledger.last_end()
     start = tail if tail is not None and tail < t0 else t0
     out = _render_body(result, req)
-    req.ledger.add("serialize", time.monotonic() - start, start=start)
+    end = time.monotonic()
+    req.ledger.add("serialize", end - start, start=start)
+    if req.ledger.stages():
+        # a top-n answer (its _post stamped handoff and rerank): the third
+        # part of this serialize phase, so each such answer has one of each
+        req.ledger.add_stage("render", end - t0)
     return out
 
 

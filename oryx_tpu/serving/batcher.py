@@ -41,6 +41,7 @@ degraded service can never pass for a working chip.
 
 from __future__ import annotations
 
+import itertools
 import logging
 import threading
 import time
@@ -77,8 +78,11 @@ _PA = get_perfattr()
 
 def _dispatch_bytes(padded: int, features: int, y, kb: int) -> float:
     """Approximate bytes one coalesced dispatch moves: the query upload,
-    the item-matrix stream out of HBM (the dominant term — the top-k scan
-    is bandwidth-bound in Y), and the result fetch."""
+    one read of the item matrix out of HBM (by far the largest term) and
+    the result fetch. A count of bytes, not a model of the kernel's time:
+    the fused scan reaches 0.27 % of its roofline at 5M x 250 (ledger,
+    PR 24) and its time is linear in query rows, so it is selection-bound
+    and not bandwidth-bound in Y."""
     try:
         y_bytes = float(getattr(y, "nbytes", 0) or 0)
     except Exception:  # non-jax stub matrices in tests
@@ -327,6 +331,10 @@ class TopKBatcher:
         # wedge on an already-compiled shape still trips at device_timeout
         self._compiling: dict[tuple, float] = {}  # guarded-by: _lock
         self._on_accel = False
+        # numbers the (matrix, k-bucket) groups as they start forming; the
+        # number rides the group's handle to _resolve, names its batcher.*
+        # regions and lands on its DispatchRecord (next() is GIL-atomic)
+        self._dispatch_seq = itertools.count()
         self._queue: list[_Pending] = []  # guarded-by: _lock
         self._thread: threading.Thread | None = None  # guarded-by: _lock
         self._closed = False  # guarded-by: _lock
@@ -708,129 +716,148 @@ class TopKBatcher:
             # failures stay inside their group: a bad shape / OOM against
             # one target matrix must not fail requests scoring another
             shape_key = None
+            n_disp = next(self._dispatch_seq)
             try:
                 faults.fire("serving.device")
                 t0 = time.monotonic()
                 y = group[0].y
                 b = len(group)
-                # a capacity-padded serving view scores zero rows past
-                # valid_rows — they're HBM-cheap but not useful FLOPs, so
-                # the MFU figure counts only the real-data prefix
-                n_rows = group[0].valid_rows or y.shape[0]
-                group_flops = 2.0 * b * n_rows * y.shape[1]
                 self._note_device(y)
-                # per-dtype peak: a quantized (int8) dispatch's MFU window
-                # divides by the int8 peak, an exact bf16 one by bf16
-                _PERF.set_peak("serving", self._peak_for_matrix(y))
                 padded = _pad_rows(b, self._on_accel)
-                # keyed on the FULL (capacity) shape: the serving view
-                # pads rows up a bucket ladder precisely so store growth
-                # keeps hitting these compiled entries
-                shape_key = (
-                    padded, kb, recall, tuple(y.shape),
-                    str(getattr(y, "dtype", "")),
-                )
-                first_compile = False
-                with self._cond:
-                    # recovery probes re-test against the latest matrix;
-                    # the probe thread reads it under the same lock
-                    # [oryxlint guarded-by fix: these three were unlocked]
-                    self._last_y = y
-                    self.flops_scored += group_flops
-                    if shape_key not in self._compiled_shapes:
-                        # first dispatch of this shape may cold-compile for
-                        # minutes: give the hang watchdog compile grace
-                        # (for THIS shape, until it resolves) so it doesn't
-                        # misread the compile as a hung device and fail
-                        # the device path over to host scoring
-                        first_compile = True
-                        self._compiling[shape_key] = (
-                            time.monotonic() + self.compile_timeout
-                        )
-                for p in group:
-                    if p.ledger is not None:
-                        # picked -> this group starts forming
-                        p.ledger.add("batch_wait", t0 - t_pick, start=t_pick)
-                t_pad = time.monotonic()
-                xs = np.zeros((padded, y.shape[1]), dtype=np.float32)
-                for i, p in enumerate(group):
-                    xs[i] = p.vec
-                pad_s = time.monotonic() - t_pad
-                for p in group:
-                    if p.ledger is not None:
-                        p.ledger.add("pad", pad_s, start=t_pad)
-                if tr.enabled:
-                    # device span: dispatch issue until the host fetch
-                    # resolves (_resolve); one span per request so every
-                    # request's trace tree shows its own device time
+                with tr.region(
+                    "batcher.launch", dispatch=n_disp, rows=b,
+                    padded=padded, k_bucket=kb,
+                ):
+                    # a capacity-padded serving view scores zero rows past
+                    # valid_rows — they're HBM-cheap but not useful FLOPs,
+                    # so the MFU figure counts only the real-data prefix
+                    n_rows = group[0].valid_rows or y.shape[0]
+                    group_flops = 2.0 * b * n_rows * y.shape[1]
+                    # per-dtype peak: a quantized (int8) dispatch's MFU
+                    # window divides by the int8 peak, an exact bf16 one
+                    # by bf16
+                    _PERF.set_peak("serving", self._peak_for_matrix(y))
+                    # keyed on the FULL (capacity) shape: the serving
+                    # view pads rows up a bucket ladder precisely so
+                    # store growth keeps hitting these compiled entries
+                    shape_key = (
+                        padded, kb, recall, tuple(y.shape),
+                        str(getattr(y, "dtype", "")),
+                    )
+                    first_compile = False
+                    with self._cond:
+                        # recovery probes re-test against the latest
+                        # matrix; the probe thread reads it under the
+                        # same lock [oryxlint guarded-by fix: these
+                        # three were unlocked]
+                        self._last_y = y
+                        self.flops_scored += group_flops
+                        if shape_key not in self._compiled_shapes:
+                            # first dispatch of this shape may
+                            # cold-compile for minutes: give the hang
+                            # watchdog compile grace (for THIS shape,
+                            # until it resolves) so it doesn't misread
+                            # the compile as a hung device and fail the
+                            # device path over to host scoring
+                            first_compile = True
+                            self._compiling[shape_key] = (
+                                time.monotonic() + self.compile_timeout
+                            )
                     for p in group:
-                        if p.t_enq:
-                            p.dev_span = [tr.start(
-                                "batcher.device", parent=p.trace_parent,
-                                k=kb, batch=b, rows=padded,
-                            )]
-                t_disp = time.monotonic()
-                if gap_pending:
-                    # the idle gap between the previous dispatch finishing
-                    # and this one being issued, split by measured cause
-                    gap_pending = False
-                    with self._lock:
-                        gap = t_disp - self._gap_mark
-                        causes = classify_idle_gap(
-                            gap, wait_s=self._gap_wait,
-                            serialize_s=self._gap_resolve,
-                            down_s=self._gap_down,
-                        )
-                        self._gap_wait = 0.0
-                        self._gap_resolve = 0.0
-                        self._gap_down = 0.0
-                        self._gap_mark = t_disp
-                    for cause, s in causes.items():
-                        _PA.record_idle_gap(cause, s)
-                vals, idx = topk_dot_batch(
-                    jnp.asarray(xs), y, k=kb, recall=recall
-                )
-                try:
-                    vals.copy_to_host_async()
-                    idx.copy_to_host_async()
-                except AttributeError:  # non-jax array (tests with stubs)
-                    pass
-                t_issued = time.monotonic()
-                with self._lock:
-                    # the device is busy from here: the next idle gap
-                    # starts when its results land (_resolve)
-                    self._gap_mark = max(self._gap_mark, t_issued)
-                if first_compile:
-                    # the jit call traces+compiles synchronously on the
-                    # first dispatch of a shape, then enqueues: the call
-                    # duration IS the compile stall (a warm call returns
-                    # in microseconds). Feed the compile telemetry, charge
-                    # the stall to the device's idle account, and mark it
-                    # as a distinct waterfall span — the first dispatch
-                    # after a generation swap lands here by construction
-                    # (a new matrix identity is a new shape signature).
-                    compile_s = t_issued - t_disp
-                    _PA.record_compile("serving", compile_s)
-                    _PA.record_idle_gap("compile_stall", compile_s)
+                        if p.ledger is not None:
+                            # picked -> this group starts forming
+                            p.ledger.add(
+                                "batch_wait", t0 - t_pick, start=t_pick
+                            )
+                    t_pad = time.monotonic()
+                    xs = np.zeros((padded, y.shape[1]), dtype=np.float32)
+                    for i, p in enumerate(group):
+                        xs[i] = p.vec
+                    pad_s = time.monotonic() - t_pad
+                    for p in group:
+                        if p.ledger is not None:
+                            p.ledger.add("pad", pad_s, start=t_pad)
                     if tr.enabled:
-                        tr.record_interval(
-                            "batcher.compile_stall", t_disp, t_issued,
-                            parent=group[0].trace_parent,
-                            k=kb, rows=padded,
+                        # device span: dispatch issue until the host
+                        # fetch resolves (_resolve); one span per
+                        # request so every request's trace tree shows
+                        # its own device time, and names the dispatch
+                        # that caused the wait
+                        for p in group:
+                            if p.t_enq:
+                                p.dev_span = [tr.start(
+                                    "batcher.device",
+                                    parent=p.trace_parent,
+                                    k=kb, batch=b, rows=padded,
+                                    dispatch=n_disp,
+                                )]
+                    with tr.region("batcher.issue"):
+                        t_disp = time.monotonic()
+                        if gap_pending:
+                            # the idle gap between the previous dispatch
+                            # finishing and this one being issued, split
+                            # by measured cause
+                            gap_pending = False
+                            with self._lock:
+                                gap = t_disp - self._gap_mark
+                                causes = classify_idle_gap(
+                                    gap, wait_s=self._gap_wait,
+                                    serialize_s=self._gap_resolve,
+                                    down_s=self._gap_down,
+                                )
+                                self._gap_wait = 0.0
+                                self._gap_resolve = 0.0
+                                self._gap_down = 0.0
+                                self._gap_mark = t_disp
+                            for cause, s in causes.items():
+                                _PA.record_idle_gap(cause, s)
+                        vals, idx = topk_dot_batch(
+                            jnp.asarray(xs), y, k=kb, recall=recall
                         )
-                # per-dispatch cost accounting, finalized at resolve time
-                # (wall-clock runs dispatch → host fetch materialized):
-                # occupancy = real rows / the capacity-padded view shape
-                tp = group[0].trace_parent
-                cost = (
-                    t0, group_flops,
-                    _dispatch_bytes(padded, y.shape[1], y, kb),
-                    b, padded, int(n_rows), int(y.shape[0]),
-                    tp.trace_id if tp is not None else None,
-                    group[0].score_mode,
-                    t_disp,
-                )
-                launched.append((group, kb, vals, idx, shape_key, cost))
+                        try:
+                            vals.copy_to_host_async()
+                            idx.copy_to_host_async()
+                        except AttributeError:  # non-jax array (test stubs)
+                            pass
+                        t_issued = time.monotonic()
+                    with self._lock:
+                        # the device is busy from here: the next idle gap
+                        # starts when its results land (_resolve)
+                        self._gap_mark = max(self._gap_mark, t_issued)
+                    if first_compile:
+                        # the jit call traces+compiles synchronously on
+                        # the first dispatch of a shape, then enqueues: the
+                        # call duration IS the compile stall (a warm call
+                        # returns in microseconds). Feed the compile
+                        # telemetry, charge the stall to the device's idle
+                        # account, and mark it as a distinct waterfall span
+                        # — the first dispatch after a generation swap
+                        # lands here by construction (a new matrix identity
+                        # is a new shape signature).
+                        compile_s = t_issued - t_disp
+                        _PA.record_compile("serving", compile_s)
+                        _PA.record_idle_gap("compile_stall", compile_s)
+                        if tr.enabled:
+                            tr.record_interval(
+                                "batcher.compile_stall", t_disp, t_issued,
+                                parent=group[0].trace_parent,
+                                k=kb, rows=padded,
+                            )
+                    # per-dispatch cost accounting, finalized at resolve
+                    # time (wall-clock runs dispatch → host fetch
+                    # materialized): occupancy = real rows / the
+                    # capacity-padded view shape. The dispatch's number
+                    # rides along to the DispatchRecord.
+                    tp = group[0].trace_parent
+                    cost = (
+                        t0, group_flops,
+                        _dispatch_bytes(padded, y.shape[1], y, kb),
+                        b, padded, int(n_rows), int(y.shape[0]),
+                        tp.trace_id if tp is not None else None,
+                        group[0].score_mode,
+                        t_disp, n_disp,
+                    )
+                    launched.append((group, kb, vals, idx, shape_key, cost))
             except Exception as e:
                 log.exception("batcher group dispatch failed (k=%d)", kb)
                 # no compile is in flight anymore: drop the grace entry,
@@ -870,57 +897,63 @@ class TopKBatcher:
         self, item: tuple[list[_Pending], int, object, object, tuple, tuple]
     ) -> None:
         group, kb, vals_dev, idx_dev, shape_key, cost = item
+        (t0, flops, bytes_moved, b, padded, valid, cap, trace_id,
+         mode, t_disp, n_disp) = cost
         try:
-            vals = np.asarray(vals_dev)
-            idx = np.asarray(idx_dev)
-            t_fetch = time.monotonic()
-            # results are on the host: the dispatch's device work + fetch
-            # is complete — record its cost (FLOPs/bytes/wall/occupancy)
-            # into the live perf accounting
-            (t0, flops, bytes_moved, b, padded, valid, cap, trace_id,
-             mode, t_disp) = cost
-            _PERF.record_dispatch(
-                "serving",
-                flops=flops, bytes_moved=bytes_moved,
-                wall_s=t_fetch - t0, rows=b, padded_rows=padded,
-                valid_rows=valid, capacity_rows=cap, trace_id=trace_id,
-                t_start=t0, score_mode=mode,
-            )
-            # the dispatch completed, so this shape's compile is done:
-            # drop its grace window and never grant it one again. Both
-            # under the lock — the watchdog iterates _compiling.values()
-            # holding it (an unlocked pop mid-iteration kills the watchdog
-            # thread with RuntimeError), and _launch's membership probe of
-            # _compiled_shapes reads under it too
-            with self._cond:
-                self._compiled_shapes.add(shape_key)
-                self._compiling.pop(shape_key, None)
-                # the device finished this dispatch when the fetch landed:
-                # the next idle gap starts here. Earlier accumulator
-                # slices predate the device finishing — outside the new
-                # gap window by construction — so they reset with it.
-                if t_fetch > self._gap_mark:
-                    self._gap_mark = t_fetch
-                    self._gap_wait = 0.0
-                    self._gap_resolve = 0.0
-                    self._gap_down = 0.0
-            for i, p in enumerate(group):
-                k_eff = min(p.k, kb)
-                span = p.take_dev_span()
-                if span is not None:
-                    _TRACER.finish(span)
-                if p.ledger is not None:
-                    # dispatch issue -> results fetched to host
-                    p.ledger.add("device", t_fetch - t_disp, start=t_disp)
-                # the watchdog may have host-resolved this request while the
-                # fetch above sat on a wedged transport — and may win the
-                # race BETWEEN a done() check and the set; try_set absorbs
-                # the lost race instead of failing the rest of the group
-                try_set_result(p.future, (vals[i, :k_eff], idx[i, :k_eff]))
-            with self._lock:
-                # result-distribution tail: host work the device idles
-                # behind (the host_serialize slice of the next gap)
-                self._gap_resolve += time.monotonic() - t_fetch
+            with _TRACER.region("batcher.fetch", dispatch=n_disp):
+                vals = np.asarray(vals_dev)
+                idx = np.asarray(idx_dev)
+                t_fetch = time.monotonic()
+            with _TRACER.region("batcher.distribute", dispatch=n_disp):
+                # results are on the host: the dispatch's device work +
+                # fetch is complete — record its cost (FLOPs/bytes/wall/
+                # occupancy) into the live perf accounting
+                _PERF.record_dispatch(
+                    "serving",
+                    flops=flops, bytes_moved=bytes_moved,
+                    wall_s=t_fetch - t0, rows=b, padded_rows=padded,
+                    valid_rows=valid, capacity_rows=cap, trace_id=trace_id,
+                    t_start=t0, score_mode=mode,
+                    dispatch=n_disp, k_bucket=kb,
+                )
+                # the dispatch completed, so this shape's compile is done:
+                # drop its grace window and never grant it one again. Both
+                # under the lock — the watchdog iterates
+                # _compiling.values() holding it (an unlocked pop
+                # mid-iteration kills the watchdog thread with
+                # RuntimeError), and _launch's membership probe of
+                # _compiled_shapes reads under it too
+                with self._cond:
+                    self._compiled_shapes.add(shape_key)
+                    self._compiling.pop(shape_key, None)
+                    # the device finished this dispatch when the fetch
+                    # landed: the next idle gap starts here. Earlier
+                    # accumulator slices predate the device finishing —
+                    # outside the new gap window by construction — so they
+                    # reset with it.
+                    if t_fetch > self._gap_mark:
+                        self._gap_mark = t_fetch
+                        self._gap_wait = 0.0
+                        self._gap_resolve = 0.0
+                        self._gap_down = 0.0
+                for i, p in enumerate(group):
+                    k_eff = min(p.k, kb)
+                    span = p.take_dev_span()
+                    if span is not None:
+                        _TRACER.finish(span)
+                    if p.ledger is not None:
+                        # dispatch issue -> results fetched to host
+                        p.ledger.add("device", t_fetch - t_disp, start=t_disp)
+                    # the watchdog may have host-resolved this request
+                    # while the fetch above sat on a wedged transport — and
+                    # may win the race BETWEEN a done() check and the set;
+                    # try_set absorbs the lost race instead of failing the
+                    # rest of the group
+                    try_set_result(p.future, (vals[i, :k_eff], idx[i, :k_eff]))
+                with self._lock:
+                    # result-distribution tail: host work the device idles
+                    # behind (the host_serialize slice of the next gap)
+                    self._gap_resolve += time.monotonic() - t_fetch
         except Exception as e:
             log.exception("batcher group resolve failed (k=%d)", kb)
             with self._cond:

@@ -88,6 +88,9 @@ REQUIRED_PERFATTR_FAMILIES = (
     "oryx_device_idle_gap_seconds",
     "oryx_xla_compile_seconds",
     "oryx_xla_compiles_total",
+    # the parts of `serialize` on the deferred top-n path (ISSUE 25); the
+    # benchmark's post_*_ms_per_req readers key on it
+    "oryx_post_stage_seconds",
 )
 
 
