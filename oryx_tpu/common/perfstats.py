@@ -73,13 +73,16 @@ class DispatchRecord:
     """One device dispatch's cost accounting. A serving dispatch also
     carries the batcher's number for it (``dispatch``, the same number its
     ``batcher.*`` regions and the requests' ``batcher.device`` spans carry)
-    and its ``k_bucket``; kinds without them (train) leave both None."""
+    and its ``k_bucket``; kinds without them (train) leave both None. A
+    dispatch through the fused top-k kernel carries the kernel's own count
+    of the item chunks it folded and walked (ops/pallas_topk.py's
+    threshold gate); every other path leaves those two None."""
 
     __slots__ = (
         "kind", "t_start", "wall_s", "flops", "bytes_moved",
         "rows", "padded_rows", "valid_rows", "capacity_rows",
         "occupancy", "trace_id", "score_mode", "seq",
-        "dispatch", "k_bucket",
+        "dispatch", "k_bucket", "chunks_folded", "chunks_total",
     )
 
     def __init__(
@@ -97,6 +100,8 @@ class DispatchRecord:
         score_mode: str | None = None,
         dispatch: int | None = None,
         k_bucket: int | None = None,
+        chunks_folded: int | None = None,
+        chunks_total: int | None = None,
     ):
         self.kind = kind
         self.t_start = t_start
@@ -124,6 +129,8 @@ class DispatchRecord:
         self.seq = -1
         self.dispatch = dispatch
         self.k_bucket = k_bucket
+        self.chunks_folded = chunks_folded
+        self.chunks_total = chunks_total
 
     def chrome_event(self, pid: int) -> dict:
         """This record as a Chrome trace-event `X` slice (Perfetto)."""
@@ -150,6 +157,10 @@ class DispatchRecord:
         if self.dispatch is not None:
             # joins the slice to the batcher.* regions of the same number
             event["args"].update(dispatch=self.dispatch, k_bucket=self.k_bucket)
+        if self.chunks_total is not None:
+            event["args"].update(
+                chunks_folded=self.chunks_folded, chunks_total=self.chunks_total
+            )
         return event
 
 
@@ -261,13 +272,15 @@ class PerfStats:
         score_mode: str | None = None,
         dispatch: int | None = None,
         k_bucket: int | None = None,
+        chunks_folded: int | None = None,
+        chunks_total: int | None = None,
     ) -> DispatchRecord:
         rec = DispatchRecord(
             kind,
             t_start if t_start is not None else time.monotonic() - wall_s,
             wall_s, flops, bytes_moved, rows, padded_rows, valid_rows,
             capacity_rows, trace_id, score_mode,
-            dispatch, k_bucket,
+            dispatch, k_bucket, chunks_folded, chunks_total,
         )
         rec.seq = next(self._seq)
         buf = self._buf

@@ -1810,7 +1810,9 @@ def topk_path(y, k: int, recall: float = 1.0) -> str:
     return "pallas" if fused else "xla"
 
 
-def topk_dot_batch(xs, y, *, k: int, recall: float = 1.0):
+def topk_dot_batch(
+    xs, y, *, k: int, recall: float = 1.0, counted: bool = False
+):
     """Batched top-k scoring; topk_path names the kernel selection.
     recall < 1 takes the approximate partial-reduce; exact requests take
     the fused streaming Pallas kernel on TPU (gen-2 bitonic-merge kernel,
@@ -1823,30 +1825,38 @@ def topk_dot_batch(xs, y, *, k: int, recall: float = 1.0):
     row shards, one device per shard) scores per shard — each shard
     re-entering this selection with its own dtype — and merges the
     partials with the cross-shard bitonic merge (ops/shard_topk.py),
-    selecting exactly the indices of the unsharded dispatch."""
+    selecting exactly the indices of the unsharded dispatch.
+
+    counted=True appends a third result: the fused kernel's int32[2]
+    device array (item chunks it folded, item chunks it walked — its
+    threshold gate, ops/pallas_topk.py), or None on every other path."""
     path = topk_path(y, k, recall)
+    if path in ("pallas", "pallas-int8"):
+        from oryx_tpu.ops.pallas_topk import topk_dot_batch_pallas
+
+        if path == "pallas-int8":
+            return topk_dot_batch_pallas(
+                xs, y.q, scales=y.scale, k=k, counted=counted
+            )
+        # mixed-precision queries score in the matrix's dtype (the bf16
+        # serving view); accumulation is f32 either way
+        return topk_dot_batch_pallas(
+            jnp.asarray(xs, dtype=y.dtype), y, k=k, counted=counted
+        )
     if path == "sharded":
         from oryx_tpu.ops.shard_topk import topk_dot_batch_sharded
 
-        return topk_dot_batch_sharded(xs, y, k=k, recall=recall)
-    if path == "chunked":
-        return topk_dot_batch_chunked(xs, y.chunks, k=k, recall=recall)
-    if path == "pallas-int8":
-        from oryx_tpu.ops.pallas_topk import topk_dot_batch_pallas
-
-        return topk_dot_batch_pallas(xs, y.q, scales=y.scale, k=k)
-    if path == "xla-int8":
-        return topk_dot_batch_quant_xla(
+        out = topk_dot_batch_sharded(xs, y, k=k, recall=recall)
+    elif path == "chunked":
+        out = topk_dot_batch_chunked(xs, y.chunks, k=k, recall=recall)
+    elif path == "xla-int8":
+        out = topk_dot_batch_quant_xla(
             xs, y.q, y.scale, k=k, recall=float(recall) if recall < 1.0 else 1.0
         )
-    if xs.dtype != y.dtype:
-        # mixed-precision queries score in the matrix's dtype (the bf16
-        # serving view); accumulation is f32 either way
+    else:
         xs = jnp.asarray(xs, dtype=y.dtype)
-    if path == "approx":
-        return topk_dot_batch_approx(xs, y, k=k, recall=float(recall))
-    if path == "pallas":
-        from oryx_tpu.ops.pallas_topk import topk_dot_batch_pallas
-
-        return topk_dot_batch_pallas(xs, y, k=k)
-    return topk_dot_batch_xla(xs, y, k=k)
+        if path == "approx":
+            out = topk_dot_batch_approx(xs, y, k=k, recall=float(recall))
+        else:
+            out = topk_dot_batch_xla(xs, y, k=k)
+    return (*out, None) if counted else out

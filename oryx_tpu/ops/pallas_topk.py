@@ -14,18 +14,17 @@ Second generation (PR 8), three changes over the first kernel:
 
 - Selection: the first kernel ran k sequential argmax+mask sweeps over a
   [Bb, k+Ib] candidate buffer — O(k·Ib) VPU work per block that capped
-  the fused path at k<=32. Now every 128-item chunk of a block is scored
-  and BITONIC-sorted ascending (28 compare-exchange stages), split
-  against the descending running top-128 and merged back (1 + 7
-  stages): 36 vectorized stages per chunk, independent of k, exact for
-  any k <= 128 — the comparisons order by (value desc, index asc), the
-  same total order as jax.lax.top_k, so duplicate scores tie-break
-  identically. The chunks of a block are a fori_loop, not an unrolled
-  merge tree: the unrolled form of PR 8 (a [Bb, block_i] score block,
-  sorted whole) took 377 s to compile under Mosaic at block_i=4096 —
-  longer than the batcher's cold-compile grace — against ~1 s for the
-  loop, and needed `rev` and lane-splitting reshapes that Mosaic does
-  not lower (PR 21).
+  the fused path at k<=32. Now a 128-item chunk is BITONIC-sorted
+  ascending (28 compare-exchange stages), split against the descending
+  running top-128 and merged back (1 + 7 stages): 36 vectorized stages,
+  independent of k, exact for any k <= 128 — the comparisons order by
+  (value desc, index asc), the same total order as jax.lax.top_k, so
+  duplicate scores tie-break identically. The chunks of a block are a
+  fori_loop, not an unrolled merge tree: the unrolled form of PR 8 (a
+  [Bb, block_i] score block, sorted whole) took 377 s to compile under
+  Mosaic at block_i=4096 — longer than the batcher's cold-compile grace
+  — against ~1 s for the loop, and needed `rev` and lane-splitting
+  reshapes that Mosaic does not lower (PR 21).
 - Streaming: the item matrix stays in HBM (`memory_space=ANY`) and the
   kernel issues its own double-buffered `pltpu.make_async_copy` DMAs
   into a 2-slot VMEM scratch, starting block i+1's copy before computing
@@ -36,11 +35,54 @@ Second generation (PR 8), three changes over the first kernel:
   and locks the winner into the same table (bench uses it; serving
   inherits whatever the table holds at dispatch time).
 
-Measured on one v5e chip (PR 21, 512 x 1.31M x 50f bf16): 236 ms at every
-k, against XLA's 41 ms (k=16), 72 ms (k=32) and 257 ms (k=128); at 4096
-rows 1876 ms, where XLA's 21 GB score matrix does not fit the chip at
-all. Time is linear in query rows and flat in features: the kernel is
-bound by the sort network, not by HBM or the MXU (ROADMAP S2).
+The threshold gate (PR 26). Until PR 26 EVERY chunk paid the 36 stages,
+so the kernel was bound by the sort network, flat in k and features and
+linear in query rows: 1,116 ms for a 512-row dispatch over a 6,291,456
+x 256 bf16 view, whatever it held. But a chunk can change a row block's
+result only if one of its scores is above that row's running k-th score
+(`thr`: lane k - 1 of the descending running list). Strict `>` is the
+kernel's own order: items stream in index order, so a chunk's indices
+are above every index already held and an equal score loses its tie. An
+item enters a row's top-k of n seen with probability k / n, so over a
+large catalog almost no chunk qualifies; the zero rows the batcher pads
+a dispatch with score 0.0 everywhere and qualify once. So the kernel
+scores `_GATE_CHUNKS` chunks with one dot, tests the elementwise max of
+their scores against `thr` (one compare, one reduce to a scalar, one
+branch), and only inside a group that fires tests and folds its chunks
+one by one. A skipped chunk holds nothing that precedes any row's k-th
+element, so the output is bit for bit what folding every chunk gives;
+lanes past k of the running list go stale and are never returned. The
+kernel counts the chunks it folds (one int32 per row block); the
+wrapper returns (folded, walked) beside the results when asked
+(`counted=True`), and the batcher puts them on the DispatchRecord and
+into `oryx_topk_chunks_folded` / `oryx_topk_chunks`.
+
+Measured on one v5e chip (PR 26; 512-row dispatch, k = 128, over a
+6,291,456 x 256 bf16 view holding 5,000,000 rows of standard-normal
+factors; the ungated kernel took 1,116 ms in every case, and every
+result below is bit-identical to its):
+
+    real rows of the 512     time       chunks folded of 196,608
+    1                         23.5 ms        788
+    16                        62.0 ms      6,791
+    112                      159.2 ms     22,801
+    512                      619.4 ms     97,391
+    112, ascending *         255.8 ms     39,066
+    512, ascending *         967.9 ms    156,252
+
+    * the worst case: the items stored in ascending order of score for
+      every row asked about, so that every chunk of real items folds. A
+      chunk that folds costs 6.2 us against 5.67 us before the gate; the
+      ungated kernel's time is not reached here only because the view's
+      capacity-pad rows (score 0) still skip.
+
+A gated group of 8 chunks costs 0.78 us (one gate per chunk: 0.33 us a
+chunk, 69 ms for the one-row dispatch; one gate per 16 or 32 chunks is
+no faster than per 8), so the floor of a 512-row dispatch over this view
+is 19 ms against 3.05 ms of HBM time, and 3/4 of it is the three
+all-padding row blocks of the 512-row bucket. PR 21's comparison (XLA
+matmul + top_k at 512 x 1.31M x 50f: 41 / 72 / 257 ms at k 16 / 32 /
+128, the ungated kernel 236 ms) has not been repeated with the gate.
 
 The kernel also scores QUANTIZED item matrices (int8 rows + per-row f32
 scales, ops/transfer.py QuantizedMatrix): the int8 stream halves the
@@ -69,6 +111,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128  # TPU lane tile; also the padded top-k slot width
+
+# 128-item chunks scored by one dot and tested by one gate (pow2). The
+# gate's cost is the reduce to a scalar and the branch, which the chunks
+# of a group share; a group that fires tests its chunks one by one.
+_GATE_CHUNKS = 8
 
 # Scoped-VMEM working-set budget the block table sizes against (v5e
 # exposes ~16 MB; leave headroom for the compiler's own temporaries).
@@ -158,14 +205,14 @@ def _split_top(av, ai, bv, bi):
 # ---------------------------------------------------------------------------
 
 def _topk_kernel(
-    *refs, block_i, n_items, quantized,
+    *refs, block_i, n_items, k, quantized,
 ):
     if quantized:
-        (xs_ref, y_hbm, scale_ref, vals_ref, idx_ref,
-         run_vals, run_idx, y_buf, sem) = refs
+        (xs_ref, y_hbm, scale_ref, vals_ref, idx_ref, folds_ref,
+         run_vals, run_idx, thr, group_scores, y_buf, sem, folded) = refs
     else:
-        (xs_ref, y_hbm, vals_ref, idx_ref,
-         run_vals, run_idx, y_buf, sem) = refs
+        (xs_ref, y_hbm, vals_ref, idx_ref, folds_ref,
+         run_vals, run_idx, thr, group_scores, y_buf, sem, folded) = refs
         scale_ref = None
     i = pl.program_id(1)
     ni = pl.num_programs(1)
@@ -181,6 +228,8 @@ def _topk_kernel(
         dma(0, 0).start()
         run_vals[:] = jnp.full_like(run_vals, -jnp.inf)
         run_idx[:] = jnp.zeros_like(run_idx)
+        thr[:] = jnp.full_like(thr, -jnp.inf)
+        folded[0] = 0
 
     # prefetch block i+1 while block i computes: the double buffer
     @pl.when(i + 1 < ni)
@@ -190,19 +239,51 @@ def _topk_kernel(
     dma(slot, i).wait()
 
     xs = xs_ref[:]
+    n_gate = group_scores.shape[1] // _LANE  # chunks behind one gate
     lane = jax.lax.broadcasted_iota(jnp.int32, (xs.shape[0], _LANE), 1)
-    # [Bb, K] x [128, K]^T on the MXU, contracting the feature axis of
-    # both (no materialized transpose)
+    lane_g = jax.lax.broadcasted_iota(jnp.int32, group_scores.shape, 1)
+    # [Bb, K] x [n_gate * 128, K]^T on the MXU, contracting the feature
+    # axis of both (no materialized transpose)
     contract = (((1,), (1,)), ((), ()))
 
-    def fold_chunk(c, run):
-        """Score one 128-item chunk of the block, sort it ascending (28
-        stages) and fold it into the descending running top-128 (1 + 7
-        stages). A loop, not an unrolled tree: the program stays one
-        chunk long whatever block_i is, and the [Bb, block_i] score
-        block never exists."""
-        off = pl.multiple_of(c * _LANE, _LANE)
-        y_c = y_buf[slot, pl.ds(off, _LANE), :]
+    def beats_kth(scores):
+        """Whether any score is above its row's running k-th. Strict `>`
+        is the kernel's own order: items stream in index order, so every
+        index still to come is above every index in the running list and
+        an equal score loses its tie."""
+        return jnp.max(jnp.where(scores > thr[:], 1.0, 0.0)) > 0.0
+
+    def fold_chunk(scores, col):
+        """Sort one chunk's scores ascending (28 stages) and fold them
+        into the descending running top-128 (1 + 7 stages)."""
+        cv, ci = _bitonic_sort(scores, col, descending=False)
+        nv, nidx = _bitonic_merge(
+            *_split_top(run_vals[:], run_idx[:], cv, ci), descending=True
+        )
+        run_vals[:] = nv
+        run_idx[:] = nidx
+        # every row's new k-th value, across all lanes
+        thr[:] = jnp.broadcast_to(
+            jnp.max(
+                jnp.where(lane == k - 1, nv, -jnp.inf), axis=1, keepdims=True
+            ),
+            nv.shape,
+        )
+        folded[0] = folded[0] + 1
+
+    def gate_group(g, carry):
+        """Score n_gate 128-item chunks of the block with one dot and
+        fold, in order, only those holding a score above some row's
+        running k-th (`thr`). A chunk that does not holds nothing that
+        precedes any row's k-th element, so folding it would leave lanes
+        [:k] of the running list as they are: it costs a compare. Lanes
+        past k go stale, and stay at or below the k-th, so a later merge
+        still has the exact top-k in its first k lanes. Loops, not
+        unrolled trees: the program stays one fold long whatever block_i
+        is, and the [Bb, block_i] score block never exists."""
+        c0 = g * n_gate
+        off = pl.multiple_of(c0 * _LANE, n_gate * _LANE)
+        y_g = y_buf[slot, pl.ds(off, n_gate * _LANE), :]
         if scale_ref is not None:
             # TRUE int8 path: queries arrive pre-quantized (wrapper,
             # per-row scales), so the dot runs int8 x int8 -> int32 on
@@ -212,25 +293,47 @@ def _topk_kernel(
             # scaling a row by a positive constant never changes that
             # row's top-k order, so the wrapper applies them to the
             # returned values after the kernel.
+            scale_g = jnp.concatenate(
+                [scale_ref[pl.ds(c0 + j, 1), :] for j in range(n_gate)], axis=1
+            )
             scores = jax.lax.dot_general(
-                xs, y_c, contract, preferred_element_type=jnp.int32
-            ).astype(jnp.float32) * scale_ref[pl.ds(c, 1), :]
+                xs, y_g, contract, preferred_element_type=jnp.int32
+            ).astype(jnp.float32) * scale_g
         else:
             scores = jax.lax.dot_general(
-                xs, y_c, contract, preferred_element_type=jnp.float32
+                xs, y_g, contract, preferred_element_type=jnp.float32
             )
-        col = i * block_i + off + lane
-        scores = jnp.where(col < n_items, scores, -jnp.inf)  # tail padding
-        cv, ci = _bitonic_sort(scores, col, descending=False)
-        return _bitonic_merge(*_split_top(*run, cv, ci), descending=True)
+        col0 = i * block_i + off
+        scores = jnp.where(col0 + lane_g < n_items, scores, -jnp.inf)  # tail padding
+        # the gate is mostly a reduce to a scalar and a branch: one for
+        # the group, on the elementwise max of its chunks' scores, and
+        # one per chunk only inside a group that fired
+        best = scores[:, :_LANE]
+        for j in range(1, n_gate):
+            best = jnp.maximum(best, scores[:, j * _LANE:(j + 1) * _LANE])
 
-    nv, nidx = jax.lax.fori_loop(
-        0, block_i // _LANE, fold_chunk, (run_vals[:], run_idx[:])
-    )
-    run_vals[:] = nv
-    run_idx[:] = nidx
-    vals_ref[:] = nv
-    idx_ref[:] = nidx
+        @pl.when(beats_kth(best))
+        def _walk():
+            group_scores[:] = scores
+
+            def gate_chunk(j, carry):
+                at = pl.multiple_of(j * _LANE, _LANE)
+                s_j = group_scores[:, pl.ds(at, _LANE)]
+
+                @pl.when(beats_kth(s_j))
+                def _fold():
+                    fold_chunk(s_j, col0 + at + lane)
+
+                return carry
+
+            jax.lax.fori_loop(0, n_gate, gate_chunk, 0)
+
+        return carry
+
+    jax.lax.fori_loop(0, block_i // (n_gate * _LANE), gate_group, 0)
+    vals_ref[:] = run_vals[:]
+    idx_ref[:] = run_idx[:]
+    folds_ref[:] = jnp.full(folds_ref.shape, folded[0], jnp.int32)
 
 
 def _pad_to(x, size, axis, value=0.0):
@@ -268,15 +371,19 @@ def _working_set_bytes(
     block_b: int, block_i: int, feat_pad: int, y_itemsize: int
 ) -> int:
     """Scoped-VMEM estimate for one grid step: the 2-slot Y stream
-    buffer, the (pipelined, so doubled) query, scale and output blocks,
-    the running top-k, and one chunk's sort network temporaries. The
-    score block is one 128-item chunk at a time, so block_i enters only
-    through the stream buffer and the scales."""
+    buffer, the (pipelined, so doubled) query, scale and output blocks
+    (the fold count's (8, 128) tile among them), the running top-k with
+    the gate's threshold block and one group's scores beside it, and one
+    chunk's sort network temporaries. The score block is one group of
+    128-item chunks at a time, so block_i enters only through the stream
+    buffer and the scales."""
     return (
         2 * block_i * feat_pad * y_itemsize
         + 2 * block_b * feat_pad * 4
         + 2 * block_i * 4
         + 6 * block_b * _LANE * 8
+        + block_b * (1 + _GATE_CHUNKS) * _LANE * 4
+        + 2 * 8 * _LANE * 4
         + 8 * block_b * _LANE * 4
     )
 
@@ -392,8 +499,10 @@ def _topk_pallas_jit(
     nb = xs_p.shape[0] // block_b
     ni = y_p.shape[0] // block_i
 
+    n_gate = min(_GATE_CHUNKS, block_i // _LANE)
     kernel = partial(
-        _topk_kernel, block_i=block_i, n_items=n_items, quantized=quantized
+        _topk_kernel, block_i=block_i, n_items=n_items, k=k,
+        quantized=quantized,
     )
     in_specs = [
         pl.BlockSpec((block_b, feat_pad), lambda b, i: (b, 0)),
@@ -412,23 +521,29 @@ def _topk_pallas_jit(
             pl.BlockSpec((block_i // _LANE, _LANE), lambda b, i: (i, 0))
         )
         operands.append(scale_p)
-    vals, idx = pl.pallas_call(
+    vals, idx, folds = pl.pallas_call(
         kernel,
         grid=(nb, ni),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((block_b, _LANE), lambda b, i: (b, 0)),
             pl.BlockSpec((block_b, _LANE), lambda b, i: (b, 0)),
+            # one fold count per row block, spread over an (8, 128) tile
+            pl.BlockSpec((8, _LANE), lambda b, i: (b, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((xs_p.shape[0], _LANE), jnp.float32),
             jax.ShapeDtypeStruct((xs_p.shape[0], _LANE), jnp.int32),
+            jax.ShapeDtypeStruct((nb * 8, _LANE), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_b, _LANE), jnp.float32),
             pltpu.VMEM((block_b, _LANE), jnp.int32),
+            pltpu.VMEM((block_b, _LANE), jnp.float32),
+            pltpu.VMEM((block_b, n_gate * _LANE), jnp.float32),
             pltpu.VMEM((2, block_i, feat_pad), y_p.dtype),
             pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
         interpret=interpret,
     )(*operands)
@@ -437,7 +552,10 @@ def _topk_pallas_jit(
         # scale the selected values back into score units (sx > 0, so
         # -inf padding slots stay -inf)
         vals = vals * sx[:n_b, None]
-    return vals, idx
+    chunks = jnp.stack(
+        [jnp.sum(folds[::8, 0]), jnp.int32(nb * ni * (block_i // _LANE))]
+    )
+    return vals, idx, chunks
 
 
 def topk_dot_batch_pallas(
@@ -449,6 +567,7 @@ def topk_dot_batch_pallas(
     block_b: int | None = None,
     block_i: int | None = None,
     interpret: bool = False,
+    counted: bool = False,
 ):
     """Top-k of xs @ y.T per row without materializing the score matrix.
 
@@ -459,6 +578,17 @@ def topk_dot_batch_pallas(
     scales for an int8 y (ops/transfer.py QuantizedMatrix) — scores become
     (xs @ y.T) * scale. interpret=True runs the kernel in the Pallas
     interpreter (CPU tests).
+
+    The kernel sorts and merges a 128-item chunk only if one of its
+    scores is above some row's running k-th score (the module docstring
+    says why that is exact); every other chunk costs its share of one
+    compare. Its time therefore follows the input: a few real rows over
+    a large catalog fold a few percent of the chunks, every chunk folds
+    only where the items are stored in ascending order of score for the
+    rows asked about, and a chunk that folds costs about a tenth more
+    than before the gate. counted=True appends an int32[2] array, (chunks
+    folded, chunks walked = row blocks x item chunks), so a caller can
+    see which case it is in.
 
     block_b/block_i default to the tuned table (`tuned_blocks`): the
     largest pow2 item block whose double-buffered stream + sort
@@ -484,8 +614,9 @@ def topk_dot_batch_pallas(
     # silently larger block — and never past the next pow2 of the real
     # row count (no point padding the item axis beyond it)
     block_i = max(_LANE, min(_pow2_floor(block_i), _pow2_ceil(n_items)))
-    return _topk_pallas_jit(
+    vals, idx, chunks = _topk_pallas_jit(
         xs, y, scales,
         k=k, block_b=block_b, block_i=block_i,
         quantized=scales is not None, interpret=interpret,
     )
+    return (vals, idx, chunks) if counted else (vals, idx)

@@ -369,6 +369,11 @@ class TopKBatcher:
         # (a superseded dispatcher racing its replacement) lose updates.
         self.dispatches = 0  # guarded-by: _lock (writes)
         self.coalesced = 0  # guarded-by: _lock (writes)
+        # item chunks the fused top-k kernel folded / walked, summed over
+        # its dispatches: their ratio is how often its threshold gate let
+        # a chunk through to the sort network (ops/pallas_topk.py)
+        self.chunks_folded = 0  # guarded-by: _lock (writes)
+        self.chunks_total = 0  # guarded-by: _lock (writes)
         self.host_fallbacks = 0  # guarded-by: _lock (writes)
         self.device_failovers = 0  # guarded-by: _lock (writes)
         # analytic FLOPs dispatched to the device (2·B·I·F per group,
@@ -396,6 +401,14 @@ class TopKBatcher:
             ("oryx_topk_coalesced",
              "requests coalesced into device dispatches",
              lambda: float(self.coalesced)),
+            ("oryx_topk_chunks_folded",
+             "128-item chunks the fused top-k kernel sorted and merged: "
+             "those holding a score above a row block's running k-th",
+             lambda: float(self.chunks_folded)),
+            ("oryx_topk_chunks",
+             "128-item chunks the fused top-k kernel walked (row blocks x "
+             "item chunks of its dispatches)",
+             lambda: float(self.chunks_total)),
             ("oryx_topk_mean_batch",
              "achieved mean coalesced batch size (coalesced/dispatches "
              "over the process lifetime; >1 means requests are sharing "
@@ -629,7 +642,7 @@ class TopKBatcher:
         # synchronous device round trip per group; with the copy already
         # in flight, the fetch of batch N overlaps the scan of batch N+1.
         me = threading.current_thread()
-        inflight: list[tuple[list[_Pending], int, object, object, tuple, tuple]] = []
+        inflight: list[tuple[list[_Pending], int, object, object, object, tuple, tuple]] = []
         while True:
             with self._cond:
                 while not self._queue and not self._closed and not inflight:
@@ -676,7 +689,7 @@ class TopKBatcher:
 
     def _launch(
         self, batch: list[_Pending]
-    ) -> list[tuple[list[_Pending], int, object, object, tuple, tuple]]:
+    ) -> list[tuple[list[_Pending], int, object, object, object, tuple, tuple]]:
         """Issue one device dispatch per (matrix, k-bucket) group and start
         the async result copies; returns the in-flight group handles."""
         import jax.numpy as jnp
@@ -811,12 +824,17 @@ class TopKBatcher:
                                 self._gap_mark = t_disp
                             for cause, s in causes.items():
                                 _PA.record_idle_gap(cause, s)
-                        vals, idx = topk_dot_batch(
-                            jnp.asarray(xs), y, k=kb, recall=recall
+                        # chunks: the fused kernel's (folded, walked)
+                        # item-chunk counts, None on every other path
+                        vals, idx, chunks = topk_dot_batch(
+                            jnp.asarray(xs), y, k=kb, recall=recall,
+                            counted=True,
                         )
                         try:
                             vals.copy_to_host_async()
                             idx.copy_to_host_async()
+                            if chunks is not None:
+                                chunks.copy_to_host_async()
                         except AttributeError:  # non-jax array (test stubs)
                             pass
                         t_issued = time.monotonic()
@@ -857,7 +875,9 @@ class TopKBatcher:
                         group[0].score_mode,
                         t_disp, n_disp,
                     )
-                    launched.append((group, kb, vals, idx, shape_key, cost))
+                    launched.append(
+                        (group, kb, vals, idx, chunks, shape_key, cost)
+                    )
             except Exception as e:
                 log.exception("batcher group dispatch failed (k=%d)", kb)
                 # no compile is in flight anymore: drop the grace entry,
@@ -894,15 +914,18 @@ class TopKBatcher:
             _PERF.note_fallback(n)
 
     def _resolve(
-        self, item: tuple[list[_Pending], int, object, object, tuple, tuple]
+        self, item: tuple[list[_Pending], int, object, object, object, tuple, tuple]
     ) -> None:
-        group, kb, vals_dev, idx_dev, shape_key, cost = item
+        group, kb, vals_dev, idx_dev, chunks_dev, shape_key, cost = item
         (t0, flops, bytes_moved, b, padded, valid, cap, trace_id,
          mode, t_disp, n_disp) = cost
         try:
             with _TRACER.region("batcher.fetch", dispatch=n_disp):
                 vals = np.asarray(vals_dev)
                 idx = np.asarray(idx_dev)
+                folded = total = None
+                if chunks_dev is not None:
+                    folded, total = (int(c) for c in np.asarray(chunks_dev))
                 t_fetch = time.monotonic()
             with _TRACER.region("batcher.distribute", dispatch=n_disp):
                 # results are on the host: the dispatch's device work +
@@ -915,6 +938,7 @@ class TopKBatcher:
                     valid_rows=valid, capacity_rows=cap, trace_id=trace_id,
                     t_start=t0, score_mode=mode,
                     dispatch=n_disp, k_bucket=kb,
+                    chunks_folded=folded, chunks_total=total,
                 )
                 # the dispatch completed, so this shape's compile is done:
                 # drop its grace window and never grant it one again. Both
@@ -951,6 +975,9 @@ class TopKBatcher:
                     # rest of the group
                     try_set_result(p.future, (vals[i, :k_eff], idx[i, :k_eff]))
                 with self._lock:
+                    if total is not None:
+                        self.chunks_folded += folded
+                        self.chunks_total += total
                     # result-distribution tail: host work the device idles
                     # behind (the host_serialize slice of the next gap)
                     self._gap_resolve += time.monotonic() - t_fetch

@@ -126,6 +126,58 @@ def test_a_record_of_another_kind_has_no_number():
     assert "dispatch" not in rec.chrome_event(1)["args"]
 
 
+def _gauge(name):
+    for line in get_registry().render_prometheus().splitlines():
+        if line.startswith(name + " "):
+            return float(line.split()[1])
+    return None
+
+
+def test_fused_dispatch_carries_the_kernels_fold_count(y, monkeypatch):
+    """The kernel's own count of the chunks it folded rides with the
+    results to the DispatchRecord and the two /metrics counters (ISSUE 26);
+    a dispatch on any other path reports none. The stub is the fused path
+    itself, run by the Pallas interpreter as `topk_path` would on a TPU."""
+    from oryx_tpu.ops import als
+    from oryx_tpu.ops.pallas_topk import topk_dot_batch_pallas
+
+    batcher = TopKBatcher.shared()
+    batcher.register_gauges()  # as the serving layer does at start-up
+    t_mark = time.monotonic()
+    before = (batcher.chunks_folded, batcher.chunks_total)
+    _burst(batcher, y, [10, 12], rounds=1)  # the XLA path, as on any CPU
+    records = [r for r in get_perfstats().records_since(t_mark) if r.kind == "serving"]
+    assert records and all(r.chunks_folded is None and r.chunks_total is None for r in records)
+    assert "chunks_total" not in records[0].chrome_event(1)["args"]
+    assert (batcher.chunks_folded, batcher.chunks_total) == before
+    assert _gauge("oryx_topk_chunks") == float(before[1])
+
+    def fused(xs, y, *, k, recall=1.0, counted=False):
+        return topk_dot_batch_pallas(
+            xs, y, k=k, block_b=8, block_i=128, interpret=True, counted=counted
+        )
+
+    monkeypatch.setattr(als, "topk_dot_batch", fused)
+    t_mark = time.monotonic()
+    _burst(batcher, y, [10, 12, 9], rounds=2)
+    records = [r for r in get_perfstats().records_since(t_mark) if r.kind == "serving"]
+    assert records
+    for r in records:
+        # 200 items in blocks of 128: two chunks a row block, the first
+        # always folded (nothing beats -inf before it)
+        row_blocks = -(-r.padded_rows // 8)
+        assert r.chunks_total == 2 * row_blocks
+        assert row_blocks <= r.chunks_folded <= r.chunks_total
+        args = r.chrome_event(1)["args"]
+        assert (args["chunks_folded"], args["chunks_total"]) == (r.chunks_folded, r.chunks_total)
+    moved = (batcher.chunks_folded - before[0], batcher.chunks_total - before[1])
+    assert moved == (
+        sum(r.chunks_folded for r in records), sum(r.chunks_total for r in records)
+    )
+    assert _gauge("oryx_topk_chunks_folded") == float(batcher.chunks_folded)
+    assert _gauge("oryx_topk_chunks") == float(batcher.chunks_total)
+
+
 @pytest.fixture
 def tracing_on():
     tr = get_tracer()

@@ -241,3 +241,142 @@ def test_kernel_lowers_for_tpu_at_serving_shapes(quantized):
                 )
             exported = export.export(jax.jit(fn), platforms=["tpu"])(*args)
             assert "tpu_custom_call" in exported.mlir_module()
+
+
+# -- the threshold gate (ISSUE 26) ---------------------------------------------
+#
+# Integer-valued factors: every dot product is exact in float32 whatever the
+# order of the sum, so the kernel's values are compared bit for bit and the
+# scores are full of exact ties.
+
+def _int_factors(rng, n, feats, lo=-9, hi=9):
+    return rng.integers(lo, hi + 1, size=(n, feats)).astype(np.float32)
+
+
+def _along_a_line(scale_of_item, rows=5):
+    """One feature: row r (a positive multiple) scores item i at
+    r * scale_of_item[i], so every row sees the items in the same order."""
+    y = np.asarray(scale_of_item, dtype=np.float32)[:, None]
+    xs = np.arange(1, rows + 1, dtype=np.float32)[:, None]
+    return xs, y
+
+
+def _gate_case(name):
+    """(xs, y, k, block_i) of one exactness case."""
+    rng = np.random.default_rng(GATE_CASES.index(name))
+    n = 1500  # 12 chunks of 128 over 3 blocks of 512, the last chunk short
+    if name == "ascending":  # every chunk holds a new best: every chunk fires
+        return (*_along_a_line(np.arange(n)), 16, 512)
+    if name == "descending":  # nothing after the first chunk can enter
+        return (*_along_a_line(-np.arange(n)), 16, 512)
+    if name == "all-equal":  # strict >: nothing ever beats an equal score
+        return (*_along_a_line(np.full(n, 3.0)), 100, 512)
+    if name == "tie-runs-ascending":
+        # runs of 100 equal scores straddle the chunk (128) and block (512)
+        # edges; within a run the lowest index wins
+        return (*_along_a_line(np.arange(n) // 100), 128, 512)
+    if name == "tie-runs-descending":
+        return (*_along_a_line(-(np.arange(n) // 100)), 128, 512)
+    if name == "zero-rows-after-real":  # as the batcher pads a dispatch
+        xs = _int_factors(rng, 24, 12)
+        xs[5:] = 0.0
+        return xs, _int_factors(rng, n, 12), 32, 256
+    if name == "short-tail":  # n_items off the block: the -inf tail never fires
+        return _int_factors(rng, 9, 12), _int_factors(rng, 1111, 12), 32, 512
+    if name.startswith("k="):  # lane k - 1 is the threshold; lanes past it go stale
+        return (
+            _int_factors(rng, 11, 20, -30, 30), _int_factors(rng, 2500, 20, -30, 30),
+            int(name[2:]), 256,
+        )
+    raise AssertionError(name)
+
+
+GATE_CASES = [
+    "ascending", "descending", "all-equal", "tie-runs-ascending",
+    "tie-runs-descending", "zero-rows-after-real", "short-tail",
+    "k=1", "k=10", "k=16", "k=32", "k=100", "k=128",
+]
+
+
+def _model_folds(scores, k, block_b):
+    """What the kernel's gate should count, walked in numpy: per row
+    block, the chunks holding a score above some row's running k-th."""
+    rows, n = scores.shape
+    pad = -(-rows // block_b) * block_b - rows
+    scores = np.concatenate([scores, np.zeros((pad, n), scores.dtype)])  # zero rows
+    folds = 0
+    for b in range(0, scores.shape[0], block_b):
+        blk = scores[b:b + block_b]
+        top = np.full((block_b, k), -np.inf, dtype=np.float32)  # descending
+        for c in range(0, n, 128):
+            chunk = blk[:, c:c + 128]
+            if (chunk > top[:, -1:]).any():
+                folds += 1
+                top = -np.sort(-np.concatenate([top, chunk], axis=1), axis=1)[:, :k]
+    return folds
+
+
+@pytest.mark.parametrize("name", GATE_CASES)
+def test_gated_kernel_is_bit_identical_to_top_k_of_the_plain_scores(name):
+    xs, y, k, block_i = _gate_case(name)
+    scores = xs @ y.T  # exact: integers far below 2**24
+    v_ref, i_ref = jax.lax.top_k(jnp.asarray(scores), k)
+    v, i, chunks = topk_dot_batch_pallas(
+        jnp.asarray(xs), jnp.asarray(y), k=k, block_b=8, block_i=block_i,
+        interpret=True, counted=True,
+    )
+    assert np.array_equal(np.asarray(i), np.asarray(i_ref))
+    assert np.array_equal(np.asarray(v), np.asarray(v_ref))
+    # the kernel's own count of the chunks it folded, against the model's
+    folded, total = (int(c) for c in np.asarray(chunks))
+    row_blocks = -(-xs.shape[0] // 8)
+    item_chunks = -(-y.shape[0] // block_i) * (block_i // 128)
+    assert total == row_blocks * item_chunks
+    assert folded == _model_folds(scores, k, 8) <= total
+    real_chunks = -(-y.shape[0] // 128)
+    if name == "ascending":
+        assert folded == row_blocks * real_chunks  # the worst case: all of them
+    if name in ("descending", "all-equal"):
+        assert folded == row_blocks * -(-k // 128)  # the first chunk alone
+
+
+def test_gated_kernel_with_fewer_items_than_k_keeps_neg_inf_slots():
+    rng = np.random.default_rng(8)
+    xs, y = _int_factors(rng, 4, 6), _int_factors(rng, 40, 6)
+    v, i, chunks = topk_dot_batch_pallas(
+        jnp.asarray(xs), jnp.asarray(y), k=100, block_b=8, block_i=128,
+        interpret=True, counted=True,
+    )
+    scores = xs @ y.T
+    order = np.argsort(-scores, axis=1, kind="stable")
+    assert np.array_equal(np.asarray(i)[:, :40], order)
+    assert np.array_equal(np.asarray(v)[:, :40], np.take_along_axis(scores, order, axis=1))
+    assert np.all(np.isneginf(np.asarray(v)[:, 40:]))
+    assert [int(c) for c in np.asarray(chunks)] == [1, 1]
+
+
+@pytest.mark.parametrize("k", [10, 128])
+def test_gated_int8_kernel_is_bit_identical_to_top_k_of_its_scores(k):
+    # integer item rows quantize to themselves times one scale per row, so
+    # the int8 kernel's scores (int32 dot x item scale) are exact too; the
+    # per-row scales reorder items across rows and enter before the gate
+    from oryx_tpu.ops.pallas_topk import quantize_queries
+
+    rng = np.random.default_rng(40 + k)
+    q = rng.integers(-127, 128, size=(1700, 16)).astype(np.int8)
+    scale = rng.choice([0.25, 0.5, 1.0, 2.0], size=1700).astype(np.float32)
+    xs = _int_factors(rng, 10, 16, -127, 127)
+    xs[:, 0] = 127.0  # every row's scale is exactly 1: quantized to itself
+    xs[6:] = 0.0
+    qx, sx = quantize_queries(jnp.asarray(xs))
+    assert np.array_equal(np.asarray(qx), xs) and np.all(np.asarray(sx) == 1.0)
+    scores = (xs.astype(np.int64) @ q.T.astype(np.int64)).astype(np.float32) * scale
+    v_ref, i_ref = jax.lax.top_k(jnp.asarray(scores), k)
+    v, i, chunks = topk_dot_batch_pallas(
+        jnp.asarray(xs), jnp.asarray(q), scales=jnp.asarray(scale), k=k,
+        block_b=8, block_i=1024, interpret=True, counted=True,
+    )
+    assert np.array_equal(np.asarray(i), np.asarray(i_ref))
+    assert np.array_equal(np.asarray(v), np.asarray(v_ref))
+    folded, total = (int(c) for c in np.asarray(chunks))
+    assert folded == _model_folds(scores, k, 8) < total

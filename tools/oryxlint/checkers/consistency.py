@@ -91,6 +91,11 @@ REQUIRED_PERFATTR_FAMILIES = (
     # the parts of `serialize` on the deferred top-n path (ISSUE 25); the
     # benchmark's post_*_ms_per_req readers key on it
     "oryx_post_stage_seconds",
+    # how often the fused top-k kernel's threshold gate lets a chunk
+    # through to its sort network (ISSUE 26): a benchmark share of the
+    # two is the next per-layer metric of the top-k kernel
+    "oryx_topk_chunks_folded",
+    "oryx_topk_chunks",
 )
 
 
