@@ -14,6 +14,7 @@ computes the top-k scan's operations and bytes.
 
 from __future__ import annotations
 
+import gc
 import json
 import math
 import os
@@ -28,7 +29,7 @@ from pathlib import Path
 
 import numpy as np
 
-from benchmarks import latency, loadgen, xplane
+from benchmarks import latency, loadgen, timeline, xplane
 
 # Agreement of the HTTP top-N with the float32 reference, per sampled user
 # (chip_smoke.py's written tolerance). Serving scans candidates in bf16 and
@@ -73,9 +74,13 @@ def reference_scores(xs: np.ndarray, y_host: np.ndarray) -> np.ndarray:
     return out
 
 
-def agree(answer: list, scores: np.ndarray, known: np.ndarray, how_many: int) -> str | None:
-    """None when one user's served [[item, score], ...] agrees with the
-    reference scores of that user; else what differs."""
+def compare(answer: list, scores: np.ndarray, known: np.ndarray, how_many: int) -> dict:
+    """One user's served [[item, score], ...] against the reference scores of
+    that user: {"fault": what is wrong with its form or None, "score_rel":
+    the worst served score's distance from the reference's, "overlap": how
+    many of the reference's items were served, "gap_over_slack": how far the
+    worst served item lies under the reference's last, in slacks}. The
+    numbers are None where the form is wrong."""
     s = scores.copy()
     s[known] = -np.inf
     ref_top = np.argsort(-s, kind="stable")[:how_many]
@@ -83,22 +88,43 @@ def agree(answer: list, scores: np.ndarray, known: np.ndarray, how_many: int) ->
     slack = BF16_SLACK * float(np.max(np.abs(scores)))
     rows = [int(item[1:]) for item, _ in answer]
     got = np.asarray([score for _, score in answer], dtype=np.float64)
+    out = {"fault": None, "score_rel": None, "overlap": None, "gap_over_slack": None}
     if len(rows) != how_many:
-        return f"{len(rows)} items served, not {how_many}"
-    if set(rows) & set(known.tolist()):
-        return "a known item was served"
-    if np.any(np.diff(got) > 0):
-        return "scores not descending"
-    rel = np.max(np.abs(got - scores[rows]) / np.maximum(np.abs(scores[rows]), 1e-6))
-    if rel > SCORE_RTOL:
-        return f"scores differ from the f32 reference by rel {rel:.2e}"
-    overlap = len(set(rows) & set(ref_top.tolist()))
-    if overlap < math.ceil(MIN_OVERLAP_SHARE * how_many):
-        return f"only {overlap}/{how_many} of the reference's items served"
-    for r in rows:
-        if r not in ref_top and s[r] < last - slack:
-            return f"item row {r} scores {s[r]:.5f}, reference's last {last:.5f} (slack {slack:.5f})"
+        out["fault"] = f"{len(rows)} items served, not {how_many}"
+    elif set(rows) & set(known.tolist()):
+        out["fault"] = "a known item was served"
+    elif np.any(np.diff(got) > 0):
+        out["fault"] = "scores not descending"
+    else:
+        out["score_rel"] = float(
+            np.max(np.abs(got - scores[rows]) / np.maximum(np.abs(scores[rows]), 1e-6))
+        )
+        out["overlap"] = len(set(rows) & set(ref_top.tolist()))
+        out["gap_over_slack"] = float(max(0.0, last - min(s[r] for r in rows)) / slack)
+    return out
+
+
+def verdict(c: dict, how_many: int) -> str | None:
+    """None when the numbers of one compare() are within the tolerances;
+    else what differs."""
+    if c["fault"]:
+        return c["fault"]
+    if c["score_rel"] > SCORE_RTOL:
+        return f"scores differ from the f32 reference by rel {c['score_rel']:.2e}"
+    if c["overlap"] < math.ceil(MIN_OVERLAP_SHARE * how_many):
+        return f"only {c['overlap']}/{how_many} of the reference's items served"
+    if c["gap_over_slack"] > 1.0:
+        return (
+            f"an item served scores {c['gap_over_slack']:.2f} slacks under the "
+            f"reference's last (slack {BF16_SLACK:g} x max|score|)"
+        )
     return None
+
+
+def agree(answer: list, scores: np.ndarray, known: np.ndarray, how_many: int) -> str | None:
+    """None when one user's served [[item, score], ...] agrees with the
+    reference scores of that user; else what differs."""
+    return verdict(compare(answer, scores, known, how_many), how_many)
 
 
 def draw_factors(seed: int, stream: int, rows: int, features: int) -> np.ndarray:
@@ -138,6 +164,18 @@ def scrape(base: str) -> dict[str, float]:
     return out
 
 
+def queued_ahead_share(records: list) -> float | None:
+    """Share of the dispatches that were launched while the one before them
+    was still on the device (`t_start` before the previous record's
+    `t_start + wall_s`): the depth-1 pipeline's loaded state, in which a
+    request waits for the scan ahead of its own. 1.0 or 0.0 is one state
+    throughout; between them the pipeline flipped inside the records."""
+    pairs = list(zip(records, records[1:]))
+    if not pairs:
+        return None
+    return sum(1 for a, b in pairs if b.t_start < a.t_start + a.wall_s) / len(pairs)
+
+
 def _sleep_until(t: float) -> None:
     wait = t - time.monotonic()
     if wait > 0:
@@ -157,6 +195,18 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float, in
     from oryx_tpu.common.perfstats import get_perfstats
     from oryx_tpu.serving.server import ServingLayer
 
+    # the cyclic collector stops every thread of the server for as long as a
+    # collection walks the model's id maps: time each one (gc_pause_share)
+    collections: list[tuple[float, float]] = []  # (monotonic start, seconds)
+
+    def on_gc(phase: str, _info: dict) -> None:
+        now = time.monotonic()
+        if phase == "start":
+            collections.append((now, 0.0))
+        else:
+            collections[-1] = (collections[-1][0], now - collections[-1][0])
+
+    gc.callbacks.append(on_gc)
     config, traffic = cell["config"], cell["traffic"]
     n_items, features = config["items"], config["features"]
     population = {"items": n_items, "active_users": config["active_users"]}
@@ -242,7 +292,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float, in
         _sleep_until(t_open)
         setup_s = time.time() - t_process
         before = scrape(base)
-        trace_out = None
+        trace_out = timeline_out = None
         if trace:
             trace_dir = Path(cell["scratch"]) / "trace"
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -254,11 +304,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float, in
             _sleep_until(min(time.monotonic() + TRACE_MAX_S, t_close - 0.5))
             jax.profiler.stop_trace()
             found = xplane.find_xplane(trace_dir)
-            trace_out = xplane.reduce_trace(found) if found else None
+            if found:
+                trace_out = xplane.reduce_trace(found, prefer=timeline.REGION_PREFIX)
+                timeline_out = timeline.parse(found)
         _sleep_until(t_close)
         after = scrape(base)
-        records = get_perfstats().records_since(t_open)
-        records = [r for r in records if r.t_start < t_close]
+        ring = get_perfstats().records_since(t_open - 1.0)  # the warm-up's last second too
+        records = [r for r in ring if t_open <= r.t_start < t_close]
+        pauses = [s for t, s in collections if t_open <= t < t_close]
         out, _ = gen.communicate(timeout=seconds + 240)
         result = json.loads(out.strip().splitlines()[-1])
         gen = None
@@ -271,12 +324,13 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float, in
         path = traffic["path"]
         with ThreadPoolExecutor(CHECK_USERS) as pool:  # together: one dispatch
             answers = list(pool.map(lambda u: _get(f"{base}{path.format(user=u)}"), users.tolist()))
-        faults = []
+        faults, checks = [], []
         for row, (u, (status, body)) in enumerate(zip(users.tolist(), answers)):
-            wrong = (
-                f"status {status}" if status != 200
-                else agree(json.loads(body), scores[row], known[u], how_many)
-            )
+            if status != 200:
+                faults.append(f"user u{u}: status {status}")
+                continue
+            checks.append(compare(json.loads(body), scores[row], known[u], how_many))
+            wrong = verdict(checks[-1], how_many)
             if wrong:
                 faults.append(f"user u{u}: {wrong}")
         wrong_bodies = sum(
@@ -289,40 +343,75 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float, in
         compiles = sum(v for s, v in delta.items() if s.startswith("oryx_xla_compiles_total"))
         if compiles:
             faults.append(f"{compiles:.0f} compile(s) inside the window")
+        # the configuration's guarantee: the scan is the exact (bf16) one. The answers
+        # alone cannot tell: the f32 re-rank makes an int8 scan's top-10 the same
+        not_exact = sum(1 for r in records if r.score_mode != "exact")
+        if not_exact:
+            faults.append(f"{not_exact} dispatch(es) in the window not in score-mode exact")
         for f in faults:
             print(f"als_serving: {f}", file=sys.stderr)
+
+        def worst(key, pick):
+            read = [c[key] for c in checks if c[key] is not None]
+            return pick(read) if read else None
+
+        # every number `correct` rests on: [read, how it is held, limit]
+        compared = {
+            "users_compared": [len(checks), "==", CHECK_USERS],
+            "worst_score_rel": [worst("score_rel", max), "<=", SCORE_RTOL],
+            "least_overlap": [worst("overlap", min), ">=", math.ceil(MIN_OVERLAP_SHARE * how_many)],
+            "worst_gap_over_slack": [worst("gap_over_slack", max), "<=", 1.0],
+            "malformed_answers": [sum(1 for c in checks if c["fault"]), "==", 0],
+            "wrong_bodies_in_window": [wrong_bodies, "==", 0],
+            "compiles_in_window": [compiles, "==", 0],
+            "dispatches_not_exact": [not_exact, "==", 0],
+            "good_in_window": [len(good), ">=", 1],
+        }
+        late = [
+            ms for ms, w in zip(result["late_ms"], result["in_window"]) if w and ms is not None
+        ]
         info(
             generator_processes=1, connections_opened=result["connections_opened"],
             errors=result["errors"], warm_s=warm_s,
             in_flight_at_window_end=latency.in_flight_at(result, warm_s + seconds),
             prime_s=t_open - t_prime,
+            gen_late_p95_ms=latency.percentile(late, 95) if late else None,
+            # the tail and its cause, for a reader of untraced runs (per-layer metrics in a traced one)
+            latency_p95_ms=latency.percentile(good, 95) if good else None,
+            collector_pauses_s=[round(s, 4) for s in pauses if s > 0.05],
+            # the window's dispatches, from the DispatchRecord ring
+            dispatches=len(records),
+            rows_per_dispatch=sum(r.rows for r in records) / len(records) if records else None,
+            shapes=sorted({(r.padded_rows, r.k_bucket) for r in records}),
+            queued_ahead_share=queued_ahead_share(records),
+            queued_ahead_share_at_open=queued_ahead_share([r for r in ring if r.t_start < t_open]),
         )
     finally:
+        gc.callbacks.remove(on_gc)
         if gen is not None:
             gen.kill()
             gen.wait()
         serving.close()
 
-    late = [ms for ms, w in zip(result["late_ms"], result["in_window"]) if w and ms is not None]
     return {
         "correct": not faults and bool(good),
         "attempted": attempted,
         "failed": failed,
         "setup_s": setup_s,
-        "end_to_end": {
-            "p50_ms": latency.percentile(good, 50) if good else None,
-            "p95_ms": latency.percentile(good, 95) if good else None,
-        },
+        "end_to_end": {"p50_ms": latency.percentile(good, 50) if good else None},
         # what the per-layer readers (benchmarks/metrics/*.py) read
         "sources": {
             "config": config,
             "traffic": traffic,
             "counters": delta,
             "dispatch_records": [
-                {"rows": r.rows, "padded_rows": r.padded_rows, "bytes_moved": r.bytes_moved}
+                {"rows": r.rows, "padded_rows": r.padded_rows, "k_bucket": r.k_bucket}
                 for r in records
             ],
-            "generator": {"late_ms": late},
+            "generator": {"late_ms": late, "latency_ms": good},
+            "collector": {"window_s": seconds, "pauses_s": pauses},
             "trace": trace_out,
+            "timeline": timeline_out,
         },
+        "compared": compared,
     }

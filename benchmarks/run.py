@@ -155,6 +155,11 @@ def main(argv: list[str]) -> int:
             if values.get(m["name"]) is not None
         }
     line["metrics"] = metrics
+    # what `correct` compared, each number beside its limit: the last key of
+    # the line and the last lines of stderr
+    line["compared"] = out.get("compared", {})
+    for name, (value, holds, limit) in line["compared"].items():
+        print(f"run.py: compared {name} = {value} (has to be {holds} {limit})", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0
 

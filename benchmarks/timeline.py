@@ -28,12 +28,10 @@ pair that breaks it (a number that never reached the device, a kernel the
 trace lost) and a dispatch of which the window lacks the issue, the fetch
 or the kernel (in flight at an edge) are left out and counted.
 
-A reader is handed only `src`, which carries no path: `of(src)` returns
-None when `src` has no `trace` key (as `{}`), `src["timeline"]` where a test
-supplies one, else the parse of the traced run's xplane under run.py's
-`SCRATCH / "trace"` (the kind removes that directory before every traced
-run, so it is this run's). A program without the regions (the parent of
-PR 25) gives an empty timeline and every reader None.
+A reader is handed only `src`: the kind parses the traced run's xplane
+once and hands the result over as `src["timeline"]`; an untraced run, and
+`{}`, have none. A program without the regions (the parent of PR 25) gives
+an empty timeline and every reader None.
 """
 
 from __future__ import annotations
@@ -41,14 +39,11 @@ from __future__ import annotations
 import gzip
 from pathlib import Path
 
-from benchmarks.xplane import OPS_LINE, find_xplane
+from benchmarks.xplane import OPS_LINE
 
-TRACE_DIR = Path(__file__).resolve().parent.parent / ".bench_out" / "trace"
 KERNEL = "topk_pallas"
 REGION_PREFIX = "batcher."
 SKEW_NS = 1e6
-
-_parsed: dict[tuple[str, int], dict] = {}
 
 
 def parse(path: str | Path, device_prefix: str = "/device:TPU:") -> dict:
@@ -87,22 +82,6 @@ def parse(path: str | Path, device_prefix: str = "/device:TPU:") -> dict:
     for events in regions.values():
         events.sort(key=lambda e: e["start"])
     return {"regions": regions, "kernels": kernels}
-
-
-def of(src: dict) -> dict | None:
-    """The timeline a reader reads; the contract is in the module's text."""
-    if "trace" not in src:
-        return None
-    if "timeline" in src:
-        return src["timeline"]
-    found = find_xplane(TRACE_DIR)
-    if found is None:
-        return None
-    key = (str(found), found.stat().st_mtime_ns)
-    if key not in _parsed:
-        _parsed.clear()  # one traced run a process
-        _parsed[key] = parse(found)
-    return _parsed[key]
 
 
 def _inside(events: list[dict], outer: dict) -> dict | None:
@@ -153,7 +132,7 @@ def join(timeline: dict) -> dict | None:
 
 def joined_of(src: dict) -> dict | None:
     """join() of the timeline a reader reads, or None without one."""
-    timeline = of(src)
+    timeline = src.get("timeline")
     return join(timeline) if timeline else None
 
 
@@ -163,7 +142,7 @@ def mean_ms(spans_ns: list[float]) -> float | None:
 
 def region_ms(src: dict, name: str) -> float | None:
     """Mean duration of every `name` region in the traced window, in ms."""
-    timeline = of(src)
+    timeline = src.get("timeline")
     if not timeline:
         return None
     return mean_ms([e["end"] - e["start"] for e in timeline["regions"].get(name, [])])

@@ -36,23 +36,32 @@ def _merge(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
     return merged
 
 
-def _label_gap(host_events: list[tuple[float, float, str]], gap: tuple[float, float]) -> str:
+def _label_gap(
+    host_events: list[tuple[float, float, str]], gap: tuple[float, float], prefer: str = ""
+) -> str:
     """Name of the host event that covers most of the gap; among equals the
-    shortest (innermost) one."""
-    best, best_key = "no_host_event", (0.0, 0.0)
+    shortest (innermost) one. Where `prefer` is given, an event whose own
+    name (after `<thread>:`) starts with it wins over every other: the
+    program's regions say what the host was doing, the runtime's events
+    only which call it was in."""
+    best, best_key = "no_host_event", (False, 0.0, 0.0)
     for start, end, name in host_events:
         overlap = min(end, gap[1]) - max(start, gap[0])
         if overlap > 0:
-            key = (overlap, -(end - start))
+            preferred = bool(prefer) and name.partition(":")[2].startswith(prefer)
+            key = (preferred, overlap, -(end - start))
             if key > best_key:
                 best, best_key = name, key
     return best
 
 
-def reduce_trace(path: str | Path, device_prefix: str = "/device:TPU:") -> dict | None:
+def reduce_trace(
+    path: str | Path, device_prefix: str = "/device:TPU:", prefer: str = ""
+) -> dict | None:
     """{window_s, busy_s, devices, ops: {name: [count, seconds]}, idle_gaps:
     [[label, seconds], ...]} or None when the trace holds no device op.
-    busy_s is the union of device-op intervals, averaged over the devices."""
+    busy_s is the union of device-op intervals, averaged over the devices;
+    `prefer` is the prefix of the program's own regions (see _label_gap)."""
     from jax.profiler import ProfileData
 
     path = Path(path)
@@ -100,7 +109,7 @@ def reduce_trace(path: str | Path, device_prefix: str = "/device:TPU:") -> dict 
         "busy_s": busy * 1e-9,
         "devices": len(per_device),
         "ops": ops,
-        "idle_gaps": [[_label_gap(host_events, g), (g[1] - g[0]) * 1e-9] for g in gaps],
+        "idle_gaps": [[_label_gap(host_events, g, prefer), (g[1] - g[0]) * 1e-9] for g in gaps],
     }
 
 

@@ -3,6 +3,7 @@ benchmarks/run.py end to end (ISSUE 24). No chip: nothing here is a device
 number."""
 
 import json
+import operator
 import os
 import re
 import subprocess
@@ -20,7 +21,12 @@ REPO = Path(__file__).resolve().parents[2]
 HERE = Path(__file__).resolve().parent
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
 PATHS = BENCH["paths"]
-TRAFFIC_FILES = sorted((REPO / "benchmarks" / "traffic").glob("*.json"))
+# a traffic file states the kind whose generator reads it (absent: als-serving);
+# another kind's files are for that kind's own tests/benchmarks/test_<kind>.py
+TRAFFIC_FILES = [
+    f for f in sorted((REPO / "benchmarks" / "traffic").glob("*.json"))
+    if json.loads(f.read_text()).get("kind", "als-serving") == "als-serving"
+]
 RECORDED = HERE / "data" / "steady128-5s.xplane.pb.gz"
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -157,38 +163,18 @@ def _reader(name):
     return load_module(find(PATHS, f"metrics/{name}.py")).read
 
 
-def test_readers_read_their_sources_and_return_nothing_when_there_is_nothing():
-    ph = 'oryx_request_phase_seconds_sum{phase="%s"}'
-    src = {
-        "counters": {
-            'oryx_request_phase_seconds_count{phase="device"}': 100.0,
-            ph % "parse": 0.2, ph % "auth": 0.1, ph % "write": 0.1,
-            ph % "serialize": 1.0, ph % "queue_wait": 50.0, ph % "batch_wait": 6.0,
-            "oryx_topk_coalesced": 452.0, "oryx_topk_dispatches": 4.0,
-        },
-        "dispatch_records": [
-            {"padded_rows": 512, "bytes_moved": 1.0}, {"padded_rows": 512, "bytes_moved": 1.0},
-            {"padded_rows": 4096, "bytes_moved": 2.0},
-        ],
-        "generator": {"late_ms": [float(j) for j in range(101)]},
-        "trace": {
-            "window_s": 10.0, "busy_s": 9.5,
-            "ops": {"%_topk_pallas_jit.1 = custom-call": [4, 4.0], "%pad.2 = pad": [4, 0.04]},
-            "idle_gaps": [],
-        },
-        "config": {"items": 5_000_000, "features": 250},
-        "traffic": {"k_bucket": 128},
-        "peaks": json.loads((REPO / "benchmarks" / "peaks.json").read_text())["TPU v5 lite"],
-    }
-    expect = {
-        "gen_late_p95_ms": 95.0, "frontend_ms_per_req": 4.0, "post_ms_per_req": 10.0,
-        "batcher_wait_ms_per_req": 560.0, "dispatch_rows": 113.0, "dispatch_shapes": 2.0,
-        "topk_kernel_ms": 1000.0, "topk_roofline": 0.305, "device_idle_share": 5.0,
-    }
-    assert set(expect) == {m["name"] for m in BENCH["per_layer"]}
-    for name, value in expect.items():
-        assert _reader(name)(src) == pytest.approx(value, rel=0.01), name
-        assert _reader(name)({}) is None, name
+@pytest.mark.parametrize("name", [m["name"] for m in BENCH["per_layer"]])
+def test_every_listed_reader_reads_its_case_and_returns_nothing_from_nothing(name):
+    """cases/<name>.json is {"src": what the reader is handed, "expect": what
+    it has to read from it}; a "peaks" string in src names a device kind of
+    peaks.json. Listing a metric is adding its reader, its case and its
+    entry: no table here names them."""
+    case = json.loads(find(PATHS, f"cases/{name}.json").read_text())
+    src = case["src"]
+    if isinstance(src.get("peaks"), str):
+        src["peaks"] = json.loads(find(PATHS, "peaks.json").read_text())[src["peaks"]]
+    assert _reader(name)(src) == pytest.approx(case["expect"], rel=0.01)
+    assert _reader(name)({}) is None
 
 
 # -- the trace reduction on a recorded trace --------------------------------------
@@ -213,6 +199,11 @@ def test_interval_union_and_gap_labels():
     host = [(0.0, 10.0, "main:outer"), (3.0, 5.0, "batcher:fetch")]
     assert xplane._label_gap(host, (3.5, 4.5)) == "batcher:fetch"  # innermost of equals
     assert xplane._label_gap(host, (20.0, 21.0)) == "no_host_event"
+    # the program's own region wins over a runtime event that covers more of the gap
+    host = [(0.0, 4.0, "python3:batcher.issue"), (0.0, 10.0, "python3:PjitFunction(f)")]
+    assert xplane._label_gap(host, (1.0, 9.0)) == "python3:PjitFunction(f)"
+    assert xplane._label_gap(host, (1.0, 9.0), prefer="batcher.") == "python3:batcher.issue"
+    assert xplane._label_gap(host, (5.0, 9.0), prefer="batcher.") == "python3:PjitFunction(f)"
 
 
 # -- BENCHMARK.json against the contract and the files ------------------------------
@@ -290,7 +281,11 @@ def test_cpu_rehearsal_of_a_test_only_cell_added_as_files(trace, tmp_path):
     assert proc.returncode == 0, proc.stderr[-2000:]
     lines = proc.stdout.strip().splitlines()
     last = json.loads(lines[-1])
-    assert set(last) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(last) == ["correct", "attempted", "failed", "device", "metrics", "compared"]
+    # each number `correct` rests on, beside its limit, and again as stderr's last lines
+    for name, (value, holds, limit) in last["compared"].items():
+        assert f"run.py: compared {name} = {value} (has to be {holds} {limit})" in proc.stderr
+    assert proc.stderr.strip().splitlines()[-1].startswith("run.py: compared ")
     assert last["failed"] == 0 and last["attempted"] == 40
     # on the CPU the batcher pads rows to powers of two, so a burst may meet a
     # row count the warm-up never saw: the run then says so and is not correct
@@ -300,12 +295,21 @@ def test_cpu_rehearsal_of_a_test_only_cell_added_as_files(trace, tmp_path):
     if trace:
         # host spans and counters are read; a CPU trace has no device plane,
         # so the device metrics are left out and not written as zeros
-        assert set(last["metrics"]) == {
-            "gen_late_p95_ms", "frontend_ms_per_req", "post_ms_per_req",
+        # (a 2 s window of 40 requests may see no collection start: then no gc_pause_share)
+        assert set(last["metrics"]) | {"gc_pause_share"} == {
+            "gen_late_p95_ms", "latency_p95_ms", "frontend_ms_per_req", "post_ms_per_req",
             "batcher_wait_ms_per_req", "dispatch_rows", "dispatch_shapes",
+            "launch_host_ms", "distribute_ms", "post_handoff_ms_per_req",
+            "post_rerank_ms_per_req", "post_render_ms_per_req", "gc_pause_share",
         }
+        parts = sum(
+            m["value"] for n, m in last["metrics"].items()
+            if n in ("post_handoff_ms_per_req", "post_rerank_ms_per_req", "post_render_ms_per_req")
+        )
+        assert parts <= last["metrics"]["post_ms_per_req"]["value"]
+        assert "residue" in proc.stderr
     else:
-        assert set(last["metrics"]) == {"p50_ms", "p95_ms", "setup_s"}
+        assert set(last["metrics"]) == {"p50_ms", "setup_s"}
     for m in last["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0
     notes = [json.loads(ln)["info"] for ln in lines[:-1]]
@@ -333,3 +337,80 @@ def test_alone_with_the_benchmark_files_it_prints_no_result(tmp_path):
         cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120,
     )
     assert proc.returncode != 0 and proc.stdout.strip() == ""
+
+
+# -- `correct` comes out false when the timed path is broken underneath --------------
+
+def _scan_of_the_wrong_rows(monkeypatch):
+    """The device scan answers for the negated query: the candidates it hands
+    on are each user's WORST items, honestly re-ranked and served."""
+    from oryx_tpu.ops import als as ops_als
+
+    sound = ops_als.topk_dot_batch
+    monkeypatch.setattr(ops_als, "topk_dot_batch", lambda xs, y, **kw: sound(-xs, y, **kw))
+
+
+def _scan_in_int8(monkeypatch):
+    """The control for the scan: the program's own lower-precision path
+    (score-mode quantized: int8 rows and per-row scales) switched on. Its
+    answers are the exact ones (the host re-ranks in float32), so it is the
+    dispatch records' score mode that fails it."""
+    from oryx_tpu.common import config as program_config
+
+    sound = program_config.load_config
+
+    def quantized(*args, overlay=None, **kw):
+        return sound(*args, overlay={**(overlay or {}), "oryx.serving.api.score-mode": "quantized"}, **kw)
+
+    monkeypatch.setattr(program_config, "load_config", quantized)
+
+
+def _scores_in_bfloat16(monkeypatch):
+    """The control for the re-rank: the host's exact scores, float32 by the
+    configuration's guarantees, computed in the nearest precision below it."""
+    import jax.numpy as jnp
+
+    from oryx_tpu.apps.als import serving as als_app
+
+    sound = als_app._rerank_exact
+
+    def low(user_vector, vals, idx, host_mat, cosine):
+        vals, idx = sound(user_vector, vals, idx, host_mat, cosine)
+        return np.asarray(jnp.asarray(vals, dtype=jnp.bfloat16), dtype=np.float32), idx
+
+    monkeypatch.setattr(als_app, "_rerank_exact", low)
+
+
+@pytest.mark.parametrize(
+    "fault,failing",
+    [
+        (None, set()),
+        (_scan_of_the_wrong_rows, {"least_overlap", "worst_gap_over_slack"}),
+        (_scan_in_int8, {"dispatches_not_exact"}),
+        (_scores_in_bfloat16, {"worst_score_rel"}),
+    ],
+    ids=["sound", "scan_of_the_wrong_rows", "scan_in_int8", "scores_in_bfloat16"],
+)
+def test_a_fault_under_the_timed_path_reads_not_correct(fault, failing, tmp_path, monkeypatch):
+    """The kind's whole run in this process (run.py's look for a chip is
+    skipped), the program broken underneath: `correct` is false exactly when
+    a compared number breaks its limit, and it is the number the fault moves."""
+    import time
+
+    if fault:
+        fault(monkeypatch)
+    cell = {
+        "config": json.loads(find(PATHS, "configs/als-tiny.json").read_text()),
+        "traffic": json.loads(find(PATHS, "traffic/tiny.json").read_text()),
+        "chips": 1, "scratch": str(tmp_path),
+    }
+    out = als_serving.run(cell, 2**31 + 11, 1.0, False, time.time(), lambda **kv: None)
+    holds = {"<=": operator.le, ">=": operator.ge, "==": operator.eq}
+    broken = {
+        name for name, (value, how, limit) in out["compared"].items()
+        if name != "compiles_in_window" and not holds[how](value, limit)
+    }
+    assert broken == failing
+    assert out["failed"] == 0 and out["attempted"] == 20
+    # (a compile inside the window, which the CPU's row padding can cause, also reads false)
+    assert out["correct"] is (not failing and out["compared"]["compiles_in_window"][0] == 0)

@@ -1,14 +1,11 @@
 """benchmarks/timeline.py and its seven readers (ISSUE 25): the join on a
-hand-built timeline, the readers on built sources, the reduction on a
-recorded annotated v5e trace, and a CPU rehearsal of run.py with the seven
-entries of benchmarks/timeline_per_layer.json listed. No chip: nothing
-here is a device number."""
+hand-built timeline, the identities the readers print, and the reduction on
+recorded annotated v5e traces. Each reader's own value is held by its case
+file (tests/benchmarks/cases/, test_benchmark.py), and the CPU rehearsal
+there prints the five a CPU trace yields. No chip: nothing here is a device
+number."""
 
 import json
-import os
-import shutil
-import subprocess
-import sys
 from pathlib import Path
 
 import pytest
@@ -20,7 +17,6 @@ REPO = Path(__file__).resolve().parents[2]
 HERE = Path(__file__).resolve().parent
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
 PATHS = BENCH["paths"]
-PENDING = json.loads((REPO / "benchmarks" / "timeline_per_layer.json").read_text())
 RECORDED = HERE / "data" / "steady128-annotated-5s.xplane.pb.gz"
 TWO_STATES = HERE / "data" / "steady128-two-states-5s.xplane.pb.gz"
 DEVICE_SIDE = {"device_queue_ms", "fetch_tail_ms"}
@@ -128,7 +124,7 @@ def test_the_join_allows_the_two_clocks_a_small_skew(late_ms, joins):
 def test_without_a_device_plane_there_is_no_join():
     tl = dict(_built(), kernels=None)
     assert timeline.join(tl) is None
-    assert timeline.joined_ms({"trace": None, "timeline": tl}, lambda d: 1.0) is None
+    assert timeline.joined_ms({"timeline": tl}, lambda d: 1.0) is None
 
 
 def test_a_region_inside_a_launch_is_found_on_the_same_thread_only():
@@ -139,35 +135,21 @@ def test_a_region_inside_a_launch_is_found_on_the_same_thread_only():
     assert timeline._inside([_ev(104, 106), _ev(107, 110)], launch) is None  # two: not one
 
 
-# -- the seven readers on built sources -----------------------------------------------
+# -- the identities the readers print, and a CPU run ----------------------------------
 
-def _src():
-    stage = "oryx_post_stage_seconds_%s{stage=\"%s\"}"
-    phase = "oryx_request_phase_seconds_%s{phase=\"%s\"}"
-    counters = {phase % ("count", "device"): 100.0, phase % ("sum", "device"): 200.0,
-                phase % ("sum", "serialize"): 9.0}
-    for name, seconds in (("handoff", 8.0), ("rerank", 0.5), ("render", 0.2)):
-        counters[stage % ("sum", name)] = seconds
-        counters[stage % ("count", name)] = 100.0
-    return {"counters": counters, "trace": None, "timeline": _built()}
+def _case(name):
+    return json.loads(find(PATHS, f"cases/{name}.json").read_text())
 
 
-def test_the_seven_readers_read_their_sources_and_return_nothing_when_there_is_nothing(capsys):
-    expect = {
-        "device_queue_ms": (900.0 + 981.0 + 979.0) / 3, "fetch_tail_ms": (3.0 + 1003.0 + 9.0) / 3,
-        "launch_host_ms": (12.0 + 10.0 + 12.0 + 10.0) / 4, "distribute_ms": (3.0 + 4.0 + 3.0 + 3.0) / 4,
-        "post_handoff_ms_per_req": 80.0, "post_rerank_ms_per_req": 5.0,
-        "post_render_ms_per_req": 2.0,
-    }
-    assert set(expect) == {m["name"] for m in PENDING} == DEVICE_SIDE | HOST_SIDE
-    src = _src()
-    for name, value in expect.items():
-        assert _reader(name)(src) == pytest.approx(value, rel=1e-9), name
-        assert _reader(name)({}) is None, name
+def test_the_readers_print_the_identities_and_a_cpu_trace_yields_the_host_side(capsys):
+    """The cases of the seven hold `_built()` and the counters beside it."""
+    src = _case("device_queue_ms")["src"]
+    assert src["timeline"] == _built()
+    for name in DEVICE_SIDE | HOST_SIDE:
+        assert _reader(name)(src) == pytest.approx(_case(name)["expect"], rel=1e-9), name
     err = capsys.readouterr().err
-    # the two identities, printed for a reader: issue 6 + queue 953.333 + kernel 990
-    # (the joined dispatches' own) + tail 338.333 against the device phase's 2,000 ms;
-    # and 80 + 5 + 2 of serialize's 90
+    # issue 6 + queue 953.333 + kernel 990 (the joined dispatches' own) + tail 338.333
+    # against the device phase's 2,000 ms; and 80 + 5 + 2 of serialize's 90
     assert "3 dispatches joined, 2 left out" in err
     assert "= 2287.667 ms; device phase per request 2000.000 ms, ratio 1.1438" in err
     assert "= 87.000 ms of post_ms_per_req 90.000; residue 3.000 ms" in err
@@ -176,7 +158,7 @@ def test_the_seven_readers_read_their_sources_and_return_nothing_when_there_is_n
     for name in DEVICE_SIDE:
         assert _reader(name)(cpu) is None, name
     for name in HOST_SIDE:
-        assert _reader(name)(cpu) == pytest.approx(expect[name]), name
+        assert _reader(name)(cpu) == pytest.approx(_case(name)["expect"]), name
 
 
 def test_a_program_without_the_regions_and_the_family_gives_nothing_and_does_not_raise():
@@ -187,44 +169,20 @@ def test_a_program_without_the_regions_and_the_family_gives_nothing_and_does_not
         "trace": {"ops": {}, "window_s": 1.0, "busy_s": 1.0, "idle_gaps": []},
         "timeline": {"regions": {}, "kernels": _built()["kernels"]},
     }
-    for m in PENDING:
-        assert _reader(m["name"])(parent) is None, m["name"]
+    for name in DEVICE_SIDE | HOST_SIDE | {"topk_fold_share"}:
+        assert _reader(name)(parent) is None, name
 
 
-def test_the_pending_entries_are_wellformed_and_each_has_its_reader():
-    listed = {m["name"] for m in BENCH["per_layer"]}
-    layers = {m["layer"] for m in BENCH["per_layer"]}
-    end_to_end = {m["name"] for m in BENCH["end_to_end"]}
-    for m in PENDING:
-        assert set(m) == {"name", "unit", "better", "source", "layer", "moves"}
-        assert m["name"] not in listed or m in BENCH["per_layer"]
-        assert find(PATHS, f"metrics/{m['name']}.py").is_file()
-        assert m["layer"] in layers and m["moves"] in end_to_end and m["better"] == "lower"
-        assert m["source"] == ("device_trace" if m["name"] in DEVICE_SIDE else "program_span")
+# -- the timeline a reader is handed ------------------------------------------------------
 
-
-# -- the xplane on disk ----------------------------------------------------------------
-
-def test_of_reads_src_then_the_traced_runs_xplane(tmp_path, monkeypatch):
-    assert timeline.of({}) is None  # no `trace` key: not a traced run
-    supplied = {"regions": {}, "kernels": None}
-    assert timeline.of({"trace": None, "timeline": supplied}) is supplied
-    monkeypatch.setattr(timeline, "TRACE_DIR", tmp_path / "trace")
-    assert timeline.of({"trace": None}) is None  # nothing on disk
-    run = tmp_path / "trace" / "plugins" / "profile" / "2026_01_01"
-    run.mkdir(parents=True)
-    import gzip
-
-    (run / "host.xplane.pb").write_bytes(gzip.open(RECORDED).read())
-    first = timeline.of({"trace": None})
-    assert first["kernels"] and first["regions"]["batcher.launch"]
-    assert timeline.of({"trace": None}) is first  # parsed once a process
-
-
-def test_the_trace_directory_is_run_pys_scratch():
-    from benchmarks import run
-
-    assert timeline.TRACE_DIR == run.SCRATCH / "trace"
+def test_the_readers_take_the_timeline_from_src_and_never_from_the_disk():
+    for src in ({}, {"trace": None, "timeline": None}):  # untraced; traced, no xplane found
+        assert timeline.region_ms(src, "batcher.launch") is None
+        assert timeline.joined_of(src) is None
+    src = {"trace": None, "timeline": _built()}
+    assert timeline.region_ms(src, "batcher.launch") == pytest.approx(11.0)
+    assert len(timeline.joined_of(src)["dispatches"]) == 3
+    assert not hasattr(timeline, "TRACE_DIR")
 
 
 # -- the reduction on a recorded annotated trace --------------------------------------------
@@ -240,7 +198,7 @@ def test_timeline_of_a_recorded_annotated_v5e_trace():
     )
     joined = timeline.join(tl)
     assert len(joined["dispatches"]) >= 2 and joined["left_out"] <= 3
-    src = {"trace": None, "timeline": tl}
+    src = {"timeline": tl}
     kernel_ms = timeline.joined_ms(src, lambda d: d["kernel"]["end"] - d["kernel"]["start"])
     assert 1000.0 < kernel_ms < 1300.0  # the 512-row scan of 6.29M rows
     # one scan is queued ahead: a dispatch waits about one kernel time for the device
@@ -269,41 +227,6 @@ def test_a_recorded_trace_of_the_pipeline_leaving_its_idle_state():
     assert all(0.0 < ms < 20.0 for ms in queue[:2])  # the upload and the lane pad, no scan ahead
     assert [d["launch"]["rows"] for d in joined["dispatches"]][2] == 1
     assert all(0.95 < ms / 1114.8 < 1.05 for ms in queue[2:])
-    src = {"trace": None, "timeline": timeline.parse(TWO_STATES)}
+    src = {"timeline": timeline.parse(TWO_STATES)}
     assert _reader("device_queue_ms")(src) == pytest.approx(sum(queue) / 4)
     assert 0.0 < _reader("fetch_tail_ms")(src) < 5.0
-
-
-# -- the CPU rehearsal with the seven entries listed -----------------------------------------
-
-def test_cpu_rehearsal_with_the_pending_entries_listed(tmp_path):
-    """run.py end to end on the CPU, from a copy whose BENCHMARK.json lists
-    the seven pending entries: the five host-side metrics print values read
-    from real annotations and counters, the two joined to the device are
-    left out, and the nine there keep their six."""
-    bench = dict(BENCH, per_layer=BENCH["per_layer"] + PENDING)
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    for p in PATHS:
-        shutil.copytree(REPO / p, tmp_path / p, ignore=shutil.ignore_patterns("__pycache__"))
-    env = dict(
-        os.environ, PYTHONPATH=str(REPO), JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache")
-    )
-    proc = subprocess.run(
-        # niced: a server, a generator and XLA's threads beside five other test workers
-        ["nice", "-n", "10", sys.executable, "benchmarks/run.py", "--workload", "als-tiny.tiny",
-         "--seed", str(2**31 + 7), "--seconds", "2", "--trace", "1"],
-        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    last = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert last["device"]["platform"] == "cpu" and last["failed"] == 0
-    assert set(last["metrics"]) == HOST_SIDE | {
-        "gen_late_p95_ms", "frontend_ms_per_req", "post_ms_per_req",
-        "batcher_wait_ms_per_req", "dispatch_rows", "dispatch_shapes",
-    }
-    for m in last["metrics"].values():
-        assert set(m) == {"value", "unit"} and m["value"] > 0
-    parts = sum(last["metrics"][n]["value"] for n in HOST_SIDE if n.startswith("post_"))
-    assert parts <= last["metrics"]["post_ms_per_req"]["value"]
-    assert "residue" in proc.stderr
-    assert (tmp_path / ".bench_out" / "trace").is_dir()  # the copy's own scratch
