@@ -186,6 +186,11 @@ class ALSServingModel(ServingModel):
         # dispatch labels/metrics must report what ran, not what the
         # config asked for
         self._effective_mode = score_mode
+        # the next full view build ends by freezing the loaded model out
+        # of the collector's sight (serving/viewsync.py
+        # freeze_loaded_model): the first build, and the first after each
+        # generation the manager announces
+        self.freeze_due = True
         self.sync = sync or SyncConfig()
         # (device matrix [capacity,K], ids [n], version, host f32 mirror
         # [capacity,K]) swapped as ONE tuple: readers always see a matched
@@ -458,8 +463,8 @@ class ALSServingModel(ServingModel):
         fallback when a delta can't serve (drift overflow, capacity
         exhausted, arena compaction). Call under _sync_lock."""
         from oryx_tpu.ops.transfer import (
-            CHUNKED_OVER_BYTES, ChunkedMatrix, device_put_maybe_chunked,
-            quantized_device_put, row_capacity, sharded_device_put,
+            CHUNKED_OVER_BYTES, device_put_maybe_chunked,
+            quantized_device_put, row_capacity, sharded_device_put, view_rows,
         )
 
         t0 = time.monotonic()
@@ -484,10 +489,9 @@ class ALSServingModel(ServingModel):
         itemsize = 1 if quantize else 2
         # capacity-padded rows: store growth within the headroom scatters
         # into existing rows — no realloc, no new batcher dispatch shape.
-        # Oversized (chunked) models skip the padding: their chunks are
+        # Oversized (chunked) models skip the headroom: their chunks are
         # bounded already and growth full-resyncs (blocking mode also
-        # skips it — it rebuilds per drift anyway, and unpadded views
-        # keep its behavior exactly pre-incremental)
+        # skips it — it rebuilds per drift anyway)
         cap = n
         if self.sync.mode != "blocking":
             cap = row_capacity(n, self.sync.capacity_headroom)
@@ -496,6 +500,17 @@ class ALSServingModel(ServingModel):
                 and cap * self.state.features * itemsize > CHUNKED_OVER_BYTES
             ):
                 cap = n
+        # ... in the shape the top-k kernel DMAs (ops/pallas_topk.py
+        # view_shape): rows a multiple of the item block, per shard when
+        # sharded, so no dispatch copies the view to pad it. The
+        # capacity ladder's counts already are at serving scale
+        # (6,291,456 = 3 x 2^21 for 5M items); small stores round up to
+        # their one block. The feature axis is lane-padded inside the
+        # upload; the host mirror keeps the published width.
+        cap = view_rows(
+            cap, self.state.features, jnp.int8 if quantize else jnp.bfloat16,
+            self.sync.shard_count,
+        )
         if cap > n:
             host = np.zeros((cap, self.state.features), dtype=np.float32)
             host[:n] = mat
@@ -509,9 +524,8 @@ class ALSServingModel(ServingModel):
         # step. Either way the f32 host matrix rides along for the exact
         # candidate re-rank — row-aligned with the device view by
         # construction, read lock-free on the request path. Oversized
-        # models come back as a ChunkedMatrix: a single (20M, 250)-class
-        # operand's program is too large to compile (ops/transfer.py);
-        # the batcher scores it chunk-and-merge.
+        # models come back as a ChunkedMatrix (ops/transfer.py
+        # CHUNKED_OVER_BYTES); the batcher scores it chunk-and-merge.
         by_shard = None
         if sharded:
             # pod-scale row shards over the CAPACITY rows: growth within
@@ -548,6 +562,11 @@ class ALSServingModel(ServingModel):
             cap * 4 if quantize else 0
         )
         self._note_resync("full", n, sync_bytes, dur, version, by_shard)
+        if self.freeze_due:
+            from oryx_tpu.serving.viewsync import freeze_loaded_model
+
+            self.freeze_due = False
+            freeze_loaded_model()
         return view
 
     # -- background resync --------------------------------------------------
@@ -1286,6 +1305,10 @@ class ALSServingModelManager(AbstractServingModelManager):
             )
             if old is not None:
                 old.close()  # stop the replaced model's resync thread
+        if key in ("MODEL", "MODEL-REF") and self.model is not None:
+            # a generation swap: new id maps and expected-id sets, in a
+            # new model or in the one kept (same rank)
+            self.model.freeze_due = True
 
     def close(self) -> None:
         if self.model is not None:

@@ -43,6 +43,10 @@ class SeqServingModel(ServingModel):
         # (device E [capacity,d] bf16, ids [n], version, host f32 mirror)
         # swapped as ONE tuple — readers take the snapshot lock-free
         self._device_view: tuple | None = None
+        # the next full view build ends by freezing the loaded model out
+        # of the collector's sight (serving/viewsync.py): the first
+        # build, and the first after each generation
+        self.freeze_due = True
 
     def fraction_loaded(self) -> float:
         return self.state.fraction_loaded()
@@ -122,9 +126,11 @@ class SeqServingModel(ServingModel):
         bf16 upload. Call under _sync_lock."""
         from oryx_tpu.ops.transfer import (
             device_put_maybe_chunked, row_capacity, sharded_device_put,
+            view_rows,
         )
         from oryx_tpu.serving.viewsync import (
-            note_sync_bytes, set_shard_rows, view_sync_metrics,
+            freeze_loaded_model, note_sync_bytes, set_shard_rows,
+            view_sync_metrics,
         )
         import time as _time
 
@@ -132,7 +138,12 @@ class SeqServingModel(ServingModel):
         mat, ids, version = self.state.items.snapshot()
         mat = np.asarray(mat, dtype=np.float32)
         n = len(ids)
-        cap = row_capacity(n, self.sync.capacity_headroom)
+        # capacity rows in the shape the top-k kernel DMAs (per shard when
+        # sharded; ops/transfer.py view_rows): no dispatch pads the view
+        cap = view_rows(
+            row_capacity(n, self.sync.capacity_headroom), self.state.dim,
+            jnp.bfloat16, self.sync.shard_count,
+        )
         if cap > n:
             host = np.zeros((cap, self.state.dim), dtype=np.float32)
             host[:n] = mat
@@ -159,6 +170,9 @@ class SeqServingModel(ServingModel):
         note_sync_bytes(metrics[0], cap * self.state.dim * 2, by_shard)
         metrics[1].observe(_time.monotonic() - t0)
         metrics[2].inc(kind="full")
+        if self.freeze_due:
+            self.freeze_due = False
+            freeze_loaded_model()
         return view
 
     # -- queries -----------------------------------------------------------
@@ -289,3 +303,5 @@ class SeqServingModelManager(AbstractServingModelManager):
         state = apply_seq_update(prev, key, message)
         if state is not None and state is not prev:
             self.model = SeqServingModel(state, sync=self.sync)
+        if key in ("MODEL", "MODEL-REF") and self.model is not None:
+            self.model.freeze_due = True  # a generation swap: new id maps

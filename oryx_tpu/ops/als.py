@@ -1739,10 +1739,9 @@ def topk_dot_batch_chunked(xs, y_chunks, *, k: int, recall: float = 1.0):
     the SAME compiled program), then one merge over the C*k candidates
     with indices rebased to global rows.
 
-    Why: a single (20M, 250) bf16 dispatch is a 10 GB operand, and the
-    kernel wrapper's lane-padded copy of it does not fit beside it in
-    16 GB of HBM; bounded chunk shapes keep every compiled program and
-    its temporaries small and reusable. Top-k is
+    Why: a single (20M, 250) bf16 dispatch is a 12 GB operand of 16 GB
+    of HBM; bounded chunk shapes keep every compiled program and its
+    temporaries small and reusable. Top-k is
     associative over row partitions, so the merge is exact; with
     recall < 1 each chunk's partial reduce carries the same per-chunk
     recall target."""
@@ -1829,7 +1828,13 @@ def topk_dot_batch(
 
     counted=True appends a third result: the fused kernel's int32[2]
     device array (item chunks it folded, item chunks it walked — its
-    threshold gate, ops/pallas_topk.py), or None on every other path."""
+    threshold gate, ops/pallas_topk.py), or None on every other path.
+
+    A resident serving view is lane-padded in features (ops/transfer.py
+    kernel_view_put); queries at the published width are zero-padded to
+    it here, once for every path — zeros change no dot product."""
+    if xs.shape[1] < y.shape[1]:
+        xs = jnp.pad(jnp.asarray(xs), ((0, 0), (0, y.shape[1] - xs.shape[1])))
     path = topk_path(y, k, recall)
     if path in ("pallas", "pallas-int8"):
         from oryx_tpu.ops.pallas_topk import topk_dot_batch_pallas

@@ -353,6 +353,11 @@ def _pow2_floor(n: int) -> int:
     return 1 << max(0, n.bit_length() - 1)
 
 
+def lane_pad(n_feat: int) -> int:
+    """The feature width the kernel DMAs: up to the 128-lane tile."""
+    return max(_LANE, -(-n_feat // _LANE) * _LANE)
+
+
 # ---------------------------------------------------------------------------
 # block tuning: per-(feature-pad, dtype) table, autotunable on hardware
 # ---------------------------------------------------------------------------
@@ -418,6 +423,35 @@ def tuned_blocks(feat_pad: int, y_itemsize: int) -> tuple[int, int]:
     return block_b, block_i
 
 
+def item_block(
+    n_items: int, feat_pad: int, y_itemsize: int, block_i: int | None = None
+) -> int:
+    """The item block one dispatch over `n_items` rows streams: the tuned
+    block (or the caller's), a multiple of the lane tile for the chunk
+    loop and pow2 so the compiled-shape count stays small. Non-pow2
+    requests round DOWN — an operator shrinking the block to dodge a
+    VMEM overflow must get at most what they asked for, never a
+    silently larger block — and never past the next pow2 of the row
+    count (no point padding the item axis beyond it)."""
+    if block_i is None:
+        block_i = tuned_blocks(feat_pad, y_itemsize)[1]
+    return max(_LANE, min(_pow2_floor(block_i), _pow2_ceil(n_items)))
+
+
+def view_shape(n_rows: int, n_feat: int, dtype) -> tuple[int, int]:
+    """The shape a resident [n_rows, n_feat] item matrix of `dtype` is
+    stored in so the kernel reads it as it lies: features up to the lane
+    tile (zeros there leave every dot product as it is), rows up to a
+    multiple of the item block a dispatch over them streams. The one
+    rule for every form of the serving view (ops/transfer.py pads inside
+    the upload); an array stored otherwise is padded by
+    topk_dot_batch_pallas on every call. Applying it to its own result
+    changes nothing."""
+    feat_pad = lane_pad(n_feat)
+    block_i = item_block(n_rows, feat_pad, jnp.dtype(dtype).itemsize)
+    return -(-n_rows // block_i) * block_i, feat_pad
+
+
 def autotune_blocks(
     xs, y, *, k: int, scales=None, candidates=AUTOTUNE_BLOCK_I, iters: int = 5
 ) -> tuple[int, int]:
@@ -430,7 +464,7 @@ def autotune_blocks(
 
     import numpy as np
 
-    feat_pad = max(_LANE, -(-xs.shape[1] // _LANE) * _LANE)
+    feat_pad = lane_pad(y.shape[1])
     itemsize = jnp.dtype(y.dtype).itemsize
     block_b = 128
     best, best_ms = None, None
@@ -479,25 +513,33 @@ def quantize_queries(xs):
 
 @partial(
     jax.jit,
-    static_argnames=("k", "block_b", "block_i", "quantized", "interpret"),
+    static_argnames=(
+        "k", "n_items", "block_b", "block_i", "quantized", "interpret"
+    ),
 )
 def _topk_pallas_jit(
-    xs, y, scales, *, k, block_b, block_i, quantized, interpret
+    xs, y, scales, *, k, n_items, block_b, block_i, quantized, interpret
 ):
-    n_b, n_feat = xs.shape
+    """The kernel over an item matrix ALREADY in the shape it DMAs
+    (view_shape): nothing the size of the catalog is copied here. Rows
+    at or past `n_items` are the caller's padding and never selected."""
+    n_b = xs.shape[0]
+    feat_pad = y.shape[1]
+    if feat_pad % _LANE or y.shape[0] % block_i or xs.shape[1] > feat_pad:
+        raise ValueError(
+            f"item matrix {y.shape} is not in the kernel's shape for "
+            f"{xs.shape[1]} features and item block {block_i} (view_shape)"
+        )
     if quantized:
         # int8 queries into the int8 kernel; per-row query scales apply
         # to the returned VALUES only (row-positive scaling is top-k
         # order-invariant, so they never need to enter the kernel)
         xs, sx = quantize_queries(xs)
-    n_items = y.shape[0]
-    # pad features to the lane tile (zeros leave dot products unchanged),
-    # batch to the block size, items to the item block
-    feat_pad = max(_LANE, -(-n_feat // _LANE) * _LANE)
+    # the query block alone is padded: features to the view's lanes
+    # (zeros leave dot products unchanged), batch to the block size
     xs_p = _pad_to(_pad_to(xs, feat_pad, 1), -(-n_b // block_b) * block_b, 0)
-    y_p = _pad_to(_pad_to(y, feat_pad, 1), -(-n_items // block_i) * block_i, 0)
     nb = xs_p.shape[0] // block_b
-    ni = y_p.shape[0] // block_i
+    ni = y.shape[0] // block_i
 
     n_gate = min(_GATE_CHUNKS, block_i // _LANE)
     kernel = partial(
@@ -510,17 +552,16 @@ def _topk_pallas_jit(
         # double-buffered DMA blocks out of it
         pl.BlockSpec(memory_space=pl.ANY),
     ]
-    operands = [xs_p, y_p]
+    operands = [xs_p, y]
     if quantized:
         # one row of scales per 128-item chunk, so the kernel picks a
         # chunk's scales with a sublane index
-        scale_p = _pad_to(
-            jnp.asarray(scales, dtype=jnp.float32), ni * block_i, 0
-        ).reshape(-1, _LANE)
         in_specs.append(
             pl.BlockSpec((block_i // _LANE, _LANE), lambda b, i: (i, 0))
         )
-        operands.append(scale_p)
+        operands.append(
+            jnp.asarray(scales, dtype=jnp.float32).reshape(-1, _LANE)
+        )
     vals, idx, folds = pl.pallas_call(
         kernel,
         grid=(nb, ni),
@@ -541,7 +582,7 @@ def _topk_pallas_jit(
             pltpu.VMEM((block_b, _LANE), jnp.int32),
             pltpu.VMEM((block_b, _LANE), jnp.float32),
             pltpu.VMEM((block_b, n_gate * _LANE), jnp.float32),
-            pltpu.VMEM((2, block_i, feat_pad), y_p.dtype),
+            pltpu.VMEM((2, block_i, feat_pad), y.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SMEM((1,), jnp.int32),
         ],
@@ -598,25 +639,23 @@ def topk_dot_batch_pallas(
     """
     if k > _LANE:
         raise ValueError(f"k must be <= {_LANE}, got {k}")
-    n_b = xs.shape[0]
     n_items = y.shape[0]
-    feat_pad = max(_LANE, -(-xs.shape[1] // _LANE) * _LANE)
-    t_bb, t_bi = tuned_blocks(feat_pad, jnp.dtype(y.dtype).itemsize)
+    feat_pad = lane_pad(y.shape[1])
+    itemsize = jnp.dtype(y.dtype).itemsize
     if block_b is None:
-        block_b = t_bb
-    if block_i is None:
-        block_i = t_bi
-    block_b = min(block_b, max(8, n_b))
-    # the chunk loop needs a block_i that is a multiple of the lane tile;
-    # pow2 keeps the compiled-shape count small. Non-pow2
-    # requests round DOWN — an operator shrinking the block to dodge a
-    # VMEM overflow must get at most what they asked for, never a
-    # silently larger block — and never past the next pow2 of the real
-    # row count (no point padding the item axis beyond it)
-    block_i = max(_LANE, min(_pow2_floor(block_i), _pow2_ceil(n_items)))
+        block_b = tuned_blocks(feat_pad, itemsize)[0]
+    block_b = min(block_b, max(8, xs.shape[0]))
+    block_i = item_block(n_items, feat_pad, itemsize, block_i)
+    if y.shape[1] != feat_pad or n_items % block_i:
+        # not a resident serving view (tests, tools, the trainer's
+        # evaluation): pad here, outside the jitted call, once per call
+        rows = -(-n_items // block_i) * block_i
+        y = _pad_to(_pad_to(y, feat_pad, 1), rows, 0)
+        if scales is not None:
+            scales = _pad_to(jnp.asarray(scales, dtype=jnp.float32), rows, 0)
     vals, idx, chunks = _topk_pallas_jit(
         xs, y, scales,
-        k=k, block_b=block_b, block_i=block_i,
+        k=k, n_items=n_items, block_b=block_b, block_i=block_i,
         quantized=scales is not None, interpret=interpret,
     )
     return (vals, idx, chunks) if counted else (vals, idx)

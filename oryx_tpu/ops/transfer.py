@@ -31,20 +31,36 @@ def _write(buf, chunk, start):
     return jax.lax.dynamic_update_slice(buf, chunk, idx)
 
 
-def staged_device_put(a: np.ndarray, dtype=None, chunk_bytes: int = DEFAULT_CHUNK_BYTES):
+def staged_device_put(
+    a: np.ndarray,
+    dtype=None,
+    chunk_bytes: int = DEFAULT_CHUNK_BYTES,
+    shape: tuple[int, ...] | None = None,
+):
     """Upload ``a`` to the default device in row-chunks of at most
     ``chunk_bytes``, concatenating on device. Returns a committed device
     array (equivalent to ``jnp.asarray(a, dtype)`` for 1-2D inputs).
+
+    ``shape`` (at least ``a.shape`` on every axis) is the device array's
+    shape when it is larger: ``a`` lands at the origin and the rest is
+    zero. The padding happens inside the upload — each chunk is written
+    as it is into the zeroed device buffer — so no second array the size
+    of the matrix ever exists, on the host or on the device.
 
     Small arrays take the direct path — staging only pays off when the
     transfer itself is the risk.
     """
     a = np.asarray(a)  # NOT ascontiguousarray: it promotes 0-d to 1-d
+    shape = a.shape if shape is None else tuple(int(n) for n in shape)
     if dtype is not None and a.ndim:
         target_bytes = a.shape[0] * int(np.prod(a.shape[1:], dtype=np.int64)) * jnp.dtype(dtype).itemsize
     else:
         target_bytes = a.nbytes
     if a.ndim == 0 or target_bytes <= chunk_bytes or a.shape[0] <= 1:
+        if shape != a.shape:
+            padded = np.zeros(shape, dtype=a.dtype)
+            padded[tuple(slice(0, n) for n in a.shape)] = a
+            a = padded
         out = jnp.asarray(a, dtype=dtype)
         return jax.block_until_ready(out)
 
@@ -56,7 +72,7 @@ def staged_device_put(a: np.ndarray, dtype=None, chunk_bytes: int = DEFAULT_CHUN
     # collecting all chunks then concatenating would transiently double
     # device memory, enough to turn a fitting model swap into an OOM
     out_dtype = jnp.dtype(dtype) if dtype is not None else a.dtype
-    buf = jnp.zeros(a.shape, dtype=out_dtype)
+    buf = jnp.zeros(shape, dtype=out_dtype)
     for start in range(0, a.shape[0], rows_per):
         dev = jnp.asarray(
             np.ascontiguousarray(a[start : start + rows_per]), dtype=out_dtype
@@ -67,13 +83,35 @@ def staged_device_put(a: np.ndarray, dtype=None, chunk_bytes: int = DEFAULT_CHUN
     return jax.block_until_ready(buf)
 
 
+def kernel_view_put(a: np.ndarray, dtype=None, *, align_rows: bool = True):
+    """Staged upload of a host [N, F] item matrix (or one chunk or shard
+    of one) in the shape the fused top-k kernel DMAs
+    (ops/pallas_topk.py view_shape): features zero-padded to the lane
+    tile and, with align_rows, zero rows up to a multiple of the item
+    block, so no dispatch ever copies the resident matrix to pad it. On
+    a TPU a bf16[N, 250] array occupies N x 256 lanes in its tiled HBM
+    layout anyway: the stored pad costs no memory, it only moves the
+    copy out of every dispatch. Zero rows score 0.0 like the capacity
+    rows of a serving view, and callers drop indices past their real
+    rows the same way."""
+    from oryx_tpu.ops.pallas_topk import view_shape
+
+    a = np.asarray(a)
+    rows, width = view_shape(
+        a.shape[0], a.shape[1], a.dtype if dtype is None else dtype
+    )
+    if not align_rows:
+        rows = a.shape[0]
+    return staged_device_put(a, dtype=dtype, shape=(rows, width))
+
+
 # ---------------------------------------------------------------------------
 # chunked device matrices: models too large to score as ONE array (a
-# (20M, 250) bf16 operand is 10 GB of a 16 GB chip, and the fused
-# kernel's lane-padded copy of it does not fit beside it; the one-shot
-# dispatch failed in round 5). The matrix lives as bounded row chunks;
-# every compiled program sees only a chunk shape, and all equal chunks
-# share one program.
+# (20M, 250) bf16 operand is 12 GB of a 16 GB chip, and a non-donated
+# delta scatter of it needs a second one). The matrix lives as bounded
+# row chunks, each in the kernel's shape (kernel_view_put); every
+# compiled program sees only a chunk shape, and all equal chunks share
+# one program.
 # ---------------------------------------------------------------------------
 
 # auto-chunk threshold + per-chunk target for serving device views
@@ -212,16 +250,21 @@ def sharded_device_put(
         # layout exists to prevent. Committed shards pin every
         # descendant computation (delta scatters, the unit-view
         # normalize) to their own device.
+        # Every shard keeps exactly the rows its plan owns (a shard's
+        # local index IS its global index minus plan.lo), lane-padded in
+        # features; the serving tier sizes the capacity so that each
+        # shard's rows are a multiple of its item block (view_rows).
         with jax.default_device(devs[s]):
             if quantize:
-                qm = quantized_device_put(block)
+                qm = quantized_device_put(block, align_rows=False)
                 shards.append(QuantizedMatrix(
                     jax.device_put(qm.q, devs[s]),
                     jax.device_put(qm.scale, devs[s]),
                 ))
             else:
                 shards.append(jax.device_put(
-                    staged_device_put(block, dtype=dtype), devs[s]
+                    kernel_view_put(block, dtype=dtype, align_rows=False),
+                    devs[s],
                 ))
     return ShardedMatrix(shards, plan)
 
@@ -302,11 +345,17 @@ def _int8_unit_scales(q):
     return jnp.where(norms > 0, 1.0 / jnp.maximum(norms, 1e-12), 0.0)
 
 
-def quantized_device_put(a: np.ndarray) -> QuantizedMatrix:
+def quantized_device_put(
+    a: np.ndarray, *, align_rows: bool = True
+) -> QuantizedMatrix:
     """Quantize a host f32 matrix per-row and upload (staged) as a
-    QuantizedMatrix device view."""
+    QuantizedMatrix device view in the kernel's shape (kernel_view_put).
+    The scales are per row: padded with the rows, never in features."""
     q, scale = quantize_rows_int8(a)
-    return QuantizedMatrix(staged_device_put(q), staged_device_put(scale))
+    q_dev = kernel_view_put(q, align_rows=align_rows)
+    return QuantizedMatrix(
+        q_dev, staged_device_put(scale, shape=(q_dev.shape[0],))
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -404,8 +453,12 @@ def scatter_rows(buf, idx: np.ndarray, rows: np.ndarray, *, donate: bool = False
     b = _scatter_bucket(d)
     idx_p = np.full(b, buf.shape[0], dtype=np.int32)  # pads drop on device
     idx_p[:d] = idx
+    # rows arrive at the published width; the buffer may be lane-padded
+    # (kernel_view_put): written at the buffer's width, the pad lanes of
+    # a dirty row stay zero
+    rows = np.asarray(rows, dtype=buf.dtype)
     rows_p = np.zeros((b,) + tuple(buf.shape[1:]), dtype=buf.dtype)
-    rows_p[:d] = np.asarray(rows, dtype=buf.dtype)
+    rows_p[(slice(0, d),) + tuple(slice(0, n) for n in rows.shape[1:])] = rows
     fn = _scatter_donated if donate else _scatter
     return jax.block_until_ready(
         fn(buf, jnp.asarray(rows_p), jnp.asarray(idx_p))
@@ -446,27 +499,46 @@ def row_capacity(n: int, headroom: float) -> int:
     return -(-target // unit) * unit
 
 
+def view_rows(n: int, features: int, dtype, shards: int = 1) -> int:
+    """Rows of a device view holding ``n`` rows of ``dtype`` in the
+    kernel's shape (ops/pallas_topk.py view_shape): a multiple of the
+    item block, and split into ``shards`` equal row shards each of them
+    one (RowShards.plan splits an evenly divisible count evenly)."""
+    from oryx_tpu.ops.pallas_topk import view_shape
+
+    return shards * view_shape(-(-n // shards), features, dtype)[0]
+
+
 def device_put_maybe_chunked(
     a: np.ndarray,
     dtype=None,
     over_bytes: int | None = None,
     chunk_bytes: int | None = None,
 ):
-    """staged_device_put for matrices that fit one program; ChunkedMatrix
-    above `over_bytes` (in TARGET dtype), with ~`chunk_bytes` chunks.
+    """kernel_view_put for matrices that fit one program; ChunkedMatrix
+    above `over_bytes` (in TARGET dtype), with ~`chunk_bytes` chunks,
+    each in the kernel's shape (only the last holds padding rows).
     Thresholds resolve at call time so tests can lower the module
     constants and exercise the chunked path at toy scale."""
+    from oryx_tpu.ops.pallas_topk import view_shape
+
     if over_bytes is None:
         over_bytes = CHUNKED_OVER_BYTES
     if chunk_bytes is None:
         chunk_bytes = CHUNK_TARGET_BYTES
     a = np.asarray(a)
-    itemsize = jnp.dtype(dtype).itemsize if dtype is not None else a.itemsize
-    target_bytes = int(np.prod(a.shape, dtype=np.int64)) * itemsize
-    if a.ndim != 2 or target_bytes <= over_bytes:
+    if a.ndim != 2:
         return staged_device_put(a, dtype=dtype)
-    rows_per = max(1, chunk_bytes // max(1, a.shape[1] * itemsize))
+    out_dtype = a.dtype if dtype is None else dtype
+    itemsize = jnp.dtype(out_dtype).itemsize
+    target_bytes = int(np.prod(a.shape, dtype=np.int64)) * itemsize
+    if target_bytes <= over_bytes:
+        return kernel_view_put(a, dtype=dtype)
+    rows_per = view_shape(
+        max(1, chunk_bytes // max(1, a.shape[1] * itemsize)),
+        a.shape[1], out_dtype,
+    )[0]
     return ChunkedMatrix(
-        staged_device_put(a[at : at + rows_per], dtype=dtype)
+        kernel_view_put(a[at : at + rows_per], dtype=dtype)
         for at in range(0, a.shape[0], rows_per)
     )

@@ -79,12 +79,19 @@ _PA = get_perfattr()
 def _dispatch_bytes(padded: int, features: int, y, kb: int) -> float:
     """Approximate bytes one coalesced dispatch moves: the query upload,
     one read of the item matrix out of HBM (by far the largest term) and
-    the result fetch. A count of bytes, not a model of the kernel's time:
-    the fused scan reaches 0.27 % of its roofline at 5M x 250 (ledger,
-    PR 24) and its time is linear in query rows, so it is selection-bound
-    and not bandwidth-bound in Y."""
+    the result fetch, at the PUBLISHED `features`: the lanes a resident
+    view is padded with (ops/transfer.py kernel_view_put) are the
+    implementation's and are taken off the matrix's bytes. A count of
+    bytes, not a model of the kernel's time: the fused scan reaches 9 %
+    of its roofline at 5M x 250 (ledger, PR 26) and its time follows the
+    query rows, so it is selection-bound and not bandwidth-bound in Y."""
     try:
         y_bytes = float(getattr(y, "nbytes", 0) or 0)
+        if y_bytes:
+            y_bytes -= (
+                float(y.shape[0]) * (y.shape[1] - features)
+                * np.dtype(y.dtype).itemsize
+            )
     except Exception:  # non-jax stub matrices in tests
         y_bytes = 0.0
     return float(padded * features * 4 + y_bytes + padded * kb * 8)
@@ -745,7 +752,10 @@ class TopKBatcher:
                     # valid_rows — they're HBM-cheap but not useful FLOPs,
                     # so the MFU figure counts only the real-data prefix
                     n_rows = group[0].valid_rows or y.shape[0]
-                    group_flops = 2.0 * b * n_rows * y.shape[1]
+                    # ... and at the published feature count (the
+                    # queries'), not the view's lane-padded width
+                    features = int(group[0].vec.shape[-1])
+                    group_flops = 2.0 * b * n_rows * features
                     # per-dtype peak: a quantized (int8) dispatch's MFU
                     # window divides by the int8 peak, an exact bf16 one
                     # by bf16
@@ -783,9 +793,10 @@ class TopKBatcher:
                                 "batch_wait", t0 - t_pick, start=t_pick
                             )
                     t_pad = time.monotonic()
+                    # at the view's width: its pad lanes stay zero
                     xs = np.zeros((padded, y.shape[1]), dtype=np.float32)
                     for i, p in enumerate(group):
-                        xs[i] = p.vec
+                        xs[i, :features] = p.vec
                     pad_s = time.monotonic() - t_pad
                     for p in group:
                         if p.ledger is not None:
@@ -869,7 +880,7 @@ class TopKBatcher:
                     tp = group[0].trace_parent
                     cost = (
                         t0, group_flops,
-                        _dispatch_bytes(padded, y.shape[1], y, kb),
+                        _dispatch_bytes(padded, features, y, kb),
                         b, padded, int(n_rows), int(y.shape[0]),
                         tp.trace_id if tp is not None else None,
                         group[0].score_mode,

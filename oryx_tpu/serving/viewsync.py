@@ -12,6 +12,7 @@ unit/LSH/quantized views the seq model has no use for.)
 
 from __future__ import annotations
 
+import gc
 import logging
 import threading
 
@@ -107,6 +108,24 @@ def sharded_delta_bytes(plan, rows, bytes_of_d) -> tuple[int, dict[int, int]]:
         for s, local, _ in plan.split(np.asarray(rows))
     }
     return sum(by_shard.values()), by_shard
+
+
+def freeze_loaded_model() -> None:
+    """Take a loaded model out of the cyclic collector's sight. A full
+    collection walks every tracked container entry by entry, and a
+    model's id lists, expected-id sets and known-item sets hold millions
+    of entries: 0.31-0.36 s a pass at 5M items, during which every
+    thread of the server stands still (PERF.md, PR 27), four times that
+    at 20M. Called by a serving model once its view is built, for each
+    generation: what the old generation left in cycles is collected this
+    once (unfreeze first), then everything alive moves to the permanent
+    generation, which no collection visits. The maps are acyclic
+    containers of str and int, which reference counts free when a
+    generation goes; ids the speed layer adds later enter the frozen
+    containers without being walked."""
+    gc.unfreeze()
+    gc.collect()
+    gc.freeze()
 
 
 def extend_view_ids(ids: list, delta) -> list | None:

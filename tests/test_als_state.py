@@ -276,7 +276,10 @@ def test_chunked_device_view_serves_identically(monkeypatch):
     chunked = build()
 
     assert isinstance(chunked._y_view_full()[0], ChunkedMatrix)
-    assert chunked._y_view_full()[0].shape == (n, k)
+    # 300 rows round up to their one item block (512), in chunks of 128
+    # rows, each stored lane-padded: the kernel's shape
+    assert chunked._y_view_full()[0].shape == (512, 128)
+    assert [c.shape for c in chunked._y_view_full()[0].chunks] == [(128, 128)] * 4
     q = rng.standard_normal(k).astype(np.float32)
     assert chunked.top_n(q, 12) == plain.top_n(q, 12)
     assert chunked.top_n(q, 12, cosine=True) == plain.top_n(q, 12, cosine=True)

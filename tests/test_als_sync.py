@@ -242,11 +242,14 @@ def test_device_and_host_views_row_equal_after_delta_scatter():
     import jax.numpy as jnp
 
     # every valid row of the device view equals the host mirror rounded
-    # to the device dtype (bf16); capacity padding stays zero
+    # to the device dtype (bf16); capacity padding stays zero, and so do
+    # the lanes the view is padded with (the kernel's shape: 8 -> 128)
+    assert dev.shape == (host_mat.shape[0], 128)
     np.testing.assert_array_equal(
-        dev[:n], np.asarray(host_mat[:n].astype(jnp.bfloat16), dtype=np.float32)
+        dev[:n, :8],
+        np.asarray(host_mat[:n].astype(jnp.bfloat16), dtype=np.float32),
     )
-    assert not dev[n:].any()
+    assert not dev[n:].any() and not dev[:, 8:].any()
     # host mirror rows match the store exactly
     for j, ident in enumerate(ids):
         np.testing.assert_array_equal(host_mat[j], st.y.get(ident))

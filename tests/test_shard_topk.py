@@ -49,7 +49,8 @@ def test_sharded_topk_bit_identical_bf16(n_shards):
     xs, y = _corpus()
     y_full = staged_device_put(y, dtype=jnp.bfloat16)
     y_sharded = sharded_device_put(y, n_shards, dtype=jnp.bfloat16)
-    assert y_sharded.shape == y_full.shape
+    # the sharded view is stored lane-padded (the kernel's shape)
+    assert y_sharded.shape == (y.shape[0], 128)
     v0, i0 = topk_dot_batch(jnp.asarray(xs), y_full, k=10)
     v1, i1 = topk_dot_batch(jnp.asarray(xs), y_sharded, k=10)
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
@@ -66,9 +67,9 @@ def test_sharded_topk_bit_identical_quantized(n_shards):
     sharded = sharded_device_put(y, n_shards, quantize=True)
     # per-row scales are row-local: shard-local quantization must be
     # bit-identical to quantize-then-slice
-    np.testing.assert_array_equal(
-        np.concatenate([np.asarray(sh.q) for sh in sharded.shards]), q
-    )
+    q_sharded = np.concatenate([np.asarray(sh.q) for sh in sharded.shards])
+    np.testing.assert_array_equal(q_sharded[:, :q.shape[1]], q)
+    assert not q_sharded[:, q.shape[1]:].any()  # the lane pad
     v0, i0 = topk_dot_batch(jnp.asarray(xs), full, k=10)
     v1, i1 = topk_dot_batch(jnp.asarray(xs), sharded, k=10)
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
@@ -134,7 +135,9 @@ def test_sharded_placement_uses_distinct_devices():
     assert [next(iter(sh.devices())) for sh in afterq.shards] == qdevs
     # full view reassembles exactly across devices
     np.testing.assert_array_equal(
-        np.concatenate([np.asarray(sh, dtype=np.float32) for sh in sm.shards]),
+        np.concatenate(
+            [np.asarray(sh, dtype=np.float32) for sh in sm.shards]
+        )[:, :y.shape[1]],
         np.asarray(
             staged_device_put(y, dtype=jnp.bfloat16), dtype=np.float32
         ),
@@ -168,7 +171,8 @@ def test_sharded_scatter_touches_owning_shard_only():
     assert out.shards[3] is old_shards[3]
     assert out.shards[1] is not old_shards[1]
     got = np.asarray(out.shards[1], dtype=np.float32)
-    np.testing.assert_allclose(got[[1, 3]], new_rows, rtol=0.01)
+    np.testing.assert_allclose(got[[1, 3], :6], new_rows, rtol=0.01)
+    assert not got[:, 6:].any()  # dirty rows land lane-padded with zeros
     # empty delta: the view object rides through unchanged
     same = scatter_rows(out, np.array([], dtype=np.int64), np.zeros((0, 6)))
     assert same is out
@@ -183,7 +187,8 @@ def test_sharded_scatter_quantized_requantizes_locally():
     out = scatter_rows(sm, rows, fresh)
     assert out.shards[0] is old[0] and out.shards[2] is old[2]
     q_exp, s_exp = quantize_rows_int8(fresh)
-    np.testing.assert_array_equal(np.asarray(out.shards[1].q)[1], q_exp[0])
+    np.testing.assert_array_equal(np.asarray(out.shards[1].q)[1, :4], q_exp[0])
+    assert not np.asarray(out.shards[1].q)[:, 4:].any()
     np.testing.assert_allclose(
         np.asarray(out.shards[1].scale)[1], s_exp[0], rtol=1e-6
     )
