@@ -76,13 +76,16 @@ class DispatchRecord:
     and its ``k_bucket``; kinds without them (train) leave both None. A
     dispatch through the fused top-k kernel carries the kernel's own count
     of the item chunks it folded and walked (ops/pallas_topk.py's
-    threshold gate); every other path leaves those two None."""
+    threshold gate), and the dispatch's row blocks beside those of them
+    the kernel did not walk (they lie past the real rows); every other
+    path leaves those four None."""
 
     __slots__ = (
         "kind", "t_start", "wall_s", "flops", "bytes_moved",
         "rows", "padded_rows", "valid_rows", "capacity_rows",
         "occupancy", "trace_id", "score_mode", "seq",
         "dispatch", "k_bucket", "chunks_folded", "chunks_total",
+        "row_blocks", "row_blocks_skipped",
     )
 
     def __init__(
@@ -102,6 +105,8 @@ class DispatchRecord:
         k_bucket: int | None = None,
         chunks_folded: int | None = None,
         chunks_total: int | None = None,
+        row_blocks: int | None = None,
+        row_blocks_skipped: int | None = None,
     ):
         self.kind = kind
         self.t_start = t_start
@@ -131,6 +136,8 @@ class DispatchRecord:
         self.k_bucket = k_bucket
         self.chunks_folded = chunks_folded
         self.chunks_total = chunks_total
+        self.row_blocks = row_blocks
+        self.row_blocks_skipped = row_blocks_skipped
 
     def chrome_event(self, pid: int) -> dict:
         """This record as a Chrome trace-event `X` slice (Perfetto)."""
@@ -159,7 +166,9 @@ class DispatchRecord:
             event["args"].update(dispatch=self.dispatch, k_bucket=self.k_bucket)
         if self.chunks_total is not None:
             event["args"].update(
-                chunks_folded=self.chunks_folded, chunks_total=self.chunks_total
+                chunks_folded=self.chunks_folded, chunks_total=self.chunks_total,
+                row_blocks=self.row_blocks,
+                row_blocks_skipped=self.row_blocks_skipped,
             )
         return event
 
@@ -274,6 +283,8 @@ class PerfStats:
         k_bucket: int | None = None,
         chunks_folded: int | None = None,
         chunks_total: int | None = None,
+        row_blocks: int | None = None,
+        row_blocks_skipped: int | None = None,
     ) -> DispatchRecord:
         rec = DispatchRecord(
             kind,
@@ -281,6 +292,7 @@ class PerfStats:
             wall_s, flops, bytes_moved, rows, padded_rows, valid_rows,
             capacity_rows, trace_id, score_mode,
             dispatch, k_bucket, chunks_folded, chunks_total,
+            row_blocks, row_blocks_skipped,
         )
         rec.seq = next(self._seq)
         buf = self._buf

@@ -1733,11 +1733,14 @@ PALLAS_TOPK_MAX_K = 128
 PALLAS_TOPK_MIN_ITEMS = 32768
 
 
-def topk_dot_batch_chunked(xs, y_chunks, *, k: int, recall: float = 1.0):
+def topk_dot_batch_chunked(
+    xs, y_chunks, *, k: int, recall: float = 1.0, rows=None
+):
     """Exact batched top-k over an item matrix supplied as row CHUNKS:
     per-chunk top-k with the normal kernel (every equal-shaped chunk hits
     the SAME compiled program), then one merge over the C*k candidates
-    with indices rebased to global rows.
+    with indices rebased to global rows. Every chunk is given the same
+    `rows` (topk_dot_batch).
 
     Why: a single (20M, 250) bf16 dispatch is a 12 GB operand of 16 GB
     of HBM; bounded chunk shapes keep every compiled program and its
@@ -1754,7 +1757,9 @@ def topk_dot_batch_chunked(xs, y_chunks, *, k: int, recall: float = 1.0):
     vals, idxs = [], []
     base = 0
     for y in y_chunks:
-        v, i = topk_dot_batch(xs, y, k=min(k, y.shape[0]), recall=recall)
+        v, i = topk_dot_batch(
+            xs, y, k=min(k, y.shape[0]), recall=recall, rows=rows
+        )
         pad = k - v.shape[1]
         if pad > 0:  # a chunk smaller than k still merges cleanly
             v = jnp.pad(v, ((0, 0), (0, pad)), constant_values=-jnp.inf)
@@ -1810,7 +1815,7 @@ def topk_path(y, k: int, recall: float = 1.0) -> str:
 
 
 def topk_dot_batch(
-    xs, y, *, k: int, recall: float = 1.0, counted: bool = False
+    xs, y, *, k: int, recall: float = 1.0, counted: bool = False, rows=None
 ):
     """Batched top-k scoring; topk_path names the kernel selection.
     recall < 1 takes the approximate partial-reduce; exact requests take
@@ -1830,6 +1835,13 @@ def topk_dot_batch(
     device array (item chunks it folded, item chunks it walked — its
     threshold gate, ops/pallas_topk.py), or None on every other path.
 
+    rows: how many leading rows of xs are real, None for all. The fused
+    kernel does not walk a row block that lies past them and returns
+    filler there (ops/pallas_topk.py); every shard and chunk is given the
+    same count, and the XLA, approximate and host paths ignore it: rows
+    past `rows` are the caller's padding on every path, and only the
+    rows before them are the same on all.
+
     A resident serving view is lane-padded in features (ops/transfer.py
     kernel_view_put); queries at the published width are zero-padded to
     it here, once for every path — zeros change no dot product."""
@@ -1841,19 +1853,21 @@ def topk_dot_batch(
 
         if path == "pallas-int8":
             return topk_dot_batch_pallas(
-                xs, y.q, scales=y.scale, k=k, counted=counted
+                xs, y.q, scales=y.scale, k=k, counted=counted, rows=rows
             )
         # mixed-precision queries score in the matrix's dtype (the bf16
         # serving view); accumulation is f32 either way
         return topk_dot_batch_pallas(
-            jnp.asarray(xs, dtype=y.dtype), y, k=k, counted=counted
+            jnp.asarray(xs, dtype=y.dtype), y, k=k, counted=counted, rows=rows
         )
     if path == "sharded":
         from oryx_tpu.ops.shard_topk import topk_dot_batch_sharded
 
-        out = topk_dot_batch_sharded(xs, y, k=k, recall=recall)
+        out = topk_dot_batch_sharded(xs, y, k=k, recall=recall, rows=rows)
     elif path == "chunked":
-        out = topk_dot_batch_chunked(xs, y.chunks, k=k, recall=recall)
+        out = topk_dot_batch_chunked(
+            xs, y.chunks, k=k, recall=recall, rows=rows
+        )
     elif path == "xla-int8":
         out = topk_dot_batch_quant_xla(
             xs, y.q, y.scale, k=k, recall=float(recall) if recall < 1.0 else 1.0

@@ -52,15 +52,16 @@ branch), and only inside a group that fires tests and folds its chunks
 one by one. A skipped chunk holds nothing that precedes any row's k-th
 element, so the output is bit for bit what folding every chunk gives;
 lanes past k of the running list go stale and are never returned. The
-kernel counts the chunks it folds (one int32 per row block); the
-wrapper returns (folded, walked) beside the results when asked
-(`counted=True`), and the batcher puts them on the DispatchRecord and
-into `oryx_topk_chunks_folded` / `oryx_topk_chunks`.
+kernel counts the chunks it folds and the chunks it walks (two int32
+per row block); the wrapper returns (folded, walked) beside the results
+when asked (`counted=True`), and the batcher puts them on the
+DispatchRecord and into `oryx_topk_chunks_folded` / `oryx_topk_chunks`.
 
 Measured on one v5e chip (PR 26; 512-row dispatch, k = 128, over a
 6,291,456 x 256 bf16 view holding 5,000,000 rows of standard-normal
-factors; the ungated kernel took 1,116 ms in every case, and every
-result below is bit-identical to its):
+factors, all four row blocks walked as they were until PR 30; the
+ungated kernel took 1,116 ms in every case, and every result below is
+bit-identical to its):
 
     real rows of the 512     time       chunks folded of 196,608
     1                         23.5 ms        788
@@ -78,11 +79,37 @@ result below is bit-identical to its):
 
 A gated group of 8 chunks costs 0.78 us (one gate per chunk: 0.33 us a
 chunk, 69 ms for the one-row dispatch; one gate per 16 or 32 chunks is
-no faster than per 8), so the floor of a 512-row dispatch over this view
-is 19 ms against 3.05 ms of HBM time, and 3/4 of it is the three
-all-padding row blocks of the 512-row bucket. PR 21's comparison (XLA
-matmul + top_k at 512 x 1.31M x 50f: 41 / 72 / 257 ms at k 16 / 32 /
-128, the ungated kernel 236 ms) has not been repeated with the gate.
+no faster than per 8), so one pass of a row block over this view has a
+floor of 4.2-4.8 ms against 3.93 ms of HBM time for its 3.22 GB. PR 21's
+comparison (XLA matmul + top_k at 512 x 1.31M x 50f: 41 / 72 / 257 ms at
+k 16 / 32 / 128, the ungated kernel 236 ms) has not been repeated with
+the gate.
+
+Dead row blocks (PR 30). The grid walks the whole view once per row
+block, so until PR 30 a 512-row dispatch was FOUR passes whatever it
+held, and the batcher's dispatches hold 1-10 real rows: three of the
+four passes scored zeros (12.7 of 36.2 ms at 5 real rows, and 9.66 GB
+of the 12.9 GB read from HBM). The kernel is now told how many leading
+rows of the query block are real (`rows`: an int32 scalar prefetched
+into SMEM, traced, so one compiled program serves every count) and a
+row block that lies wholly past them is dead: it starts no DMA, waits
+on none, runs no gate loop and counts no chunk; its grid steps are
+empty (2,304 of them cost 0.12 ms) and its output blocks are written
+once with (-inf, index 0, counts 0). A dynamic bound on the grid's row
+dimension was measured against this and is no faster (23.31 against
+23.43 ms at 5 rows, 11.75 against 10.14 at 1), and leaves the dead
+blocks' outputs to be masked outside the kernel. Same view, 512-row
+dispatch, k = 128, one v5e (PR 30; parent -> this kernel, the real rows
+bit for bit the parent's):
+
+    real rows     bf16 k 128        bf16 k 32         int8 k 128
+    1             22.89 -> 10.14
+    5             36.16 -> 23.43    23.29 -> 10.51    30.17 -> 22.47
+    129          171.87 -> 163.43   (two blocks walked, two skipped)
+    512          618.03 -> 618.13   (no block is dead)
+
+What is left of a small dispatch is its one live block: its own pass
+over the view and about 600 folds a real row at 6.07 us.
 
 The kernel also scores QUANTIZED item matrices (int8 rows + per-row f32
 scales, ops/transfer.py QuantizedMatrix): the int8 stream halves the
@@ -94,10 +121,13 @@ scales (order-invariant per row) multiply the returned values after the
 kernel. The serving tier re-ranks surviving candidates in f32 either
 way (apps/als/serving.py _rerank_exact).
 
-Layout: grid (B-blocks, I-blocks) with the item dimension innermost; the
-running top-k scratch is (re)initialized at item-block 0 and written to the
-output block on every step (the final step's write wins). k is padded to
-the 128-lane tile internally and sliced by the wrapper.
+Layout: grid (B-blocks, I-blocks) with the item dimension innermost, so
+each row block is one pass over the item matrix; the real-row count
+rides ahead of the grid as a scalar-prefetch operand. A live block's
+running top-k scratch is (re)initialized at item-block 0 and written to
+the output block on every step (the final step's write wins); a dead
+block writes its filler at item-block 0 and nothing after. k is padded
+to the 128-lane tile internally and sliced by the wrapper.
 """
 
 from __future__ import annotations
@@ -205,135 +235,155 @@ def _split_top(av, ai, bv, bi):
 # ---------------------------------------------------------------------------
 
 def _topk_kernel(
-    *refs, block_i, n_items, k, quantized,
+    rows_ref, *refs, block_i, n_items, k, quantized,
 ):
     if quantized:
-        (xs_ref, y_hbm, scale_ref, vals_ref, idx_ref, folds_ref,
-         run_vals, run_idx, thr, group_scores, y_buf, sem, folded) = refs
+        (xs_ref, y_hbm, scale_ref, vals_ref, idx_ref, counts_ref,
+         run_vals, run_idx, thr, group_scores, y_buf, sem, counts) = refs
     else:
-        (xs_ref, y_hbm, vals_ref, idx_ref, folds_ref,
-         run_vals, run_idx, thr, group_scores, y_buf, sem, folded) = refs
+        (xs_ref, y_hbm, vals_ref, idx_ref, counts_ref,
+         run_vals, run_idx, thr, group_scores, y_buf, sem, counts) = refs
         scale_ref = None
     i = pl.program_id(1)
-    ni = pl.num_programs(1)
-    slot = jax.lax.rem(i, 2)
+    # rows_ref[0] leading rows of the query block are real (scalar
+    # prefetch): a row block past them holds the caller's padding alone
+    live = pl.program_id(0) * xs_ref.shape[0] < rows_ref[0]
 
-    def dma(s, chunk):
-        return pltpu.make_async_copy(
-            y_hbm.at[pl.ds(chunk * block_i, block_i)], y_buf.at[s], sem.at[s]
-        )
+    @pl.when(jnp.logical_not(live) & (i == 0))
+    def _dead():
+        # no DMA, no gate: the block's outputs are written once, here, and
+        # keep the defined filler through the block's other (empty) steps
+        vals_ref[:] = jnp.full_like(vals_ref, -jnp.inf)
+        idx_ref[:] = jnp.zeros_like(idx_ref)
+        counts_ref[:] = jnp.zeros_like(counts_ref)
 
-    @pl.when(i == 0)
-    def _init():
-        dma(0, 0).start()
-        run_vals[:] = jnp.full_like(run_vals, -jnp.inf)
-        run_idx[:] = jnp.zeros_like(run_idx)
-        thr[:] = jnp.full_like(thr, -jnp.inf)
-        folded[0] = 0
+    @pl.when(live)
+    def _live():
+        ni = pl.num_programs(1)
+        slot = jax.lax.rem(i, 2)
 
-    # prefetch block i+1 while block i computes: the double buffer
-    @pl.when(i + 1 < ni)
-    def _prefetch():
-        dma(jax.lax.rem(i + 1, 2), i + 1).start()
-
-    dma(slot, i).wait()
-
-    xs = xs_ref[:]
-    n_gate = group_scores.shape[1] // _LANE  # chunks behind one gate
-    lane = jax.lax.broadcasted_iota(jnp.int32, (xs.shape[0], _LANE), 1)
-    lane_g = jax.lax.broadcasted_iota(jnp.int32, group_scores.shape, 1)
-    # [Bb, K] x [n_gate * 128, K]^T on the MXU, contracting the feature
-    # axis of both (no materialized transpose)
-    contract = (((1,), (1,)), ((), ()))
-
-    def beats_kth(scores):
-        """Whether any score is above its row's running k-th. Strict `>`
-        is the kernel's own order: items stream in index order, so every
-        index still to come is above every index in the running list and
-        an equal score loses its tie."""
-        return jnp.max(jnp.where(scores > thr[:], 1.0, 0.0)) > 0.0
-
-    def fold_chunk(scores, col):
-        """Sort one chunk's scores ascending (28 stages) and fold them
-        into the descending running top-128 (1 + 7 stages)."""
-        cv, ci = _bitonic_sort(scores, col, descending=False)
-        nv, nidx = _bitonic_merge(
-            *_split_top(run_vals[:], run_idx[:], cv, ci), descending=True
-        )
-        run_vals[:] = nv
-        run_idx[:] = nidx
-        # every row's new k-th value, across all lanes
-        thr[:] = jnp.broadcast_to(
-            jnp.max(
-                jnp.where(lane == k - 1, nv, -jnp.inf), axis=1, keepdims=True
-            ),
-            nv.shape,
-        )
-        folded[0] = folded[0] + 1
-
-    def gate_group(g, carry):
-        """Score n_gate 128-item chunks of the block with one dot and
-        fold, in order, only those holding a score above some row's
-        running k-th (`thr`). A chunk that does not holds nothing that
-        precedes any row's k-th element, so folding it would leave lanes
-        [:k] of the running list as they are: it costs a compare. Lanes
-        past k go stale, and stay at or below the k-th, so a later merge
-        still has the exact top-k in its first k lanes. Loops, not
-        unrolled trees: the program stays one fold long whatever block_i
-        is, and the [Bb, block_i] score block never exists."""
-        c0 = g * n_gate
-        off = pl.multiple_of(c0 * _LANE, n_gate * _LANE)
-        y_g = y_buf[slot, pl.ds(off, n_gate * _LANE), :]
-        if scale_ref is not None:
-            # TRUE int8 path: queries arrive pre-quantized (wrapper,
-            # per-row scales), so the dot runs int8 x int8 -> int32 on
-            # the MXU — the 2x-rate mode the int8 MFU peak describes —
-            # exactly. Item scales multiply back in before selection
-            # (they reorder across rows); the QUERY scales do not:
-            # scaling a row by a positive constant never changes that
-            # row's top-k order, so the wrapper applies them to the
-            # returned values after the kernel.
-            scale_g = jnp.concatenate(
-                [scale_ref[pl.ds(c0 + j, 1), :] for j in range(n_gate)], axis=1
+        def dma(s, chunk):
+            return pltpu.make_async_copy(
+                y_hbm.at[pl.ds(chunk * block_i, block_i)], y_buf.at[s], sem.at[s]
             )
-            scores = jax.lax.dot_general(
-                xs, y_g, contract, preferred_element_type=jnp.int32
-            ).astype(jnp.float32) * scale_g
-        else:
-            scores = jax.lax.dot_general(
-                xs, y_g, contract, preferred_element_type=jnp.float32
+
+        @pl.when(i == 0)
+        def _init():
+            dma(0, 0).start()
+            run_vals[:] = jnp.full_like(run_vals, -jnp.inf)
+            run_idx[:] = jnp.zeros_like(run_idx)
+            thr[:] = jnp.full_like(thr, -jnp.inf)
+            counts[0] = 0
+            counts[1] = 0
+
+        # prefetch block i+1 while block i computes: the double buffer
+        @pl.when(i + 1 < ni)
+        def _prefetch():
+            dma(jax.lax.rem(i + 1, 2), i + 1).start()
+
+        dma(slot, i).wait()
+
+        xs = xs_ref[:]
+        n_gate = group_scores.shape[1] // _LANE  # chunks behind one gate
+        lane = jax.lax.broadcasted_iota(jnp.int32, (xs.shape[0], _LANE), 1)
+        lane_g = jax.lax.broadcasted_iota(jnp.int32, group_scores.shape, 1)
+        # [Bb, K] x [n_gate * 128, K]^T on the MXU, contracting the feature
+        # axis of both (no materialized transpose)
+        contract = (((1,), (1,)), ((), ()))
+
+        def beats_kth(scores):
+            """Whether any score is above its row's running k-th. Strict `>`
+            is the kernel's own order: items stream in index order, so every
+            index still to come is above every index in the running list and
+            an equal score loses its tie."""
+            return jnp.max(jnp.where(scores > thr[:], 1.0, 0.0)) > 0.0
+
+        def fold_chunk(scores, col):
+            """Sort one chunk's scores ascending (28 stages) and fold them
+            into the descending running top-128 (1 + 7 stages)."""
+            cv, ci = _bitonic_sort(scores, col, descending=False)
+            nv, nidx = _bitonic_merge(
+                *_split_top(run_vals[:], run_idx[:], cv, ci), descending=True
             )
-        col0 = i * block_i + off
-        scores = jnp.where(col0 + lane_g < n_items, scores, -jnp.inf)  # tail padding
-        # the gate is mostly a reduce to a scalar and a branch: one for
-        # the group, on the elementwise max of its chunks' scores, and
-        # one per chunk only inside a group that fired
-        best = scores[:, :_LANE]
-        for j in range(1, n_gate):
-            best = jnp.maximum(best, scores[:, j * _LANE:(j + 1) * _LANE])
+            run_vals[:] = nv
+            run_idx[:] = nidx
+            # every row's new k-th value, across all lanes
+            thr[:] = jnp.broadcast_to(
+                jnp.max(
+                    jnp.where(lane == k - 1, nv, -jnp.inf), axis=1, keepdims=True
+                ),
+                nv.shape,
+            )
+            counts[0] = counts[0] + 1
 
-        @pl.when(beats_kth(best))
-        def _walk():
-            group_scores[:] = scores
+        def gate_group(g, carry):
+            """Score n_gate 128-item chunks of the block with one dot and
+            fold, in order, only those holding a score above some row's
+            running k-th (`thr`). A chunk that does not holds nothing that
+            precedes any row's k-th element, so folding it would leave lanes
+            [:k] of the running list as they are: it costs a compare. Lanes
+            past k go stale, and stay at or below the k-th, so a later merge
+            still has the exact top-k in its first k lanes. Loops, not
+            unrolled trees: the program stays one fold long whatever block_i
+            is, and the [Bb, block_i] score block never exists."""
+            c0 = g * n_gate
+            off = pl.multiple_of(c0 * _LANE, n_gate * _LANE)
+            y_g = y_buf[slot, pl.ds(off, n_gate * _LANE), :]
+            if scale_ref is not None:
+                # TRUE int8 path: queries arrive pre-quantized (wrapper,
+                # per-row scales), so the dot runs int8 x int8 -> int32 on
+                # the MXU — the 2x-rate mode the int8 MFU peak describes —
+                # exactly. Item scales multiply back in before selection
+                # (they reorder across rows); the QUERY scales do not:
+                # scaling a row by a positive constant never changes that
+                # row's top-k order, so the wrapper applies them to the
+                # returned values after the kernel.
+                scale_g = jnp.concatenate(
+                    [scale_ref[pl.ds(c0 + j, 1), :] for j in range(n_gate)], axis=1
+                )
+                scores = jax.lax.dot_general(
+                    xs, y_g, contract, preferred_element_type=jnp.int32
+                ).astype(jnp.float32) * scale_g
+            else:
+                scores = jax.lax.dot_general(
+                    xs, y_g, contract, preferred_element_type=jnp.float32
+                )
+            col0 = i * block_i + off
+            scores = jnp.where(col0 + lane_g < n_items, scores, -jnp.inf)  # tail padding
+            # the gate is mostly a reduce to a scalar and a branch: one for
+            # the group, on the elementwise max of its chunks' scores, and
+            # one per chunk only inside a group that fired
+            best = scores[:, :_LANE]
+            for j in range(1, n_gate):
+                best = jnp.maximum(best, scores[:, j * _LANE:(j + 1) * _LANE])
 
-            def gate_chunk(j, carry):
-                at = pl.multiple_of(j * _LANE, _LANE)
-                s_j = group_scores[:, pl.ds(at, _LANE)]
+            @pl.when(beats_kth(best))
+            def _walk():
+                group_scores[:] = scores
 
-                @pl.when(beats_kth(s_j))
-                def _fold():
-                    fold_chunk(s_j, col0 + at + lane)
+                def gate_chunk(j, carry):
+                    at = pl.multiple_of(j * _LANE, _LANE)
+                    s_j = group_scores[:, pl.ds(at, _LANE)]
 
-                return carry
+                    @pl.when(beats_kth(s_j))
+                    def _fold():
+                        fold_chunk(s_j, col0 + at + lane)
 
-            jax.lax.fori_loop(0, n_gate, gate_chunk, 0)
+                    return carry
 
-        return carry
+                jax.lax.fori_loop(0, n_gate, gate_chunk, 0)
 
-    jax.lax.fori_loop(0, block_i // (n_gate * _LANE), gate_group, 0)
-    vals_ref[:] = run_vals[:]
-    idx_ref[:] = run_idx[:]
-    folds_ref[:] = jnp.full(folds_ref.shape, folded[0], jnp.int32)
+            return carry
+
+        jax.lax.fori_loop(0, block_i // (n_gate * _LANE), gate_group, 0)
+        counts[1] = counts[1] + block_i // _LANE
+        vals_ref[:] = run_vals[:]
+        idx_ref[:] = run_idx[:]
+        # lane 0: chunks folded, lane 1: chunks walked, by this row block
+        counts_ref[:] = jnp.where(
+            jax.lax.broadcasted_iota(jnp.int32, counts_ref.shape, 1) == 1,
+            counts[1], counts[0],
+        )
 
 
 def _pad_to(x, size, axis, value=0.0):
@@ -438,6 +488,17 @@ def item_block(
     return max(_LANE, min(_pow2_floor(block_i), _pow2_ceil(n_items)))
 
 
+def row_block(
+    n_queries: int, feat_pad: int, y_itemsize: int, block_b: int | None = None
+) -> int:
+    """The rows of one row block of a dispatch of `n_queries` query rows:
+    the tuned block (or the caller's), never more than the dispatch holds
+    above the 8-sublane tile."""
+    if block_b is None:
+        block_b = tuned_blocks(feat_pad, y_itemsize)[0]
+    return min(block_b, max(8, n_queries))
+
+
 def view_shape(n_rows: int, n_feat: int, dtype) -> tuple[int, int]:
     """The shape a resident [n_rows, n_feat] item matrix of `dtype` is
     stored in so the kernel reads it as it lies: features up to the lane
@@ -450,6 +511,16 @@ def view_shape(n_rows: int, n_feat: int, dtype) -> tuple[int, int]:
     feat_pad = lane_pad(n_feat)
     block_i = item_block(n_rows, feat_pad, jnp.dtype(dtype).itemsize)
     return -(-n_rows // block_i) * block_i, feat_pad
+
+
+def dispatch_grid(n_queries: int, y_shape, y_dtype) -> tuple[int, int]:
+    """(row blocks, 128-item chunks each of them walks) of one dispatch of
+    `n_queries` query rows at the tuned blocks over an item matrix of
+    `y_shape` and `y_dtype`: what the kernel's walked count is a multiple
+    of, so a caller can read the row blocks it walked out of it."""
+    feat_pad = lane_pad(y_shape[1])
+    block_b = row_block(n_queries, feat_pad, jnp.dtype(y_dtype).itemsize)
+    return -(-n_queries // block_b), view_shape(*y_shape, y_dtype)[0] // _LANE
 
 
 def autotune_blocks(
@@ -518,11 +589,14 @@ def quantize_queries(xs):
     ),
 )
 def _topk_pallas_jit(
-    xs, y, scales, *, k, n_items, block_b, block_i, quantized, interpret
+    xs, y, scales, rows, *, k, n_items, block_b, block_i, quantized, interpret
 ):
     """The kernel over an item matrix ALREADY in the shape it DMAs
     (view_shape): nothing the size of the catalog is copied here. Rows
-    at or past `n_items` are the caller's padding and never selected."""
+    at or past `n_items` are the caller's padding and never selected.
+    `rows` (int32 scalar, traced: one program whatever it holds) is how
+    many leading rows of `xs` are real; a row block past them is not
+    walked."""
     n_b = xs.shape[0]
     feat_pad = y.shape[1]
     if feat_pad % _LANE or y.shape[0] % block_i or xs.shape[1] > feat_pad:
@@ -546,8 +620,9 @@ def _topk_pallas_jit(
         _topk_kernel, block_i=block_i, n_items=n_items, k=k,
         quantized=quantized,
     )
+    # index maps take the prefetched scalar after the grid indices
     in_specs = [
-        pl.BlockSpec((block_b, feat_pad), lambda b, i: (b, 0)),
+        pl.BlockSpec((block_b, feat_pad), lambda b, i, rows: (b, 0)),
         # the item matrix stays in HBM: the kernel streams its own
         # double-buffered DMA blocks out of it
         pl.BlockSpec(memory_space=pl.ANY),
@@ -557,46 +632,47 @@ def _topk_pallas_jit(
         # one row of scales per 128-item chunk, so the kernel picks a
         # chunk's scales with a sublane index
         in_specs.append(
-            pl.BlockSpec((block_i // _LANE, _LANE), lambda b, i: (i, 0))
+            pl.BlockSpec((block_i // _LANE, _LANE), lambda b, i, rows: (i, 0))
         )
         operands.append(
             jnp.asarray(scales, dtype=jnp.float32).reshape(-1, _LANE)
         )
-    vals, idx, folds = pl.pallas_call(
+    vals, idx, counts = pl.pallas_call(
         kernel,
-        grid=(nb, ni),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block_b, _LANE), lambda b, i: (b, 0)),
-            pl.BlockSpec((block_b, _LANE), lambda b, i: (b, 0)),
-            # one fold count per row block, spread over an (8, 128) tile
-            pl.BlockSpec((8, _LANE), lambda b, i: (b, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nb, ni),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((block_b, _LANE), lambda b, i, rows: (b, 0)),
+                pl.BlockSpec((block_b, _LANE), lambda b, i, rows: (b, 0)),
+                # a row block's (folded, walked) chunk counts in lanes 0
+                # and 1 of an (8, 128) tile
+                pl.BlockSpec((8, _LANE), lambda b, i, rows: (b, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_b, _LANE), jnp.float32),
+                pltpu.VMEM((block_b, _LANE), jnp.int32),
+                pltpu.VMEM((block_b, _LANE), jnp.float32),
+                pltpu.VMEM((block_b, n_gate * _LANE), jnp.float32),
+                pltpu.VMEM((2, block_i, feat_pad), y.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2,), jnp.int32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((xs_p.shape[0], _LANE), jnp.float32),
             jax.ShapeDtypeStruct((xs_p.shape[0], _LANE), jnp.int32),
             jax.ShapeDtypeStruct((nb * 8, _LANE), jnp.int32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_b, _LANE), jnp.float32),
-            pltpu.VMEM((block_b, _LANE), jnp.int32),
-            pltpu.VMEM((block_b, _LANE), jnp.float32),
-            pltpu.VMEM((block_b, n_gate * _LANE), jnp.float32),
-            pltpu.VMEM((2, block_i, feat_pad), y.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SMEM((1,), jnp.int32),
-        ],
         interpret=interpret,
-    )(*operands)
+    )(rows.reshape(1), *operands)
     vals, idx = vals[:n_b, :k], idx[:n_b, :k]
     if quantized:
         # scale the selected values back into score units (sx > 0, so
         # -inf padding slots stay -inf)
         vals = vals * sx[:n_b, None]
-    chunks = jnp.stack(
-        [jnp.sum(folds[::8, 0]), jnp.int32(nb * ni * (block_i // _LANE))]
-    )
-    return vals, idx, chunks
+    return vals, idx, jnp.sum(counts[::8, :2], axis=0)
 
 
 def topk_dot_batch_pallas(
@@ -609,6 +685,7 @@ def topk_dot_batch_pallas(
     block_i: int | None = None,
     interpret: bool = False,
     counted: bool = False,
+    rows=None,
 ):
     """Top-k of xs @ y.T per row without materializing the score matrix.
 
@@ -628,8 +705,16 @@ def topk_dot_batch_pallas(
     only where the items are stored in ascending order of score for the
     rows asked about, and a chunk that folds costs about a tenth more
     than before the gate. counted=True appends an int32[2] array, (chunks
-    folded, chunks walked = row blocks x item chunks), so a caller can
-    see which case it is in.
+    folded, chunks walked = row blocks walked x item chunks), so a caller
+    can see which case it is in.
+
+    rows: how many leading rows of xs are real (an int, or an int32
+    scalar array; never a static argument, so every count shares one
+    compiled program); None means all of them. A block of block_b rows
+    that lies wholly past them is not walked: it starts no DMA, runs no
+    gate, counts no chunk, and returns (-inf, index 0) in every slot. The
+    rows before `rows` come back bit for bit as without it; so does the
+    rest of the block the last of them falls in.
 
     block_b/block_i default to the tuned table (`tuned_blocks`): the
     largest pow2 item block whose double-buffered stream + sort
@@ -642,19 +727,18 @@ def topk_dot_batch_pallas(
     n_items = y.shape[0]
     feat_pad = lane_pad(y.shape[1])
     itemsize = jnp.dtype(y.dtype).itemsize
-    if block_b is None:
-        block_b = tuned_blocks(feat_pad, itemsize)[0]
-    block_b = min(block_b, max(8, xs.shape[0]))
+    block_b = row_block(xs.shape[0], feat_pad, itemsize, block_b)
     block_i = item_block(n_items, feat_pad, itemsize, block_i)
     if y.shape[1] != feat_pad or n_items % block_i:
         # not a resident serving view (tests, tools, the trainer's
         # evaluation): pad here, outside the jitted call, once per call
-        rows = -(-n_items // block_i) * block_i
-        y = _pad_to(_pad_to(y, feat_pad, 1), rows, 0)
+        y_rows = -(-n_items // block_i) * block_i
+        y = _pad_to(_pad_to(y, feat_pad, 1), y_rows, 0)
         if scales is not None:
-            scales = _pad_to(jnp.asarray(scales, dtype=jnp.float32), rows, 0)
+            scales = _pad_to(jnp.asarray(scales, dtype=jnp.float32), y_rows, 0)
     vals, idx, chunks = _topk_pallas_jit(
         xs, y, scales,
+        jnp.asarray(xs.shape[0] if rows is None else rows, dtype=jnp.int32),
         k=k, n_items=n_items, block_b=block_b, block_i=block_i,
         quantized=scales is not None, interpret=interpret,
     )

@@ -136,12 +136,16 @@ def merge_topk_partials(partials, k: int):
     return _merge_stacked_jit(vals, idx, k=k)
 
 
-def topk_dot_batch_sharded(xs, sm, *, k: int, recall: float = 1.0):
+def topk_dot_batch_sharded(
+    xs, sm, *, k: int, recall: float = 1.0, rows=None
+):
     """Batched top-k over a ShardedMatrix: each shard scores its row
     slice with the normal kernel-selection path (ops.als.topk_dot_batch
     — fused Pallas on TPU, quantized/bf16 per the shard's dtype), with
     the query block placed on the shard's device, then the per-shard
-    partials merge exactly with indices rebased to global rows.
+    partials merge exactly with indices rebased to global rows. Every
+    shard is given the same `rows` (ops.als.topk_dot_batch: the real
+    leading rows of xs).
 
     Top-k is associative over row partitions, so the merge is exact;
     with recall < 1 each shard's partial reduce carries the same
@@ -161,7 +165,9 @@ def topk_dot_batch_sharded(xs, sm, *, k: int, recall: float = 1.0):
             continue  # an empty shard contributes no candidates
         dev = next(iter(shard.devices()), None)
         xs_s = xs if dev is None else jax.device_put(xs, dev)
-        v, i = topk_dot_batch(xs_s, shard, k=min(k, n_s), recall=recall)
+        v, i = topk_dot_batch(
+            xs_s, shard, k=min(k, n_s), recall=recall, rows=rows
+        )
         partials.append((v, i + sm.plan.lo(s)))
     t0 = time.monotonic()
     # host-side reduce: partials come back to the default device and the

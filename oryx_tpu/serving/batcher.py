@@ -107,7 +107,10 @@ from oryx_tpu.ops.als import PALLAS_TOPK_MAX_K
 # which bounds the result fetch and host trim below the 128 bucket's.
 K_BUCKETS = (16, 32, PALLAS_TOPK_MAX_K, 1024)
 
-MAX_BATCH = 4096  # rows per device dispatch (the bench-measured knee)
+# rows per device dispatch: the ladder's top rung. Not a measured knee
+# (none has been measured): the fused kernel's time is linear in the row
+# blocks it walks.
+MAX_BATCH = 4096
 
 # Queue-depth bound before the batcher sheds load (503 + Retry-After via
 # serving/app.ShedLoad) instead of queueing without limit. At the default
@@ -131,10 +134,14 @@ PROBE_INTERVAL = 20.0
 # k-bucket. The ladder was chosen on the belief that the scan is
 # HBM-bandwidth-bound in Y and so nearly flat in rows; PR 21's chip run
 # measured the fused kernel LINEAR in rows instead (236 ms at 512 rows,
-# 1876 ms at 4096, 1.31M x 50f), so a 513-request group pays for 4096 —
-# ROADMAP S3 re-chooses the ladder from the measured cost curve. On CPU
-# the sgemm is compute-bound per row: fine-grained pow2 padding keeps
-# wasted rows under 2x.
+# 1876 ms at 4096, 1.31M x 50f). Since PR 30 the kernel is told the
+# group's real row count and walks only the 128-row blocks that hold
+# one, so the padding costs the query upload and the result fetch, not
+# kernel time: a 513-request group pays for 5 row blocks, not 32, though
+# it still compiles and fetches the 4096-row shape. ROADMAP S2
+# re-chooses the ladder from the measured cost curve. On CPU the sgemm
+# is compute-bound per row: fine-grained pow2 padding keeps wasted rows
+# under 2x.
 BATCH_BUCKETS_ACCEL = (512, MAX_BATCH)
 
 
@@ -381,6 +388,10 @@ class TopKBatcher:
         # a chunk through to the sort network (ops/pallas_topk.py)
         self.chunks_folded = 0  # guarded-by: _lock (writes)
         self.chunks_total = 0  # guarded-by: _lock (writes)
+        # row blocks of the fused kernel's dispatches, and those of them it
+        # did not walk because they lie past the dispatch's real rows
+        self.row_blocks = 0  # guarded-by: _lock (writes)
+        self.row_blocks_skipped = 0  # guarded-by: _lock (writes)
         self.host_fallbacks = 0  # guarded-by: _lock (writes)
         self.device_failovers = 0  # guarded-by: _lock (writes)
         # analytic FLOPs dispatched to the device (2·B·I·F per group,
@@ -413,9 +424,17 @@ class TopKBatcher:
              "those holding a score above a row block's running k-th",
              lambda: float(self.chunks_folded)),
             ("oryx_topk_chunks",
-             "128-item chunks the fused top-k kernel walked (row blocks x "
-             "item chunks of its dispatches)",
+             "128-item chunks the fused top-k kernel walked (row blocks "
+             "walked x item chunks of its dispatches)",
              lambda: float(self.chunks_total)),
+            ("oryx_topk_row_blocks",
+             "row blocks of the fused top-k kernel's dispatches (padded "
+             "rows / the kernel's row block)",
+             lambda: float(self.row_blocks)),
+            ("oryx_topk_row_blocks_skipped",
+             "row blocks the fused top-k kernel did not walk: those past "
+             "the real rows of their dispatch",
+             lambda: float(self.row_blocks_skipped)),
             ("oryx_topk_mean_batch",
              "achieved mean coalesced batch size (coalesced/dispatches "
              "over the process lifetime; >1 means requests are sharing "
@@ -836,10 +855,12 @@ class TopKBatcher:
                             for cause, s in causes.items():
                                 _PA.record_idle_gap(cause, s)
                         # chunks: the fused kernel's (folded, walked)
-                        # item-chunk counts, None on every other path
+                        # item-chunk counts, None on every other path.
+                        # rows: the kernel walks no row block past the
+                        # group's b real rows
                         vals, idx, chunks = topk_dot_batch(
                             jnp.asarray(xs), y, k=kb, recall=recall,
-                            counted=True,
+                            counted=True, rows=b,
                         )
                         try:
                             vals.copy_to_host_async()
@@ -934,9 +955,16 @@ class TopKBatcher:
             with _TRACER.region("batcher.fetch", dispatch=n_disp):
                 vals = np.asarray(vals_dev)
                 idx = np.asarray(idx_dev)
-                folded = total = None
+                folded = total = blocks = skipped = None
                 if chunks_dev is not None:
                     folded, total = (int(c) for c in np.asarray(chunks_dev))
+                    # the kernel walks whole row blocks: its own count of
+                    # chunks walked says how many
+                    from oryx_tpu.ops.pallas_topk import dispatch_grid
+
+                    y = group[0].y
+                    blocks, per_block = dispatch_grid(padded, y.shape, y.dtype)
+                    skipped = blocks - total // per_block
                 t_fetch = time.monotonic()
             with _TRACER.region("batcher.distribute", dispatch=n_disp):
                 # results are on the host: the dispatch's device work +
@@ -950,6 +978,7 @@ class TopKBatcher:
                     t_start=t0, score_mode=mode,
                     dispatch=n_disp, k_bucket=kb,
                     chunks_folded=folded, chunks_total=total,
+                    row_blocks=blocks, row_blocks_skipped=skipped,
                 )
                 # the dispatch completed, so this shape's compile is done:
                 # drop its grace window and never grant it one again. Both
@@ -989,6 +1018,8 @@ class TopKBatcher:
                     if total is not None:
                         self.chunks_folded += folded
                         self.chunks_total += total
+                        self.row_blocks += blocks
+                        self.row_blocks_skipped += skipped
                     # result-distribution tail: host work the device idles
                     # behind (the host_serialize slice of the next gap)
                     self._gap_resolve += time.monotonic() - t_fetch

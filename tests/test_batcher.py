@@ -6,6 +6,7 @@ The batched path must be indistinguishable from per-request topk_dot calls
 """
 
 import threading
+import time
 
 import numpy as np
 import jax.numpy as jnp
@@ -97,6 +98,94 @@ def test_dispatch_groups_by_matrix_and_bucket(y):
         dvals, didx = _direct(p.vec, k_eff, p.y)
         assert list(idx) == list(didx)
         np.testing.assert_allclose(vals, dvals, rtol=1e-5)
+
+
+def test_launch_tells_the_kernel_how_many_rows_are_real(y, monkeypatch):
+    """Each group's dispatch carries rows = len(group) beside the padded
+    query block, whatever the block is padded to."""
+    from oryx_tpu.ops import als
+
+    seen = []
+    real_topk_dot_batch = als.topk_dot_batch
+
+    def recorder(xs, y, **kw):
+        seen.append((xs.shape[0], kw.get("rows"), kw.get("counted")))
+        return real_topk_dot_batch(xs, y, **kw)
+
+    monkeypatch.setattr(als, "topk_dot_batch", recorder)
+    rng = np.random.default_rng(6)
+    b = TopKBatcher()
+    reqs = [
+        _Pending(rng.normal(size=8).astype(np.float32), k, y, Future())
+        for k in (3, 4, 5, 40, 41)
+    ]
+    for item in b._launch(reqs):
+        b._resolve(item)
+    assert sorted(seen) == [(2, 2, True), (4, 3, True)]  # k-buckets 128 and 16
+    for p in reqs:
+        assert list(p.future.result(timeout=5)[1]) == list(_direct(p.vec, p.k, y)[1])
+
+
+@pytest.mark.parametrize("requests, live", [(3, 1), (129, 2), (512, 4)])
+def test_fused_dispatch_counts_the_row_blocks_the_kernel_skipped(
+    y, monkeypatch, requests, live
+):
+    """A dispatch of the accelerator's 512-row bucket through the fused
+    kernel (the Pallas interpreter standing in for the chip): the record and
+    the two counters read the kernel's own count of the row blocks it walked,
+    one of four for three requests."""
+    from oryx_tpu.common.metrics import get_registry
+    from oryx_tpu.common.perfstats import get_perfstats
+    from oryx_tpu.ops import als
+    from oryx_tpu.ops.pallas_topk import topk_dot_batch_pallas
+
+    def fused(xs, y, *, k, recall=1.0, counted=False, rows=None):
+        return topk_dot_batch_pallas(
+            xs, y, k=k, interpret=True, counted=counted, rows=rows
+        )
+
+    monkeypatch.setattr(als, "topk_dot_batch", fused)
+    b = TopKBatcher()
+    b.register_gauges()
+    b._peak_flops = None  # _note_device has run: the platform below stays
+    b._on_accel = True
+    rng = np.random.default_rng(requests)
+    reqs = [
+        _Pending(rng.normal(size=8).astype(np.float32), 10, y, Future())
+        for _ in range(requests)
+    ]
+    t_mark = time.monotonic()
+    for item in b._launch(reqs):
+        b._resolve(item)
+    (rec,) = [r for r in get_perfstats().records_since(t_mark) if r.kind == "serving"]
+    assert (rec.rows, rec.padded_rows) == (requests, 512)
+    assert (rec.row_blocks, rec.row_blocks_skipped) == (4, 4 - live)
+    # 200 items in one item block of 256: two chunks a row block walked
+    assert rec.chunks_total == 2 * live
+    args = rec.chrome_event(1)["args"]
+    assert (args["row_blocks"], args["row_blocks_skipped"]) == (4, 4 - live)
+    assert (b.row_blocks, b.row_blocks_skipped) == (4, 4 - live)
+    gauges = dict(
+        line.split() for line in get_registry().render_prometheus().splitlines()
+        if line.startswith("oryx_topk_row_blocks")
+    )
+    assert float(gauges["oryx_topk_row_blocks"]) == 4.0
+    assert float(gauges["oryx_topk_row_blocks_skipped"]) == float(4 - live)
+    for p in reqs[:: max(1, requests // 7)]:
+        assert list(p.future.result(timeout=5)[1]) == list(_direct(p.vec, 10, y)[1])
+
+
+def test_a_dispatch_off_the_fused_path_counts_no_row_blocks(y):
+    from oryx_tpu.common.perfstats import get_perfstats
+
+    b = TopKBatcher()
+    t_mark = time.monotonic()
+    b.submit(np.ones(8, dtype=np.float32), 10, y)
+    b.close()
+    (rec,) = [r for r in get_perfstats().records_since(t_mark) if r.kind == "serving"]
+    assert rec.row_blocks is None and rec.row_blocks_skipped is None
+    assert "row_blocks" not in rec.chrome_event(1)["args"]
+    assert (b.row_blocks, b.row_blocks_skipped) == (0, 0)
 
 
 def test_k_larger_than_items():

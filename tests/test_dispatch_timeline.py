@@ -152,9 +152,10 @@ def test_fused_dispatch_carries_the_kernels_fold_count(y, monkeypatch):
     assert (batcher.chunks_folded, batcher.chunks_total) == before
     assert _gauge("oryx_topk_chunks") == float(before[1])
 
-    def fused(xs, y, *, k, recall=1.0, counted=False):
+    def fused(xs, y, *, k, recall=1.0, counted=False, rows=None):
         return topk_dot_batch_pallas(
-            xs, y, k=k, block_b=8, block_i=128, interpret=True, counted=counted
+            xs, y, k=k, block_b=8, block_i=128, interpret=True, counted=counted,
+            rows=rows,
         )
 
     monkeypatch.setattr(als, "topk_dot_batch", fused)

@@ -158,7 +158,8 @@ def test_jitted_wrapper_pads_no_catalog(quantized):
         pt._topk_pallas_jit, k=32, n_items=rows, block_b=8, block_i=block_i,
         quantized=quantized, interpret=True,
     )
-    closed = jax.make_jaxpr(fn)(xs, y, scales)
+    real = jax.ShapeDtypeStruct((), jnp.int32)  # the count of real query rows
+    closed = jax.make_jaxpr(fn)(xs, y, scales, real)
     sized = _primitives_with_output_rows(closed.jaxpr, rows)
     sized += _primitives_with_output_rows(closed.jaxpr, rows // 128)  # the scales' tile
     assert "pad" not in sized and "concatenate" not in sized, sized
@@ -166,7 +167,7 @@ def test_jitted_wrapper_pads_no_catalog(quantized):
     # and an operand that is not in the kernel's shape is refused, not padded
     for bad in ((rows, 250), (rows - 128, width)):
         with pytest.raises(ValueError, match="kernel's shape"):
-            jax.make_jaxpr(fn)(xs, jax.ShapeDtypeStruct(bad, y.dtype), scales)
+            jax.make_jaxpr(fn)(xs, jax.ShapeDtypeStruct(bad, y.dtype), scales, real)
 
 
 def test_unaligned_callers_are_padded_outside_the_jitted_call():
@@ -216,7 +217,7 @@ def _parent_path(xs, y, kb, scales=None):
     if scales is not None:
         scales = jnp.pad(jnp.asarray(scales, jnp.float32), (0, rows - n_items))
     vals, idx, _chunks = pt._topk_pallas_jit(
-        xs, y_p, scales, k=kb, n_items=n_items, block_b=block_b,
+        xs, y_p, scales, jnp.int32(xs.shape[0]), k=kb, n_items=n_items, block_b=block_b,
         block_i=block_i, quantized=scales is not None, interpret=True,
     )
     return np.asarray(vals), np.asarray(idx)

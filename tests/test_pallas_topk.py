@@ -224,20 +224,27 @@ def test_kernel_lowers_for_tpu_at_serving_shapes(quantized):
     items, feats = row_capacity(1_000_000, 0.125), 50
     for rows in BATCH_BUCKETS_ACCEL:
         for k in (kb for kb in K_BUCKETS if kb <= PALLAS_TOPK_MAX_K):
+            # the count of real rows is an operand of the program (a
+            # scalar prefetched into SMEM), as the batcher passes it
+            real = jax.ShapeDtypeStruct((), jnp.int32)
             if quantized:
-                fn = lambda xs, q, sc: topk_dot_batch_pallas(  # noqa: E731
-                    xs, q, scales=sc, k=k
+                fn = lambda xs, q, sc, real: topk_dot_batch_pallas(  # noqa: E731
+                    xs, q, scales=sc, k=k, rows=real
                 )
                 args = (
                     jax.ShapeDtypeStruct((rows, feats), jnp.float32),
                     jax.ShapeDtypeStruct((items, feats), jnp.int8),
                     jax.ShapeDtypeStruct((items,), jnp.float32),
+                    real,
                 )
             else:
-                fn = lambda xs, y: topk_dot_batch_pallas(xs, y, k=k)  # noqa: E731
+                fn = lambda xs, y, real: topk_dot_batch_pallas(  # noqa: E731
+                    xs, y, k=k, rows=real
+                )
                 args = (
                     jax.ShapeDtypeStruct((rows, feats), jnp.bfloat16),
                     jax.ShapeDtypeStruct((items, feats), jnp.bfloat16),
+                    real,
                 )
             exported = export.export(jax.jit(fn), platforms=["tpu"])(*args)
             assert "tpu_custom_call" in exported.mlir_module()
@@ -380,3 +387,83 @@ def test_gated_int8_kernel_is_bit_identical_to_top_k_of_its_scores(k):
     assert np.array_equal(np.asarray(v), np.asarray(v_ref))
     folded, total = (int(c) for c in np.asarray(chunks))
     assert folded == _model_folds(scores, k, 8) < total
+
+
+# -- row blocks past the real rows are not walked (ISSUE 30) -------------------
+#
+# A 512-row query block as the batcher pads it: `rows` real rows, then zeros,
+# in four row blocks of 128 over 1,500 items (12 chunks in 3 item blocks).
+
+_REAL_ROWS = [1, 5, 128, 129, 300, 512]
+
+
+def _padded_dispatch(dtype):
+    """(xs of 512 real rows, item operands, plain scores of them): integer
+    factors, so both forms score exactly and are compared bit for bit."""
+    rng = np.random.default_rng(30)
+    if dtype == "int8":
+        q = rng.integers(-127, 128, size=(1500, 16)).astype(np.int8)
+        scale = rng.choice([0.25, 0.5, 1.0, 2.0], size=1500).astype(np.float32)
+        xs = _int_factors(rng, 512, 16, -127, 127)
+        xs[:, 0] = 127.0  # every real row quantizes to itself (scale 1)
+        scores = (xs.astype(np.int64) @ q.T.astype(np.int64)).astype(np.float32) * scale
+        return xs, dict(y=jnp.asarray(q), scales=jnp.asarray(scale)), scores
+    xs, y = _int_factors(rng, 512, 12), _int_factors(rng, 1500, 12)
+    return xs, dict(y=jnp.asarray(y, dtype=jnp.bfloat16)), xs @ y.T
+
+
+def _run_padded(xs, operands, rows, real=None):
+    x = xs.copy()
+    x[rows:] = 0.0
+    dtype = jnp.float32 if "scales" in operands else jnp.bfloat16
+    v, i, c = topk_dot_batch_pallas(
+        jnp.asarray(x, dtype=dtype), operands["y"], scales=operands.get("scales"),
+        k=32, block_i=512, interpret=True, counted=True, rows=real,
+    )
+    return np.asarray(v), np.asarray(i), [int(n) for n in np.asarray(c)]
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("rows", _REAL_ROWS)
+def test_row_blocks_past_the_real_rows_are_not_walked(rows, dtype):
+    xs, operands, scores = _padded_dispatch(dtype)
+    v, i, (folded, walked) = _run_padded(xs, operands, rows, real=rows)
+    v_all, i_all, (folded_all, walked_all) = _run_padded(xs, operands, rows)
+    # the real rows: what walking every block gives, and lax.top_k's answer
+    v_ref, i_ref = jax.lax.top_k(jnp.asarray(scores[:rows]), 32)
+    assert np.array_equal(v[:rows], v_all[:rows]) and np.array_equal(i[:rows], i_all[:rows])
+    assert np.array_equal(v[:rows], np.asarray(v_ref)) and np.array_equal(i[:rows], np.asarray(i_ref))
+    # a live block comes back whole (its zero rows included); a dead one holds
+    # the defined filler
+    live = -(-rows // 128)
+    assert np.array_equal(v[:live * 128], v_all[:live * 128])
+    assert np.array_equal(i[:live * 128], i_all[:live * 128])
+    assert np.all(np.isneginf(v[live * 128:])) and not i[live * 128:].any()
+    # the kernel's own counts: live blocks x 12 item chunks walked, and no
+    # fold of a dead block (a block of zero rows folds its first chunk alone)
+    assert (walked, walked_all) == (live * 12, 4 * 12)
+    padded = np.where(np.arange(512)[:, None] < rows, scores, 0.0)
+    assert folded == _model_folds(padded[:live * 128], 32, 128)
+    assert folded_all == folded + (4 - live) == _model_folds(padded, 32, 128)
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+def test_every_row_real_is_what_no_count_gives(dtype):
+    xs, operands, _ = _padded_dispatch(dtype)
+    counted = _run_padded(xs, operands, 512, real=512)
+    plain = _run_padded(xs, operands, 512)
+    assert all(np.array_equal(a, b) for a, b in zip(counted, plain))
+    # a count past the block, or none at all, walks every block too
+    assert _run_padded(xs, operands, 512, real=10_000)[2] == plain[2]
+    assert _run_padded(xs, operands, 512, real=0)[2] == [0, 0]
+
+
+def test_the_count_of_real_rows_is_traced_not_compiled_in():
+    from oryx_tpu.ops.pallas_topk import _topk_pallas_jit
+
+    xs, operands, _ = _padded_dispatch("bf16")
+    _run_padded(xs, operands, 3, real=3)
+    entries = _topk_pallas_jit._cache_size()
+    for real in (4, 200, np.int32(7), jnp.asarray(9), None):
+        _run_padded(xs, operands, 3, real=real)
+    assert _topk_pallas_jit._cache_size() == entries

@@ -284,3 +284,46 @@ def test_sharded_matrix_through_the_batcher():
         np.testing.assert_array_equal(np.asarray(idx), np.asarray(i0)[0])
     finally:
         b.close()
+
+
+@pytest.mark.parametrize("form", ["sharded", "sharded-int8", "chunked"])
+def test_every_shard_and_chunk_is_given_the_same_real_row_count(form, monkeypatch):
+    """`rows` (the real leading rows of the query block) reaches the fused
+    kernel of every shard and every chunk unchanged (ISSUE 30): each pays
+    for its live row blocks alone, and the merged real rows are what the
+    whole matrix gives. The Pallas interpreter stands in for the chip."""
+    from oryx_tpu.ops import als, pallas_topk
+    from oryx_tpu.ops.transfer import ChunkedMatrix
+
+    seen = []
+    real_kernel = pallas_topk.topk_dot_batch_pallas
+
+    def kernel(xs, y, **kw):
+        seen.append((int(y.shape[0]), kw.get("rows")))
+        return real_kernel(xs, y, interpret=True, **kw)
+
+    monkeypatch.setattr(pallas_topk, "topk_dot_batch_pallas", kernel)
+    monkeypatch.setattr(als, "_on_tpu", lambda a: True)
+    monkeypatch.setattr(als, "PALLAS_TOPK_MIN_ITEMS", 16)
+    rng = np.random.default_rng(12)
+    y = rng.integers(-9, 10, size=(300, 8)).astype(np.float32)
+    xs = np.zeros((24, 8), dtype=np.float32)
+    xs[:3] = rng.integers(-9, 10, size=(3, 8))
+    if form == "chunked":
+        view = ChunkedMatrix(
+            [staged_device_put(y[lo:lo + 100], dtype=jnp.bfloat16) for lo in (0, 100, 200)]
+        )
+    else:
+        view = sharded_device_put(
+            y, 4, **({"quantize": True} if form == "sharded-int8" else {"dtype": jnp.bfloat16})
+        )
+    parts = 3 if form == "chunked" else 4
+    v, i = topk_dot_batch(jnp.asarray(xs), view, k=10, rows=3)
+    assert len(seen) == parts and {rows for _, rows in seen} == {3}
+    v_all, i_all = topk_dot_batch(jnp.asarray(xs), view, k=10)
+    assert [rows for _, rows in seen[parts:]] == [None] * parts
+    np.testing.assert_array_equal(np.asarray(i)[:3], np.asarray(i_all)[:3])
+    np.testing.assert_array_equal(np.asarray(v)[:3], np.asarray(v_all)[:3])
+    if form != "sharded-int8":  # integer factors: the plain scores are exact
+        ref = jax.lax.top_k(jnp.asarray(xs[:3] @ y.T), 10)[1]
+        np.testing.assert_array_equal(np.asarray(i)[:3], np.asarray(ref))

@@ -87,6 +87,11 @@ def test_latency_slo_counts_slow_requests():
     slo.ensure_serving_slos(cfg)
     t = slo.tracker("serving-latency")
     h = get_registry().histogram("oryx_serving_request_seconds")
+    # samples the singleton took under another test's threshold count
+    # another series (tests/test_perfattr.py sets 1e-9: every request slow),
+    # and a window that starts at one reads 0.0 whatever happens here
+    with t._lock:
+        t._samples.clear()
     _gap()
     t.burn_rate(t.fast_s)  # baseline sample
     _gap()

@@ -96,6 +96,11 @@ REQUIRED_PERFATTR_FAMILIES = (
     # two is the next per-layer metric of the top-k kernel
     "oryx_topk_chunks_folded",
     "oryx_topk_chunks",
+    # how many of a dispatch's row blocks the kernel did not walk because
+    # they hold no request (ISSUE 30); a benchmark share of the two waits
+    # for a `benchmark` PR
+    "oryx_topk_row_blocks",
+    "oryx_topk_row_blocks_skipped",
 )
 
 
