@@ -143,19 +143,3 @@ class LocalitySensitiveHash:
             return np.arange(self.num_partitions, dtype=np.int64)
         how_many = int(self._prefix_for_bits[self.max_bits_differing])
         return self._by_popcount[:how_many] ^ main
-
-
-def measured_topn_recall(
-    got_ids, query_vec: np.ndarray, mat: np.ndarray, ids, k: int
-) -> float:
-    """|got ∩ exact top-k| / k for ONE query: the exact top-k is rescored
-    from the full matrix, so an LSH (or any approximate) answer's recall
-    is MEASURED, never assumed from a sample-rate or recall-target knob.
-    Used by the bench's LSH HTTP stage to exactly rescore a sample of its
-    own responses (mirrors the reference's eval of hash sampling)."""
-    scores = mat @ np.asarray(query_vec, dtype=np.float32)
-    kk = min(k, scores.shape[0])
-    top = np.argpartition(-scores, kk - 1)[:kk]
-    top = top[np.argsort(-scores[top], kind="stable")]
-    exact = {ids[int(j)] for j in top}
-    return len(set(got_ids) & exact) / max(1, kk)

@@ -28,8 +28,9 @@ from oryx_tpu.common.perfattr import current_ledger
 from oryx_tpu.common.tracing import current_span, get_tracer
 from oryx_tpu.ops.als import compute_updated_xu
 from oryx_tpu.apps.als.common import ALSConfig
-from oryx_tpu.serving.app import chain_future
+from oryx_tpu.serving.app import chain_future, configure_post_pool, post_pool
 from oryx_tpu.serving.batcher import TopKBatcher, cosine_scale, host_topk, select_topk
+from oryx_tpu.serving.viewsync import extend_view_ids, view_sync_metrics
 from oryx_tpu.apps.als.state import ALSState, apply_update_message
 
 log = logging.getLogger(__name__)
@@ -125,22 +126,6 @@ class SyncConfig:
         return SyncConfig(mode, headroom, frac, shards)
 
 
-# Sync metric families + dirty-delta id extension moved to the shared
-# serving/viewsync.py (the app-SPI split: the seq device view reports
-# into the same oryx_device_sync_* vocabulary). ALS-local aliases keep
-# every internal call site unchanged.
-from oryx_tpu.serving.viewsync import (  # noqa: E402 - after module setup
-    extend_view_ids as _extend_ids,
-    view_sync_metrics as _sync_metrics,
-)
-
-# Post-processing pool moved to serving/app.py (post_pool /
-# configure_post_pool) in the app-SPI split: every app whose endpoints
-# chain work off batcher futures shares it. ALS-local aliases kept for
-# existing importers.
-from oryx_tpu.serving.app import configure_post_pool, post_pool as _post_pool  # noqa: F401,E402
-
-
 class _LshPartitions:
     """Per-partition contiguous scoring blocks for the LSH host path:
     rows[p] maps block rows back to store rows, mats[p] is the contiguous
@@ -208,7 +193,7 @@ class ALSServingModel(ServingModel):
         self._resync_thread: threading.Thread | None = None  # guarded-by: _sync_lock (writes)
         self._resync_evt = threading.Event()
         self._stop = threading.Event()
-        # last completed resync, for bench/debug introspection:
+        # last completed resync, for test/debug introspection:
         # {kind, rows, bytes, seconds, version}
         self.last_resync: dict | None = None  # guarded-by: _sync_lock (writes)
         # LSH candidate subsampling (CPU-parity approximation; the TPU path
@@ -304,7 +289,7 @@ class ALSServingModel(ServingModel):
         self._partition_view = view
         self._partition_built_at = time.monotonic()
         dur = time.monotonic() - t0
-        _sync_metrics()[3].observe(dur)
+        view_sync_metrics()[3].observe(dur)
         tr = get_tracer()
         if tr.enabled:
             tr.record_interval(
@@ -539,7 +524,7 @@ class ALSServingModel(ServingModel):
             )
             from oryx_tpu.serving.viewsync import set_shard_rows
 
-            set_shard_rows(_sync_metrics()[4], y_dev.plan, n)
+            set_shard_rows(view_sync_metrics()[4], y_dev.plan, n)
             per_row = self.state.features * itemsize + (4 if quantize else 0)
             by_shard = {
                 s: y_dev.plan.size(s) * per_row
@@ -576,7 +561,7 @@ class ALSServingModel(ServingModel):
                      by_shard: dict[int, int] | None = None) -> None:
         from oryx_tpu.serving.viewsync import note_sync_bytes
 
-        m_bytes, m_secs, m_total = _sync_metrics()[:3]
+        m_bytes, m_secs, m_total = view_sync_metrics()[:3]
         note_sync_bytes(m_bytes, n_bytes, by_shard)
         m_secs.observe(seconds)
         m_total.inc(kind=kind)
@@ -703,7 +688,7 @@ class ALSServingModel(ServingModel):
         if delta.rows.size == 0:
             return True  # raced an already-applied version: nothing to do
         rows, mat_rows = delta.rows, delta.mat
-        ids = _extend_ids(ids, delta)
+        ids = extend_view_ids(ids, delta)
         if ids is None:
             return False
         # The host f32 mirror and cached norms update the SAME dirty rows
@@ -764,7 +749,7 @@ class ALSServingModel(ServingModel):
             else:
                 y_new = scatter_rows(y_dev, rows, mat_rows)
             if delta.n > n_old:
-                set_shard_rows(_sync_metrics()[4], y_dev.plan, delta.n)
+                set_shard_rows(view_sync_metrics()[4], y_dev.plan, delta.n)
         elif quantized:
             # quantize the dirty rows ONCE here (per-row scales are
             # independent — never a full requantization) so the unit view
@@ -871,7 +856,7 @@ class ALSServingModel(ServingModel):
             return True
         t0 = time.monotonic()
         rows, mat_rows = delta.rows, delta.mat
-        ids = _extend_ids(ids, delta)
+        ids = extend_view_ids(ids, delta)
         if ids is None:
             return False
         new_parts_of_dirty = self._lsh.indices_for(
@@ -1134,7 +1119,7 @@ class ALSServingModel(ServingModel):
         # serialize on the batcher dispatcher thread inside the watchdog
         # window, stalling the device pipeline and deadlocking any
         # rescorer that submits its own query
-        return chain_future(fut, post, executor=_post_pool())
+        return chain_future(fut, post, executor=post_pool())
 
     def get_user_vector(self, user: str) -> np.ndarray | None:
         return self.state.x.get(user)

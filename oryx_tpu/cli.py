@@ -320,16 +320,21 @@ def _apply_platform_env(config: Config | None = None) -> None:
 
 
 def _run_until_interrupt(layer) -> int:
-    stop = signal.getsignal(signal.SIGTERM)
-    signal.signal(signal.SIGTERM, lambda *_: layer.close())
+    def unwind(*_):
+        # like Ctrl-C: leave whatever start() or join the signal found the
+        # main thread in, so that close() runs once, below, and not inside
+        # a half-done start() whose later threads it would never see
+        raise KeyboardInterrupt
+
+    stop = signal.signal(signal.SIGTERM, unwind)
     try:
         layer.start()
         layer.await_termination()
     except KeyboardInterrupt:
         pass
     finally:
-        layer.close()
         signal.signal(signal.SIGTERM, stop)
+        layer.close()
     return 0
 
 

@@ -3,19 +3,16 @@ snapshot bundler — the fleet's black box.
 
 The in-memory observability built so far (tracing ring, perfstats
 dispatch ring, /metrics) dies with its process: when a replica is
-SIGKILLed mid update-storm, or an accel bench stage times out and the
-driver kills it, the evidence evaporates at exactly the moment it is
-needed (round 5's ``_bench_http_body``/``_bench_train_body`` failures on
-the chip are a bare ``error:`` string because nothing survived the
-kill). This module keeps the last seconds of
-STRUCTURED lifecycle evidence on disk, where a supervisor — or the bench
-driver, or an operator — can harvest it from the corpse:
+SIGKILLed mid update-storm the evidence evaporates at exactly the moment
+it is needed. This module keeps the last seconds of STRUCTURED lifecycle
+evidence on disk, where a supervisor or an operator can harvest it from
+the corpse:
 
 - ``FlightRecorder.record(kind=..., **fields)`` appends one JSONL event
   to a bounded segment ring under the flight dir (``oryx.monitoring.
   flight.dir``): ejections/readmissions, shed episodes, host-fallback
   dispatches, wedge transitions, generation adoptions, fault injections,
-  health up→degraded flips, bench stage phases. Every ``kind`` is
+  health up→degraded flips. Every ``kind`` is
   registered in ``EVENT_KINDS`` (the oryxlint ``flight-events`` rule
   holds call sites and the docs catalog to it) and every event is
   stamped with pid, wall time, and the fleet replica id — the same id
@@ -25,11 +22,11 @@ driver, or an operator — can harvest it from the corpse:
 - ``snapshot()`` bundles the recent event ring, finished tracing spans,
   the perfstats dispatch ring, a /metrics text snapshot, and the config
   fingerprint into ONE artifact file — triggered by ``GET
-  /debug/flight``, automatically on a healthz up→degraded transition,
-  and by bench stages on failure.
+  /debug/flight`` and automatically on a healthz up→degraded
+  transition.
 - ``harvest()`` packs a DEAD process's on-disk ring (the supervisor
-  calls it on a replica corpse before restarting it; the bench driver
-  calls it on a SIGKILLed stage) — crash-loop last words.
+  calls it on a replica corpse before restarting it) — crash-loop last
+  words.
 
 Recording is cheap (one locked JSONL append on rare lifecycle events;
 ``episode_s`` rate-limits bursty kinds like sheds) and ON by default:
@@ -65,7 +62,6 @@ EVENT_KINDS: dict[str, str] = {
     "health-degraded": "GET /healthz flipped up->degraded",
     "replica-death": "the fleet supervisor observed a replica corpse",
     "snapshot": "a flight snapshot bundle was written",
-    "bench-stage": "a bench stage/phase lifecycle marker",
     "quality-alarm": (
         "live model quality degraded: the quality SLO's fast burn rate "
         "crossed the alarm threshold while windowed live recall sat "
@@ -386,8 +382,8 @@ def read_events(flight_dir: str, limit: int = 0) -> list[dict]:
 def harvest(flight_dir: str, **meta) -> str | None:
     """Pack a (possibly dead) process's on-disk event ring into one
     harvest artifact under ``<flight_dir>/harvest/`` — the supervisor's
-    crash-loop-last-words path and the bench driver's timeout path. Works
-    on a corpse: reads only the segment files the dead process left.
+    crash-loop-last-words path. Works on a corpse: reads only the
+    segment files the dead process left.
     Returns the artifact path, or None when the dir never existed (the
     process died before recording anything)."""
     flight_dir = _strip_scheme(flight_dir)

@@ -1,13 +1,12 @@
 """Runtime device-performance accounting: per-dispatch cost records,
 live MFU, occupancy, and on-demand profile windows.
 
-Until now MFU and FLOP accounting lived only inside bench.py — a
-production process could be running at 0.9% MFU (the measured TPU
-serving figure) with nothing on /metrics saying so. This module promotes
-that accounting from bench-time to runtime: every device dispatch — a
-coalesced top-k group in the serving batcher, a train-scan chunk in the
-ALS builder — reports its analytic FLOPs (ops/flops.py), bytes moved,
-wall-clock, and padding occupancy into a process-wide ring of
+A production process could be running at 0.9% MFU with nothing on
+/metrics saying so. This module keeps the accounting at runtime: every
+device dispatch — a coalesced top-k group in the serving batcher, a
+train-scan chunk in the ALS builder — reports its analytic FLOPs
+(ops/flops.py), bytes moved, wall-clock, and padding occupancy into a
+process-wide ring of
 ``DispatchRecord``s, from which live gauges/histograms are derived:
 
 - ``oryx_device_mfu{kind}`` — achieved FLOP/s over the chip's dense

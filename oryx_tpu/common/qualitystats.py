@@ -2,10 +2,9 @@
 detection, and the per-process half of the per-generation scorecards.
 
 Every quality number the system had before this module was offline —
-bench stages and the nightly gates measure synthetic corpora, while the
-traffic actually being served was quality-blind
-(``lsh_measured_recall_at_10`` proved the assumed-0.95 LSH recall was
-really 0.49, and only because bench sampled its own responses). This
+the nightly gates measure synthetic corpora, while the traffic actually
+being served was quality-blind (an exact rescore of sampled responses
+showed the assumed-0.95 LSH recall was really 0.49). This
 module measures the model being served, on the traffic it serves:
 
 - **Shadow rescore sampling**: a config-gated fraction
@@ -19,7 +18,7 @@ module measures the model being served, on the traffic it serves:
   windowed ``oryx_live_recall_at_k{score_mode}`` gauge plus the
   ``oryx_live_score_margin`` histogram (relative score given up by the
   approximation, trace exemplars attached) — quantized/approx/LSH recall
-  becomes a runtime fact instead of a bench claim.
+  becomes a runtime fact instead of an offline claim.
 
 - **Input & prediction drift**: batch generations persist a compact
   ``TrainingProfile`` (item-popularity sketch, event rate, new-item
@@ -302,8 +301,7 @@ class QualityStats:
             "oryx_live_recall_at_k",
             "Windowed mean recall@k of shadow-rescored served responses "
             "against the exact host rescore, by serving score mode "
-            "(NaN until a sample lands in the window) — the runtime "
-            "counterpart of bench's measured-recall fields",
+            "(NaN until a sample lands in the window)",
             labeled=True,
         )
         h_margin = reg.histogram(
@@ -430,7 +428,7 @@ class QualityStats:
 
     def flush(self, timeout: float = 10.0) -> bool:
         """Wait until every accepted sample has been fully processed
-        (tests, chaos, bench — never the request path). Dropped samples
+        (tests, chaos — never the request path). Dropped samples
         never count as accepted, so a paused drain + overflow still
         flushes once unblocked."""
         deadline = time.monotonic() + timeout

@@ -51,7 +51,7 @@ def replica_overlays(
 
     Shared config stays shared (broker, topics, model dir); only identity
     and per-process resources differ per replica. Exposed as a function so
-    tests and the bench can build the exact child configs without spawning.
+    tests can build the exact child configs without spawning.
 
     ``shards`` (default ``oryx.fleet.shards``) is the fleet's SECOND
     scaling dimension: every replica serves its device view row-sharded

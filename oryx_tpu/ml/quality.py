@@ -1,9 +1,10 @@
-"""Shared build-and-evaluate harnesses: the bench's training stages and
-the nightly quality gates (tests/test_quality_gate.py) run the SAME
-code, so a silent quality regression in any trainer fails both.
+"""Shared build-and-evaluate harnesses: the nightly quality run
+(tools/quality_nightly.py) and the tier-1 quality gates
+(tests/test_quality_gate.py) run the SAME code, so a silent quality
+regression in any trainer fails both.
 
 - ALS: the bf16 singularity guard (ops/als.py _half_step jitter retry)
-  cannot silently regress between bench runs. Measures what
+  cannot silently regress between runs. Measures what
   BASELINE.json's north star asks for: end-to-end build wall-clock at a
   given interaction scale plus held-out mean-per-user AUC — with NaN
   factor rows surfaced as a first-class diagnostic.
@@ -15,7 +16,7 @@ code, so a silent quality regression in any trainer fails both.
 - Serving recall gate: the quantized (int8 + exact rescore) and approx
   (partial-reduce) score modes are measured for recall@k against the
   exact top-k on a standing synthetic corpus; either mode below
-  MIN_SCORE_MODE_RECALL fails the QUALITY bench — speed can never
+  MIN_SCORE_MODE_RECALL fails the nightly run and the gate — speed can never
   silently buy wrong answers.
 """
 
@@ -166,8 +167,8 @@ class RecallReport:
 
 def mean_recall_at_k(got_idx: np.ndarray, exact_idx: np.ndarray, k: int) -> float:
     """Mean per-query |top-k ∩ exact top-k| / k — the ONE recall
-    definition the gate and the bench's measured-recall fields share, so
-    the numbers they report can never drift in meaning."""
+    definition the gate and the nightly run share, so the numbers they
+    report can never drift in meaning."""
     return float(
         np.mean([
             len(set(map(int, g[:k])) & set(map(int, e[:k]))) / k

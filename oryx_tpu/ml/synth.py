@@ -1,13 +1,13 @@
-"""Synthetic MovieLens-shaped interaction data, shared by the bench and
-the Spark-MLlib baseline runner (tools/spark_baseline.py).
+"""Synthetic MovieLens-shaped interaction data for the ALS quality
+harness (ml/quality.py) and whatever measures the build.
 
-The bench host has no dataset egress, so the ALS north-star measurement
+No dataset ships with the repo, so the ALS north-star measurement
 (BASELINE.json: model-build wall-clock at MovieLens-25M scale) runs on
 data synthesized to the ML-25M shape: ~162k users x 59k items x 25M
 interactions, Zipf-skewed item popularity, log-normal user activity.
-Both the TPU build and the Spark baseline MUST consume this exact
-generator with the same seed — otherwise the speedup ratio compares two
-different problems.
+Anything the build is compared with MUST consume this exact generator
+with the same seed — otherwise the ratio compares two different
+problems.
 
 Planted latent structure: users and items carry genres and most of a
 user's interactions stay inside their genre. Without structure the

@@ -29,11 +29,11 @@ Second generation (PR 8), three changes over the first kernel:
   kernel issues its own double-buffered `pltpu.make_async_copy` DMAs
   into a 2-slot VMEM scratch, starting block i+1's copy before computing
   block i.
-- Blocks: `(block_b, block_i)` come from a per-(feature-pad, dtype)
-  table (`tuned_blocks`) sized against the VMEM budget and cached for
-  the process; `autotune_blocks` measures candidates on real hardware
-  and locks the winner into the same table (bench uses it; serving
-  inherits whatever the table holds at dispatch time).
+- Blocks: `(block_b, block_i)` are a pure function of the feature pad
+  and the item matrix's itemsize (`tuned_blocks`): the largest block
+  whose working set fits the VMEM budget. The shape a resident view is
+  stored in (`view_shape`) follows from it, so nothing in the process
+  can change it after an upload.
 
 The threshold gate (PR 26). Until PR 26 EVERY chunk paid the 36 stages,
 so the kernel was bound by the sort network, flat in k and features and
@@ -132,7 +132,6 @@ to the 128-lane tile internally and sliced by the wrapper.
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -147,7 +146,7 @@ _LANE = 128  # TPU lane tile; also the padded top-k slot width
 # of a group share; a group that fires tests its chunks one by one.
 _GATE_CHUNKS = 8
 
-# Scoped-VMEM working-set budget the block table sizes against (v5e
+# Scoped-VMEM working-set budget the block rule sizes against (v5e
 # exposes ~16 MB; leave headroom for the compiler's own temporaries).
 _VMEM_BUDGET_BYTES = 12 << 20
 
@@ -409,17 +408,9 @@ def lane_pad(n_feat: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# block tuning: per-(feature-pad, dtype) table, autotunable on hardware
+# block rule: (feature pad, itemsize) -> (block_b, block_i), from the VMEM
+# budget alone; view_shape stores a resident view by it
 # ---------------------------------------------------------------------------
-
-# (feat_pad, y-dtype itemsize) -> (block_b, block_i). Seeded lazily by the
-# VMEM-budget solver; overwritten by autotune_blocks' measured winners and
-# the ORYX_PALLAS_BLOCKS env override ("block_b,block_i"). Compile-time
-# cache: every topk_dot_batch_pallas call with default blocks consults it,
-# so one autotune pass retunes every later dispatch of that (f, dtype).
-_BLOCK_TABLE: dict[tuple[int, int], tuple[int, int]] = {}
-
-AUTOTUNE_BLOCK_I = (1024, 2048, 4096, 8192)
 
 
 def _working_set_bytes(
@@ -445,22 +436,11 @@ def _working_set_bytes(
 
 def tuned_blocks(feat_pad: int, y_itemsize: int) -> tuple[int, int]:
     """(block_b, block_i) for a feature pad + item-matrix itemsize: the
-    cached table entry if one exists (env override, autotune winner, or a
-    previous solve), else the largest pow2 block_i whose working set fits
-    the VMEM budget at block_b=128. int8 matrices (itemsize 1) stream
-    twice the rows of bf16 per byte, so their tuned block_i is larger."""
-    key = (int(feat_pad), int(y_itemsize))
-    hit = _BLOCK_TABLE.get(key)
-    if hit is not None:
-        return hit
-    env = os.environ.get("ORYX_PALLAS_BLOCKS")
-    if env:
-        try:
-            bb, bi = (int(t) for t in env.split(","))
-            _BLOCK_TABLE[key] = (bb, bi)
-            return bb, bi
-        except ValueError:
-            pass
+    largest pow2 block_i whose working set fits the VMEM budget at
+    block_b=128. A function of its arguments and nothing else: a resident
+    view is stored by it (`view_shape`) and `_topk_pallas_jit` refuses an
+    operand that is not. int8 matrices (itemsize 1) stream twice the rows
+    of bf16 per byte, so the same block_i is half the bytes."""
     block_b = 128
     block_i = 8192
     # >= 1024: a quantized block's scales are a [block_i/128, 128] f32
@@ -469,7 +449,6 @@ def tuned_blocks(feat_pad: int, y_itemsize: int) -> tuple[int, int]:
         block_b, block_i, feat_pad, y_itemsize
     ) > _VMEM_BUDGET_BYTES:
         block_i //= 2
-    _BLOCK_TABLE[key] = (block_b, block_i)
     return block_b, block_i
 
 
@@ -479,10 +458,10 @@ def item_block(
     """The item block one dispatch over `n_items` rows streams: the tuned
     block (or the caller's), a multiple of the lane tile for the chunk
     loop and pow2 so the compiled-shape count stays small. Non-pow2
-    requests round DOWN — an operator shrinking the block to dodge a
-    VMEM overflow must get at most what they asked for, never a
-    silently larger block — and never past the next pow2 of the row
-    count (no point padding the item axis beyond it)."""
+    requests round DOWN — a test or a probe shrinking the block (the
+    only callers that pass one) must get at most what it asked for,
+    never a silently larger block — and never past the next pow2 of the
+    row count (no point padding the item axis beyond it)."""
     if block_i is None:
         block_i = tuned_blocks(feat_pad, y_itemsize)[1]
     return max(_LANE, min(_pow2_floor(block_i), _pow2_ceil(n_items)))
@@ -521,48 +500,6 @@ def dispatch_grid(n_queries: int, y_shape, y_dtype) -> tuple[int, int]:
     feat_pad = lane_pad(y_shape[1])
     block_b = row_block(n_queries, feat_pad, jnp.dtype(y_dtype).itemsize)
     return -(-n_queries // block_b), view_shape(*y_shape, y_dtype)[0] // _LANE
-
-
-def autotune_blocks(
-    xs, y, *, k: int, scales=None, candidates=AUTOTUNE_BLOCK_I, iters: int = 5
-) -> tuple[int, int]:
-    """Measure candidate block_i values on the live backend and lock the
-    winner into the block table (keyed by this matrix's feature pad +
-    dtype, so every later default-block dispatch of the same shape class
-    uses it). Compiles each candidate once before timing. Meant for bench
-    and operator tooling — never called on a request path."""
-    import time as _time
-
-    import numpy as np
-
-    feat_pad = lane_pad(y.shape[1])
-    itemsize = jnp.dtype(y.dtype).itemsize
-    block_b = 128
-    best, best_ms = None, None
-    for bi in candidates:
-        if _working_set_bytes(block_b, bi, feat_pad, itemsize) > _VMEM_BUDGET_BYTES:
-            continue
-        # a candidate inside the VMEM budget that fails to compile or run
-        # is a kernel defect: it raises, it does not quietly lose
-        fn = lambda: topk_dot_batch_pallas(
-            xs, y, k=k, scales=scales, block_b=block_b, block_i=bi
-        )
-        jax.block_until_ready(fn())  # compile
-        t0 = _time.perf_counter()
-        r = None
-        for _ in range(iters):
-            r = fn()
-        np.asarray(r[0])
-        ms = (_time.perf_counter() - t0) / iters * 1000
-        if best_ms is None or ms < best_ms:
-            best, best_ms = bi, ms
-    if best is None:
-        raise ValueError(
-            f"no block_i candidate of {tuple(candidates)} fits the "
-            f"{_VMEM_BUDGET_BYTES}-byte VMEM budget at feature pad {feat_pad}"
-        )
-    _BLOCK_TABLE[(feat_pad, itemsize)] = (block_b, best)
-    return block_b, best
 
 
 # ---------------------------------------------------------------------------
@@ -716,7 +653,7 @@ def topk_dot_batch_pallas(
     rows before `rows` come back bit for bit as without it; so does the
     rest of the block the last of them falls in.
 
-    block_b/block_i default to the tuned table (`tuned_blocks`): the
+    block_b/block_i default to the block rule (`tuned_blocks`): the
     largest pow2 item block whose double-buffered stream + sort
     temporaries fit the scoped-VMEM budget. A kernel that fails to
     compile or run raises; ops.als.topk_path decides from shapes, before

@@ -235,7 +235,7 @@ def next_item_hit_rate(
 ) -> float:
     """Mean hit-rate@k over next-item examples: the fraction whose true
     next item lands in the model's top-k — the ONE definition the batch
-    eval, the quality gate, and the bench's seq stage all share. NaN when
+    eval and the quality gate share. NaN when
     there is nothing to evaluate."""
     n = int(contexts.shape[0])
     if n == 0:

@@ -65,7 +65,7 @@ REPO_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 def configure_compilation_cache(config: Config | None = None) -> str:
     """Turn on JAX's persistent compilation cache for this process and
     return its directory. Called by `cli batch|speed|serving`,
-    chip_smoke.py and the bench stage bodies before first JAX use, so
+    chip_smoke.py and benchmarks/run.py before first JAX use, so
     every start after the first skips its cold XLA compiles — the moral
     equivalent of the reference reusing a warm Spark context across
     generations.

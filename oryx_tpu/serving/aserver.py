@@ -251,6 +251,11 @@ class AsyncHTTPServer:
         # latency by the loop count
         pending = []
         for ls in self._loopstates:
+            if ls.thread is not None:
+                # a close that interrupts start(): let a loop that is
+                # still coming up arrive, or it is skipped here and
+                # joined in vain below
+                ls.started.wait(timeout=10)
             if ls.loop is not None and ls.loop.is_running():
                 pending.append(
                     (ls, asyncio.run_coroutine_threadsafe(
@@ -341,7 +346,9 @@ class AsyncHTTPServer:
             ls.started.set()
             loop.close()
             return
-        ls.started.set()
+        # set from inside the loop: whoever waited on it finds the loop
+        # running, which is what close() asks before it shuts one down
+        loop.call_soon(ls.started.set)
         try:
             loop.run_forever()
         finally:

@@ -572,7 +572,7 @@ class TopKBatcher:
         # queue-wait measures from here to the dispatcher picking the
         # batch up; the ledger is the submitting request's (thread-local,
         # installed by ServingApp.dispatch_nowait — None off the request
-        # path, e.g. bench/probe submits)
+        # path, e.g. a test's or a probe's submits)
         p.t_enq = time.monotonic()
         p.ledger = current_ledger()
         if p.ledger is not None:

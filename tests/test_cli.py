@@ -116,7 +116,53 @@ def test_serving_subprocess_round_trip(tmp_path):
         assert status == 200 and json.loads(body) == 2
     finally:
         proc.terminate()
-        proc.wait(timeout=10)
+        try:
+            # the round trip is the subject, not how fast a loaded host
+            # lets the child unwind: 10 s timed out under six workers
+            proc.wait(timeout=60)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            proc.wait(timeout=10)
+            raise
+
+
+def test_sigterm_during_start_unwinds_before_close():
+    """SIGTERM that finds the main thread inside start() must not run
+    close() there: the serving frontend answers from its first event loop
+    while start() is still bringing up the others, and a close() in the
+    middle left those running, so the process never exited (one run of
+    the round trip above in twenty under load)."""
+    import os
+    import signal
+    import threading
+
+    if threading.current_thread() is not threading.main_thread():
+        pytest.skip("signal handlers belong to the main thread")
+
+    class Layer:
+        def __init__(self):
+            self.in_start = False
+            self.closed_inside_start = []
+
+        def start(self):
+            self.in_start = True
+            try:
+                os.kill(os.getpid(), signal.SIGTERM)
+                time.sleep(0.2)  # the handler runs here, on this thread
+            finally:
+                self.in_start = False
+
+        def await_termination(self):
+            raise AssertionError("start() was not unwound")
+
+        def close(self):
+            self.closed_inside_start.append(self.in_start)
+
+    before = signal.getsignal(signal.SIGTERM)
+    layer = Layer()
+    assert cli._run_until_interrupt(layer) == 0
+    assert layer.closed_inside_start == [False]
+    assert signal.getsignal(signal.SIGTERM) is before
 
 
 def test_loadtest_command(tmp_path):
@@ -222,8 +268,8 @@ def test_loadtest_multiloop_smoke(tmp_path):
     """Tier-1 frontend-throughput smoke: an unpaced ~2s loadtest against
     an in-process MULTI-LOOP server must push real traffic with zero
     errors, and the report's post-run /metrics scrape must show more than
-    one event loop carrying it — a cheap canary so frontend-throughput
-    regressions fail here instead of only in bench.py."""
+    one event loop carrying it — a cheap canary so a frontend that
+    stopped fanning out fails here, without the chip."""
     import io
     import json as _json
     from contextlib import redirect_stdout

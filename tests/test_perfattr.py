@@ -608,31 +608,3 @@ def test_parse_metric_sample_edges():
     assert _parse_metric_sample("# HELP foo bar") is None
     assert _parse_metric_sample("foo{a=") is None
     assert _parse_metric_sample("foo nan_is_fine_but_words_are_not") is None
-
-
-# ---- bench phase heartbeats -------------------------------------------------
-
-
-def test_bench_flight_phase_records_prev_duration():
-    import bench
-
-    class Rec:
-        def __init__(self):
-            self.rows = []
-
-        def record(self, **fields):
-            self.rows.append(fields)
-
-    rec = Rec()
-    bench._STAGE_PHASE.pop("t-stage", None)
-    bench._flight_phase(rec, "t-stage", "alpha")
-    time.sleep(0.01)
-    bench._flight_phase(rec, "t-stage", "beta")
-    assert rec.rows[0] == {
-        "kind": "bench-stage", "stage": "t-stage", "phase": "alpha",
-    }
-    second = rec.rows[1]
-    assert second["phase"] == "beta"
-    assert second["prev_phase"] == "alpha"
-    assert second["prev_s"] >= 0.01
-    bench._STAGE_PHASE.pop("t-stage", None)

@@ -1,7 +1,6 @@
 """Runtime performance observability: perfstats records and live MFU,
-per-metric histogram buckets + exemplars, /debug/profile, the metric→
-trace exemplar path on both frontends, and the bench ratchet
-(tools/check_bench.py).
+per-metric histogram buckets + exemplars, /debug/profile, and the
+metric→trace exemplar path on both frontends.
 
 Includes the tier-1 acceptance smoke: under a traced load window,
 /metrics must report a non-null oryx_device_mfu and an
@@ -13,16 +12,11 @@ Perfetto-loadable artifact.
 import http.client
 import json
 import math
-import os
-import subprocess
-import sys
 import threading
 import time
 
 import numpy as np
 import pytest
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---- histogram buckets + exemplars ----------------------------------------
@@ -460,6 +454,10 @@ def test_perf_smoke_mfu_occupancy_profile(tmp_path):
     )
     manager = _als_manager(cfg)
     ps = get_perfstats()
+    # process-wide state an earlier test of this worker may have left: a
+    # device->host fallback zeroes the serving MFU gauge for a whole
+    # window, which is not this test's subject
+    ps._fallback_until.clear()
     t_mark = time.monotonic()
     # the process-global occupancy histogram is cumulative across tests:
     # the load window's contribution is measured as a sum/count DELTA
@@ -576,90 +574,3 @@ def test_debug_profile_gated_when_disabled(tmp_path):
             assert status == 403, body
     finally:
         _restore_tracer()
-
-
-# ---- bench ratchet (tools/check_bench.py) ---------------------------------
-
-
-def _run_check_bench(tmp_path, baseline: dict, current: dict):
-    bpath = tmp_path / "baseline.json"
-    cpath = tmp_path / "current.json"
-    bpath.write_text(json.dumps(baseline))
-    cpath.write_text(json.dumps(current))
-    return subprocess.run(
-        [
-            sys.executable, os.path.join(ROOT, "tools", "check_bench.py"),
-            "--baseline", str(bpath), "--current", str(cpath),
-        ],
-        capture_output=True, text=True, timeout=120,
-    )
-
-
-_RATCHET = {
-    "metrics": [
-        {"name": "kernel_mfu", "platform": "tpu", "baseline": 0.01,
-         "direction": "up", "tolerance": 0.1},
-        {"name": "latency_ms_p99", "platform": "tpu", "baseline": 100.0,
-         "direction": "down", "tolerance": 0.2},
-        {"name": "value", "platform": "cpu", "baseline": 100.0,
-         "direction": "up", "tolerance": 0.3},
-    ]
-}
-
-
-def test_check_bench_passes_within_tolerance(tmp_path):
-    proc = _run_check_bench(tmp_path, _RATCHET, {
-        "platform": "tpu", "kernel_mfu": 0.0095, "latency_ms_p99": 110.0,
-    })
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "ratchet ok" in proc.stdout
-    # the cpu-locked metric was skipped, not failed
-    assert "SKIP" in proc.stdout
-
-
-def test_check_bench_fails_on_regression(tmp_path):
-    proc = _run_check_bench(tmp_path, _RATCHET, {
-        "platform": "tpu", "kernel_mfu": 0.005, "latency_ms_p99": 50.0,
-    })
-    assert proc.returncode == 1, proc.stdout + proc.stderr
-    assert "kernel_mfu" in proc.stdout and "FAIL" in proc.stdout
-    assert "RATCHET FAILED" in proc.stderr
-
-
-def test_check_bench_fails_on_missing_metric(tmp_path):
-    proc = _run_check_bench(tmp_path, _RATCHET, {
-        "platform": "tpu", "kernel_mfu": 0.02,
-    })
-    assert proc.returncode == 1
-    assert "MISSING" in proc.stdout
-
-
-def test_check_bench_latency_ratchets_down(tmp_path):
-    proc = _run_check_bench(tmp_path, _RATCHET, {
-        "platform": "tpu", "kernel_mfu": 0.02, "latency_ms_p99": 130.0,
-    })
-    assert proc.returncode == 1
-    assert "latency_ms_p99" in proc.stdout
-
-
-def test_check_bench_pending_rows_report_but_never_fail(tmp_path):
-    """A "pending": true row (baseline declared ahead of its first banked
-    measurement — PR 8's retightened pallas_speedup and the new
-    score-mode metrics) must render loudly but fail nothing, whether the
-    metric is absent from the run or present below the future floor."""
-    ratchet = {
-        "metrics": [
-            {"name": "kernel_mfu", "platform": "tpu", "baseline": 0.01,
-             "direction": "up", "tolerance": 0.1},
-            {"name": "qps_quantized", "platform": "tpu", "baseline": 36000,
-             "direction": "up", "tolerance": 0.25, "pending": True},
-            {"name": "pallas_speedup", "platform": "tpu", "baseline": 3.0,
-             "direction": "up", "tolerance": 0.15, "pending": True},
-        ]
-    }
-    proc = _run_check_bench(tmp_path, ratchet, {
-        "platform": "tpu", "kernel_mfu": 0.02, "pallas_speedup": 1.94,
-    })
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert proc.stdout.count("PENDING") == 2
-    assert "ratchet ok" in proc.stdout

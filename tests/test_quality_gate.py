@@ -72,8 +72,8 @@ def test_quality_harness_smoke():
 
 # ---- RDF + k-means gates (round-3 verdict #5) ---------------------------
 # Floors calibrated on this host (2026-07-30, CPU, seeds noted inline);
-# each harness is the SAME code the bench's kmeans+rdf stage runs, so a
-# trainer regression fails both the gate and the bench artifact.
+# each harness is the SAME code tools/quality_nightly.py runs, so a
+# trainer regression fails both the gate and the nightly artifact.
 
 RDF_ACC_FLOOR = 0.88  # raised round 5 with the feature_subset=14 default.
 # Evidence: sqrt-auto measured 0.8813 at full covertype shape (2026-07-30,
@@ -106,7 +106,7 @@ def test_rdf_covertype_shape_accuracy_floor():
 
 @nightly
 def test_kmeans_planted_blob_floors():
-    """Planted Gaussian blobs at bench scale (reference eval strategies
+    """Planted Gaussian blobs at full scale (reference eval strategies
     KMeansUpdate.java:137-173). SSE within 5% of the generating centers
     and a healthy silhouette — the k-means|| reduction bug this gate was
     built against cost 1.7-4.2x SSE by losing whole blobs."""
@@ -179,7 +179,7 @@ def test_seq_next_item_hit_rate_floor():
 
 def test_seq_quality_harness_smoke():
     """Always-on toy-scale smoke of the seq gate harness (the same code
-    path bench's seq stage and the nightly gate run)."""
+    path the nightly gate runs)."""
     from oryx_tpu.common.rng import RandomManager
     from oryx_tpu.ml.quality import build_and_evaluate_seq
 

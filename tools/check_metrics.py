@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Static metric-name consistency check — thin wrapper (DEPRECATED entry
-point; the logic now lives in the oryxlint ``metric-docs`` and
-``bench-ratchet`` rules, tools/oryxlint/checkers/consistency.py, and
-runs with the rest of the static-analysis suite via
-``python -m tools.oryxlint``).
+point; the logic now lives in the oryxlint ``metric-docs`` rule,
+tools/oryxlint/checkers/consistency.py, and runs with the rest of the
+static-analysis suite via ``python -m tools.oryxlint``).
 
 Kept as a CLI because operators and older docs invoke it directly. The
 collector functions (``code_metric_names``, ``doc_metric_names``) are
@@ -14,9 +13,8 @@ the rule's behavior).
 
 Contract (unchanged): every ``oryx_``-prefixed string literal under
 ``oryx_tpu/`` matches ``^oryx_[a-z0-9_]+$`` and has a reference-table
-row in ``docs/observability.md`` (and vice versa); every metric name
-ratcheted in ``BASELINE_RATCHET.json`` still exists in ``bench.py``'s
-output vocabulary; the score-mode bench/doc vocabulary is present.
+row in ``docs/observability.md`` (and vice versa); the label names the
+docs must keep are present.
 
 Exit status 0 = consistent; 1 = drift (each problem printed on stderr).
 """
@@ -40,7 +38,6 @@ VALID_NAME = _rule.VALID_METRIC_NAME
 CODE_LITERAL = _rule.METRIC_LITERAL
 DOC_ROW = _rule.DOC_ROW
 IGNORE = _rule.METRIC_IGNORE
-REQUIRED_BENCH_FIELDS = _rule.REQUIRED_BENCH_FIELDS
 REQUIRED_DOC_TOKENS = _rule.REQUIRED_DOC_TOKENS
 REQUIRED_PERFATTR_FAMILIES = _rule.REQUIRED_PERFATTR_FAMILIES
 
@@ -58,15 +55,7 @@ def doc_metric_names() -> set[str]:
 
 
 def vocabulary_problems() -> list[str]:
-    import re
-
     problems = []
-    bench_text = (ROOT / "bench.py").read_text(encoding="utf-8")
-    for name in REQUIRED_BENCH_FIELDS:
-        if not re.search(rf'"{re.escape(name)}"', bench_text):
-            problems.append(
-                f"{name}: required bench vocabulary missing from bench.py"
-            )
     doc_text = DOC.read_text(encoding="utf-8")
     for tok in REQUIRED_DOC_TOKENS:
         if tok not in doc_text:
@@ -74,15 +63,6 @@ def vocabulary_problems() -> list[str]:
                 f"{tok}: required label name missing from docs/observability.md"
             )
     return problems
-
-
-def ratchet_problems() -> list[str]:
-    """Ratcheted names must exist in bench.py; stale pending rows fail
-    (tools/check_bench.stale_pending_problems) — rendered through the
-    oryxlint rule so both CLIs and the tier-1 lint agree."""
-    return [
-        f.message for f in _rule.ratchet_findings(ROOT)
-    ]
 
 
 def main() -> int:
@@ -93,7 +73,6 @@ def main() -> int:
     problems.extend(
         _rule.metric_doc_problems(code_metric_names(), doc_metric_names())
     )
-    problems.extend(ratchet_problems())
     problems.extend(vocabulary_problems())
     for p in problems:
         print(p, file=sys.stderr)

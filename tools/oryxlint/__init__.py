@@ -22,8 +22,6 @@ Checkers shipped (tools/oryxlint/checkers/):
   (both directions; absorbed tools/check_config.py)
 - ``metric-docs``            oryx_* metric names vs docs/observability.md
   (both directions; absorbed tools/check_metrics.py)
-- ``bench-ratchet``          BASELINE_RATCHET.json vocabulary + stale
-  ``pending`` rows vs banked bench artifacts
 - ``param-dropped``          a config value read into a variable must
   reach a sink on every path, interprocedurally
   (tools/oryxlint/dataflow.py value-flow engine)
@@ -32,7 +30,7 @@ Checkers shipped (tools/oryxlint/checkers/):
 - ``lock-order``             inverted lock-acquisition pairs and
   violations of the canonical order in tools/oryxlint/lockorder.toml
 - ``shard-topology``         half-wired shard-count surfaces (config
-  keys vs /healthz, ReplicaInfo, supervisor overlay, bench honesty)
+  keys vs /healthz, ReplicaInfo, supervisor overlay)
 
 Run ``python -m tools.oryxlint`` (``--changed`` for a git-diff-scoped
 fast pass, ``--json`` for machine consumption — each finding carries

@@ -1,5 +1,5 @@
 """Project-consistency checkers (rules ``config-keys``, ``metric-docs``,
-``bench-ratchet``, ``flight-events``).
+``flight-events``).
 
 These absorb the one-off tools this repo grew over PRs 4-8 into the
 checker SPI — the old entry points (tools/check_config.py,
@@ -12,11 +12,7 @@ tools/check_metrics.py) remain as thin CLI wrappers:
 - ``metric-docs``: every ``oryx_*`` metric name in code matches the
   naming contract and has a row in docs/observability.md, and every
   documented row still exists in code (the reverse docs rule) — plus the
-  score-mode bench/doc vocabulary.
-- ``bench-ratchet``: every metric locked in BASELINE_RATCHET.json still
-  exists in bench.py's output vocabulary, and no ``pending`` row has
-  outlived a banked artifact of its platform that measures it
-  (tools/check_bench.py owns that artifact scan).
+  label names the docs must keep.
 - ``flight-events``: every flight-recorder ``record(kind="...")`` call
   site uses a kind registered in the ``EVENT_KINDS`` catalog
   (oryx_tpu/common/flightrec.py), and every cataloged kind has a row in
@@ -60,22 +56,9 @@ DOC_ROW = re.compile(r"^\|\s*`(oryx_[^`]+)`", re.M)
 # Not metrics: the package's own name appears as a string in a few places.
 METRIC_IGNORE = {"oryx_tpu"}
 
-# Score-mode vocabulary (PR 8): bench fields the serving-mode claims ride
-# on, and the label key the batcher's dispatch records carry. PR 11 adds
-# the shard-scaling vocabulary (sharded top-k + measured train MFU) and
-# the per-shard sync label.
-REQUIRED_BENCH_FIELDS = (
-    "qps_quantized",
-    "approx_recall_at_10",
-    "quantized_recall_at_10",
-    "lsh_measured_recall_at_10",
-    # the live shadow-rescore sampler's runtime recall (ISSUE 15): bench
-    # http, tools/quality_nightly.py, and oryx_live_recall_at_k share
-    # this one vocabulary
-    "live_recall_at_10",
-    "shard_topk_scaling_2shard",
-    "train_mfu",
-)
+# Label names the metric reference must keep: the batcher's dispatch
+# records, the per-shard sync, the SLO signal, the request phase and the
+# compile cause are keyed on them.
 REQUIRED_DOC_TOKENS = ("score_mode", "shard", "signal", "phase", "cause")
 
 # Hot-path latency-attribution vocabulary (ISSUE 17): the perfattr
@@ -295,14 +278,6 @@ def metric_findings(
         ))
     for problem in perfattr_family_problems(set(code), doc_names):
         out.append(Finding(doc_rel, 1, "metric-docs", problem))
-    bench = root / "bench.py"
-    bench_text = bench.read_text(encoding="utf-8") if bench.exists() else ""
-    for name in REQUIRED_BENCH_FIELDS:
-        if not re.search(rf'"{re.escape(name)}"', bench_text):
-            out.append(Finding(
-                "bench.py", 1, "metric-docs",
-                f"{name}: required bench vocabulary missing from bench.py",
-            ))
     doc_text = doc.read_text(encoding="utf-8")
     for tok in REQUIRED_DOC_TOKENS:
         if tok not in doc_text:
@@ -393,54 +368,6 @@ def flight_findings(root: Path, project: Project | None = None) -> list[Finding]
     return out
 
 
-def ratchet_findings(root: Path) -> list[Finding]:
-    import json
-
-    ratchet = root / "BASELINE_RATCHET.json"
-    bench = root / "bench.py"
-    out: list[Finding] = []
-    if not ratchet.exists():
-        return [Finding("BASELINE_RATCHET.json", 1, "bench-ratchet", "missing")]
-    try:
-        metrics = json.loads(ratchet.read_text(encoding="utf-8"))["metrics"]
-    except (json.JSONDecodeError, KeyError, TypeError) as e:
-        return [Finding(
-            "BASELINE_RATCHET.json", 1, "bench-ratchet", f"unparseable ({e})"
-        )]
-    bench_text = bench.read_text(encoding="utf-8") if bench.exists() else ""
-    for m in metrics:
-        name = m.get("name")
-        if not name:
-            out.append(Finding(
-                "BASELINE_RATCHET.json", 1, "bench-ratchet",
-                f"metric entry without a name: {m}",
-            ))
-        elif not re.search(rf'"{re.escape(name)}"', bench_text):
-            out.append(Finding(
-                "BASELINE_RATCHET.json", 1, "bench-ratchet",
-                f"{name}: ratcheted but bench.py never emits a field of "
-                "that name — the ratchet would fail every run as 'missing'",
-            ))
-    # every pending row must record its declaring round, or the stale
-    # check below could never age it out
-    for m in metrics:
-        if m.get("pending") and not m.get("pending_since"):
-            out.append(Finding(
-                "BASELINE_RATCHET.json", 1, "bench-ratchet",
-                f"{m.get('name')}: pending row without pending_since — "
-                "record the declaring bench round so the flag can be "
-                "aged out once an artifact measures it",
-            ))
-    # stale `pending` rows: a banked artifact of the right platform now
-    # measures the metric, so the flag should have been removed by the PR
-    # that banked it (tools/check_bench.py owns the artifact scan)
-    from tools import check_bench
-
-    for problem in check_bench.stale_pending_problems(metrics, root=str(root)):
-        out.append(Finding("BASELINE_RATCHET.json", 1, "bench-ratchet", problem))
-    return out
-
-
 class ConsistencyChecker(Checker):
     name = "consistency"
     rules = {
@@ -452,11 +379,6 @@ class ConsistencyChecker(Checker):
             "oryx_* metric names must match the naming contract and stay "
             "in lockstep with docs/observability.md (both directions)"
         ),
-        "bench-ratchet": (
-            "BASELINE_RATCHET.json rows must exist in bench.py's output "
-            "vocabulary, and pending rows must not outlive a banked "
-            "artifact that measures them"
-        ),
         "flight-events": (
             "flight-recorder record(kind=...) call sites must use a kind "
             "registered in EVENT_KINDS, and the docs event catalog must "
@@ -465,7 +387,6 @@ class ConsistencyChecker(Checker):
     }
     severities = {
         "metric-docs": "warning",
-        "bench-ratchet": "warning",
         "flight-events": "warning",
     }
     fix_hints = {
@@ -476,11 +397,6 @@ class ConsistencyChecker(Checker):
         "metric-docs": (
             "add/remove the row in docs/observability.md so code and docs "
             "agree in both directions"
-        ),
-        "bench-ratchet": (
-            "update BASELINE_RATCHET.json: fix the metric name, add "
-            "pending_since, or lock the measured baseline and drop the "
-            "pending flag"
         ),
         "flight-events": (
             "register the kind in EVENT_KINDS "
@@ -497,6 +413,5 @@ class ConsistencyChecker(Checker):
         out: list[Finding] = []
         out.extend(config_findings(root, texts))
         out.extend(metric_findings(root, texts))
-        out.extend(ratchet_findings(root))
         out.extend(flight_findings(root, project))
         return out

@@ -14,8 +14,8 @@ parameter with the same every-path requirement, so a wrapper in the
 middle of the chain cannot absorb the value. ``# oryxlint: sink`` on a
 use line declares an intentional terminal read.
 
-Scope: modules under ``oryx_tpu/`` (bench/tools read config through ad
-hoc plumbing that is not long-lived wiring).
+Scope: modules under ``oryx_tpu/`` (tools read config through ad hoc
+plumbing that is not long-lived wiring).
 """
 
 from __future__ import annotations

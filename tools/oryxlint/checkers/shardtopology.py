@@ -1,14 +1,12 @@
 """Shard-topology vocabulary checker (rule ``shard-topology``).
 
-PR 11 wired the shard count through five surfaces — the serving view
+PR 11 wired the shard count through four surfaces — the serving view
 (``oryx.serving.api.sync.shard-count``), the fleet overlay
 (``oryx.fleet.shards``), the train mesh (``oryx.batch.train.shards``),
-the ``/healthz`` ``shards`` field the front's prober reads into
-``ReplicaInfo.shards`` (mis-sharded replicas get ejected), and the
-bench ``shard_devices`` honesty field. Each of those was hand-checked
-in review; a new shard-bearing surface that wires only some of them
-ships a replica the front cannot vet, or a bench claim nobody can
-audit.
+and the ``/healthz`` ``shards`` field the front's prober reads into
+``ReplicaInfo.shards`` (mis-sharded replicas get ejected). Each of those
+was hand-checked in review; a new shard-bearing surface that wires only
+some of them ships a replica the front cannot vet.
 
 The rule pins the vocabulary both ways:
 
@@ -16,8 +14,8 @@ The rule pins the vocabulary both ways:
   site (config key read somewhere + declared; healthz emits ``shards``
   next to its shard-count read; ``ReplicaInfo`` declares ``shards``;
   the front parses the probe body's ``shards``; the supervisor overlay
-  carries the sync key; bench.py carries ``shard_devices``) — a
-  half-unwired removal is as broken as a half-wired addition;
+  carries the sync key) — a half-unwired removal is as broken as a
+  half-wired addition;
 - every shard-shaped config key read anywhere (``*.shards`` /
   ``*.shard-count``) must be one of the known keys — a NEW shard
   surface fails loudly here until it is added to ``KNOWN_SHARD_KEYS``
@@ -58,16 +56,15 @@ class ShardTopologyChecker(Checker):
             "a shard-count surface is half-wired: a new shard config key "
             "outside the known vocabulary, or a known surface (healthz "
             "shards field, ReplicaInfo.shards, front probe parse, "
-            "supervisor overlay, bench shard_devices) has gone missing"
+            "supervisor overlay) has gone missing"
         ),
     }
     severities = {"shard-topology": "error"}
     fix_hints = {
         "shard-topology": (
             "wire the surface end to end — config key, /healthz shards, "
-            "ReplicaInfo.shards + front probe, supervisor overlay, bench "
-            "shard_devices — and register the key in "
-            "checkers/shardtopology.py KNOWN_SHARD_KEYS"
+            "ReplicaInfo.shards + front probe, supervisor overlay — and "
+            "register the key in checkers/shardtopology.py KNOWN_SHARD_KEYS"
         ),
     }
 
@@ -89,9 +86,9 @@ class ShardTopologyChecker(Checker):
                         rel, line, "shard-topology",
                         f"{m.group(1)}: shard-bearing config key outside "
                         "the known vocabulary — a new shard surface must "
-                        "wire /healthz shards, ReplicaInfo.shards, the "
-                        "supervisor overlay, and bench shard_devices, then "
-                        "register in KNOWN_SHARD_KEYS",
+                        "wire /healthz shards, ReplicaInfo.shards and the "
+                        "supervisor overlay, then register in "
+                        "KNOWN_SHARD_KEYS",
                     ))
 
         # 2) known keys must still be read somewhere (only when the tree
@@ -140,15 +137,6 @@ class ShardTopologyChecker(Checker):
                     "reads oryx.fleet.shards but never overlays "
                     "oryx.serving.api.sync.shard-count onto replicas — "
                     "the fleet knob would be a silent no-op on every child",
-                ))
-        bench = project.root / "bench.py"
-        if bench.exists() and reads:
-            if '"shard_devices"' not in bench.read_text(encoding="utf-8"):
-                findings.append(Finding(
-                    "bench.py", 1, "shard-topology",
-                    "shard vocabulary in the tree but bench.py lost the "
-                    "shard_devices honesty field — shard-scaling claims "
-                    "become unauditable",
                 ))
         return findings
 

@@ -63,8 +63,8 @@ fi
 # ruff is optional in the minimal container; the gate runs wherever it
 # exists (dev laptops, CI images with the full toolchain)
 if python -m ruff --version >/dev/null 2>&1; then
-    python -m ruff check oryx_tpu tools bench.py || exit 1
-    python -m ruff format --check oryx_tpu tools bench.py || exit 1
+    python -m ruff check oryx_tpu tools chip_smoke.py || exit 1
+    python -m ruff format --check oryx_tpu tools chip_smoke.py || exit 1
 else
     echo "precommit: ruff not installed; skipping lint/format gate"
 fi

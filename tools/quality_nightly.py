@@ -198,10 +198,8 @@ def main() -> int:
         "score_mode_recall",
         {
             # _rescored suffix: these measure the full serve pipeline
-            # (overfetch + exact f32 re-rank). bench.py's
-            # approx_recall_at_10/quantized_recall_at_10 are the RAW
-            # kernel selections at k — same helper, different pipeline;
-            # the names differ so the two artifacts can't be conflated
+            # (overfetch + exact f32 re-rank), not the RAW kernel
+            # selection at k
             "approx_recall_at_10_rescored": round(rr.recall_approx, 4),
             "quantized_recall_at_10_rescored": round(rr.recall_quantized, 4),
             "k": rr.k,
@@ -210,9 +208,9 @@ def main() -> int:
             "approx_recall_target": rr.approx_recall_target,
             # the RUNTIME sampler's numbers on the same class of corpus:
             # nightly and production share one recall vocabulary
-            # (oryx_live_recall_at_k == live_recall_at_10 here and in
-            # bench's http stage), so a nightly regression and a live
-            # pager fire on the same definition
+            # (oryx_live_recall_at_k == live_recall_at_10 here), so a
+            # nightly regression and a live pager fire on the same
+            # definition
             **live,
             "wall_s": round(time.perf_counter() - t0, 1),
         },
