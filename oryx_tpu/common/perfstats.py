@@ -75,16 +75,18 @@ class DispatchRecord:
     and its ``k_bucket``; kinds without them (train) leave both None. A
     dispatch through the fused top-k kernel carries the kernel's own count
     of the item chunks it folded and walked (ops/pallas_topk.py's
-    threshold gate), and the dispatch's row blocks beside those of them
-    the kernel did not walk (they lie past the real rows); every other
-    path leaves those four None."""
+    threshold gate), the dispatch's row blocks beside those of them the
+    kernel did not walk (they lie past the real rows), and the (8, 128)
+    sublane tiles its folds sorted (``fold_tiles``: 16 a fold of a whole
+    128-row block, fewer where the block holds few real rows); every
+    other path leaves those five None."""
 
     __slots__ = (
         "kind", "t_start", "wall_s", "flops", "bytes_moved",
         "rows", "padded_rows", "valid_rows", "capacity_rows",
         "occupancy", "trace_id", "score_mode", "seq",
         "dispatch", "k_bucket", "chunks_folded", "chunks_total",
-        "row_blocks", "row_blocks_skipped",
+        "row_blocks", "row_blocks_skipped", "fold_tiles",
     )
 
     def __init__(
@@ -106,6 +108,7 @@ class DispatchRecord:
         chunks_total: int | None = None,
         row_blocks: int | None = None,
         row_blocks_skipped: int | None = None,
+        fold_tiles: int | None = None,
     ):
         self.kind = kind
         self.t_start = t_start
@@ -137,6 +140,7 @@ class DispatchRecord:
         self.chunks_total = chunks_total
         self.row_blocks = row_blocks
         self.row_blocks_skipped = row_blocks_skipped
+        self.fold_tiles = fold_tiles
 
     def chrome_event(self, pid: int) -> dict:
         """This record as a Chrome trace-event `X` slice (Perfetto)."""
@@ -168,6 +172,7 @@ class DispatchRecord:
                 chunks_folded=self.chunks_folded, chunks_total=self.chunks_total,
                 row_blocks=self.row_blocks,
                 row_blocks_skipped=self.row_blocks_skipped,
+                fold_tiles=self.fold_tiles,
             )
         return event
 
@@ -284,6 +289,7 @@ class PerfStats:
         chunks_total: int | None = None,
         row_blocks: int | None = None,
         row_blocks_skipped: int | None = None,
+        fold_tiles: int | None = None,
     ) -> DispatchRecord:
         rec = DispatchRecord(
             kind,
@@ -291,7 +297,7 @@ class PerfStats:
             wall_s, flops, bytes_moved, rows, padded_rows, valid_rows,
             capacity_rows, trace_id, score_mode,
             dispatch, k_bucket, chunks_folded, chunks_total,
-            row_blocks, row_blocks_skipped,
+            row_blocks, row_blocks_skipped, fold_tiles,
         )
         rec.seq = next(self._seq)
         buf = self._buf

@@ -1831,13 +1831,15 @@ def topk_dot_batch(
     partials with the cross-shard bitonic merge (ops/shard_topk.py),
     selecting exactly the indices of the unsharded dispatch.
 
-    counted=True appends a third result: the fused kernel's int32[2]
+    counted=True appends a third result: the fused kernel's int32[3]
     device array (item chunks it folded, item chunks it walked — its
-    threshold gate, ops/pallas_topk.py), or None on every other path.
+    threshold gate — and the sublane tiles those folds sorted,
+    ops/pallas_topk.py), or None on every other path.
 
     rows: how many leading rows of xs are real, None for all. The fused
-    kernel does not walk a row block that lies past them and returns
-    filler there (ops/pallas_topk.py); every shard and chunk is given the
+    kernel does not walk a row block that lies past them, folds only the
+    sublane tiles that hold them, and returns filler for every row at or
+    past them (ops/pallas_topk.py); every shard and chunk is given the
     same count, and the XLA, approximate and host paths ignore it: rows
     past `rows` are the caller's padding on every path, and only the
     rows before them are the same on all.

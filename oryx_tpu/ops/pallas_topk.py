@@ -53,9 +53,10 @@ one by one. A skipped chunk holds nothing that precedes any row's k-th
 element, so the output is bit for bit what folding every chunk gives;
 lanes past k of the running list go stale and are never returned. The
 kernel counts the chunks it folds and the chunks it walks (two int32
-per row block); the wrapper returns (folded, walked) beside the results
-when asked (`counted=True`), and the batcher puts them on the
-DispatchRecord and into `oryx_topk_chunks_folded` / `oryx_topk_chunks`.
+per row block; a third since PR 32, below); the wrapper returns them
+beside the results when asked (`counted=True`), and the batcher puts
+them on the DispatchRecord and into `oryx_topk_chunks_folded` /
+`oryx_topk_chunks`.
 
 Measured on one v5e chip (PR 26; 512-row dispatch, k = 128, over a
 6,291,456 x 256 bf16 view holding 5,000,000 rows of standard-normal
@@ -111,6 +112,60 @@ bit for bit the parent's):
 What is left of a small dispatch is its one live block: its own pass
 over the view and about 600 folds a real row at 6.07 us.
 
+Live tiles (PR 32). A fold ran its 36 compare-exchange stages over the
+whole [128, 128] row block, sixteen (8, 128) sublane tiles of values and
+sixteen of indices, and the batcher's dispatches hold 1-10 real rows:
+fifteen of the sixteen tiles it sorted held zero rows. The kernel knows
+how many rows of a block are real (`rows`, above), so a fired chunk is
+now folded at the narrowest of the widths 1, 2, 4, 8 tiles or the whole
+block (`_FOLD_TILES`) that holds the block's live tiles: static,
+sublane-aligned slices of the score block, the running lists and `thr`,
+one `pl.when` a width, chosen from the count the kernel is given and
+never from an option (all five compile in 3-4 s). A row's running list
+changes only through that row's own scores and no row takes part in
+another's sort network, so the real rows come back bit for bit as
+before. A row at or past `rows` starts with `thr` = +inf, so it never
+fires the gate (a zero row used to fire once), and is written out as
+(-inf, index 0) whether its block is live or dead. The kernel counts the
+tiles its folds sort beside the chunks (a third int32 per row block):
+`oryx_topk_fold_tiles` over 16 x `oryx_topk_chunks_folded` is the share
+of a whole-block fold's work still done.
+
+The fold is a dependent chain, so its time is the chain's latency and
+not the tiles' count: one tile costs 3.0 us (83 ns, about 78 cycles, a
+stage) where sixteen cost 6.2 (170 cycles a stage), and the fired
+group's store and chunk tests add 0.65 us a fold. Same view, 512-row
+dispatch, one v5e (PR 32; parent -> this kernel, the real rows bit for
+bit the parent's, every other row the filler; the folded chunks are the
+parent's, the width is the tiles a fold sorts):
+
+    real rows     bf16 k 128         chunks folded   width   us a fold
+    1             10.16 ->   7.77          844          1      3.65
+    5             23.42 ->  15.25        2,878          1      3.65
+    8             32.12 ->  20.10        4,211          1      3.65
+    9             34.57 ->  22.08        4,595          2      3.78
+    17            51.63 ->  34.46        7,296          4      4.08
+    25            65.62 ->  43.19        9,545          4      4.03
+    33            77.34 ->  59.06       11,442          8      4.75
+    64           111.26 ->  83.99       17,038          8      4.65
+    127          153.40 -> 154.12       24,084         16      6.2
+    129          163.59 -> 161.99       24,972     16 and 1
+    512          618.32 -> 621.12       97,361         16      (+0.45 %)
+    5, bf16 k 32  10.52 ->   7.93          908          1
+    5, int8 k 128 22.47 ->  14.28        2,878          1
+    5, int8 k 32   9.43 ->   6.86          903          1
+
+Measured against it and not kept: folding the live tiles one by one in a
+`fori_loop` (the same 7.80 and 15.30 ms at 1 and 5 rows, but 2, 3 and 4
+tiles cost 6.7, 9.7 and 12.7 us a fold: nothing of one tile's chain
+overlaps the next's), and a tile testing its own eight rows against
+`thr` before it folds (0.6 us more a fold at one tile; 24.0 / 39.7 /
+55.3 / 71.2 ms at 9 / 17 / 25 / 33 rows, behind the widths; 131.6 ms at
+64 rows, 267.6 at 127 and 1,076.5 at 512, far behind the whole-block
+fold: sixteen reduces to a scalar a chunk cost more than the tiles they
+save). What is left of a one-row dispatch: the pass, 4.7 ms, and 844
+folds at 3.65 us.
+
 The kernel also scores QUANTIZED item matrices (int8 rows + per-row f32
 scales, ops/transfer.py QuantizedMatrix): the int8 stream halves the
 bf16 HBM traffic that dominates the scan, queries are per-row
@@ -125,8 +180,9 @@ Layout: grid (B-blocks, I-blocks) with the item dimension innermost, so
 each row block is one pass over the item matrix; the real-row count
 rides ahead of the grid as a scalar-prefetch operand. A live block's
 running top-k scratch is (re)initialized at item-block 0 and written to
-the output block on every step (the final step's write wins); a dead
-block writes its filler at item-block 0 and nothing after. k is padded
+the output block on every step (the final step's write wins), its rows
+past the real ones as filler; a dead block writes its filler at
+item-block 0 and nothing after. k is padded
 to the 128-lane tile internally and sliced by the wrapper.
 """
 
@@ -140,6 +196,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128  # TPU lane tile; also the padded top-k slot width
+_SUBLANE = 8  # rows of one float32 (8, 128) tile: the unit a fold works on
+
+# Widths, in sublane tiles, of the fold below the whole row block: a fired
+# chunk is folded at the narrowest width that holds the block's real rows.
+_FOLD_TILES = (1, 2, 4, 8)
 
 # 128-item chunks scored by one dot and tested by one gate (pow2). The
 # gate's cost is the reduce to a scalar and the branch, which the chunks
@@ -244,9 +305,20 @@ def _topk_kernel(
          run_vals, run_idx, thr, group_scores, y_buf, sem, counts) = refs
         scale_ref = None
     i = pl.program_id(1)
+    block_b = xs_ref.shape[0]
+    n_tiles = block_b // _SUBLANE
+    # the widths, in sublane tiles, a fold comes in: the whole block last
+    fold_widths = [w for w in _FOLD_TILES if w < n_tiles] + [n_tiles]
     # rows_ref[0] leading rows of the query block are real (scalar
-    # prefetch): a row block past them holds the caller's padding alone
-    live = pl.program_id(0) * xs_ref.shape[0] < rows_ref[0]
+    # prefetch): this row block holds live_rows of them, in its first
+    # live_tiles (8, 128) sublane tiles; past them is the caller's padding
+    live_rows = jnp.clip(rows_ref[0] - pl.program_id(0) * block_b, 0, block_b)
+    live_tiles = (live_rows + (_SUBLANE - 1)) // _SUBLANE
+    live = live_rows > 0
+
+    def is_real(n):
+        """[n, 128] mask of the block's first n rows: which are real."""
+        return jax.lax.broadcasted_iota(jnp.int32, (n, _LANE), 0) < live_rows
 
     @pl.when(jnp.logical_not(live) & (i == 0))
     def _dead():
@@ -271,9 +343,11 @@ def _topk_kernel(
             dma(0, 0).start()
             run_vals[:] = jnp.full_like(run_vals, -jnp.inf)
             run_idx[:] = jnp.zeros_like(run_idx)
-            thr[:] = jnp.full_like(thr, -jnp.inf)
+            # a padding row never fires the gate: nothing is above +inf
+            thr[:] = jnp.where(is_real(block_b), -jnp.inf, jnp.inf)
             counts[0] = 0
             counts[1] = 0
+            counts[2] = 0
 
         # prefetch block i+1 while block i computes: the double buffer
         @pl.when(i + 1 < ni)
@@ -284,7 +358,6 @@ def _topk_kernel(
 
         xs = xs_ref[:]
         n_gate = group_scores.shape[1] // _LANE  # chunks behind one gate
-        lane = jax.lax.broadcasted_iota(jnp.int32, (xs.shape[0], _LANE), 1)
         lane_g = jax.lax.broadcasted_iota(jnp.int32, group_scores.shape, 1)
         # [Bb, K] x [n_gate * 128, K]^T on the MXU, contracting the feature
         # axis of both (no materialized transpose)
@@ -297,23 +370,45 @@ def _topk_kernel(
             an equal score loses its tie."""
             return jnp.max(jnp.where(scores > thr[:], 1.0, 0.0)) > 0.0
 
-        def fold_chunk(scores, col):
+        def fold_rows(scores, col):
             """Sort one chunk's scores ascending (28 stages) and fold them
-            into the descending running top-128 (1 + 7 stages)."""
-            cv, ci = _bitonic_sort(scores, col, descending=False)
+            into the descending running top-128 (1 + 7 stages), for the
+            leading rows of the block that `scores` holds: a row's list
+            changes only through that row's own scores, so the rows left
+            out keep theirs."""
+            n = scores.shape[0]
+            rs = slice(0, n)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (n, _LANE), 1)
+            cv, ci = _bitonic_sort(scores, col + lane, descending=False)
             nv, nidx = _bitonic_merge(
-                *_split_top(run_vals[:], run_idx[:], cv, ci), descending=True
+                *_split_top(run_vals[rs, :], run_idx[rs, :], cv, ci),
+                descending=True,
             )
-            run_vals[:] = nv
-            run_idx[:] = nidx
-            # every row's new k-th value, across all lanes
-            thr[:] = jnp.broadcast_to(
-                jnp.max(
-                    jnp.where(lane == k - 1, nv, -jnp.inf), axis=1, keepdims=True
-                ),
-                nv.shape,
+            run_vals[rs, :] = nv
+            run_idx[rs, :] = nidx
+            # every real row's new k-th value, across all lanes
+            kth = jnp.max(
+                jnp.where(lane == k - 1, nv, -jnp.inf), axis=1, keepdims=True
             )
+            thr[rs, :] = jnp.where(
+                is_real(n), jnp.broadcast_to(kth, nv.shape), jnp.inf
+            )
+
+        def fold_chunk(s_j, col):
+            """Fold one chunk into the live sublane tiles of the block and
+            no others: the narrowest of the widths that holds them. The 36
+            stages are a dependent chain, so a fold's time is the chain's
+            latency plus a little for every tile that rides along; rows of
+            the width past the real ones are sorted and never returned."""
             counts[0] = counts[0] + 1
+            below = 0
+            for w in fold_widths:
+                def _fold(w=w):
+                    fold_rows(s_j[:w * _SUBLANE], col)
+                    counts[2] = counts[2] + w
+
+                pl.when((below < live_tiles) & (live_tiles <= w))(_fold)
+                below = w
 
         def gate_group(g, carry):
             """Score n_gate 128-item chunks of the block with one dot and
@@ -366,7 +461,7 @@ def _topk_kernel(
 
                     @pl.when(beats_kth(s_j))
                     def _fold():
-                        fold_chunk(s_j, col0 + at + lane)
+                        fold_chunk(s_j, col0 + at)
 
                     return carry
 
@@ -376,12 +471,15 @@ def _topk_kernel(
 
         jax.lax.fori_loop(0, block_i // (n_gate * _LANE), gate_group, 0)
         counts[1] = counts[1] + block_i // _LANE
-        vals_ref[:] = run_vals[:]
-        idx_ref[:] = run_idx[:]
-        # lane 0: chunks folded, lane 1: chunks walked, by this row block
+        # a padding row of a live block returns what a dead block does
+        real = is_real(block_b)
+        vals_ref[:] = jnp.where(real, run_vals[:], -jnp.inf)
+        idx_ref[:] = jnp.where(real, run_idx[:], 0)
+        # lane 0: chunks folded, lane 1: chunks walked, lane 2: sublane
+        # tiles folded, by this row block
+        lane_c = jax.lax.broadcasted_iota(jnp.int32, counts_ref.shape, 1)
         counts_ref[:] = jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, counts_ref.shape, 1) == 1,
-            counts[1], counts[0],
+            lane_c == 0, counts[0], jnp.where(lane_c == 1, counts[1], counts[2])
         )
 
 
@@ -471,11 +569,11 @@ def row_block(
     n_queries: int, feat_pad: int, y_itemsize: int, block_b: int | None = None
 ) -> int:
     """The rows of one row block of a dispatch of `n_queries` query rows:
-    the tuned block (or the caller's), never more than the dispatch holds
-    above the 8-sublane tile."""
+    the tuned block (or the caller's), never more than the dispatch
+    holds, in whole 8-row sublane tiles (the unit the kernel folds)."""
     if block_b is None:
         block_b = tuned_blocks(feat_pad, y_itemsize)[0]
-    return min(block_b, max(8, n_queries))
+    return -(-max(1, min(block_b, n_queries)) // _SUBLANE) * _SUBLANE
 
 
 def view_shape(n_rows: int, n_feat: int, dtype) -> tuple[int, int]:
@@ -533,7 +631,8 @@ def _topk_pallas_jit(
     at or past `n_items` are the caller's padding and never selected.
     `rows` (int32 scalar, traced: one program whatever it holds) is how
     many leading rows of `xs` are real; a row block past them is not
-    walked."""
+    walked, and a fold sorts the live sublane tiles of its block alone.
+    Third result: int32[3], (chunks folded, chunks walked, tiles folded)."""
     n_b = xs.shape[0]
     feat_pad = y.shape[1]
     if feat_pad % _LANE or y.shape[0] % block_i or xs.shape[1] > feat_pad:
@@ -583,8 +682,8 @@ def _topk_pallas_jit(
             out_specs=[
                 pl.BlockSpec((block_b, _LANE), lambda b, i, rows: (b, 0)),
                 pl.BlockSpec((block_b, _LANE), lambda b, i, rows: (b, 0)),
-                # a row block's (folded, walked) chunk counts in lanes 0
-                # and 1 of an (8, 128) tile
+                # a row block's (chunks folded, chunks walked, tiles
+                # folded) in lanes 0-2 of an (8, 128) tile
                 pl.BlockSpec((8, _LANE), lambda b, i, rows: (b, 0)),
             ],
             scratch_shapes=[
@@ -594,7 +693,7 @@ def _topk_pallas_jit(
                 pltpu.VMEM((block_b, n_gate * _LANE), jnp.float32),
                 pltpu.VMEM((2, block_i, feat_pad), y.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SMEM((2,), jnp.int32),
+                pltpu.SMEM((3,), jnp.int32),
             ],
         ),
         out_shape=[
@@ -609,7 +708,7 @@ def _topk_pallas_jit(
         # scale the selected values back into score units (sx > 0, so
         # -inf padding slots stay -inf)
         vals = vals * sx[:n_b, None]
-    return vals, idx, jnp.sum(counts[::8, :2], axis=0)
+    return vals, idx, jnp.sum(counts[::8, :3], axis=0)
 
 
 def topk_dot_batch_pallas(
@@ -641,17 +740,21 @@ def topk_dot_batch_pallas(
     a large catalog fold a few percent of the chunks, every chunk folds
     only where the items are stored in ascending order of score for the
     rows asked about, and a chunk that folds costs about a tenth more
-    than before the gate. counted=True appends an int32[2] array, (chunks
-    folded, chunks walked = row blocks walked x item chunks), so a caller
-    can see which case it is in.
+    than before the gate. counted=True appends an int32[3] array, (chunks
+    folded, chunks walked = row blocks walked x item chunks, sublane
+    tiles those folds sorted), so a caller can see which case it is in.
 
     rows: how many leading rows of xs are real (an int, or an int32
     scalar array; never a static argument, so every count shares one
-    compiled program); None means all of them. A block of block_b rows
-    that lies wholly past them is not walked: it starts no DMA, runs no
-    gate, counts no chunk, and returns (-inf, index 0) in every slot. The
-    rows before `rows` come back bit for bit as without it; so does the
-    rest of the block the last of them falls in.
+    compiled program); None means all of them. The rows before `rows`
+    come back bit for bit as without it; every row at or past `rows`
+    comes back (-inf, index 0) in every slot, whichever block it is in. A
+    block of block_b rows that lies wholly past them is not walked: it
+    starts no DMA, runs no gate and counts no chunk. In the block the
+    last real row falls in, the rows past it never fire the gate, and a
+    fired chunk is folded over the block's live 8-row sublane tiles alone
+    (at the narrowest of a few widths that holds them), so a dispatch of
+    a few requests in a 512-row block sorts 8 rows a fold, not 128.
 
     block_b/block_i default to the block rule (`tuned_blocks`): the
     largest pow2 item block whose double-buffered stream + sort

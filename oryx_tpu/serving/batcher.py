@@ -392,6 +392,9 @@ class TopKBatcher:
         # did not walk because they lie past the dispatch's real rows
         self.row_blocks = 0  # guarded-by: _lock (writes)
         self.row_blocks_skipped = 0  # guarded-by: _lock (writes)
+        # (8, 128) sublane tiles the kernel's folds sorted: 16 a fold of a
+        # whole 128-row block, 1 where the block holds one to eight requests
+        self.fold_tiles = 0  # guarded-by: _lock (writes)
         self.host_fallbacks = 0  # guarded-by: _lock (writes)
         self.device_failovers = 0  # guarded-by: _lock (writes)
         # analytic FLOPs dispatched to the device (2·B·I·F per group,
@@ -435,6 +438,11 @@ class TopKBatcher:
              "row blocks the fused top-k kernel did not walk: those past "
              "the real rows of their dispatch",
              lambda: float(self.row_blocks_skipped)),
+            ("oryx_topk_fold_tiles",
+             "(8, 128) sublane tiles the fused top-k kernel's folds sorted: "
+             "over 16 x oryx_topk_chunks_folded, the share of a whole-block "
+             "fold's work still done",
+             lambda: float(self.fold_tiles)),
             ("oryx_topk_mean_batch",
              "achieved mean coalesced batch size (coalesced/dispatches "
              "over the process lifetime; >1 means requests are sharing "
@@ -955,9 +963,9 @@ class TopKBatcher:
             with _TRACER.region("batcher.fetch", dispatch=n_disp):
                 vals = np.asarray(vals_dev)
                 idx = np.asarray(idx_dev)
-                folded = total = blocks = skipped = None
+                folded = total = tiles = blocks = skipped = None
                 if chunks_dev is not None:
-                    folded, total = (int(c) for c in np.asarray(chunks_dev))
+                    folded, total, tiles = (int(c) for c in np.asarray(chunks_dev))
                     # the kernel walks whole row blocks: its own count of
                     # chunks walked says how many
                     from oryx_tpu.ops.pallas_topk import dispatch_grid
@@ -979,6 +987,7 @@ class TopKBatcher:
                     dispatch=n_disp, k_bucket=kb,
                     chunks_folded=folded, chunks_total=total,
                     row_blocks=blocks, row_blocks_skipped=skipped,
+                    fold_tiles=tiles,
                 )
                 # the dispatch completed, so this shape's compile is done:
                 # drop its grace window and never grant it one again. Both
@@ -1020,6 +1029,7 @@ class TopKBatcher:
                         self.chunks_total += total
                         self.row_blocks += blocks
                         self.row_blocks_skipped += skipped
+                        self.fold_tiles += tiles
                     # result-distribution tail: host work the device idles
                     # behind (the host_serialize slice of the next gap)
                     self._gap_resolve += time.monotonic() - t_fetch

@@ -133,7 +133,9 @@ def test_fused_dispatch_counts_the_row_blocks_the_kernel_skipped(
     """A dispatch of the accelerator's 512-row bucket through the fused
     kernel (the Pallas interpreter standing in for the chip): the record and
     the two counters read the kernel's own count of the row blocks it walked,
-    one of four for three requests."""
+    one of four for three requests; and the record and `oryx_topk_fold_tiles`
+    carry the sublane tiles its folds sorted, one of a block's sixteen for
+    three requests (ISSUE 32)."""
     from oryx_tpu.common.metrics import get_registry
     from oryx_tpu.common.perfstats import get_perfstats
     from oryx_tpu.ops import als
@@ -165,12 +167,19 @@ def test_fused_dispatch_counts_the_row_blocks_the_kernel_skipped(
     args = rec.chrome_event(1)["args"]
     assert (args["row_blocks"], args["row_blocks_skipped"]) == (4, 4 - live)
     assert (b.row_blocks, b.row_blocks_skipped) == (4, 4 - live)
+    # 3 requests: every fold sorts the one live tile; 512: whole blocks of
+    # sixteen; 129: block 0 whole and block 1's one live tile
+    assert rec.chunks_folded >= live
+    lo, hi = {3: (1, 1), 129: (2, 15), 512: (16, 16)}[requests]
+    assert lo * rec.chunks_folded <= rec.fold_tiles <= hi * rec.chunks_folded
+    assert args["fold_tiles"] == b.fold_tiles == rec.fold_tiles
     gauges = dict(
         line.split() for line in get_registry().render_prometheus().splitlines()
-        if line.startswith("oryx_topk_row_blocks")
+        if line.startswith(("oryx_topk_row_blocks", "oryx_topk_fold_tiles"))
     )
     assert float(gauges["oryx_topk_row_blocks"]) == 4.0
     assert float(gauges["oryx_topk_row_blocks_skipped"]) == float(4 - live)
+    assert float(gauges["oryx_topk_fold_tiles"]) == float(rec.fold_tiles)
     for p in reqs[:: max(1, requests // 7)]:
         assert list(p.future.result(timeout=5)[1]) == list(_direct(p.vec, 10, y)[1])
 
@@ -184,8 +193,9 @@ def test_a_dispatch_off_the_fused_path_counts_no_row_blocks(y):
     b.close()
     (rec,) = [r for r in get_perfstats().records_since(t_mark) if r.kind == "serving"]
     assert rec.row_blocks is None and rec.row_blocks_skipped is None
-    assert "row_blocks" not in rec.chrome_event(1)["args"]
-    assert (b.row_blocks, b.row_blocks_skipped) == (0, 0)
+    assert rec.fold_tiles is None
+    assert not {"row_blocks", "fold_tiles"} & set(rec.chrome_event(1)["args"])
+    assert (b.row_blocks, b.row_blocks_skipped, b.fold_tiles) == (0, 0, 0)
 
 
 def test_k_larger_than_items():

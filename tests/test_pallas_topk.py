@@ -249,6 +249,27 @@ def test_kernel_error_propagates_instead_of_falling_back(monkeypatch):
     )
 
 
+def _kernel_call(quantized, k, rows, items, feats, sds=jax.ShapeDtypeStruct):
+    """(fn, argument shapes) of one fused dispatch as the batcher makes it:
+    the count of real rows is an operand of the program (a scalar
+    prefetched into SMEM), never compiled in."""
+    real = sds((), jnp.int32)
+    if quantized:
+        fn = lambda xs, q, sc, real: topk_dot_batch_pallas(  # noqa: E731
+            xs, q, scales=sc, k=k, rows=real, counted=True
+        )
+        return fn, (
+            sds((rows, feats), jnp.float32), sds((items, feats), jnp.int8),
+            sds((items,), jnp.float32), real,
+        )
+    fn = lambda xs, y, real: topk_dot_batch_pallas(  # noqa: E731
+        xs, y, k=k, rows=real, counted=True
+    )
+    return fn, (
+        sds((rows, feats), jnp.bfloat16), sds((items, feats), jnp.bfloat16), real,
+    )
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 def test_kernel_lowers_for_tpu_at_serving_shapes(quantized):
     # every (row bucket, k bucket) the batcher dispatches to the fused
@@ -265,30 +286,76 @@ def test_kernel_lowers_for_tpu_at_serving_shapes(quantized):
     items, feats = row_capacity(1_000_000, 0.125), 50
     for rows in BATCH_BUCKETS_ACCEL:
         for k in (kb for kb in K_BUCKETS if kb <= PALLAS_TOPK_MAX_K):
-            # the count of real rows is an operand of the program (a
-            # scalar prefetched into SMEM), as the batcher passes it
-            real = jax.ShapeDtypeStruct((), jnp.int32)
-            if quantized:
-                fn = lambda xs, q, sc, real: topk_dot_batch_pallas(  # noqa: E731
-                    xs, q, scales=sc, k=k, rows=real
-                )
-                args = (
-                    jax.ShapeDtypeStruct((rows, feats), jnp.float32),
-                    jax.ShapeDtypeStruct((items, feats), jnp.int8),
-                    jax.ShapeDtypeStruct((items,), jnp.float32),
-                    real,
-                )
-            else:
-                fn = lambda xs, y, real: topk_dot_batch_pallas(  # noqa: E731
-                    xs, y, k=k, rows=real
-                )
-                args = (
-                    jax.ShapeDtypeStruct((rows, feats), jnp.bfloat16),
-                    jax.ShapeDtypeStruct((items, feats), jnp.bfloat16),
-                    real,
-                )
+            fn, args = _kernel_call(quantized, k, rows, items, feats)
             exported = export.export(jax.jit(fn), platforms=["tpu"])(*args)
             assert "tpu_custom_call" in exported.mlir_module()
+
+
+# -- compiled for a described v5e (no chip): what lowering alone cannot show ----
+#
+# libtpu compiles for a chip that is described and not attached. Only this
+# file describes one, inside a fixture, so every xdist worker collects the
+# same tests and one worker loads the library.
+
+_TOPOLOGY_ENV = {
+    "TPU_ACCELERATOR_TYPE": "v5litepod-4",
+    "TPU_WORKER_HOSTNAMES": "localhost",
+    "TPU_SKIP_MDS_QUERY": "1",
+    "TPU_LOG_DIR": "disabled",
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    before = {name: os.environ.get(name) for name in _TOPOLOGY_ENV}
+    os.environ.update({n: v for n, v in _TOPOLOGY_ENV.items() if before[n] is None})
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe one is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        for name, value in before.items():
+            if value is None:
+                os.environ.pop(name, None)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("form", ["bf16-k128", "bf16-k32", "int8-k128"])
+def test_kernel_compiles_for_the_v5e_at_the_benchmarks_shape(one_chip, form):
+    # the 512-row dispatch over the 5M x 250 catalog in its resident view, the
+    # count of real rows an operand: Mosaic must take every fold width (the
+    # sublane-aligned slices of the score block and of the running lists) and
+    # do it in seconds, not the minutes an unrolled merge tree took (PR 21)
+    import time
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    dtype, k = form.split("-k")
+    fn, args = _kernel_call(
+        dtype == "int8", int(k), 512, 6_291_456, 256,
+        sds=lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip),
+    )
+    # a program compiled for a described chip is written to the persistent
+    # cache but cannot be read back without one: keep it out
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        t0 = time.monotonic()
+        compiled = jax.jit(fn).lower(*args).compile()
+        took = time.monotonic() - t0
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert took < 60.0, f"the kernel took {took:.0f} s to compile"
+    # nothing the size of the catalog is copied inside the program
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
 # -- the threshold gate (ISSUE 26) ---------------------------------------------
@@ -346,22 +413,30 @@ GATE_CASES = [
 ]
 
 
-def _model_folds(scores, k, block_b):
+def _model_folds(scores, k, block_b, rows=None, tiles=False):
     """What the kernel's gate should count, walked in numpy: per row
-    block, the chunks holding a score above some row's running k-th."""
-    rows, n = scores.shape
-    pad = -(-rows // block_b) * block_b - rows
-    scores = np.concatenate([scores, np.zeros((pad, n), scores.dtype)])  # zero rows
-    folds = 0
-    for b in range(0, scores.shape[0], block_b):
-        blk = scores[b:b + block_b]
-        top = np.full((block_b, k), -np.inf, dtype=np.float32)  # descending
+    block, the chunks holding a score above some REAL row's running k-th
+    (the first `rows` rows of `scores` are real, None for all; a padding
+    row never fires and is never folded). tiles=True returns (chunks
+    folded, sublane tiles folded) by the kernel's rule: a fold sorts the
+    narrowest of its widths that holds the block's live (8-row) tiles."""
+    from oryx_tpu.ops.pallas_topk import _FOLD_TILES
+
+    n_rows, n = scores.shape
+    rows = n_rows if rows is None else min(rows, n_rows)
+    folds = tile_count = 0
+    for b in range(0, rows, block_b):
+        blk = scores[b:min(b + block_b, rows)]  # the block's real rows
+        live_tiles, n_tiles = -(-blk.shape[0] // 8), block_b // 8
+        width = min(w for w in (*_FOLD_TILES, n_tiles) if live_tiles <= w <= n_tiles)
+        top = np.full((blk.shape[0], k), -np.inf, dtype=np.float32)  # descending
         for c in range(0, n, 128):
             chunk = blk[:, c:c + 128]
             if (chunk > top[:, -1:]).any():
                 folds += 1
+                tile_count += width
                 top = -np.sort(-np.concatenate([top, chunk], axis=1), axis=1)[:, :k]
-    return folds
+    return (folds, tile_count) if tiles else folds
 
 
 @pytest.mark.parametrize("name", GATE_CASES)
@@ -375,12 +450,13 @@ def test_gated_kernel_is_bit_identical_to_top_k_of_the_plain_scores(name):
     )
     assert np.array_equal(np.asarray(i), np.asarray(i_ref))
     assert np.array_equal(np.asarray(v), np.asarray(v_ref))
-    # the kernel's own count of the chunks it folded, against the model's
-    folded, total = (int(c) for c in np.asarray(chunks))
+    # the kernel's own count of the chunks it folded, against the model's;
+    # every fold of an 8-row block sorts its one sublane tile
+    folded, total, tiles = (int(c) for c in np.asarray(chunks))
     row_blocks = -(-xs.shape[0] // 8)
     item_chunks = -(-y.shape[0] // block_i) * (block_i // 128)
     assert total == row_blocks * item_chunks
-    assert folded == _model_folds(scores, k, 8) <= total
+    assert tiles == folded == _model_folds(scores, k, 8) <= total
     real_chunks = -(-y.shape[0] // 128)
     if name == "ascending":
         assert folded == row_blocks * real_chunks  # the worst case: all of them
@@ -400,7 +476,7 @@ def test_gated_kernel_with_fewer_items_than_k_keeps_neg_inf_slots():
     assert np.array_equal(np.asarray(i)[:, :40], order)
     assert np.array_equal(np.asarray(v)[:, :40], np.take_along_axis(scores, order, axis=1))
     assert np.all(np.isneginf(np.asarray(v)[:, 40:]))
-    assert [int(c) for c in np.asarray(chunks)] == [1, 1]
+    assert [int(c) for c in np.asarray(chunks)] == [1, 1, 1]
 
 
 @pytest.mark.parametrize("k", [10, 128])
@@ -426,16 +502,21 @@ def test_gated_int8_kernel_is_bit_identical_to_top_k_of_its_scores(k):
     )
     assert np.array_equal(np.asarray(i), np.asarray(i_ref))
     assert np.array_equal(np.asarray(v), np.asarray(v_ref))
-    folded, total = (int(c) for c in np.asarray(chunks))
-    assert folded == _model_folds(scores, k, 8) < total
+    folded, total, tiles = (int(c) for c in np.asarray(chunks))
+    # the last of the two 8-row blocks holds the batch pad's rows 10-15
+    assert (folded, tiles) == _model_folds(scores, k, 8, tiles=True)
+    assert folded < total
 
 
-# -- row blocks past the real rows are not walked (ISSUE 30) -------------------
+# -- row blocks past the real rows are not walked (ISSUE 30), and a fold works
+# -- on the live sublane tiles of its row block alone (ISSUE 32) ---------------
 #
 # A 512-row query block as the batcher pads it: `rows` real rows, then zeros,
-# in four row blocks of 128 over 1,500 items (12 chunks in 3 item blocks).
+# in four row blocks of 128 (sixteen 8-row tiles each) over 1,500 items (12
+# chunks in 3 item blocks). The contract: the real rows bit for bit
+# `lax.top_k`'s, every row at or past `rows` (-inf, index 0).
 
-_REAL_ROWS = [1, 5, 128, 129, 300, 512]
+_REAL_ROWS = [1, 5, 7, 8, 9, 64, 127, 128, 129, 300, 511, 512]
 
 
 def _padded_dispatch(dtype):
@@ -453,39 +534,48 @@ def _padded_dispatch(dtype):
     return xs, dict(y=jnp.asarray(y, dtype=jnp.bfloat16)), xs @ y.T
 
 
-def _run_padded(xs, operands, rows, real=None):
+def _run_padded(xs, operands, rows, real=None, k=32):
     x = xs.copy()
     x[rows:] = 0.0
     dtype = jnp.float32 if "scales" in operands else jnp.bfloat16
     v, i, c = topk_dot_batch_pallas(
         jnp.asarray(x, dtype=dtype), operands["y"], scales=operands.get("scales"),
-        k=32, block_i=512, interpret=True, counted=True, rows=real,
+        k=k, block_i=512, interpret=True, counted=True, rows=real,
     )
     return np.asarray(v), np.asarray(i), [int(n) for n in np.asarray(c)]
+
+
+def _is_filler(v, i):
+    return bool(np.all(np.isneginf(v)) and not i.any())
 
 
 @pytest.mark.parametrize("dtype", ["bf16", "int8"])
 @pytest.mark.parametrize("rows", _REAL_ROWS)
 def test_row_blocks_past_the_real_rows_are_not_walked(rows, dtype):
     xs, operands, scores = _padded_dispatch(dtype)
-    v, i, (folded, walked) = _run_padded(xs, operands, rows, real=rows)
-    v_all, i_all, (folded_all, walked_all) = _run_padded(xs, operands, rows)
-    # the real rows: what walking every block gives, and lax.top_k's answer
+    v, i, (folded, walked, tiles) = _run_padded(xs, operands, rows, real=rows)
+    v_all, i_all, (folded_all, walked_all, tiles_all) = _run_padded(xs, operands, rows)
+    # the real rows: what calling every row real gives, and lax.top_k's answer
     v_ref, i_ref = jax.lax.top_k(jnp.asarray(scores[:rows]), 32)
     assert np.array_equal(v[:rows], v_all[:rows]) and np.array_equal(i[:rows], i_all[:rows])
     assert np.array_equal(v[:rows], np.asarray(v_ref)) and np.array_equal(i[:rows], np.asarray(i_ref))
-    # a live block comes back whole (its zero rows included); a dead one holds
-    # the defined filler
+    # every row at or past `rows` holds the one filler, in the block the last
+    # real row falls in as in the blocks past it
+    assert _is_filler(v[rows:], i[rows:])
+    # the kernel's own counts: live blocks x 12 item chunks walked; no fold for
+    # a padding row (a zero row CALLED real folds its first chunk alone); and
+    # the tiles its folds sorted, against the plain model of them
     live = -(-rows // 128)
-    assert np.array_equal(v[:live * 128], v_all[:live * 128])
-    assert np.array_equal(i[:live * 128], i_all[:live * 128])
-    assert np.all(np.isneginf(v[live * 128:])) and not i[live * 128:].any()
-    # the kernel's own counts: live blocks x 12 item chunks walked, and no
-    # fold of a dead block (a block of zero rows folds its first chunk alone)
     assert (walked, walked_all) == (live * 12, 4 * 12)
+    assert (folded, tiles) == _model_folds(scores, 32, 128, rows=rows, tiles=True)
     padded = np.where(np.arange(512)[:, None] < rows, scores, 0.0)
-    assert folded == _model_folds(padded[:live * 128], 32, 128)
-    assert folded_all == folded + (4 - live) == _model_folds(padded, 32, 128)
+    assert (folded_all, tiles_all) == _model_folds(padded, 32, 128, tiles=True)
+    assert tiles_all == 16 * folded_all  # every row real: whole blocks alone
+    assert folded_all == folded + (4 - live)  # a block of zero rows folds once
+    if rows < 128:  # the narrowest width over the live tiles of block 0
+        assert tiles == folded * {1: 1, 5: 1, 7: 1, 8: 1, 9: 2, 64: 8, 127: 16}[rows]
+    if rows == 129:
+        assert folded < tiles < 16 * folded  # block 0 whole, block 1 one tile
 
 
 @pytest.mark.parametrize("dtype", ["bf16", "int8"])
@@ -496,7 +586,53 @@ def test_every_row_real_is_what_no_count_gives(dtype):
     assert all(np.array_equal(a, b) for a, b in zip(counted, plain))
     # a count past the block, or none at all, walks every block too
     assert _run_padded(xs, operands, 512, real=10_000)[2] == plain[2]
-    assert _run_padded(xs, operands, 512, real=0)[2] == [0, 0]
+    # no real row at all: nothing is walked or folded, every row is filler
+    v, i, counts = _run_padded(xs, operands, 512, real=0)
+    assert counts == [0, 0, 0] and _is_filler(v, i)
+
+
+@pytest.mark.parametrize("rows", [1, 8, 9, 40, 128, 136])
+def test_live_tiles_in_the_worst_case_every_chunk_folds(rows):
+    # ascending scores along one feature: every chunk of real items holds a
+    # new best for every real row, so every chunk folds, in every live block,
+    # and the result is still lax.top_k's bit for bit
+    xs, y = _along_a_line(np.arange(1500), rows=rows)
+    xs = np.concatenate([xs, np.zeros((512 - rows, 1), np.float32)])
+    scores = xs[:rows] @ y.T
+    v, i, (folded, walked, tiles) = (np.asarray(a) for a in topk_dot_batch_pallas(
+        jnp.asarray(xs), jnp.asarray(y), k=128, block_i=512, interpret=True,
+        counted=True, rows=rows,
+    ))
+    v_ref, i_ref = jax.lax.top_k(jnp.asarray(scores), 128)
+    assert np.array_equal(v[:rows], np.asarray(v_ref)) and np.array_equal(i[:rows], np.asarray(i_ref))
+    assert _is_filler(v[rows:], i[rows:])
+    live = -(-rows // 128)
+    assert (int(folded), int(walked)) == (live * 12, live * 12)
+    assert (int(folded), int(tiles)) == _model_folds(scores, 128, 128, tiles=True)
+    # 1-8 rows: one tile a fold; 9: two; 40: five live, so the width of
+    # eight; a full block: all sixteen; 136: sixteen for block 0, one for 1
+    assert int(tiles) == 12 * {1: 1, 8: 1, 9: 2, 40: 8, 128: 16, 136: 17}[rows]
+
+
+@pytest.mark.parametrize("rows", [7, 8, 9, 10, 16, 17])
+def test_duplicate_scores_straddling_a_tile_boundary(rows):
+    # the same query in every row on both sides of the 8-row tile edges, over
+    # items whose every score appears five times: each tile sorts on its own,
+    # and every real row must still come back as lax.top_k's stable answer
+    rng = np.random.default_rng(32)
+    y = np.repeat(_int_factors(rng, 300, 12), 5, axis=0)
+    xs = np.zeros((512, 12), np.float32)
+    xs[:rows] = _int_factors(rng, 1, 12)
+    scores = xs[:rows] @ y.T
+    v, i, (folded, _, tiles) = (np.asarray(a) for a in topk_dot_batch_pallas(
+        jnp.asarray(xs), jnp.asarray(y), k=25, block_i=512, interpret=True,
+        counted=True, rows=rows,
+    ))
+    v_ref, i_ref = jax.lax.top_k(jnp.asarray(scores), 25)
+    assert np.array_equal(v[:rows], np.asarray(v_ref)) and np.array_equal(i[:rows], np.asarray(i_ref))
+    assert all(np.array_equal(i[r], i[0]) for r in range(rows))
+    assert _is_filler(v[rows:], i[rows:])
+    assert int(tiles) == int(folded) * {1: 1, 2: 2, 3: 4}[-(-rows // 8)]
 
 
 def test_the_count_of_real_rows_is_traced_not_compiled_in():
@@ -505,6 +641,7 @@ def test_the_count_of_real_rows_is_traced_not_compiled_in():
     xs, operands, _ = _padded_dispatch("bf16")
     _run_padded(xs, operands, 3, real=3)
     entries = _topk_pallas_jit._cache_size()
-    for real in (4, 200, np.int32(7), jnp.asarray(9), None):
+    # one live tile, several, a whole block, two blocks, none: one program
+    for real in (4, 9, 128, 200, 0, np.int32(7), jnp.asarray(9), None):
         _run_padded(xs, operands, 3, real=real)
     assert _topk_pallas_jit._cache_size() == entries

@@ -84,6 +84,10 @@ REQUIRED_PERFATTR_FAMILIES = (
     # for a `benchmark` PR
     "oryx_topk_row_blocks",
     "oryx_topk_row_blocks_skipped",
+    # the sublane tiles the kernel's folds sorted (ISSUE 32): over 16 x
+    # oryx_topk_chunks_folded, how much of a whole-block fold a dispatch's
+    # real rows still cost; the share waits for a `benchmark` PR too
+    "oryx_topk_fold_tiles",
 )
 
 
