@@ -1,0 +1,18 @@
+"""Expert layer: the busiest expert's tokens over the mean tokens of a
+touched expert, averaged over the window's dispatches and layers (1.0: even
+load). From the counts the step makes on the device: delta
+`oryx_moe_expert_tokens_max_total` a dispatch's layer, over delta
+`oryx_moe_routed_total` / `oryx_moe_experts_touched_total`."""
+
+from benchmarks.metrics import _seq
+
+
+def read(src):
+    c = src.get("counters") or {}
+    routed = c.get("oryx_moe_routed_total", 0.0)
+    touched = c.get("oryx_moe_experts_touched_total", 0.0)
+    n = _seq.all_steps(src)
+    if not routed or not touched or not n:
+        return None
+    busiest = c.get("oryx_moe_expert_tokens_max_total", 0.0) / (n * src["config"]["num_hidden_layers"])
+    return busiest / (routed / touched)

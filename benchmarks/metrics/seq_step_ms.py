@@ -1,0 +1,23 @@
+"""Batched encoder step: mean device time of one encoder dispatch, prefill
+and denoise together weighted by their counts, from the traced window's
+`XLA Modules` events (benchmarks/seqtrace.py). Each kind's own mean goes to
+stderr."""
+
+import sys
+
+
+def read(src):
+    steps = src.get("steps")
+    if not steps:
+        return None
+    count = sum(p["count"] for p in steps.values())
+    if not count:
+        return None
+    for name, p in sorted(steps.items()):
+        if p["count"]:
+            print(
+                f"seq_step_ms: {name}: {p['count']} dispatches, "
+                f"{p['seconds'] / p['count'] * 1e3:.3f} ms each",
+                file=sys.stderr,
+            )
+    return sum(p["seconds"] for p in steps.values()) / count * 1e3

@@ -32,7 +32,7 @@ from oryx_tpu.common.metrics import get_registry
 from oryx_tpu.common.tracing import get_tracer
 from oryx_tpu.ml.update import MLUpdate, split_by_time
 from oryx_tpu.ops.als import align_factors
-from oryx_tpu.ops.seq import GRU_PARAM_NAMES, next_item_hit_rate, train_gru
+from oryx_tpu.ops.seq import GRU_PARAM_NAMES, GruEncoder, next_item_hit_rate
 from oryx_tpu.apps.seq.common import (
     SeqConfig,
     item_sequences,
@@ -207,7 +207,8 @@ class SeqUpdate(MLUpdate):
             )
             if resume_e is not None:
                 resume_params = self._prev_params
-        model, epochs = train_gru(
+        # the batch layer trains the GRU, through the encoder seam
+        model, epochs = GruEncoder(dim, self.seq.window).train(
             contexts, mask, targets,
             n_items=len(vocab), dim=dim, item_ids=vocab,
             epochs=self.seq.epochs,

@@ -1,7 +1,9 @@
-"""Seq serving tier: GRU session encoder + top-k over item embeddings.
+"""Seq serving tier: a session encoder + top-k over item embeddings.
 
 The request path is the ALS shape on purpose: encode the session's item
-history into a hidden state (the "user vector"), then score the whole
+history into hidden states (the "user vectors": one from the GRU, one a
+block position from an encoder that generates, ops/sdar.py) through the
+batched encoder step (serving/stepper.py), then score the whole
 catalog with ONE matmul + top-k through the shared micro-batcher
 (serving/batcher.py) — so coalesced dispatch, shedding, host fallback,
 and perfstats MFU all apply unchanged. The device view is a
@@ -16,6 +18,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from concurrent.futures import Future
 
 import numpy as np
@@ -24,11 +27,11 @@ import jax.numpy as jnp
 
 from oryx_tpu.api import AbstractServingModelManager, ServingModel
 from oryx_tpu.common.config import Config
-from oryx_tpu.serving.app import chain_future, configure_post_pool, post_pool
+from oryx_tpu.serving.app import configure_post_pool, post_pool
 from oryx_tpu.serving.batcher import TopKBatcher
+from oryx_tpu.serving.futureutil import try_set_exception, try_set_result
 from oryx_tpu.apps.seq.common import SeqConfig
 from oryx_tpu.apps.seq.state import SeqState, apply_seq_update
-from oryx_tpu.ops.seq import encode_sessions
 
 log = logging.getLogger(__name__)
 
@@ -47,6 +50,9 @@ class SeqServingModel(ServingModel):
         # of the collector's sight (serving/viewsync.py): the first
         # build, and the first after each generation
         self.freeze_due = True
+        # (parameters, their Engine) and (view ids, view row -> E_in row)
+        self._engine_of: tuple | None = None
+        self._row_token: tuple | None = None
 
     def fraction_loaded(self) -> float:
         return self.state.fraction_loaded()
@@ -177,24 +183,62 @@ class SeqServingModel(ServingModel):
 
     # -- queries -----------------------------------------------------------
 
-    def encode(self, context_items: list[str]) -> np.ndarray | None:
-        """Session item history (oldest -> newest) -> hidden state, or
-        None when no context item is known to the model."""
+    def _engine(self):
+        """This generation's encoder on the device (serving/stepper.py):
+        made with the model's first request, again when a MODEL message
+        swapped the parameters in."""
+        params = self.state.params
+        eng = self._engine_of
+        if eng is None or eng[0] is not params:
+            from oryx_tpu.serving.stepper import Engine
+
+            with self._sync_lock:
+                eng = self._engine_of
+                if eng is None or eng[0] is not params:
+                    eng = (params, Engine(self.state.encoder, params, head=self._head))
+                    self._engine_of = eng
+        return eng[1]
+
+    def _head(self) -> tuple:
+        """What a generating encoder's step takes its logits over: the
+        served view, its real rows, and for each view row its row of the
+        encoder's input embedding (the last, [MASK], where the item came by
+        UP after the model and has none yet)."""
+        y_dev, ids, _version, _host = self._view()
+        cached = self._row_token
+        if cached is None or cached[0] is not ids:
+            token_of = self.state.token_of
+            mask_id = self.state.encoder.cfg.mask_id
+            rows = np.full((int(y_dev.shape[0]),), mask_id, dtype=np.int32)
+            rows[: len(ids)] = [token_of.get(i, mask_id) for i in ids]
+            cached = (ids, jnp.asarray(rows))
+            self._row_token = cached
+        return y_dev, len(ids), cached[1]
+
+    def _encode_async(self, context_items: list[str]) -> Future | None:
+        """Future of the stepper's `Encoded` for a session, or None when
+        no context item is known to the model. Every request of every
+        encoder is encoded this way: admitted by the batched encoder step,
+        never by a device call of its own in the request's thread."""
         if not context_items or self.state.params is None:
             return None
-        ctx = context_items[-self.state.window:]
-        vecs, have = self.state.items.get_many(ctx)
-        if not have.any():
+        prepared = self.state.encoder.prepare(self.state, context_items)
+        if prepared is None:
             return None
-        # left-pad to the fixed window so the jitted encoder compiles ONE
-        # (1, window, d) program for every context length (an unpadded
-        # call would compile per distinct session length on the hot path)
-        w = self.state.window
-        mat = np.zeros((1, w, self.state.dim), dtype=np.float32)
-        mask = np.zeros((1, w), dtype=np.float32)
-        mat[0, w - len(ctx):] = vecs
-        mask[0, w - len(ctx):] = have.astype(np.float32)
-        return encode_sessions(self.state.params, mat, mask)[0]
+        from oryx_tpu.serving.stepper import SeqStepper
+
+        return SeqStepper.shared().submit(self._engine(), prepared)
+
+    def encode(self, context_items: list[str]) -> np.ndarray | None:
+        """Session item history (oldest -> newest) -> hidden state ([d];
+        [block, d] from an encoder that generates a block), or None when
+        no context item is known to the model. A synchronous wrapper:
+        submits to the stepper and waits."""
+        fut = self._encode_async(context_items)
+        if fut is None:
+            return None
+        hidden = fut.result().hidden
+        return hidden[0] if hidden.shape[0] == 1 else hidden
 
     def next_items_async(
         self,
@@ -204,14 +248,18 @@ class SeqServingModel(ServingModel):
     ) -> Future:
         """Top next items for a session context, excluding the session's
         own history — a Future so the deferred endpoint holds no worker
-        thread while the coalesced device dispatch is in flight."""
+        thread while the encoder's steps and the coalesced scan are in
+        flight. An encoder that generates a block answers one entry a
+        position: {"item": the item fixed there, "step": the step that
+        fixed it, "next": the `how_many` best [id, score] by that step's
+        logits}; the GRU answers its [id, score] pairs as before."""
         out: Future = Future()
         try:
-            h = self.encode(context_items)
+            enc_fut = self._encode_async(context_items)
         except BaseException as e:  # noqa: BLE001 - carried to caller
             out.set_exception(e)
             return out
-        if h is None:
+        if enc_fut is None:
             out.set_result(None)  # no known context item: 404 at the route
             return out
         y_dev, ids, _version, host_mat = self._view()
@@ -219,16 +267,84 @@ class SeqServingModel(ServingModel):
         if n == 0:
             out.set_result([])
             return out
+        from oryx_tpu.common.perfattr import current_ledger, swap_ledger
         from oryx_tpu.common.tracing import current_span
 
         span = current_span()
         trace_id = span.trace_id if span is not None else None
+        ledger = current_ledger()
         k = min(n, how_many + len(exclude) + 8)
-        fut = TopKBatcher.shared().submit_nowait(
-            h, k, y_dev, host_mat=host_mat, valid_rows=n,
-        )
+        generates = self.state.encoder.steps > 0
 
-        def _post(result):
+        def _scan(f):
+            # the stepper's thread: enqueue only. One row a position goes
+            # to the shared batcher; the first carries the request's ledger
+            # (the rows ride one dispatch, so its phases are the request's)
+            try:
+                encoded = f.result()
+                prev = swap_ledger(ledger)
+                try:
+                    futs = []
+                    for h in encoded.hidden:
+                        futs.append(TopKBatcher.shared().submit_nowait(
+                            h, k, y_dev, host_mat=host_mat, valid_rows=n,
+                        ))
+                        swap_ledger(None)
+                finally:
+                    swap_ledger(prev)
+            except BaseException as e:  # noqa: BLE001 - carried to caller
+                try_set_exception(out, e)
+                return
+            results: list = [None] * len(futs)
+            left = [len(futs)]
+            lock = threading.Lock()
+
+            def _one(i, g):
+                try:
+                    results[i] = g.result()
+                except BaseException as e:  # noqa: BLE001 - carried to caller
+                    try_set_exception(out, e)
+                    return
+                with lock:
+                    left[0] -= 1
+                    last = left[0] == 0
+                if last:
+                    try:
+                        post_pool().submit(_finish, encoded, results)
+                    except Exception:  # pool shut down: fail, never run inline
+                        try_set_exception(
+                            out, RuntimeError("post-processing pool is shut down")
+                        )
+
+            for i, g in enumerate(futs):
+                g.add_done_callback(lambda g, i=i: _one(i, g))
+
+        def _finish(encoded, results):
+            try:
+                t_post = time.monotonic()
+                pages = [
+                    _post(h, r) for h, r in zip(encoded.hidden, results)
+                ]
+                if generates:
+                    answer = [
+                        {"item": ids[int(row)], "step": int(step), "next": page}
+                        for row, step, page in zip(encoded.rows, encoded.steps, pages)
+                    ]
+                else:
+                    answer = pages[0]
+                if ledger is not None:
+                    # the first two parts of `serialize` (perfattr.POST_STAGES),
+                    # as apps/als/serving.py _post stamps them
+                    tail = ledger.last_end()
+                    if tail is not None:
+                        ledger.add_stage("handoff", max(0.0, t_post - tail))
+                        ledger.add_stage("rerank", time.monotonic() - t_post)
+            except BaseException as e:  # noqa: BLE001 - carried to caller
+                try_set_exception(out, e)
+                return
+            try_set_result(out, answer)
+
+        def _post(h, result):
             from oryx_tpu.serving.batcher import host_topk
 
             vals, idx = np.asarray(result[0]), np.asarray(result[1])
@@ -272,7 +388,8 @@ class SeqServingModel(ServingModel):
                 )
             return pairs
 
-        return chain_future(fut, _post, executor=post_pool())
+        enc_fut.add_done_callback(_scan)
+        return out
 
     def next_items(
         self,

@@ -31,7 +31,6 @@ from oryx_tpu.api import AbstractSpeedModelManager
 from oryx_tpu.common.config import Config
 from oryx_tpu.common.locks import RateLimitCheck
 from oryx_tpu.common.metrics import get_registry
-from oryx_tpu.ops.seq import encode_sessions
 from oryx_tpu.apps.seq.common import (
     SeqConfig,
     parse_session_events,
@@ -163,7 +162,7 @@ class SeqSpeedModelManager(AbstractSpeedModelManager):
         mask_b = np.zeros((b_pad, window), dtype=np.float32)
         mat_b[: rows.size] = mat[rows]
         mask_b[: rows.size] = mask[rows]
-        h = encode_sessions(st.params, mat_b, mask_b)[: rows.size]
+        h = st.encoder.encode_host(st.params, mat_b, mask_b)[: rows.size]
 
         # Reference magnitude: hidden states are tanh-bounded while
         # trained embedding rows carry the softmax's learned scale, so a

@@ -10,7 +10,7 @@ until the device is the bottleneck") has an instrument to aim with:
 - **Request phase budgets.** Every request carries a ``PhaseLedger`` —
   a cheap append-only list of ``(phase, start, seconds)`` stamps the
   frontends and the batcher fill in as the request traverses parse →
-  auth → queue_wait → batch_wait → pad → device (or host_fallback) →
+  auth → (encode) → queue_wait → batch_wait → pad → device (or host_fallback) →
   serialize → write. The frontend flushes the ledger once after the
   response bytes are written: each stamp lands in the
   ``oryx_request_phase_seconds{phase}`` histogram (with metric→trace
@@ -75,6 +75,7 @@ from oryx_tpu.common.tracing import get_tracer
 PHASES = (
     "parse",          # socket read -> parsed request, + routing/query build
     "auth",           # credential check
+    "encode",         # seq app: stepper admission -> last hidden state on host
     "queue_wait",     # batcher enqueue -> picked into a dispatch batch
     "batch_wait",     # picked -> its coalesced group starts forming
     "pad",            # group formation: pad-to-capacity matrix fill
@@ -93,6 +94,16 @@ POST_STAGES = (
     "handoff",  # results on the host -> _post starts on a post-pool thread
     "rerank",   # _post: pad filter, exact re-rank, trim, shadow-sample enqueue
     "render",   # response object -> payload bytes (_render_body)
+)
+
+# Parts of `encode` (serving/stepper.py), stamped like the post stages and
+# like them no phases: waiting for a cache slot and a cycle, the cycle that
+# ran the prefill (and the block's first step), the cycles of the remaining
+# steps. Into oryx_seq_encode_stage_seconds{stage}.
+ENCODE_STAGES = (
+    "encode_wait",
+    "prefill",
+    "denoise",
 )
 
 # Device idle-gap causes. `unattributed` is the honesty valve: time the
@@ -290,7 +301,8 @@ class PerfAttr:
                 seconds, trace_id=ledger.trace_id, phase=phase
             )
         for stage, seconds in ledger.stages():
-            self._h_post.observe(seconds, stage=stage)
+            h = self._h_encode if stage in ENCODE_STAGES else self._h_post
+            h.observe(seconds, stage=stage)
         if self.enabled:
             with self._win_lock:
                 self._prune(self._phase_win, now)
@@ -486,6 +498,13 @@ class PerfAttr:
                 "Per top-n answer, the parts of the serialize phase on the "
                 "deferred path (handoff to the post pool, rerank = _post, "
                 "render = payload bytes), by stage",
+                buckets=PHASE_SECONDS_BUCKETS,
+            )
+            self._h_encode = reg.histogram(
+                "oryx_seq_encode_stage_seconds",
+                "Per seq request, the parts of the encode phase (encode_wait "
+                "for a slot and a cycle, prefill = the cycle of its prefill "
+                "and first step, denoise = its remaining steps), by stage",
                 buckets=PHASE_SECONDS_BUCKETS,
             )
             self._h_gap = reg.histogram(

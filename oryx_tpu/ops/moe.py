@@ -1,0 +1,121 @@
+"""A sparse mixture-of-experts feed-forward layer on one chip.
+
+    p = softmax(u Wr)            over the E experts, in float32
+    the k largest, their weights divided by their sum
+    y = sum_e w_e * (silu(u Wg_e) * (u Wu_e)) Wd_e
+
+No token is dropped, whatever the load of an expert: the (token, expert)
+pairs are sorted by expert and each expert multiplies the contiguous rows
+that chose it (a grouped product), so an expert nobody chose costs no
+FLOP and, on the grouped kernel, no read of its weights. One chip holds
+every expert: the layer is given no share and nothing stands in for
+absent chips.
+
+`moe_apply` also counts, on the device and in the step's own dispatch,
+what the serving counters report (serving/stepper.py, oryx_moe_*): the
+pairs routed, the experts touched and the busiest expert's tokens.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+# rows of the sorted (token, expert) pairs are padded up to a multiple of
+# this: the grouped kernel's row tile
+ROW_TILE = 128
+
+
+def route(u: jax.Array, wr: jax.Array, k: int):
+    """Router: (weights [N,k] float32 summing to 1, experts [N,k] int32).
+    Logits and softmax in float32 at `highest` precision (a default f32
+    product on the TPU is one bf16 pass)."""
+    logits = jnp.dot(
+        u.astype(jnp.float32), wr.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    p = jax.nn.softmax(logits, axis=-1)
+    w, e = jax.lax.top_k(p, k)
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w, e.astype(jnp.int32)
+
+
+def _grouped(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array) -> jax.Array:
+    """lhs[rows of group g] @ rhs[g] for every group, float32 out. On the
+    TPU the Pallas grouped matmul (it visits only the row tiles of groups
+    that hold rows); elsewhere XLA's ragged dot."""
+    if jax.default_backend() == "tpu":
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+        k, n = rhs.shape[1], rhs.shape[2]
+        return gmm(
+            lhs, rhs, sizes, preferred_element_type=jnp.float32,
+            tiling=(ROW_TILE, k, min(n, 1024)),
+        )
+    return jax.lax.ragged_dot(
+        lhs, rhs, sizes, preferred_element_type=jnp.float32
+    )
+
+
+def moe_apply(
+    u: jax.Array, wr: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
+    k: int, live: jax.Array | None = None,
+):
+    """u [N,H] float32 (normalised) -> (y [N,H] float32, counts int32[3]).
+
+    wr [H,E], wg/wu [E,H,F], wd [E,F,H] in the weights' dtype; the
+    activations enter each product in that dtype and accumulate in
+    float32. `live` [N] bool marks the real tokens of a padded step: a
+    padding token's pairs are sent to no expert (they sort behind every
+    group) and count nowhere. counts = (pairs routed, experts touched,
+    the busiest expert's pairs)."""
+    n, h = u.shape
+    n_experts = wr.shape[1]
+    w, e = route(u, wr, k)
+    if live is None:
+        live = jnp.ones((n,), dtype=bool)
+    pairs = n * k
+    flat_e = jnp.where(live[:, None], e, n_experts).reshape(pairs)
+    order = jnp.argsort(flat_e, stable=True)
+    token = (order // k).astype(jnp.int32)
+    sizes = jnp.bincount(flat_e, length=n_experts + 1)[:n_experts].astype(jnp.int32)
+    rows = -(-pairs // ROW_TILE) * ROW_TILE
+    x = u.astype(wg.dtype)[token]
+    if rows > pairs:
+        x = jnp.pad(x, ((0, rows - pairs), (0, 0)))
+    g = _grouped(x, wg, sizes)
+    up = _grouped(x, wu, sizes)
+    mid = (jax.nn.silu(g) * up).astype(wd.dtype)
+    out = _grouped(mid, wd, sizes)[:pairs]
+    # back to token order: pair j of token i sits at sorted row inv[i*k+j].
+    # A grouped product writes no row past its last group (the padding
+    # tokens' pairs): whatever those hold is dropped, not weighted by zero
+    inv = jnp.argsort(order).astype(jnp.int32)
+    out = out[inv].reshape(n, k, h)
+    w = jnp.where(live[:, None], w, 0.0)
+    y = jnp.sum(jnp.where(w[:, :, None] > 0, out, 0.0) * w[:, :, None], axis=1)
+    counts = jnp.stack([
+        jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes),
+    ]).astype(jnp.int32)
+    return y, counts
+
+
+def moe_reference(u, wr, wg, wu, wd, k: int):
+    """The plain form, float32 throughout: every expert in turn on every
+    token, weighted by the token's routing weight for it (zero unless it
+    is one of the token's k). For tests and the plain reference; run it
+    under `jax.default_matmul_precision("highest")`."""
+    f32 = jnp.float32
+    u = u.astype(f32)
+    p = jax.nn.softmax(u @ wr.astype(f32), axis=-1)
+    w, e = jax.lax.top_k(p, k)
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    dense_w = jnp.zeros(p.shape, f32).at[jnp.arange(u.shape[0])[:, None], e].add(w)
+
+    def one_expert(acc, xs):
+        g_w, u_w, d_w, col = xs
+        y = (jax.nn.silu(u @ g_w.astype(f32)) * (u @ u_w.astype(f32))) @ d_w.astype(f32)
+        return acc + col[:, None] * y, None
+
+    acc, _ = jax.lax.scan(one_expert, jnp.zeros(u.shape, f32), (wg, wu, wd, dense_w.T))
+    return acc

@@ -1,0 +1,548 @@
+"""A block-diffusion mixture-of-experts transformer over the item catalog
+(`sdar_moe`: the Qwen3-MoE layer, generation by diffusion over blocks).
+
+    layer:  h = x + Attn(RMSNorm(x));  y = h + MoE(RMSNorm(h))
+    Attn:   q = u Wq [heads x d], k = u Wk, v = u Wv [kv_heads x d]; q and k
+            RMS-normalised per head (a learned weight of d each); RoPE over
+            the whole head by absolute position; query head j reads
+            key-value head j // (heads / kv_heads);
+            softmax(q k^T / sqrt(d) + M) v; Wo; no biases
+    M:      the session's history is the prefix, causal within itself; the
+            block of B positions appended after it sees the whole prefix
+            and ALL of the block
+    MoE:    ops/moe.py
+    out:    final RMSNorm, logits = z E_out^T over the item catalog
+
+The vocabulary is the item catalog: row i of `E_out` is item i's row of
+the served view (the FactorStore, as for the GRU), row t of `E_in` the
+input embedding of announced id t, and the last row of `E_in` is [MASK].
+
+Generation, one block a request: append B [MASK] positions; for T steps
+run the layers over the block against the prefix's cached keys and
+values, take logits at the still-masked positions and fix the one whose
+largest softmax probability is highest to its argmax (static
+low-confidence remasking, one position a step). The hidden state of a
+position at the step that fixed it is what the catalog scan ranks.
+
+Precision: weights in their stored dtype (bfloat16 as published), the
+activations enter every product in that dtype and accumulate in float32;
+the residual stream, the norms, the softmaxes and the router are float32.
+
+Two forms live here. `prefill` / `denoise_step` are the served ones: a
+slot cache on the device, fixed shapes, per-request block state that never
+visits the host between steps. `reference_*` is the plain one: float32,
+`highest` precision, full forward passes over prefix + block, no cache.
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+from typing import NamedTuple
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from oryx_tpu.ops.moe import moe_apply, moe_reference
+
+# tensors of an SDAR artifact, beside the catalog ("E", the FactorStore's):
+# "E_in", "final_norm" and, for layer l, "L<l>.<name>" of each of these. A
+# layer's tensors are arrays of their own and never slices of a stacked one:
+# the grouped kernel takes whole buffers, and a slice of 400 MB of experts
+# would be copied for it at every step
+LAYER_TENSORS = (
+    "ln1", "wq", "wk", "wv", "q_norm", "k_norm", "wo",
+    "ln2", "router", "wg", "wu", "wd",
+)
+NORM_TENSORS = ("ln1", "ln2", "q_norm", "k_norm")
+
+
+class SdarConfig(NamedTuple):
+    hidden: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    experts: int
+    expert_width: int
+    experts_per_token: int
+    layers: int
+    vocab: int               # rows of E_in: the items and, last, [MASK]
+    rope_theta: float = 1_000_000.0
+    eps: float = 1e-6
+    block_length: int = 4
+    denoise_steps: int = 4
+    max_len: int = 100       # longest prefix a slot holds
+
+    @property
+    def mask_id(self) -> int:
+        return self.vocab - 1
+
+    @property
+    def positions(self) -> int:
+        return self.max_len + self.block_length
+
+    @staticmethod
+    def from_extensions(ext) -> "SdarConfig":
+        """From an artifact's extensions: the source's own key names."""
+        g = ext
+        return SdarConfig(
+            hidden=int(g("hidden_size")),
+            heads=int(g("num_attention_heads")),
+            kv_heads=int(g("num_key_value_heads")),
+            head_dim=int(g("head_dim")),
+            experts=int(g("num_experts")),
+            expert_width=int(g("moe_intermediate_size")),
+            experts_per_token=int(g("num_experts_per_tok")),
+            layers=int(g("num_hidden_layers")),
+            vocab=int(g("vocab_size")),
+            rope_theta=float(g("rope_theta", 1_000_000.0)),
+            eps=float(g("rms_norm_eps", 1e-6)),
+            block_length=int(g("block_length", 4)),
+            denoise_steps=int(g("denoise_steps", 4)),
+            max_len=int(g("max_len", 100)),
+        )
+
+    def to_extensions(self) -> dict:
+        return {
+            "hidden_size": self.hidden, "num_attention_heads": self.heads,
+            "num_key_value_heads": self.kv_heads, "head_dim": self.head_dim,
+            "num_experts": self.experts, "moe_intermediate_size": self.expert_width,
+            "num_experts_per_tok": self.experts_per_token,
+            "num_hidden_layers": self.layers, "vocab_size": self.vocab,
+            "rope_theta": self.rope_theta, "rms_norm_eps": self.eps,
+            "block_length": self.block_length, "denoise_steps": self.denoise_steps,
+            "max_len": self.max_len,
+        }
+
+
+def layer_shapes(cfg: SdarConfig) -> dict[str, tuple]:
+    H, E, F = cfg.hidden, cfg.experts, cfg.expert_width
+    q, kv = cfg.heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    return {
+        "ln1": (H,), "ln2": (H,),
+        "wq": (H, q), "wk": (H, kv), "wv": (H, kv), "wo": (q, H),
+        "q_norm": (cfg.head_dim,), "k_norm": (cfg.head_dim,),
+        "router": (H, E), "wg": (E, H, F), "wu": (E, H, F), "wd": (E, F, H),
+    }
+
+
+def tensor_shapes(cfg: SdarConfig) -> dict[str, tuple]:
+    """Every tensor of an artifact by its name."""
+    out = {"E_in": (cfg.vocab, cfg.hidden), "final_norm": (cfg.hidden,)}
+    for l in range(cfg.layers):
+        out.update({f"L{l}.{k}": v for k, v in layer_shapes(cfg).items()})
+    return out
+
+
+def param_count(cfg: SdarConfig) -> int:
+    return sum(int(np.prod(v)) for v in tensor_shapes(cfg).values())
+
+
+def init_tensors(cfg: SdarConfig, seed: int, dtype=jnp.bfloat16) -> dict:
+    """An artifact's tensors, standard normal x 0.02 (norm weights 1) from
+    the seed, made on the device one tensor at a time."""
+    out = {}
+    for i, (name, shape) in enumerate(sorted(tensor_shapes(cfg).items())):
+        if name == "final_norm" or name.split(".")[-1] in NORM_TENSORS:
+            out[name] = jnp.ones(shape, dtype=dtype)
+        else:
+            key = jax.random.fold_in(jax.random.PRNGKey(seed % (2**31)), i)
+            out[name] = _normal(key, shape, dtype)
+    return out
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _normal(key, shape, dtype):
+    return (jax.random.normal(key, shape, dtype=jnp.float32) * 0.02).astype(dtype)
+
+
+def params_of(cfg: SdarConfig, tensors: dict, dtype=None) -> dict:
+    """An artifact's tensors -> the parameters the forms below take:
+    {"E_in", "final_norm", "layers": [{name: array}, ...]}, checked against
+    the shapes the configuration states; cast to `dtype` where one is given
+    (an array already on the device in that dtype is taken as it is)."""
+    for name, shape in tensor_shapes(cfg).items():
+        if name not in tensors:
+            raise ValueError(f"SDAR model lacks tensor {name!r}")
+        if tuple(np.shape(tensors[name])) != shape:
+            raise ValueError(
+                f"SDAR tensor {name!r} shaped {tuple(np.shape(tensors[name]))}, "
+                f"the extensions say {shape}"
+            )
+    take = lambda name: jnp.asarray(tensors[name], dtype=dtype)
+    return {
+        "E_in": take("E_in"), "final_norm": take("final_norm"),
+        "layers": [
+            {k: take(f"L{l}.{k}") for k in LAYER_TENSORS} for l in range(cfg.layers)
+        ],
+    }
+
+
+def init_params(cfg: SdarConfig, seed: int, dtype=jnp.bfloat16) -> dict:
+    return params_of(cfg, init_tensors(cfg, seed, dtype))
+
+
+# -- pieces both forms share (the dtype of the inputs decides the precision) --
+
+def rms_norm(x, w, eps):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w.astype(jnp.float32)
+
+
+def rope(x, pos, theta):
+    """x [..., T, heads, d] float32, pos [..., T] -> rotated over the whole
+    head (the rotate-half form: dimension i pairs with i + d/2)."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = pos.astype(jnp.float32)[..., None] * inv            # [..., T, d/2]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)[..., None, :]
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)[..., None, :]
+    x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+
+
+def _qkv(cfg: SdarConfig, p: dict, u, pos):
+    """u [R,T,H] float32 -> q [R,T,heads,d], k, v [R,T,kv,d] float32, q and k
+    normalised per head and rotated."""
+    dt = p["wq"].dtype
+    ub = u.astype(dt)
+    f32 = jnp.float32
+    r, t = u.shape[0], u.shape[1]
+    q = jnp.dot(ub, p["wq"], preferred_element_type=f32).reshape(r, t, cfg.heads, cfg.head_dim)
+    k = jnp.dot(ub, p["wk"], preferred_element_type=f32).reshape(r, t, cfg.kv_heads, cfg.head_dim)
+    v = jnp.dot(ub, p["wv"], preferred_element_type=f32).reshape(r, t, cfg.kv_heads, cfg.head_dim)
+    q = rope(rms_norm(q, p["q_norm"], cfg.eps), pos, cfg.rope_theta)
+    k = rope(rms_norm(k, p["k_norm"], cfg.eps), pos, cfg.rope_theta)
+    return q, k, v
+
+
+def _attend(cfg: SdarConfig, q, k, v, allowed, dt):
+    """q [R,T,heads,d], k/v [R,S,kv,d], allowed [R,T,S] bool -> [R,T,heads*d]
+    float32. Scores and softmax in float32; the products take q, k, the
+    probabilities and v in `dt`."""
+    f32 = jnp.float32
+    r, t = q.shape[0], q.shape[1]
+    group = cfg.heads // cfg.kv_heads
+    qg = q.reshape(r, t, cfg.kv_heads, group, cfg.head_dim).astype(dt)
+    s = jnp.einsum("rtgjd,rsgd->rgjts", qg, k.astype(dt), preferred_element_type=f32)
+    s = s / math.sqrt(cfg.head_dim)
+    s = jnp.where(allowed[:, None, None, :, :], s, -jnp.inf)
+    # a padding query may be allowed nothing: its row is zeros, not NaN
+    m = jnp.max(s, axis=-1, keepdims=True)
+    e = jnp.exp(s - jnp.where(jnp.isfinite(m), m, 0.0))
+    prob = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+    o = jnp.einsum("rgjts,rsgd->rtgjd", prob.astype(dt), v.astype(dt), preferred_element_type=f32)
+    return o.reshape(r, t, cfg.heads * cfg.head_dim)
+
+
+def _moe(cfg: SdarConfig, p: dict, x, live):
+    """The expert layer over the flattened tokens of x [R,T,H]."""
+    r, t, h = x.shape
+    with jax.named_scope("sdar.moe"):
+        u = rms_norm(x, p["ln2"], cfg.eps).reshape(r * t, h)
+        y, counts = moe_apply(
+            u, p["router"], p["wg"], p["wu"], p["wd"],
+            cfg.experts_per_token, live.reshape(r * t),
+        )
+    return x + y.reshape(r, t, h), counts
+
+
+# -- the served form: a slot cache, fixed shapes -----------------------------
+
+def init_state(cfg: SdarConfig, slots: int, dtype=jnp.bfloat16) -> dict:
+    """Per-request state for `slots` requests and one scratch slot (the last:
+    padding rows of a dispatch write there). k, v: the prefix's keys and
+    values, one array a layer; tok / masked / z / row / step: the block (its
+    input tokens, which positions are still [MASK], and for each fixed
+    position the hidden state, the view row and the step that fixed it)."""
+    s, b = slots + 1, cfg.block_length
+    kv = (s, cfg.max_len, cfg.kv_heads, cfg.head_dim)
+    return {
+        "k": [jnp.zeros(kv, dtype) for _ in range(cfg.layers)],
+        "v": [jnp.zeros(kv, dtype) for _ in range(cfg.layers)],
+        "tok": jnp.full((s, b), cfg.mask_id, jnp.int32),
+        "masked": jnp.ones((s, b), bool),
+        "z": jnp.zeros((s, b, cfg.hidden), jnp.float32),
+        "row": jnp.full((s, b), -1, jnp.int32),
+        "step": jnp.full((s, b), -1, jnp.int32),
+    }
+
+
+def state_bytes(cfg: SdarConfig, slots: int, itemsize: int = 2) -> int:
+    s = slots + 1
+    kv = 2 * cfg.layers * s * cfg.max_len * cfg.kv_heads * cfg.head_dim * itemsize
+    return kv + s * cfg.block_length * (4 + 1 + 4 * cfg.hidden + 4 + 4)
+
+
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+def prefill(cfg: SdarConfig, params: dict, state: dict, tokens, lengths, slots):
+    """tokens [P,T] int32 (right-padded), lengths [P], slots [P] (the scratch
+    slot for a padding row, whose length is 0) -> (state, hidden [P,H] at
+    each session's last position, counts int32[3] summed over the layers).
+    Writes the prefix's keys and values into the slots and resets their
+    blocks to B [MASK] positions."""
+    p_rows, t = tokens.shape
+    dt = params["layers"][0]["wq"].dtype
+    pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (p_rows, t))
+    live = pos < lengths[:, None]
+    allowed = (pos[:, None, :] <= pos[:, :, None]) & live[:, None, :]
+    x = params["E_in"][tokens].astype(jnp.float32)
+    k_cache, v_cache = list(state["k"]), list(state["v"])
+    counts = jnp.zeros((3,), jnp.int32)
+    for l, p in enumerate(params["layers"]):
+        with jax.named_scope("sdar.attn"):
+            u = rms_norm(x, p["ln1"], cfg.eps)
+            q, k, v = _qkv(cfg, p, u, pos)
+            k_cache[l] = k_cache[l].at[slots, :t].set(k.astype(k_cache[l].dtype))
+            v_cache[l] = v_cache[l].at[slots, :t].set(v.astype(v_cache[l].dtype))
+            o = _attend(cfg, q, k, v, allowed, dt)
+            x = x + jnp.dot(o.astype(dt), p["wo"], preferred_element_type=jnp.float32)
+        x, c = _moe(cfg, p, x, live)
+        counts = counts + c
+    last = jnp.maximum(lengths - 1, 0)
+    hidden = x[jnp.arange(p_rows), last]
+    b = cfg.block_length
+    state = dict(
+        state, k=k_cache, v=v_cache,
+        tok=state["tok"].at[slots].set(cfg.mask_id),
+        masked=state["masked"].at[slots].set(True),
+        row=state["row"].at[slots].set(-1),
+        step=state["step"].at[slots].set(-1),
+        z=state["z"].at[slots].set(jnp.zeros((b, cfg.hidden), jnp.float32)),
+    )
+    return state, hidden, counts
+
+
+def _block_hidden(cfg: SdarConfig, params: dict, state: dict, slots, lengths, live):
+    """The layers over the blocks of `slots` against their cached prefixes:
+    final-normed z [D,B,H] float32 and the layers' counts."""
+    d_rows, b = slots.shape[0], cfg.block_length
+    dt = params["layers"][0]["wq"].dtype
+    pos = lengths[:, None] + jnp.arange(b, dtype=jnp.int32)[None, :]
+    x = params["E_in"][state["tok"][slots]].astype(jnp.float32)
+    t = cfg.max_len
+    in_prefix = jnp.arange(t, dtype=jnp.int32)[None, :] < lengths[:, None]      # [D,T]
+    # a block position sees its whole prefix and ALL of the block
+    allowed = jnp.concatenate(
+        [jnp.broadcast_to(in_prefix[:, None, :], (d_rows, b, t)),
+         jnp.ones((d_rows, b, b), bool)], axis=-1,
+    )
+    live_tok = jnp.broadcast_to(live[:, None], (d_rows, b))
+    counts = jnp.zeros((3,), jnp.int32)
+    for l, p in enumerate(params["layers"]):
+        with jax.named_scope("sdar.attn"):
+            u = rms_norm(x, p["ln1"], cfg.eps)
+            q, k, v = _qkv(cfg, p, u, pos)
+            keys = jnp.concatenate([state["k"][l][slots].astype(jnp.float32), k], axis=1)
+            vals = jnp.concatenate([state["v"][l][slots].astype(jnp.float32), v], axis=1)
+            o = _attend(cfg, q, keys, vals, allowed, dt)
+            x = x + jnp.dot(o.astype(dt), p["wo"], preferred_element_type=jnp.float32)
+        x, c = _moe(cfg, p, x, live_tok)
+        counts = counts + c
+    return rms_norm(x, params["final_norm"], cfg.eps), counts
+
+
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+def denoise_step(
+    cfg: SdarConfig, params: dict, state: dict, view, n_valid, row_token,
+    slots, lengths, live, step,
+):
+    """One denoising step of every block in `slots` [D] (the scratch slot and
+    live False for a padding row): logits of the still-masked positions over
+    the `n_valid` real rows of `view` [rows, H]; the position whose largest
+    softmax probability is highest is fixed to its argmax. `row_token` [rows]
+    maps a view row to its E_in row (the [MASK] row where an item has no
+    input embedding yet). `step` [D] is each block's own step number.
+
+    -> (state, out) with out = {"z": [D,B,H] float32 hidden of each fixed
+    position at its step, "row": [D,B] view rows fixed, "step": [D,B] the
+    steps that fixed them, "counts": int32[3]}: what a finished request
+    needs, and every row's, so one fetch serves whichever finished."""
+    d_rows, b = slots.shape[0], cfg.block_length
+    z, counts = _block_hidden(cfg, params, state, slots, lengths, live)
+    with jax.named_scope("sdar.head"):
+        dt = view.dtype
+        zq = z.reshape(d_rows * b, cfg.hidden).astype(dt)
+        # a served view is lane-padded (ops/pallas_topk.py view_shape)
+        zq = jnp.pad(zq, ((0, 0), (0, view.shape[1] - cfg.hidden)))
+        logits = jnp.dot(zq, view.T, preferred_element_type=jnp.float32)
+        logits = jnp.where(jnp.arange(view.shape[0])[None, :] < n_valid, logits, -jnp.inf)
+        top = jnp.max(logits, axis=-1)
+        arg = jnp.argmax(logits, axis=-1).astype(jnp.int32).reshape(d_rows, b)
+        conf = jnp.exp(top - jax.nn.logsumexp(logits, axis=-1)).reshape(d_rows, b)
+    masked = state["masked"][slots]
+    pick = jnp.argmax(jnp.where(masked, conf, -1.0), axis=-1)                  # [D]
+    chosen_row = arg[jnp.arange(d_rows), pick]
+    onehot = (jnp.arange(b)[None, :] == pick[:, None]) & masked & live[:, None]
+    new_tok = jnp.where(onehot, row_token[chosen_row][:, None], state["tok"][slots])
+    new_row = jnp.where(onehot, chosen_row[:, None], state["row"][slots])
+    new_step = jnp.where(onehot, step[:, None], state["step"][slots])
+    new_z = jnp.where(onehot[:, :, None], z, state["z"][slots])
+    state = dict(
+        state,
+        tok=state["tok"].at[slots].set(new_tok),
+        masked=state["masked"].at[slots].set(masked & ~onehot),
+        row=state["row"].at[slots].set(new_row),
+        step=state["step"].at[slots].set(new_step),
+        z=state["z"].at[slots].set(new_z),
+    )
+    return state, {"z": new_z, "row": new_row, "step": new_step, "counts": counts}
+
+
+# -- behind the encoder seam (ops/seq.py) ------------------------------------
+
+class SdarEncoder:
+    """The block behind the seam: `prefill` fills a request's cache slot,
+    `steps` denoising steps follow, and the request hands the catalog scan
+    `block` rows. Shapes are few and fixed: a prefill is `prefill_rows`
+    sessions padded to a length bucket, a step is `step_rows` blocks."""
+
+    name = "sdar"
+    own_input = True  # E_in: an input embedding apart from the catalog
+    prefill_rows = 8
+    step_rows = 32
+
+    def __init__(self, cfg: SdarConfig, dtype=jnp.bfloat16):
+        self.cfg = cfg
+        self.dtype = dtype
+        self.dim = cfg.hidden
+        self.steps = cfg.denoise_steps
+        self.block = cfg.block_length
+        self.window = cfg.max_len
+        self.length_buckets = tuple(sorted({min(32, cfg.max_len), cfg.max_len}))
+
+    @staticmethod
+    def from_extensions(ext) -> "SdarEncoder":
+        return SdarEncoder(
+            SdarConfig.from_extensions(ext), jnp.dtype(str(ext("dtype", "bfloat16")))
+        )
+
+    def load_params(self, tensors: dict) -> dict:
+        """An artifact's tensors -> the parameters on the device in the
+        dtype the artifact states, checked against the shapes its
+        extensions state."""
+        return params_of(self.cfg, tensors, self.dtype)
+
+    def device_params(self, params: dict) -> dict:
+        return params
+
+    def init_state(self, slots: int):
+        return init_state(self.cfg, slots, self.dtype)
+
+    def prepare(self, seq_state, context_items):
+        """The E_in rows of the newest `max_len` context items that have
+        one; an item the model was not announced with (it arrived by UP
+        since) has a head row and no input embedding, and is skipped as
+        context until the next generation."""
+        token_of = seq_state.token_of
+        tokens = [token_of[i] for i in context_items if i in token_of]
+        if not tokens:
+            return None
+        return np.asarray(tokens[-self.cfg.max_len:], dtype=np.int32)
+
+    def length(self, prepared) -> int:
+        return int(prepared.shape[0])
+
+    def pack(self, prepared: list, bucket: int, slots, scratch: int):
+        tokens = np.zeros((self.prefill_rows, bucket), dtype=np.int32)
+        lengths = np.zeros((self.prefill_rows,), dtype=np.int32)
+        slot_of = np.full((self.prefill_rows,), scratch, dtype=np.int32)
+        for i, tok in enumerate(prepared):
+            tokens[i, : len(tok)] = tok
+            lengths[i] = len(tok)
+            slot_of[i] = slots[i]
+        return tokens, lengths, slot_of
+
+    def prefill(self, params, state, tokens, lengths, slots):
+        packed = (jnp.asarray(tokens), jnp.asarray(lengths), jnp.asarray(slots))
+        return prefill(self.cfg, params, state, *packed)
+
+    def step(self, params, state, head, slots, lengths, live, step):
+        view, n_valid, row_token = head
+        rows = (jnp.asarray(slots), jnp.asarray(lengths), jnp.asarray(live), jnp.asarray(step))
+        return denoise_step(self.cfg, params, state, view, jnp.int32(n_valid), row_token, *rows)
+
+    def train(self, *args, **kw):
+        raise NotImplementedError(
+            "an SDAR model reaches serving as an artifact; the batch layer trains the GRU"
+        )
+
+
+# -- the plain reference: float32, highest precision, no cache ---------------
+
+def reference_forward(cfg: SdarConfig, params: dict, tokens, n_prefix):
+    """tokens [T] int32 = prefix, one block, then padding -> final-normed
+    hidden [T,H] float32. Full forward pass, nothing cached; the mask is
+    causal within the first `n_prefix` positions and lets the block (the
+    next B) see the whole prefix and all of itself; the padding after it is
+    seen by nobody. `n_prefix` may be traced: one program for every length."""
+    f32 = jnp.float32
+    with jax.default_matmul_precision("highest"):
+        t = tokens.shape[0]
+        pos = jnp.arange(t)
+        in_block = (pos >= n_prefix) & (pos < n_prefix + cfg.block_length)
+        allowed = (pos[None, :] <= pos[:, None]) | (in_block[:, None] & in_block[None, :])
+        x = params["E_in"][tokens].astype(f32)
+        group = cfg.heads // cfg.kv_heads
+        for p in params["layers"]:
+            u = rms_norm(x, p["ln1"], cfg.eps)
+            q = (u @ p["wq"].astype(f32)).reshape(t, cfg.heads, cfg.head_dim)
+            k = (u @ p["wk"].astype(f32)).reshape(t, cfg.kv_heads, cfg.head_dim)
+            v = (u @ p["wv"].astype(f32)).reshape(t, cfg.kv_heads, cfg.head_dim)
+            q = rope(rms_norm(q, p["q_norm"], cfg.eps), pos, cfg.rope_theta)
+            k = rope(rms_norm(k, p["k_norm"], cfg.eps), pos, cfg.rope_theta)
+            k = jnp.repeat(k, group, axis=1)
+            v = jnp.repeat(v, group, axis=1)
+            s = jnp.einsum("thd,shd->hts", q, k) / math.sqrt(cfg.head_dim)
+            s = jnp.where(allowed[None], s, -jnp.inf)
+            o = jnp.einsum("hts,shd->thd", jax.nn.softmax(s, axis=-1), v)
+            x = x + o.reshape(t, cfg.heads * cfg.head_dim) @ p["wo"].astype(f32)
+            u = rms_norm(x, p["ln2"], cfg.eps)
+            x = x + moe_reference(
+                u, p["router"], p["wg"], p["wu"], p["wd"], cfg.experts_per_token,
+            )
+        return rms_norm(x, params["final_norm"], cfg.eps)
+
+
+@partial(jax.jit, static_argnums=(0,))
+def reference_block_logits(cfg: SdarConfig, params: dict, e_out, tokens, n_prefix, n_valid):
+    """Logits [B, rows] float32 of the block's positions over the catalog
+    `e_out` [rows, H] (rows at or past `n_valid` read -inf), by the plain
+    form: `tokens` [T] = prefix + block (its [MASK] and fixed positions as
+    they stand) + padding."""
+    z = reference_forward(cfg, params, tokens, n_prefix)
+    zb = jax.lax.dynamic_slice_in_dim(z, n_prefix, cfg.block_length, axis=0)
+    with jax.default_matmul_precision("highest"):
+        logits = zb @ e_out.astype(jnp.float32).T
+    return jnp.where(jnp.arange(e_out.shape[0])[None, :] < n_valid, logits, -jnp.inf)
+
+
+def reference_generate(cfg: SdarConfig, params: dict, e_out, prefix, row_token=None, n_valid=None):
+    """One block's generation by the plain form: prefix [n] int32 tokens,
+    e_out [rows, H] -> {"row": [B] catalog rows fixed, "step": [B] the steps
+    that fixed them, "logits": [steps, B, rows] float32}."""
+    b = cfg.block_length
+    n = len(prefix)
+    n_valid = int(e_out.shape[0]) if n_valid is None else int(n_valid)
+    tok = np.full(b, cfg.mask_id, dtype=np.int32)
+    masked = np.ones(b, dtype=bool)
+    row = np.full(b, -1, dtype=np.int64)
+    step_of = np.full(b, -1, dtype=np.int64)
+    all_logits = []
+    for step in range(cfg.denoise_steps):
+        tokens = np.zeros(cfg.positions, dtype=np.int32)
+        tokens[:n], tokens[n:n + b] = prefix, tok
+        logits = np.asarray(reference_block_logits(
+            cfg, params, e_out, jnp.asarray(tokens), jnp.int32(n), jnp.int32(n_valid),
+        ))
+        all_logits.append(logits)
+        top = logits.max(-1)
+        conf = np.exp(top - np.asarray(jax.nn.logsumexp(jnp.asarray(logits), axis=-1)))
+        pick = int(np.argmax(np.where(masked, conf, -1.0)))
+        item = int(np.argmax(logits[pick]))
+        masked[pick] = False
+        row[pick], step_of[pick] = item, step
+        tok[pick] = item if row_token is None else int(row_token[item])
+    return {"row": row, "step": step_of, "logits": np.stack(all_logits)}

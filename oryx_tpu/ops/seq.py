@@ -245,9 +245,10 @@ def next_item_hit_rate(
           for name in GRU_PARAM_NAMES}
     k = min(k, int(e.shape[0]))
     hits = 0
+    gru = GruEncoder(int(e.shape[1]), int(contexts.shape[1]))
     for lo in range(0, n, chunk):
-        h = encode_vectors(
-            jp, e_j[jnp.asarray(contexts[lo : lo + chunk])],
+        _, h, _ = gru.prefill(
+            jp, None, e_j[jnp.asarray(contexts[lo : lo + chunk])],
             jnp.asarray(mask[lo : lo + chunk]),
         )
         logits = np.asarray(h @ e_j.T)
@@ -256,14 +257,129 @@ def next_item_hit_rate(
     return hits / n
 
 
-def encode_sessions(params: dict, item_vectors: np.ndarray, mask: np.ndarray) -> np.ndarray:
-    """Host-friendly wrapper: encode pre-gathered [B,L,d] item vectors
-    (zeros on padded steps) into [B,d] hidden states."""
-    jp = {k: jnp.asarray(np.asarray(params[k], dtype=np.float32)) for k in GRU_PARAM_NAMES}
-    return np.asarray(
-        encode_vectors(
-            jp,
-            jnp.asarray(np.asarray(item_vectors, dtype=np.float32)),
-            jnp.asarray(np.asarray(mask, dtype=np.float32)),
+# -- the encoder seam ---------------------------------------------------------
+#
+# What turns a session into the vector(s) the catalog scan ranks is an
+# ENCODER. The three tiers reach it through one interface and never call a
+# model's functions directly; which encoder a model has is written in its
+# artifact (extension "encoder", absent: "gru"), never in a config key.
+#
+#   name, steps, block      `steps` device steps follow the prefill (0: the
+#                           prefill's hidden state is the answer); a request
+#                           hands the scan `block` rows
+#   own_input               the encoder has an input embedding apart from the
+#                           catalog (row-aligned with the announced ids)
+#   length_buckets          the padded context lengths a prefill compiles
+#   prefill_rows, step_rows rows of a prefill and of a step dispatch
+#   load_params(tensors)    an artifact's tensors -> parameters, checked
+#   device_params(params)   the parameters as the device calls take them
+#   init_state(slots)       per-request device state for `slots` requests
+#                           (None: the encoder keeps none)
+#   prepare(seq_state, items) -> one request's host input, or None when no
+#                           context item is known to the model
+#   length(prepared)        its context length (picks the bucket)
+#   pack(prepared, bucket, slots, scratch) -> the arrays of one prefill
+#   prefill(params, state, *packed) -> (state, hidden [rows, d], counts)
+#   step(params, state, head, slots, lengths, live, step) -> (state, out)
+#   train(...) / loss       for the encoder that trains
+
+
+class GruEncoder:
+    """The GRU behind the seam: its state is h, and it answers after
+    `prefill` (no steps). `prefill` is `encode_vectors`, unchanged."""
+
+    name = "gru"
+    own_input = False  # its input embedding IS the catalog row
+    steps = 0
+    block = 1
+    prefill_rows = 8
+    step_rows = 0
+
+    def __init__(self, dim: int, window: int):
+        self.dim = int(dim)
+        self.window = int(window)
+        self.length_buckets = (self.window,)
+
+    def state_spec(self) -> dict:
+        return {"h": ((self.dim,), np.float32)}
+
+    def load_params(self, tensors: dict) -> dict:
+        """An artifact's tensors -> the host parameters (float32), checked
+        against the width the artifact states."""
+        params = {
+            name: np.asarray(tensors[name], dtype=np.float32)
+            for name in GRU_PARAM_NAMES if name in tensors
+        }
+        if len(params) != len(GRU_PARAM_NAMES):
+            raise ValueError("seq MODEL message lacks recurrent weight tensors")
+        if np.shape(params["Wh"]) != (self.dim, 3 * self.dim):
+            raise ValueError(
+                f"seq recurrent weights shaped {np.shape(params['Wh'])} "
+                f"inconsistent with dim={self.dim}"
+            )
+        return params
+
+    def device_params(self, host: dict) -> dict:
+        return {k: jnp.asarray(np.asarray(host[k], dtype=np.float32)) for k in GRU_PARAM_NAMES}
+
+    def init_state(self, slots: int):
+        return None
+
+    def prepare(self, seq_state, context_items):
+        """(vectors [window, d] left-padded, mask [window]) of the newest
+        `window` context items, gathered from the item store."""
+        ctx = list(context_items)[-self.window:]
+        if not ctx:
+            return None
+        vecs, have = seq_state.items.get_many(ctx)
+        if not have.any():
+            return None
+        mat = np.zeros((self.window, self.dim), dtype=np.float32)
+        mask = np.zeros((self.window,), dtype=np.float32)
+        mat[self.window - len(ctx):] = vecs
+        mask[self.window - len(ctx):] = have.astype(np.float32)
+        return mat, mask
+
+    def length(self, prepared) -> int:
+        return self.window
+
+    def pack(self, prepared: list, bucket: int, slots, scratch: int):
+        mats = np.zeros((self.prefill_rows, self.window, self.dim), dtype=np.float32)
+        masks = np.zeros((self.prefill_rows, self.window), dtype=np.float32)
+        for i, (mat, mask) in enumerate(prepared):
+            mats[i], masks[i] = mat, mask
+        return mats, masks
+
+    def prefill(self, params, state, mats, masks):
+        return state, encode_vectors(params, jnp.asarray(mats), jnp.asarray(masks)), None
+
+    def step(self, params, state, head, slots, lengths, live, step):
+        raise NotImplementedError("the GRU answers after prefill")
+
+    loss = staticmethod(_nll)
+
+    def train(self, *args, **kw):
+        return train_gru(*args, **kw)
+
+    def encode_host(self, params: dict, item_vectors: np.ndarray, mask: np.ndarray) -> np.ndarray:
+        """Host-friendly prefill: pre-gathered [B,L,d] item vectors (zeros
+        on padded steps) -> [B,d] hidden states."""
+        return np.asarray(
+            encode_vectors(
+                self.device_params(params),
+                jnp.asarray(np.asarray(item_vectors, dtype=np.float32)),
+                jnp.asarray(np.asarray(mask, dtype=np.float32)),
+            )
         )
-    )
+
+
+def encoder_for(name: str, ext):
+    """The encoder an artifact names; `ext(key, default)` reads the
+    artifact's extensions."""
+    if name == "gru":
+        return GruEncoder(int(ext("dim")), int(ext("window", 8)))
+    if name == "sdar":
+        from oryx_tpu.ops.sdar import SdarEncoder
+
+        return SdarEncoder.from_extensions(ext)
+    raise ValueError(f"unknown seq encoder {name!r}")

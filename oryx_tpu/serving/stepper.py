@@ -1,0 +1,403 @@
+"""Batched encoder step for the seq app: the second kind of device step.
+
+`TopKBatcher` has one kind of step, a top-k scan. A session encoder needs
+another before it: a PREFILL over the session's 15-100 events and, for an
+encoder that generates (ops/sdar.py), a few STEPS over a block of positions
+that read the prefill's keys and values. Both are far too heavy to run once
+a request in the request's thread (a pass streams the model's weights), so
+requests are admitted to cache SLOTS on the device and share dispatches:
+
+    cycle:  pick      admit waiting requests to free slots (at most
+                      `prefill_rows`, in arrival order)
+            launch    ONE prefill dispatch (the admitted sessions, padded to
+                      the length bucket of the longest)
+            launch    ONE step dispatch (every block in flight, whatever
+                      its step: those admitted this cycle take step 0)
+            fetch     block on the PREVIOUS cycle's results (depth-1
+                      pipeline, as TopKBatcher._run: the fetch of cycle N
+                      overlaps the device work of cycle N+1)
+            distribute  finished requests' hidden rows to their futures
+
+A request's state never visits the host between steps: the host counts the
+steps it launched (a block takes exactly `encoder.steps`) and fetches a
+block's rows with the dispatch of its last step. The GRU is a prefill with
+no steps. The encoder is reached through the seam of ops/seq.py only.
+
+Shapes are few and fixed (`prefill_rows` x each length bucket, `step_rows`
+blocks) and all compile before an engine's first request (`warm`); an
+answer does not depend on what shared its dispatches (every row of every
+product is its own).
+
+Counters (docs/observability.md): steps and their real and padded tokens by
+kind, blocks, denoising steps, slots in use; the expert layer's routed
+pairs, experts touched and busiest expert's pairs are counted ON THE DEVICE
+by the step itself (ops/moe.py) and fetched with its result.
+"""
+
+from __future__ import annotations
+
+import itertools
+import logging
+import threading
+import time
+from collections import deque
+from concurrent.futures import Future
+from typing import NamedTuple
+
+import numpy as np
+
+from oryx_tpu.common.metrics import get_registry
+from oryx_tpu.common.perfattr import current_ledger, get_perfattr
+from oryx_tpu.common.tracing import get_tracer
+from oryx_tpu.serving.futureutil import try_set_exception, try_set_result
+
+log = logging.getLogger(__name__)
+
+_TRACER = get_tracer()
+_PA = get_perfattr()
+
+
+class Encoded(NamedTuple):
+    """What the encoder hands the catalog scan for one request."""
+
+    hidden: np.ndarray            # [block, d] float32, one row a position
+    rows: np.ndarray | None       # [block] view rows fixed (None: none fixed)
+    steps: np.ndarray | None      # [block] the step that fixed each
+
+
+class _Req:
+    __slots__ = (
+        "prepared", "length", "future", "ledger", "t_enq", "t_pick",
+        "t_first", "slot", "step",
+    )
+
+    def __init__(self, prepared, length: int, future: Future):
+        self.prepared = prepared
+        self.length = length
+        self.future = future
+        self.ledger = current_ledger()
+        self.t_enq = time.monotonic()
+        self.t_pick = self.t_first = 0.0
+        self.slot = -1
+        self.step = 0
+
+
+class Engine:
+    """One model generation's encoder on the device: its parameters, the
+    slots' state and who holds them. Made by the serving model; after that
+    the stepper's thread alone touches state, slots and lists."""
+
+    def __init__(self, encoder, params, head=None):
+        self.encoder = encoder
+        self.params = encoder.device_params(params)
+        # () -> what `encoder.step` takes as `head` (the catalog view the
+        # step's logits are over); None for an encoder with no steps
+        self.head = head
+        self.slots = max(1, encoder.step_rows)
+        self.state = None
+        self.free = list(range(self.slots))[::-1]
+        self.waiting: deque[_Req] = deque()
+        self.active: list[_Req] = []
+        self.warmed = False
+
+    @property
+    def idle(self) -> bool:
+        return not self.waiting and not self.active
+
+
+class _Metrics:
+    def __init__(self):
+        reg = get_registry()
+        self.steps = reg.counter(
+            "oryx_seq_steps_total",
+            "Encoder dispatches of the seq stepper, by kind (prefill | denoise)",
+            labeled=True,
+        )
+        self.tokens = reg.counter(
+            "oryx_seq_step_tokens_total",
+            "Tokens of the seq stepper's dispatches, by kind and by tokens "
+            "(real | padded: the dispatch's whole shape)",
+            labeled=True,
+        )
+        self.blocks = reg.counter(
+            "oryx_seq_blocks_total", "Blocks the seq stepper finished generating"
+        )
+        self.denoise = reg.counter(
+            "oryx_seq_denoise_steps_total",
+            "Denoising steps taken by the blocks the seq stepper finished",
+        )
+        self.slots = reg.gauge(
+            "oryx_seq_slots_in_use", "Cache slots of the seq stepper held by a request"
+        )
+        self.routed = reg.counter(
+            "oryx_moe_routed_total",
+            "(token, expert) pairs the expert layers routed, counted on the device",
+        )
+        self.touched = reg.counter(
+            "oryx_moe_experts_touched_total",
+            "Experts that received a token, summed over steps and layers",
+        )
+        self.busiest = reg.counter(
+            "oryx_moe_expert_tokens_max_total",
+            "The busiest expert's tokens, summed over steps and layers",
+        )
+
+
+class SeqStepper:
+    """Coalesces session encodes into batched prefill and step dispatches."""
+
+    _shared: "SeqStepper | None" = None
+    _shared_lock = threading.Lock()
+
+    @classmethod
+    def shared(cls) -> "SeqStepper":
+        with cls._shared_lock:
+            if cls._shared is None:
+                cls._shared = cls()
+            return cls._shared
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._cond = threading.Condition(self._lock)
+        self._queue: list[tuple[Engine, _Req]] = []
+        self._thread: threading.Thread | None = None
+        self._closed = False
+        self._cycle_seq = itertools.count(1)
+        self._compiled: set[tuple] = set()
+        self._m = _Metrics()
+        self.cycles = 0
+
+    # -- submit ------------------------------------------------------------
+
+    def submit(self, engine: Engine, prepared) -> Future:
+        """One request's `encoder.prepare` output -> Future of `Encoded`."""
+        fut: Future = Future()
+        req = _Req(prepared, engine.encoder.length(prepared), fut)
+        if req.ledger is not None:
+            # routing + building the query since the last stamped phase
+            tail = req.ledger.last_end()
+            if tail is not None and tail < req.t_enq:
+                req.ledger.add("parse", req.t_enq - tail, start=tail)
+        with self._cond:
+            if self._closed:
+                raise RuntimeError("stepper is closed")
+            if self._thread is None or not self._thread.is_alive():
+                self._thread = threading.Thread(
+                    target=self._run, name="oryx-seq-stepper", daemon=True
+                )
+                self._thread.start()
+            self._queue.append((engine, req))
+            self._cond.notify()
+        return fut
+
+    def close(self) -> None:
+        with self._cond:
+            self._closed = True
+            self._cond.notify_all()
+            t = self._thread
+        if t is not None:
+            t.join(timeout=5)
+
+    # -- the dispatcher thread ---------------------------------------------
+
+    def _run(self) -> None:  # oryxlint: offloop (dedicated dispatcher thread)
+        engines: dict[int, Engine] = {}
+        inflight: list[tuple] = []
+        while True:
+            with self._cond:
+                while not self._queue and not self._closed and not inflight and not engines:
+                    self._cond.wait()
+                if self._closed and not self._queue and not inflight and not engines:
+                    return
+                queued, self._queue = self._queue, []
+            for engine, req in queued:
+                engines[id(engine)] = engine
+                engine.waiting.append(req)
+            launched = []
+            for engine in list(engines.values()):
+                try:
+                    item = self._cycle(engine)
+                except Exception as e:  # noqa: BLE001 - fail the engine's requests, keep the thread
+                    log.exception("stepper cycle failed")
+                    self._fail(engine, e)
+                    item = None
+                if item is not None:
+                    launched.append(item)
+                if engine.idle:
+                    del engines[id(engine)]
+            for item in inflight:
+                self._resolve(item)
+            inflight = launched
+
+    def _fail(self, engine: Engine, e: Exception) -> None:
+        for req in list(engine.waiting) + engine.active:
+            try_set_exception(req.future, e)
+        engine.waiting.clear()
+        engine.active.clear()
+        engine.free = list(range(engine.slots))[::-1]
+        engine.state = None  # a donated state may be gone: start over
+
+    def _first_use(self, key: tuple, t0: float) -> None:
+        """The first dispatch of a shape traces and compiles inside its
+        call: feed the compile telemetry, as the batcher does."""
+        if key not in self._compiled:
+            self._compiled.add(key)
+            _PA.record_compile("seq", time.monotonic() - t0)
+
+    def warm(self, engine: Engine) -> None:
+        """Compile every shape the engine will use, on padding rows alone
+        (they write the scratch slot and reach no expert)."""
+        enc = engine.encoder
+        if enc.steps and engine.state is None:
+            engine.state = enc.init_state(engine.slots)
+        for bucket in enc.length_buckets:
+            t0 = time.monotonic()
+            packed = enc.pack([], bucket, [], engine.slots)
+            engine.state, hidden, _ = enc.prefill(engine.params, engine.state, *packed)
+            np.asarray(hidden)
+            self._first_use((enc.name, "prefill", bucket, id(engine.params)), t0)
+        if enc.steps:
+            t0 = time.monotonic()
+            pad = np.full((enc.step_rows,), engine.slots, dtype=np.int32)
+            zero = np.zeros((enc.step_rows,), dtype=np.int32)
+            engine.state, out = enc.step(
+                engine.params, engine.state, engine.head(), pad, zero,
+                np.zeros((enc.step_rows,), dtype=bool), zero,
+            )
+            np.asarray(out["counts"])
+            self._first_use((enc.name, "step", id(engine.params)), t0)
+        engine.warmed = True
+
+    def _cycle(self, engine: Engine):
+        """Admit, launch this cycle's dispatches, return what `_resolve`
+        needs (None when there was nothing to launch)."""
+        enc = engine.encoder
+        tr = _TRACER
+        if not engine.warmed:
+            self.warm(engine)
+        n = next(self._cycle_seq)
+        with tr.region("stepper.pick", cycle=n):
+            t_pick = time.monotonic()
+            admitted: list[_Req] = []
+            while (
+                engine.waiting and len(admitted) < enc.prefill_rows
+                and (not enc.steps or engine.free)
+            ):
+                req = engine.waiting.popleft()
+                if enc.steps:
+                    req.slot = engine.free.pop()
+                req.t_pick = t_pick
+                admitted.append(req)
+        if not admitted and not engine.active:
+            return None
+        hidden = counts_p = out = None
+        finished: list[tuple[int, _Req]] = []
+        if admitted:
+            bucket = min(
+                b for b in enc.length_buckets if b >= max(r.length for r in admitted)
+            )
+            real = sum(r.length for r in admitted)
+            with tr.region(
+                "stepper.launch", cycle=n, kind="prefill", rows=len(admitted),
+                padded=enc.prefill_rows, tokens=real, bucket=bucket,
+            ):
+                t0 = time.monotonic()
+                packed = enc.pack(
+                    [r.prepared for r in admitted], bucket,
+                    [r.slot for r in admitted], engine.slots,
+                )
+                engine.state, hidden, counts_p = enc.prefill(
+                    engine.params, engine.state, *packed
+                )
+                if not enc.steps:
+                    hidden.copy_to_host_async()
+                if counts_p is not None:
+                    counts_p.copy_to_host_async()
+                self._first_use((enc.name, "prefill", bucket, id(engine.params)), t0)
+            self._m.steps.inc(kind="prefill")
+            self._m.tokens.inc(real, kind="prefill", tokens="real")
+            self._m.tokens.inc(enc.prefill_rows * bucket, kind="prefill", tokens="padded")
+            if enc.steps:
+                engine.active.extend(admitted)
+        if enc.steps and engine.active:
+            rows = engine.active[: enc.step_rows]
+            with tr.region(
+                "stepper.launch", cycle=n, kind="denoise", rows=len(rows),
+                padded=enc.step_rows, tokens=len(rows) * enc.block,
+            ):
+                t0 = time.monotonic()
+                slots = np.full((enc.step_rows,), engine.slots, dtype=np.int32)
+                lengths = np.zeros((enc.step_rows,), dtype=np.int32)
+                step = np.zeros((enc.step_rows,), dtype=np.int32)
+                live = np.zeros((enc.step_rows,), dtype=bool)
+                for i, r in enumerate(rows):
+                    slots[i], lengths[i], step[i], live[i] = r.slot, r.length, r.step, True
+                    r.step += 1
+                engine.state, out = enc.step(
+                    engine.params, engine.state, engine.head(), slots, lengths, live, step,
+                )
+                finished = [(i, r) for i, r in enumerate(rows) if r.step >= enc.steps]
+                out["counts"].copy_to_host_async()
+                if finished:
+                    for key in ("z", "row", "step"):
+                        out[key].copy_to_host_async()
+                self._first_use((enc.name, "step", id(engine.params)), t0)
+            self._m.steps.inc(kind="denoise")
+            self._m.tokens.inc(len(rows) * enc.block, kind="denoise", tokens="real")
+            self._m.tokens.inc(enc.step_rows * enc.block, kind="denoise", tokens="padded")
+            # a finished block's rows ride this dispatch's result: its slot
+            # is free for the next cycle's prefill (the device runs in order)
+            for _, r in finished:
+                engine.active.remove(r)
+                engine.free.append(r.slot)
+        self._m.slots.set(engine.slots - len(engine.free) if enc.steps else 0)
+        self.cycles += 1
+        return n, enc, admitted, hidden, counts_p, finished, out
+
+    def _resolve(self, item: tuple) -> None:
+        n, enc, admitted, hidden_dev, counts_p, finished, out = item
+        try:
+            with _TRACER.region("stepper.fetch", cycle=n):
+                counts = np.zeros((3,), dtype=np.int64)
+                if counts_p is not None:
+                    counts += np.asarray(counts_p)
+                if out is not None:
+                    counts += np.asarray(out["counts"])
+                hidden = np.asarray(hidden_dev) if not enc.steps else None
+                if finished:
+                    z, row, step = (np.asarray(out[k]) for k in ("z", "row", "step"))
+                t_fetch = time.monotonic()
+            if counts.any():
+                self._m.routed.inc(float(counts[0]))
+                self._m.touched.inc(float(counts[1]))
+                self._m.busiest.inc(float(counts[2]))
+            for i, req in enumerate(admitted):
+                # its prefill (and its first step) ran in this cycle
+                req.t_first = t_fetch
+                if not enc.steps:
+                    self._finish(req, t_fetch, Encoded(
+                        np.asarray(hidden[i:i + 1], dtype=np.float32), None, None
+                    ))
+            for i, req in finished:
+                self._m.blocks.inc()
+                self._m.denoise.inc(req.step)
+                self._finish(req, t_fetch, Encoded(
+                    np.asarray(z[i], dtype=np.float32), row[i].astype(np.int64),
+                    step[i].astype(np.int64),
+                ))
+        except Exception as e:  # noqa: BLE001 - a transfer error fails these requests only
+            log.exception("stepper resolve failed")
+            for req in admitted + [r for _, r in finished]:
+                try_set_exception(req.future, e)
+
+    @staticmethod
+    def _finish(req: _Req, t_done: float, result: Encoded) -> None:
+        led = req.ledger
+        if led is not None:
+            # `encode`: admission to the last hidden state on the host; its
+            # parts are stages (they tile it, and join no budget of their own)
+            led.add("encode", t_done - req.t_enq, start=req.t_enq)
+            led.add_stage("encode_wait", req.t_pick - req.t_enq)
+            led.add_stage("prefill", req.t_first - req.t_pick)
+            led.add_stage("denoise", t_done - req.t_first)
+        try_set_result(req.future, result)

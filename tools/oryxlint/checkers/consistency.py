@@ -88,6 +88,19 @@ REQUIRED_PERFATTR_FAMILIES = (
     # oryx_topk_chunks_folded, how much of a whole-block fold a dispatch's
     # real rows still cost; the share waits for a `benchmark` PR too
     "oryx_topk_fold_tiles",
+    # the batched encoder step of the seq app (ISSUE 33): its dispatches
+    # and their real and padded tokens, and the expert layer's load counted
+    # on the device; the benchmark's seq_step_ms / step_tokens /
+    # step_pad_share / moe_load_peak / moe_roofline readers key on them
+    "oryx_seq_encode_stage_seconds",
+    "oryx_seq_steps_total",
+    "oryx_seq_step_tokens_total",
+    "oryx_seq_blocks_total",
+    "oryx_seq_denoise_steps_total",
+    "oryx_seq_slots_in_use",
+    "oryx_moe_routed_total",
+    "oryx_moe_experts_touched_total",
+    "oryx_moe_expert_tokens_max_total",
 )
 
 
