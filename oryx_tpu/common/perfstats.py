@@ -74,19 +74,21 @@ class DispatchRecord:
     ``batcher.*`` regions and the requests' ``batcher.device`` spans carry)
     and its ``k_bucket``; kinds without them (train) leave both None. A
     dispatch through the fused top-k kernel carries the kernel's own count
-    of the item chunks it folded and walked (ops/pallas_topk.py's
-    threshold gate), the dispatch's row blocks beside those of them the
-    kernel did not walk (they lie past the real rows), and the (8, 128)
-    sublane tiles its folds sorted (``fold_tiles``: 16 a fold of a whole
-    128-row block, fewer where the block holds few real rows); every
-    other path leaves those five None."""
+    of the item chunks that fired and that it walked (ops/pallas_topk.py's
+    threshold gate; ``chunks_folded`` counts every fired chunk), the
+    dispatch's row blocks beside those of them the kernel did not walk
+    (they lie past the real rows), the (8, 128) sublane tiles its folds
+    sorted (``fold_tiles``: 16 a fold of a whole 128-row block, fewer
+    where the block holds few real rows) and the fired chunks it placed
+    without a sort (``chunks_inserted``: no row had more than one
+    entrant); every other path leaves those six None."""
 
     __slots__ = (
         "kind", "t_start", "wall_s", "flops", "bytes_moved",
         "rows", "padded_rows", "valid_rows", "capacity_rows",
         "occupancy", "trace_id", "score_mode", "seq",
         "dispatch", "k_bucket", "chunks_folded", "chunks_total",
-        "row_blocks", "row_blocks_skipped", "fold_tiles",
+        "row_blocks", "row_blocks_skipped", "fold_tiles", "chunks_inserted",
     )
 
     def __init__(
@@ -109,6 +111,7 @@ class DispatchRecord:
         row_blocks: int | None = None,
         row_blocks_skipped: int | None = None,
         fold_tiles: int | None = None,
+        chunks_inserted: int | None = None,
     ):
         self.kind = kind
         self.t_start = t_start
@@ -141,6 +144,7 @@ class DispatchRecord:
         self.row_blocks = row_blocks
         self.row_blocks_skipped = row_blocks_skipped
         self.fold_tiles = fold_tiles
+        self.chunks_inserted = chunks_inserted
 
     def chrome_event(self, pid: int) -> dict:
         """This record as a Chrome trace-event `X` slice (Perfetto)."""
@@ -173,6 +177,7 @@ class DispatchRecord:
                 row_blocks=self.row_blocks,
                 row_blocks_skipped=self.row_blocks_skipped,
                 fold_tiles=self.fold_tiles,
+                chunks_inserted=self.chunks_inserted,
             )
         return event
 
@@ -290,6 +295,7 @@ class PerfStats:
         row_blocks: int | None = None,
         row_blocks_skipped: int | None = None,
         fold_tiles: int | None = None,
+        chunks_inserted: int | None = None,
     ) -> DispatchRecord:
         rec = DispatchRecord(
             kind,
@@ -297,7 +303,7 @@ class PerfStats:
             wall_s, flops, bytes_moved, rows, padded_rows, valid_rows,
             capacity_rows, trace_id, score_mode,
             dispatch, k_bucket, chunks_folded, chunks_total,
-            row_blocks, row_blocks_skipped, fold_tiles,
+            row_blocks, row_blocks_skipped, fold_tiles, chunks_inserted,
         )
         rec.seq = next(self._seq)
         buf = self._buf

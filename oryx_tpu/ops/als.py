@@ -1831,10 +1831,11 @@ def topk_dot_batch(
     partials with the cross-shard bitonic merge (ops/shard_topk.py),
     selecting exactly the indices of the unsharded dispatch.
 
-    counted=True appends a third result: the fused kernel's int32[3]
-    device array (item chunks it folded, item chunks it walked — its
-    threshold gate — and the sublane tiles those folds sorted,
-    ops/pallas_topk.py), or None on every other path.
+    counted=True appends a third result: the fused kernel's int32[4]
+    device array (item chunks that fired, item chunks it walked — its
+    threshold gate — the sublane tiles its folds sorted, and the fired
+    chunks it placed without a sort, ops/pallas_topk.py), or None on
+    every other path.
 
     rows: how many leading rows of xs are real, None for all. The fused
     kernel does not walk a row block that lies past them, folds only the

@@ -53,10 +53,10 @@ one by one. A skipped chunk holds nothing that precedes any row's k-th
 element, so the output is bit for bit what folding every chunk gives;
 lanes past k of the running list go stale and are never returned. The
 kernel counts the chunks it folds and the chunks it walks (two int32
-per row block; a third since PR 32, below); the wrapper returns them
-beside the results when asked (`counted=True`), and the batcher puts
-them on the DispatchRecord and into `oryx_topk_chunks_folded` /
-`oryx_topk_chunks`.
+per row block; a third since PR 32 and a fourth since PR 35, below); the
+wrapper returns them beside the results when asked (`counted=True`), and
+the batcher puts them on the DispatchRecord and into
+`oryx_topk_chunks_folded` / `oryx_topk_chunks`.
 
 Measured on one v5e chip (PR 26; 512-row dispatch, k = 128, over a
 6,291,456 x 256 bf16 view holding 5,000,000 rows of standard-normal
@@ -165,6 +165,81 @@ overlaps the next's), and a tile testing its own eight rows against
 fold: sixteen reduces to a scalar a chunk cost more than the tiles they
 save). What is left of a one-row dispatch: the pass, 4.7 ms, and 844
 folds at 3.65 us.
+
+Single entrants (PR 35). What was left of a small dispatch after PR 32
+was the folds: a fired chunk paid the 36 dependent stages (3.0 us on one
+tile, 3.7 with the fired group's store and chunk tests) whatever it
+brought, and most fired chunks bring ONE score above the row's running
+k-th: an item enters a row's top-k of n seen with probability k / n, so
+late in the catalog a chunk that fires holds one entrant and the other
+127 scores are at or below `thr`. For those the sort of the chunk and
+the split and merge compute what a compare and a lane shift give. So a
+chunk's test now says HOW MANY of its scores are above each live row's
+`thr` and the kernel acts on the largest count over the rows (one lane
+sum and one reduce to a scalar, in place of the any-test's reduce, at
+the live width): 0, the chunk is skipped as before; 1, every row has at
+most one entrant and each is placed (`insert_rows`): the row's entrant
+is a lane max of the scores above `thr`, the lanes holding a value at or
+above it stay (the list is sorted descending over all 128 lanes, so they
+are a prefix, and an equal value already held has the lower index and
+stays ahead: the kernel's own strict `>`), the entrant takes the first
+lane outside the prefix and the rest move up one lane (`pltpu.roll`),
+lane 127 falling off; the new k-th is the smaller of the entrant and the
+old (k - 1)-th; a row with no entrant has the entrant -inf, every lane
+stays and `thr` is kept, so rows need no branch of their own. 2 or more
+in some row: the fold, unchanged. It is exact: a chunk score not above
+`thr` cannot be among a row's k best (an equal one loses its tie), so
+lanes [:k] after the insert are the k best of the old k and the entrant,
+bit for bit what the fold gives; for k < 128 a fold would also have
+carried scores between the k-th and the 128-th into lanes past k, which
+are stale by the kernel's own contract, never returned, and stay at or
+below the k-th as every later fold needs. The choice is made from what
+the kernel observes in the chunk it holds, at the same static widths as
+the fold (`_FOLD_TILES`); no caller, option or shape selects it, and
+the int8 kernel takes the same path. The kernel counts the chunks it
+places (a fourth int32 per row block): `oryx_topk_chunks_inserted` over
+`oryx_topk_chunks_folded` (which counts every fired chunk, placed or
+folded, as before) is the share of the path that engages; the tiles
+count only what the folds sorted.
+
+Read first, on the chip, with a build that counts and still folds every
+fired chunk: the share of fired chunks whose largest per-row count is 1
+is 0.83 / 0.86 / 0.90 at 1 / 2 / 5 real rows at k 128 and 0.86 / 0.89 /
+0.91 at k 32 (the issue expected two thirds), and that build is as fast
+as the parent (the count costs what the any-test did). A placed chunk
+costs 0.7-0.9 us with the fired group's share against 3.7 for a folded
+one; at the whole block 0.6 against 6.3. Same view, 512-row dispatch,
+one v5e (PR 35; parent -> this kernel, calls issued back to back as PR
+32's table was taken, the real rows bit for bit the parent's, every
+other row the filler; fired are the parent's folded chunks):
+
+    real rows     bf16 k 128           fired    placed   share placed
+    1              7.51 ->   5.60         775      645      0.83
+    2              9.76 ->   6.27       1,376    1,186      0.86
+    5             15.20 ->   7.63       2,860    2,568      0.90
+    8             19.73 ->   8.69       4,130    3,748      0.91
+    9             21.70 ->   9.28       4,516    4,102      0.91
+    17            34.19 ->  11.78       7,240    6,614      0.91
+    25            43.16 ->  13.43       9,556    8,781      0.92
+    33            59.13 ->  15.71      11,479   10,595      0.92
+    64            84.17 ->  18.96      17,099   15,892      0.93
+    127          154.97 ->  29.17      24,197   22,446      0.93
+    129          162.81 ->  34.54      25,089   23,197      0.92
+    512          621.48 -> 114.63      97,397   90,427      0.93
+    1, bf16 k 32   5.43 ->   4.80         246      211      0.86
+    5, bf16 k 32   8.11 ->   5.55         949      865      0.91
+    5, int8 k 128 14.16 ->   6.65       2,844    2,547      0.90
+    5, int8 k 32   7.06 ->   4.48         957      874      0.91
+
+So kernel(r) = 5.1 + 0.5 r ms where it was 5.9 + 1.9 r, and a dispatch
+of 512 real rows is four passes (19 ms), 7,000 folds (44 ms) and 90,000
+placements (52 ms). Measured against it and not kept: the entrant's
+value and index reduced before the branch, beside the count (within
+0.4 ms of it up to 64 rows, 5-6 % slower at 127-512: the reduces are
+then paid by every tested chunk), and the new k-th read out of the new
+list as a fold does (a fourth reduce, in the chain: within 0.25 ms at
+1-17 rows, 1-7 % slower at 33-512). What is left of a one-row dispatch:
+the pass, 4.7 ms, 130 folds (0.5 ms) and 645 placements (0.4 ms).
 
 The kernel also scores QUANTIZED item matrices (int8 rows + per-row f32
 scales, ops/transfer.py QuantizedMatrix): the int8 stream halves the
@@ -348,6 +423,7 @@ def _topk_kernel(
             counts[0] = 0
             counts[1] = 0
             counts[2] = 0
+            counts[3] = 0
 
         # prefetch block i+1 while block i computes: the double buffer
         @pl.when(i + 1 < ni)
@@ -394,21 +470,67 @@ def _topk_kernel(
                 is_real(n), jnp.broadcast_to(kth, nv.shape), jnp.inf
             )
 
-        def fold_chunk(s_j, col):
-            """Fold one chunk into the live sublane tiles of the block and
-            no others: the narrowest of the widths that holds them. The 36
-            stages are a dependent chain, so a fold's time is the chain's
-            latency plus a little for every tile that rides along; rows of
-            the width past the real ones are sorted and never returned."""
-            counts[0] = counts[0] + 1
-            below = 0
-            for w in fold_widths:
-                def _fold(w=w):
-                    fold_rows(s_j[:w * _SUBLANE], col)
-                    counts[2] = counts[2] + w
+        def insert_rows(scores, above, col):
+            """Place the ONE score of each row that is above its `thr`
+            (`above` marks it; a row with none keeps its list) into the
+            descending running list: the values at or above the entrant
+            stay, the entrant follows them (it came later, so an equal value
+            already held has the lower index and stays ahead), the rest move
+            one lane up and lane 127 falls off. The list is sorted, so "at or
+            above the entrant" is a prefix of the lanes and the entrant's
+            place needs no count: it is the first lane outside the prefix.
+            Lanes [:k] come out as a fold's would; the new k-th is the
+            smaller of the entrant and the old (k - 1)-th."""
+            n = scores.shape[0]
+            rs = slice(0, n)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (n, _LANE), 1)
+            rv, ri, old = run_vals[rs, :], run_idx[rs, :], thr[rs, :]
+            # the entrant's value and index, and the old (k - 1)-th: three lane
+            # reduces that wait on nothing but the chunk's test
+            v = jnp.max(jnp.where(above, scores, -jnp.inf), axis=1, keepdims=True)
+            vi = jnp.max(jnp.where(above, col + lane, 0), axis=1, keepdims=True)
+            if k > 1:
+                kth = jnp.minimum(v, jnp.max(
+                    jnp.where(lane == k - 2, rv, -jnp.inf), axis=1, keepdims=True
+                ))
+            else:
+                kth = v
+            stays = rv >= v  # a row with no entrant: v = -inf, every lane stays
+            up_v, up_i = pltpu.roll(rv, 1, 1), pltpu.roll(ri, 1, 1)  # out[j] = x[j - 1]
+            first_out = jnp.logical_not(stays) & ((up_v >= v) | (lane == 0))
+            run_vals[rs, :] = jnp.where(stays, rv, jnp.where(first_out, v, up_v))
+            run_idx[rs, :] = jnp.where(stays, ri, jnp.where(first_out, vi, up_i))
+            # v > old only in a row with an entrant; a padding row keeps +inf
+            thr[rs, :] = jnp.where(v > old, kth, old)
 
-                pl.when((below < live_tiles) & (live_tiles <= w))(_fold)
-                below = w
+        def gate_chunk(w, at, col):
+            """One chunk of a fired group, at the w sublane tiles that hold
+            the block's real rows: count, per row, the scores above the
+            row's `thr`, and act on the largest count (one reduce to a
+            scalar). 0: nothing of the chunk precedes any row's k-th. 1:
+            every row has at most one entrant, which is placed without a
+            sort. More: the 36 stages. Those are a dependent chain, so a
+            fold's time is the chain's latency plus a little for every tile
+            that rides along; rows of the width past the real ones never
+            fire, are sorted along and never returned."""
+            n = w * _SUBLANE
+            s_j = group_scores[:n, pl.ds(at, _LANE)]
+            above = s_j > thr[:n, :]
+            most = jnp.max(
+                jnp.sum(jnp.where(above, 1.0, 0.0), axis=1, keepdims=True)
+            )
+
+            @pl.when(most == 1.0)
+            def _insert():
+                insert_rows(s_j, above, col)
+                counts[0] = counts[0] + 1
+                counts[3] = counts[3] + 1
+
+            @pl.when(most > 1.0)
+            def _fold():
+                fold_rows(s_j, col)
+                counts[0] = counts[0] + 1
+                counts[2] = counts[2] + w
 
         def gate_group(g, carry):
             """Score n_gate 128-item chunks of the block with one dot and
@@ -455,17 +577,23 @@ def _topk_kernel(
             def _walk():
                 group_scores[:] = scores
 
-                def gate_chunk(j, carry):
-                    at = pl.multiple_of(j * _LANE, _LANE)
-                    s_j = group_scores[:, pl.ds(at, _LANE)]
+                def chunks_at(w):
+                    def gate(j, carry):
+                        at = pl.multiple_of(j * _LANE, _LANE)
+                        gate_chunk(w, at, col0 + at)
+                        return carry
 
-                    @pl.when(beats_kth(s_j))
-                    def _fold():
-                        fold_chunk(s_j, col0 + at)
+                    jax.lax.fori_loop(0, n_gate, gate, 0)
 
-                    return carry
-
-                jax.lax.fori_loop(0, n_gate, gate_chunk, 0)
+                # the group's chunks are tested, placed and folded over the
+                # live sublane tiles of the block and no others: the
+                # narrowest of the widths that holds them
+                below = 0
+                for w in fold_widths:
+                    pl.when((below < live_tiles) & (live_tiles <= w))(
+                        partial(chunks_at, w)
+                    )
+                    below = w
 
             return carry
 
@@ -475,12 +603,14 @@ def _topk_kernel(
         real = is_real(block_b)
         vals_ref[:] = jnp.where(real, run_vals[:], -jnp.inf)
         idx_ref[:] = jnp.where(real, run_idx[:], 0)
-        # lane 0: chunks folded, lane 1: chunks walked, lane 2: sublane
-        # tiles folded, by this row block
+        # lane 0: chunks fired, lane 1: chunks walked, lane 2: sublane tiles
+        # the folds sorted, lane 3: chunks placed without a sort, by this
+        # row block
         lane_c = jax.lax.broadcasted_iota(jnp.int32, counts_ref.shape, 1)
-        counts_ref[:] = jnp.where(
-            lane_c == 0, counts[0], jnp.where(lane_c == 1, counts[1], counts[2])
-        )
+        out = jnp.zeros(counts_ref.shape, jnp.int32)
+        for j in range(4):
+            out = jnp.where(lane_c == j, counts[j], out)
+        counts_ref[:] = out
 
 
 def _pad_to(x, size, axis, value=0.0):
@@ -632,7 +762,8 @@ def _topk_pallas_jit(
     `rows` (int32 scalar, traced: one program whatever it holds) is how
     many leading rows of `xs` are real; a row block past them is not
     walked, and a fold sorts the live sublane tiles of its block alone.
-    Third result: int32[3], (chunks folded, chunks walked, tiles folded)."""
+    Third result: int32[4], (chunks fired, chunks walked, tiles the folds
+    sorted, chunks placed without a sort)."""
     n_b = xs.shape[0]
     feat_pad = y.shape[1]
     if feat_pad % _LANE or y.shape[0] % block_i or xs.shape[1] > feat_pad:
@@ -682,8 +813,8 @@ def _topk_pallas_jit(
             out_specs=[
                 pl.BlockSpec((block_b, _LANE), lambda b, i, rows: (b, 0)),
                 pl.BlockSpec((block_b, _LANE), lambda b, i, rows: (b, 0)),
-                # a row block's (chunks folded, chunks walked, tiles
-                # folded) in lanes 0-2 of an (8, 128) tile
+                # a row block's (chunks fired, chunks walked, tiles
+                # sorted, chunks inserted) in lanes 0-3 of an (8, 128) tile
                 pl.BlockSpec((8, _LANE), lambda b, i, rows: (b, 0)),
             ],
             scratch_shapes=[
@@ -693,7 +824,7 @@ def _topk_pallas_jit(
                 pltpu.VMEM((block_b, n_gate * _LANE), jnp.float32),
                 pltpu.VMEM((2, block_i, feat_pad), y.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SMEM((3,), jnp.int32),
+                pltpu.SMEM((4,), jnp.int32),
             ],
         ),
         out_shape=[
@@ -708,7 +839,7 @@ def _topk_pallas_jit(
         # scale the selected values back into score units (sx > 0, so
         # -inf padding slots stay -inf)
         vals = vals * sx[:n_b, None]
-    return vals, idx, jnp.sum(counts[::8, :3], axis=0)
+    return vals, idx, jnp.sum(counts[::8, :4], axis=0)
 
 
 def topk_dot_batch_pallas(
@@ -740,9 +871,13 @@ def topk_dot_batch_pallas(
     a large catalog fold a few percent of the chunks, every chunk folds
     only where the items are stored in ascending order of score for the
     rows asked about, and a chunk that folds costs about a tenth more
-    than before the gate. counted=True appends an int32[3] array, (chunks
-    folded, chunks walked = row blocks walked x item chunks, sublane
-    tiles those folds sorted), so a caller can see which case it is in.
+    than before the gate. A fired chunk that brings no row more than one
+    such score is not sorted at all: each row's entrant is placed into
+    its running list (a lane max, two lane rotates), with the same result
+    bit for bit. counted=True appends an int32[4] array, (chunks fired =
+    folded or placed, chunks walked = row blocks walked x item chunks,
+    sublane tiles the folds sorted, chunks placed without a sort), so a
+    caller can see which case it is in.
 
     rows: how many leading rows of xs are real (an int, or an int32
     scalar array; never a static argument, so every count shares one
@@ -752,9 +887,10 @@ def topk_dot_batch_pallas(
     block of block_b rows that lies wholly past them is not walked: it
     starts no DMA, runs no gate and counts no chunk. In the block the
     last real row falls in, the rows past it never fire the gate, and a
-    fired chunk is folded over the block's live 8-row sublane tiles alone
-    (at the narrowest of a few widths that holds them), so a dispatch of
-    a few requests in a 512-row block sorts 8 rows a fold, not 128.
+    fired chunk is placed or folded over the block's live 8-row sublane
+    tiles alone (at the narrowest of a few widths that holds them), so a
+    dispatch of a few requests in a 512-row block sorts 8 rows a fold,
+    not 128.
 
     block_b/block_i default to the block rule (`tuned_blocks`): the
     largest pow2 item block whose double-buffered stream + sort

@@ -395,6 +395,9 @@ class TopKBatcher:
         # (8, 128) sublane tiles the kernel's folds sorted: 16 a fold of a
         # whole 128-row block, 1 where the block holds one to eight requests
         self.fold_tiles = 0  # guarded-by: _lock (writes)
+        # fired chunks the kernel placed without a sort: none brought a row
+        # more than one score above its running k-th
+        self.chunks_inserted = 0  # guarded-by: _lock (writes)
         self.host_fallbacks = 0  # guarded-by: _lock (writes)
         self.device_failovers = 0  # guarded-by: _lock (writes)
         # analytic FLOPs dispatched to the device (2·B·I·F per group,
@@ -423,8 +426,9 @@ class TopKBatcher:
              "requests coalesced into device dispatches",
              lambda: float(self.coalesced)),
             ("oryx_topk_chunks_folded",
-             "128-item chunks the fused top-k kernel sorted and merged: "
-             "those holding a score above a row block's running k-th",
+             "128-item chunks that fired in the fused top-k kernel: those "
+             "holding a score above a row block's running k-th, folded "
+             "(sorted and merged) or placed without a sort",
              lambda: float(self.chunks_folded)),
             ("oryx_topk_chunks",
              "128-item chunks the fused top-k kernel walked (row blocks "
@@ -440,9 +444,15 @@ class TopKBatcher:
              lambda: float(self.row_blocks_skipped)),
             ("oryx_topk_fold_tiles",
              "(8, 128) sublane tiles the fused top-k kernel's folds sorted: "
-             "over 16 x oryx_topk_chunks_folded, the share of a whole-block "
-             "fold's work still done",
+             "over 16 x (oryx_topk_chunks_folded - oryx_topk_chunks_inserted), "
+             "the share of a whole-block fold's work still done",
              lambda: float(self.fold_tiles)),
+            ("oryx_topk_chunks_inserted",
+             "fired 128-item chunks the fused top-k kernel placed without a "
+             "sort (no row had more than one entrant): over "
+             "oryx_topk_chunks_folded, the share of fired chunks that cost "
+             "an insert and not the 36 stages",
+             lambda: float(self.chunks_inserted)),
             ("oryx_topk_mean_batch",
              "achieved mean coalesced batch size (coalesced/dispatches "
              "over the process lifetime; >1 means requests are sharing "
@@ -862,8 +872,9 @@ class TopKBatcher:
                                 self._gap_mark = t_disp
                             for cause, s in causes.items():
                                 _PA.record_idle_gap(cause, s)
-                        # chunks: the fused kernel's (folded, walked)
-                        # item-chunk counts, None on every other path.
+                        # chunks: the fused kernel's counts (chunks fired,
+                        # walked, tiles sorted, chunks inserted), None on
+                        # every other path.
                         # rows: the kernel walks no row block past the
                         # group's b real rows
                         vals, idx, chunks = topk_dot_batch(
@@ -963,9 +974,11 @@ class TopKBatcher:
             with _TRACER.region("batcher.fetch", dispatch=n_disp):
                 vals = np.asarray(vals_dev)
                 idx = np.asarray(idx_dev)
-                folded = total = tiles = blocks = skipped = None
+                folded = total = tiles = inserted = blocks = skipped = None
                 if chunks_dev is not None:
-                    folded, total, tiles = (int(c) for c in np.asarray(chunks_dev))
+                    folded, total, tiles, inserted = (
+                        int(c) for c in np.asarray(chunks_dev)
+                    )
                     # the kernel walks whole row blocks: its own count of
                     # chunks walked says how many
                     from oryx_tpu.ops.pallas_topk import dispatch_grid
@@ -987,7 +1000,7 @@ class TopKBatcher:
                     dispatch=n_disp, k_bucket=kb,
                     chunks_folded=folded, chunks_total=total,
                     row_blocks=blocks, row_blocks_skipped=skipped,
-                    fold_tiles=tiles,
+                    fold_tiles=tiles, chunks_inserted=inserted,
                 )
                 # the dispatch completed, so this shape's compile is done:
                 # drop its grace window and never grant it one again. Both
@@ -1030,6 +1043,7 @@ class TopKBatcher:
                         self.row_blocks += blocks
                         self.row_blocks_skipped += skipped
                         self.fold_tiles += tiles
+                        self.chunks_inserted += inserted
                     # result-distribution tail: host work the device idles
                     # behind (the host_serialize slice of the next gap)
                     self._gap_resolve += time.monotonic() - t_fetch

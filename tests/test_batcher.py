@@ -169,17 +169,25 @@ def test_fused_dispatch_counts_the_row_blocks_the_kernel_skipped(
     assert (b.row_blocks, b.row_blocks_skipped) == (4, 4 - live)
     # 3 requests: every fold sorts the one live tile; 512: whole blocks of
     # sixteen; 129: block 0 whole and block 1's one live tile
+    # the kernel's fourth count rides along: the fired chunks it placed
+    # without a sort, which sort no tile (ISSUE 35)
     assert rec.chunks_folded >= live
+    sorts = rec.chunks_folded - rec.chunks_inserted
+    assert 0 <= rec.chunks_inserted <= rec.chunks_folded - live  # chunk 0 folds
     lo, hi = {3: (1, 1), 129: (2, 15), 512: (16, 16)}[requests]
-    assert lo * rec.chunks_folded <= rec.fold_tiles <= hi * rec.chunks_folded
+    assert lo * sorts <= rec.fold_tiles <= hi * sorts
     assert args["fold_tiles"] == b.fold_tiles == rec.fold_tiles
+    assert args["chunks_inserted"] == b.chunks_inserted == rec.chunks_inserted
     gauges = dict(
         line.split() for line in get_registry().render_prometheus().splitlines()
-        if line.startswith(("oryx_topk_row_blocks", "oryx_topk_fold_tiles"))
+        if line.startswith(
+            ("oryx_topk_row_blocks", "oryx_topk_fold_tiles", "oryx_topk_chunks_inserted")
+        )
     )
     assert float(gauges["oryx_topk_row_blocks"]) == 4.0
     assert float(gauges["oryx_topk_row_blocks_skipped"]) == float(4 - live)
     assert float(gauges["oryx_topk_fold_tiles"]) == float(rec.fold_tiles)
+    assert float(gauges["oryx_topk_chunks_inserted"]) == float(rec.chunks_inserted)
     for p in reqs[:: max(1, requests // 7)]:
         assert list(p.future.result(timeout=5)[1]) == list(_direct(p.vec, 10, y)[1])
 
@@ -193,9 +201,9 @@ def test_a_dispatch_off_the_fused_path_counts_no_row_blocks(y):
     b.close()
     (rec,) = [r for r in get_perfstats().records_since(t_mark) if r.kind == "serving"]
     assert rec.row_blocks is None and rec.row_blocks_skipped is None
-    assert rec.fold_tiles is None
-    assert not {"row_blocks", "fold_tiles"} & set(rec.chrome_event(1)["args"])
-    assert (b.row_blocks, b.row_blocks_skipped, b.fold_tiles) == (0, 0, 0)
+    assert rec.fold_tiles is None and rec.chunks_inserted is None
+    assert not {"row_blocks", "fold_tiles", "chunks_inserted"} & set(rec.chrome_event(1)["args"])
+    assert (b.row_blocks, b.row_blocks_skipped, b.fold_tiles, b.chunks_inserted) == (0, 0, 0, 0)
 
 
 def test_k_larger_than_items():

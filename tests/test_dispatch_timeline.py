@@ -169,11 +169,14 @@ def test_fused_dispatch_carries_the_kernels_fold_count(y, monkeypatch):
         row_blocks = -(-r.padded_rows // 8)
         assert r.chunks_total == 2 * row_blocks
         assert row_blocks <= r.chunks_folded <= r.chunks_total
-        # a row block of 8 is one sublane tile: one tile a fold
-        assert r.fold_tiles == r.chunks_folded
+        # a row block of 8 is one sublane tile: one tile a fold, none for a
+        # fired chunk placed without a sort (the kernel's fourth count)
+        assert r.fold_tiles == r.chunks_folded - r.chunks_inserted
+        assert 0 <= r.chunks_inserted <= r.chunks_folded - row_blocks
         args = r.chrome_event(1)["args"]
         assert (args["chunks_folded"], args["chunks_total"]) == (r.chunks_folded, r.chunks_total)
         assert args["fold_tiles"] == r.fold_tiles
+        assert args["chunks_inserted"] == r.chunks_inserted
     moved = (batcher.chunks_folded - before[0], batcher.chunks_total - before[1])
     assert moved == (
         sum(r.chunks_folded for r in records), sum(r.chunks_total for r in records)
@@ -181,6 +184,7 @@ def test_fused_dispatch_carries_the_kernels_fold_count(y, monkeypatch):
     assert _gauge("oryx_topk_chunks_folded") == float(batcher.chunks_folded)
     assert _gauge("oryx_topk_chunks") == float(batcher.chunks_total)
     assert _gauge("oryx_topk_fold_tiles") == float(batcher.fold_tiles) > 0
+    assert _gauge("oryx_topk_chunks_inserted") == float(batcher.chunks_inserted)
 
 
 @pytest.fixture

@@ -5,6 +5,8 @@ is chip_smoke.py's job."""
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -376,10 +378,43 @@ def _along_a_line(scale_of_item, rows=5):
     return xs, y
 
 
+def _from_scores(scores):
+    """(xs, y) whose plain scores are `scores` [rows, items]: row r asks for
+    feature r alone, so small integers and halves come out exact in bf16 too."""
+    return np.eye(scores.shape[0], dtype=np.float32), np.ascontiguousarray(scores.T)
+
+
+def _held_lists(rows, n=1500):
+    """Scores [rows, n] whose chunk 0 gives every row the list 4, 3, 2, 1 (items
+    0-3) and whose every other score is a filler far below: what a later chunk
+    brings is written into it by the case."""
+    scores = np.full((rows, n), -50.0, dtype=np.float32)
+    scores[:, :4] = [4.0, 3.0, 2.0, 1.0]
+    return scores
+
+
 def _gate_case(name):
     """(xs, y, k, block_i) of one exactness case."""
     rng = np.random.default_rng(GATE_CASES.index(name))
     n = 1500  # 12 chunks of 128 over 3 blocks of 512, the last chunk short
+    if name == "single-entrants-tied":
+        # one entrant a row a chunk, so each is placed without a sort: equal to
+        # a held value (it loses the tie to nothing held and goes after it),
+        # above the best (lane 0), just above the k-th (lane k - 1); a score
+        # EQUAL to the k-th does not enter and does not fire
+        s = _held_lists(2, n)
+        s[0, :4] = [9.0, 7.0, 5.0, 3.0]
+        s[0, 300], s[0, 400], s[0, 700] = 7.0, 5.0, 9.0  # 400: the k-th by then
+        s[1, 410], s[1, 701], s[1, 900] = 10.0, 2.0, 2.5  # 701: the k-th by then
+        return (*_from_scores(s), 4, 512)
+    if name == "two-and-one":  # two entrants in one row, one in another: a fold
+        s = _held_lists(2, n)
+        s[0, 300], s[0, 301], s[1, 310] = 8.0, 6.0, 10.0
+        return (*_from_scores(s), 4, 512)
+    if name == "one-each":  # one entrant in each of two rows: an insert
+        s = _held_lists(2, n)
+        s[0, 300], s[1, 310] = 8.0, 10.0
+        return (*_from_scores(s), 4, 512)
     if name == "ascending":  # every chunk holds a new best: every chunk fires
         return (*_along_a_line(np.arange(n)), 16, 512)
     if name == "descending":  # nothing after the first chunk can enter
@@ -410,21 +445,29 @@ GATE_CASES = [
     "ascending", "descending", "all-equal", "tie-runs-ascending",
     "tie-runs-descending", "zero-rows-after-real", "short-tail",
     "k=1", "k=10", "k=16", "k=32", "k=100", "k=128",
+    "single-entrants-tied", "two-and-one", "one-each",
 ]
 
+# (chunks fired, sublane tiles sorted, chunks inserted) of the built cases:
+# chunk 0 folds (128 scores above -inf), every later fired chunk is as named
+_BUILT_COUNTS = {
+    "single-entrants-tied": (5, 1, 4), "two-and-one": (2, 2, 0), "one-each": (2, 1, 1),
+}
 
-def _model_folds(scores, k, block_b, rows=None, tiles=False):
-    """What the kernel's gate should count, walked in numpy: per row
-    block, the chunks holding a score above some REAL row's running k-th
-    (the first `rows` rows of `scores` are real, None for all; a padding
-    row never fires and is never folded). tiles=True returns (chunks
-    folded, sublane tiles folded) by the kernel's rule: a fold sorts the
-    narrowest of its widths that holds the block's live (8-row) tiles."""
+
+def _model_counts(scores, k, block_b, rows=None):
+    """What the kernel should count, walked in numpy: (chunks fired, sublane
+    tiles sorted, chunks inserted). Per row block, a chunk fires if it holds
+    a score above some REAL row's running k-th (the first `rows` rows of
+    `scores` are real, None for all; a padding row never fires). A fired
+    chunk that brings no row more than one such score is inserted and sorts
+    nothing; any other is folded, and a fold sorts the narrowest of its
+    widths that holds the block's live (8-row) tiles."""
     from oryx_tpu.ops.pallas_topk import _FOLD_TILES
 
     n_rows, n = scores.shape
     rows = n_rows if rows is None else min(rows, n_rows)
-    folds = tile_count = 0
+    fired = tile_count = inserted = 0
     for b in range(0, rows, block_b):
         blk = scores[b:min(b + block_b, rows)]  # the block's real rows
         live_tiles, n_tiles = -(-blk.shape[0] // 8), block_b // 8
@@ -432,11 +475,13 @@ def _model_folds(scores, k, block_b, rows=None, tiles=False):
         top = np.full((blk.shape[0], k), -np.inf, dtype=np.float32)  # descending
         for c in range(0, n, 128):
             chunk = blk[:, c:c + 128]
-            if (chunk > top[:, -1:]).any():
-                folds += 1
-                tile_count += width
+            most = int((chunk > top[:, -1:]).sum(axis=1).max())
+            if most:
+                fired += 1
+                inserted += most == 1
+                tile_count += width * (most > 1)
                 top = -np.sort(-np.concatenate([top, chunk], axis=1), axis=1)[:, :k]
-    return (folds, tile_count) if tiles else folds
+    return fired, tile_count, inserted
 
 
 @pytest.mark.parametrize("name", GATE_CASES)
@@ -450,13 +495,16 @@ def test_gated_kernel_is_bit_identical_to_top_k_of_the_plain_scores(name):
     )
     assert np.array_equal(np.asarray(i), np.asarray(i_ref))
     assert np.array_equal(np.asarray(v), np.asarray(v_ref))
-    # the kernel's own count of the chunks it folded, against the model's;
-    # every fold of an 8-row block sorts its one sublane tile
-    folded, total, tiles = (int(c) for c in np.asarray(chunks))
+    # the kernel's own counts against the model's: a fired chunk of an 8-row
+    # block is inserted, or folded and sorts its one sublane tile
+    folded, total, tiles, inserted = (int(c) for c in np.asarray(chunks))
     row_blocks = -(-xs.shape[0] // 8)
     item_chunks = -(-y.shape[0] // block_i) * (block_i // 128)
     assert total == row_blocks * item_chunks
-    assert tiles == folded == _model_folds(scores, k, 8) <= total
+    assert (folded, tiles, inserted) == _model_counts(scores, k, 8)
+    assert tiles + inserted == folded <= total
+    if name in _BUILT_COUNTS:
+        assert (folded, tiles, inserted) == _BUILT_COUNTS[name]
     real_chunks = -(-y.shape[0] // 128)
     if name == "ascending":
         assert folded == row_blocks * real_chunks  # the worst case: all of them
@@ -476,7 +524,7 @@ def test_gated_kernel_with_fewer_items_than_k_keeps_neg_inf_slots():
     assert np.array_equal(np.asarray(i)[:, :40], order)
     assert np.array_equal(np.asarray(v)[:, :40], np.take_along_axis(scores, order, axis=1))
     assert np.all(np.isneginf(np.asarray(v)[:, 40:]))
-    assert [int(c) for c in np.asarray(chunks)] == [1, 1, 1]
+    assert [int(c) for c in np.asarray(chunks)] == [1, 1, 1, 0]
 
 
 @pytest.mark.parametrize("k", [10, 128])
@@ -502,10 +550,10 @@ def test_gated_int8_kernel_is_bit_identical_to_top_k_of_its_scores(k):
     )
     assert np.array_equal(np.asarray(i), np.asarray(i_ref))
     assert np.array_equal(np.asarray(v), np.asarray(v_ref))
-    folded, total, tiles = (int(c) for c in np.asarray(chunks))
+    folded, total, tiles, inserted = (int(c) for c in np.asarray(chunks))
     # the last of the two 8-row blocks holds the batch pad's rows 10-15
-    assert (folded, tiles) == _model_folds(scores, k, 8, tiles=True)
-    assert folded < total
+    assert (folded, tiles, inserted) == _model_counts(scores, k, 8)
+    assert inserted <= folded < total
 
 
 # -- row blocks past the real rows are not walked (ISSUE 30), and a fold works
@@ -519,28 +567,34 @@ def test_gated_int8_kernel_is_bit_identical_to_top_k_of_its_scores(k):
 _REAL_ROWS = [1, 5, 7, 8, 9, 64, 127, 128, 129, 300, 511, 512]
 
 
-def _padded_dispatch(dtype):
+@functools.lru_cache(maxsize=None)
+def _padded_dispatch(dtype, items=1500, taper=1.0):
     """(xs of 512 real rows, item operands, plain scores of them): integer
-    factors, so both forms score exactly and are compared bit for bit."""
+    factors, so both forms score exactly and are compared bit for bit. The
+    item factors shrink along the catalog to `taper` of their size (still
+    integers), so late chunks bring a row fewer entrants."""
     rng = np.random.default_rng(30)
+    shrink = (1.0 - (1.0 - taper) * np.arange(items) / items)[:, None]
     if dtype == "int8":
-        q = rng.integers(-127, 128, size=(1500, 16)).astype(np.int8)
-        scale = rng.choice([0.25, 0.5, 1.0, 2.0], size=1500).astype(np.float32)
+        q = rng.integers(-127, 128, size=(items, 16))
+        q = np.trunc(q * shrink).astype(np.int8)
+        scale = rng.choice([0.25, 0.5, 1.0, 2.0], size=items).astype(np.float32)
         xs = _int_factors(rng, 512, 16, -127, 127)
         xs[:, 0] = 127.0  # every real row quantizes to itself (scale 1)
         scores = (xs.astype(np.int64) @ q.T.astype(np.int64)).astype(np.float32) * scale
         return xs, dict(y=jnp.asarray(q), scales=jnp.asarray(scale)), scores
-    xs, y = _int_factors(rng, 512, 12), _int_factors(rng, 1500, 12)
+    xs, y = _int_factors(rng, 512, 12), _int_factors(rng, items, 12)
+    y = np.trunc(y * shrink).astype(np.float32)
     return xs, dict(y=jnp.asarray(y, dtype=jnp.bfloat16)), xs @ y.T
 
 
-def _run_padded(xs, operands, rows, real=None, k=32):
+def _run_padded(xs, operands, rows, real=None, k=32, block_i=512):
     x = xs.copy()
     x[rows:] = 0.0
     dtype = jnp.float32 if "scales" in operands else jnp.bfloat16
     v, i, c = topk_dot_batch_pallas(
         jnp.asarray(x, dtype=dtype), operands["y"], scales=operands.get("scales"),
-        k=k, block_i=512, interpret=True, counted=True, rows=real,
+        k=k, block_i=block_i, interpret=True, counted=True, rows=real,
     )
     return np.asarray(v), np.asarray(i), [int(n) for n in np.asarray(c)]
 
@@ -553,8 +607,10 @@ def _is_filler(v, i):
 @pytest.mark.parametrize("rows", _REAL_ROWS)
 def test_row_blocks_past_the_real_rows_are_not_walked(rows, dtype):
     xs, operands, scores = _padded_dispatch(dtype)
-    v, i, (folded, walked, tiles) = _run_padded(xs, operands, rows, real=rows)
-    v_all, i_all, (folded_all, walked_all, tiles_all) = _run_padded(xs, operands, rows)
+    v, i, (folded, walked, tiles, inserted) = _run_padded(xs, operands, rows, real=rows)
+    v_all, i_all, (folded_all, walked_all, tiles_all, inserted_all) = _run_padded(
+        xs, operands, rows
+    )
     # the real rows: what calling every row real gives, and lax.top_k's answer
     v_ref, i_ref = jax.lax.top_k(jnp.asarray(scores[:rows]), 32)
     assert np.array_equal(v[:rows], v_all[:rows]) and np.array_equal(i[:rows], i_all[:rows])
@@ -567,15 +623,19 @@ def test_row_blocks_past_the_real_rows_are_not_walked(rows, dtype):
     # the tiles its folds sorted, against the plain model of them
     live = -(-rows // 128)
     assert (walked, walked_all) == (live * 12, 4 * 12)
-    assert (folded, tiles) == _model_folds(scores, 32, 128, rows=rows, tiles=True)
+    assert (folded, tiles, inserted) == _model_counts(scores, 32, 128, rows=rows)
     padded = np.where(np.arange(512)[:, None] < rows, scores, 0.0)
-    assert (folded_all, tiles_all) == _model_folds(padded, 32, 128, tiles=True)
-    assert tiles_all == 16 * folded_all  # every row real: whole blocks alone
-    assert folded_all == folded + (4 - live)  # a block of zero rows folds once
+    assert (folded_all, tiles_all, inserted_all) == _model_counts(padded, 32, 128)
+    # every row real: the folds sort whole blocks alone
+    assert tiles_all == 16 * (folded_all - inserted_all)
+    # a block of zero rows folds once (128 scores of 0.0 above -inf), and the
+    # zero rows of a live block enter with the first chunk, which folds anyway
+    assert (folded_all, inserted_all) == (folded + (4 - live), inserted)
+    sorts = folded - inserted  # the fired chunks that were folded
     if rows < 128:  # the narrowest width over the live tiles of block 0
-        assert tiles == folded * {1: 1, 5: 1, 7: 1, 8: 1, 9: 2, 64: 8, 127: 16}[rows]
+        assert tiles == sorts * {1: 1, 5: 1, 7: 1, 8: 1, 9: 2, 64: 8, 127: 16}[rows]
     if rows == 129:
-        assert folded < tiles < 16 * folded  # block 0 whole, block 1 one tile
+        assert sorts < tiles < 16 * sorts  # block 0 whole, block 1 one tile
 
 
 @pytest.mark.parametrize("dtype", ["bf16", "int8"])
@@ -588,7 +648,7 @@ def test_every_row_real_is_what_no_count_gives(dtype):
     assert _run_padded(xs, operands, 512, real=10_000)[2] == plain[2]
     # no real row at all: nothing is walked or folded, every row is filler
     v, i, counts = _run_padded(xs, operands, 512, real=0)
-    assert counts == [0, 0, 0] and _is_filler(v, i)
+    assert counts == [0, 0, 0, 0] and _is_filler(v, i)
 
 
 @pytest.mark.parametrize("rows", [1, 8, 9, 40, 128, 136])
@@ -599,7 +659,7 @@ def test_live_tiles_in_the_worst_case_every_chunk_folds(rows):
     xs, y = _along_a_line(np.arange(1500), rows=rows)
     xs = np.concatenate([xs, np.zeros((512 - rows, 1), np.float32)])
     scores = xs[:rows] @ y.T
-    v, i, (folded, walked, tiles) = (np.asarray(a) for a in topk_dot_batch_pallas(
+    v, i, (folded, walked, tiles, inserted) = (np.asarray(a) for a in topk_dot_batch_pallas(
         jnp.asarray(xs), jnp.asarray(y), k=128, block_i=512, interpret=True,
         counted=True, rows=rows,
     ))
@@ -608,7 +668,9 @@ def test_live_tiles_in_the_worst_case_every_chunk_folds(rows):
     assert _is_filler(v[rows:], i[rows:])
     live = -(-rows // 128)
     assert (int(folded), int(walked)) == (live * 12, live * 12)
-    assert (int(folded), int(tiles)) == _model_folds(scores, 128, 128, tiles=True)
+    # 128 new bests a row a chunk: nothing is a single entrant
+    assert (int(folded), int(tiles), int(inserted)) == _model_counts(scores, 128, 128)
+    assert int(inserted) == 0
     # 1-8 rows: one tile a fold; 9: two; 40: five live, so the width of
     # eight; a full block: all sixteen; 136: sixteen for block 0, one for 1
     assert int(tiles) == 12 * {1: 1, 8: 1, 9: 2, 40: 8, 128: 16, 136: 17}[rows]
@@ -624,7 +686,7 @@ def test_duplicate_scores_straddling_a_tile_boundary(rows):
     xs = np.zeros((512, 12), np.float32)
     xs[:rows] = _int_factors(rng, 1, 12)
     scores = xs[:rows] @ y.T
-    v, i, (folded, _, tiles) = (np.asarray(a) for a in topk_dot_batch_pallas(
+    v, i, (folded, _, tiles, inserted) = (np.asarray(a) for a in topk_dot_batch_pallas(
         jnp.asarray(xs), jnp.asarray(y), k=25, block_i=512, interpret=True,
         counted=True, rows=rows,
     ))
@@ -632,7 +694,83 @@ def test_duplicate_scores_straddling_a_tile_boundary(rows):
     assert np.array_equal(v[:rows], np.asarray(v_ref)) and np.array_equal(i[:rows], np.asarray(i_ref))
     assert all(np.array_equal(i[r], i[0]) for r in range(rows))
     assert _is_filler(v[rows:], i[rows:])
-    assert int(tiles) == int(folded) * {1: 1, 2: 2, 3: 4}[-(-rows // 8)]
+    assert int(tiles) == int(folded - inserted) * {1: 1, 2: 2, 3: 4}[-(-rows // 8)]
+
+
+# -- a fired chunk's single entrants are placed without the sort (ISSUE 35) ----
+#
+# A chunk that brings no row more than one score above the row's running k-th
+# is inserted: lanes [:k] must come out bit for bit as a fold's, whatever the
+# width it is placed at, and the kernel's fourth count says how often.
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("rows", [1, 5, 9, 40, 129])
+@pytest.mark.parametrize("k", [10, 32, 128])
+def test_single_entrants_are_placed_bit_for_bit(k, rows, dtype):
+    # 16,384 items in 128 chunks, their factors shrinking to four ninths: the
+    # late chunks that fire bring a row one entrant more often than two, so
+    # inserts and folds alternate at every width (1, 2 and 8 tiles, the whole
+    # block, a whole block and a tile)
+    xs, operands, scores = _padded_dispatch(dtype, items=16384, taper=4 / 9)
+    v, i, (folded, walked, tiles, inserted) = _run_padded(
+        xs, operands, rows, real=rows, k=k, block_i=2048
+    )
+    v_ref, i_ref = jax.lax.top_k(jnp.asarray(scores[:rows]), k)
+    assert np.array_equal(v[:rows], np.asarray(v_ref)) and np.array_equal(i[:rows], np.asarray(i_ref))
+    assert _is_filler(v[rows:], i[rows:])
+    assert (folded, tiles, inserted) == _model_counts(scores, k, 128, rows=rows)
+    assert 0 < inserted < folded < walked == -(-rows // 128) * 128
+
+
+@pytest.mark.parametrize(
+    "row_a,row_b,counts",
+    [
+        (0, 1, (2, 1, 1)),      # one tile
+        (0, 8, (2, 2, 1)),      # two tiles: placed at the width of two
+        (3, 20, (2, 4, 1)),     # three live tiles: the width of four
+        (2, 127, (2, 16, 1)),   # the whole block
+        (5, 130, (4, 17, 2)),   # a whole block and one tile of the next
+    ],
+    ids=["one-tile", "two-tiles", "four-tiles", "whole-block", "two-blocks"],
+)
+def test_single_entrants_in_rows_of_different_tiles(row_a, row_b, counts):
+    # chunk 0 folds into every real row (4, 3, 2, 1); chunk 2 brings one entrant
+    # to row_a (a new best) and one to row_b (just above its k-th) and nothing to
+    # the rows between: placed, at the width that holds the block's live tiles
+    rows = row_b + 1
+    scores = _held_lists(rows)
+    scores[row_a, 300], scores[row_b, 310] = 10.0, 1.5
+    # 136 features in every case (the rows' own, then zeros): one program
+    xs, y = np.zeros((512, 136), np.float32), np.zeros((1500, 136), np.float32)
+    xs[:rows, :rows], y[:, :rows] = _from_scores(scores)
+    v, i, chunks = (np.asarray(a) for a in topk_dot_batch_pallas(
+        jnp.asarray(xs), jnp.asarray(y), k=4, block_i=512, interpret=True,
+        counted=True, rows=rows,
+    ))
+    v_ref, i_ref = jax.lax.top_k(jnp.asarray(scores), 4)
+    assert np.array_equal(v[:rows], np.asarray(v_ref)) and np.array_equal(i[:rows], np.asarray(i_ref))
+    assert list(i[row_a]) == [300, 0, 1, 2] and list(i[row_b]) == [0, 1, 2, 310]
+    assert _is_filler(v[rows:], i[rows:])
+    folded, _, tiles, inserted = (int(c) for c in chunks)
+    assert (folded, tiles, inserted) == counts == _model_counts(scores, 4, 128)
+
+
+def test_counts_of_a_standard_normal_catalog():
+    # (fired, walked, tiles sorted, inserted): an insert is a fired chunk and
+    # sorts nothing; over 20,000 standard-normal items most late chunks that
+    # fire bring one entrant
+    rng = np.random.default_rng(35)
+    xs = jnp.asarray(rng.normal(size=(3, 16)), dtype=jnp.float32)
+    y = jnp.asarray(rng.normal(size=(20_000, 16)), dtype=jnp.float32)
+    v, i, chunks = topk_dot_batch_pallas(
+        xs, y, k=10, block_b=8, block_i=1024, interpret=True, counted=True
+    )
+    v_ref, i_ref = topk_dot_batch_xla(xs, y, k=10)
+    assert np.array_equal(np.asarray(i), np.asarray(i_ref))
+    np.testing.assert_allclose(np.asarray(v), np.asarray(v_ref), atol=1e-4)
+    fired, walked, tiles, inserted = (int(c) for c in np.asarray(chunks))
+    assert walked == 160 and tiles == fired - inserted
+    assert 0 < inserted <= fired < walked and inserted > fired // 2
 
 
 def test_the_count_of_real_rows_is_traced_not_compiled_in():

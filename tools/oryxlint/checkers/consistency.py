@@ -88,6 +88,10 @@ REQUIRED_PERFATTR_FAMILIES = (
     # oryx_topk_chunks_folded, how much of a whole-block fold a dispatch's
     # real rows still cost; the share waits for a `benchmark` PR too
     "oryx_topk_fold_tiles",
+    # the fired chunks the kernel placed without a sort (ISSUE 35): over
+    # oryx_topk_chunks_folded, the share of the single-entrant path that
+    # engages; that share waits for a `benchmark` PR as well
+    "oryx_topk_chunks_inserted",
     # the batched encoder step of the seq app (ISSUE 33): its dispatches
     # and their real and padded tokens, and the expert layer's load counted
     # on the device; the benchmark's seq_step_ms / step_tokens /
