@@ -202,13 +202,15 @@ class SeqServingModel(ServingModel):
     def _head(self) -> tuple:
         """What a generating encoder's step takes its logits over: the
         served view, its real rows, and for each view row its row of the
-        encoder's input embedding (the last, [MASK], where the item came by
-        UP after the model and has none yet)."""
+        encoder's input embedding (the encoder's `unknown_token`, [MASK],
+        where the item came by UP after the model and has none yet)."""
         y_dev, ids, _version, _host = self._view()
+        mask_id = self.state.encoder.unknown_token
+        if mask_id is None:  # the step feeds back the view's own row (a tied embedding)
+            return y_dev, len(ids), None
         cached = self._row_token
         if cached is None or cached[0] is not ids:
             token_of = self.state.token_of
-            mask_id = self.state.encoder.cfg.mask_id
             rows = np.full((int(y_dev.shape[0]),), mask_id, dtype=np.int32)
             rows[: len(ids)] = [token_of.get(i, mask_id) for i in ids]
             cached = (ids, jnp.asarray(rows))

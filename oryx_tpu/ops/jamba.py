@@ -1,0 +1,693 @@
+"""A hybrid state-space / attention decoder over the item catalog (`jamba`:
+Mamba-1 layers with an attention layer every `attn_layer_period`, a dense
+SwiGLU feed-forward in every layer, generation token by token).
+
+    layer:   h = x + Mixer(RMSNorm(x));  y = h + MLP(RMSNorm(h))
+    Mixer:   attention where l % attn_layer_period == attn_layer_offset,
+             else Mamba
+    Mamba:   [x, z] = u W_in;  x = silu(conv1d_causal(x) + b_conv)  (depthwise,
+             width d_conv);  [dt, B, C] = x W_x, each RMS-normalised (a learned
+             weight each);  dt = softplus(dt W_dt + b_dt);  A = -exp(A_log);
+             h_t = exp(dt_t A) h_{t-1} + (dt_t B_t) x_t;  y_t = h_t C_t + D x_t;
+             out = (y silu(z)) W_out.  No biases but the conv's and b_dt.
+    Attn:    q = u Wq [heads x d], k = u Wk, v = u Wv [kv_heads x d]; causal
+             softmax(q k^T / sqrt(d)) v; Wo.  No positions, no biases.
+    MLP:     (silu(u Wg) * (u Wu)) Wd
+    out:     final RMSNorm, logits = z E^T over the TIED embedding
+
+The vocabulary is the item catalog: the embedding is tied, so row i of the
+served view (the FactorStore's "E") is item i's output row and row t of
+`E_in` the same values as the input embedding of announced id t.
+
+Generation, a basket of B items a request: `prefill` runs all but the last
+of the session's events into a cache slot; then B `step`s, one token each:
+step 0 feeds the last event, step i the item step i-1 chose (the argmax of
+the head over the view's real rows, fed back on the device). The hidden
+state of step i is what the catalog scan ranks for position i.
+
+A slot holds two kinds of state side by side: a Mamba layer's recurrent
+state h [d_state, d_inner] and the last d_conv - 1 inputs of its conv, both
+of a fixed size whatever the session's length, and an attention layer's
+keys and values, one row a position. A padded position changes neither.
+
+Precision: weights in their stored dtype (bfloat16 as published), the
+activations enter every product in that dtype and accumulate in float32; the
+residual stream, the norms, the softmax, the softplus, the conv, dt, A and the
+recurrence with its state are float32; keys and values are stored in the
+weights' dtype.
+
+Two forms live here. `prefill` / `decode_step` are the served ones: the slot cache
+on the device, fixed shapes, the recurrence as a Pallas kernel chunked over
+positions (`selective_scan`). `reference_forward` is the plain one: float32,
+`highest` precision, a sequential scan, full causal attention, no cache.
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+from typing import NamedTuple
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from oryx_tpu.ops.sdar import _attend, _normal, rms_norm
+from oryx_tpu.ops.seq import announced_tokens, catalog_head
+
+# tensors of a Jamba artifact, beside the catalog ("E", the FactorStore's):
+# "E_in", "final_norm" and, for layer l, "L<l>.<name>" of `layer_shapes`: the
+# feed-forward's in every layer, the mixer's by the layer's kind. Channels lie
+# on the last axis (the lanes): `conv_w` is [d_conv, d_inner] and `A_log`
+# [d_state, d_inner]
+NORM_TENSORS = ("ln1", "ln2", "dt_norm", "b_norm", "c_norm")
+# the recurrence's own parameters stay float32 whatever the weights' dtype
+FLOAT32_TENSORS = ("dt_bias", "A_log", "D")
+DT_INIT = (1e-3, 1e-1)  # softplus(b_dt) is drawn log-uniform in this range
+
+_LANE = 128
+SCAN_CHUNK = 8       # positions a grid step of the scan walks
+SCAN_CHANNELS = 512  # channels whose state a loop iteration holds in registers
+
+
+class JambaConfig(NamedTuple):
+    hidden: int
+    heads: int
+    kv_heads: int
+    intermediate: int
+    layers: int
+    vocab: int
+    attn_period: int
+    attn_offset: int
+    d_state: int = 16
+    d_conv: int = 4
+    dt_rank: int = 160
+    expand: int = 2
+    eps: float = 1e-6
+    basket: int = 4          # items generated a request
+    max_len: int = 100       # longest session a slot holds
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden // self.heads
+
+    @property
+    def d_inner(self) -> int:
+        return self.expand * self.hidden
+
+    @property
+    def positions(self) -> int:
+        return self.max_len + self.basket
+
+    def is_attention(self, layer: int) -> bool:
+        return layer % self.attn_period == self.attn_offset
+
+    @staticmethod
+    def from_extensions(ext) -> "JambaConfig":
+        """From an artifact's extensions: the source's own key names."""
+        g = ext
+        return JambaConfig(
+            hidden=int(g("hidden_size")),
+            heads=int(g("num_attention_heads")),
+            kv_heads=int(g("num_key_value_heads")),
+            intermediate=int(g("intermediate_size")),
+            layers=int(g("num_hidden_layers")),
+            vocab=int(g("vocab_size")),
+            attn_period=int(g("attn_layer_period")),
+            attn_offset=int(g("attn_layer_offset")),
+            d_state=int(g("mamba_d_state", 16)),
+            d_conv=int(g("mamba_d_conv", 4)),
+            dt_rank=int(g("mamba_dt_rank", 160)),
+            expand=int(g("mamba_expand", 2)),
+            eps=float(g("rms_norm_eps", 1e-6)),
+            basket=int(g("basket", 4)),
+            max_len=int(g("max_len", 100)),
+        )
+
+    def to_extensions(self) -> dict:
+        return {
+            "hidden_size": self.hidden, "num_attention_heads": self.heads,
+            "num_key_value_heads": self.kv_heads, "intermediate_size": self.intermediate,
+            "num_hidden_layers": self.layers, "vocab_size": self.vocab,
+            "attn_layer_period": self.attn_period, "attn_layer_offset": self.attn_offset,
+            "mamba_d_state": self.d_state, "mamba_d_conv": self.d_conv,
+            "mamba_dt_rank": self.dt_rank, "mamba_expand": self.expand,
+            "rms_norm_eps": self.eps, "basket": self.basket, "max_len": self.max_len,
+        }
+
+
+def layer_shapes(cfg: JambaConfig, layer: int) -> dict[str, tuple]:
+    H, F, C, N, R = cfg.hidden, cfg.intermediate, cfg.d_inner, cfg.d_state, cfg.dt_rank
+    out = {"ln1": (H,), "ln2": (H,), "wg": (H, F), "wu": (H, F), "wd": (F, H)}
+    if cfg.is_attention(layer):
+        q, kv = cfg.heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+        out.update(wq=(H, q), wk=(H, kv), wv=(H, kv), wo=(q, H))
+    else:
+        out.update(
+            in_proj=(H, 2 * C), conv_w=(cfg.d_conv, C), conv_b=(C,), x_proj=(C, R + 2 * N),
+            dt_norm=(R,), b_norm=(N,), c_norm=(N,), dt_proj=(R, C), dt_bias=(C,),
+            A_log=(N, C), D=(C,), out_proj=(C, H),
+        )
+    return out
+
+
+def tensor_shapes(cfg: JambaConfig) -> dict[str, tuple]:
+    """Every tensor of an artifact by its name."""
+    out = {"E_in": (cfg.vocab, cfg.hidden), "final_norm": (cfg.hidden,)}
+    for l in range(cfg.layers):
+        out.update({f"L{l}.{k}": v for k, v in layer_shapes(cfg, l).items()})
+    return out
+
+
+def param_count(cfg: JambaConfig) -> int:
+    return sum(int(np.prod(v)) for v in tensor_shapes(cfg).values())
+
+
+@partial(jax.jit, static_argnums=(1,))
+def _dt_bias(key, shape):
+    """b_dt with softplus(b_dt) log-uniform in DT_INIT (Mamba's published
+    initialisation): the inverse softplus of the drawn step."""
+    lo, hi = DT_INIT
+    dt = jnp.exp(jax.random.uniform(key, shape) * (math.log(hi) - math.log(lo)) + math.log(lo))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _conv_weight(key, shape, dtype):
+    """Uniform in +-1/sqrt(d_conv): the depthwise conv's default
+    initialisation (its fan-in is its width), which Mamba keeps."""
+    bound = 1.0 / math.sqrt(shape[0])
+    return jax.random.uniform(key, shape, minval=-bound, maxval=bound).astype(dtype)
+
+
+def init_tensors(cfg: JambaConfig, seed: int, dtype=jnp.bfloat16) -> dict:
+    """An artifact's tensors from the seed, made on the device one at a time:
+    standard normal x 0.02, norm weights 1, and Mamba's published
+    initialisation of the mixer's own parameters (A_log = log 1..d_state
+    down the state axis, D = 1, b_dt as `_dt_bias`, the conv's weight as
+    `_conv_weight`), without which the state saturates or dies, or the conv
+    passes next to nothing, and the recurrence carries no weight in the output."""
+    out = {}
+    for i, (name, shape) in enumerate(sorted(tensor_shapes(cfg).items())):
+        kind = name.split(".")[-1]
+        key = jax.random.fold_in(jax.random.PRNGKey(seed % (2**31)), i)
+        if kind == "final_norm" or kind in NORM_TENSORS:
+            out[name] = jnp.ones(shape, dtype=dtype)
+        elif kind == "A_log":
+            steps = jnp.log(jnp.arange(1, cfg.d_state + 1, dtype=jnp.float32))
+            out[name] = jnp.tile(steps[:, None], (1, shape[1]))
+        elif kind == "D":
+            out[name] = jnp.ones(shape, jnp.float32)
+        elif kind == "dt_bias":
+            out[name] = _dt_bias(key, shape)
+        elif kind == "conv_w":
+            out[name] = _conv_weight(key, shape, dtype)
+        else:
+            out[name] = _normal(key, shape, dtype)
+    return out
+
+
+def params_of(cfg: JambaConfig, tensors: dict, dtype=None) -> dict:
+    """An artifact's tensors -> the parameters the forms below take:
+    {"E_in", "final_norm", "layers": [{name: array}, ...]}, checked against
+    the shapes the configuration states; cast to `dtype` where one is given
+    (the recurrence's own parameters to float32 always)."""
+    for name, shape in tensor_shapes(cfg).items():
+        if name not in tensors:
+            raise ValueError(f"Jamba model lacks tensor {name!r}")
+        if tuple(np.shape(tensors[name])) != shape:
+            raise ValueError(
+                f"Jamba tensor {name!r} shaped {tuple(np.shape(tensors[name]))}, "
+                f"the extensions say {shape}"
+            )
+
+    def take(name):
+        kind = name.split(".")[-1]
+        return jnp.asarray(tensors[name], dtype=jnp.float32 if kind in FLOAT32_TENSORS else dtype)
+
+    return {
+        "E_in": take("E_in"), "final_norm": take("final_norm"),
+        "layers": [
+            {k: take(f"L{l}.{k}") for k in layer_shapes(cfg, l)} for l in range(cfg.layers)
+        ],
+    }
+
+
+def init_params(cfg: JambaConfig, seed: int, dtype=jnp.bfloat16) -> dict:
+    return params_of(cfg, init_tensors(cfg, seed, dtype))
+
+
+# -- the selective scan: one Pallas kernel, chunked over positions -----------
+
+def _scan_kernel(len_ref, x_ref, dt_ref, bb_ref, cc_ref, a_ref, d_ref, h0_ref, y_ref, h_ref, *, chunk, width):
+    """Grid (row, chunk of positions). The row's state h [N, C] (channels on
+    lanes) stays in VMEM as the revisited output block; a chunk walks its
+    positions in order, `width` channels at a time. A chunk wholly past the
+    row's length does nothing but zero its outputs."""
+    r, t = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(t == 0)
+    def _():
+        h_ref[...] = h0_ref[...]
+
+    live = t * chunk < len_ref[r]
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(live)
+    def _():
+        lanes = min(width, _LANE)
+
+        def channels(i, carry):
+            for q in range(width // lanes):  # independent chains, one lane tile each
+                sl = pl.ds(pl.multiple_of(i * width, width) + q * lanes, lanes)
+                a, d, h = a_ref[:, sl], d_ref[:, sl], h_ref[:, sl]
+                for j in range(chunk):
+                    dt, x = dt_ref[j:j + 1, sl], x_ref[j:j + 1, sl]        # [1, lanes]
+                    b, c = bb_ref[j][:, :lanes], cc_ref[j][:, :lanes]      # [N, lanes]
+                    h = jnp.exp(dt * a) * h + (dt * x) * b
+                    y_ref[j:j + 1, sl] = jnp.sum(h * c, axis=0, keepdims=True) + d * x
+                h_ref[:, sl] = h
+            return carry
+
+        jax.lax.fori_loop(0, x_ref.shape[1] // width, channels, 0)
+
+
+def selective_scan(x, dt, b, c, a, d, h0, lengths):
+    """The recurrence h_t = exp(dt_t A) h_{t-1} + (dt_t B_t) x_t,
+    y_t = h_t C_t + D x_t over the first `lengths[r]` positions of each row;
+    a position at or past a row's length changes no state (its y is not to
+    be read). All float32:
+
+    x, dt [R,T,C]; b, c [R,T,N]; a [N,C] (= -exp(A_log)); d [C]; h0 [R,N,C];
+    lengths [R] int32 -> (y [R,T,C], h [R,N,C] after the last real position).
+    """
+    f32 = jnp.float32
+    rows, t, ch = x.shape
+    n = a.shape[0]
+    chunk = min(SCAN_CHUNK, t)
+    width = min(SCAN_CHANNELS, ch)
+    if ch % width or (width > _LANE and width % _LANE):
+        raise ValueError(f"{ch} channels do not divide into blocks of {width}")
+    real = jnp.arange(t, dtype=jnp.int32)[None, :] < lengths[:, None]
+    dt = jnp.where(real[:, :, None], dt, 0.0)  # exp(0 A) = 1 and 0 B x = 0: h stays
+    pad = -t % chunk
+    if pad:
+        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad), (0, 0))) for v in (x, dt, b, c))
+    tp = t + pad
+    lane = min(_LANE, ch)
+    # B_t and C_t scale a whole column of the state: handed over already
+    # spread along a lane tile, so the kernel broadcasts nothing across lanes
+    bb = jnp.broadcast_to(b.astype(f32)[..., None], (rows, tp, n, lane))
+    cc = jnp.broadcast_to(c.astype(f32)[..., None], (rows, tp, n, lane))
+    seq = pl.BlockSpec((None, chunk, ch), lambda r, i, lens: (r, i, 0))
+    col = pl.BlockSpec((None, chunk, n, lane), lambda r, i, lens: (r, i, 0, 0))
+    state = pl.BlockSpec((None, n, ch), lambda r, i, lens: (r, 0, 0))
+    y, h = pl.pallas_call(
+        partial(_scan_kernel, chunk=chunk, width=width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows, tp // chunk),
+            in_specs=[
+                seq, seq, col, col,
+                pl.BlockSpec((n, ch), lambda r, i, lens: (0, 0)),
+                pl.BlockSpec((1, ch), lambda r, i, lens: (0, 0)),
+                state,
+            ],
+            out_specs=[seq, state],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((rows, tp, ch), f32),
+            jax.ShapeDtypeStruct((rows, n, ch), f32),
+        ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=jax.default_backend() != "tpu",
+        name="jamba_scan",
+    )(
+        lengths.astype(jnp.int32), x.astype(f32), dt.astype(f32), bb, cc,
+        a.astype(f32), d.astype(f32).reshape(1, ch), h0.astype(f32),
+    )
+    return y[:, :t], h
+
+
+# -- pieces both served programs share (the dtype of the weights decides the
+# precision of a product's inputs) -----------------------------------------
+
+def _dot(x, w):
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+
+
+def _mlp(cfg: JambaConfig, p: dict, x):
+    with jax.named_scope("jamba.mlp"):
+        u = rms_norm(x, p["ln2"], cfg.eps)
+        return x + _dot(jax.nn.silu(_dot(u, p["wg"])) * _dot(u, p["wu"]), p["wd"])
+
+
+def _conv(p: dict, window):
+    """window [..., T + d_conv - 1, C] float32 (the d_conv - 1 inputs before
+    the first position in front) -> silu(conv + bias) [..., T, C]."""
+    w = p["conv_w"].astype(jnp.float32)
+    k = w.shape[0]
+    t = window.shape[-2] - (k - 1)
+    out = sum(window[..., j:j + t, :] * w[j] for j in range(k))
+    return jax.nn.silu(out + p["conv_b"].astype(jnp.float32))
+
+
+def _ssm_inputs(cfg: JambaConfig, p: dict, xc):
+    """xc [..., C] (after the conv) -> dt [..., C], B, C [..., N] float32."""
+    r, n = cfg.dt_rank, cfg.d_state
+    dbc = _dot(xc, p["x_proj"])
+    dt = rms_norm(dbc[..., :r], p["dt_norm"], cfg.eps)
+    b = rms_norm(dbc[..., r:r + n], p["b_norm"], cfg.eps)
+    c = rms_norm(dbc[..., r + n:], p["c_norm"], cfg.eps)
+    return jax.nn.softplus(_dot(dt, p["dt_proj"]) + p["dt_bias"]), b, c
+
+
+def _mamba(cfg: JambaConfig, p: dict, x, tail, h0, lengths):
+    """The Mamba mixer over x [R,T,H] float32 (the residual stream) from the
+    conv's last inputs `tail` [R, d_conv - 1, C] and the state `h0` [R,N,C]:
+    (x + mixer, the new tail, the new state). Positions at or past
+    `lengths` [R] are padding: they change neither."""
+    k = cfg.d_conv
+    with jax.named_scope("jamba.mamba"):
+        xz = _dot(rms_norm(x, p["ln1"], cfg.eps), p["in_proj"])
+        xs, z = xz[..., : cfg.d_inner], xz[..., cfg.d_inner:]
+        window = jnp.concatenate([tail, xs], axis=1)
+        # the last d_conv - 1 REAL inputs: window rows lengths .. lengths + k - 2
+        at = lengths[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None, :]
+        new_tail = jnp.take_along_axis(window, at[:, :, None], axis=1)
+        with jax.named_scope("jamba.scan"):
+            xc = _conv(p, window)
+        dt, b, c = _ssm_inputs(cfg, p, xc)
+        with jax.named_scope("jamba.scan"):
+            y, h = selective_scan(xc, dt, b, c, -jnp.exp(p["A_log"]), p["D"], h0, lengths)
+        return x + _dot(y * jax.nn.silu(z), p["out_proj"]), new_tail, h
+
+
+def _qkv(cfg: JambaConfig, p: dict, u):
+    r, t = u.shape[0], u.shape[1]
+    q = _dot(u, p["wq"]).reshape(r, t, cfg.heads, cfg.head_dim)
+    k = _dot(u, p["wk"]).reshape(r, t, cfg.kv_heads, cfg.head_dim)
+    v = _dot(u, p["wv"]).reshape(r, t, cfg.kv_heads, cfg.head_dim)
+    return q, k, v
+
+
+# -- the served form: a slot cache of two kinds of state, fixed shapes -------
+
+def init_state(cfg: JambaConfig, slots: int, dtype=jnp.bfloat16) -> dict:
+    """Per-request state for `slots` requests and one scratch slot (the last:
+    padding rows of a dispatch write there). By layer (None where the layer
+    is of the other kind): h, conv: a Mamba layer's recurrent state and its
+    conv's last inputs, float32; k, v: an attention layer's keys and values.
+    x_in: the next step's input embedding; z / row / step: the basket (for
+    each position generated the hidden state, the view row chosen and the
+    step that chose it)."""
+    s, b = slots + 1, cfg.basket
+    kv = (s, cfg.positions, cfg.kv_heads, cfg.head_dim)
+    attn = [cfg.is_attention(l) for l in range(cfg.layers)]
+    f32 = jnp.float32
+    return {
+        "h": [None if a else jnp.zeros((s, cfg.d_state, cfg.d_inner), f32) for a in attn],
+        "conv": [None if a else jnp.zeros((s, cfg.d_conv - 1, cfg.d_inner), f32) for a in attn],
+        "k": [jnp.zeros(kv, dtype) if a else None for a in attn],
+        "v": [jnp.zeros(kv, dtype) if a else None for a in attn],
+        "x_in": jnp.zeros((s, cfg.hidden), dtype),
+        "z": jnp.zeros((s, b, cfg.hidden), f32),
+        "row": jnp.full((s, b), -1, jnp.int32),
+        "step": jnp.full((s, b), -1, jnp.int32),
+    }
+
+
+def state_bytes(cfg: JambaConfig, slots: int, itemsize: int = 2) -> dict[str, int]:
+    """Bytes of the slots' state by its kind: `recurrent` is the same
+    whatever a session's length, `kv` grows a row a position."""
+    s = slots + 1
+    mamba = sum(1 for l in range(cfg.layers) if not cfg.is_attention(l))
+    recurrent = mamba * s * (cfg.d_state + cfg.d_conv - 1) * cfg.d_inner * 4
+    kv = (cfg.layers - mamba) * 2 * s * cfg.positions * cfg.kv_heads * cfg.head_dim * itemsize
+    return {"recurrent": recurrent, "kv": kv}
+
+
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+def prefill(cfg: JambaConfig, params: dict, state: dict, tokens, lengths, slots, last):
+    """tokens [P,T] int32 (right-padded) = each session WITHOUT its last
+    event, lengths [P], slots [P] (the scratch slot for a padding row, whose
+    length is 0), last [P] the last event's token -> (state, the stream
+    [P,H] at each row's last position). Starts every slot from zero: writes
+    the recurrent state, conv inputs, keys and values the events leave, the
+    last event as the first step's input, and an empty basket."""
+    p_rows, t = tokens.shape
+    f32 = jnp.float32
+    dt = params["layers"][0]["wg"].dtype
+    pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (p_rows, t))
+    live = pos < lengths[:, None]
+    allowed = (pos[:, None, :] <= pos[:, :, None]) & live[:, None, :]
+    x = params["E_in"][tokens].astype(f32)
+    new = {key: list(state[key]) for key in ("h", "conv", "k", "v")}
+    zero_tail = jnp.zeros((p_rows, cfg.d_conv - 1, cfg.d_inner), f32)
+    zero_h = jnp.zeros((p_rows, cfg.d_state, cfg.d_inner), f32)
+    for l, p in enumerate(params["layers"]):
+        if cfg.is_attention(l):
+            with jax.named_scope("jamba.attn"):
+                q, k, v = _qkv(cfg, p, rms_norm(x, p["ln1"], cfg.eps))
+                new["k"][l] = new["k"][l].at[slots, :t].set(k.astype(new["k"][l].dtype))
+                new["v"][l] = new["v"][l].at[slots, :t].set(v.astype(new["v"][l].dtype))
+                x = x + _dot(_attend(cfg, q, k, v, allowed, dt), p["wo"])
+        else:
+            x, tail, h = _mamba(cfg, p, x, zero_tail, zero_h, lengths)
+            new["conv"][l] = new["conv"][l].at[slots].set(tail)
+            new["h"][l] = new["h"][l].at[slots].set(h)
+        x = _mlp(cfg, p, x)
+    hidden = x[jnp.arange(p_rows), jnp.maximum(lengths - 1, 0)]
+    b = cfg.basket
+    state = dict(
+        state, **new,
+        x_in=state["x_in"].at[slots].set(params["E_in"][last].astype(state["x_in"].dtype)),
+        z=state["z"].at[slots].set(jnp.zeros((b, cfg.hidden), f32)),
+        row=state["row"].at[slots].set(-1),
+        step=state["step"].at[slots].set(-1),
+    )
+    return state, hidden
+
+
+def _token_hidden(cfg: JambaConfig, params: dict, state: dict, slots, pos, live):
+    """The layers over ONE token of each of `slots` [D] (its input embedding
+    is the slot's `x_in`, its position `pos` [D]): the final-normed hidden
+    state [D,H] float32 and the layers' new state."""
+    f32 = jnp.float32
+    dt = params["layers"][0]["wg"].dtype
+    x = state["x_in"][slots].astype(f32)[:, None, :]                            # [D,1,H]
+    new = {key: list(state[key]) for key in ("h", "conv", "k", "v")}
+    allowed = (jnp.arange(cfg.positions, dtype=jnp.int32)[None, :] <= pos[:, None])[:, None, :]
+    real = live.astype(jnp.int32)
+    for l, p in enumerate(params["layers"]):
+        if cfg.is_attention(l):
+            with jax.named_scope("jamba.attn"):
+                q, k, v = _qkv(cfg, p, rms_norm(x, p["ln1"], cfg.eps))
+                new["k"][l] = new["k"][l].at[slots, pos].set(k[:, 0].astype(new["k"][l].dtype))
+                new["v"][l] = new["v"][l].at[slots, pos].set(v[:, 0].astype(new["v"][l].dtype))
+                o = _attend(
+                    cfg, q, new["k"][l][slots].astype(f32), new["v"][l][slots].astype(f32), allowed, dt
+                )
+                x = x + _dot(o, p["wo"])
+        else:
+            x, tail, h = _mamba(cfg, p, x, new["conv"][l][slots], new["h"][l][slots], real)
+            new["conv"][l] = new["conv"][l].at[slots].set(tail)
+            new["h"][l] = new["h"][l].at[slots].set(h)
+        x = _mlp(cfg, p, x)
+    return rms_norm(x[:, 0], params["final_norm"], cfg.eps), new
+
+
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+def decode_step(cfg: JambaConfig, params: dict, state: dict, view, n_valid, slots, lengths, live, step):
+    """One token of every sequence in `slots` [D] (the scratch slot and live
+    False for a padding row): the layers over each slot's pending input at
+    position lengths + step, the head over the `n_valid` real rows of `view`
+    [rows, H], and the argmax fed back: its view row (the tied embedding) is
+    the slot's next input. `step` [D] is each sequence's own step number, the
+    basket position it fills.
+
+    -> (state, out) with out = {"z": [D,B,H] float32 hidden of each position
+    generated so far, "row": [D,B] the view rows chosen, "step": [D,B] the
+    steps that chose them}: what a finished request needs, and every row's,
+    so one fetch serves whichever finished."""
+    b = cfg.basket
+    z, new = _token_hidden(cfg, params, state, slots, lengths + step, live)
+    with jax.named_scope("jamba.head"):
+        dt = view.dtype
+        zq = jnp.pad(z.astype(dt), ((0, 0), (0, view.shape[1] - cfg.hidden)))
+        _top, arg, _conf = catalog_head(zq, view, n_valid)
+        fed = view[arg][:, : cfg.hidden]
+    here = (jnp.arange(b)[None, :] == step[:, None]) & live[:, None]             # [D,B]
+    new_z = jnp.where(here[:, :, None], z[:, None, :], state["z"][slots])
+    new_row = jnp.where(here, arg[:, None], state["row"][slots])
+    new_step = jnp.where(here, step[:, None], state["step"][slots])
+    state = dict(
+        state, **new,
+        x_in=state["x_in"].at[slots].set(fed.astype(state["x_in"].dtype)),
+        z=state["z"].at[slots].set(new_z),
+        row=state["row"].at[slots].set(new_row),
+        step=state["step"].at[slots].set(new_step),
+    )
+    return state, {"z": new_z, "row": new_row, "step": new_step}
+
+
+# -- behind the encoder seam (ops/seq.py) ------------------------------------
+
+class JambaEncoder:
+    """The decoder behind the seam: `prefill` runs a request's events but the
+    last into its cache slot, `steps` one-token steps follow, and the request
+    hands the catalog scan `block` rows. Shapes are few and fixed: a prefill
+    is `prefill_rows` sessions padded to a length bucket, a step is
+    `step_rows` tokens."""
+
+    name = "jamba"
+    own_input = True      # E_in: the tied embedding as the model was announced
+    step_kind = "decode"
+    step_tokens = 1       # a step runs one token a sequence
+    unknown_token = None  # a step feeds back the view's own row: no token needed
+    # 4 sessions x 100 positions is still about a pass over the weights' worth
+    # of MXU time (12 ms beside 7); 8 x 100 was 32 ms for what is mostly padding
+    prefill_rows = 4
+    step_rows = 32
+
+    def __init__(self, cfg: JambaConfig, dtype=jnp.bfloat16):
+        self.cfg = cfg
+        self.dtype = dtype
+        self.dim = cfg.hidden
+        self.steps = cfg.basket
+        self.block = cfg.basket
+        self.window = cfg.max_len
+        self.length_buckets = tuple(sorted({min(32, cfg.max_len), cfg.max_len}))
+
+    @staticmethod
+    def from_extensions(ext) -> "JambaEncoder":
+        return JambaEncoder(
+            JambaConfig.from_extensions(ext), jnp.dtype(str(ext("dtype", "bfloat16")))
+        )
+
+    def load_params(self, tensors: dict) -> dict:
+        return params_of(self.cfg, tensors, self.dtype)
+
+    def device_params(self, params: dict) -> dict:
+        return params
+
+    def init_state(self, slots: int):
+        return init_state(self.cfg, slots, self.dtype)
+
+    def state_bytes(self, slots: int) -> dict[str, int]:
+        return state_bytes(self.cfg, slots, jnp.dtype(self.dtype).itemsize)
+
+    def prepare(self, seq_state, context_items):
+        """The E_in rows of the newest `max_len` context items that have
+        one (an item that arrived by UP since the model is skipped as
+        context until the next generation)."""
+        return announced_tokens(seq_state, context_items, self.cfg.max_len)
+
+    def length(self, prepared) -> int:
+        return int(prepared.shape[0]) - 1  # the last event is the first step's
+
+    def pack(self, prepared: list, bucket: int, slots, scratch: int):
+        tokens = np.zeros((self.prefill_rows, bucket), dtype=np.int32)
+        lengths = np.zeros((self.prefill_rows,), dtype=np.int32)
+        slot_of = np.full((self.prefill_rows,), scratch, dtype=np.int32)
+        last = np.zeros((self.prefill_rows,), dtype=np.int32)
+        for i, tok in enumerate(prepared):
+            tokens[i, : len(tok) - 1] = tok[:-1]
+            lengths[i], last[i], slot_of[i] = len(tok) - 1, tok[-1], slots[i]
+        return tokens, lengths, slot_of, last
+
+    def prefill(self, params, state, tokens, lengths, slots, last):
+        packed = tuple(jnp.asarray(a) for a in (tokens, lengths, slots, last))
+        state, hidden = prefill(self.cfg, params, state, *packed)
+        return state, hidden, None
+
+    def step(self, params, state, head, slots, lengths, live, step):
+        view, n_valid, _row_token = head
+        rows = (jnp.asarray(slots), jnp.asarray(lengths), jnp.asarray(live), jnp.asarray(step))
+        return decode_step(self.cfg, params, state, view, jnp.int32(n_valid), *rows)
+
+    def train(self, *args, **kw):
+        raise NotImplementedError(
+            "a Jamba model reaches serving as an artifact; the batch layer trains the GRU"
+        )
+
+
+# -- the plain reference: float32, highest precision, no cache ---------------
+
+def _reference_mamba(cfg: JambaConfig, p: dict, u):
+    """u [T,H] float32 (normalised) -> the mixer's output [T,H]: the
+    recurrence one position after another."""
+    f32 = jnp.float32
+    k = cfg.d_conv
+    xz = u @ p["in_proj"].astype(f32)
+    x, z = xz[:, : cfg.d_inner], xz[:, cfg.d_inner:]
+    window = jnp.concatenate([jnp.zeros((k - 1, cfg.d_inner), f32), x], axis=0)
+    w = p["conv_w"].astype(f32)
+    x = jax.nn.silu(
+        sum(window[j:j + x.shape[0]] * w[j] for j in range(k)) + p["conv_b"].astype(f32)
+    )
+    r, n = cfg.dt_rank, cfg.d_state
+    dbc = x @ p["x_proj"].astype(f32)
+    dt = rms_norm(dbc[:, :r], p["dt_norm"], cfg.eps)
+    b = rms_norm(dbc[:, r:r + n], p["b_norm"], cfg.eps)
+    c = rms_norm(dbc[:, r + n:], p["c_norm"], cfg.eps)
+    dt = jax.nn.softplus(dt @ p["dt_proj"].astype(f32) + p["dt_bias"])
+    a = -jnp.exp(p["A_log"])                                                    # [N,C]
+
+    def one(h, xs):
+        x_t, dt_t, b_t, c_t = xs
+        h = jnp.exp(dt_t[None, :] * a) * h + (dt_t * x_t)[None, :] * b_t[:, None]
+        return h, jnp.sum(h * c_t[:, None], axis=0) + p["D"] * x_t
+
+    _, y = jax.lax.scan(one, jnp.zeros((n, cfg.d_inner), f32), (x, dt, b, c))
+    return (y * jax.nn.silu(z)) @ p["out_proj"].astype(f32)
+
+
+def reference_forward(cfg: JambaConfig, params: dict, tokens):
+    """tokens [T] int32 -> final-normed hidden [T,H] float32: one full causal
+    forward pass, nothing cached, nothing padded."""
+    f32 = jnp.float32
+    with jax.default_matmul_precision("highest"):
+        t = tokens.shape[0]
+        causal = jnp.tril(jnp.ones((t, t), bool))
+        x = params["E_in"][tokens].astype(f32)
+        for l, p in enumerate(params["layers"]):
+            u = rms_norm(x, p["ln1"], cfg.eps)
+            if cfg.is_attention(l):
+                q = (u @ p["wq"].astype(f32)).reshape(t, cfg.heads, cfg.head_dim)
+                k = (u @ p["wk"].astype(f32)).reshape(t, cfg.kv_heads, cfg.head_dim)
+                v = (u @ p["wv"].astype(f32)).reshape(t, cfg.kv_heads, cfg.head_dim)
+                k = jnp.repeat(k, cfg.heads // cfg.kv_heads, axis=1)
+                v = jnp.repeat(v, cfg.heads // cfg.kv_heads, axis=1)
+                s = jnp.einsum("thd,shd->hts", q, k) / math.sqrt(cfg.head_dim)
+                s = jnp.where(causal[None], s, -jnp.inf)
+                o = jnp.einsum("hts,shd->thd", jax.nn.softmax(s, axis=-1), v)
+                x = x + o.reshape(t, cfg.heads * cfg.head_dim) @ p["wo"].astype(f32)
+            else:
+                x = x + _reference_mamba(cfg, p, u)
+            u = rms_norm(x, p["ln2"], cfg.eps)
+            x = x + (jax.nn.silu(u @ p["wg"].astype(f32)) * (u @ p["wu"].astype(f32))) @ p["wd"].astype(f32)
+        return rms_norm(x, params["final_norm"], cfg.eps)
+
+
+def reference_generate(cfg: JambaConfig, params: dict, e_out, session, n_valid=None):
+    """A basket by the plain form: session [n] int32 tokens, e_out [rows, H]
+    (the tied embedding: row i is token i's) -> {"row": [B] catalog rows
+    chosen, "logits": [B, rows] float32}. A full forward pass a position."""
+    n_valid = int(e_out.shape[0]) if n_valid is None else int(n_valid)
+    tokens = [int(t) for t in session]
+    rows, all_logits = [], []
+    for _ in range(cfg.basket):
+        z = reference_forward(cfg, params, jnp.asarray(tokens, dtype=jnp.int32))[-1]
+        with jax.default_matmul_precision("highest"):
+            logits = np.asarray(jnp.asarray(e_out, jnp.float32)[:n_valid] @ z)
+        all_logits.append(logits)
+        rows.append(int(np.argmax(logits)))
+        tokens.append(rows[-1])
+    return {"row": np.asarray(rows), "logits": np.stack(all_logits)}

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from oryx_tpu.ops.moe import moe_apply, moe_reference
+from oryx_tpu.ops.seq import announced_tokens, catalog_head
 
 # tensors of an SDAR artifact, beside the catalog ("E", the FactorStore's):
 # "E_in", "final_norm" and, for layer l, "L<l>.<name>" of each of these. A
@@ -367,11 +368,8 @@ def denoise_step(
         zq = z.reshape(d_rows * b, cfg.hidden).astype(dt)
         # a served view is lane-padded (ops/pallas_topk.py view_shape)
         zq = jnp.pad(zq, ((0, 0), (0, view.shape[1] - cfg.hidden)))
-        logits = jnp.dot(zq, view.T, preferred_element_type=jnp.float32)
-        logits = jnp.where(jnp.arange(view.shape[0])[None, :] < n_valid, logits, -jnp.inf)
-        top = jnp.max(logits, axis=-1)
-        arg = jnp.argmax(logits, axis=-1).astype(jnp.int32).reshape(d_rows, b)
-        conf = jnp.exp(top - jax.nn.logsumexp(logits, axis=-1)).reshape(d_rows, b)
+        _top, arg, conf = catalog_head(zq, view, n_valid)
+        arg, conf = arg.reshape(d_rows, b), conf.reshape(d_rows, b)
     masked = state["masked"][slots]
     pick = jnp.argmax(jnp.where(masked, conf, -1.0), axis=-1)                  # [D]
     chosen_row = arg[jnp.arange(d_rows), pick]
@@ -401,6 +399,7 @@ class SdarEncoder:
 
     name = "sdar"
     own_input = True  # E_in: an input embedding apart from the catalog
+    step_kind = "denoise"
     prefill_rows = 8
     step_rows = 32
 
@@ -410,6 +409,7 @@ class SdarEncoder:
         self.dim = cfg.hidden
         self.steps = cfg.denoise_steps
         self.block = cfg.block_length
+        self.step_tokens = cfg.block_length  # a step runs every position of a block
         self.window = cfg.max_len
         self.length_buckets = tuple(sorted({min(32, cfg.max_len), cfg.max_len}))
 
@@ -431,16 +431,23 @@ class SdarEncoder:
     def init_state(self, slots: int):
         return init_state(self.cfg, slots, self.dtype)
 
+    def state_bytes(self, slots: int) -> dict[str, int]:
+        """The slots' keys and values (the block's own state is a few KiB)."""
+        c = self.cfg
+        per_position = 2 * c.layers * c.kv_heads * c.head_dim * jnp.dtype(self.dtype).itemsize
+        return {"kv": (slots + 1) * c.max_len * per_position}
+
+    @property
+    def unknown_token(self) -> int:
+        """What a step feeds for a view row with no input embedding yet."""
+        return self.cfg.mask_id
+
     def prepare(self, seq_state, context_items):
         """The E_in rows of the newest `max_len` context items that have
         one; an item the model was not announced with (it arrived by UP
         since) has a head row and no input embedding, and is skipped as
         context until the next generation."""
-        token_of = seq_state.token_of
-        tokens = [token_of[i] for i in context_items if i in token_of]
-        if not tokens:
-            return None
-        return np.asarray(tokens[-self.cfg.max_len:], dtype=np.int32)
+        return announced_tokens(seq_state, context_items, self.cfg.max_len)
 
     def length(self, prepared) -> int:
         return int(prepared.shape[0])

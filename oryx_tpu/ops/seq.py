@@ -257,6 +257,19 @@ def next_item_hit_rate(
     return hits / n
 
 
+def catalog_head(z, view, n_valid):
+    """The head of an encoder that generates, over the served view: z [R, F]
+    (in the view's dtype, lane-padded as the view is, ops/pallas_topk.py
+    view_shape) x view [rows, F] -> for each row of z the largest logit over
+    the view's first `n_valid` rows, its view row (int32) and its softmax
+    probability (the confidence). Logits accumulate in float32."""
+    logits = jnp.dot(z, view.T, preferred_element_type=jnp.float32)
+    logits = jnp.where(jnp.arange(view.shape[0])[None, :] < n_valid, logits, -jnp.inf)
+    top = jnp.max(logits, axis=-1)
+    arg = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return top, arg, jnp.exp(top - jax.nn.logsumexp(logits, axis=-1))
+
+
 # -- the encoder seam ---------------------------------------------------------
 #
 # What turns a session into the vector(s) the catalog scan ranks is an
@@ -281,6 +294,12 @@ def next_item_hit_rate(
 #   pack(prepared, bucket, slots, scratch) -> the arrays of one prefill
 #   prefill(params, state, *packed) -> (state, hidden [rows, d], counts)
 #   step(params, state, head, slots, lengths, live, step) -> (state, out)
+#   step_kind, step_tokens  the label a step dispatch counts under and the
+#                           tokens a row of it runs (a block's positions, or
+#                           one token a sequence)
+#   unknown_token           what a step feeds for a view row with no input
+#                           embedding yet (None: it feeds the view's own row)
+#   state_bytes(slots)      {kind of state: bytes} of the slots' state
 #   train(...) / loss       for the encoder that trains
 
 
@@ -373,6 +392,16 @@ class GruEncoder:
         )
 
 
+def announced_tokens(seq_state, context_items, max_len: int):
+    """The input-embedding rows of the newest `max_len` context items that
+    have one (`SeqState.token_of`), or None where none has: an item the model
+    was not announced with (it arrived by UP since) has a head row and no
+    input embedding, and is skipped as context until the next generation."""
+    token_of = seq_state.token_of
+    tokens = [token_of[i] for i in context_items if i in token_of]
+    return np.asarray(tokens[-max_len:], dtype=np.int32) if tokens else None
+
+
 def encoder_for(name: str, ext):
     """The encoder an artifact names; `ext(key, default)` reads the
     artifact's extensions."""
@@ -382,4 +411,8 @@ def encoder_for(name: str, ext):
         from oryx_tpu.ops.sdar import SdarEncoder
 
         return SdarEncoder.from_extensions(ext)
+    if name == "jamba":
+        from oryx_tpu.ops.jamba import JambaEncoder
+
+        return JambaEncoder.from_extensions(ext)
     raise ValueError(f"unknown seq encoder {name!r}")

@@ -110,7 +110,7 @@ class _Metrics:
         reg = get_registry()
         self.steps = reg.counter(
             "oryx_seq_steps_total",
-            "Encoder dispatches of the seq stepper, by kind (prefill | denoise)",
+            "Encoder dispatches of the seq stepper, by kind (prefill | denoise | decode)",
             labeled=True,
         )
         self.tokens = reg.counter(
@@ -128,6 +128,12 @@ class _Metrics:
         )
         self.slots = reg.gauge(
             "oryx_seq_slots_in_use", "Cache slots of the seq stepper held by a request"
+        )
+        self.state_bytes = reg.gauge(
+            "oryx_seq_slot_state_bytes",
+            "Bytes of the seq stepper's cache slots on the device, by kind of state "
+            "(recurrent: a fixed size a slot | kv: a row a position)",
+            labeled=True,
         )
         self.routed = reg.counter(
             "oryx_moe_routed_total",
@@ -250,6 +256,8 @@ class SeqStepper:
         enc = engine.encoder
         if enc.steps and engine.state is None:
             engine.state = enc.init_state(engine.slots)
+            for kind, n_bytes in enc.state_bytes(engine.slots).items():
+                self._m.state_bytes.set(n_bytes, state=kind)
         for bucket in enc.length_buckets:
             t0 = time.monotonic()
             packed = enc.pack([], bucket, [], engine.slots)
@@ -264,7 +272,7 @@ class SeqStepper:
                 engine.params, engine.state, engine.head(), pad, zero,
                 np.zeros((enc.step_rows,), dtype=bool), zero,
             )
-            np.asarray(out["counts"])
+            np.asarray(out["row"])
             self._first_use((enc.name, "step", id(engine.params)), t0)
         engine.warmed = True
 
@@ -321,9 +329,10 @@ class SeqStepper:
                 engine.active.extend(admitted)
         if enc.steps and engine.active:
             rows = engine.active[: enc.step_rows]
+            per_row = enc.step_tokens
             with tr.region(
-                "stepper.launch", cycle=n, kind="denoise", rows=len(rows),
-                padded=enc.step_rows, tokens=len(rows) * enc.block,
+                "stepper.launch", cycle=n, kind=enc.step_kind, rows=len(rows),
+                padded=enc.step_rows, tokens=len(rows) * per_row,
             ):
                 t0 = time.monotonic()
                 slots = np.full((enc.step_rows,), engine.slots, dtype=np.int32)
@@ -337,14 +346,15 @@ class SeqStepper:
                     engine.params, engine.state, engine.head(), slots, lengths, live, step,
                 )
                 finished = [(i, r) for i, r in enumerate(rows) if r.step >= enc.steps]
-                out["counts"].copy_to_host_async()
+                if "counts" in out:
+                    out["counts"].copy_to_host_async()
                 if finished:
                     for key in ("z", "row", "step"):
                         out[key].copy_to_host_async()
                 self._first_use((enc.name, "step", id(engine.params)), t0)
-            self._m.steps.inc(kind="denoise")
-            self._m.tokens.inc(len(rows) * enc.block, kind="denoise", tokens="real")
-            self._m.tokens.inc(enc.step_rows * enc.block, kind="denoise", tokens="padded")
+            self._m.steps.inc(kind=enc.step_kind)
+            self._m.tokens.inc(len(rows) * per_row, kind=enc.step_kind, tokens="real")
+            self._m.tokens.inc(enc.step_rows * per_row, kind=enc.step_kind, tokens="padded")
             # a finished block's rows ride this dispatch's result: its slot
             # is free for the next cycle's prefill (the device runs in order)
             for _, r in finished:
@@ -361,7 +371,7 @@ class SeqStepper:
                 counts = np.zeros((3,), dtype=np.int64)
                 if counts_p is not None:
                     counts += np.asarray(counts_p)
-                if out is not None:
+                if out is not None and "counts" in out:
                     counts += np.asarray(out["counts"])
                 hidden = np.asarray(hidden_dev) if not enc.steps else None
                 if finished:
