@@ -37,9 +37,13 @@ recurrence with its state are float32; keys and values are stored in the
 weights' dtype.
 
 Two forms live here. `prefill` / `decode_step` are the served ones: the slot cache
-on the device, fixed shapes, the recurrence as a Pallas kernel chunked over
-positions (`selective_scan`). `reference_forward` is the plain one: float32,
-`highest` precision, a sequential scan, full causal attention, no cache.
+on the device, fixed shapes. A prefill starts its rows from zero and walks
+their positions, the recurrence as a Pallas kernel chunked over positions
+(`selective_scan`); a step continues every live row's slot by ONE position,
+the conv and the recurrence as two Pallas kernels that read and write the
+slots' state where it lies (`step_conv`, `step_scan`). `reference_forward` is
+the plain one: float32, `highest` precision, a sequential scan, full causal
+attention, no cache.
 """
 
 from __future__ import annotations
@@ -278,6 +282,13 @@ def _scan_kernel(len_ref, x_ref, dt_ref, bb_ref, cc_ref, a_ref, d_ref, h0_ref, y
         jax.lax.fori_loop(0, x_ref.shape[1] // width, channels, 0)
 
 
+def _channel_block(ch: int) -> int:
+    width = min(SCAN_CHANNELS, ch)
+    if ch % width or (width > _LANE and width % _LANE):
+        raise ValueError(f"{ch} channels do not divide into blocks of {width}")
+    return width
+
+
 def selective_scan(x, dt, b, c, a, d, h0, lengths):
     """The recurrence h_t = exp(dt_t A) h_{t-1} + (dt_t B_t) x_t,
     y_t = h_t C_t + D x_t over the first `lengths[r]` positions of each row;
@@ -290,10 +301,7 @@ def selective_scan(x, dt, b, c, a, d, h0, lengths):
     f32 = jnp.float32
     rows, t, ch = x.shape
     n = a.shape[0]
-    chunk = min(SCAN_CHUNK, t)
-    width = min(SCAN_CHANNELS, ch)
-    if ch % width or (width > _LANE and width % _LANE):
-        raise ValueError(f"{ch} channels do not divide into blocks of {width}")
+    chunk, width = SCAN_CHUNK, _channel_block(ch)
     real = jnp.arange(t, dtype=jnp.int32)[None, :] < lengths[:, None]
     dt = jnp.where(real[:, :, None], dt, 0.0)  # exp(0 A) = 1 and 0 B x = 0: h stays
     pad = -t % chunk
@@ -333,6 +341,199 @@ def selective_scan(x, dt, b, c, a, d, h0, lengths):
         a.astype(f32), d.astype(f32).reshape(1, ch), h0.astype(f32),
     )
     return y[:, :t], h
+
+
+# -- the one-token step: a slot's state read and written where it lies ---------
+#
+# The state operand of both kernels is the layer's WHOLE slot array, aliased
+# to the output and held in HBM; the blocks a grid step works on are chosen
+# through scalar-prefetched vectors, so nothing is gathered before the kernel
+# and nothing scattered after it, and a row that is not live changes nothing.
+# Live rows name distinct slots.
+
+_ROWS = 8  # a float32 sublane tile: rows are handed over, and slots grouped, by eight
+
+
+def _row_of(block, r):
+    """Row `r` (traced) of a [rows, lanes] block as [1, lanes]: Mosaic loads
+    no sublane at a traced offset, so the row is masked out and summed."""
+    at = jax.lax.broadcasted_iota(jnp.int32, block.shape, 0) == r
+    return jnp.sum(jnp.where(at, block, 0.0), axis=0, keepdims=True)
+
+
+def _with_row(block, r, value):
+    """`block` with its row `r` (traced) set to `value` [1, lanes]."""
+    at = jax.lax.broadcasted_iota(jnp.int32, block.shape, 0) == r
+    return jnp.where(at, value, block)
+
+
+def _in_hbm(state):
+    """The out_shape of a slot array updated in place. It stays in HBM, and
+    so does the input aliased to it: left free, XLA stages the whole array
+    through VMEM around the call (10.8 MB in and out a layer)."""
+    return pltpu.HBM(state.shape, state.dtype)
+
+
+def _step_conv_kernel(group_ref, fresh_ref, slot_ref, live_ref, x_ref, w_ref, bias_ref, tail_ref, xc_ref, new_ref, *, sub):
+    """Grid (group of eight slots that holds a live row). x [D,C] the rows'
+    new conv inputs, w [K,C], bias [1,C], tail [K-1, 8, C] the group's last
+    inputs -> xc[row] = silu(conv + bias) for each live row of the group, its
+    slot's tail moved on by one; zeros for a row that is not live. Past the
+    `fresh` groups the grid revisits the last one and does nothing."""
+    g = pl.program_id(0)
+    k = w_ref.shape[0]
+    per = tail_ref.shape[1]
+    fresh = g < fresh_ref[0]
+
+    @pl.when(g == 0)
+    def _():
+        xc_ref[...] = jnp.zeros_like(xc_ref)
+
+    @pl.when(fresh | (g == 0))
+    def _():
+        new_ref[...] = tail_ref[...]
+
+    @pl.when(fresh)
+    def _():
+        def row(i, carry):
+            @pl.when((live_ref[i] != 0) & (slot_ref[i] // per == group_ref[g]))
+            def _():
+                eight = pl.ds(pl.multiple_of((i // sub) * sub, sub), sub)
+                r = slot_ref[i] % per
+                x = _row_of(x_ref[eight, :], i % sub)
+                acc = _row_of(tail_ref[0], r) * w_ref[0:1, :]
+                for j in range(1, k - 1):
+                    acc = acc + _row_of(tail_ref[j], r) * w_ref[j:j + 1, :]
+                acc = acc + x * w_ref[k - 1:k, :]
+                xc_ref[eight, :] = _with_row(xc_ref[eight, :], i % sub, jax.nn.silu(acc + bias_ref[...]))
+                for j in range(k - 2):
+                    new_ref[j] = _with_row(new_ref[j], r, _row_of(tail_ref[j + 1], r))
+                new_ref[k - 2] = _with_row(new_ref[k - 2], r, x)
+
+            return carry
+
+        jax.lax.fori_loop(0, slot_ref.shape[0], row, 0)
+
+
+def step_conv(p: dict, x, conv, slots, live):
+    """The conv at ONE new position of each row: x [D,C] float32 the rows'
+    inputs, conv [S, d_conv - 1, C] the slots' last inputs -> (silu(conv +
+    bias) [D,C], conv with every live row's slot moved on by one)."""
+    f32, i32 = jnp.float32, jnp.int32
+    d, ch = x.shape
+    k = p["conv_w"].shape[0]
+    s = conv.shape[0]
+    sub = _ROWS if d % _ROWS == 0 else d
+    per = min(_ROWS, s)
+    groups = pl.cdiv(s, per)
+    steps = min(d, groups)
+    # the groups that hold a live row, each once and in order; then the last again
+    held = jnp.any(
+        live[:, None] & (slots[:, None] // per == jnp.arange(groups, dtype=i32)[None, :]), axis=0
+    )
+    fresh = jnp.sum(held.astype(i32))
+    order = jnp.argsort(jnp.logical_not(held), stable=True).astype(i32)
+    visit = order[jnp.minimum(jnp.arange(steps, dtype=i32), jnp.maximum(fresh - 1, 0))]
+    whole = lambda n: pl.BlockSpec((n, ch), lambda g, *_: (0, 0))  # noqa: E731
+    # the chip keeps [S, K-1, C] with the K-1 inputs outermost (three rows
+    # would pad a sublane tile of eight): handed over as it lies, [K-1, S, C],
+    # the swap is no copy, and a group's block is eight rows of each input's plane
+    tails = jnp.swapaxes(conv, 0, 1)
+    group = pl.BlockSpec((k - 1, per, ch), lambda g, visit, *_: (0, visit[g], 0))
+    xc, tails = pl.pallas_call(
+        partial(_step_conv_kernel, sub=sub),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(steps,),
+            in_specs=[whole(d), whole(k), whole(1), group],
+            out_specs=[whole(d), group],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((d, ch), f32), _in_hbm(tails)],
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=jax.default_backend() != "tpu",
+        name="jamba_step_conv",
+    )(
+        visit, fresh.reshape(1), slots.astype(i32), live.astype(i32),
+        x.astype(f32), p["conv_w"].astype(f32), p["conv_b"].astype(f32).reshape(1, ch), tails,
+    )
+    return xc, jnp.swapaxes(tails, 0, 1)
+
+
+def _step_scan_kernel(slot_ref, live_ref, x_ref, dt_ref, bb_ref, cc_ref, a_ref, d_ref, h_ref, y_ref, new_ref, *, width):
+    """Grid (row). ONE position of the recurrence for the row: x, dt [8,C] of
+    the row's tile of eight rows, bb, cc [N, lane tile] the row's B and C, h
+    [N,C] its slot's state -> y[row] and the slot's state after the position,
+    `width` channels at a time as `_scan_kernel` walks them. A row that is not
+    live yields zeros and passes its slot's block through: padding rows all
+    name the scratch slot and lie together, so they revisit one block, fetched
+    once and written back once."""
+    i = pl.program_id(0)
+    r = i % x_ref.shape[0]
+    live = live_ref[i] != 0
+
+    @pl.when(live)
+    def _():
+        lanes = min(width, _LANE)
+        b, c = bb_ref[:, :lanes], cc_ref[:, :lanes]
+
+        def channels(j, carry):
+            for q in range(width // lanes):  # independent chains, one lane tile each
+                sl = pl.ds(pl.multiple_of(j * width, width) + q * lanes, lanes)
+                dt, x = _row_of(dt_ref[:, sl], r), _row_of(x_ref[:, sl], r)    # [1, lanes]
+                h = jnp.exp(dt * a_ref[:, sl]) * h_ref[:, sl] + (dt * x) * b
+                y = jnp.sum(h * c, axis=0, keepdims=True) + d_ref[:, sl] * x
+                y_ref[:, sl] = _with_row(y_ref[:, sl], r, y)
+                new_ref[:, sl] = h
+            return carry
+
+        jax.lax.fori_loop(0, x_ref.shape[1] // width, channels, 0)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        y_ref[...] = _with_row(y_ref[...], r, 0.0)
+        # where the row before named the same slot the output block is
+        # revisited and already holds what has to go back
+        before = slot_ref[jnp.maximum(i - 1, 0)]
+
+        @pl.when((i == 0) | (before != slot_ref[i]))
+        def _():
+            new_ref[...] = h_ref[...]
+
+
+def step_scan(x, dt, b, c, a, d, h, slots, live):
+    """`selective_scan`'s recurrence at ONE position of each row, on the
+    slots' state where it lies: x, dt [D,C]; b, c [D,N]; a [N,C]; d [C]; h
+    [S,N,C] float32 -> (y [D,C], h with every live row's slot advanced)."""
+    f32, i32 = jnp.float32, jnp.int32
+    rows, ch = x.shape
+    n = a.shape[0]
+    lane = min(_LANE, ch)
+    sub = _ROWS if rows % _ROWS == 0 else rows
+    # B and C spread along a lane tile, as `selective_scan` hands them over
+    bb = jnp.broadcast_to(b.astype(f32)[..., None], (rows, n, lane))
+    cc = jnp.broadcast_to(c.astype(f32)[..., None], (rows, n, lane))
+    eight = pl.BlockSpec((sub, ch), lambda i, *_: (i // sub, 0))
+    col = pl.BlockSpec((None, n, lane), lambda i, *_: (i, 0, 0))
+    whole = lambda r: pl.BlockSpec((r, ch), lambda i, *_: (0, 0))  # noqa: E731
+    slot = pl.BlockSpec((None, n, ch), lambda i, slots, live: (slots[i], 0, 0))
+    return pl.pallas_call(
+        partial(_step_scan_kernel, width=_channel_block(ch)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows,),
+            in_specs=[eight, eight, col, col, whole(n), whole(1), slot],
+            out_specs=[eight, slot],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((rows, ch), f32), _in_hbm(h)],
+        input_output_aliases={8: 1},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=jax.default_backend() != "tpu",
+        name="jamba_step_scan",
+    )(
+        slots.astype(i32), live.astype(i32), x.astype(f32), dt.astype(f32), bb, cc,
+        a.astype(f32), d.astype(f32).reshape(1, ch), h,
+    )
 
 
 # -- pieces both served programs share (the dtype of the weights decides the
@@ -475,32 +676,47 @@ def prefill(cfg: JambaConfig, params: dict, state: dict, tokens, lengths, slots,
     return state, hidden
 
 
+def _mamba_step(cfg: JambaConfig, p: dict, x, conv, h, slots, live):
+    """`_mamba` at ONE position of each row: x [D,H] float32 the residual
+    stream, conv and h the layer's WHOLE slot arrays -> (x + mixer, conv, h)
+    with the live rows' slots moved on by the position."""
+    with jax.named_scope("jamba.mamba"):
+        xz = _dot(rms_norm(x, p["ln1"], cfg.eps), p["in_proj"])
+        xs, z = xz[:, : cfg.d_inner], xz[:, cfg.d_inner:]
+        with jax.named_scope("jamba.scan"):
+            xc, conv = step_conv(p, xs, conv, slots, live)
+        dt, b, c = _ssm_inputs(cfg, p, xc)
+        with jax.named_scope("jamba.scan"):
+            y, h = step_scan(xc, dt, b, c, -jnp.exp(p["A_log"]), p["D"], h, slots, live)
+        return x + _dot(y * jax.nn.silu(z), p["out_proj"]), conv, h
+
+
 def _token_hidden(cfg: JambaConfig, params: dict, state: dict, slots, pos, live):
     """The layers over ONE token of each of `slots` [D] (its input embedding
     is the slot's `x_in`, its position `pos` [D]): the final-normed hidden
-    state [D,H] float32 and the layers' new state."""
+    state [D,H] float32 and the layers' new state. A Mamba layer's state is
+    updated in its slot; only `live` rows' slots change."""
     f32 = jnp.float32
     dt = params["layers"][0]["wg"].dtype
-    x = state["x_in"][slots].astype(f32)[:, None, :]                            # [D,1,H]
+    x = state["x_in"][slots].astype(f32)                                        # [D,H]
     new = {key: list(state[key]) for key in ("h", "conv", "k", "v")}
     allowed = (jnp.arange(cfg.positions, dtype=jnp.int32)[None, :] <= pos[:, None])[:, None, :]
-    real = live.astype(jnp.int32)
     for l, p in enumerate(params["layers"]):
         if cfg.is_attention(l):
             with jax.named_scope("jamba.attn"):
-                q, k, v = _qkv(cfg, p, rms_norm(x, p["ln1"], cfg.eps))
+                q, k, v = _qkv(cfg, p, rms_norm(x, p["ln1"], cfg.eps)[:, None, :])
                 new["k"][l] = new["k"][l].at[slots, pos].set(k[:, 0].astype(new["k"][l].dtype))
                 new["v"][l] = new["v"][l].at[slots, pos].set(v[:, 0].astype(new["v"][l].dtype))
                 o = _attend(
                     cfg, q, new["k"][l][slots].astype(f32), new["v"][l][slots].astype(f32), allowed, dt
                 )
-                x = x + _dot(o, p["wo"])
+                x = x + _dot(o[:, 0], p["wo"])
         else:
-            x, tail, h = _mamba(cfg, p, x, new["conv"][l][slots], new["h"][l][slots], real)
-            new["conv"][l] = new["conv"][l].at[slots].set(tail)
-            new["h"][l] = new["h"][l].at[slots].set(h)
+            x, new["conv"][l], new["h"][l] = _mamba_step(
+                cfg, p, x, new["conv"][l], new["h"][l], slots, live
+            )
         x = _mlp(cfg, p, x)
-    return rms_norm(x[:, 0], params["final_norm"], cfg.eps), new
+    return rms_norm(x, params["final_norm"], cfg.eps), new
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))

@@ -2,9 +2,10 @@
 kinds of slot state through the batched encoder step (serving/stepper.py) and
 the seq app's request path, on the CPU at a small size: 4 layers (Mamba,
 attention, Mamba, Mamba), hidden 64, d_inner 128, d_state 16, 4 query heads
-on one key-value head, 300 items, seeded weights. The selective scan is the
-Pallas kernel in the interpreter here; `test_the_scan_compiles_for_a_v5e`
-also compiles it at the published widths for a described chip.
+on one key-value head, 300 items, seeded weights. The selective scan and the
+one-token step's two in-place kernels are Pallas kernels in the interpreter
+here; `test_the_scan_compiles_for_a_v5e` also compiles them at the published
+widths for a described chip.
 """
 
 from __future__ import annotations
@@ -293,6 +294,184 @@ def test_padding_rows_touch_only_the_scratch_slot():
     assert np.abs(h[untouched]).max() == 0 and np.abs(np.asarray(state["k"][1])[untouched]).max() == 0
 
 
+# ---- the one-token step: the slots' state updated where it lies --------------------
+
+def _step_rows(n_live, seed=0, rows=32):
+    """`n_live` live rows first, in shuffled slot order; the rest padding on
+    the scratch slot."""
+    rng = np.random.default_rng(seed)
+    slots = np.full(rows, rows, np.int32)
+    slots[:n_live] = rng.permutation(rows)[:n_live]
+    live = np.arange(rows) < n_live
+    return slots, live
+
+
+@pytest.mark.parametrize("n_live", [1, 3, 32])
+def test_one_step_of_the_update_against_the_mixer_at_one_position(n_live):
+    """`_mamba_step` on the whole slot arrays against the prefill's mixer at
+    T = 1 on gathered state, and from an empty slot against the reference."""
+    params, _ = _weights()
+    p = params["layers"][0]
+    rng = np.random.default_rng(n_live)
+    rows, c = 32, CFG.d_inner
+    x = jnp.asarray(rng.standard_normal((rows, CFG.hidden)).astype(np.float32))
+    conv = jnp.asarray(rng.standard_normal((rows + 1, CFG.d_conv - 1, c)).astype(np.float32))
+    h = jnp.asarray(rng.standard_normal((rows + 1, CFG.d_state, c)).astype(np.float32))
+    slots, live = _step_rows(n_live, seed=n_live)
+    want, tail, state = jamba._mamba(
+        CFG, p, x[:, None, :], conv[slots], h[slots], jnp.asarray(live.astype(np.int32))
+    )
+    got, new_conv, new_h = jamba._mamba_step(CFG, p, x, conv, h, jnp.asarray(slots), jnp.asarray(live))
+    on = np.flatnonzero(live)
+    np.testing.assert_allclose(np.asarray(got)[on], np.asarray(want)[on, 0], atol=1e-6)
+    np.testing.assert_allclose(np.asarray(new_h)[slots[on]], np.asarray(state)[on], atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(new_conv)[slots[on]], np.asarray(tail)[on])
+    others = np.setdiff1d(np.arange(rows + 1), slots[on])
+    np.testing.assert_array_equal(np.asarray(new_h)[others], np.asarray(h)[others])
+    np.testing.assert_array_equal(np.asarray(new_conv)[others], np.asarray(conv)[others])
+    # an empty slot: one position of the reference's recurrence
+    zero_conv, zero_h = jnp.zeros_like(conv), jnp.zeros_like(h)
+    got, _, _ = jamba._mamba_step(CFG, p, x, zero_conv, zero_h, jnp.asarray(slots), jnp.asarray(live))
+    with jax.default_matmul_precision("highest"):
+        for i in on[:3]:
+            u = sdar.rms_norm(x[i:i + 1], p["ln1"], CFG.eps)
+            ref = np.asarray(x[i] + jamba._reference_mamba(CFG, p, u)[0])
+            np.testing.assert_allclose(np.asarray(got[i]), ref, atol=F32_ATOL)
+
+
+def _random_state(enc, seed):
+    """A slot cache in which every slot holds something, the scratch slot too."""
+    rng = np.random.default_rng(seed)
+
+    def fill(a):
+        if a is None:
+            return None
+        if jnp.issubdtype(a.dtype, jnp.integer):
+            return jnp.asarray(rng.integers(0, 100, a.shape).astype(np.int32))
+        return jnp.asarray(rng.standard_normal(a.shape).astype(np.float32), a.dtype)
+
+    return jax.tree.map(fill, enc.init_state(enc.step_rows), is_leaf=lambda a: a is None)
+
+
+def _host(state):
+    return jax.tree.map(lambda a: None if a is None else np.asarray(a), state, is_leaf=lambda a: a is None)
+
+
+NAMED = (5, 17, 2)  # the live rows' slots
+
+
+@pytest.fixture(scope="module")
+def around_a_step():
+    """(the state before, the state after) ONE step of three live rows over a
+    cache whose every slot is in use."""
+    params, e = _weights()
+    enc = jamba.JambaEncoder(CFG, jnp.float32)
+    state = _random_state(enc, 11)
+    before = _host(state)
+    slots = np.full(enc.step_rows, enc.step_rows, np.int32)
+    slots[:3] = NAMED
+    lengths = np.zeros(enc.step_rows, np.int32)
+    lengths[:3] = (6, 20, 11)
+    live = np.arange(enc.step_rows) < 3
+    step = np.zeros(enc.step_rows, np.int32)
+    step[:3] = (0, 3, 1)
+    state, _ = enc.step(params, state, (jnp.asarray(e), N_ITEMS, None), slots, lengths, live, step)
+    return before, _host(state)
+
+
+@pytest.mark.parametrize("key", ["h", "conv", "k", "v", "x_in", "z", "row", "step"])
+def test_a_step_leaves_every_slot_it_does_not_name_bit_identical(around_a_step, key):
+    """The in-place write is the new way to corrupt a neighbour: every slot
+    but the three named (and the scratch slot, the padding rows') is after
+    the step what it was before it, to the bit."""
+    before, after = around_a_step
+    others = [s for s in range(33) if s not in NAMED + (32,)]
+    layers = before[key] if isinstance(before[key], list) else [before[key]]
+    after_layers = after[key] if isinstance(after[key], list) else [after[key]]
+    seen = 0
+    for was, now in zip(layers, after_layers):
+        if was is None:
+            continue
+        np.testing.assert_array_equal(now[others], was[others])
+        assert not np.array_equal(now[list(NAMED)], was[list(NAMED)])  # and the named ones moved
+        seen += 1
+    assert seen
+
+
+@pytest.mark.parametrize("holds", ["zeros", "nan", "huge"])
+def test_padding_rows_write_the_scratch_slot_alone_whatever_it_holds(holds):
+    """29 padding rows on the scratch slot: the live rows' answers and every
+    other slot are the same whatever the scratch slot holds."""
+    params, e = _weights()
+    enc = jamba.JambaEncoder(CFG, jnp.float32)
+    head = (jnp.asarray(e), N_ITEMS, None)
+    scratch = enc.step_rows
+    value = {"zeros": 0.0, "nan": np.nan, "huge": 3e38}[holds]
+
+    def run(poison):
+        state = _random_state(enc, 5)
+        if poison:
+            state = jax.tree.map(
+                lambda a: None if a is None else (a if jnp.issubdtype(a.dtype, jnp.integer) else a.at[scratch].set(value)),
+                state, is_leaf=lambda a: a is None,
+            )
+        slots, live = _step_rows(3, seed=4)
+        lengths = np.where(live, 9, 0).astype(np.int32)
+        state, out = enc.step(params, state, head, slots, lengths, live, np.zeros(enc.step_rows, np.int32))
+        return slots, _host(state), {k: np.asarray(v) for k, v in out.items()}
+
+    slots, clean, clean_out = run(False)
+    _, dirty, dirty_out = run(True)
+    for k in ("z", "row", "step"):
+        np.testing.assert_array_equal(dirty_out[k][:3], clean_out[k][:3])
+    kept = np.arange(scratch)
+    for key in ("h", "conv", "k", "v", "x_in", "z"):
+        pairs = zip(clean[key], dirty[key]) if isinstance(clean[key], list) else [(clean[key], dirty[key])]
+        for was, now in pairs:
+            if was is not None:
+                np.testing.assert_array_equal(now[kept], was[kept])
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize(
+    "kernel,key,aliased", [("jamba_step_scan", "h", (8, 1)), ("jamba_step_conv", "conv", (7, 1))]
+)
+def test_the_step_addresses_its_state_by_slot(kernel, key, aliased):
+    """The state is addressed by slot, not carried: the step's jaxpr holds no
+    gather and no scatter of a layer's state array, and the kernel that
+    updates it aliases its state input to its output."""
+    params, e = _weights()
+    enc = jamba.JambaEncoder(CFG, jnp.float32)
+    state = enc.init_state(enc.step_rows)
+    rows = lambda dt: jnp.zeros(enc.step_rows, dt)  # noqa: E731
+    jaxpr = jax.make_jaxpr(jamba.decode_step, static_argnums=(0,))(
+        CFG, params, state, jnp.asarray(e), jnp.int32(N_ITEMS), rows(jnp.int32), rows(jnp.int32), rows(bool), rows(jnp.int32)
+    )
+    shape = state[key][0].shape
+    swapped = (shape[1], shape[0], shape[2])  # the conv's inputs are handed over as they lie on the chip
+    moved = [
+        eqn.primitive.name for eqn in _equations(jaxpr.jaxpr)
+        if eqn.primitive.name.startswith(("gather", "scatter", "dynamic_slice", "dynamic_update_slice"))
+        and tuple(eqn.invars[0].aval.shape) in (shape, swapped, (enc.step_rows,) + shape[1:])
+    ]
+    assert not moved
+    calls = [
+        eqn for eqn in _equations(jaxpr.jaxpr)
+        if eqn.primitive.name == "pallas_call" and eqn.params["name"] == kernel
+    ]
+    mamba_layers = sum(1 for l in range(CFG.layers) if not CFG.is_attention(l))
+    assert len(calls) == mamba_layers
+    for eqn in calls:
+        assert tuple(eqn.params["input_output_aliases"]) == (aliased,)
+        assert tuple(eqn.invars[aliased[0]].aval.shape) in (shape, swapped)
+
+
 # ---- through the seam, the stepper and the app ------------------------------------
 
 def _jamba_message(seed=7):
@@ -425,19 +604,53 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compile_uncached(lowered):
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+
+
 @pytest.mark.parametrize("rows,t", [(8, 32), (8, 100), (32, 1)], ids=["prefill_32", "prefill_100", "step"])
 def test_the_scan_compiles_for_a_v5e(one_chip, rows, t, monkeypatch):
-    """The kernel at the published widths (5,120 channels, 16 states) through
-    the chip's own compiler: what Mosaic refuses, it refuses here."""
+    """The kernels at the published widths (5,120 channels, 16 states) through
+    the chip's own compiler: what Mosaic refuses, it refuses here. A prefill's
+    is the scan over its positions; a step's are the two that update 32 rows'
+    slots of 33 in place, compiled inside a step of one Mamba and one attention
+    layer, whose text also shows that no slot array is copied, gathered or
+    scattered around them."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel's compiled form
     sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     ch, n = 5120, 16
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        compiled = jax.jit(jamba.selective_scan).lower(
+    if t > 1:
+        compiled = _compile_uncached(jax.jit(jamba.selective_scan).lower(
             sds((rows, t, ch)), sds((rows, t, ch)), sds((rows, t, n)), sds((rows, t, n)),
             sds((n, ch)), sds((ch,)), sds((rows, n, ch)), sds((rows,), jnp.int32),
-        ).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-    assert "jamba_scan" in compiled.as_text()
+        ))
+        assert "jamba_scan" in compiled.as_text()
+        return
+    real = jamba.JambaConfig(
+        hidden=2560, heads=20, kv_heads=1, intermediate=8192, layers=2, vocab=65536,
+        attn_period=2, attn_offset=1,
+    )
+    assert real.d_inner == ch and real.d_state == n
+    on_chip = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)  # noqa: E731
+    params = on_chip(jax.eval_shape(lambda: jamba.init_params(real, 1)))
+    state = on_chip(jax.eval_shape(lambda: jamba.init_state(real, rows)))
+    assert state["h"][0].shape == (rows + 1, n, ch)
+    text = _compile_uncached(jamba.decode_step.lower(
+        real, params, state, sds((81920, 2560), jnp.bfloat16), sds((), jnp.int32),
+        sds((rows,), jnp.int32), sds((rows,), jnp.int32), sds((rows,), jnp.bool_), sds((rows,), jnp.int32),
+    )).as_text()
+    assert "jamba_step_scan" in text and "jamba_step_conv" in text and "jamba_scan" not in text.replace("jamba_step_scan", "")
+    # the slots' state is updated where it lies: no instruction but the two
+    # kernels (and the program's own parameters and results) holds a whole
+    # slot array, in either layout the chip keeps it in
+    whole = ("f32[33,16,5120]", "f32[32,16,5120]", "f32[33,3,5120]", "f32[32,3,5120]", "f32[3,33,5120]")
+    moving = [
+        line.strip()[:200] for line in text.splitlines()
+        if any(w in line.split("metadata=")[0] for w in whole)
+        and any(f" {op}(" in line for op in ("copy", "copy-start", "gather", "scatter", "dynamic-slice", "dynamic-update-slice", "fusion", "slice-start"))
+    ]
+    assert not moving, moving
