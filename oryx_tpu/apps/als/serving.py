@@ -1014,16 +1014,18 @@ class ALSServingModel(ServingModel):
 
         def _post(result):
             t_post = time.monotonic()
-            pairs = _post_pairs(result)
-            if rescorer is None and pairs:
-                # device-path live recall: the exact reference is the
-                # row-aligned host mirror the response was re-ranked
-                # against (no copy; the drain reads it by reference)
-                self._shadow_sample(
-                    user_vector, pairs, how_many, exclude, cosine,
-                    self._effective_mode, trace_id,
-                    lambda: (host_mat, ids, n),
-                )
+            # the region around exactly what the `rerank` stage times
+            with get_tracer().region("post.rerank", cpu=True):
+                pairs = _post_pairs(result)
+                if rescorer is None and pairs:
+                    # device-path live recall: the exact reference is the
+                    # row-aligned host mirror the response was re-ranked
+                    # against (no copy; the drain reads it by reference)
+                    self._shadow_sample(
+                        user_vector, pairs, how_many, exclude, cosine,
+                        self._effective_mode, trace_id,
+                        lambda: (host_mat, ids, n),
+                    )
             if ledger is not None:
                 # the first two parts of `serialize` (perfattr.POST_STAGES):
                 # the wait between the device phase's end and this call,

@@ -270,7 +270,7 @@ class SeqServingModel(ServingModel):
             out.set_result([])
             return out
         from oryx_tpu.common.perfattr import current_ledger, swap_ledger
-        from oryx_tpu.common.tracing import current_span
+        from oryx_tpu.common.tracing import current_span, get_tracer
 
         span = current_span()
         trace_id = span.trace_id if span is not None else None
@@ -324,16 +324,18 @@ class SeqServingModel(ServingModel):
         def _finish(encoded, results):
             try:
                 t_post = time.monotonic()
-                pages = [
-                    _post(h, r) for h, r in zip(encoded.hidden, results)
-                ]
-                if generates:
-                    answer = [
-                        {"item": ids[int(row)], "step": int(step), "next": page}
-                        for row, step, page in zip(encoded.rows, encoded.steps, pages)
+                # the region around exactly what the `rerank` stage times
+                with get_tracer().region("post.rerank", cpu=True):
+                    pages = [
+                        _post(h, r) for h, r in zip(encoded.hidden, results)
                     ]
-                else:
-                    answer = pages[0]
+                    if generates:
+                        answer = [
+                            {"item": ids[int(row)], "step": int(step), "next": page}
+                            for row, step, page in zip(encoded.rows, encoded.steps, pages)
+                        ]
+                    else:
+                        answer = pages[0]
                 if ledger is not None:
                     # the first two parts of `serialize` (perfattr.POST_STAGES),
                     # as apps/als/serving.py _post stamps them

@@ -102,6 +102,12 @@ EVENT_KINDS: dict[str, str] = {
         "replica, down drained one, stopped it, and removed its ring "
         "keys"
     ),
+    "stall": (
+        "an event loop's 50 ms heartbeat fired 100 ms late or more: the "
+        "lateness, the process's CPU time over the beat, the collections "
+        "that ran in it and what every instrumented thread is in "
+        "(common/tracing.py note_beat)"
+    ),
     "crash-loop": (
         "the fleet supervisor gave up restarting crash-looping replicas "
         "(max fast fails reached); the affected replicas surface as "

@@ -62,6 +62,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from oryx_tpu.common.metrics import get_registry, linear_buckets
+from oryx_tpu.common.tracing import get_tracer, name_thread
 
 log = logging.getLogger(__name__)
 
@@ -448,6 +449,8 @@ class QualityStats:
         self._stop.set()
 
     def _drain_loop(self) -> None:  # oryxlint: offloop (dedicated shadow-rescore thread)
+        name_thread("oryx-quality")
+        tr = get_tracer()
         while not self._stop.is_set():
             try:
                 sample = self._queue.get(timeout=1.0)
@@ -456,7 +459,11 @@ class QualityStats:
             while self.drain_gate.is_set() and not self._stop.is_set():
                 time.sleep(0.005)
             try:
-                self._process(sample)
+                # an exact host top-k over the WHOLE matrix on every core
+                # the BLAS takes: the region is how a stall line and the
+                # xplane name what the serving threads waited behind
+                with tr.region("quality.rescore", cpu=True):
+                    self._process(sample)
             except Exception:  # noqa: BLE001 - the sampler never breaks serving
                 log.exception("shadow rescore sample failed")
             finally:

@@ -44,18 +44,37 @@ session, on the device trace's clock: per dispatch
 ``batcher.launch{dispatch, rows, padded, k_bucket}`` holding
 ``batcher.issue``, and later ``batcher.fetch{dispatch}`` and
 ``batcher.distribute{dispatch}`` (docs/observability.md says what each
-covers).
+covers). The stepper's thread and the post pool's open theirs the same
+way (``stepper.*``, ``post.*``).
+
+A region also COUNTS, always on, with no profiler session and the ring
+off: ``oryx_region_seconds_total{region}`` (wall),
+``oryx_region_cpu_seconds_total{region}`` (the thread's own CPU time, for
+the regions that ask for it; wall minus CPU is the time the thread was not
+running: waiting for the interpreter lock, for a lock of the program, for
+the runtime, or descheduled) and
+``oryx_regions_total{region}``, in per-thread cells that only their thread
+writes and a scrape sums. Each thread's OPEN region is
+kept too, so the program can say at any moment what every instrumented
+thread is in (``thread_table``: ``GET /debug/threads``), and the event
+loops' heartbeat (serving/aserver.py) hands its lateness to
+``note_beat``, the witness of the stalls in which every thread waits.
 """
 
 from __future__ import annotations
 
+import collections
+import gc
 import itertools
 import logging
 import os
 import re
+import sys
 import threading
 import time
 from typing import NamedTuple
+
+log = logging.getLogger(__name__)
 
 _TRACEPARENT_RE = re.compile(
     r"^([0-9a-f]{2})-([0-9a-f]{32})-([0-9a-f]{16})-([0-9a-f]{2})$"
@@ -249,16 +268,23 @@ class Tracer:
             self._record(s)
         return s
 
-    def region(self, name: str, **attrs) -> "_Region":
+    def region(self, name: str, cpu: bool = False, **attrs) -> "_Region":
         """Context manager for a span bound to the calling thread, on both
         clocks: it ALWAYS enters a ``jax.profiler.TraceAnnotation`` (which
         costs well under a microsecond with no profiler session, and with
         one puts the region into the xplane beside the device's ops), and
-        it records a ``Span`` into the ring only while tracing is enabled.
+        it records a ``Span`` into the ring only while tracing is enabled;
+        and it ALWAYS adds its wall time and one to the thread's cell of
+        ``name`` (the ``oryx_region_*`` counters, whose label is the name
+        alone) and is the thread's open region meanwhile. ``cpu=True`` adds
+        the thread's CPU time too: two ``time.thread_time`` calls, each a
+        5.9 us system call on the serving host where the wall clock is
+        0.09 us (my chip run, PR 39), so only the regions whose off-CPU
+        share some reader reads ask for it, and only they have the series.
         The span parents to the thread's current span and is the current
         span inside the block, so nested regions form a tree. In a process
         without jax only the ring half exists."""
-        return _Region(self, name, attrs)
+        return _Region(self, name, attrs, cpu)
 
     def _record(self, span: Span) -> None:
         span.seq = next(self._seq)
@@ -347,20 +373,78 @@ def _trace_annotation():
     return _annotation_cls
 
 
+class _ThreadRegions:
+    """One thread's account of its regions. Only the owning thread writes:
+    `cells` is {region: [wall s, CPU s, count]}; `open` the innermost open
+    region as (name, monotonic start) or None; `long` the innermost region
+    of STALL_S or more that it last left, as (name, start, end)."""
+
+    __slots__ = ("thread", "name", "cells", "open", "long")
+
+    def __init__(self):
+        self.thread = threading.current_thread()
+        self.name = self.thread.name
+        self.cells: dict[str, list[float]] = {}
+        self.open: tuple[str, float] | None = None
+        self.long: tuple[str, float, float] | None = None
+
+
+_region_lock = threading.Lock()
+_region_threads: list[_ThreadRegions] = []  # guarded-by: _region_lock
+# the cells of threads that have exited, so the counters stay monotonic
+_region_retired: dict[str, list[float]] = {}  # guarded-by: _region_lock
+
+
+def _thread_regions() -> _ThreadRegions:
+    st = getattr(_tls, "regions", None)
+    if st is None:
+        st = _tls.regions = _ThreadRegions()
+        with _region_lock:
+            _region_threads.append(st)
+    return st
+
+
+def _new_cell(st: _ThreadRegions, name: str, cpu: bool) -> list[float]:
+    """A thread's first entry of a region: the one place that takes a lock
+    (the registry's), so that the region's series are on /metrics whatever
+    cleared the registry since the name was last seen."""
+    cell = st.cells[name] = [0.0, 0.0, 0]
+    if cpu:
+        _cpu_regions.add(name)
+    _bind_region(name)
+    return cell
+
+
 class _Region:
     """What ``Tracer.region`` returns; see there."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_annotation", "_span", "_prev")
+    __slots__ = (
+        "_tracer", "_name", "_attrs", "_annotation", "_span", "_prev",
+        "_st", "_cell", "_outer", "_t0", "_c0", "_cpu",
+    )
 
-    def __init__(self, tracer: Tracer, name: str, attrs: dict):
+    def __init__(self, tracer: Tracer, name: str, attrs: dict, cpu: bool = False):
         self._tracer = tracer
         self._name = name
         self._attrs = attrs
+        self._cpu = cpu
         self._annotation = None
         self._span = None
         self._prev = None
 
     def __enter__(self) -> "_Region":
+        st = self._st = _thread_regions()
+        self._cell = st.cells.get(self._name) or _new_cell(st, self._name, self._cpu)
+        self._outer = st.open
+        # the clocks are read OUTSIDE the annotation: entering or leaving one
+        # may give the interpreter lock away (the dispatcher's first one
+        # after it has set its requests' futures does, to the threads it
+        # woke), and that wait is the region's, or the regions would not
+        # tile. The wall clock is read outside the CPU clock, so a region
+        # that never leaves the CPU reads CPU <= wall
+        t0 = self._t0 = time.monotonic()
+        self._c0 = time.thread_time() if self._cpu else None
+        st.open = (self._name, t0)
         cls = _trace_annotation()
         if cls is not None:
             self._annotation = cls(self._name, **self._attrs)
@@ -378,6 +462,263 @@ class _Region:
             self._tracer.finish(self._span)
         if self._annotation is not None:
             self._annotation.__exit__(exc_type, exc, tb)
+        cell, t0 = self._cell, self._t0
+        if self._c0 is not None:
+            cell[1] += time.thread_time() - self._c0
+        t1 = time.monotonic()
+        cell[0] += t1 - t0
+        cell[2] += 1
+        st = self._st
+        st.open = self._outer
+        if t1 - t0 >= STALL_S and (st.long is None or st.long[1] < t0):
+            # the innermost wins: a child that was as long leaves first,
+            # and its parent, which started before it, does not replace it
+            st.long = (self._name, t0, t1)
+
+
+def thread_region_seconds(name: str) -> float:
+    """Wall seconds the CALLING thread has spent in region `name`: what a
+    dispatcher reads to take a region's delta between two of its own
+    events without a second stopwatch."""
+    cell = _thread_regions().cells.get(name)
+    return cell[0] if cell is not None else 0.0
+
+
+def _live_cells() -> list[dict[str, list[float]]]:  # oryxlint: holds=_region_lock
+    """The live threads' cells, after folding the exited threads' into
+    `_region_retired` (so a counter never falls when a thread ends)."""
+    for st in list(_region_threads):
+        if not st.thread.is_alive():
+            _region_threads.remove(st)
+            _fold(_region_retired, st.cells)
+    return [st.cells for st in _region_threads]
+
+
+def _fold(into: dict[str, list[float]], cells: dict[str, list[float]]) -> None:
+    for name, cell in list(cells.items()):
+        total = into.setdefault(name, [0.0, 0.0, 0])
+        for i in range(3):
+            total[i] += cell[i]
+
+
+def region_totals() -> dict[str, tuple[float, float, float]]:
+    """{region: (wall s, CPU s, count)} summed over every thread that has
+    entered one, the exited ones included. A thread's cell may move under
+    the read: the three numbers of a region still open elsewhere are each
+    right and need not be of the same instant."""
+    out: dict[str, list[float]] = {}
+    with _region_lock:
+        live = _live_cells()
+        _fold(out, _region_retired)
+    for cells in live:
+        _fold(out, cells)
+    return {name: (c[0], c[1], c[2]) for name, c in out.items()}
+
+
+def _region_value(name: str, i: int) -> float:
+    """One series of one region: what a scrape's callback reads."""
+    with _region_lock:
+        live = _live_cells()
+        total = _region_retired.get(name, (0.0, 0.0, 0))[i]
+    for cells in live:
+        cell = cells.get(name)
+        if cell is not None:
+            total += cell[i]
+    return float(total)
+
+
+# the regions some thread has entered with cpu=True: only they have a series
+# of the CPU family (a 0 there would read "never on the CPU")
+_cpu_regions: set[str] = set()
+
+_REGION_FAMILIES = (
+    ("oryx_region_seconds_total",
+     "Wall seconds the program's threads spent in each region "
+     "(Tracer.region), by region"),
+    ("oryx_region_cpu_seconds_total",
+     "CPU seconds of the thread's own (time.thread_time) inside each region "
+     "that asks for them, by region; wall minus CPU is the time the thread "
+     "was not running: waiting for the interpreter lock, a lock, the "
+     "runtime, or descheduled"),
+    ("oryx_regions_total", "Regions entered and left, by region"),
+)
+
+
+def _bind_region(name: str) -> None:
+    from oryx_tpu.common.metrics import get_registry
+
+    reg = get_registry()
+    for i, (family, help_text) in enumerate(_REGION_FAMILIES):
+        if i == 1 and name not in _cpu_regions:
+            continue
+        reg.counter(family, help_text, labeled=True).set_function(
+            lambda i=i: _region_value(name, i), region=name
+        )
+
+
+def thread_table(now: float | None = None) -> list[dict]:
+    """What every live thread that has entered a region is in right now:
+    [{"thread", "region" (None between regions), "age_s", and "long": the
+    innermost region of STALL_S or more it last left, as {"region",
+    "seconds", "ended_s_ago"}}]."""
+    now = time.monotonic() if now is None else now
+    with _region_lock:
+        threads = [st for st in _region_threads if st.thread.is_alive()]
+    rows = []
+    for st in threads:
+        open_, long_ = st.open, st.long
+        row = {
+            "thread": st.name,
+            "region": open_[0] if open_ else None,
+            "age_s": round(now - open_[1], 6) if open_ else None,
+        }
+        if long_ is not None:
+            row["long"] = {
+                "region": long_[0],
+                "seconds": round(long_[2] - long_[1], 6),
+                "ended_s_ago": round(now - long_[2], 6),
+            }
+        rows.append(row)
+    return rows
+
+
+# -- thread names the profiler can show -------------------------------------
+
+_PR_SET_NAME = 15
+
+
+def name_thread(name: str) -> None:
+    """Give the CALLING thread an OS name (Linux `prctl(PR_SET_NAME)`, 15
+    bytes; a no-op elsewhere). Python 3.12 does not hand a thread's name
+    to the OS, and the profiler's host lines are named from the OS: call
+    it as the thread starts, before its first region. The thread's rows
+    of `thread_table` take the name too."""
+    _thread_regions().name = name
+    if not sys.platform.startswith("linux"):
+        return
+    try:
+        import ctypes
+
+        libc = ctypes.CDLL(None, use_errno=True)
+        libc.prctl.argtypes = [
+            ctypes.c_int, ctypes.c_char_p, ctypes.c_ulong, ctypes.c_ulong,
+            ctypes.c_ulong,
+        ]
+        libc.prctl.restype = ctypes.c_int
+        libc.prctl(_PR_SET_NAME, name.encode()[:15], 0, 0, 0)
+    except (OSError, AttributeError):  # no libc to ask: the name is cosmetic
+        pass
+
+
+# -- the stall witness --------------------------------------------------------
+#
+# Runs in which every request waits about a second while the device does what
+# it always does had no span over them. An event loop that re-arms a short
+# timer sees such a stall as the timer firing late, and what it can read then
+# tells the candidates apart: the process's CPU time over the beat (near zero:
+# descheduled, or every thread blocked; near the lateness: ONE thread held the
+# interpreter lock), the collections that ran in it, and what every
+# instrumented thread is in or has just left.
+
+BEAT_S = 0.05   # the event loops' heartbeat (serving/aserver.py)
+STALL_S = 0.1   # a beat this late is a stall; a region this long is kept
+LOOP_LAG_BUCKETS = (0.0005, 0.001, 0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5)
+
+# collections of 5 ms or more: (monotonic start, seconds, generation)
+_collections: collections.deque = collections.deque(maxlen=64)
+_gc_started = [0.0]
+_stall_lock = threading.Lock()
+_stalled_until = 0.0  # guarded-by: _stall_lock
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    now = time.monotonic()
+    if phase == "start":
+        _gc_started[0] = now
+    elif now - _gc_started[0] >= 0.005:
+        _collections.append((_gc_started[0], now - _gc_started[0], info.get("generation")))
+
+
+def _stall_metrics():
+    """(lag histogram, stall seconds, stalls) on the global registry."""
+    from oryx_tpu.common.metrics import get_registry
+
+    reg = get_registry()
+    return (
+        reg.histogram(
+            "oryx_http_loop_lag_seconds",
+            "How late each event loop's 50 ms heartbeat fired, by loop: the "
+            "time a ready callback waited for the loop's thread",
+            buckets=LOOP_LAG_BUCKETS,
+        ),
+        reg.counter(
+            "oryx_stall_seconds_total",
+            "Seconds in which an event loop's heartbeat was 100 ms or more "
+            "overdue (a stall that several loops see counts once)",
+        ),
+        reg.counter(
+            "oryx_stalls_total",
+            "Stalls: an event loop's heartbeat fired 100 ms late or more",
+        ),
+    )
+
+
+def arm_stall_witness() -> None:
+    """What the event loops' owner calls as they start: the witness's
+    `gc.callbacks` entry (once a process) and its three families, so that a
+    process that never stalls reads 0 and not nothing."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    _stall_metrics()
+
+
+def note_beat(loop: str, late_s: float, cpu_s: float) -> dict | None:
+    """One heartbeat of event loop `loop` fired just now, `late_s` after it
+    was due and `cpu_s` of process CPU time after it was armed, BEAT_S
+    before it was due.
+    Observes the lag; a beat STALL_S late or more is a stall: counted,
+    logged as one WARNING line and recorded as a flight event `stall`.
+    Returns the stall's fields, or None."""
+    lag, stall_seconds, stalls = _stall_metrics()
+    late_s = max(0.0, late_s)
+    lag.observe(late_s, loop=loop)
+    if late_s < STALL_S:
+        return None
+    now = time.monotonic()
+    # a stall of the whole process is seen by every loop: the first to fire
+    # counts it and writes the line, the others add what it had not seen yet
+    global _stalled_until
+    with _stall_lock:
+        fresh = now - late_s >= _stalled_until
+        unseen = min(late_s, now - _stalled_until)
+        _stalled_until = now
+    stall_seconds.inc(max(0.0, unseen))
+    if not fresh:
+        return None
+    stalls.inc()
+    stall = {
+        "loop": loop,
+        "late_s": round(late_s, 4),
+        "process_cpu_s": round(cpu_s, 4),
+        "collections": [
+            {"generation": gen, "seconds": round(s, 4)}
+            for t, s, gen in list(_collections) if t + s >= now - late_s - BEAT_S
+        ],
+        # what each thread is in, or the long region it left inside the beat
+        "threads": [
+            row for row in thread_table(now)
+            if row["region"] or row.get("long", {}).get("ended_s_ago", late_s) < late_s
+        ],
+    }
+    log.warning(
+        "stall: loop %s heartbeat %.0f ms late, process CPU %.0f ms in the "
+        "beat, collections %s, threads %s",
+        loop, late_s * 1e3, cpu_s * 1e3, stall["collections"], stall["threads"],
+    )
+    from oryx_tpu.common.flightrec import get_flightrec
+
+    get_flightrec().record(kind="stall", **stall)
+    return stall
 
 
 # -- export -----------------------------------------------------------------
@@ -549,4 +890,8 @@ def configure_tracing(config) -> Tracer:
         capacity=config.get_int("oryx.monitoring.tracing.buffer-size", 2048),
         slow_threshold=config.get("oryx.monitoring.slow-request-threshold", None),
     )
+    # the regions' series, again: a registry cleared since a name was first
+    # entered (tests do) has lost them, and a thread's later entries bind nothing
+    for name in region_totals():
+        _bind_region(name)
     return tr

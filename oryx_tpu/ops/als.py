@@ -1814,6 +1814,28 @@ def topk_path(y, k: int, recall: float = 1.0) -> str:
     return "pallas" if fused else "xla"
 
 
+def stage_topk_operands(xs, y, *, k: int, recall: float = 1.0, rows=None):
+    """The eager part of topk_dot_batch: (xs, rows) as its jitted call
+    takes them. The queries go to the device, zero-padded to a lane-padded
+    view's width and, where the path scores in the matrix's dtype (the
+    bf16 serving view; accumulation is f32 either way), cast to it; the
+    fused kernel's row count becomes the int32 scalar it prefetches.
+    topk_dot_batch does the queries' part to whatever it is handed (the
+    kernel's wrapper uploads the count) and finds nothing left to do on
+    operands that are staged already, so a caller that times the upload
+    apart from the call (serving/batcher.py) stages first."""
+    if not isinstance(xs, jax.Array):
+        xs = jnp.asarray(xs)
+    if xs.shape[1] < y.shape[1]:
+        xs = jnp.pad(xs, ((0, 0), (0, y.shape[1] - xs.shape[1])))
+    path = topk_path(y, k, recall)
+    if path in ("pallas", "approx", "xla") and xs.dtype != y.dtype:
+        xs = jnp.asarray(xs, dtype=y.dtype)
+    if path in ("pallas", "pallas-int8") and rows is not None:
+        rows = jnp.asarray(rows, dtype=jnp.int32)
+    return xs, rows
+
+
 def topk_dot_batch(
     xs, y, *, k: int, recall: float = 1.0, counted: bool = False, rows=None
 ):
@@ -1847,9 +1869,11 @@ def topk_dot_batch(
 
     A resident serving view is lane-padded in features (ops/transfer.py
     kernel_view_put); queries at the published width are zero-padded to
-    it here, once for every path — zeros change no dot product."""
-    if xs.shape[1] < y.shape[1]:
-        xs = jnp.pad(jnp.asarray(xs), ((0, 0), (0, y.shape[1] - xs.shape[1])))
+    it (stage_topk_operands), once for every path — zeros change no dot
+    product."""
+    # `rows` goes on as it was given: each shard and chunk re-enters here
+    # with the same count, and the fused kernel's wrapper uploads it
+    xs, _ = stage_topk_operands(xs, y, k=k, recall=recall)
     path = topk_path(y, k, recall)
     if path in ("pallas", "pallas-int8"):
         from oryx_tpu.ops.pallas_topk import topk_dot_batch_pallas
@@ -1858,11 +1882,7 @@ def topk_dot_batch(
             return topk_dot_batch_pallas(
                 xs, y.q, scales=y.scale, k=k, counted=counted, rows=rows
             )
-        # mixed-precision queries score in the matrix's dtype (the bf16
-        # serving view); accumulation is f32 either way
-        return topk_dot_batch_pallas(
-            jnp.asarray(xs, dtype=y.dtype), y, k=k, counted=counted, rows=rows
-        )
+        return topk_dot_batch_pallas(xs, y, k=k, counted=counted, rows=rows)
     if path == "sharded":
         from oryx_tpu.ops.shard_topk import topk_dot_batch_sharded
 
@@ -1876,7 +1896,6 @@ def topk_dot_batch(
             xs, y.q, y.scale, k=k, recall=float(recall) if recall < 1.0 else 1.0
         )
     else:
-        xs = jnp.asarray(xs, dtype=y.dtype)
         if path == "approx":
             out = topk_dot_batch_approx(xs, y, k=k, recall=float(recall))
         else:

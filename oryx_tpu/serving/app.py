@@ -10,6 +10,7 @@ registered by app modules through register(app); path patterns support
 
 from __future__ import annotations
 
+import itertools
 import json
 import re
 import threading
@@ -26,7 +27,12 @@ from oryx_tpu.bus.api import TopicProducer
 from oryx_tpu.common.config import Config
 from oryx_tpu.common.metrics import GaugeSeriesGone, get_registry
 from oryx_tpu.common.perfattr import swap_ledger
-from oryx_tpu.common.tracing import configure_tracing, swap_current
+from oryx_tpu.common.tracing import (
+    configure_tracing,
+    get_tracer,
+    name_thread,
+    swap_current,
+)
 
 
 @dataclass
@@ -124,9 +130,13 @@ def post_pool():
             if _POST_POOL is None:
                 from concurrent.futures import ThreadPoolExecutor
 
+                seq = itertools.count()
                 _POST_POOL = ThreadPoolExecutor(
                     max_workers=_POST_POOL_WORKERS,
                     thread_name_prefix="oryx-topn-post",
+                    # each worker, as it starts: the name its line of a
+                    # profiler trace and its row of /debug/threads carry
+                    initializer=lambda: name_thread(f"oryx-post-{next(seq)}"),
                 )
     return _POST_POOL
 
@@ -737,12 +747,18 @@ def _render(result: Any, req: Request) -> tuple[int, bytes, str]:
     t0 = time.monotonic()
     tail = req.ledger.last_end()
     start = tail if tail is not None and tail < t0 else t0
-    out = _render_body(result, req)
+    # a top-n answer (its _post stamped handoff and rerank): render is the
+    # third part of this serialize phase, so each such answer has one of
+    # each, and the region `post.render` around exactly what the stage times
+    top_n = bool(req.ledger.stages())
+    if top_n:
+        with get_tracer().region("post.render"):
+            out = _render_body(result, req)
+    else:
+        out = _render_body(result, req)
     end = time.monotonic()
     req.ledger.add("serialize", end - start, start=start)
-    if req.ledger.stages():
-        # a top-n answer (its _post stamped handoff and rerank): the third
-        # part of this serialize phase, so each such answer has one of each
+    if top_n:
         req.ledger.add_stage("render", end - t0)
     return out
 

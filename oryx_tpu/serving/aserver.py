@@ -44,8 +44,12 @@ from urllib.parse import parse_qs, urlsplit
 
 from oryx_tpu.common.perfattr import PhaseLedger, get_perfattr
 from oryx_tpu.common.tracing import (
+    BEAT_S,
+    arm_stall_witness,
     format_traceparent,
     get_tracer,
+    name_thread,
+    note_beat,
     parse_traceparent,
 )
 from oryx_tpu.serving.app import Deferred, Request, ServingApp
@@ -122,6 +126,10 @@ class _LoopState:
         self.requests = 0
         self.started = threading.Event()
         self.error: BaseException | None = None
+        # the heartbeat (_beat): when the armed timer is due on the loop's
+        # clock, and the process's CPU time when it was armed
+        self.beat_due = 0.0
+        self.beat_cpu = 0.0
 
 
 def _loop_requests_reader(ref):
@@ -197,6 +205,7 @@ class AsyncHTTPServer:
 
         # loop 0 binds first and resolves an ephemeral port; the remaining
         # loops then join that CONCRETE port with SO_REUSEPORT
+        arm_stall_witness()
         first = _LoopState(0)
         self._loopstates = [first]
         self._start_loop(first)
@@ -321,7 +330,24 @@ class AsyncHTTPServer:
         if ls.server is not None:
             await ls.server.wait_closed()
 
+    def _beat(self, ls: _LoopState, first: bool = False) -> None:
+        """The loop's heartbeat, on its thread: a BEAT_S timer that says how
+        late it fired (oryx_http_loop_lag_seconds{loop}) and re-arms. A beat
+        STALL_S late is a stall, and common/tracing.py note_beat writes its
+        one line: a loop that is late for its own timer was late for every
+        request that became ready meanwhile."""
+        loop = ls.loop
+        if not first:
+            note_beat(
+                str(ls.index), loop.time() - ls.beat_due,
+                time.process_time() - ls.beat_cpu,
+            )
+        ls.beat_due = loop.time() + BEAT_S
+        ls.beat_cpu = time.process_time()
+        loop.call_at(ls.beat_due, self._beat, ls)
+
     def _run_loop(self, ls: _LoopState) -> None:
+        name_thread(f"oryx-loop-{ls.index}")
         loop = asyncio.new_event_loop()
         asyncio.set_event_loop(loop)
         ls.loop = loop
@@ -349,6 +375,7 @@ class AsyncHTTPServer:
         # set from inside the loop: whoever waited on it finds the loop
         # running, which is what close() asks before it shuts one down
         loop.call_soon(ls.started.set)
+        loop.call_soon(self._beat, ls, True)
         try:
             loop.run_forever()
         finally:

@@ -54,7 +54,12 @@ from oryx_tpu.common.perfattr import (
     get_perfattr,
 )
 from oryx_tpu.common.perfstats import get_perfstats
-from oryx_tpu.common.tracing import current_span, get_tracer
+from oryx_tpu.common.tracing import (
+    current_span,
+    get_tracer,
+    name_thread,
+    thread_region_seconds,
+)
 from oryx_tpu.serving.futureutil import try_set_exception, try_set_result
 
 import numpy as np
@@ -364,15 +369,15 @@ class TopKBatcher:
         self._probe_started = 0.0  # guarded-by: _lock
         self._last_y = None  # guarded-by: _lock
         # idle-gap attribution (common/perfattr.py): _gap_mark is when the
-        # device was last known busy (dispatch issued / results fetched);
-        # the accumulators hold measured slices of the idle time since —
-        # cond waits (empty queue), resolve fetch/distribution tails
-        # (host serialize), and down-window backoff. Classified and reset
-        # at the next dispatch issue (_launch), reset whenever the device
-        # finishes work (_resolve).
+        # device was last known busy (dispatch issued / results fetched).
+        # What filled the idle time since is read off the dispatcher's own
+        # regions at the next issue (_launch): the wall `batcher.idle`
+        # (empty queue) and `batcher.distribute` (host serialize) gained
+        # since the issue before, _gap_seen being their readings then and
+        # the thread they are of. Only the down-window backoff has no
+        # region (the probe's thread adds it) and keeps its accumulator.
         self._gap_mark = time.monotonic()  # guarded-by: _lock
-        self._gap_wait = 0.0  # guarded-by: _lock
-        self._gap_resolve = 0.0  # guarded-by: _lock
+        self._gap_seen = (0, 0.0, 0.0)  # guarded-by: _lock
         self._gap_down = 0.0  # guarded-by: _lock
         self._down_since = 0.0  # guarded-by: _lock
         # observability: dispatch count + coalesced-request count let a
@@ -686,27 +691,34 @@ class TopKBatcher:
         # synchronous device round trip per group; with the copy already
         # in flight, the fetch of batch N overlaps the scan of batch N+1.
         me = threading.current_thread()
+        name_thread("oryx-topk")
+        tr = _TRACER
         inflight: list[tuple[list[_Pending], int, object, object, object, tuple, tuple]] = []
+        # the top-level regions tile this thread's life: idle, pick, one
+        # launch a group, then fetch and distribute of each group of the
+        # cycle before, retire
         while True:
-            with self._cond:
-                while not self._queue and not self._closed and not inflight:
-                    t_w = time.monotonic()
-                    self._cond.wait()
-                    # empty-queue idle accounting for the gap classifier
-                    self._gap_wait += time.monotonic() - t_w
-                if self._closed and not self._queue and not inflight:
-                    return
-                if self._thread is not me:
-                    # superseded after a wedge: a fresh dispatcher owns the
-                    # queue now; whatever this one still holds was already
-                    # failed over by the watchdog
-                    return
-                batch, self._queue = self._queue[: self.max_batch], self._queue[self.max_batch:]
-                for p in batch:
-                    self._inflight[id(p)] = p
-                self._busy_since = time.monotonic()
+            if not inflight:
+                with tr.region("batcher.idle"), self._cond:
+                    while not self._queue and not self._closed:
+                        self._cond.wait()
+            batch: list[_Pending] = []
             try:
-                launched = self._launch(batch) if batch else []
+                with tr.region("batcher.pick"):
+                    with self._cond:
+                        if self._closed and not self._queue and not inflight:
+                            return
+                        if self._thread is not me:
+                            # superseded after a wedge: a fresh dispatcher
+                            # owns the queue now; whatever this one still
+                            # holds was already failed over by the watchdog
+                            return
+                        batch, self._queue = self._queue[: self.max_batch], self._queue[self.max_batch:]
+                        for p in batch:
+                            self._inflight[id(p)] = p
+                        self._busy_since = time.monotonic()
+                    t_pick, groups = self._group(batch)
+                launched = self._launch_groups(groups, t_pick) if groups else []
             except Exception as e:  # pragma: no cover - defensive: a failure
                 # before the per-group guard (grouping, imports) must fail
                 # the whole batch, not kill the thread with futures pending
@@ -716,7 +728,7 @@ class TopKBatcher:
                 launched = []
             for item in inflight:
                 self._resolve(item)
-            with self._cond:
+            with tr.region("batcher.retire"), self._cond:
                 if self._thread is not me:
                     # superseded mid-cycle: the replacement dispatcher owns
                     # _busy_since now — wiping it would blind the watchdog
@@ -736,10 +748,14 @@ class TopKBatcher:
     ) -> list[tuple[list[_Pending], int, object, object, object, tuple, tuple]]:
         """Issue one device dispatch per (matrix, k-bucket) group and start
         the async result copies; returns the in-flight group handles."""
-        import jax.numpy as jnp
+        t_pick, groups = self._group(batch)
+        return self._launch_groups(groups, t_pick)
 
-        from oryx_tpu.ops.als import topk_dot_batch
-
+    def _group(
+        self, batch: list[_Pending]
+    ) -> tuple[float, dict[tuple[int, int, float], list[_Pending]]]:
+        """The end of the pick: queue-wait ends, the batch is grouped by
+        matrix and k-bucket and counted."""
         tr = _TRACER
         # queue-wait ends now: the dispatcher owns the batch
         t_pick = time.monotonic()
@@ -766,7 +782,14 @@ class TopKBatcher:
         with self._lock:
             self.dispatches += len(groups)
             self.coalesced += len(batch)
+        return t_pick, groups
 
+    def _launch_groups(
+        self, groups: dict[tuple[int, int, float], list[_Pending]], t_pick: float
+    ) -> list[tuple[list[_Pending], int, object, object, object, tuple, tuple]]:
+        from oryx_tpu.ops import als
+
+        tr = _TRACER
         launched = []
         gap_pending = True  # classify the inter-dispatch idle gap once
         for (_, kb, recall), group in groups.items():
@@ -782,76 +805,78 @@ class TopKBatcher:
                 self._note_device(y)
                 padded = _pad_rows(b, self._on_accel)
                 with tr.region(
-                    "batcher.launch", dispatch=n_disp, rows=b,
+                    "batcher.launch", cpu=True, dispatch=n_disp, rows=b,
                     padded=padded, k_bucket=kb,
                 ):
-                    # a capacity-padded serving view scores zero rows past
-                    # valid_rows — they're HBM-cheap but not useful FLOPs,
-                    # so the MFU figure counts only the real-data prefix
-                    n_rows = group[0].valid_rows or y.shape[0]
-                    # ... and at the published feature count (the
-                    # queries'), not the view's lane-padded width
-                    features = int(group[0].vec.shape[-1])
-                    group_flops = 2.0 * b * n_rows * features
-                    # per-dtype peak: a quantized (int8) dispatch's MFU
-                    # window divides by the int8 peak, an exact bf16 one
-                    # by bf16
-                    _PERF.set_peak("serving", self._peak_for_matrix(y))
-                    # keyed on the FULL (capacity) shape: the serving
-                    # view pads rows up a bucket ladder precisely so
-                    # store growth keeps hitting these compiled entries
-                    shape_key = (
-                        padded, kb, recall, tuple(y.shape),
-                        str(getattr(y, "dtype", "")),
-                    )
-                    first_compile = False
-                    with self._cond:
-                        # recovery probes re-test against the latest
-                        # matrix; the probe thread reads it under the
-                        # same lock [oryxlint guarded-by fix: these
-                        # three were unlocked]
-                        self._last_y = y
-                        self.flops_scored += group_flops
-                        if shape_key not in self._compiled_shapes:
-                            # first dispatch of this shape may
-                            # cold-compile for minutes: give the hang
-                            # watchdog compile grace (for THIS shape,
-                            # until it resolves) so it doesn't misread
-                            # the compile as a hung device and fail the
-                            # device path over to host scoring
-                            first_compile = True
-                            self._compiling[shape_key] = (
-                                time.monotonic() + self.compile_timeout
-                            )
-                    for p in group:
-                        if p.ledger is not None:
-                            # picked -> this group starts forming
-                            p.ledger.add(
-                                "batch_wait", t0 - t_pick, start=t_pick
-                            )
-                    t_pad = time.monotonic()
-                    # at the view's width: its pad lanes stay zero
-                    xs = np.zeros((padded, y.shape[1]), dtype=np.float32)
-                    for i, p in enumerate(group):
-                        xs[i, :features] = p.vec
-                    pad_s = time.monotonic() - t_pad
-                    for p in group:
-                        if p.ledger is not None:
-                            p.ledger.add("pad", pad_s, start=t_pad)
-                    if tr.enabled:
-                        # device span: dispatch issue until the host
-                        # fetch resolves (_resolve); one span per
-                        # request so every request's trace tree shows
-                        # its own device time, and names the dispatch
-                        # that caused the wait
+                    with tr.region("batcher.launch.form"):
+                        # a capacity-padded serving view scores zero rows
+                        # past valid_rows — they're HBM-cheap but not useful
+                        # FLOPs, so the MFU figure counts only the real-data
+                        # prefix
+                        n_rows = group[0].valid_rows or y.shape[0]
+                        # ... and at the published feature count (the
+                        # queries'), not the view's lane-padded width
+                        features = int(group[0].vec.shape[-1])
+                        group_flops = 2.0 * b * n_rows * features
+                        # per-dtype peak: a quantized (int8) dispatch's MFU
+                        # window divides by the int8 peak, an exact bf16 one
+                        # by bf16
+                        _PERF.set_peak("serving", self._peak_for_matrix(y))
+                        # keyed on the FULL (capacity) shape: the serving
+                        # view pads rows up a bucket ladder precisely so
+                        # store growth keeps hitting these compiled entries
+                        shape_key = (
+                            padded, kb, recall, tuple(y.shape),
+                            str(getattr(y, "dtype", "")),
+                        )
+                        first_compile = False
+                        with self._cond:
+                            # recovery probes re-test against the latest
+                            # matrix; the probe thread reads it under the
+                            # same lock [oryxlint guarded-by fix: these
+                            # three were unlocked]
+                            self._last_y = y
+                            self.flops_scored += group_flops
+                            if shape_key not in self._compiled_shapes:
+                                # first dispatch of this shape may
+                                # cold-compile for minutes: give the hang
+                                # watchdog compile grace (for THIS shape,
+                                # until it resolves) so it doesn't misread
+                                # the compile as a hung device and fail the
+                                # device path over to host scoring
+                                first_compile = True
+                                self._compiling[shape_key] = (
+                                    time.monotonic() + self.compile_timeout
+                                )
                         for p in group:
-                            if p.t_enq:
-                                p.dev_span = [tr.start(
-                                    "batcher.device",
-                                    parent=p.trace_parent,
-                                    k=kb, batch=b, rows=padded,
-                                    dispatch=n_disp,
-                                )]
+                            if p.ledger is not None:
+                                # picked -> this group starts forming
+                                p.ledger.add(
+                                    "batch_wait", t0 - t_pick, start=t_pick
+                                )
+                        t_pad = time.monotonic()
+                        # at the view's width: its pad lanes stay zero
+                        xs = np.zeros((padded, y.shape[1]), dtype=np.float32)
+                        for i, p in enumerate(group):
+                            xs[i, :features] = p.vec
+                        pad_s = time.monotonic() - t_pad
+                        for p in group:
+                            if p.ledger is not None:
+                                p.ledger.add("pad", pad_s, start=t_pad)
+                        if tr.enabled:
+                            # device span: dispatch issue until the host
+                            # fetch resolves (_resolve); one span per
+                            # request so every request's trace tree shows
+                            # its own device time, and names the dispatch
+                            # that caused the wait
+                            for p in group:
+                                if p.t_enq:
+                                    p.dev_span = [tr.start(
+                                        "batcher.device",
+                                        parent=p.trace_parent,
+                                        k=kb, batch=b, rows=padded,
+                                        dispatch=n_disp,
+                                    )]
                     with tr.region("batcher.issue"):
                         t_disp = time.monotonic()
                         if gap_pending:
@@ -859,35 +884,33 @@ class TopKBatcher:
                             # finishing and this one being issued, split
                             # by measured cause
                             gap_pending = False
-                            with self._lock:
-                                gap = t_disp - self._gap_mark
-                                causes = classify_idle_gap(
-                                    gap, wait_s=self._gap_wait,
-                                    serialize_s=self._gap_resolve,
-                                    down_s=self._gap_down,
-                                )
-                                self._gap_wait = 0.0
-                                self._gap_resolve = 0.0
-                                self._gap_down = 0.0
-                                self._gap_mark = t_disp
-                            for cause, s in causes.items():
-                                _PA.record_idle_gap(cause, s)
-                        # chunks: the fused kernel's counts (chunks fired,
-                        # walked, tiles sorted, chunks inserted), None on
-                        # every other path.
-                        # rows: the kernel walks no row block past the
-                        # group's b real rows
-                        vals, idx, chunks = topk_dot_batch(
-                            jnp.asarray(xs), y, k=kb, recall=recall,
-                            counted=True, rows=b,
-                        )
-                        try:
-                            vals.copy_to_host_async()
-                            idx.copy_to_host_async()
-                            if chunks is not None:
-                                chunks.copy_to_host_async()
-                        except AttributeError:  # non-jax array (test stubs)
-                            pass
+                            self._classify_gap(t_disp)
+                        with tr.region("batcher.issue.upload"):
+                            # whatever topk_dot_batch would do to its
+                            # operands before its jitted call, done here so
+                            # that the two are timed apart: the queries'
+                            # upload and cast, the row count's upload
+                            xd, rows_d = als.stage_topk_operands(
+                                xs, y, k=kb, recall=recall, rows=b
+                            )
+                        with tr.region("batcher.issue.call"):
+                            # chunks: the fused kernel's counts (chunks
+                            # fired, walked, tiles sorted, chunks inserted),
+                            # None on every other path.
+                            # rows: the kernel walks no row block past the
+                            # group's b real rows
+                            vals, idx, chunks = als.topk_dot_batch(
+                                xd, y, k=kb, recall=recall,
+                                counted=True, rows=rows_d,
+                            )
+                        with tr.region("batcher.issue.copy"):
+                            try:
+                                vals.copy_to_host_async()
+                                idx.copy_to_host_async()
+                                if chunks is not None:
+                                    chunks.copy_to_host_async()
+                            except AttributeError:  # non-jax array (test stubs)
+                                pass
                         t_issued = time.monotonic()
                     with self._lock:
                         # the device is busy from here: the next idle gap
@@ -940,6 +963,25 @@ class TopKBatcher:
                 self._fail_group_over(group, e)
         return launched
 
+    def _classify_gap(self, t_disp: float) -> None:
+        """The idle gap from the device last known busy to this issue, by
+        cause: what the dispatcher's regions gained since the issue before
+        (of THIS thread: a superseding dispatcher starts from zero)."""
+        me = threading.get_ident()
+        idle = thread_region_seconds("batcher.idle")
+        distribute = thread_region_seconds("batcher.distribute")
+        with self._lock:
+            seen = self._gap_seen if self._gap_seen[0] == me else (me, 0.0, 0.0)
+            causes = classify_idle_gap(
+                t_disp - self._gap_mark, wait_s=idle - seen[1],
+                serialize_s=distribute - seen[2], down_s=self._gap_down,
+            )
+            self._gap_seen = (me, idle, distribute)
+            self._gap_down = 0.0
+            self._gap_mark = t_disp
+        for cause, s in causes.items():
+            _PA.record_idle_gap(cause, s)
+
     def _fail_group_over(self, group: list[_Pending], e: Exception) -> None:
         """A device dispatch/transfer ERROR (not a wedge — the watchdog
         owns those): serve the group exactly on the host instead of
@@ -971,14 +1013,17 @@ class TopKBatcher:
         (t0, flops, bytes_moved, b, padded, valid, cap, trace_id,
          mode, t_disp, n_disp) = cost
         try:
-            with _TRACER.region("batcher.fetch", dispatch=n_disp):
-                vals = np.asarray(vals_dev)
-                idx = np.asarray(idx_dev)
+            tr = _TRACER
+            with tr.region("batcher.fetch", dispatch=n_disp):
+                with tr.region("batcher.fetch.vals"):
+                    vals = np.asarray(vals_dev)
+                with tr.region("batcher.fetch.idx"):
+                    idx = np.asarray(idx_dev)
                 folded = total = tiles = inserted = blocks = skipped = None
-                if chunks_dev is not None:
-                    folded, total, tiles, inserted = (
-                        int(c) for c in np.asarray(chunks_dev)
-                    )
+                with tr.region("batcher.fetch.chunks"):
+                    counts = None if chunks_dev is None else np.asarray(chunks_dev)
+                if counts is not None:
+                    folded, total, tiles, inserted = (int(c) for c in counts)
                     # the kernel walks whole row blocks: its own count of
                     # chunks walked says how many
                     from oryx_tpu.ops.pallas_topk import dispatch_grid
@@ -987,7 +1032,7 @@ class TopKBatcher:
                     blocks, per_block = dispatch_grid(padded, y.shape, y.dtype)
                     skipped = blocks - total // per_block
                 t_fetch = time.monotonic()
-            with _TRACER.region("batcher.distribute", dispatch=n_disp):
+            with tr.region("batcher.distribute", dispatch=n_disp):
                 # results are on the host: the dispatch's device work +
                 # fetch is complete — record its cost (FLOPs/bytes/wall/
                 # occupancy) into the live perf accounting
@@ -1013,14 +1058,12 @@ class TopKBatcher:
                     self._compiled_shapes.add(shape_key)
                     self._compiling.pop(shape_key, None)
                     # the device finished this dispatch when the fetch
-                    # landed: the next idle gap starts here. Earlier
-                    # accumulator slices predate the device finishing —
-                    # outside the new gap window by construction — so they
-                    # reset with it.
+                    # landed: the next idle gap starts here. An earlier
+                    # down-window slice predates the device finishing —
+                    # outside the new gap window by construction — so it
+                    # resets with it.
                     if t_fetch > self._gap_mark:
                         self._gap_mark = t_fetch
-                        self._gap_wait = 0.0
-                        self._gap_resolve = 0.0
                         self._gap_down = 0.0
                 for i, p in enumerate(group):
                     k_eff = min(p.k, kb)
@@ -1044,9 +1087,6 @@ class TopKBatcher:
                         self.row_blocks_skipped += skipped
                         self.fold_tiles += tiles
                         self.chunks_inserted += inserted
-                    # result-distribution tail: host work the device idles
-                    # behind (the host_serialize slice of the next gap)
-                    self._gap_resolve += time.monotonic() - t_fetch
         except Exception as e:
             log.exception("batcher group resolve failed (k=%d)", kb)
             with self._cond:

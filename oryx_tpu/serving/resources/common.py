@@ -15,7 +15,12 @@ import json
 import time
 
 from oryx_tpu.common.metrics import get_registry
-from oryx_tpu.common.tracing import chrome_trace, get_tracer, span_forest
+from oryx_tpu.common.tracing import (
+    chrome_trace,
+    get_tracer,
+    span_forest,
+    thread_table,
+)
 from oryx_tpu.serving.app import OryxServingException, RawResponse, Request, ServingApp
 
 
@@ -288,6 +293,15 @@ def register(app: ServingApp) -> None:
                 default=str,
             )
         return RawResponse(200, body.encode("utf-8"), "application/json")
+
+    # nonblocking: a read of a few threads' rows, no lock the hot path takes
+    @app.route("GET", "/debug/threads", nonblocking=True)
+    def debug_threads(a: ServingApp, req: Request):
+        """What every instrumented thread (the dispatchers, the post pool,
+        the event loops) is in right now: its open region and the region's
+        age, and the last region of 100 ms or more it left
+        (common/tracing.py thread_table). The table a `stall` event carries."""
+        return {"threads": thread_table()}
 
     # NOT nonblocking: bundling renders the whole metrics page and writes
     # the artifact to disk — worker-thread work, never an event loop's

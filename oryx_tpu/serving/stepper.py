@@ -7,16 +7,19 @@ that read the prefill's keys and values. Both are far too heavy to run once
 a request in the request's thread (a pass streams the model's weights), so
 requests are admitted to cache SLOTS on the device and share dispatches:
 
-    cycle:  pick      admit waiting requests to free slots (at most
-                      `prefill_rows`, in arrival order)
-            launch    ONE prefill dispatch (the admitted sessions, padded to
+    cycle:  pick      take what was submitted; admit waiting requests to
+                      free slots (at most `prefill_rows`, in arrival order)
+            prefill   ONE prefill dispatch (the admitted sessions, padded to
                       the length bucket of the longest)
-            launch    ONE step dispatch (every block in flight, whatever
+            step      ONE step dispatch (every block in flight, whatever
                       its step: those admitted this cycle take step 0)
             fetch     block on the PREVIOUS cycle's results (depth-1
                       pipeline, as TopKBatcher._run: the fetch of cycle N
                       overlaps the device work of cycle N+1)
             distribute  finished requests' hidden rows to their futures
+
+These are the thread's regions too (`stepper.idle` is its wait with nothing
+to step), and they tile its life (docs/observability.md).
 
 A request's state never visits the host between steps: the host counts the
 steps it launched (a block takes exactly `encoder.steps`) and fetches a
@@ -48,7 +51,7 @@ import numpy as np
 
 from oryx_tpu.common.metrics import get_registry
 from oryx_tpu.common.perfattr import current_ledger, get_perfattr
-from oryx_tpu.common.tracing import get_tracer
+from oryx_tpu.common.tracing import get_tracer, name_thread
 from oryx_tpu.serving.futureutil import try_set_exception, try_set_result
 
 log = logging.getLogger(__name__)
@@ -207,36 +210,52 @@ class SeqStepper:
     # -- the dispatcher thread ---------------------------------------------
 
     def _run(self) -> None:  # oryxlint: offloop (dedicated dispatcher thread)
+        name_thread("oryx-seq")
+        tr = _TRACER
         engines: dict[int, Engine] = {}
         inflight: list[tuple] = []
         while True:
-            with self._cond:
-                while not self._queue and not self._closed and not inflight and not engines:
-                    self._cond.wait()
-                if self._closed and not self._queue and not inflight and not engines:
-                    return
-                queued, self._queue = self._queue, []
-            for engine, req in queued:
-                engines[id(engine)] = engine
-                engine.waiting.append(req)
+            if not inflight and not engines:
+                with tr.region("stepper.idle"), self._cond:
+                    while not self._queue and not self._closed:
+                        self._cond.wait()
+            n = next(self._cycle_seq)
+            with tr.region("stepper.pick", cpu=True, cycle=n):
+                with self._cond:
+                    if self._closed and not self._queue and not inflight and not engines:
+                        return
+                    queued, self._queue = self._queue, []
+                for engine, req in queued:
+                    engines[id(engine)] = engine
+                    engine.waiting.append(req)
+                picked = []
+                for engine in list(engines.values()):
+                    try:
+                        picked.append((engine, self._admit(engine)))
+                    except Exception as e:  # noqa: BLE001 - as in the launch below
+                        log.exception("stepper warm-up failed")
+                        self._fail(engine, e)
             launched = []
-            for engine in list(engines.values()):
+            for engine, admitted in picked:
                 try:
-                    item = self._cycle(engine)
+                    item = self._cycle(engine, n, admitted)
                 except Exception as e:  # noqa: BLE001 - fail the engine's requests, keep the thread
                     log.exception("stepper cycle failed")
-                    self._fail(engine, e)
+                    self._fail(engine, e, admitted)
                     item = None
                 if item is not None:
                     launched.append(item)
+            for engine in list(engines.values()):
                 if engine.idle:
                     del engines[id(engine)]
             for item in inflight:
                 self._resolve(item)
             inflight = launched
 
-    def _fail(self, engine: Engine, e: Exception) -> None:
-        for req in list(engine.waiting) + engine.active:
+    def _fail(self, engine: Engine, e: Exception, admitted: list = ()) -> None:
+        # `admitted`: requests a failed cycle had taken off `waiting` and
+        # (an encoder without steps) put nowhere else yet
+        for req in [*admitted, *engine.waiting, *engine.active]:
             try_set_exception(req.future, e)
         engine.waiting.clear()
         engine.active.clear()
@@ -276,26 +295,29 @@ class SeqStepper:
             self._first_use((enc.name, "step", id(engine.params)), t0)
         engine.warmed = True
 
-    def _cycle(self, engine: Engine):
-        """Admit, launch this cycle's dispatches, return what `_resolve`
-        needs (None when there was nothing to launch)."""
+    def _admit(self, engine: Engine) -> list[_Req]:
+        """The pick's part of one engine: waiting requests to free slots."""
         enc = engine.encoder
-        tr = _TRACER
         if not engine.warmed:
             self.warm(engine)
-        n = next(self._cycle_seq)
-        with tr.region("stepper.pick", cycle=n):
-            t_pick = time.monotonic()
-            admitted: list[_Req] = []
-            while (
-                engine.waiting and len(admitted) < enc.prefill_rows
-                and (not enc.steps or engine.free)
-            ):
-                req = engine.waiting.popleft()
-                if enc.steps:
-                    req.slot = engine.free.pop()
-                req.t_pick = t_pick
-                admitted.append(req)
+        t_pick = time.monotonic()
+        admitted: list[_Req] = []
+        while (
+            engine.waiting and len(admitted) < enc.prefill_rows
+            and (not enc.steps or engine.free)
+        ):
+            req = engine.waiting.popleft()
+            if enc.steps:
+                req.slot = engine.free.pop()
+            req.t_pick = t_pick
+            admitted.append(req)
+        return admitted
+
+    def _cycle(self, engine: Engine, n: int, admitted: list[_Req]):
+        """Launch this cycle's dispatches for what `_admit` admitted, return
+        what `_resolve` needs (None when there was nothing to launch)."""
+        enc = engine.encoder
+        tr = _TRACER
         if not admitted and not engine.active:
             return None
         hidden = counts_p = out = None
@@ -306,68 +328,76 @@ class SeqStepper:
             )
             real = sum(r.length for r in admitted)
             with tr.region(
-                "stepper.launch", cycle=n, kind="prefill", rows=len(admitted),
+                "stepper.prefill", cpu=True, cycle=n, rows=len(admitted),
                 padded=enc.prefill_rows, tokens=real, bucket=bucket,
             ):
                 t0 = time.monotonic()
-                packed = enc.pack(
-                    [r.prepared for r in admitted], bucket,
-                    [r.slot for r in admitted], engine.slots,
-                )
-                engine.state, hidden, counts_p = enc.prefill(
-                    engine.params, engine.state, *packed
-                )
-                if not enc.steps:
-                    hidden.copy_to_host_async()
-                if counts_p is not None:
-                    counts_p.copy_to_host_async()
-                self._first_use((enc.name, "prefill", bucket, id(engine.params)), t0)
-            self._m.steps.inc(kind="prefill")
-            self._m.tokens.inc(real, kind="prefill", tokens="real")
-            self._m.tokens.inc(enc.prefill_rows * bucket, kind="prefill", tokens="padded")
-            if enc.steps:
-                engine.active.extend(admitted)
+                with tr.region("stepper.prefill.pack"):
+                    packed = enc.pack(
+                        [r.prepared for r in admitted], bucket,
+                        [r.slot for r in admitted], engine.slots,
+                    )
+                with tr.region("stepper.prefill.call"):
+                    engine.state, hidden, counts_p = enc.prefill(
+                        engine.params, engine.state, *packed
+                    )
+                with tr.region("stepper.prefill.copy"):
+                    if not enc.steps:
+                        hidden.copy_to_host_async()
+                    if counts_p is not None:
+                        counts_p.copy_to_host_async()
+                    self._first_use((enc.name, "prefill", bucket, id(engine.params)), t0)
+                    self._m.steps.inc(kind="prefill")
+                    self._m.tokens.inc(real, kind="prefill", tokens="real")
+                    self._m.tokens.inc(enc.prefill_rows * bucket, kind="prefill", tokens="padded")
+                    if enc.steps:
+                        engine.active.extend(admitted)
         if enc.steps and engine.active:
             rows = engine.active[: enc.step_rows]
             per_row = enc.step_tokens
             with tr.region(
-                "stepper.launch", cycle=n, kind=enc.step_kind, rows=len(rows),
+                "stepper.step", cpu=True, cycle=n, kind=enc.step_kind, rows=len(rows),
                 padded=enc.step_rows, tokens=len(rows) * per_row,
             ):
                 t0 = time.monotonic()
-                slots = np.full((enc.step_rows,), engine.slots, dtype=np.int32)
-                lengths = np.zeros((enc.step_rows,), dtype=np.int32)
-                step = np.zeros((enc.step_rows,), dtype=np.int32)
-                live = np.zeros((enc.step_rows,), dtype=bool)
-                for i, r in enumerate(rows):
-                    slots[i], lengths[i], step[i], live[i] = r.slot, r.length, r.step, True
-                    r.step += 1
-                engine.state, out = enc.step(
-                    engine.params, engine.state, engine.head(), slots, lengths, live, step,
-                )
-                finished = [(i, r) for i, r in enumerate(rows) if r.step >= enc.steps]
-                if "counts" in out:
-                    out["counts"].copy_to_host_async()
-                if finished:
-                    for key in ("z", "row", "step"):
-                        out[key].copy_to_host_async()
-                self._first_use((enc.name, "step", id(engine.params)), t0)
-            self._m.steps.inc(kind=enc.step_kind)
-            self._m.tokens.inc(len(rows) * per_row, kind=enc.step_kind, tokens="real")
-            self._m.tokens.inc(enc.step_rows * per_row, kind=enc.step_kind, tokens="padded")
-            # a finished block's rows ride this dispatch's result: its slot
-            # is free for the next cycle's prefill (the device runs in order)
-            for _, r in finished:
-                engine.active.remove(r)
-                engine.free.append(r.slot)
+                with tr.region("stepper.step.fill"):
+                    slots = np.full((enc.step_rows,), engine.slots, dtype=np.int32)
+                    lengths = np.zeros((enc.step_rows,), dtype=np.int32)
+                    step = np.zeros((enc.step_rows,), dtype=np.int32)
+                    live = np.zeros((enc.step_rows,), dtype=bool)
+                    for i, r in enumerate(rows):
+                        slots[i], lengths[i], step[i], live[i] = r.slot, r.length, r.step, True
+                        r.step += 1
+                with tr.region("stepper.step.call"):
+                    engine.state, out = enc.step(
+                        engine.params, engine.state, engine.head(), slots, lengths, live, step,
+                    )
+                with tr.region("stepper.step.copy"):
+                    finished = [(i, r) for i, r in enumerate(rows) if r.step >= enc.steps]
+                    if "counts" in out:
+                        out["counts"].copy_to_host_async()
+                    if finished:
+                        for key in ("z", "row", "step"):
+                            out[key].copy_to_host_async()
+                    self._first_use((enc.name, "step", id(engine.params)), t0)
+                    self._m.steps.inc(kind=enc.step_kind)
+                    self._m.tokens.inc(len(rows) * per_row, kind=enc.step_kind, tokens="real")
+                    self._m.tokens.inc(enc.step_rows * per_row, kind=enc.step_kind, tokens="padded")
+                    # a finished block's rows ride this dispatch's result: its
+                    # slot is free for the next cycle's prefill (the device
+                    # runs in order)
+                    for _, r in finished:
+                        engine.active.remove(r)
+                        engine.free.append(r.slot)
         self._m.slots.set(engine.slots - len(engine.free) if enc.steps else 0)
         self.cycles += 1
         return n, enc, admitted, hidden, counts_p, finished, out
 
     def _resolve(self, item: tuple) -> None:
         n, enc, admitted, hidden_dev, counts_p, finished, out = item
+        tr = _TRACER
         try:
-            with _TRACER.region("stepper.fetch", cycle=n):
+            with tr.region("stepper.fetch", cycle=n):
                 counts = np.zeros((3,), dtype=np.int64)
                 if counts_p is not None:
                     counts += np.asarray(counts_p)
@@ -377,24 +407,25 @@ class SeqStepper:
                 if finished:
                     z, row, step = (np.asarray(out[k]) for k in ("z", "row", "step"))
                 t_fetch = time.monotonic()
-            if counts.any():
-                self._m.routed.inc(float(counts[0]))
-                self._m.touched.inc(float(counts[1]))
-                self._m.busiest.inc(float(counts[2]))
-            for i, req in enumerate(admitted):
-                # its prefill (and its first step) ran in this cycle
-                req.t_first = t_fetch
-                if not enc.steps:
+            with tr.region("stepper.distribute", cpu=True, cycle=n):
+                if counts.any():
+                    self._m.routed.inc(float(counts[0]))
+                    self._m.touched.inc(float(counts[1]))
+                    self._m.busiest.inc(float(counts[2]))
+                for i, req in enumerate(admitted):
+                    # its prefill (and its first step) ran in this cycle
+                    req.t_first = t_fetch
+                    if not enc.steps:
+                        self._finish(req, t_fetch, Encoded(
+                            np.asarray(hidden[i:i + 1], dtype=np.float32), None, None
+                        ))
+                for i, req in finished:
+                    self._m.blocks.inc()
+                    self._m.denoise.inc(req.step)
                     self._finish(req, t_fetch, Encoded(
-                        np.asarray(hidden[i:i + 1], dtype=np.float32), None, None
+                        np.asarray(z[i], dtype=np.float32), row[i].astype(np.int64),
+                        step[i].astype(np.int64),
                     ))
-            for i, req in finished:
-                self._m.blocks.inc()
-                self._m.denoise.inc(req.step)
-                self._finish(req, t_fetch, Encoded(
-                    np.asarray(z[i], dtype=np.float32), row[i].astype(np.int64),
-                    step[i].astype(np.int64),
-                ))
         except Exception as e:  # noqa: BLE001 - a transfer error fails these requests only
             log.exception("stepper resolve failed")
             for req in admitted + [r for _, r in finished]:

@@ -36,3 +36,25 @@ class WedgeHook:
         if (self.calls == 1 or not self._first_only) and not self.release.is_set():
             self.release.wait(timeout=self._timeout)
         return self._real(xs, y, k=k, **kwargs)
+
+
+def region_tiling(spans, tid, top_level):
+    """How well one thread's regions tile, from the tracer ring's spans.
+
+    -> (covered, by_parent): `covered` is the summed wall of the thread's
+    top-level regions (names in `top_level`) after the first one, over the
+    thread's time from the first one's exit to the last one's; `by_parent`
+    is {parent name: summed wall of its children / its own summed wall} for
+    every region of the thread that had children."""
+    mine = sorted((s for s in spans if s.tid == tid), key=lambda s: s.start)
+    top = [s for s in mine if s.name in top_level]
+    assert all(s.parent is None for s in top), "a top-level region opened inside another"
+    covered = sum(s.duration for s in top[1:]) / (top[-1].end - top[0].end)
+    own, kids = {}, {}
+    for s in mine:
+        if s.parent is not None:
+            kids[s.parent.name] = kids.get(s.parent.name, 0.0) + s.duration
+    for s in mine:
+        if s.name in kids:
+            own[s.name] = own.get(s.name, 0.0) + s.duration
+    return covered, {name: kids[name] / own[name] for name in kids}

@@ -393,3 +393,67 @@ def test_serving_model_approx_recall_wired():
 
     with pytest.raises(ValueError, match="approx-recall"):
         ALSConfig.from_config(load_config(overlay={"oryx.als.approx-recall": 0.0}))
+
+
+class _Late:
+    """A device result that takes 1 ms to reach the host."""
+
+    def __init__(self, value):
+        self._value = value
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(0.001)
+        return self._value
+
+
+def test_the_dispatchers_regions_tile_its_life(y, monkeypatch):
+    """50 dispatches of a stub that sleeps 2 ms: the top-level regions (idle,
+    pick, launch, fetch, distribute, retire) cover the dispatcher thread's
+    time to within 5 %, and each parent's children cover the parent."""
+    from e2e_common import region_tiling
+
+    from oryx_tpu.common.tracing import get_tracer, region_totals
+    from oryx_tpu.ops import als
+
+    def stub(xs, y, *, k, **kw):
+        time.sleep(0.002)
+        n = xs.shape[0]
+        return (
+            _Late(np.zeros((n, k), np.float32)),
+            _Late(np.tile(np.arange(k, dtype=np.int32), (n, 1))),
+            None,
+        )
+
+    monkeypatch.setattr(als, "topk_dot_batch", stub)
+    tr = get_tracer()
+    tr.configure(enabled=True, capacity=8192)
+    tr.clear()
+    before = region_totals()
+    b = TopKBatcher()
+    try:
+        vec = np.ones(8, dtype=np.float32)
+        for i in range(50):
+            vals, idx = b.submit(vec, 5, y)
+            assert list(idx) == [0, 1, 2, 3, 4]
+            if i % 10 == 9:
+                time.sleep(0.01)  # let it reach its idle wait now and then
+        tid = b._thread.ident
+    finally:
+        b.close()
+        spans = tr.snapshot()
+        tr.configure(enabled=False, capacity=2048)
+    top = {
+        "batcher.idle", "batcher.pick", "batcher.launch", "batcher.fetch",
+        "batcher.distribute", "batcher.retire",
+    }
+    covered, by_parent = region_tiling(spans, tid, top)
+    assert 0.95 <= covered <= 1.0001, covered
+    assert set(by_parent) == {"batcher.launch", "batcher.issue", "batcher.fetch"}
+    for parent, share in by_parent.items():
+        assert 0.95 <= share <= 1.0001, (parent, share)
+    # the counters saw the same fifty, whatever the ring holds
+    after = region_totals()
+    for name in ("batcher.launch", "batcher.issue.call", "batcher.fetch.vals", "batcher.distribute"):
+        assert after[name][2] - before.get(name, (0, 0, 0))[2] == 50, name
+    call = after["batcher.issue.call"][0] - before.get("batcher.issue.call", (0.0,))[0]
+    assert 0.1 <= call < 1.0  # 50 x 2 ms of the stub, inside the call region alone

@@ -89,9 +89,14 @@ def test_profiler_session_shows_one_region_tree_per_dispatch(y, tmp_path):
         assert launch_of[n]["end"] <= fetch["start"]
         assert fetch["end"] <= by_number[n]["start"]
     assert len(regions["batcher.issue"]) == moved
-    # every region opened has a reader or a documented use: these four
+    # every region opened has a reader or a documented use: the four the
+    # timeline joins, and the ones that tile the dispatcher's life and its
+    # launch, issue and fetch for the oryx_region_* counters' readers
     assert set(regions) == {
-        "batcher.launch", "batcher.issue", "batcher.fetch", "batcher.distribute"
+        "batcher.launch", "batcher.issue", "batcher.fetch", "batcher.distribute",
+        "batcher.idle", "batcher.pick", "batcher.retire", "batcher.launch.form",
+        "batcher.issue.upload", "batcher.issue.call", "batcher.issue.copy",
+        "batcher.fetch.vals", "batcher.fetch.idx", "batcher.fetch.chunks",
     }
 
 

@@ -525,3 +525,218 @@ def test_healthz_via_dispatch_reports_generation():
         Request("HEAD", "/healthz", {}, {}, b"", {})
     )
     assert status == 200
+
+
+# ---- regions: always-on counters, the open-region table, the stall witness --
+
+
+def _region_series(name):
+    """(wall, cpu, count) of one region as /metrics renders them."""
+    from oryx_tpu.common.metrics import get_registry
+
+    out = {}
+    for line in get_registry().render_prometheus().splitlines():
+        for family in ("oryx_region_seconds_total", "oryx_region_cpu_seconds_total", "oryx_regions_total"):
+            if line.startswith(f'{family}{{region="{name}"}} '):
+                out[family] = float(line.rsplit(" ", 1)[1])
+    return (
+        out.get("oryx_region_seconds_total", 0.0),
+        out.get("oryx_region_cpu_seconds_total", 0.0),
+        out.get("oryx_regions_total", 0.0),
+    )
+
+
+@pytest.mark.parametrize("work", ["sleeps", "spins"])
+def test_region_counts_wall_and_cpu_with_no_profiler_and_the_ring_off(work):
+    """A region leaves its wall time, its thread's CPU time and one behind
+    with nothing switched on; a sleep reads off-CPU, a spin on-CPU."""
+    from oryx_tpu.common.tracing import get_tracer, thread_region_seconds
+
+    tr = get_tracer()
+    assert not tr.enabled
+    name = f"test.region.{work}"
+    before = _region_series(name)
+    with tr.region(name, cpu=True, attr=1):
+        if work == "sleeps":
+            time.sleep(0.1)
+        else:
+            t_end = time.monotonic() + 0.1
+            while time.monotonic() < t_end:
+                pass
+    wall, cpu, n = (a - b for a, b in zip(_region_series(name), before))
+    assert n == 1 and 0.09 < wall < 1.0
+    assert cpu <= wall + 1e-4
+    if work == "sleeps":
+        assert cpu < 0.1 * wall
+    else:
+        # one thread spinning in Python: on the CPU, but for what the test
+        # machine's other work took of the core
+        assert cpu > 0.25 * wall
+    assert thread_region_seconds(name) >= wall - 1e-9
+
+
+def test_region_cells_are_per_thread_and_outlive_their_thread():
+    """Eight threads count into their own cells with no lock; the sum is
+    exact, and stays after the threads are gone."""
+    from oryx_tpu.common.tracing import get_tracer, region_totals
+
+    tr = get_tracer()
+    name = "test.region.cells"
+    base = region_totals().get(name, (0.0, 0.0, 0))[2]
+
+    def worker():
+        for _ in range(500):
+            with tr.region(name):
+                pass
+
+    threads = [threading.Thread(target=worker) for _ in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+        assert not t.is_alive()
+    assert region_totals()[name][2] - base == 4000
+    assert _region_series(name)[2] - base == 4000  # folded from the dead threads' cells
+    # a region that does not ask for the CPU clock has no series of that family
+    from oryx_tpu.common.metrics import get_registry
+
+    page = get_registry().render_prometheus()
+    assert f'oryx_region_seconds_total{{region="{name}"}}' in page
+    assert f'oryx_region_cpu_seconds_total{{region="{name}"}}' not in page
+
+
+def test_the_open_region_table_names_what_a_blocked_thread_is_in():
+    from oryx_tpu.common.tracing import get_tracer, name_thread, thread_table
+
+    tr = get_tracer()
+    entered, release = threading.Event(), threading.Event()
+
+    def parked():
+        name_thread("oryx-test-park")
+        with tr.region("test.outer"):
+            with tr.region("test.parked", why="event"):
+                entered.set()
+                release.wait(timeout=30)
+
+    t = threading.Thread(target=parked)
+    t.start()
+    try:
+        assert entered.wait(timeout=10)
+        time.sleep(0.15)
+        row = next(r for r in thread_table() if r["thread"] == "oryx-test-park")
+        assert row["region"] == "test.parked"  # the innermost
+        assert 0.1 < row["age_s"] < 10
+    finally:
+        release.set()
+        t.join(timeout=10)
+    assert not t.is_alive()
+    assert all(r["thread"] != "oryx-test-park" for r in thread_table())  # gone with its thread
+
+
+def test_the_os_thread_name_is_set_on_linux():
+    import sys
+
+    from oryx_tpu.common.tracing import name_thread
+
+    seen = []
+
+    def worker():
+        name_thread("oryx-test-name-that-is-long")
+        with open(f"/proc/self/task/{threading.get_native_id()}/comm") as f:
+            seen.append(f.read().strip())
+
+    if not sys.platform.startswith("linux"):
+        pytest.skip("prctl(PR_SET_NAME) is Linux's")
+    t = threading.Thread(target=worker)
+    t.start()
+    t.join(timeout=10)
+    assert seen == ["oryx-test-name-"]  # the kernel keeps 15 bytes
+
+
+def test_a_blocked_event_loop_leaves_one_stall_line_and_a_quiet_second_none(tmp_path, caplog):
+    """The witness: a handler that blocks its event loop for 300 ms makes the
+    loop's heartbeat late, and the one `stall` event says how late, what CPU
+    time the process spent, and what a thread parked in a region was in."""
+    import http.client
+    import logging
+
+    from oryx_tpu.bus.broker import get_broker
+    from oryx_tpu.common import flightrec
+    from oryx_tpu.common.config import load_config
+    from oryx_tpu.common.metrics import get_registry
+    from oryx_tpu.common.tracing import get_tracer, name_thread
+    from oryx_tpu.serving.server import ServingLayer
+
+    bus = "mem://tracing-stall"
+    broker = get_broker(bus)
+    for topic in ("OryxInput", "OryxUpdate"):
+        if not broker.topic_exists(topic):
+            broker.create_topic(topic, 1)
+    cfg = load_config(overlay={
+        "oryx.input-topic.broker": bus,
+        "oryx.update-topic.broker": bus,
+        "oryx.serving.api.port": 0,
+        "oryx.monitoring.flight.dir": str(tmp_path / "flight"),
+        "oryx.serving.model-manager-class": "oryx_tpu.apps.example.serving.ExampleServingModelManager",
+        "oryx.serving.application-resources": [
+            "oryx_tpu.serving.resources.common",
+            "oryx_tpu.serving.resources.example",
+        ],
+    })
+    tr = get_tracer()
+    entered, release = threading.Event(), threading.Event()
+
+    def parked():
+        name_thread("oryx-test-wait")
+        with tr.region("test.waiting"):
+            entered.set()
+            release.wait(timeout=60)
+
+    def stalls():
+        return [e for e in flightrec.read_events(str(tmp_path / "flight")) if e["kind"] == "stall"]
+
+    t = threading.Thread(target=parked)
+    t.start()
+    try:
+        assert entered.wait(timeout=10)
+        with ServingLayer(cfg) as sl:
+            sl.app.route("GET", "/test-block", nonblocking=True)(
+                lambda a, req: time.sleep(0.3) or {"blocked_s": 0.3}
+            )
+            conn = http.client.HTTPConnection("127.0.0.1", sl.port, timeout=10)
+            conn.request("GET", "/healthz")  # the loops are up and beating
+            assert conn.getresponse().read()
+            time.sleep(0.5)  # start-up's own stalls (imports, the first compile) are over
+            for _ in range(5):  # a quiet second (a loaded test machine may stall for real: look again)
+                counted = get_registry().counter("oryx_stalls_total").value()
+                seen = len(stalls())
+                time.sleep(1.0)
+                if get_registry().counter("oryx_stalls_total").value() == counted:
+                    break
+            assert get_registry().counter("oryx_stalls_total").value() == counted
+            assert len(stalls()) == seen
+            with caplog.at_level(logging.WARNING, logger="oryx_tpu.common.tracing"):
+                conn.request("GET", "/test-block")
+                resp = conn.getresponse()
+                assert resp.read() and resp.status == 200
+                time.sleep(0.3)  # the late beat fires right after the handler returns
+            conn.request("GET", "/debug/threads")
+            table = json.loads(conn.getresponse().read())["threads"]
+            conn.close()
+            new = stalls()[seen:]
+            assert get_registry().counter("oryx_stalls_total").value() == counted + len(new)
+            ours = [e for e in new if 0.25 <= e["late_s"] <= 0.6]
+            assert len(ours) == 1, new  # (a loaded test machine may add a real one beside it)
+            stall = ours[0]
+            assert 0.0 <= stall["process_cpu_s"] <= 0.7
+            assert isinstance(stall["collections"], list)
+            waiting = [r for r in stall["threads"] if r["thread"] == "oryx-test-wait"]
+            assert waiting and waiting[0]["region"] == "test.waiting" and waiting[0]["age_s"] > 0.3
+            lines = [r.getMessage() for r in caplog.records if r.getMessage().startswith("stall: ")]
+            assert len(lines) == len(new) and all("test.waiting" in line for line in lines)
+            # the operator's table is the same one, live
+            assert any(r["thread"] == "oryx-test-wait" and r["region"] == "test.waiting" for r in table)
+            assert any(r["thread"].startswith("oryx-loop-") for r in table)
+    finally:
+        release.set()
+        t.join(timeout=10)

@@ -105,6 +105,17 @@ REQUIRED_PERFATTR_FAMILIES = (
     "oryx_moe_routed_total",
     "oryx_moe_experts_touched_total",
     "oryx_moe_expert_tokens_max_total",
+    # what every thread on the serving path is doing (ISSUE 39): the
+    # regions' always-on counters (common/tracing.py), the event loops'
+    # heartbeat and the stall witness; the benchmark's launch_*_ms,
+    # *_offcpu_share, *_idle_share, loop_lag_ms and stall_share readers
+    # key on them
+    "oryx_region_seconds_total",
+    "oryx_region_cpu_seconds_total",
+    "oryx_regions_total",
+    "oryx_http_loop_lag_seconds",
+    "oryx_stall_seconds_total",
+    "oryx_stalls_total",
 )
 
 
