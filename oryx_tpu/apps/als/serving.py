@@ -1000,8 +1000,10 @@ class ALSServingModel(ServingModel):
         # host_mat doubles as the wedged-device fallback: the batcher
         # scores on the host if the accelerator transport hangs.
         # valid_rows: the device matrix is capacity-padded past n (zero
-        # rows scatter-reserved for speed-layer growth); the batcher's
-        # FLOP accounting must not count the padding as scored work.
+        # rows scatter-reserved for speed-layer growth); the batcher
+        # hands the fused kernel the count, which then neither streams
+        # nor scores the padding, and its FLOP accounting does not count
+        # the padding as scored work on any path.
         fut = TopKBatcher.shared().submit_nowait(
             user_vector, k, y, host_mat=host_mat, cosine=cosine,
             host_norms=host_norms, recall=self.effective_recall(),
@@ -1040,8 +1042,15 @@ class ALSServingModel(ServingModel):
             vals, idx = result
             vals, idx = np.asarray(vals), np.asarray(idx)
             if int(y.shape[0]) > n:
-                # capacity-padding rows score 0.0 (zero vectors) and enter
-                # the candidate set when fewer than k real scores beat 0.
+                # the view is stored with room to grow, and this request
+                # scores the rows its own id list names. The fused kernel
+                # is handed the count and selects nothing at or past it
+                # (ops/pallas_topk.py n_valid), so there this filter
+                # finds nothing to drop unless the dispatch's group held
+                # a request with a longer list. Every OTHER path (XLA,
+                # approximate, chunked, sharded) scores the whole
+                # capacity: its zero rows score 0.0 and enter the
+                # candidate set when fewer than k real scores beat 0.
                 # Dropping them keeps an EXACT prefix: every real row a
                 # pad displaced scored <= the pad's 0.0, so the kept rows
                 # are the true top-|kept| — the host rescore is needed

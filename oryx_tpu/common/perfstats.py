@@ -79,9 +79,11 @@ class DispatchRecord:
     dispatch's row blocks beside those of them the kernel did not walk
     (they lie past the real rows), the (8, 128) sublane tiles its folds
     sorted (``fold_tiles``: 16 a fold of a whole 128-row block, fewer
-    where the block holds few real rows) and the fired chunks it placed
+    where the block holds few real rows), the fired chunks it placed
     without a sort (``chunks_inserted``: no row had more than one
-    entrant); every other path leaves those six None."""
+    entrant) and the chunks of the view's capacity its walked row blocks
+    left alone (``item_chunks_skipped``: the rows stored behind the last
+    of ``valid_rows``); every other path leaves those seven None."""
 
     __slots__ = (
         "kind", "t_start", "wall_s", "flops", "bytes_moved",
@@ -89,6 +91,7 @@ class DispatchRecord:
         "occupancy", "trace_id", "score_mode", "seq",
         "dispatch", "k_bucket", "chunks_folded", "chunks_total",
         "row_blocks", "row_blocks_skipped", "fold_tiles", "chunks_inserted",
+        "item_chunks_skipped",
     )
 
     def __init__(
@@ -112,6 +115,7 @@ class DispatchRecord:
         row_blocks_skipped: int | None = None,
         fold_tiles: int | None = None,
         chunks_inserted: int | None = None,
+        item_chunks_skipped: int | None = None,
     ):
         self.kind = kind
         self.t_start = t_start
@@ -145,6 +149,7 @@ class DispatchRecord:
         self.row_blocks_skipped = row_blocks_skipped
         self.fold_tiles = fold_tiles
         self.chunks_inserted = chunks_inserted
+        self.item_chunks_skipped = item_chunks_skipped
 
     def chrome_event(self, pid: int) -> dict:
         """This record as a Chrome trace-event `X` slice (Perfetto)."""
@@ -178,6 +183,7 @@ class DispatchRecord:
                 row_blocks_skipped=self.row_blocks_skipped,
                 fold_tiles=self.fold_tiles,
                 chunks_inserted=self.chunks_inserted,
+                item_chunks_skipped=self.item_chunks_skipped,
             )
         return event
 
@@ -296,6 +302,7 @@ class PerfStats:
         row_blocks_skipped: int | None = None,
         fold_tiles: int | None = None,
         chunks_inserted: int | None = None,
+        item_chunks_skipped: int | None = None,
     ) -> DispatchRecord:
         rec = DispatchRecord(
             kind,
@@ -304,6 +311,7 @@ class PerfStats:
             capacity_rows, trace_id, score_mode,
             dispatch, k_bucket, chunks_folded, chunks_total,
             row_blocks, row_blocks_skipped, fold_tiles, chunks_inserted,
+            item_chunks_skipped,
         )
         rec.seq = next(self._seq)
         buf = self._buf

@@ -1814,16 +1814,22 @@ def topk_path(y, k: int, recall: float = 1.0) -> str:
     return "pallas" if fused else "xla"
 
 
-def stage_topk_operands(xs, y, *, k: int, recall: float = 1.0, rows=None):
+def stage_topk_operands(
+    xs, y, *, k: int, recall: float = 1.0, rows=None, n_valid=None
+):
     """The eager part of topk_dot_batch: (xs, rows) as its jitted call
     takes them. The queries go to the device, zero-padded to a lane-padded
     view's width and, where the path scores in the matrix's dtype (the
     bf16 serving view; accumulation is f32 either way), cast to it; the
-    fused kernel's row count becomes the int32 scalar it prefetches.
-    topk_dot_batch does the queries' part to whatever it is handed (the
-    kernel's wrapper uploads the count) and finds nothing left to do on
-    operands that are staged already, so a caller that times the upload
-    apart from the call (serving/batcher.py) stages first."""
+    fused kernel's two counts, the real query rows and the view's valid
+    item rows, become the ONE int32[2] array it prefetches (one upload
+    for both; returned in `rows`' place, and topk_dot_batch takes it
+    there). Every other path ignores both counts and gets `rows` back as
+    it came. topk_dot_batch does the queries' part to whatever it is
+    handed (the kernel's wrapper uploads the counts) and finds nothing
+    left to do on operands that are staged already, so a caller that
+    times the upload apart from the call (serving/batcher.py) stages
+    first."""
     if not isinstance(xs, jax.Array):
         xs = jnp.asarray(xs)
     if xs.shape[1] < y.shape[1]:
@@ -1831,13 +1837,18 @@ def stage_topk_operands(xs, y, *, k: int, recall: float = 1.0, rows=None):
     path = topk_path(y, k, recall)
     if path in ("pallas", "approx", "xla") and xs.dtype != y.dtype:
         xs = jnp.asarray(xs, dtype=y.dtype)
-    if path in ("pallas", "pallas-int8") and rows is not None:
-        rows = jnp.asarray(rows, dtype=jnp.int32)
+    if path in ("pallas", "pallas-int8") and (
+        rows is not None or n_valid is not None
+    ):
+        from oryx_tpu.ops.pallas_topk import stage_counts
+
+        rows = stage_counts(rows, n_valid, xs.shape[0], y.shape[0])
     return xs, rows
 
 
 def topk_dot_batch(
-    xs, y, *, k: int, recall: float = 1.0, counted: bool = False, rows=None
+    xs, y, *, k: int, recall: float = 1.0, counted: bool = False, rows=None,
+    n_valid=None,
 ):
     """Batched top-k scoring; topk_path names the kernel selection.
     recall < 1 takes the approximate partial-reduce; exact requests take
@@ -1867,12 +1878,23 @@ def topk_dot_batch(
     past `rows` are the caller's padding on every path, and only the
     rows before them are the same on all.
 
+    n_valid: how many leading rows of y are items, None for all: a
+    serving view is stored with room to grow, and the rows behind its
+    last item belong to none. The fused kernel neither streams nor
+    scores the item blocks that lie past them and never selects a row at
+    or past them (ops/pallas_topk.py), so its candidates all lie below
+    `n_valid`. Every other path ignores it, as it ignores `rows`, and
+    scores the view's whole capacity: there a caller still drops the
+    indices at or past its own count (apps/als/serving.py _post_pairs).
+    A shard or a chunk is given none: its valid range is its own. `rows`
+    may be both counts as stage_topk_operands staged them.
+
     A resident serving view is lane-padded in features (ops/transfer.py
     kernel_view_put); queries at the published width are zero-padded to
     it (stage_topk_operands), once for every path — zeros change no dot
     product."""
     # `rows` goes on as it was given: each shard and chunk re-enters here
-    # with the same count, and the fused kernel's wrapper uploads it
+    # with the same count, and the fused kernel's wrapper uploads the counts
     xs, _ = stage_topk_operands(xs, y, k=k, recall=recall)
     path = topk_path(y, k, recall)
     if path in ("pallas", "pallas-int8"):
@@ -1880,9 +1902,12 @@ def topk_dot_batch(
 
         if path == "pallas-int8":
             return topk_dot_batch_pallas(
-                xs, y.q, scales=y.scale, k=k, counted=counted, rows=rows
+                xs, y.q, scales=y.scale, k=k, counted=counted, rows=rows,
+                n_valid=n_valid,
             )
-        return topk_dot_batch_pallas(xs, y, k=k, counted=counted, rows=rows)
+        return topk_dot_batch_pallas(
+            xs, y, k=k, counted=counted, rows=rows, n_valid=n_valid
+        )
     if path == "sharded":
         from oryx_tpu.ops.shard_topk import topk_dot_batch_sharded
 

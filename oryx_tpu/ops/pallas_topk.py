@@ -241,6 +241,44 @@ list as a fold does (a fourth reduce, in the chain: within 0.25 ms at
 1-17 rows, 1-7 % slower at 33-512). What is left of a one-row dispatch:
 the pass, 4.7 ms, 130 folds (0.5 ms) and 645 placements (0.4 ms).
 
+Valid item rows (PR 40). A serving view is stored with room to grow
+(ops/transfer.py row_capacity: an eighth of headroom, rounded up a bucket
+ladder), so the speed layer appends without a new device matrix or a new
+compiled shape: 6,291,456 rows for 5,000,000 items. Until PR 40 the
+kernel's item count was the operand's static row count, so a fifth of
+every pass streamed, multiplied and gated rows of zeros that belong to
+no item (they scored 0.0 and the serving model dropped them on the
+host). The count of valid item rows now rides beside `rows` in ONE
+scalar-prefetch operand (int32[2], `stage_counts`: one upload for both,
+traced, so every catalog size that fits a view shares its compiled
+program). With live_blocks = ceil(n_valid / block_i): a grid step at or
+past live_blocks starts no DMA, waits on none, runs no dot and no gate
+and counts no chunk; block i + 1 is prefetched only where i + 1 <
+live_blocks, so every copy started is waited on; the tail mask compares
+against n_valid, so the rows between it and the end of the last live
+block are never selected and never move a threshold; the output block
+is revisited over the item axis, so what the last live step wrote is
+what comes back; n_valid = 0 is a dead row block. On the int8 path the
+scales' block index stays on the last live block behind it, so their
+pipeline fetches nothing new. A view filled to its capacity runs the
+walk it ran before. Same view, 512-row dispatch, one v5e (PR 40; parent
+-> n_valid 5,000,000 | n_valid 6,291,456; calls issued back to back;
+values and indices of the real rows bit for bit the parent's in every
+case; chunks walked a row block 49,152 -> 39,104):
+
+    real rows     bf16 k 128                  bf16 k 32
+    1              5.615 ->   4.776 |   5.643    4.784 -> 3.933 | 4.802
+    5              7.716 ->   6.877 |   7.736    5.519 -> 4.671 | 5.540
+    129           34.107 ->  32.401 |  34.127
+    512          114.312 -> 110.882 | 114.336
+    5, int8 k 128  6.722 ->   6.124 |   6.719
+    1, int8 k 32   3.637 ->   3.029 |   3.651
+
+0.84-0.85 ms a pass in bf16 (20.4 % of a 4.15 ms pass), 0.60 in int8; the
+guards cost a full view 0.02-0.03 ms. What is left of a one-row dispatch
+at k 32: 3.93 ms against 3.13 ms of HBM time for the 2.56 GB the items
+take at 256 lanes.
+
 The kernel also scores QUANTIZED item matrices (int8 rows + per-row f32
 scales, ops/transfer.py QuantizedMatrix): the int8 stream halves the
 bf16 HBM traffic that dominates the scan, queries are per-row
@@ -252,13 +290,14 @@ kernel. The serving tier re-ranks surviving candidates in f32 either
 way (apps/als/serving.py _rerank_exact).
 
 Layout: grid (B-blocks, I-blocks) with the item dimension innermost, so
-each row block is one pass over the item matrix; the real-row count
-rides ahead of the grid as a scalar-prefetch operand. A live block's
-running top-k scratch is (re)initialized at item-block 0 and written to
-the output block on every step (the final step's write wins), its rows
-past the real ones as filler; a dead block writes its filler at
-item-block 0 and nothing after. k is padded
-to the 128-lane tile internally and sliced by the wrapper.
+each row block is one pass over the item matrix's valid rows; the counts
+of real query rows and of valid item rows ride ahead of the grid as one
+scalar-prefetch operand. A live block's running top-k scratch is
+(re)initialized at item-block 0 and written to the output block on every
+live step (the last live step's write wins), its rows past the real ones
+as filler; a dead block writes its filler at item-block 0 and nothing
+after. k is padded to the 128-lane tile internally and sliced by the
+wrapper.
 """
 
 from __future__ import annotations
@@ -267,6 +306,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -370,7 +410,7 @@ def _split_top(av, ai, bv, bi):
 # ---------------------------------------------------------------------------
 
 def _topk_kernel(
-    rows_ref, *refs, block_i, n_items, k, quantized,
+    counts_in, *refs, block_i, k, quantized,
 ):
     if quantized:
         (xs_ref, y_hbm, scale_ref, vals_ref, idx_ref, counts_ref,
@@ -384,12 +424,17 @@ def _topk_kernel(
     n_tiles = block_b // _SUBLANE
     # the widths, in sublane tiles, a fold comes in: the whole block last
     fold_widths = [w for w in _FOLD_TILES if w < n_tiles] + [n_tiles]
-    # rows_ref[0] leading rows of the query block are real (scalar
+    # counts_in[0] leading rows of the query block are real (scalar
     # prefetch): this row block holds live_rows of them, in its first
     # live_tiles (8, 128) sublane tiles; past them is the caller's padding
-    live_rows = jnp.clip(rows_ref[0] - pl.program_id(0) * block_b, 0, block_b)
+    live_rows = jnp.clip(counts_in[0] - pl.program_id(0) * block_b, 0, block_b)
     live_tiles = (live_rows + (_SUBLANE - 1)) // _SUBLANE
-    live = live_rows > 0
+    # counts_in[1] leading rows of the item matrix are items (never more
+    # than it holds): they lie in its first live_blocks item blocks, and
+    # the blocks behind them are the view's capacity, which no step reads
+    n_valid = jnp.clip(counts_in[1], 0, pl.num_programs(1) * block_i)
+    live_blocks = (n_valid + (block_i - 1)) // block_i
+    live = (live_rows > 0) & (live_blocks > 0)
 
     def is_real(n):
         """[n, 128] mask of the block's first n rows: which are real."""
@@ -403,9 +448,8 @@ def _topk_kernel(
         idx_ref[:] = jnp.zeros_like(idx_ref)
         counts_ref[:] = jnp.zeros_like(counts_ref)
 
-    @pl.when(live)
+    @pl.when(live & (i < live_blocks))
     def _live():
-        ni = pl.num_programs(1)
         slot = jax.lax.rem(i, 2)
 
         def dma(s, chunk):
@@ -425,8 +469,10 @@ def _topk_kernel(
             counts[2] = 0
             counts[3] = 0
 
-        # prefetch block i+1 while block i computes: the double buffer
-        @pl.when(i + 1 < ni)
+        # prefetch block i+1 while block i computes: the double buffer. Only
+        # a block some step will wait on: a copy started and never waited
+        # on would leave its semaphore signalled for the next row block
+        @pl.when(i + 1 < live_blocks)
         def _prefetch():
             dma(jax.lax.rem(i + 1, 2), i + 1).start()
 
@@ -565,7 +611,8 @@ def _topk_kernel(
                     xs, y_g, contract, preferred_element_type=jnp.float32
                 )
             col0 = i * block_i + off
-            scores = jnp.where(col0 + lane_g < n_items, scores, -jnp.inf)  # tail padding
+            # the last live block's rows past the items are never selected
+            scores = jnp.where(col0 + lane_g < n_valid, scores, -jnp.inf)
             # the gate is mostly a reduce to a scalar and a branch: one for
             # the group, on the elementwise max of its chunks' scores, and
             # one per chunk only inside a group that fired
@@ -720,14 +767,25 @@ def view_shape(n_rows: int, n_feat: int, dtype) -> tuple[int, int]:
     return -(-n_rows // block_i) * block_i, feat_pad
 
 
-def dispatch_grid(n_queries: int, y_shape, y_dtype) -> tuple[int, int]:
-    """(row blocks, 128-item chunks each of them walks) of one dispatch of
-    `n_queries` query rows at the tuned blocks over an item matrix of
-    `y_shape` and `y_dtype`: what the kernel's walked count is a multiple
-    of, so a caller can read the row blocks it walked out of it."""
+def dispatch_grid(
+    n_queries: int, y_shape, y_dtype, n_valid: int | None = None
+) -> tuple[int, int, int]:
+    """(row blocks, 128-item chunks each LIVE one of them walks, chunks of
+    the view behind those) of one dispatch of `n_queries` query rows at
+    the tuned blocks over an item matrix of `y_shape` and `y_dtype` whose
+    first `n_valid` rows are items (None: all of them). A row block walks
+    the item blocks that hold a valid row and no other, so the second is
+    what the kernel's walked count is a multiple of (a caller can read the
+    row blocks it walked out of it) and the third is the capacity each of
+    them left alone."""
     feat_pad = lane_pad(y_shape[1])
-    block_b = row_block(n_queries, feat_pad, jnp.dtype(y_dtype).itemsize)
-    return -(-n_queries // block_b), view_shape(*y_shape, y_dtype)[0] // _LANE
+    itemsize = jnp.dtype(y_dtype).itemsize
+    block_b = row_block(n_queries, feat_pad, itemsize)
+    block_i = item_block(y_shape[0], feat_pad, itemsize)
+    view_rows = -(-y_shape[0] // block_i) * block_i  # view_shape's rows
+    valid = view_rows if n_valid is None else max(0, min(n_valid, view_rows))
+    walked = -(-valid // block_i) * (block_i // _LANE)
+    return -(-n_queries // block_b), walked, view_rows // _LANE - walked
 
 
 # ---------------------------------------------------------------------------
@@ -747,23 +805,44 @@ def quantize_queries(xs):
     return q, sx
 
 
+def stage_counts(rows, n_valid, n_queries: int, n_items: int):
+    """The kernel's scalar-prefetch operand: int32[2], (real query rows,
+    valid item rows), None meaning all `n_queries` and all `n_items`. Two
+    host numbers make ONE upload (the second never past `n_items`, where
+    a caller's unaligned matrix is padded); a count that is already on
+    the device (or traced) is stacked there, and the kernel reads no
+    further than its operand whatever it says. An int32[2] array given as
+    `rows` is such an operand staged earlier and is passed through."""
+    if getattr(rows, "shape", None) == (2,):
+        return rows
+    rows = n_queries if rows is None else rows
+    n_valid = n_items if n_valid is None else n_valid
+    if isinstance(rows, jax.Array) or isinstance(n_valid, jax.Array):
+        return jnp.stack([
+            jnp.asarray(rows, dtype=jnp.int32).reshape(()),
+            jnp.asarray(n_valid, dtype=jnp.int32).reshape(()),
+        ])
+    return jnp.asarray(np.array([rows, min(n_valid, n_items)], dtype=np.int32))
+
+
 @partial(
     jax.jit,
-    static_argnames=(
-        "k", "n_items", "block_b", "block_i", "quantized", "interpret"
-    ),
+    static_argnames=("k", "block_b", "block_i", "quantized", "interpret"),
 )
 def _topk_pallas_jit(
-    xs, y, scales, rows, *, k, n_items, block_b, block_i, quantized, interpret
+    xs, y, scales, counts, *, k, block_b, block_i, quantized, interpret
 ):
     """The kernel over an item matrix ALREADY in the shape it DMAs
-    (view_shape): nothing the size of the catalog is copied here. Rows
-    at or past `n_items` are the caller's padding and never selected.
-    `rows` (int32 scalar, traced: one program whatever it holds) is how
-    many leading rows of `xs` are real; a row block past them is not
-    walked, and a fold sorts the live sublane tiles of its block alone.
-    Third result: int32[4], (chunks fired, chunks walked, tiles the folds
-    sorted, chunks placed without a sort)."""
+    (view_shape): nothing the size of the catalog is copied here.
+    `counts` is int32[2], traced, so one program serves whatever it
+    holds (`stage_counts`): how many leading rows of `xs` are real, and
+    how many leading rows of `y` are items. A row block past the real
+    rows is not walked, and a fold sorts the live sublane tiles of its
+    block alone; an item block past the valid rows is neither streamed
+    nor scored, and the rows at or past the count inside the last live
+    block are never selected. Third result: int32[4], (chunks fired,
+    chunks walked, tiles the folds sorted, chunks placed without a
+    sort)."""
     n_b = xs.shape[0]
     feat_pad = y.shape[1]
     if feat_pad % _LANE or y.shape[0] % block_i or xs.shape[1] > feat_pad:
@@ -784,12 +863,11 @@ def _topk_pallas_jit(
 
     n_gate = min(_GATE_CHUNKS, block_i // _LANE)
     kernel = partial(
-        _topk_kernel, block_i=block_i, n_items=n_items, k=k,
-        quantized=quantized,
+        _topk_kernel, block_i=block_i, k=k, quantized=quantized,
     )
-    # index maps take the prefetched scalar after the grid indices
+    # index maps take the prefetched counts after the grid indices
     in_specs = [
-        pl.BlockSpec((block_b, feat_pad), lambda b, i, rows: (b, 0)),
+        pl.BlockSpec((block_b, feat_pad), lambda b, i, counts: (b, 0)),
         # the item matrix stays in HBM: the kernel streams its own
         # double-buffered DMA blocks out of it
         pl.BlockSpec(memory_space=pl.ANY),
@@ -797,25 +875,29 @@ def _topk_pallas_jit(
     operands = [xs_p, y]
     if quantized:
         # one row of scales per 128-item chunk, so the kernel picks a
-        # chunk's scales with a sublane index
-        in_specs.append(
-            pl.BlockSpec((block_i // _LANE, _LANE), lambda b, i, rows: (i, 0))
-        )
+        # chunk's scales with a sublane index. Behind the last live item
+        # block the index stays on it: the pipeline fetches a block only
+        # when its index changes, so the capacity's scales are not read
+        def live_scales(b, i, counts):
+            last = (jnp.clip(counts[1], 1, ni * block_i) - 1) // block_i
+            return jnp.minimum(i, last), 0
+
+        in_specs.append(pl.BlockSpec((block_i // _LANE, _LANE), live_scales))
         operands.append(
             jnp.asarray(scales, dtype=jnp.float32).reshape(-1, _LANE)
         )
-    vals, idx, counts = pl.pallas_call(
+    vals, idx, tally = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nb, ni),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((block_b, _LANE), lambda b, i, rows: (b, 0)),
-                pl.BlockSpec((block_b, _LANE), lambda b, i, rows: (b, 0)),
+                pl.BlockSpec((block_b, _LANE), lambda b, i, counts: (b, 0)),
+                pl.BlockSpec((block_b, _LANE), lambda b, i, counts: (b, 0)),
                 # a row block's (chunks fired, chunks walked, tiles
                 # sorted, chunks inserted) in lanes 0-3 of an (8, 128) tile
-                pl.BlockSpec((8, _LANE), lambda b, i, rows: (b, 0)),
+                pl.BlockSpec((8, _LANE), lambda b, i, counts: (b, 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_b, _LANE), jnp.float32),
@@ -833,13 +915,13 @@ def _topk_pallas_jit(
             jax.ShapeDtypeStruct((nb * 8, _LANE), jnp.int32),
         ],
         interpret=interpret,
-    )(rows.reshape(1), *operands)
+    )(counts, *operands)
     vals, idx = vals[:n_b, :k], idx[:n_b, :k]
     if quantized:
         # scale the selected values back into score units (sx > 0, so
         # -inf padding slots stay -inf)
         vals = vals * sx[:n_b, None]
-    return vals, idx, jnp.sum(counts[::8, :4], axis=0)
+    return vals, idx, jnp.sum(tally[::8, :4], axis=0)
 
 
 def topk_dot_batch_pallas(
@@ -853,6 +935,7 @@ def topk_dot_batch_pallas(
     interpret: bool = False,
     counted: bool = False,
     rows=None,
+    n_valid=None,
 ):
     """Top-k of xs @ y.T per row without materializing the score matrix.
 
@@ -892,6 +975,20 @@ def topk_dot_batch_pallas(
     dispatch of a few requests in a 512-row block sorts 8 rows a fold,
     not 128.
 
+    n_valid: how many leading rows of y are items (an int or an int32
+    scalar array, traced like `rows`: every catalog size that fits one
+    view shares one compiled program); None means all of them. A serving
+    view is stored with room to grow (ops/transfer.py row_capacity), and
+    the rows behind its last item belong to none. The result is bit for
+    bit that of the kernel over y[:n_valid], whatever the rows behind
+    hold: an item block that lies wholly past the count starts no DMA,
+    runs no dot and no gate and counts no chunk, so a row block walks
+    ceil(n_valid / block_i) item blocks, and the rows of the last of
+    them at or past the count are masked out before the gate. n_valid = 0
+    returns the filler in every row. `rows` may also be the int32[2]
+    array (rows, n_valid) that ops.als.stage_topk_operands uploaded
+    earlier; n_valid is then left None.
+
     block_b/block_i default to the block rule (`tuned_blocks`): the
     largest pow2 item block whose double-buffered stream + sort
     temporaries fit the scoped-VMEM budget. A kernel that fails to
@@ -913,9 +1010,8 @@ def topk_dot_batch_pallas(
         if scales is not None:
             scales = _pad_to(jnp.asarray(scales, dtype=jnp.float32), y_rows, 0)
     vals, idx, chunks = _topk_pallas_jit(
-        xs, y, scales,
-        jnp.asarray(xs.shape[0] if rows is None else rows, dtype=jnp.int32),
-        k=k, n_items=n_items, block_b=block_b, block_i=block_i,
+        xs, y, scales, stage_counts(rows, n_valid, xs.shape[0], n_items),
+        k=k, block_b=block_b, block_i=block_i,
         quantized=scales is not None, interpret=interpret,
     )
     return (vals, idx, chunks) if counted else (vals, idx)

@@ -227,7 +227,8 @@ class _Pending:
         self.recall = recall
         # rows of y that hold real data: a capacity-padded serving view
         # (apps/als/serving.py) scatter-reserves rows past this for
-        # speed-layer growth, and FLOP accounting must not count them
+        # speed-layer growth; the fused kernel is told not to walk them
+        # and FLOP accounting must not count them
         self.valid_rows = valid_rows
         # which serving score mode produced this request (exact |
         # quantized | approx) — labels the dispatch's perfstats record so
@@ -397,6 +398,9 @@ class TopKBatcher:
         # did not walk because they lie past the dispatch's real rows
         self.row_blocks = 0  # guarded-by: _lock (writes)
         self.row_blocks_skipped = 0  # guarded-by: _lock (writes)
+        # 128-item chunks of the views' capacity (the rows stored behind a
+        # view's last item) that the walked row blocks did not walk
+        self.item_chunks_skipped = 0  # guarded-by: _lock (writes)
         # (8, 128) sublane tiles the kernel's folds sorted: 16 a fold of a
         # whole 128-row block, 1 where the block holds one to eight requests
         self.fold_tiles = 0  # guarded-by: _lock (writes)
@@ -437,7 +441,7 @@ class TopKBatcher:
              lambda: float(self.chunks_folded)),
             ("oryx_topk_chunks",
              "128-item chunks the fused top-k kernel walked (row blocks "
-             "walked x item chunks of its dispatches)",
+             "walked x the chunks of the view's valid item blocks)",
              lambda: float(self.chunks_total)),
             ("oryx_topk_row_blocks",
              "row blocks of the fused top-k kernel's dispatches (padded "
@@ -447,6 +451,11 @@ class TopKBatcher:
              "row blocks the fused top-k kernel did not walk: those past "
              "the real rows of their dispatch",
              lambda: float(self.row_blocks_skipped)),
+            ("oryx_topk_item_chunks_skipped",
+             "128-item chunks of a view's capacity the fused top-k kernel "
+             "did not walk: those behind the view's last valid item block, "
+             "once for every row block it walked",
+             lambda: float(self.item_chunks_skipped)),
             ("oryx_topk_fold_tiles",
              "(8, 128) sublane tiles the fused top-k kernel's folds sorted: "
              "over 16 x (oryx_topk_chunks_folded - oryx_topk_chunks_inserted), "
@@ -560,8 +569,11 @@ class TopKBatcher:
         host-side scoring when the device transport is wedged; host_norms
         caches its row norms for cosine fallbacks. recall < 1 selects the
         approximate device kernel (host fallback stays exact). valid_rows
-        marks the real-data prefix of a capacity-padded matrix (FLOP
-        accounting only; the caller filters padding indices from results).
+        marks the real-data prefix of a capacity-padded matrix: the fused
+        kernel scores no row past the largest count of a dispatch's group,
+        and the FLOP accounting counts none on any path (off the fused
+        kernel, and beside a request with a longer prefix, the caller
+        still filters padding indices from results).
         score_mode labels the dispatch's perfstats record (exact |
         quantized | approx) for per-mode observability.
         """
@@ -809,11 +821,17 @@ class TopKBatcher:
                     padded=padded, k_bucket=kb,
                 ):
                     with tr.region("batcher.launch.form"):
-                        # a capacity-padded serving view scores zero rows
-                        # past valid_rows — they're HBM-cheap but not useful
-                        # FLOPs, so the MFU figure counts only the real-data
-                        # prefix
-                        n_rows = group[0].valid_rows or y.shape[0]
+                        # a serving view is stored with room to grow: the
+                        # fused kernel is handed the count of its items and
+                        # neither streams nor scores the rows behind them,
+                        # and the MFU figure counts the real-data prefix on
+                        # every path. Requests of one group share y; one
+                        # submitted against a shorter id list is scored
+                        # over the longest and drops what its own list does
+                        # not name (apps/als/serving.py _post_pairs)
+                        n_rows = max(
+                            p.valid_rows or y.shape[0] for p in group
+                        )
                         # ... and at the published feature count (the
                         # queries'), not the view's lane-padded width
                         features = int(group[0].vec.shape[-1])
@@ -889,16 +907,20 @@ class TopKBatcher:
                             # whatever topk_dot_batch would do to its
                             # operands before its jitted call, done here so
                             # that the two are timed apart: the queries'
-                            # upload and cast, the row count's upload
+                            # upload and cast, and the two counts' upload
+                            # as one array
                             xd, rows_d = als.stage_topk_operands(
-                                xs, y, k=kb, recall=recall, rows=b
+                                xs, y, k=kb, recall=recall, rows=b,
+                                n_valid=int(n_rows),
                             )
                         with tr.region("batcher.issue.call"):
                             # chunks: the fused kernel's counts (chunks
                             # fired, walked, tiles sorted, chunks inserted),
                             # None on every other path.
                             # rows: the kernel walks no row block past the
-                            # group's b real rows
+                            # group's b real rows and no item block past
+                            # the view's n_rows items (both counts are in
+                            # rows_d on the fused path)
                             vals, idx, chunks = als.topk_dot_batch(
                                 xd, y, k=kb, recall=recall,
                                 counted=True, rows=rows_d,
@@ -1019,18 +1041,26 @@ class TopKBatcher:
                     vals = np.asarray(vals_dev)
                 with tr.region("batcher.fetch.idx"):
                     idx = np.asarray(idx_dev)
-                folded = total = tiles = inserted = blocks = skipped = None
+                folded = total = tiles = inserted = None
+                blocks = skipped = items_skipped = None
                 with tr.region("batcher.fetch.chunks"):
                     counts = None if chunks_dev is None else np.asarray(chunks_dev)
                 if counts is not None:
                     folded, total, tiles, inserted = (int(c) for c in counts)
-                    # the kernel walks whole row blocks: its own count of
-                    # chunks walked says how many
+                    # the kernel walks whole row blocks over the view's
+                    # valid item blocks: its own count of chunks walked
+                    # says how many
                     from oryx_tpu.ops.pallas_topk import dispatch_grid
 
                     y = group[0].y
-                    blocks, per_block = dispatch_grid(padded, y.shape, y.dtype)
-                    skipped = blocks - total // per_block
+                    blocks, per_block, behind = dispatch_grid(
+                        padded, y.shape, y.dtype, n_valid=valid
+                    )
+                    walked = total // per_block if per_block else 0
+                    skipped = blocks - walked
+                    # the view's capacity behind its items, which each
+                    # walked row block left alone
+                    items_skipped = walked * behind
                 t_fetch = time.monotonic()
             with tr.region("batcher.distribute", dispatch=n_disp):
                 # results are on the host: the dispatch's device work +
@@ -1046,6 +1076,7 @@ class TopKBatcher:
                     chunks_folded=folded, chunks_total=total,
                     row_blocks=blocks, row_blocks_skipped=skipped,
                     fold_tiles=tiles, chunks_inserted=inserted,
+                    item_chunks_skipped=items_skipped,
                 )
                 # the dispatch completed, so this shape's compile is done:
                 # drop its grace window and never grant it one again. Both
@@ -1085,6 +1116,7 @@ class TopKBatcher:
                         self.chunks_total += total
                         self.row_blocks += blocks
                         self.row_blocks_skipped += skipped
+                        self.item_chunks_skipped += items_skipped
                         self.fold_tiles += tiles
                         self.chunks_inserted += inserted
         except Exception as e:

@@ -192,6 +192,95 @@ def test_fused_dispatch_counts_the_row_blocks_the_kernel_skipped(
         assert list(p.future.result(timeout=5)[1]) == list(_direct(p.vec, 10, y)[1])
 
 
+@pytest.mark.parametrize(
+    "valid_rows, largest, live_blocks",
+    [
+        ((18_000, 20_000, 19_000), 20_000, 3),  # the group's largest count
+        ((8_192,), 8_192, 1),                   # an item block's edge
+        ((20_000, None), 32_768, 4),            # no count: the whole view
+    ],
+    ids=["largest-of-three", "block-edge", "none-is-the-view"],
+)
+def test_fused_dispatch_walks_the_views_valid_item_blocks_alone(
+    monkeypatch, valid_rows, largest, live_blocks
+):
+    """A view stored with room to grow (32,768 rows in four item blocks of
+    8,192, the rows past its items scoring far ABOVE them): the group is
+    dispatched with its largest `valid_rows`, the count reaches the kernel
+    beside `rows` as ONE staged int32[2] array, the kernel walks the valid
+    item blocks alone, and the record, the counters and the gauges say so
+    (ISSUE 40)."""
+    from oryx_tpu.common.metrics import get_registry
+    from oryx_tpu.common.perfstats import get_perfstats
+    from oryx_tpu.ops import als, pallas_topk
+
+    capacity, feats = 32_768, 8
+    rng = np.random.default_rng(40)
+    host = rng.integers(-9, 10, size=(capacity, 128)).astype(np.float32)
+    host[:, 0], host[:, feats:] = 0.0, 0.0  # lane-padded, as a view is stored
+    host[largest:, 0] = 256.0  # behind the items: 2,048 a row against 7 x 81
+    view = jnp.asarray(host, dtype=jnp.bfloat16)
+    assert pallas_topk.view_shape(capacity, feats, view.dtype) == view.shape
+
+    staged, calls = [], []
+    real_stage = pallas_topk.stage_counts
+
+    def stage_counts(rows, n_valid, n_queries, n_items):
+        staged.append((rows, n_valid, n_queries, n_items))
+        return real_stage(rows, n_valid, n_queries, n_items)
+
+    def fused(xs, y, *, k, recall=1.0, counted=False, rows=None, **kw):
+        calls.append((rows, kw))
+        return pallas_topk.topk_dot_batch_pallas(
+            xs, y, k=k, interpret=True, counted=counted, rows=rows
+        )
+
+    monkeypatch.setattr(als, "_on_tpu", lambda a: True)  # topk_path: "pallas"
+    monkeypatch.setattr(pallas_topk, "stage_counts", stage_counts)
+    monkeypatch.setattr(als, "topk_dot_batch", fused)
+    b = TopKBatcher()
+    b.register_gauges()
+    b._peak_flops = None  # _note_device has run: the platform below stays
+    b._on_accel = True
+    reqs = []
+    for n in valid_rows:
+        vec = rng.integers(-9, 10, size=feats).astype(np.float32)
+        vec[0] = 8.0
+        reqs.append(_Pending(vec, 10, view, Future(), valid_rows=n))
+    t_mark = time.monotonic()
+    for item in b._launch(reqs):
+        b._resolve(item)
+    # one upload of both counts: staged once from two host numbers, the call
+    # is handed that very array and no count beside it, and the kernel's
+    # wrapper passes it through
+    ((rows_d, extra),) = calls
+    assert rows_d.shape == (2,) and rows_d.dtype == jnp.int32 and not extra
+    assert [int(c) for c in rows_d] == [len(reqs), largest]
+    assert staged[0] == (len(reqs), largest, 512, capacity)
+    assert [(s[0] is rows_d, s[1]) for s in staged[1:]] == [(True, None)]
+    (rec,) = [r for r in get_perfstats().records_since(t_mark) if r.kind == "serving"]
+    assert (rec.valid_rows, rec.capacity_rows) == (largest, capacity)
+    # one of four row blocks walked, over 64 chunks a live item block; the
+    # skipped row blocks are read against what a row block REALLY walks
+    assert rec.chunks_total == live_blocks * 64
+    assert (rec.row_blocks, rec.row_blocks_skipped) == (4, 3)
+    assert rec.item_chunks_skipped == (4 - live_blocks) * 64
+    assert rec.chrome_event(1)["args"]["item_chunks_skipped"] == rec.item_chunks_skipped
+    assert (b.row_blocks_skipped, b.item_chunks_skipped) == (3, rec.item_chunks_skipped)
+    gauges = dict(
+        line.split() for line in get_registry().render_prometheus().splitlines()
+        if line.startswith(("oryx_topk_item_chunks_skipped", "oryx_topk_chunks "))
+    )
+    assert float(gauges["oryx_topk_item_chunks_skipped"]) == float(rec.item_chunks_skipped)
+    assert float(gauges["oryx_topk_chunks"]) == float(rec.chunks_total)
+    # every request: the exact top-10 of the items the dispatch counted, none
+    # of the rows behind them
+    for p in reqs:
+        vals, idx = p.future.result(timeout=5)
+        d_vals, d_idx = _direct(p.vec, 10, jnp.asarray(host[:largest, :feats]))
+        assert list(idx) == list(d_idx) and list(vals) == list(d_vals)
+
+
 def test_a_dispatch_off_the_fused_path_counts_no_row_blocks(y):
     from oryx_tpu.common.perfstats import get_perfstats
 
@@ -202,7 +291,10 @@ def test_a_dispatch_off_the_fused_path_counts_no_row_blocks(y):
     (rec,) = [r for r in get_perfstats().records_since(t_mark) if r.kind == "serving"]
     assert rec.row_blocks is None and rec.row_blocks_skipped is None
     assert rec.fold_tiles is None and rec.chunks_inserted is None
-    assert not {"row_blocks", "fold_tiles", "chunks_inserted"} & set(rec.chrome_event(1)["args"])
+    assert rec.item_chunks_skipped is None and b.item_chunks_skipped == 0
+    assert not {
+        "row_blocks", "fold_tiles", "chunks_inserted", "item_chunks_skipped"
+    } & set(rec.chrome_event(1)["args"])
     assert (b.row_blocks, b.row_blocks_skipped, b.fold_tiles, b.chunks_inserted) == (0, 0, 0, 0)
 
 

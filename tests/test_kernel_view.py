@@ -155,10 +155,11 @@ def test_jitted_wrapper_pads_no_catalog(quantized):
     xs = jax.ShapeDtypeStruct((16, 250), jnp.float32 if quantized else jnp.bfloat16)
     scales = jax.ShapeDtypeStruct((rows,), jnp.float32) if quantized else None
     fn = functools.partial(
-        pt._topk_pallas_jit, k=32, n_items=rows, block_b=8, block_i=block_i,
+        pt._topk_pallas_jit, k=32, block_b=8, block_i=block_i,
         quantized=quantized, interpret=True,
     )
-    real = jax.ShapeDtypeStruct((), jnp.int32)  # the count of real query rows
+    # the counts of real query rows and of valid item rows, one operand
+    real = jax.ShapeDtypeStruct((2,), jnp.int32)
     closed = jax.make_jaxpr(fn)(xs, y, scales, real)
     sized = _primitives_with_output_rows(closed.jaxpr, rows)
     sized += _primitives_with_output_rows(closed.jaxpr, rows // 128)  # the scales' tile
@@ -217,7 +218,8 @@ def _parent_path(xs, y, kb, scales=None):
     if scales is not None:
         scales = jnp.pad(jnp.asarray(scales, jnp.float32), (0, rows - n_items))
     vals, idx, _chunks = pt._topk_pallas_jit(
-        xs, y_p, scales, jnp.int32(xs.shape[0]), k=kb, n_items=n_items, block_b=block_b,
+        xs, y_p, scales, pt.stage_counts(None, None, xs.shape[0], n_items),
+        k=kb, block_b=block_b,
         block_i=block_i, quantized=scales is not None, interpret=True,
     )
     return np.asarray(vals), np.asarray(idx)

@@ -16,6 +16,23 @@ from oryx_tpu.ops.als import topk_dot_batch, topk_dot_batch_xla
 from oryx_tpu.ops.pallas_topk import topk_dot_batch_pallas
 
 
+@pytest.fixture(autouse=True)
+def _one_tests_programs_at_a_time(request):
+    """An interpreted kernel is a large CPU program, and every program a
+    process has compiled keeps its code mapped: this file's few hundred of
+    them ran one xdist worker into the kernel's limit of 65,530 mappings a
+    process ("LLVM compilation error: Cannot allocate memory", then an
+    abort). The cases of one parametrised test share their programs; the
+    next test's are others, so the caches are dropped between tests."""
+    yield
+    if request.node.originalname != _one_tests_programs_at_a_time.last:
+        _one_tests_programs_at_a_time.last = request.node.originalname
+        jax.clear_caches()
+
+
+_one_tests_programs_at_a_time.last = None
+
+
 def _check(b, n_items, feats, k, block_b=8, block_i=256, seed=0):
     rng = np.random.default_rng(seed)
     xs = jnp.asarray(rng.normal(size=(b, feats)), dtype=jnp.float32)
@@ -253,9 +270,9 @@ def test_kernel_error_propagates_instead_of_falling_back(monkeypatch):
 
 def _kernel_call(quantized, k, rows, items, feats, sds=jax.ShapeDtypeStruct):
     """(fn, argument shapes) of one fused dispatch as the batcher makes it:
-    the count of real rows is an operand of the program (a scalar
-    prefetched into SMEM), never compiled in."""
-    real = sds((), jnp.int32)
+    the counts of real query rows and of valid item rows are ONE operand of
+    the program (int32[2], prefetched into SMEM), never compiled in."""
+    real = sds((2,), jnp.int32)
     if quantized:
         fn = lambda xs, q, sc, real: topk_dot_batch_pallas(  # noqa: E731
             xs, q, scales=sc, k=k, rows=real, counted=True
@@ -782,4 +799,108 @@ def test_the_count_of_real_rows_is_traced_not_compiled_in():
     # one live tile, several, a whole block, two blocks, none: one program
     for real in (4, 9, 128, 200, 0, np.int32(7), jnp.asarray(9), None):
         _run_padded(xs, operands, 3, real=real)
+    assert _topk_pallas_jit._cache_size() == entries
+
+
+# -- item blocks past the valid rows are neither streamed nor scored (ISSUE 40) -
+#
+# A view stored with room to grow: 1,536 rows in three item blocks of 512
+# (twelve chunks), of which the first `n_valid` are items. The rows behind them
+# score far above every item for every query row, so one that was selected, or
+# that raised a row's threshold, would show in the result. The contract: bit
+# for bit the kernel's answer over `y[:n_valid]`.
+
+_VIEW_ROWS, _VIEW_BLOCK = 1536, 512
+# 1, a chunk's edge and either side of it, an item block's edge and either
+# side, mid-view, the whole view
+_N_VALID = [1, 127, 128, 129, 511, 512, 513, 750, _VIEW_ROWS]
+
+
+@functools.lru_cache(maxsize=None)
+def _view_with_headroom(dtype, n_valid):
+    """(xs of 256 real rows, item operands) of such a view: integer factors,
+    every query row with the same positive first feature, which is zero in
+    every item and huge in every row past `n_valid`."""
+    rng = np.random.default_rng(40)
+    past = np.arange(_VIEW_ROWS) >= n_valid
+    if dtype == "int8":
+        q = rng.integers(-127, 128, size=(_VIEW_ROWS, 16))
+        scale = rng.choice([0.25, 0.5, 1.0, 2.0], size=_VIEW_ROWS).astype(np.float32)
+        q[:, 0] = 0
+        q[past] = 0
+        q[past, 0], scale[past] = 127, 64.0  # 127 x 127 x 64: above any item's score
+        xs = _int_factors(rng, 256, 16, -127, 127)
+        xs[:, 0] = 127.0  # and every real row quantizes to itself (scale 1)
+        return xs, dict(y=q.astype(np.int8), scales=scale)
+    xs, y = _int_factors(rng, 256, 12), _int_factors(rng, _VIEW_ROWS, 12)
+    xs[:, 0], y[:, 0] = 8.0, 0.0
+    y[past] = 0.0
+    y[past, 0] = 256.0  # 2,048 against at most 11 x 81
+    return xs, dict(y=y)
+
+
+def _run_view(xs, operands, rows, n_valid, upto=None, k=32, staged=None):
+    """The kernel over the view's first `upto` rows (None: all of it) with
+    `rows` real query rows of 256 and the count `n_valid` (None: not given);
+    `staged` stands in for `rows` as the operand the kernel is handed."""
+    x = xs.copy()
+    x[rows:] = 0.0
+    quantized = "scales" in operands
+    v, i, c = topk_dot_batch_pallas(
+        jnp.asarray(x, dtype=jnp.float32 if quantized else jnp.bfloat16),
+        jnp.asarray(operands["y"][:upto], dtype=jnp.int8 if quantized else jnp.bfloat16),
+        scales=jnp.asarray(operands["scales"][:upto]) if quantized else None,
+        k=k, block_i=_VIEW_BLOCK, interpret=True, counted=True,
+        rows=rows if staged is None else staged, n_valid=n_valid,
+    )
+    return np.asarray(v), np.asarray(i), [int(n) for n in np.asarray(c)]
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("rows", [1, 5, 128])
+@pytest.mark.parametrize("n_valid", _N_VALID)
+def test_item_blocks_past_the_valid_rows_are_not_walked(n_valid, rows, dtype):
+    xs, operands = _view_with_headroom(dtype, n_valid)
+    v, i, (fired, walked, tiles, inserted) = _run_view(xs, operands, rows, n_valid)
+    v_cut, i_cut, (fired_cut, _, tiles_cut, inserted_cut) = _run_view(
+        xs, operands, rows, None, upto=n_valid
+    )
+    # values and indices bit for bit those of the kernel over y[:n_valid], and
+    # the same chunks fired, folded and placed on the way: no row past the
+    # count was selected, and none moved a threshold
+    assert np.array_equal(v, v_cut) and np.array_equal(i, i_cut)
+    assert (fired, tiles, inserted) == (fired_cut, tiles_cut, inserted_cut)
+    assert i[:rows].max() < n_valid and _is_filler(v[rows:], i[rows:])
+    assert np.all(np.isneginf(v[:rows, n_valid:])) and np.all(np.isfinite(v[:rows, :n_valid]))
+    # one live row block of the two, over the item blocks that hold an item
+    assert walked == 1 * -(-n_valid // _VIEW_BLOCK) * (_VIEW_BLOCK // 128)
+    if n_valid < _VIEW_ROWS:
+        # the rows behind ARE there to be found: without the count they win
+        assert _run_view(xs, operands, rows, None)[1][:rows].min() >= n_valid
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+def test_the_count_of_valid_rows_is_traced_not_compiled_in(dtype):
+    from oryx_tpu.ops.pallas_topk import _topk_pallas_jit
+
+    xs, operands = _view_with_headroom(dtype, _VIEW_ROWS)
+    whole = _run_view(xs, operands, 5, None)
+    entries = _topk_pallas_jit._cache_size()
+    # no count is the whole view; so is one past it (the kernel reads no
+    # further than the operand it was given)
+    for n_valid in (_VIEW_ROWS, np.int32(_VIEW_ROWS), jnp.asarray(_VIEW_ROWS), 10**6):
+        again = _run_view(xs, operands, 5, n_valid)
+        assert all(np.array_equal(a, b) for a, b in zip(again, whole))
+    # every other count, and the two counts staged as one array: one program
+    for n_valid in (700, 1, jnp.asarray(129)):
+        _run_view(xs, operands, 5, n_valid)
+    staged = jnp.asarray(np.array([5, 700], dtype=np.int32))
+    assert all(
+        np.array_equal(a, b) for a, b in
+        zip(_run_view(xs, operands, 5, None, staged=staged), _run_view(xs, operands, 5, 700))
+    )
+    assert _topk_pallas_jit._cache_size() == entries
+    # no item at all: nothing is walked, every row is what a dead block returns
+    v, i, counts = _run_view(xs, operands, 5, 0)
+    assert counts == [0, 0, 0, 0] and _is_filler(v, i)
     assert _topk_pallas_jit._cache_size() == entries

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from oryx_tpu.ops import als
@@ -96,3 +97,76 @@ def test_path_is_chosen_from_what_the_matrix_shows(monkeypatch, case):
     assert all(isinstance(a, jax.Array) for a in asked)
     if want in ("chunked", "sharded"):
         assert not asked
+
+
+# -- the count of valid item rows is the fused kernel's alone (ISSUE 40) --------
+
+
+def _filled(form, n, seed=40):
+    """`form` over n rows of integer factors (a score is exact in every dtype)."""
+    rng = np.random.default_rng(seed)
+    dense = jnp.asarray(rng.integers(-9, 10, size=(n, 8)), dtype=jnp.bfloat16)
+    scale = jnp.asarray(rng.choice([0.5, 1.0, 2.0], size=n), dtype=jnp.float32)
+
+    def part(lo, hi):
+        if form in (_quantized, _sharded_int8):
+            return QuantizedMatrix(dense[lo:hi].astype(jnp.int8), scale[lo:hi])
+        return dense[lo:hi]
+
+    if form in (_dense, _quantized):
+        return part(0, n)
+    if form is _chunked:
+        return ChunkedMatrix([part(0, n // 2), part(n // 2, n)])
+    plan = RowShards.plan(n, 2)
+    return ShardedMatrix(
+        [part(plan.bounds[s], plan.bounds[s + 1]) for s in range(2)], plan
+    )
+
+
+@pytest.mark.parametrize(
+    "form, recall, want",
+    [
+        (_dense, 1.0, "xla"),
+        (_dense, 0.95, "approx"),
+        (_quantized, 1.0, "xla-int8"),
+        (_chunked, 1.0, "chunked"),
+        (_sharded, 1.0, "sharded"),
+        (_sharded_int8, 1.0, "sharded"),
+    ],
+    ids=["xla", "approx", "xla-int8", "chunked", "sharded", "sharded-int8"],
+)
+def test_a_path_that_is_not_fused_ignores_the_count_of_valid_rows(form, recall, want):
+    # off the fused kernel the whole capacity is scored, with `n_valid` as
+    # without it, and the counts staged for such a path stay what they were:
+    # nothing is uploaded for a kernel that is not there
+    y = _filled(form, 300)
+    xs = jnp.asarray(
+        np.random.default_rng(41).integers(-9, 10, size=(5, 8)), dtype=jnp.float32
+    )
+    assert als.topk_path(y, 10, recall) == want
+    plain = als.topk_dot_batch(xs, y, k=10, recall=recall, counted=True, rows=3)
+    for n_valid in (200, 0, 300, 10**6):
+        counted = als.topk_dot_batch(
+            xs, y, k=10, recall=recall, counted=True, rows=3, n_valid=n_valid
+        )
+        assert counted[2] is None and plain[2] is None
+        assert np.array_equal(np.asarray(counted[0]), np.asarray(plain[0]))
+        assert np.array_equal(np.asarray(counted[1]), np.asarray(plain[1]))
+    assert int(np.asarray(plain[1]).max()) >= 200  # rows past the count are scored
+    _, rows = als.stage_topk_operands(xs, y, k=10, recall=recall, rows=3, n_valid=200)
+    assert rows == 3
+
+
+@pytest.mark.parametrize("form", [_dense, _quantized], ids=["pallas", "pallas-int8"])
+def test_the_fused_paths_counts_are_staged_as_one_array(monkeypatch, form):
+    monkeypatch.setattr(als, "_on_tpu", lambda a: True)
+    y, xs = form(BIG), np.zeros((4, 8), dtype=np.float32)
+    assert als.topk_path(y, 10) in ("pallas", "pallas-int8")
+    for rows, n_valid, want in [
+        (3, 20_000, [3, 20_000]), (None, 20_000, [4, 20_000]), (3, None, [3, BIG]),
+    ]:
+        _, staged = als.stage_topk_operands(xs, y, k=10, rows=rows, n_valid=n_valid)
+        assert isinstance(staged, jax.Array) and staged.dtype == jnp.int32
+        assert [int(c) for c in staged] == want
+    # neither count given: nothing to stage, the kernel's wrapper takes all of both
+    assert als.stage_topk_operands(xs, y, k=10)[1] is None

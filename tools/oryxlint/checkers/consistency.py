@@ -92,6 +92,9 @@ REQUIRED_PERFATTR_FAMILIES = (
     # oryx_topk_chunks_folded, the share of the single-entrant path that
     # engages; that share waits for a `benchmark` PR as well
     "oryx_topk_chunks_inserted",
+    # the chunks of a view's capacity a walked row block left alone, since
+    # the kernel is told how many of the view's rows are items (ISSUE 40)
+    "oryx_topk_item_chunks_skipped",
     # the batched encoder step of the seq app (ISSUE 33): its dispatches
     # and their real and padded tokens, and the expert layer's load counted
     # on the device; the benchmark's seq_step_ms / step_tokens /
