@@ -1,8 +1,14 @@
 """A sparse mixture-of-experts feed-forward layer on one chip.
 
-    p = softmax(u Wr)            over the E experts, in float32
-    the k largest, their weights divided by their sum
+    softmax:  p = softmax(u Wr) over the E experts, in float32; the k
+              largest, their weights divided by their sum
+    sigmoid:  s = sigmoid(u Wr); the k largest of s + b (b a learned
+              correction bias that selects and never weighs); their s
+              divided by their sum, times a scale
     y = sum_e w_e * (silu(u Wg_e) * (u Wu_e)) Wd_e
+
+Which rule is a property of the model (its encoder's configuration states
+it), never an option of the user; everything after the routing is one path.
 
 No token is dropped, whatever the load of an expert: the (token, expert)
 pairs are sorted by expert and each expert multiplies the contiguous rows
@@ -26,18 +32,32 @@ import jax.numpy as jnp
 ROW_TILE = 128
 
 
-def route(u: jax.Array, wr: jax.Array, k: int):
-    """Router: (weights [N,k] float32 summing to 1, experts [N,k] int32).
-    Logits and softmax in float32 at `highest` precision (a default f32
-    product on the TPU is one bf16 pass)."""
+SCORINGS = ("softmax", "sigmoid")
+
+
+def route(u: jax.Array, wr: jax.Array, k: int, scoring: str = "softmax", bias=None, scale: float = 1.0):
+    """Router: (weights [N,k] float32 summing to `scale`, experts [N,k]
+    int32). Logits and scores in float32 at `highest` precision (a default
+    f32 product on the TPU is one bf16 pass). `softmax`: the k largest
+    probabilities. `sigmoid`: the k experts with the largest s + `bias` [E],
+    weighted by their s alone."""
+    if scoring not in SCORINGS:
+        raise ValueError(f"unknown router scoring {scoring!r}")
     logits = jnp.dot(
         u.astype(jnp.float32), wr.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    p = jax.nn.softmax(logits, axis=-1)
-    w, e = jax.lax.top_k(p, k)
+    if scoring == "softmax":
+        w, e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    else:
+        s = jax.nn.sigmoid(logits)
+        # a model with expert groups would keep the best groups' experts
+        # alone here; with one group that limit is the identity
+        _, e = jax.lax.top_k(s if bias is None else s + bias.astype(jnp.float32), k)
+        w = jnp.take_along_axis(s, e, axis=-1)
     w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return w, e.astype(jnp.int32)
+    # no multiply at scale 1: the softmax model's program stays the one it was
+    return w * scale if scale != 1.0 else w, e.astype(jnp.int32)
 
 
 def _grouped(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array) -> jax.Array:
@@ -59,9 +79,11 @@ def _grouped(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array) -> jax.Array:
 
 def moe_apply(
     u: jax.Array, wr: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
-    k: int, live: jax.Array | None = None,
+    k: int, live: jax.Array | None = None, **rule,
 ):
     """u [N,H] float32 (normalised) -> (y [N,H] float32, counts int32[3]).
+    `rule`: the model's routing rule where it is not the softmax one
+    (`route`'s `scoring`, `bias`, `scale`).
 
     wr [H,E], wg/wu [E,H,F], wd [E,F,H] in the weights' dtype; the
     activations enter each product in that dtype and accumulate in
@@ -71,7 +93,7 @@ def moe_apply(
     the busiest expert's pairs)."""
     n, h = u.shape
     n_experts = wr.shape[1]
-    w, e = route(u, wr, k)
+    w, e = route(u, wr, k, **rule)
     if live is None:
         live = jnp.ones((n,), dtype=bool)
     pairs = n * k
@@ -100,16 +122,22 @@ def moe_apply(
     return y, counts
 
 
-def moe_reference(u, wr, wg, wu, wd, k: int):
+def moe_reference(u, wr, wg, wu, wd, k: int, scoring: str = "softmax", bias=None, scale: float = 1.0):
     """The plain form, float32 throughout: every expert in turn on every
     token, weighted by the token's routing weight for it (zero unless it
     is one of the token's k). For tests and the plain reference; run it
     under `jax.default_matmul_precision("highest")`."""
     f32 = jnp.float32
     u = u.astype(f32)
-    p = jax.nn.softmax(u @ wr.astype(f32), axis=-1)
-    w, e = jax.lax.top_k(p, k)
-    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if scoring == "softmax":
+        p = jax.nn.softmax(u @ wr.astype(f32), axis=-1)
+        w, e = jax.lax.top_k(p, k)
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    else:
+        p = jax.nn.sigmoid(u @ wr.astype(f32))
+        _, e = jax.lax.top_k(p + (0.0 if bias is None else bias.astype(f32)), k)
+        w = jnp.take_along_axis(p, e, axis=-1)
+        w = scale * w / jnp.sum(w, axis=-1, keepdims=True)
     dense_w = jnp.zeros(p.shape, f32).at[jnp.arange(u.shape[0])[:, None], e].add(w)
 
     def one_expert(acc, xs):

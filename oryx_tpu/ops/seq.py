@@ -415,4 +415,8 @@ def encoder_for(name: str, ext):
         from oryx_tpu.ops.jamba import JambaEncoder
 
         return JambaEncoder.from_extensions(ext)
+    if name == "joyai":
+        from oryx_tpu.ops.joyai import JoyaiEncoder
+
+        return JoyaiEncoder.from_extensions(ext)
     raise ValueError(f"unknown seq encoder {name!r}")

@@ -135,7 +135,7 @@ class _Metrics:
         self.state_bytes = reg.gauge(
             "oryx_seq_slot_state_bytes",
             "Bytes of the seq stepper's cache slots on the device, by kind of state "
-            "(recurrent: a fixed size a slot | kv: a row a position)",
+            "(recurrent: a fixed size a slot | kv, latent, rope_key: a row a position)",
             labeled=True,
         )
         self.routed = reg.counter(

@@ -108,6 +108,11 @@ REQUIRED_PERFATTR_FAMILIES = (
     "oryx_moe_routed_total",
     "oryx_moe_experts_touched_total",
     "oryx_moe_expert_tokens_max_total",
+    # the cache slots' bytes by kind of state (ISSUE 37; `latent` and
+    # `rope_key` since ISSUE 41): the encoder kinds' runs report it, and the
+    # oryx_moe_* counters above are fed by a decode step too
+    # (joyai_moe_roofline / joyai_experts_touched / joyai_moe_load_peak)
+    "oryx_seq_slot_state_bytes",
     # what every thread on the serving path is doing (ISSUE 39): the
     # regions' always-on counters (common/tracing.py), the event loops'
     # heartbeat and the stall witness; the benchmark's launch_*_ms,
