@@ -562,14 +562,14 @@ class JoyaiEncoder:
             lengths[i], last[i], slot_of[i] = len(tok) - 1, tok[-1], slots[i]
         return tokens, lengths, slot_of, last
 
+    # host operands ride the jitted call (the seam's comment, ops/seq.py)
     def prefill(self, params, state, tokens, lengths, slots, last):
-        packed = tuple(jnp.asarray(a) for a in (tokens, lengths, slots, last))
-        return prefill(self.cfg, params, state, *packed)
+        return prefill(self.cfg, params, state, tokens, lengths, slots, last)
 
     def step(self, params, state, head, slots, lengths, live, step):
         view, n_valid, row_token = head
-        rows = (jnp.asarray(slots), jnp.asarray(lengths), jnp.asarray(live), jnp.asarray(step))
-        return decode_step(self.cfg, params, state, view, jnp.int32(n_valid), row_token, *rows)
+        rows = (slots, lengths, live, step)
+        return decode_step(self.cfg, params, state, view, np.int32(n_valid), row_token, *rows)
 
     def train(self, *args, **kw):
         raise NotImplementedError(

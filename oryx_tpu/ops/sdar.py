@@ -462,14 +462,14 @@ class SdarEncoder:
             slot_of[i] = slots[i]
         return tokens, lengths, slot_of
 
+    # host operands ride the jitted call (the seam's comment, ops/seq.py)
     def prefill(self, params, state, tokens, lengths, slots):
-        packed = (jnp.asarray(tokens), jnp.asarray(lengths), jnp.asarray(slots))
-        return prefill(self.cfg, params, state, *packed)
+        return prefill(self.cfg, params, state, tokens, lengths, slots)
 
     def step(self, params, state, head, slots, lengths, live, step):
         view, n_valid, row_token = head
-        rows = (jnp.asarray(slots), jnp.asarray(lengths), jnp.asarray(live), jnp.asarray(step))
-        return denoise_step(self.cfg, params, state, view, jnp.int32(n_valid), row_token, *rows)
+        rows = (slots, lengths, live, step)
+        return denoise_step(self.cfg, params, state, view, np.int32(n_valid), row_token, *rows)
 
     def train(self, *args, **kw):
         raise NotImplementedError(

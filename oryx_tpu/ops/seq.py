@@ -294,6 +294,17 @@ def catalog_head(z, view, n_valid):
 #   pack(prepared, bucket, slots, scratch) -> the arrays of one prefill
 #   prefill(params, state, *packed) -> (state, hidden [rows, d], counts)
 #   step(params, state, head, slots, lengths, live, step) -> (state, out)
+#                           `packed` and `slots, lengths, live, step` are HOST
+#                           arrays (numpy, fresh every dispatch), and they and
+#                           the head's valid rows (an `np.int32`) are handed
+#                           to the jitted program as they are: the call's own
+#                           argument path transfers them. A wrapper uploads
+#                           nothing before it (no `jnp.asarray`, `jnp.int32`
+#                           or `device_put`: each is a trip through Python and
+#                           a transfer of its own, 0.27-0.38 ms on the serving
+#                           host where the call's own transfer of a numpy
+#                           operand is 0.14); a jitted program takes device
+#                           arrays too
 #   step_kind, step_tokens  the label a step dispatch counts under and the
 #                           tokens a row of it runs (a block's positions, or
 #                           one token a sequence)
@@ -370,7 +381,7 @@ class GruEncoder:
         return mats, masks
 
     def prefill(self, params, state, mats, masks):
-        return state, encode_vectors(params, jnp.asarray(mats), jnp.asarray(masks)), None
+        return state, encode_vectors(params, mats, masks), None
 
     def step(self, params, state, head, slots, lengths, live, step):
         raise NotImplementedError("the GRU answers after prefill")

@@ -23,8 +23,13 @@ to step), and they tile its life (docs/observability.md).
 
 A request's state never visits the host between steps: the host counts the
 steps it launched (a block takes exactly `encoder.steps`) and fetches a
-block's rows with the dispatch of its last step. The GRU is a prefill with
-no steps. The encoder is reached through the seam of ops/seq.py only.
+block's rows with the dispatch of its last step. What the host builds for a
+dispatch (`pack`'s arrays; a step's slots, lengths, live and step, fresh
+numpy arrays every cycle) reaches the device as arguments of the jitted call
+itself: `stepper.prefill.call` and `stepper.step.call` transfer the
+dispatch's operands, and nothing is uploaded before them. The GRU is a
+prefill with no steps. The encoder is reached through the seam of ops/seq.py
+only.
 
 Shapes are few and fixed (`prefill_rows` x each length bucket, `step_rows`
 blocks) and all compile before an engine's first request (`warm`); an
