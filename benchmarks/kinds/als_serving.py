@@ -183,7 +183,7 @@ def _sleep_until(t: float) -> None:
 
 
 def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float, info) -> dict:
-    """One run of one cell. `cell` = {config, traffic, chips, scratch};
+    """One run of one cell. `cell` = {name, config, traffic, chips, scratch};
     `t_process` is time.time() at process start; `info(**kv)` prints an
     earlier output line."""
     import jax
@@ -294,7 +294,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float, in
         before = scrape(base)
         trace_out = timeline_out = None
         if trace:
-            trace_dir = Path(cell["scratch"]) / "trace"
+            trace_dir = Path(cell["scratch"]) / "trace" / cell["name"]  # the cell's own: see xplane.find_xplane
             shutil.rmtree(trace_dir, ignore_errors=True)
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 0
@@ -348,6 +348,10 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float, in
         not_exact = sum(1 for r in records if r.score_mode != "exact")
         if not_exact:
             faults.append(f"{not_exact} dispatch(es) in the window not in score-mode exact")
+        # nor can they tell a window the host's exact top-k served after a device error
+        fallbacks = delta.get("oryx_topk_host_fallbacks", 0.0)
+        if fallbacks:
+            faults.append(f"{fallbacks:.0f} request(s) in the window scored on the host, not by the device scan")
         for f in faults:
             print(f"als_serving: {f}", file=sys.stderr)
 
@@ -364,6 +368,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float, in
             "malformed_answers": [sum(1 for c in checks if c["fault"]), "==", 0],
             "wrong_bodies_in_window": [wrong_bodies, "==", 0],
             "compiles_in_window": [compiles, "==", 0],
+            "host_fallbacks": [fallbacks, "==", 0],
             "dispatches_not_exact": [not_exact, "==", 0],
             "good_in_window": [len(good), ">=", 1],
         }

@@ -21,23 +21,15 @@ that compute the operations and bytes of a dispatch and of its scan
 
 from __future__ import annotations
 
-import gc
-import json
 import math
-import os
-import shutil
-import subprocess
-import sys
 import time
-from concurrent.futures import ThreadPoolExecutor
 from functools import partial
-from pathlib import Path
 
 import numpy as np
 
-from benchmarks import latency, seqgen, seqtrace, timeline, xplane
-from benchmarks.kinds.als_serving import _get, _sleep_until, queued_ahead_share, scrape
-from benchmarks.kinds.seq_serving import draw_catalog, holds, ref_logits
+from benchmarks.kinds import _encoder
+from benchmarks.kinds._encoder import holds  # noqa: F401 - the kind's tests read it here
+from benchmarks.kinds.seq_serving import draw_catalog, ref_logits
 
 # What `correct` holds the served answers to. For a sample of the window's
 # own requests the reference runs ONE full forward pass over [session + the
@@ -67,7 +59,6 @@ from benchmarks.kinds.seq_serving import draw_catalog, holds, ref_logits
 # hides inside the rounding: 4.77e-3 beside the sound 4.89e-3); the conv's tail
 # not carried reads 0.79-0.81 on both quartiles and padding that advances the
 # state 0.57-0.67, overlap 0.
-CHECK_REQUESTS = 32
 REFERENCE_BATCH = 16   # sessions a reference dispatch
 # against the float32 reference, the quartile: float32 leaves the order of
 # accumulation alone (5e-8 on the CPU; a bfloat16 state reads 3e-6 there); for
@@ -84,9 +75,6 @@ STATED_TIGHT = 3.2e-3
 SCORE_LOOSE = 5.0e-2   # the worst position of all, the item fed back and the last candidate: sound at most 2.3e-2, the controls over 1
 MIN_OVERLAP = 8        # of 10 candidates the reference's, by the same quartile (sound 9: the tenth logit lies 1e-2 from the eleventh)
 MIN_OVERLAP_WORST = 4  # and in the worst position of all (sound 6-8 over 27 runs; the controls 0)
-WARM_MIN_S = 5.0
-WARM_CYCLES = 5
-TRACE_MAX_S = 12.0
 # an op counts under the first scope its op_name holds: the scan lies inside
 # the mixer's scope
 SCOPES = ("jamba.scan", "jamba.mamba", "jamba.attn", "jamba.mlp", "jamba.head")
@@ -340,35 +328,10 @@ def compare(config: dict, answer: list, session: np.ndarray, logits, how_many: i
 
 def summarise(per_request: list[list[dict]], dtype: str = "bfloat16") -> dict:
     """The compared numbers of `compare`'s readings over the sampled
-    requests: by basket position the quartile over the requests (the worst
-    position's is reported), and the worst reading of all."""
-    flat = [o for req in per_request for o in req]
-    basket = len(per_request[0]) if per_request else 0
-
-    def by_position(key, q, pick):
-        read = []
-        for b in range(basket):
-            values = [req[b][key] for req in per_request if req[b][key] is not None]
-            if values:
-                read.append(float(np.percentile(values, q)))
-        return pick(read) if read else None
-
-    def worst(key, pick):
-        values = [o[key] for o in flat if o[key] is not None]
-        return pick(values) if values else None
-
-    out = {"malformed_answers": [sum(1 for o in flat if o["fault"]), "==", 0]}
-    if worst("stated_err", max) is not None:  # a configuration that states a rounding
-        out["stated_err_quartile"] = [by_position("stated_err", 25, max), "<=", STATED_TIGHT]
-    out.update(
-        score_err_quartile=[by_position("score_err", 25, max), "<=", SCORE_TIGHT[dtype]],
-        score_err_worst=[worst("score_err", max), "<=", SCORE_LOOSE],
-        fixed_gap_worst=[worst("fixed_gap", max), "<=", SCORE_LOOSE],
-        candidate_gap_worst=[worst("candidate_gap", max), "<=", SCORE_LOOSE],
-        overlap_quartile=[by_position("overlap", 25, min), ">=", MIN_OVERLAP],
-        overlap_worst=[worst("overlap", min), ">=", MIN_OVERLAP_WORST],
+    requests, under this kind's limits."""
+    return _encoder.summarise(
+        per_request, SCORE_TIGHT[dtype], STATED_TIGHT, SCORE_LOOSE, MIN_OVERLAP, MIN_OVERLAP_WORST
     )
-    return out
 
 
 # -- the model from the seed ----------------------------------------------------------------
@@ -392,11 +355,7 @@ def build(cell: dict, seed: int, info):
     import jax
     import jax.numpy as jnp
 
-    from oryx_tpu.apps.seq.serving import SeqServingModel, SeqServingModelManager
     from oryx_tpu.apps.seq.state import adopt_model
-    from oryx_tpu.bus.broker import topics
-    from oryx_tpu.common.config import load_config
-    from oryx_tpu.serving.server import ServingLayer
 
     config = cell["config"]
     n_items = config["vocab_size"]  # every id is an item
@@ -411,36 +370,14 @@ def build(cell: dict, seed: int, info):
     state = adopt_model(None, ext.get, tensors, [f"i{j}" for j in range(n_items)])
     jax.block_until_ready(state.params)
     info(phase="model_built", seconds=time.monotonic() - t_build, parameters=jamba.param_count(enc.cfg))
-
-    broker = "mem://bench"
-    overlay = {
-        "oryx.id": "bench",
-        "oryx.input-topic.broker": broker,
-        "oryx.update-topic.broker": broker,
-        "oryx.serving.api.port": 0,
-        "oryx.serving.api.read-only": True,
-        "oryx.serving.application-resources": [
-            "oryx_tpu.serving.resources.common",
-            "oryx_tpu.serving.resources.seq",
-        ],
-        "oryx.monitoring.flight.dir": str(Path(cell["scratch"]) / "flight"),
-    }
-    if jax.devices()[0].platform == "tpu":
-        overlay["oryx.compute.platform"] = "tpu"
-    cfg = load_config(overlay=overlay)
-    topics.maybe_create(broker, "OryxUpdate", partitions=1)
-    manager = SeqServingModelManager(cfg)
-    manager.model = SeqServingModel(state, sync=manager.sync)
-    serving = ServingLayer(cfg, model_manager=manager)
-    serving.start()
-    return serving, manager, state, e_host
+    return (*_encoder.serve(cell, state), state, e_host)
 
 
-def reference_logits(config: dict, params: dict, e_dev, asked: list[np.ndarray], act) -> list[np.ndarray]:
+def reference_logits(hidden, config: dict, params: dict, e_dev, asked: list[np.ndarray], act) -> list[np.ndarray]:
     """The reference's logits [basket, items] at the last `basket` positions
     of every token array in `asked`, REFERENCE_BATCH forward passes a
     dispatch (right-padded: the pass is causal, so padding changes nothing
-    before it)."""
+    before it). `hidden` is the kind's plain forward, `ref_hidden`'s form."""
     basket, width = config["basket"], config["max_len"] + config["basket"]
     out, compiled = [], {}
     for lo in range(0, len(asked), REFERENCE_BATCH):
@@ -448,7 +385,7 @@ def reference_logits(config: dict, params: dict, e_dev, asked: list[np.ndarray],
         padded = np.zeros((REFERENCE_BATCH, width), dtype=np.int32)
         for j, tokens in enumerate(group):
             padded[j, : len(tokens)] = tokens
-        z = ref_hidden(config, params, padded, act, compiled)
+        z = hidden(config, params, padded, act, compiled)
         rows = np.stack([np.arange(len(t) - basket, len(t)) for t in group])
         zb = z[np.arange(len(group))[:, None], rows]                              # [G, basket, H]
         logits = ref_logits(zb.reshape(len(group) * basket, -1), e_dev)
@@ -456,234 +393,45 @@ def reference_logits(config: dict, params: dict, e_dev, asked: list[np.ndarray],
     return out
 
 
-def check(base: str, config: dict, traffic: dict, state, e_host, sessions: list, sample: list[int], info):
-    """The sampled sessions asked again, together, and each answer against
-    the reference's one full pass over it: (readings of `compare`, faults)."""
+def check_baskets(hidden, config: dict, traffic: dict, state, e_host, served: list) -> tuple[list, int]:
+    """The sampled sessions' answers (`served`: [(answer, session)]), each
+    against the reference's one full pass (`hidden`) over it: (readings of
+    `compare`, the reference's forward passes). Kind joyai-serving's check
+    too, over its own `ref_hidden`."""
     import jax.numpy as jnp
 
-    with ThreadPoolExecutor(len(sample)) as pool:
-        answers = list(pool.map(
-            lambda i: _get(f"{base}{seqgen.session_path(traffic, sessions[i])}"), sample
-        ))
-    faults, served = [], []
-    for i, (status, body) in zip(sample, answers):
-        if status != 200:
-            faults.append(f"request {i}: status {status}")
-        else:
-            served.append((json.loads(body), sessions[i]))
-    t_ref = time.monotonic()
     asked = [basket_tokens(config, answer, session) for answer, session in served]
     sound = [j for j, t in enumerate(asked) if t is not None]
     e_dev = jnp.asarray(e_host, dtype=jnp.bfloat16)  # bf16 holds the catalog's values exactly
     tokens = [asked[j] for j in sound]
-    exact = dict(zip(sound, reference_logits(config, state.params, e_dev, tokens, None)))
+    exact = dict(zip(sound, reference_logits(hidden, config, state.params, e_dev, tokens, None)))
     # the configuration's stated rounding, where it states one below float32
     act = None if config["dtype"] == "float32" else jnp.dtype(config["dtype"])
     rounded = (
-        dict(zip(sound, reference_logits(config, state.params, e_dev, tokens, act))) if act is not None else {}
+        dict(zip(sound, reference_logits(hidden, config, state.params, e_dev, tokens, act))) if act is not None else {}
     )
     readings = [
         compare(config, answer, session, exact.get(j), int(traffic["how_many"]), rounded.get(j))
         for j, (answer, session) in enumerate(served)
     ]
-    keys = ("score_err", "rounding", "stated_err", "fixed_gap", "overlap", "candidate_gap")
-    info(phase="reference", seconds=time.monotonic() - t_ref, forwards=len(tokens), readings=[
-        [[None if o[k] is None else round(o[k], 6) for k in keys] for o in req] for req in readings
-    ])
-    for req in readings:
-        faults += [f"a basket position: {o['fault']}" for o in req if o["fault"]]
-    return readings, faults
+    return readings, len(tokens)
 
 
-def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float, info) -> dict:
-    """One run of one cell. `cell` = {config, traffic, chips, scratch}."""
-    import jax
-
-    from oryx_tpu.common.perfstats import get_perfstats
-
-    config, traffic = cell["config"], cell["traffic"]
-    n_items = config["vocab_size"]
-    # the generator's names for the basket and its steps (benchmarks/seqgen.py)
-    for key in ("block_length", "denoise_steps"):
-        if traffic[key] != config["basket"]:
-            raise ValueError(f"traffic's {key} and the configuration's basket disagree")
-    serving, manager, state, e_host = build(cell, seed, info)
-
-    # the cyclic collector stops every thread of the server while it runs:
-    # time each collection (gc_pause_share)
-    collections: list[tuple[float, float]] = []  # (monotonic start, seconds)
-
-    def on_gc(phase: str, _info: dict) -> None:
-        now = time.monotonic()
-        if phase == "start":
-            collections.append((now, 0.0))
-        else:
-            collections[-1] = (collections[-1][0], now - collections[-1][0])
-
-    gc.callbacks.append(on_gc)
-    base = f"http://127.0.0.1:{serving.port}"
-    gen = None
-    try:
-        started = scrape(base)  # before this run's first request
-        # -- warm-up, part 1: one request uploads the view and compiles (or
-        # loads) every shape of the decoder and the scan's; a second, alone,
-        # times one request
-        t_prime = time.monotonic()
-        probe = seqgen.draw_sessions(seed + 1, n_items, traffic, 2)
-        for attempt, session in zip(("first", "cycle"), probe):
-            t_req = time.monotonic()
-            status, body = _get(f"{base}{seqgen.session_path(traffic, session)}")
-            if status != 200:
-                raise RuntimeError(f"priming request -> {status}: {body[:200]!r}")
-            cycle_s = time.monotonic() - t_req
-            info(phase=f"prime_{attempt}", seconds=cycle_s)
-        warm_s = float(math.ceil(max(WARM_MIN_S, WARM_CYCLES * cycle_s)))
-        spec = {
-            "port": serving.port, "seed": seed, "traffic": traffic, "items": n_items,
-            "seconds": seconds, "warm_s": warm_s,
-        }
-        gen = subprocess.Popen(
-            [sys.executable, seqgen.__file__, json.dumps(spec)],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True,
-            env={k: v for k, v in os.environ.items() if k not in ("PYTHONPATH", "JAX_PLATFORMS")},
-        )
-        if gen.stdout.readline().strip() != "READY":
-            raise RuntimeError("the load generator did not start")
-
-        # -- warm-up, part 2: the cell's own traffic, then the window
-        t0 = time.monotonic() + 0.25
-        gen.stdin.write(json.dumps({"t0": t0}) + "\n")
-        gen.stdin.flush()
-        t_open, t_close = t0 + warm_s, t0 + warm_s + seconds
-        _sleep_until(t_open)
-        setup_s = time.time() - t_process
-        before = scrape(base)
-        trace_out = timeline_out = found = None
-        if trace:
-            trace_dir = Path(cell["scratch"]) / "trace"
-            shutil.rmtree(trace_dir, ignore_errors=True)
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0
-            options.host_tracer_level = 2
-            _sleep_until(t_open + 0.25)
-            jax.profiler.start_trace(str(trace_dir), profiler_options=options)
-            _sleep_until(min(time.monotonic() + TRACE_MAX_S, t_close - 0.5))
-            jax.profiler.stop_trace()
-            found = xplane.find_xplane(trace_dir)
-            if found:
-                trace_out = xplane.reduce_trace(found, prefer=timeline.REGION_PREFIX)
-                timeline_out = timeline.parse(found)
-        _sleep_until(t_close)
-        after = scrape(base)
-        ring = get_perfstats().records_since(t_open - 1.0)
-        records = [r for r in ring if t_open <= r.t_start < t_close]
-        pauses = [s for t, s in collections if t_open <= t < t_close]
-        out, _ = gen.communicate(timeout=seconds + 240)
-        result = json.loads(out.strip().splitlines()[-1])
-        gen = None
-
-        # -- correctness, outside the timing: sampled requests of the window,
-        # asked again together, against the reference's pass over each
-        good, attempted, failed = latency.window_latencies(result)
-        n_requests = len(result["due"])
-        sessions = seqgen.draw_sessions(seed, n_items, traffic, n_requests)
-        in_window = np.flatnonzero(np.asarray(result["in_window"], dtype=bool))
-        rng = np.random.default_rng([int(seed), 3])
-        sample = rng.choice(in_window, size=min(CHECK_REQUESTS, len(in_window)), replace=False)
-        readings, faults = check(base, config, traffic, state, e_host, sessions, sample.tolist(), info)
-        final = scrape(base)
-        wrong_bodies = sum(
-            n for kind, n in result["errors"].items()
-            if kind in ("unparsable", "wrong_block", "wrong_count", "known_item")
-        )
-        delta = {s: after[s] - before.get(s, 0.0) for s in after}
-        compiles = sum(v for s, v in delta.items() if s.startswith("oryx_xla_compiles_total"))
-        # every event of every session sent (the probes, the generator's, the
-        # sample asked again) but its last has to have run through a prefill
-        sent = list(probe) + sessions + [sessions[i] for i in sample.tolist()]
-        whole = lambda series: final.get(series, 0.0) - started.get(series, 0.0)  # noqa: E731
-        answered = whole("oryx_seq_blocks_total")
-        prefilled = whole('oryx_seq_step_tokens_total{kind="prefill",tokens="real"}')
-        timed_out = sum(n for kind, n in result["errors"].items() if kind == "timeout")
-        compared = dict(
-            {"requests_compared": [len(readings), "==", len(sample)]},
-            **summarise(readings, config["dtype"]),
-            wrong_bodies_in_window=[wrong_bodies, "==", 0],
-            compiles_in_window=[compiles, "==", 0],
-            # over the whole run, read when nothing is in flight
-            steps_per_basket=[whole("oryx_seq_denoise_steps_total") / answered if answered else None,
-                              "==", config["basket"]],
-            dropped_events=[sum(len(s) - 1 for s in sent) - prefilled if not timed_out else None, "==", 0],
-            host_fallbacks=[delta.get("oryx_topk_host_fallbacks", 0.0), "==", 0],
-            topk_shapes=[len({(r.padded_rows, r.k_bucket) for r in records}), "==", 1],
-            dispatches_not_exact=[sum(1 for r in records if r.score_mode != "exact"), "==", 0],
-            good_in_window=[len(good), ">=", 1],
-        )
-        faults += [f"{name} = {compared[name][0]} breaks its limit" for name in holds(compared)]
-        for f in faults:
-            print(f"ssm_serving: {f}", file=sys.stderr)
-
-        steps_out = None
-        if found:
-            steps_out = seqtrace.split(seqtrace.parse(found), _compiled_texts(manager.model), SCOPES)
-        late = [ms for ms, w in zip(result["late_ms"], result["in_window"]) if w and ms is not None]
-        info(
-            generator_processes=1, connections_opened=result["connections_opened"],
-            errors=result["errors"], warm_s=warm_s,
-            in_flight_at_window_end=latency.in_flight_at(result, warm_s + seconds),
-            prime_s=t_open - t_prime,
-            gen_late_p95_ms=latency.percentile(late, 95) if late else None,
-            latency_p95_ms=latency.percentile(good, 95) if good else None,
-            collector_pauses_s=[round(s, 4) for s in pauses if s > 0.05],
-            dispatches=len(records),
-            rows_per_dispatch=sum(r.rows for r in records) / len(records) if records else None,
-            shapes=sorted({(r.padded_rows, r.k_bucket) for r in records}),
-            queued_ahead_share=queued_ahead_share(records),
-            encoder_steps=sum(delta.get(f'oryx_seq_steps_total{{kind="{kind}"}}', 0.0) for kind in PROGRAMS),
-            prefill_tokens_per_step=_ratio(delta, "prefill"), decode_tokens_per_step=_ratio(delta, "decode"),
-            slot_state_bytes={
-                kind: final.get(f'oryx_seq_slot_state_bytes{{state="{kind}"}}') for kind in ("recurrent", "kv")
-            },
-        )
-    finally:
-        gc.callbacks.remove(on_gc)
-        if gen is not None:
-            gen.kill()
-            gen.wait()
-        serving.close()
-
+def basket_invariants(config: dict, final: dict, started: dict, sent: list, timed_out: int) -> dict:
+    """The entries of `compared` every kind of autoregressive basket holds,
+    over the whole run: every basket took its steps, and every event of every
+    session sent but its last ran through a prefill."""
+    whole = lambda series: final.get(series, 0.0) - started.get(series, 0.0)  # noqa: E731
+    answered = whole("oryx_seq_blocks_total")
+    prefilled = whole('oryx_seq_step_tokens_total{kind="prefill",tokens="real"}')
     return {
-        "correct": not faults and bool(good),
-        "attempted": attempted,
-        "failed": failed,
-        "setup_s": setup_s,
-        "end_to_end": {"p50_ms": latency.percentile(good, 50) if good else None},
-        "sources": {
-            "config": config,
-            "traffic": traffic,
-            "counters": delta,
-            "dispatch_records": [
-                {"rows": r.rows, "padded_rows": r.padded_rows, "k_bucket": r.k_bucket}
-                for r in records
-            ],
-            "generator": {"late_ms": late, "latency_ms": good},
-            "collector": {"window_s": seconds, "pauses_s": pauses},
-            "trace": trace_out,
-            "timeline": timeline_out,
-            # the traced window's device time by decoder program and scope
-            "steps": steps_out,
-        },
-        "compared": compared,
+        "steps_per_basket": [whole("oryx_seq_denoise_steps_total") / answered if answered else None,
+                             "==", config["basket"]],
+        "dropped_events": [sum(len(s) - 1 for s in sent) - prefilled if not timed_out else None, "==", 0],
     }
 
 
-def _ratio(delta: dict, kind: str) -> float | None:
-    n = delta.get(f'oryx_seq_steps_total{{kind="{kind}"}}', 0.0)
-    real = delta.get(f'oryx_seq_step_tokens_total{{kind="{kind}",tokens="real"}}', 0.0)
-    return real / n if n else None
-
-
-def _compiled_texts(model) -> dict[str, list[str]]:
+def compiled_texts(model) -> dict[str, list[str]]:
     """The compiled text of every decoder program the engine runs, by the
     program's name on the device trace: lowered again from the live arrays'
     shapes (a persistent compile cache makes it a load)."""
@@ -712,3 +460,15 @@ def _compiled_texts(model) -> dict[str, list[str]]:
     )
     texts[PROGRAMS["decode"]].append(lowered.compile().as_text())
     return texts
+
+
+KIND = _encoder.Kind(
+    name="ssm_serving", programs=PROGRAMS, scopes=SCOPES, build=build, check=partial(check_baskets, ref_hidden),
+    summarise=summarise, invariants=basket_invariants, compiled_texts=compiled_texts, position="basket",
+    slot_states=("recurrent", "kv"),
+)
+
+
+def run(cell: dict, seed: int, seconds: float, trace: bool, t_process: float, info) -> dict:
+    """One run of one cell. `cell` = {name, config, traffic, chips, scratch}."""
+    return _encoder.run(KIND, cell, seed, seconds, trace, t_process, info)

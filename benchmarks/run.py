@@ -110,7 +110,7 @@ def main(argv: list[str]) -> int:
 
     SCRATCH.mkdir(exist_ok=True)
     kind = load_module(find(paths, f"kinds/{config['kind'].replace('-', '_')}.py"))
-    cell = {"config": config, "traffic": traffic, "chips": chips, "scratch": str(SCRATCH)}
+    cell = {"name": args.workload, "config": config, "traffic": traffic, "chips": chips, "scratch": str(SCRATCH)}
     out = kind.run(cell, args.seed, args.seconds, bool(args.trace), T_PROCESS, info)
 
     stats = [d.memory_stats() or {} for d in devices[:chips]]

@@ -21,8 +21,12 @@ TOP_N = 10
 
 
 def find_xplane(trace_dir: str | Path) -> Path | None:
-    found = sorted(Path(trace_dir).glob("plugins/profile/*/*.xplane.pb"))
-    return found[-1] if found else None
+    """The newest trace at or under `trace_dir` (a kind traces into a
+    directory of its cell's own, `.bench_out/trace/<workload>`, so that two
+    cells run side by side in one checkout never read or remove each
+    other's)."""
+    found = list(Path(trace_dir).glob("**/plugins/profile/*/*.xplane.pb"))
+    return max(found, key=lambda f: f.stat().st_mtime) if found else None
 
 
 def _merge(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
@@ -37,31 +41,37 @@ def _merge(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
 
 
 def _label_gap(
-    host_events: list[tuple[float, float, str]], gap: tuple[float, float], prefer: str = ""
+    host_events: list[tuple[float, float, str]], gap: tuple[float, float],
+    prefer: str | tuple[str, ...] = "",
 ) -> str:
     """Name of the host event that covers most of the gap; among equals the
     shortest (innermost) one. Where `prefer` is given, an event whose own
     name (after `<thread>:`) starts with it wins over every other: the
     program's regions say what the host was doing, the runtime's events
-    only which call it was in."""
-    best, best_key = "no_host_event", (False, 0.0, 0.0)
+    only which call it was in. `prefer` may be several prefixes, in order:
+    an event of an earlier one wins over any event of a later one (the
+    thread that feeds the device before the thread that waits for it)."""
+    prefixes = (prefer,) if isinstance(prefer, str) else tuple(prefer)
+    best, best_key = "no_host_event", (0, 0.0, 0.0)
     for start, end, name in host_events:
         overlap = min(end, gap[1]) - max(start, gap[0])
         if overlap > 0:
-            preferred = bool(prefer) and name.partition(":")[2].startswith(prefer)
-            key = (preferred, overlap, -(end - start))
+            own = name.partition(":")[2]
+            rank = next((len(prefixes) - i for i, p in enumerate(prefixes) if p and own.startswith(p)), 0)
+            key = (rank, overlap, -(end - start))
             if key > best_key:
                 best, best_key = name, key
     return best
 
 
 def reduce_trace(
-    path: str | Path, device_prefix: str = "/device:TPU:", prefer: str = ""
+    path: str | Path, device_prefix: str = "/device:TPU:", prefer: str | tuple[str, ...] = ""
 ) -> dict | None:
     """{window_s, busy_s, devices, ops: {name: [count, seconds]}, idle_gaps:
     [[label, seconds], ...]} or None when the trace holds no device op.
     busy_s is the union of device-op intervals, averaged over the devices;
-    `prefer` is the prefix of the program's own regions (see _label_gap)."""
+    `prefer` is the prefix, or the ordered prefixes, of the program's own
+    regions (see _label_gap)."""
     from jax.profiler import ProfileData
 
     path = Path(path)

@@ -5,7 +5,6 @@ number."""
 import json
 import operator
 import os
-import re
 import subprocess
 import sys
 from pathlib import Path
@@ -13,6 +12,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+import listed
 from benchmarks import latency, loadgen, xplane
 from benchmarks.kinds import als_serving
 from benchmarks.run import find, load_module, metrics_of
@@ -28,8 +28,6 @@ TRAFFIC_FILES = [
     if json.loads(f.read_text()).get("kind", "als-serving") == "als-serving"
 ]
 RECORDED = HERE / "data" / "steady128-5s.xplane.pb.gz"
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 POPULATION = {"items": 5_000_000, "active_users": 20_000}
 
 
@@ -159,8 +157,14 @@ def test_topk_work_at_the_configurations_shapes(rows, items, features, k, ms_hbm
 
 # -- the per-layer readers on synthetic sources ----------------------------------
 
-def _reader(name):
-    return load_module(find(PATHS, f"metrics/{name}.py")).read
+def _reader_reads_its_case(paths, name):
+    read = load_module(find(paths, f"metrics/{name}.py")).read
+    case = json.loads(find(paths, f"cases/{name}.json").read_text())
+    src = case["src"]
+    if isinstance(src.get("peaks"), str):
+        src["peaks"] = json.loads(find(paths, "peaks.json").read_text())[src["peaks"]]
+    assert read(src) == pytest.approx(case["expect"], rel=0.01)
+    assert read({}) is None
 
 
 @pytest.mark.parametrize("name", [m["name"] for m in BENCH["per_layer"]])
@@ -169,12 +173,7 @@ def test_every_listed_reader_reads_its_case_and_returns_nothing_from_nothing(nam
     it has to read from it}; a "peaks" string in src names a device kind of
     peaks.json. Listing a metric is adding its reader, its case and its
     entry: no table here names them."""
-    case = json.loads(find(PATHS, f"cases/{name}.json").read_text())
-    src = case["src"]
-    if isinstance(src.get("peaks"), str):
-        src["peaks"] = json.loads(find(PATHS, "peaks.json").read_text())[src["peaks"]]
-    assert _reader(name)(src) == pytest.approx(case["expect"], rel=0.01)
-    assert _reader(name)({}) is None
+    _reader_reads_its_case(PATHS, name)
 
 
 # -- the trace reduction on a recorded trace --------------------------------------
@@ -204,62 +203,84 @@ def test_interval_union_and_gap_labels():
     assert xplane._label_gap(host, (1.0, 9.0)) == "python3:PjitFunction(f)"
     assert xplane._label_gap(host, (1.0, 9.0), prefer="batcher.") == "python3:batcher.issue"
     assert xplane._label_gap(host, (5.0, 9.0), prefer="batcher.") == "python3:PjitFunction(f)"
+    # several prefixes, in order: the thread that feeds the device before the one that waits for it
+    host = [
+        (0.0, 10.0, "oryx-topk:batcher.fetch"), (2.0, 3.0, "oryx-seq:stepper.step"),
+        (2.2, 2.6, "oryx-seq:stepper.step.call"), (3.0, 6.0, "oryx-seq:stepper.idle"), (0.0, 10.0, "main:outer"),
+    ]
+    both = ("stepper.", "batcher.")
+    assert xplane._label_gap(host, (1.0, 9.0), prefer=both) == "oryx-seq:stepper.idle"   # most of the gap
+    assert xplane._label_gap(host, (2.0, 3.5), prefer=both) == "oryx-seq:stepper.step"   # the host's turn
+    assert xplane._label_gap(host, (2.3, 2.5), prefer=both) == "oryx-seq:stepper.step.call"  # innermost of equals
+    assert xplane._label_gap(host, (7.0, 9.0), prefer=both) == "oryx-topk:batcher.fetch"  # no stepper region there
+    assert xplane._label_gap(host, (1.0, 9.0), prefer=("batcher.", "stepper.")) == "oryx-topk:batcher.fetch"
+    assert xplane._label_gap(host, (1.0, 9.0), prefer=("batcher.",)) == xplane._label_gap(host, (1.0, 9.0), prefer="batcher.")
+    assert xplane._label_gap(host, (1.0, 9.0), prefer=()) == xplane._label_gap(host, (1.0, 9.0)) == "oryx-topk:batcher.fetch"
 
 
 # -- BENCHMARK.json against the contract and the files ------------------------------
 
 def test_benchmark_json_resolves_to_files_and_metrics_move_what_cells_report():
-    assert set(BENCH) == {
-        "command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer",
-    }
-    configs = {c["name"]: c for c in BENCH["configs"]}
-    end = {m["name"]: m for m in BENCH["end_to_end"]}
-    assert "setup_s" in end
-    for c in BENCH["configs"]:
-        assert any(c["file"].startswith(p + "/") for p in PATHS)
-        on_disk = json.loads((REPO / c["file"]).read_text())
-        assert find(PATHS, f"kinds/{on_disk['kind'].replace('-', '_')}.py").is_file()
-        assert any(w["config"] == c["name"] for w in BENCH["workloads"])
-        assert not [k for k in c["reduced"] if k.endswith(("_dim", "_rank")) or k == "features"]
-    assert len(BENCH["workloads"]) == len({(w["config"], w["traffic"]) for w in BENCH["workloads"]})
-    for w in BENCH["workloads"]:
-        assert w["name"] == f"{w['config']}.{w['traffic']}" and w["config"] in configs
-        assert find(PATHS, f"configs/{w['config']}.json") == REPO / configs[w["config"]]["file"]
-        assert find(PATHS, f"traffic/{w['traffic']}.json").is_file()
-        assert w["chips"] in (1, 4) and 0 < len(w["why"]) <= 200
-        reported = {m["name"] for m in metrics_of(BENCH["end_to_end"], w["name"])}
-        assert "setup_s" in reported and len(reported) >= 2
-        layer = metrics_of(BENCH["per_layer"], w["name"])
-        assert layer
-        for m in layer:
-            assert m["moves"] in reported, (m["name"], w["name"])
-    for m in BENCH["per_layer"]:
-        assert find(PATHS, f"metrics/{m['name']}.py").is_file()
-        assert set(m) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
-    for m in BENCH["end_to_end"]:
-        assert m["source"] in ("host_clock", "device_trace") and 0.01 <= m["bound"] <= 0.1
+    listed.structure_holds(BENCH)
 
 
 def test_names_and_units_use_only_the_allowed_characters():
-    names = [x["name"] for key in ("configs", "workloads", "end_to_end", "per_layer") for x in BENCH[key]]
-    names += [w["traffic"] for w in BENCH["workloads"]]
-    for key in ("configs", "workloads"):
-        group = [x["name"] for x in BENCH[key]]
-        assert len(group) == len(set(group))
-    metrics = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]]
-    assert len(metrics) == len(set(metrics))
-    for n in names:
-        assert NAME.match(n), n
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
-        assert UNIT.match(m["unit"]), m
-        assert m["better"] in ("lower", "higher")
-        assert m["source"] in ("device_trace", "program_span", "program_counter", "host_clock")
-    for p in PATHS:
-        for f in (REPO / p).rglob("*"):
-            if f.is_file() and "__pycache__" not in f.parts:
-                assert re.fullmatch(r"[A-Za-z0-9_.\-/]+", str(f.relative_to(REPO))), f
+    listed.names_hold(BENCH)
     assert len((REPO / "BENCHMARK.json").read_bytes()) <= 64 * 1024
-    assert isinstance(BENCH["run_seconds"], int) and 1 <= BENCH["run_seconds"] <= 51
+
+
+def _appended(tmp_path):
+    """BENCHMARK.json as the next `model_config` PR will leave it: a
+    configuration, a cell and a per-layer metric APPENDED, the cell appended
+    to the five stepper metrics' lists, and their files (configuration,
+    traffic, reader, case) in a directory appended to `paths`."""
+    bench = json.loads(json.dumps(BENCH))
+    config, traffic, metric = "synthetic-enc-2l", "synthetic-mix", "synthetic_step_ms"
+    cell = f"{config}.{traffic}"
+    for sub in ("configs", "traffic", "metrics", "cases"):
+        (tmp_path / sub).mkdir()
+    (tmp_path / "configs" / f"{config}.json").write_text(json.dumps({"kind": "joyai-serving", "num_hidden_layers": 2}))
+    (tmp_path / "traffic" / f"{traffic}.json").write_text(json.dumps({"kind": "joyai-serving", "rate_per_s": 30}))
+    (tmp_path / "metrics" / f"{metric}.py").write_text(
+        "def read(src):\n    steps = src.get('steps')\n    return steps['ms'] if steps else None\n"
+    )
+    (tmp_path / "cases" / f"{metric}.json").write_text(json.dumps({"src": {"steps": {"ms": 2.5}}, "expect": 2.5}))
+    bench["paths"].append(str(tmp_path))
+    bench["configs"].append({
+        "name": config, "source": "https://example.org/config.json", "file": f"{tmp_path}/configs/{config}.json",
+        "reduced": ["num_hidden_layers"], "why": "a synthetic configuration, appended",
+    })
+    bench["workloads"].append({"name": cell, "config": config, "traffic": traffic, "chips": 1, "why": "appended"})
+    bench["per_layer"].append({
+        "name": metric, "unit": "ms", "better": "lower", "source": "device_trace",
+        "layer": "batched encoder step", "moves": "p50_ms", "workloads": [cell],
+    })
+    for m in bench["per_layer"]:
+        if m["name"] in listed.STEPPER:
+            m["workloads"].append(cell)
+    return bench, cell, metric
+
+
+def test_appended_entries_break_no_assertion_over_the_lists(tmp_path):
+    """Every assertion of tests/benchmarks/ that reads BENCHMARK.json's lists,
+    over the file with the next PR's entries appended at their ends: the ones
+    `test_benchmark.py` and the three encoder kinds' test files call."""
+    bench, cell, metric = _appended(tmp_path)
+    listed.structure_holds(bench)
+    listed.names_hold(bench)
+    _reader_reads_its_case(bench["paths"], metric)
+    # the appended cell reads the shared layers' metrics, the stepper's five and its own
+    mine = {m["name"] for m in metrics_of(bench["per_layer"], cell)}
+    shared = {m["name"] for m in bench["per_layer"] if "workloads" not in m}
+    assert len(shared) == 29 and mine == shared | listed.STEPPER | {metric}
+    # each earlier PR's entries are where they were: present, once, in order, contiguous
+    for args in listed.ADDED:
+        listed.entries_of(bench, *args)
+        assert listed.entries_of(BENCH, *args) == listed.entries_of(bench, *args)
+    # and what a traced CPU run of an old cell has to print did not move
+    for w in BENCH["workloads"]:
+        assert listed.cpu_names(bench, w["name"]) == listed.cpu_names(BENCH, w["name"])
+    assert listed.cpu_names(bench, "als-tiny.tiny") == listed.cpu_names(BENCH, "als-tiny.tiny")
 
 
 # -- the CPU rehearsal: run.py end to end, the generator a real subprocess -----------
@@ -294,14 +315,9 @@ def test_cpu_rehearsal_of_a_test_only_cell_added_as_files(trace, tmp_path):
     assert set(last["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
     if trace:
         # host spans and counters are read; a CPU trace has no device plane,
-        # so the device metrics are left out and not written as zeros
-        # (a 2 s window of 40 requests may see no collection start: then no gc_pause_share)
-        assert set(last["metrics"]) | {"gc_pause_share"} == {
-            "gen_late_p95_ms", "latency_p95_ms", "frontend_ms_per_req", "post_ms_per_req",
-            "batcher_wait_ms_per_req", "dispatch_rows", "dispatch_shapes",
-            "launch_host_ms", "distribute_ms", "post_handoff_ms_per_req",
-            "post_rerank_ms_per_req", "post_render_ms_per_req", "gc_pause_share",
-        }
+        # so the device metrics are left out and not written as zeros: the one
+        # rule of listed.cpu_names, over BENCHMARK.json as it stands
+        listed.printed_on_the_cpu_holds(BENCH, "als-tiny.tiny", last["metrics"])
         parts = sum(
             m["value"] for n, m in last["metrics"].items()
             if n in ("post_handoff_ms_per_req", "post_rerank_ms_per_req", "post_render_ms_per_req")
@@ -310,8 +326,8 @@ def test_cpu_rehearsal_of_a_test_only_cell_added_as_files(trace, tmp_path):
         assert "residue" in proc.stderr
     else:
         assert set(last["metrics"]) == {"p50_ms", "setup_s"}
-    for m in last["metrics"].values():
-        assert set(m) == {"value", "unit"} and m["value"] > 0
+        for m in last["metrics"].values():
+            assert set(m) == {"value", "unit"} and m["value"] > 0
     notes = [json.loads(ln)["info"] for ln in lines[:-1]]
     assert any("peak_bytes_in_use" in n and "compile_cache" in n for n in notes)
     assert any("in_flight_at_window_end" in n and n["generator_processes"] == 1 for n in notes)
@@ -381,6 +397,18 @@ def _scores_in_bfloat16(monkeypatch):
     monkeypatch.setattr(als_app, "_rerank_exact", low)
 
 
+def _scan_fails_over_to_the_host(monkeypatch):
+    """Every device dispatch raises: the batcher serves its group exactly on
+    the host (`host_topk`), so the answers are the reference's own and only
+    the count of requests that never saw the device scan can fail the run."""
+    from oryx_tpu.ops import als as ops_als
+
+    def broken(*_args, **_kw):
+        raise RuntimeError("the device scan is broken underneath")
+
+    monkeypatch.setattr(ops_als, "topk_dot_batch", broken)
+
+
 @pytest.mark.parametrize(
     "fault,failing",
     [
@@ -388,8 +416,9 @@ def _scores_in_bfloat16(monkeypatch):
         (_scan_of_the_wrong_rows, {"least_overlap", "worst_gap_over_slack"}),
         (_scan_in_int8, {"dispatches_not_exact"}),
         (_scores_in_bfloat16, {"worst_score_rel"}),
+        (_scan_fails_over_to_the_host, {"host_fallbacks"}),
     ],
-    ids=["sound", "scan_of_the_wrong_rows", "scan_in_int8", "scores_in_bfloat16"],
+    ids=["sound", "scan_of_the_wrong_rows", "scan_in_int8", "scores_in_bfloat16", "scan_fails_over_to_the_host"],
 )
 def test_a_fault_under_the_timed_path_reads_not_correct(fault, failing, tmp_path, monkeypatch):
     """The kind's whole run in this process (run.py's look for a chip is

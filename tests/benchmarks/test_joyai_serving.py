@@ -15,6 +15,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+import listed
 from benchmarks import seqgen
 from benchmarks.kinds import joyai_serving
 from benchmarks.run import find
@@ -92,7 +93,8 @@ def test_the_cell_runs_next4s_sessions_at_a_rate_on_a_rung_of_five():
 # -- the configuration file ---------------------------------------------------------
 
 def test_the_configuration_holds_every_published_number_and_cuts_depth_alone():
-    entry = [c for c in BENCH["configs"] if c["name"] == "joyai-flash-5l"][0]
+    # PR 41's entries: present, once, its eleven metrics in order and together (never "last")
+    entry, cell, mine = listed.entries_of(BENCH, *listed.ADDED[2])
     assert [k for k, v in CATALOG.items() if REAL.get(k, "absent") != v] == entry["reduced"] == ["num_hidden_layers"]
     assert REAL["published"] == {"num_hidden_layers": 40} and REAL["num_hidden_layers"] == 5
     assert REAL["kind"] == "joyai-serving" and entry["file"] == "benchmarks/configs/joyai-flash-5l.json"
@@ -114,13 +116,12 @@ def test_the_configuration_holds_every_published_number_and_cuts_depth_alone():
     assert rows == 163_840
     held = 2 * (joyai.param_count(cfg) + rows * 2048)
     assert held == pytest.approx(11.26e9, rel=2e-3) and 0.66 < held / (15.75 * 2**30) < 0.68
-    cell = [w for w in BENCH["workloads"] if w["name"] == "joyai-flash-5l.next4moe"][0]
-    assert cell["chips"] == 1 and len(cell["why"]) <= 200 and cell["traffic"] == "next4moe"
-    mine = [m for m in BENCH["per_layer"] if m["name"].startswith(("joyai_", "mla_"))]
-    assert len(mine) == 11
-    assert all(m["workloads"] == ["joyai-flash-5l.next4moe"] and m["moves"] == "p50_ms" for m in mine)
-    # additions stand at the end of their lists
-    assert BENCH["configs"][-1] is entry and BENCH["workloads"][-1] is cell and BENCH["per_layer"][-11:] == mine
+    assert cell["traffic"] == "next4moe"
+    assert [m["name"] for m in mine] == [
+        "joyai_encode_ms_per_req", "joyai_step_ms", "joyai_step_mfu", "joyai_step_hbm_roofline", "joyai_step_tokens",
+        "joyai_pad_share", "joyai_moe_roofline", "joyai_moe_load_peak", "joyai_experts_touched", "mla_attn_share",
+        "mla_attn_roofline",
+    ]
 
 
 # -- the operations and bytes of the algorithm ------------------------------------------
@@ -290,9 +291,9 @@ def test_a_fault_under_the_timed_path_reads_not_correct(control, dtype, failing,
 def test_cpu_rehearsal_prints_the_shared_layers_metrics(tmp_path):
     """run.py end to end on the test-only cell joyai-tiny.next-moe-tiny, found
     by name alone: the counters' and spans' metrics of the shared layers print
-    (the nineteen readers without a `workloads` list, less the device's: a CPU
-    trace has no device plane), and none of the kind's own (the cell is on no
-    metric's list)."""
+    (the readers without a `workloads` list, less the device's: a CPU trace has
+    no device plane; `listed.cpu_names` is the rule), and none of the kind's
+    own (the cell is on no metric's list)."""
     env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
     proc = subprocess.run(
         [sys.executable, str(REPO / "benchmarks" / "run.py"), "--workload", "joyai-tiny.next-moe-tiny",
@@ -302,12 +303,7 @@ def test_cpu_rehearsal_prints_the_shared_layers_metrics(tmp_path):
     assert proc.returncode == 0, proc.stderr[-3000:]
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["device"]["platform"] == "cpu" and last["failed"] == 0 and last["attempted"] == 20
-    assert set(last["metrics"]) | {"gc_pause_share"} == {
-        "gen_late_p95_ms", "latency_p95_ms", "frontend_ms_per_req", "post_ms_per_req",
-        "batcher_wait_ms_per_req", "dispatch_rows", "dispatch_shapes", "launch_host_ms",
-        "distribute_ms", "post_handoff_ms_per_req", "post_rerank_ms_per_req",
-        "post_render_ms_per_req", "gc_pause_share",
-    }
+    listed.printed_on_the_cpu_holds(BENCH, "joyai-tiny.next-moe-tiny", last["metrics"])
     assert last["compared"]["steps_per_basket"] == [4.0, "==", 4]
     assert last["compared"]["dropped_pairs"] == [0.0, "==", 0]
     assert proc.stderr.strip().splitlines()[-1].startswith("run.py: compared ")

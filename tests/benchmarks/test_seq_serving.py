@@ -14,6 +14,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+import listed
 from benchmarks import seqgen, seqtrace
 from benchmarks.kinds import seq_serving
 from benchmarks.run import find
@@ -93,7 +94,8 @@ def test_the_configuration_holds_the_published_widths_and_the_cut():
         "num_hidden_layers": 48, "num_key_value_heads": 4, "rms_norm_eps": 1e-06,
         "rope_theta": 1000000, "vocab_size": 151936,
     }
-    entry = [c for c in BENCH["configs"] if c["name"] == "sdar-30b-a3b-6l"][0]
+    # PR 33's entries: present, once, its seven metrics together (wherever in the lists)
+    entry, _cell, _mine = listed.entries_of(BENCH, *listed.ADDED[0])
     differs = [k for k, v in catalog.items() if REAL.get(k) != v]
     assert differs == entry["reduced"] == ["num_hidden_layers"]
     assert REAL["num_hidden_layers"] == 6 and REAL["published"]["num_hidden_layers"] == 48
@@ -341,16 +343,9 @@ def test_cpu_rehearsal_prints_the_kinds_metrics_and_no_others(tmp_path):
     assert proc.returncode == 0, proc.stderr[-3000:]
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["device"]["platform"] == "cpu" and last["failed"] == 0 and last["attempted"] == 20
-    assert set(last["metrics"]) | {"gc_pause_share"} == {
-        "gen_late_p95_ms", "latency_p95_ms", "frontend_ms_per_req", "post_ms_per_req",
-        "batcher_wait_ms_per_req", "dispatch_rows", "dispatch_shapes", "launch_host_ms",
-        "distribute_ms", "post_handoff_ms_per_req", "post_rerank_ms_per_req",
-        "post_render_ms_per_req", "gc_pause_share",
-    } | (
-        # a cell of BENCHMARK.json would also print the kind's own; this one is
-        # on no metric's list, so it prints those without a list alone
-        set()
-    )
+    # a cell of BENCHMARK.json would also print the kind's own; this one is on no
+    # metric's list, so it prints those without a list alone (`listed.cpu_names`)
+    listed.printed_on_the_cpu_holds(BENCH, "sdar-tiny.basket-tiny", last["metrics"])
     assert proc.stderr.strip().splitlines()[-1].startswith("run.py: compared ")
 
 

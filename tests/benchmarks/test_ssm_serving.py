@@ -15,6 +15,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+import listed
 from benchmarks import seqgen
 from benchmarks.kinds import ssm_serving
 from benchmarks.run import find
@@ -80,7 +81,8 @@ def test_the_configuration_holds_every_published_number_and_cuts_nothing():
         "num_attention_heads": 20, "num_experts": 1, "num_experts_per_tok": 1, "num_hidden_layers": 28,
         "num_key_value_heads": 1, "num_logits_to_keep": 1, "rms_norm_eps": 1e-06, "vocab_size": 65536,
     }
-    entry = [c for c in BENCH["configs"] if c["name"] == "jamba2-3b"][0]
+    # PR 37's entries: present, once, its eight metrics together (wherever in the lists)
+    entry, _cell, _mine = listed.entries_of(BENCH, *listed.ADDED[1])
     assert [k for k, v in catalog.items() if REAL.get(k) != v] == entry["reduced"] == []
     assert REAL["published"] == {} and REAL["kind"] == "ssm-serving"
     assert REAL["tie_word_embeddings"] is True and REAL["mamba_conv_bias"] is True
@@ -95,10 +97,6 @@ def test_the_configuration_holds_every_published_number_and_cuts_nothing():
     assert cfg.layers == 28 and cfg.d_inner == 5120 and cfg.head_dim == 128 and cfg.basket == 4
     held = 2 * jamba.param_count(cfg)
     assert 0.37 < held / 16e9 < 0.39  # 6.06 GB of the chip's 16
-    cell = [w for w in BENCH["workloads"] if w["name"] == "jamba2-3b.next4"][0]
-    assert cell["chips"] == 1 and len(cell["why"]) <= 200
-    mine = [m for m in BENCH["per_layer"] if m["name"].startswith("ssm_")]
-    assert len(mine) == 8 and all(m["workloads"] == ["jamba2-3b.next4"] and m["moves"] == "p50_ms" for m in mine)
 
 
 # -- the operations and bytes of the algorithm ------------------------------------------
@@ -302,12 +300,7 @@ def test_cpu_rehearsal_prints_the_shared_layers_metrics(tmp_path):
     assert proc.returncode == 0, proc.stderr[-3000:]
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["device"]["platform"] == "cpu" and last["failed"] == 0 and last["attempted"] == 20
-    assert set(last["metrics"]) | {"gc_pause_share"} == {
-        "gen_late_p95_ms", "latency_p95_ms", "frontend_ms_per_req", "post_ms_per_req",
-        "batcher_wait_ms_per_req", "dispatch_rows", "dispatch_shapes", "launch_host_ms",
-        "distribute_ms", "post_handoff_ms_per_req", "post_rerank_ms_per_req",
-        "post_render_ms_per_req", "gc_pause_share",
-    }
+    listed.printed_on_the_cpu_holds(BENCH, "jamba-tiny.next-tiny", last["metrics"])
     assert last["compared"]["steps_per_basket"] == [4.0, "==", 4]
     assert proc.stderr.strip().splitlines()[-1].startswith("run.py: compared ")
 
