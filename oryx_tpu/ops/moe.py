@@ -13,13 +13,20 @@ it), never an option of the user; everything after the routing is one path.
 No token is dropped, whatever the load of an expert: the (token, expert)
 pairs are sorted by expert and each expert multiplies the contiguous rows
 that chose it (a grouped product), so an expert nobody chose costs no
-FLOP and, on the grouped kernel, no read of its weights. One chip holds
-every expert: the layer is given no share and nothing stands in for
-absent chips.
+FLOP and, on the grouped kernel, no read of its weights.
+
+The layer may be told which experts it HOLDS (`held`: the first and how
+many, the chip's share of a layer whose experts lie on several chips). The
+router still scores, selects and normalises over every expert of the
+model; the layer computes the part of the result its own experts give, and
+a pair whose expert is held elsewhere goes to no group, exactly as a
+padding token's does. Nothing stands in for the absent chips or their
+exchange: what they would have added is left out.
 
 `moe_apply` also counts, on the device and in the step's own dispatch,
 what the serving counters report (serving/stepper.py, oryx_moe_*): the
-pairs routed, the experts touched and the busiest expert's tokens.
+pairs that reached an expert here, the experts touched, the busiest
+expert's tokens and, for a layer told its share, the pairs sent elsewhere.
 """
 
 from __future__ import annotations
@@ -79,25 +86,36 @@ def _grouped(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array) -> jax.Array:
 
 def moe_apply(
     u: jax.Array, wr: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
-    k: int, live: jax.Array | None = None, **rule,
+    k: int, live: jax.Array | None = None, held: tuple[int, int] | None = None, **rule,
 ):
-    """u [N,H] float32 (normalised) -> (y [N,H] float32, counts int32[3]).
-    `rule`: the model's routing rule where it is not the softmax one
-    (`route`'s `scoring`, `bias`, `scale`).
+    """u [N,H] float32 (normalised) -> (y [N,H] float32, counts int32[3],
+    or int32[4] for a layer told its share). `rule`: the model's routing
+    rule where it is not the softmax one (`route`'s `scoring`, `bias`,
+    `scale`).
 
     wr [H,E], wg/wu [E,H,F], wd [E,F,H] in the weights' dtype; the
     activations enter each product in that dtype and accumulate in
     float32. `live` [N] bool marks the real tokens of a padded step: a
     padding token's pairs are sent to no expert (they sort behind every
-    group) and count nowhere. counts = (pairs routed, experts touched,
-    the busiest expert's pairs)."""
+    group) and count nowhere. `held` = (first, count): wg/wu/wd are those
+    `count` experts' alone ([count, ...]) while wr keeps every expert's
+    column; a real token's pair for an expert outside them sorts behind
+    every group too, reads no weight and adds nothing. counts = (pairs
+    that reached an expert here, experts touched, the busiest expert's
+    pairs[, pairs sent to experts held elsewhere])."""
     n, h = u.shape
-    n_experts = wr.shape[1]
     w, e = route(u, wr, k, **rule)
     if live is None:
         live = jnp.ones((n,), dtype=bool)
+    here = live[:, None]
+    if held is None:
+        n_experts = wr.shape[1]
+    else:
+        first, n_experts = held
+        e = e - first
+        here = here & (e >= 0) & (e < n_experts)
     pairs = n * k
-    flat_e = jnp.where(live[:, None], e, n_experts).reshape(pairs)
+    flat_e = jnp.where(here, e, n_experts).reshape(pairs)
     order = jnp.argsort(flat_e, stable=True)
     token = (order // k).astype(jnp.int32)
     sizes = jnp.bincount(flat_e, length=n_experts + 1)[:n_experts].astype(jnp.int32)
@@ -114,19 +132,24 @@ def moe_apply(
     # tokens' pairs): whatever those hold is dropped, not weighted by zero
     inv = jnp.argsort(order).astype(jnp.int32)
     out = out[inv].reshape(n, k, h)
-    w = jnp.where(live[:, None], w, 0.0)
+    w = jnp.where(here, w, 0.0)
     y = jnp.sum(jnp.where(w[:, :, None] > 0, out, 0.0) * w[:, :, None], axis=1)
-    counts = jnp.stack([
-        jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes),
-    ]).astype(jnp.int32)
-    return y, counts
+    counts = [jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes)]
+    if held is not None:
+        counts.append(jnp.sum(live[:, None] & ~here))
+    return y, jnp.stack(counts).astype(jnp.int32)
 
 
-def moe_reference(u, wr, wg, wu, wd, k: int, scoring: str = "softmax", bias=None, scale: float = 1.0):
+def moe_reference(
+    u, wr, wg, wu, wd, k: int, scoring: str = "softmax", bias=None, scale: float = 1.0,
+    held: tuple[int, int] | None = None,
+):
     """The plain form, float32 throughout: every expert in turn on every
     token, weighted by the token's routing weight for it (zero unless it
-    is one of the token's k). For tests and the plain reference; run it
-    under `jax.default_matmul_precision("highest")`."""
+    is one of the token's k). `held` = (first, count): wg/wu/wd are those
+    experts' alone and the others' part of the result is left out; the
+    routing is over every expert all the same. For tests and the plain
+    reference; run it under `jax.default_matmul_precision("highest")`."""
     f32 = jnp.float32
     u = u.astype(f32)
     if scoring == "softmax":
@@ -139,6 +162,8 @@ def moe_reference(u, wr, wg, wu, wd, k: int, scoring: str = "softmax", bias=None
         w = jnp.take_along_axis(p, e, axis=-1)
         w = scale * w / jnp.sum(w, axis=-1, keepdims=True)
     dense_w = jnp.zeros(p.shape, f32).at[jnp.arange(u.shape[0])[:, None], e].add(w)
+    if held is not None:
+        dense_w = dense_w[:, held[0]:held[0] + held[1]]
 
     def one_expert(acc, xs):
         g_w, u_w, d_w, col = xs

@@ -430,4 +430,8 @@ def encoder_for(name: str, ext):
         from oryx_tpu.ops.joyai import JoyaiEncoder
 
         return JoyaiEncoder.from_extensions(ext)
+    if name == "trinity":
+        from oryx_tpu.ops.trinity import TrinityEncoder
+
+        return TrinityEncoder.from_extensions(ext)
     raise ValueError(f"unknown seq encoder {name!r}")

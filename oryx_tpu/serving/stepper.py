@@ -38,8 +38,9 @@ product is its own).
 
 Counters (docs/observability.md): steps and their real and padded tokens by
 kind, blocks, denoising steps, slots in use; the expert layer's routed
-pairs, experts touched and busiest expert's pairs are counted ON THE DEVICE
-by the step itself (ops/moe.py) and fetched with its result.
+pairs, experts touched, busiest expert's pairs and (for a layer that holds a
+share of its experts) the pairs sent elsewhere are counted ON THE DEVICE by
+the step itself (ops/moe.py) and fetched with its result.
 """
 
 from __future__ import annotations
@@ -140,12 +141,18 @@ class _Metrics:
         self.state_bytes = reg.gauge(
             "oryx_seq_slot_state_bytes",
             "Bytes of the seq stepper's cache slots on the device, by kind of state "
-            "(recurrent: a fixed size a slot | kv, latent, rope_key: a row a position)",
+            "(recurrent: a fixed size a slot | kv, latent, rope_key, full_kv: a row a position | "
+            "window_kv: a row a position up to the attention's window)",
             labeled=True,
         )
         self.routed = reg.counter(
             "oryx_moe_routed_total",
-            "(token, expert) pairs the expert layers routed, counted on the device",
+            "(token, expert) pairs the expert layers routed to an expert they hold, counted on the device",
+        )
+        self.elsewhere = reg.counter(
+            "oryx_moe_routed_elsewhere_total",
+            "(token, expert) pairs routed to an expert held on another chip (a layer told "
+            "its share computes none of them), counted on the device",
         )
         self.touched = reg.counter(
             "oryx_moe_experts_touched_total",
@@ -403,11 +410,12 @@ class SeqStepper:
         tr = _TRACER
         try:
             with tr.region("stepper.fetch", cycle=n):
-                counts = np.zeros((3,), dtype=np.int64)
-                if counts_p is not None:
-                    counts += np.asarray(counts_p)
-                if out is not None and "counts" in out:
-                    counts += np.asarray(out["counts"])
+                # (routed here, touched, busiest[, routed elsewhere]) of ops/moe.py
+                counts = np.zeros((4,), dtype=np.int64)
+                for c in (counts_p, out.get("counts") if out is not None else None):
+                    if c is not None:
+                        c = np.asarray(c)
+                        counts[: len(c)] += c
                 hidden = np.asarray(hidden_dev) if not enc.steps else None
                 if finished:
                     z, row, step = (np.asarray(out[k]) for k in ("z", "row", "step"))
@@ -417,6 +425,7 @@ class SeqStepper:
                     self._m.routed.inc(float(counts[0]))
                     self._m.touched.inc(float(counts[1]))
                     self._m.busiest.inc(float(counts[2]))
+                    self._m.elsewhere.inc(float(counts[3]))
                 for i, req in enumerate(admitted):
                     # its prefill (and its first step) ran in this cycle
                     req.t_first = t_fetch
