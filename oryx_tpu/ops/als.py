@@ -1814,29 +1814,55 @@ def topk_path(y, k: int, recall: float = 1.0) -> str:
     return "pallas" if fused else "xla"
 
 
+# the paths whose jitted call takes the queries in the matrix's dtype
+_SCORED_IN_VIEW_DTYPE = ("pallas", "approx", "xla")
+
+
+def query_dtype(y, k: int, recall: float = 1.0):
+    """The dtype topk_dot_batch's jitted call takes its queries in: the
+    matrix's on the paths that score in it (the bf16 serving view;
+    accumulation is f32 either way), float32 on the int8 paths (their
+    call quantises the queries itself) and for a sharded or chunked
+    matrix (each shard and chunk re-enters with a dtype of its own). A
+    caller that forms its query block on the host forms it in this."""
+    if topk_path(y, k, recall) in _SCORED_IN_VIEW_DTYPE:
+        return np.dtype(y.dtype)
+    return np.dtype(np.float32)
+
+
 def stage_topk_operands(
     xs, y, *, k: int, recall: float = 1.0, rows=None, n_valid=None
 ):
-    """The eager part of topk_dot_batch: (xs, rows) as its jitted call
-    takes them. The queries go to the device, zero-padded to a lane-padded
-    view's width and, where the path scores in the matrix's dtype (the
-    bf16 serving view; accumulation is f32 either way), cast to it; the
-    fused kernel's two counts, the real query rows and the view's valid
-    item rows, become the ONE int32[2] array it prefetches (one upload
-    for both; returned in `rows`' place, and topk_dot_batch takes it
-    there). Every other path ignores both counts and gets `rows` back as
-    it came. topk_dot_batch does the queries' part to whatever it is
-    handed (the kernel's wrapper uploads the counts) and finds nothing
-    left to do on operands that are staged already, so a caller that
-    times the upload apart from the call (serving/batcher.py) stages
-    first."""
-    if not isinstance(xs, jax.Array):
-        xs = jnp.asarray(xs)
+    """What topk_dot_batch does to its operands before its jitted call:
+    (xs, rows) as that call takes them. Nothing is uploaded or computed on
+    the device for operands that are on the host: they stay numpy arrays
+    and ride the jitted call's own argument path (serving/batcher.py forms
+    its block at the view's width and in query_dtype already, so all that
+    is left to do for it is the counts' array). Queries at the published
+    width are zero-padded to a lane-padded view's and, where the path
+    scores in the matrix's dtype, cast to it (a host block on the host,
+    round-to-nearest-even as XLA's convert; a device block on the device);
+    the fused kernel's two counts, the real query rows and the view's
+    valid item rows, become the ONE int32[2] array it prefetches
+    (returned in `rows`' place, and topk_dot_batch takes it there). Every
+    other path ignores both counts and gets `rows` back as it came. A host
+    block for a sharded or chunked matrix is uploaded here, once, so that
+    every shard and chunk re-enters with a device array. topk_dot_batch
+    does the queries' part to whatever it is handed (the kernel's wrapper
+    stages the counts) and finds nothing left to do on operands that are
+    staged already, so a caller that times the staging apart from the call
+    (serving/batcher.py) stages first."""
+    on_host = not isinstance(xs, jax.Array)
+    if on_host:
+        xs = np.asarray(xs)
     if xs.shape[1] < y.shape[1]:
-        xs = jnp.pad(xs, ((0, 0), (0, y.shape[1] - xs.shape[1])))
+        lanes = ((0, 0), (0, y.shape[1] - xs.shape[1]))
+        xs = np.pad(xs, lanes) if on_host else jnp.pad(xs, lanes)
     path = topk_path(y, k, recall)
-    if path in ("pallas", "approx", "xla") and xs.dtype != y.dtype:
-        xs = jnp.asarray(xs, dtype=y.dtype)
+    if path in _SCORED_IN_VIEW_DTYPE and xs.dtype != y.dtype:
+        xs = xs.astype(y.dtype) if on_host else jnp.asarray(xs, dtype=y.dtype)
+    if on_host and path in ("sharded", "chunked"):
+        xs = jnp.asarray(xs)
     if path in ("pallas", "pallas-int8") and (
         rows is not None or n_valid is not None
     ):
@@ -1894,7 +1920,7 @@ def topk_dot_batch(
     it (stage_topk_operands), once for every path — zeros change no dot
     product."""
     # `rows` goes on as it was given: each shard and chunk re-enters here
-    # with the same count, and the fused kernel's wrapper uploads the counts
+    # with the same count, and the fused kernel's wrapper stages the counts
     xs, _ = stage_topk_operands(xs, y, k=k, recall=recall)
     path = topk_path(y, k, recall)
     if path in ("pallas", "pallas-int8"):

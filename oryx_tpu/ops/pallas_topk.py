@@ -249,7 +249,7 @@ kernel's item count was the operand's static row count, so a fifth of
 every pass streamed, multiplied and gated rows of zeros that belong to
 no item (they scored 0.0 and the serving model dropped them on the
 host). The count of valid item rows now rides beside `rows` in ONE
-scalar-prefetch operand (int32[2], `stage_counts`: one upload for both,
+scalar-prefetch operand (int32[2], `stage_counts`: one array for both,
 traced, so every catalog size that fits a view shares its compiled
 program). With live_blocks = ceil(n_valid / block_i): a grid step at or
 past live_blocks starts no DMA, waits on none, runs no dot and no gate
@@ -808,11 +808,11 @@ def quantize_queries(xs):
 def stage_counts(rows, n_valid, n_queries: int, n_items: int):
     """The kernel's scalar-prefetch operand: int32[2], (real query rows,
     valid item rows), None meaning all `n_queries` and all `n_items`. Two
-    host numbers make ONE upload (the second never past `n_items`, where
-    a caller's unaligned matrix is padded); a count that is already on
-    the device (or traced) is stacked there, and the kernel reads no
-    further than its operand whatever it says. An int32[2] array given as
-    `rows` is such an operand staged earlier and is passed through."""
+    host numbers make ONE host array that rides the jitted call (the
+    second never past `n_items`, where a caller's unaligned matrix is
+    padded); a count already on the device (or traced) is stacked there,
+    and the kernel reads no further than its operand whatever it says. An
+    int32[2] array given as `rows` was staged earlier and passes through."""
     if getattr(rows, "shape", None) == (2,):
         return rows
     rows = n_queries if rows is None else rows
@@ -822,7 +822,7 @@ def stage_counts(rows, n_valid, n_queries: int, n_items: int):
             jnp.asarray(rows, dtype=jnp.int32).reshape(()),
             jnp.asarray(n_valid, dtype=jnp.int32).reshape(()),
         ])
-    return jnp.asarray(np.array([rows, min(n_valid, n_items)], dtype=np.int32))
+    return np.array([rows, min(n_valid, n_items)], dtype=np.int32)
 
 
 @partial(
@@ -986,7 +986,7 @@ def topk_dot_batch_pallas(
     ceil(n_valid / block_i) item blocks, and the rows of the last of
     them at or past the count are masked out before the gate. n_valid = 0
     returns the filler in every row. `rows` may also be the int32[2]
-    array (rows, n_valid) that ops.als.stage_topk_operands uploaded
+    array (rows, n_valid) that ops.als.stage_topk_operands staged
     earlier; n_valid is then left None.
 
     block_b/block_i default to the block rule (`tuned_blocks`): the

@@ -873,8 +873,13 @@ class TopKBatcher:
                                     "batch_wait", t0 - t_pick, start=t_pick
                                 )
                         t_pad = time.monotonic()
-                        # at the view's width: its pad lanes stay zero
-                        xs = np.zeros((padded, y.shape[1]), dtype=np.float32)
+                        # at the view's width (its pad lanes stay zero)
+                        # and in the dtype the path scores in: the real
+                        # rows alone are cast, here, as they are copied in
+                        xs = np.zeros(
+                            (padded, y.shape[1]),
+                            dtype=als.query_dtype(y, kb, recall),
+                        )
                         for i, p in enumerate(group):
                             xs[i, :features] = p.vec
                         pad_s = time.monotonic() - t_pad
@@ -906,14 +911,19 @@ class TopKBatcher:
                         with tr.region("batcher.issue.upload"):
                             # whatever topk_dot_batch would do to its
                             # operands before its jitted call, done here so
-                            # that the two are timed apart: the queries'
-                            # upload and cast, and the two counts' upload
-                            # as one array
+                            # that the two are timed apart. The block was
+                            # formed as the call takes it and nothing is
+                            # uploaded for it: what runs here is the two
+                            # counts' host array (and, for a sharded or
+                            # chunked matrix, the block's one upload before
+                            # the fan-out)
                             xd, rows_d = als.stage_topk_operands(
                                 xs, y, k=kb, recall=recall, rows=b,
                                 n_valid=int(n_rows),
                             )
                         with tr.region("batcher.issue.call"):
+                            # the block and the counts ride the jitted
+                            # call as numpy operands: it transfers them.
                             # chunks: the fused kernel's counts (chunks
                             # fired, walked, tiles sorted, chunks inserted),
                             # None on every other path.
@@ -1228,9 +1238,7 @@ class TopKBatcher:
                 from oryx_tpu.ops.als import topk_dot_batch
 
                 z = np.zeros((1, y.shape[1]), dtype=np.float32)
-                import jax.numpy as jnp
-
-                vals, idx = topk_dot_batch(jnp.asarray(z), y, k=1)
+                vals, idx = topk_dot_batch(z, y, k=1)
                 np.asarray(idx)
                 ok = True
             except Exception:

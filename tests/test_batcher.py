@@ -209,7 +209,12 @@ def test_fused_dispatch_walks_the_views_valid_item_blocks_alone(
     dispatched with its largest `valid_rows`, the count reaches the kernel
     beside `rows` as ONE staged int32[2] array, the kernel walks the valid
     item blocks alone, and the record, the counters and the gauges say so
-    (ISSUE 40)."""
+    (ISSUE 40). What the launch hands `topk_dot_batch` is on the HOST, the
+    block in the view's dtype and the counts an `np.int32[2]`, and nothing
+    is uploaded or computed on the device before that call: the operands
+    ride the jitted call (ISSUE 45)."""
+    import jax
+
     from oryx_tpu.common.metrics import get_registry
     from oryx_tpu.common.perfstats import get_perfstats
     from oryx_tpu.ops import als, pallas_topk
@@ -222,7 +227,8 @@ def test_fused_dispatch_walks_the_views_valid_item_blocks_alone(
     view = jnp.asarray(host, dtype=jnp.bfloat16)
     assert pallas_topk.view_shape(capacity, feats, view.dtype) == view.shape
 
-    staged, calls = [], []
+    staged, calls, eager = [], [], []
+    before_the_call = [False]
     real_stage = pallas_topk.stage_counts
 
     def stage_counts(rows, n_valid, n_queries, n_items):
@@ -230,14 +236,24 @@ def test_fused_dispatch_walks_the_views_valid_item_blocks_alone(
         return real_stage(rows, n_valid, n_queries, n_items)
 
     def fused(xs, y, *, k, recall=1.0, counted=False, rows=None, **kw):
-        calls.append((rows, kw))
+        before_the_call[0] = False
+        calls.append((xs, rows, kw))
         return pallas_topk.topk_dot_batch_pallas(
             xs, y, k=k, interpret=True, counted=counted, rows=rows
         )
 
+    def spy(name, real):
+        def eager_op(*args, **kw):
+            if before_the_call[0]:
+                eager.append(name)
+            return real(*args, **kw)
+        return eager_op
+
     monkeypatch.setattr(als, "_on_tpu", lambda a: True)  # topk_path: "pallas"
     monkeypatch.setattr(pallas_topk, "stage_counts", stage_counts)
     monkeypatch.setattr(als, "topk_dot_batch", fused)
+    for mod, name in [(jnp, "asarray"), (jnp, "pad"), (jnp, "stack"), (jax, "device_put")]:
+        monkeypatch.setattr(mod, name, spy(name, getattr(mod, name)))
     b = TopKBatcher()
     b.register_gauges()
     b._peak_flops = None  # _note_device has run: the platform below stays
@@ -248,14 +264,22 @@ def test_fused_dispatch_walks_the_views_valid_item_blocks_alone(
         vec[0] = 8.0
         reqs.append(_Pending(vec, 10, view, Future(), valid_rows=n))
     t_mark = time.monotonic()
+    before_the_call[0] = True
     for item in b._launch(reqs):
         b._resolve(item)
-    # one upload of both counts: staged once from two host numbers, the call
+    # one array for both counts: staged once from two host numbers, the call
     # is handed that very array and no count beside it, and the kernel's
     # wrapper passes it through
-    ((rows_d, extra),) = calls
-    assert rows_d.shape == (2,) and rows_d.dtype == jnp.int32 and not extra
-    assert [int(c) for c in rows_d] == [len(reqs), largest]
+    ((xs, rows_d, extra),) = calls
+    assert isinstance(rows_d, np.ndarray) and not extra
+    assert rows_d.dtype == np.int32 and rows_d.tolist() == [len(reqs), largest]
+    # the block: formed on the host at the view's width and in its dtype,
+    # the real rows the requests' vectors rounded to it, the rest zeros
+    assert isinstance(xs, np.ndarray) and not eager
+    assert (xs.shape, xs.dtype) == ((512, 128), view.dtype)
+    want = np.zeros((512, 128), dtype=np.float32)
+    want[: len(reqs), :feats] = [p.vec for p in reqs]
+    assert np.array_equal(xs.astype(np.float32), want)  # small integers: exact
     assert staged[0] == (len(reqs), largest, 512, capacity)
     assert [(s[0] is rows_d, s[1]) for s in staged[1:]] == [(True, None)]
     (rec,) = [r for r in get_perfstats().records_since(t_mark) if r.kind == "serving"]
@@ -279,6 +303,66 @@ def test_fused_dispatch_walks_the_views_valid_item_blocks_alone(
         vals, idx = p.future.result(timeout=5)
         d_vals, d_idx = _direct(p.vec, 10, jnp.asarray(host[:largest, :feats]))
         assert list(idx) == list(d_idx) and list(vals) == list(d_vals)
+
+
+@pytest.mark.parametrize(
+    "view_dtype, recall, block_dtype",
+    [
+        (jnp.float32, 1.0, np.float32),
+        (jnp.bfloat16, 1.0, jnp.bfloat16),
+        (jnp.bfloat16, 0.95, jnp.bfloat16),
+        ("int8", 1.0, np.float32),  # the call quantises a float32 block itself
+    ],
+    ids=["xla-f32", "xla-bf16", "approx-bf16", "xla-int8"],
+)
+def test_a_launch_hands_the_call_a_host_block_in_the_dtype_the_path_scores_in(
+    monkeypatch, view_dtype, recall, block_dtype
+):
+    """Off the fused kernel too: the block reaches `topk_dot_batch` as a
+    numpy array in the dtype its jitted call takes, `rows` as the host
+    number it was (no kernel, no counts' array), and the answers are those
+    of the parent's staging of the same queries (a float32 upload, then the
+    cast on the device), bit for bit (ISSUE 45)."""
+    from oryx_tpu.ops import als
+    from oryx_tpu.ops.transfer import QuantizedMatrix
+
+    rng = np.random.default_rng(45)
+    host = rng.normal(size=(200, 8)).astype(np.float32)
+    if view_dtype == "int8":
+        view = QuantizedMatrix(
+            jnp.asarray(np.round(host * 40), dtype=jnp.int8),
+            jnp.full((200,), 1 / 40, dtype=jnp.float32),
+        )
+    else:
+        view = jnp.asarray(host, dtype=view_dtype)
+    calls = []
+    real = als.topk_dot_batch
+
+    def recorder(xs, y, **kw):
+        calls.append((xs, kw))
+        return real(xs, y, **kw)
+
+    monkeypatch.setattr(als, "topk_dot_batch", recorder)
+    b = TopKBatcher()
+    reqs = [
+        _Pending(rng.normal(size=8).astype(np.float32), 10, view, Future(), recall=recall)
+        for _ in range(3)
+    ]
+    for item in b._launch(reqs):
+        b._resolve(item)
+    ((xs, kw),) = calls
+    assert isinstance(xs, np.ndarray) and xs.dtype == block_dtype
+    assert xs.shape[0] >= 3 and kw["rows"] == 3 and isinstance(kw["rows"], int)
+    parent = jnp.zeros(xs.shape, dtype=jnp.float32).at[:3].set(
+        jnp.asarray(np.stack([p.vec for p in reqs]))
+    )
+    if view_dtype != "int8":
+        parent = jnp.asarray(parent, dtype=view_dtype)
+    want_vals, want_idx = real(parent, view, k=kw["k"], recall=recall)
+    for i, p in enumerate(reqs):
+        vals, idx = p.future.result(timeout=5)
+        assert list(idx) == list(np.asarray(want_idx)[i][: len(idx)])
+        assert list(vals) == list(np.asarray(want_vals)[i][: len(vals)])
 
 
 def test_a_dispatch_off_the_fused_path_counts_no_row_blocks(y):

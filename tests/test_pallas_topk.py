@@ -794,12 +794,71 @@ def test_the_count_of_real_rows_is_traced_not_compiled_in():
     from oryx_tpu.ops.pallas_topk import _topk_pallas_jit
 
     xs, operands, _ = _padded_dispatch("bf16")
+    # counts on the host ride the call as a numpy operand, counts on the
+    # device are stacked there: two signatures of the one compiled program
     _run_padded(xs, operands, 3, real=3)
+    _run_padded(xs, operands, 3, real=jnp.asarray(3))
     entries = _topk_pallas_jit._cache_size()
     # one live tile, several, a whole block, two blocks, none: one program
     for real in (4, 9, 128, 200, 0, np.int32(7), jnp.asarray(9), None):
         _run_padded(xs, operands, 3, real=real)
     assert _topk_pallas_jit._cache_size() == entries
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+def test_host_operands_ride_the_one_program(dtype):
+    """The query block and the two counts handed over as numpy arrays (what
+    the batcher forms since ISSUE 45) run the program that device arrays
+    run: the same operand types, no second entry, the same bits back."""
+    import ml_dtypes
+
+    from oryx_tpu.ops.pallas_topk import _topk_pallas_jit, stage_counts
+
+    xs, operands, _ = _padded_dispatch(dtype)
+    host_dtype = np.float32 if dtype == "int8" else ml_dtypes.bfloat16
+    block = np.zeros(xs.shape, dtype=host_dtype)
+    block[:5] = xs[:5]  # integers: the cast is exact
+    counts = stage_counts(5, None, 512, 1500)
+    assert isinstance(counts, np.ndarray) and counts.tolist() == [5, 1500]
+
+    def run(x, c):
+        out = topk_dot_batch_pallas(
+            x, operands["y"], scales=operands.get("scales"), k=32,
+            block_i=512, interpret=True, counted=True, rows=c,
+        )
+        return [np.asarray(o) for o in out]
+
+    compiled = []
+
+    def on_duration(event, seconds, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(seconds)
+
+    on_device = run(jnp.asarray(block), jnp.asarray(counts))
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        on_host = run(block, counts)  # a second signature, no second program
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert not compiled
+    assert all(np.array_equal(a, b) for a, b in zip(on_host, on_device))
+    assert np.array_equal(on_host[1], _run_padded(xs, operands, 5, real=5)[1])
+
+    def program(x, c):
+        y = jnp.pad(operands["y"], ((0, 36), (0, 128 - operands["y"].shape[1])))
+        scales = operands.get("scales")
+        return _topk_pallas_jit.lower(
+            x, y, None if scales is None else jnp.pad(scales, (0, 36)), c,
+            k=32, block_b=128, block_i=512, quantized=scales is not None,
+            interpret=True,
+        )
+
+    lowered = program(block, counts)
+    assert [str(a) for a in lowered.in_avals[0][:1] + lowered.in_avals[0][3:]] == [
+        ("float32" if dtype == "int8" else "bfloat16") + f"[512,{xs.shape[1]}]",
+        "int32[2]",
+    ]
+    assert lowered.as_text() == program(jnp.asarray(block), jnp.asarray(counts)).as_text()
 
 
 # -- item blocks past the valid rows are neither streamed nor scored (ISSUE 40) -
@@ -885,7 +944,8 @@ def test_the_count_of_valid_rows_is_traced_not_compiled_in(dtype):
 
     xs, operands = _view_with_headroom(dtype, _VIEW_ROWS)
     whole = _run_view(xs, operands, 5, None)
-    entries = _topk_pallas_jit._cache_size()
+    _run_view(xs, operands, 5, jnp.asarray(_VIEW_ROWS))  # a count on the device:
+    entries = _topk_pallas_jit._cache_size()  # the program's second signature
     # no count is the whole view; so is one past it (the kernel reads no
     # further than the operand it was given)
     for n_valid in (_VIEW_ROWS, np.int32(_VIEW_ROWS), jnp.asarray(_VIEW_ROWS), 10**6):

@@ -159,6 +159,8 @@ def test_a_path_that_is_not_fused_ignores_the_count_of_valid_rows(form, recall, 
 
 @pytest.mark.parametrize("form", [_dense, _quantized], ids=["pallas", "pallas-int8"])
 def test_the_fused_paths_counts_are_staged_as_one_array(monkeypatch, form):
+    # ONE int32[2] array for both counts, and a HOST one where both are host
+    # numbers: it rides the jitted call, nothing is uploaded for it (ISSUE 45)
     monkeypatch.setattr(als, "_on_tpu", lambda a: True)
     y, xs = form(BIG), np.zeros((4, 8), dtype=np.float32)
     assert als.topk_path(y, 10) in ("pallas", "pallas-int8")
@@ -166,7 +168,152 @@ def test_the_fused_paths_counts_are_staged_as_one_array(monkeypatch, form):
         (3, 20_000, [3, 20_000]), (None, 20_000, [4, 20_000]), (3, None, [3, BIG]),
     ]:
         _, staged = als.stage_topk_operands(xs, y, k=10, rows=rows, n_valid=n_valid)
-        assert isinstance(staged, jax.Array) and staged.dtype == jnp.int32
-        assert [int(c) for c in staged] == want
+        assert isinstance(staged, np.ndarray) and staged.dtype == np.int32
+        assert staged.tolist() == want
+    # a count that is on the device already is stacked there, as before
+    _, staged = als.stage_topk_operands(xs, y, k=10, rows=jnp.int32(3), n_valid=20_000)
+    assert isinstance(staged, jax.Array) and staged.dtype == jnp.int32
+    assert [int(c) for c in staged] == [3, 20_000]
     # neither count given: nothing to stage, the kernel's wrapper takes all of both
     assert als.stage_topk_operands(xs, y, k=10)[1] is None
+
+
+# -- a dispatch's host operands ride the jitted call (ISSUE 45) ------------------
+
+_F32_TINY = float(np.finfo(np.float32).tiny)  # the smallest normal, bf16's too
+
+
+def _from_bits(bits):
+    return np.asarray(bits, dtype=np.uint32).view(np.float32)
+
+
+def _edge_queries(kind, shape, rng):
+    """float32 queries whose cast to bfloat16 is the hard case of `kind`."""
+    n = int(np.prod(shape))
+    if kind == "ties":
+        # the 16 bits that the cast drops are exactly half (ties to even, on
+        # an even and on an odd kept mantissa), one under and one over it
+        kept = rng.integers(0x3C00, 0x4200, size=n).astype(np.uint32) << 16
+        sign = rng.integers(0, 2, size=n).astype(np.uint32) << 31
+        low = rng.choice(np.array([0x8000, 0x7FFF, 0x8001], dtype=np.uint32), size=n)
+        vals = _from_bits(kept | sign | low)
+    elif kind == "subnormal":
+        # float32 subnormals, bfloat16 subnormals (the same exponent range),
+        # the smallest normals, and a rounding up out of the subnormals
+        vals = rng.choice(
+            np.concatenate([
+                _from_bits([0x00000001, 0x00008000, 0x00018000, 0x007F8000, 0x007FFFFF]),
+                np.float32([_F32_TINY, -_F32_TINY, 3 * _F32_TINY, 0.0, -0.0]),
+            ]),
+            size=n,
+        ) * rng.choice(np.float32([1.0, -1.0]), size=n)
+    else:
+        # up to bfloat16's largest finite value, a rounding up to it, and the
+        # float32 values beyond it that round to infinity
+        vals = rng.choice(
+            np.concatenate([
+                _from_bits([0x7F7F0000, 0x7F7E8000, 0x7F7F7FFF, 0x7F7F8000, 0x7F7FFFFF]),
+                np.float32([1e30, -3e38, 6.5e4, 1.0]),
+            ]),
+            size=n,
+        ) * rng.choice(np.float32([1.0, -1.0]), size=n)
+    return vals.astype(np.float32).reshape(shape)
+
+
+# path -> (matrix form, rows, recall) that takes it where `_on_tpu` says so
+_PATH_VIEWS = {
+    "pallas": (_dense, BIG, 1.0),
+    "pallas-int8": (_quantized, BIG, 1.0),
+    "xla": (_dense, 300, 1.0),
+    "approx": (_dense, 300, 0.95),
+}
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint32) if a.dtype == np.float32 else a
+
+
+@pytest.mark.parametrize("kind", ["ties", "subnormal", "large"])
+@pytest.mark.parametrize("path", list(_PATH_VIEWS))
+def test_a_host_formed_block_answers_bit_for_bit_as_the_parents_staging(
+    monkeypatch, path, kind
+):
+    """The batcher's block, zero-filled on the host in the dtype the path
+    scores in with the real rows cast as they are copied in, against the
+    parent's staging of the same queries (a float32 upload, then a cast on
+    the device): the same bits reach the same jitted call, so values and
+    indices agree to the bit on every path."""
+    from functools import partial
+
+    from oryx_tpu.ops import pallas_topk
+
+    rng = np.random.default_rng(45)
+    monkeypatch.setattr(als, "_on_tpu", lambda a: path.startswith("pallas"))
+    monkeypatch.setattr(
+        pallas_topk, "topk_dot_batch_pallas",
+        partial(pallas_topk.topk_dot_batch_pallas, interpret=True),
+    )
+    form, n, recall = _PATH_VIEWS[path]
+    y = _filled(form, n)
+    assert als.topk_path(y, 10, recall) == path
+    real, padded = 5, 16
+    queries = _edge_queries(kind, (real, 8), rng)
+
+    dtype = als.query_dtype(y, 10, recall)
+    assert dtype == (np.float32 if path.endswith("int8") else y.dtype)
+    block = np.zeros((padded, y.shape[1]), dtype=dtype)
+    for i, q in enumerate(queries):
+        block[i, :8] = q
+    staged, counts = als.stage_topk_operands(block, y, k=10, recall=recall, rows=real)
+    assert staged is block  # nothing left to do to it, and nothing uploaded
+
+    parent = jnp.zeros((padded, y.shape[1]), dtype=jnp.float32).at[:real, :8].set(queries)
+    if not path.endswith("int8"):
+        parent = jnp.asarray(parent, dtype=y.dtype)  # the eager cast that went
+    assert np.array_equal(
+        np.asarray(parent).view(np.uint8), block.view(np.uint8)
+    )
+    got = als.topk_dot_batch(staged, y, k=10, recall=recall, rows=counts)
+    want = als.topk_dot_batch(parent, y, k=10, recall=recall, rows=real)
+    assert np.array_equal(_bits(got[0]), _bits(want[0]))
+    assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
+@pytest.mark.parametrize("form", [_chunked, _sharded], ids=["chunked", "sharded"])
+def test_a_fan_out_uploads_a_host_block_once_and_re_enters_with_device_arrays(
+    monkeypatch, form
+):
+    """A chunked or sharded matrix: the host block is uploaded ONCE, before
+    the fan-out, every chunk and shard re-enters topk_dot_batch with a
+    device array and finds nothing left to stage on it; an operand that is
+    on the device in the matrix's dtype goes through as the very object."""
+    y = _filled(form, 300)
+    block = np.zeros((4, 8), dtype=als.query_dtype(y, 10))
+    assert block.dtype == np.float32  # each part re-enters with its own dtype
+    block[:3] = np.random.default_rng(45).integers(-9, 10, size=(3, 8))
+
+    uploads, entries = [], []
+    real_asarray, real_entry = jnp.asarray, als.topk_dot_batch
+
+    def asarray(a, *args, **kw):
+        if isinstance(a, np.ndarray) and a.shape == block.shape:
+            uploads.append(a)
+        return real_asarray(a, *args, **kw)
+
+    def entry(xs, part, **kw):
+        entries.append((part, xs))
+        return real_entry(xs, part, **kw)
+
+    monkeypatch.setattr(jnp, "asarray", asarray)
+    monkeypatch.setattr(als, "topk_dot_batch", entry)
+    vals, idx = als.topk_dot_batch(block, y, k=10, rows=3)
+    assert len(uploads) == 1 and uploads[0] is block
+    assert entries[0] == (y, block) and len(entries) == 3  # then its two parts
+    assert all(isinstance(xs, jax.Array) for _, xs in entries[1:])
+    want = real_entry(jnp.asarray(block), _filled(_dense, 300), k=10)
+    assert np.array_equal(np.asarray(idx)[:3], np.asarray(want[1])[:3])
+
+    part = (y.chunks if form is _chunked else y.shards)[0]
+    on_device = jnp.asarray(block, dtype=part.dtype)
+    assert als.stage_topk_operands(on_device, part, k=10)[0] is on_device
