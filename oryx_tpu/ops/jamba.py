@@ -60,7 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from oryx_tpu.ops.sdar import _attend, _normal, rms_norm
-from oryx_tpu.ops.seq import announced_tokens, catalog_head
+from oryx_tpu.ops.seq import announced_tokens, catalog_head, head_rows
 
 # tensors of a Jamba artifact, beside the catalog ("E", the FactorStore's):
 # "E_in", "final_norm" and, for layer l, "L<l>.<name>" of `layer_shapes`: the
@@ -826,7 +826,9 @@ class JambaEncoder:
     def step(self, params, state, head, slots, lengths, live, step):
         view, n_valid, _row_token = head
         rows = (slots, lengths, live, step)
-        return decode_step(self.cfg, params, state, view, np.int32(n_valid), *rows)
+        state, out = decode_step(self.cfg, params, state, view, np.int32(n_valid), *rows)
+        out["head_rows"] = head_rows(view.shape[0], int(n_valid))
+        return state, out
 
     def train(self, *args, **kw):
         raise NotImplementedError(

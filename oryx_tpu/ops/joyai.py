@@ -64,7 +64,7 @@ import jax.numpy as jnp
 
 from oryx_tpu.ops.moe import moe_apply, moe_reference
 from oryx_tpu.ops.sdar import _normal, rms_norm
-from oryx_tpu.ops.seq import announced_tokens, catalog_head
+from oryx_tpu.ops.seq import announced_tokens, catalog_head, head_rows
 
 # tensors of a JoyAI artifact, beside the catalog ("E", the FactorStore's):
 # "E_in", "final_norm" and, for layer l, "L<l>.<name>" of `layer_shapes`. A
@@ -569,7 +569,9 @@ class JoyaiEncoder:
     def step(self, params, state, head, slots, lengths, live, step):
         view, n_valid, row_token = head
         rows = (slots, lengths, live, step)
-        return decode_step(self.cfg, params, state, view, np.int32(n_valid), row_token, *rows)
+        state, out = decode_step(self.cfg, params, state, view, np.int32(n_valid), row_token, *rows)
+        out["head_rows"] = head_rows(view.shape[0], int(n_valid))
+        return state, out
 
     def train(self, *args, **kw):
         raise NotImplementedError(

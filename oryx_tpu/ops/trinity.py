@@ -70,7 +70,7 @@ import jax.numpy as jnp
 from oryx_tpu.ops.joyai import _dot, _swiglu
 from oryx_tpu.ops.moe import moe_apply, moe_reference
 from oryx_tpu.ops.sdar import _attend, _normal, rms_norm, rope
-from oryx_tpu.ops.seq import announced_tokens, catalog_head
+from oryx_tpu.ops.seq import announced_tokens, catalog_head, head_rows
 
 # tensors of a Trinity artifact, beside the catalog ("E", the FactorStore's):
 # "E_in", "final_norm" and, for layer l, "L<l>.<name>" of `layer_shapes`. A
@@ -562,7 +562,9 @@ class TrinityEncoder:
     def step(self, params, state, head, slots, lengths, live, step):
         view, n_valid, row_token = head
         rows = (slots, lengths, live, step)
-        return decode_step(self.cfg, params, state, view, np.int32(n_valid), row_token, *rows)
+        state, out = decode_step(self.cfg, params, state, view, np.int32(n_valid), row_token, *rows)
+        out["head_rows"] = head_rows(view.shape[0], int(n_valid))
+        return state, out
 
     def train(self, *args, **kw):
         raise NotImplementedError(

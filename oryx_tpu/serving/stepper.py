@@ -128,6 +128,12 @@ class _Metrics:
             "(real | padded: the dispatch's whole shape)",
             labeled=True,
         )
+        self.head_rows = reg.counter(
+            "oryx_seq_head_rows_total",
+            "View rows of the catalog head's passes, one a step dispatch, by rows "
+            "(walked: the blocks that hold a valid row | skipped: the capacity behind them)",
+            labeled=True,
+        )
         self.blocks = reg.counter(
             "oryx_seq_blocks_total", "Blocks the seq stepper finished generating"
         )
@@ -395,6 +401,10 @@ class SeqStepper:
                     self._m.steps.inc(kind=enc.step_kind)
                     self._m.tokens.inc(len(rows) * per_row, kind=enc.step_kind, tokens="real")
                     self._m.tokens.inc(enc.step_rows * per_row, kind=enc.step_kind, tokens="padded")
+                    if "head_rows" in out:
+                        walked, skipped = out["head_rows"]
+                        self._m.head_rows.inc(walked, rows="walked")
+                        self._m.head_rows.inc(skipped, rows="skipped")
                     # a finished block's rows ride this dispatch's result: its
                     # slot is free for the next cycle's prefill (the device
                     # runs in order)

@@ -190,11 +190,14 @@ def test_one_head_serves_both_generating_encoders():
     p = np.exp(logits - logits.max(-1, keepdims=True))
     np.testing.assert_allclose(np.asarray(conf), (p / p.sum(-1, keepdims=True)).max(-1), rtol=1e-4)
     assert arg.dtype == jnp.int32
-    # both programs call it, each under its own scope
+    # all four generating programs call it, each under its own scope
     import inspect
 
+    from oryx_tpu.ops import joyai, trinity
+
     assert "catalog_head(" in inspect.getsource(sdar.denoise_step.__wrapped__)
-    assert "catalog_head(" in inspect.getsource(jamba.decode_step.__wrapped__)
+    for mod in (jamba, joyai, trinity):
+        assert "catalog_head(" in inspect.getsource(mod.decode_step.__wrapped__)
 
 
 # ---- prefill, then steps, against the full forward pass ---------------------------

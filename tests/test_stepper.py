@@ -290,7 +290,7 @@ def _basket(enc, params, head, prepared, programs, uploaded):
             state, out = step_program(enc.cfg, params, state, *fixed, *(jnp.asarray(a) for a in rows))
         else:
             state, out = enc.step(params, state, head, *rows)
-        outs.append({k: np.asarray(v) for k, v in out.items()})
+        outs.append({k: np.asarray(v) for k, v in out.items() if k != "head_rows"})
     return outs, jax.tree_util.tree_map(np.asarray, state)
 
 
@@ -304,3 +304,37 @@ def test_a_basket_from_host_operands_is_the_uploaded_operands_basket(name):
     assert len(host[0]) == enc.steps
     for ours, theirs in zip(jax.tree_util.tree_leaves(host), jax.tree_util.tree_leaves(uploaded), strict=True):
         np.testing.assert_array_equal(ours, theirs)
+
+
+@pytest.mark.parametrize("name", GENERATING)
+def test_every_step_dispatch_counts_the_heads_rows(name):
+    """`oryx_seq_head_rows_total` moves once a step dispatch: walked + skipped
+    is the view's rows, walked the blocks that hold a valid row. The view here
+    has capacity behind its items, two blocks, one of them live."""
+    from oryx_tpu.common.metrics import get_registry
+    from oryx_tpu.ops.pallas_head import HEAD_BLOCK_ROWS
+
+    enc, params, (view, n_valid, row_token), prepared, _ = _tiny(name)
+    rows = 2 * HEAD_BLOCK_ROWS
+    view = jnp.pad(view, ((0, rows - view.shape[0]), (0, 0)))
+    if row_token is not None:
+        row_token = jnp.pad(row_token, (0, rows - row_token.shape[0]), constant_values=-1 if name == "joyai" else 0)
+    reg = get_registry()
+    steps = reg.counter("oryx_seq_steps_total", labeled=True)
+    head_rows = reg.counter("oryx_seq_head_rows_total", labeled=True)
+
+    def read():
+        return (
+            steps.value(kind=enc.step_kind), head_rows.value(rows="walked"), head_rows.value(rows="skipped")
+        )
+
+    before = read()
+    stepper = SeqStepper()
+    engine = Engine(enc, params, head=lambda: (view, n_valid, row_token))
+    try:
+        assert len(_drive(stepper, engine, prepared[:6])) == 6
+    finally:
+        stepper.close()
+    n, walked, skipped = (a - b for a, b in zip(read(), before))
+    assert n >= enc.steps and n_valid <= HEAD_BLOCK_ROWS
+    assert (walked, skipped) == (n * HEAD_BLOCK_ROWS, n * HEAD_BLOCK_ROWS)
