@@ -59,14 +59,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from oryx_tpu.ops.sdar import _attend, _normal, rms_norm
-from oryx_tpu.ops.seq import announced_tokens, catalog_head, head_rows
+from oryx_tpu.ops.decoder import (
+    DecoderEncoder, Layout, advance, attend, basket, dot, reset, rms_norm, swiglu, view_head,
+)
 
-# tensors of a Jamba artifact, beside the catalog ("E", the FactorStore's):
-# "E_in", "final_norm" and, for layer l, "L<l>.<name>" of `layer_shapes`: the
-# feed-forward's in every layer, the mixer's by the layer's kind. Channels lie
-# on the last axis (the lanes): `conv_w` is [d_conv, d_inner] and `A_log`
-# [d_state, d_inner]
+# a layer's tensors (`layer_shapes`): the feed-forward's in every layer, the
+# mixer's by the layer's kind. Channels lie on the last axis (the lanes):
+# `conv_w` is [d_conv, d_inner] and `A_log` [d_state, d_inner]
 NORM_TENSORS = ("ln1", "ln2", "dt_norm", "b_norm", "c_norm")
 # the recurrence's own parameters stay float32 whatever the weights' dtype
 FLOAT32_TENSORS = ("dt_bias", "A_log", "D")
@@ -158,25 +157,13 @@ def layer_shapes(cfg: JambaConfig, layer: int) -> dict[str, tuple]:
     return out
 
 
-def tensor_shapes(cfg: JambaConfig) -> dict[str, tuple]:
-    """Every tensor of an artifact by its name."""
-    out = {"E_in": (cfg.vocab, cfg.hidden), "final_norm": (cfg.hidden,)}
-    for l in range(cfg.layers):
-        out.update({f"L{l}.{k}": v for k, v in layer_shapes(cfg, l).items()})
-    return out
-
-
-def param_count(cfg: JambaConfig) -> int:
-    return sum(int(np.prod(v)) for v in tensor_shapes(cfg).values())
-
-
-@partial(jax.jit, static_argnums=(1,))
-def _dt_bias(key, shape):
+@partial(jax.jit, static_argnums=(1, 2))
+def _dt_bias(key, shape, dtype):
     """b_dt with softplus(b_dt) log-uniform in DT_INIT (Mamba's published
     initialisation): the inverse softplus of the drawn step."""
     lo, hi = DT_INIT
     dt = jnp.exp(jax.random.uniform(key, shape) * (math.log(hi) - math.log(lo)) + math.log(lo))
-    return dt + jnp.log(-jnp.expm1(-dt))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
 
 
 @partial(jax.jit, static_argnums=(1, 2))
@@ -187,61 +174,20 @@ def _conv_weight(key, shape, dtype):
     return jax.random.uniform(key, shape, minval=-bound, maxval=bound).astype(dtype)
 
 
-def init_tensors(cfg: JambaConfig, seed: int, dtype=jnp.bfloat16) -> dict:
-    """An artifact's tensors from the seed, made on the device one at a time:
-    standard normal x 0.02, norm weights 1, and Mamba's published
-    initialisation of the mixer's own parameters (A_log = log 1..d_state
-    down the state axis, D = 1, b_dt as `_dt_bias`, the conv's weight as
-    `_conv_weight`), without which the state saturates or dies, or the conv
-    passes next to nothing, and the recurrence carries no weight in the output."""
-    out = {}
-    for i, (name, shape) in enumerate(sorted(tensor_shapes(cfg).items())):
-        kind = name.split(".")[-1]
-        key = jax.random.fold_in(jax.random.PRNGKey(seed % (2**31)), i)
-        if kind == "final_norm" or kind in NORM_TENSORS:
-            out[name] = jnp.ones(shape, dtype=dtype)
-        elif kind == "A_log":
-            steps = jnp.log(jnp.arange(1, cfg.d_state + 1, dtype=jnp.float32))
-            out[name] = jnp.tile(steps[:, None], (1, shape[1]))
-        elif kind == "D":
-            out[name] = jnp.ones(shape, jnp.float32)
-        elif kind == "dt_bias":
-            out[name] = _dt_bias(key, shape)
-        elif kind == "conv_w":
-            out[name] = _conv_weight(key, shape, dtype)
-        else:
-            out[name] = _normal(key, shape, dtype)
-    return out
+def _a_log(key, shape, dtype):
+    """log 1..d_state down the state axis."""
+    return jnp.tile(jnp.log(jnp.arange(1, shape[0] + 1, dtype=dtype))[:, None], (1, shape[1]))
 
 
-def params_of(cfg: JambaConfig, tensors: dict, dtype=None) -> dict:
-    """An artifact's tensors -> the parameters the forms below take:
-    {"E_in", "final_norm", "layers": [{name: array}, ...]}, checked against
-    the shapes the configuration states; cast to `dtype` where one is given
-    (the recurrence's own parameters to float32 always)."""
-    for name, shape in tensor_shapes(cfg).items():
-        if name not in tensors:
-            raise ValueError(f"Jamba model lacks tensor {name!r}")
-        if tuple(np.shape(tensors[name])) != shape:
-            raise ValueError(
-                f"Jamba tensor {name!r} shaped {tuple(np.shape(tensors[name]))}, "
-                f"the extensions say {shape}"
-            )
-
-    def take(name):
-        kind = name.split(".")[-1]
-        return jnp.asarray(tensors[name], dtype=jnp.float32 if kind in FLOAT32_TENSORS else dtype)
-
-    return {
-        "E_in": take("E_in"), "final_norm": take("final_norm"),
-        "layers": [
-            {k: take(f"L{l}.{k}") for k in layer_shapes(cfg, l)} for l in range(cfg.layers)
-        ],
-    }
-
-
-def init_params(cfg: JambaConfig, seed: int, dtype=jnp.bfloat16) -> dict:
-    return params_of(cfg, init_tensors(cfg, seed, dtype))
+# Mamba's published initialisation of the mixer's own parameters (A_log, D = 1,
+# b_dt, the conv's weight), without which the state saturates or dies, or the
+# conv passes next to nothing, and the recurrence carries no weight in the output
+LAYOUT = Layout(
+    "Jamba", layer_shapes, NORM_TENSORS + ("D",),
+    special={"A_log": _a_log, "dt_bias": _dt_bias, "conv_w": _conv_weight}, float32=FLOAT32_TENSORS,
+)
+tensor_shapes, param_count, init_tensors = LAYOUT.tensor_shapes, LAYOUT.param_count, LAYOUT.init_tensors
+params_of, init_params = LAYOUT.params_of, LAYOUT.init_params
 
 
 # -- the selective scan: one Pallas kernel, chunked over positions -----------
@@ -536,17 +482,13 @@ def step_scan(x, dt, b, c, a, d, h, slots, live):
     )
 
 
-# -- pieces both served programs share (the dtype of the weights decides the
-# precision of a product's inputs) -----------------------------------------
-
-def _dot(x, w):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
-
+# -- pieces both served programs share (ops/decoder.py `dot`: the dtype of the
+# weights decides the precision of a product's inputs) ----------------------
 
 def _mlp(cfg: JambaConfig, p: dict, x):
     with jax.named_scope("jamba.mlp"):
         u = rms_norm(x, p["ln2"], cfg.eps)
-        return x + _dot(jax.nn.silu(_dot(u, p["wg"])) * _dot(u, p["wu"]), p["wd"])
+        return x + swiglu(u, p["wg"], p["wu"], p["wd"])
 
 
 def _conv(p: dict, window):
@@ -562,11 +504,11 @@ def _conv(p: dict, window):
 def _ssm_inputs(cfg: JambaConfig, p: dict, xc):
     """xc [..., C] (after the conv) -> dt [..., C], B, C [..., N] float32."""
     r, n = cfg.dt_rank, cfg.d_state
-    dbc = _dot(xc, p["x_proj"])
+    dbc = dot(xc, p["x_proj"])
     dt = rms_norm(dbc[..., :r], p["dt_norm"], cfg.eps)
     b = rms_norm(dbc[..., r:r + n], p["b_norm"], cfg.eps)
     c = rms_norm(dbc[..., r + n:], p["c_norm"], cfg.eps)
-    return jax.nn.softplus(_dot(dt, p["dt_proj"]) + p["dt_bias"]), b, c
+    return jax.nn.softplus(dot(dt, p["dt_proj"]) + p["dt_bias"]), b, c
 
 
 def _mamba(cfg: JambaConfig, p: dict, x, tail, h0, lengths):
@@ -576,7 +518,7 @@ def _mamba(cfg: JambaConfig, p: dict, x, tail, h0, lengths):
     `lengths` [R] are padding: they change neither."""
     k = cfg.d_conv
     with jax.named_scope("jamba.mamba"):
-        xz = _dot(rms_norm(x, p["ln1"], cfg.eps), p["in_proj"])
+        xz = dot(rms_norm(x, p["ln1"], cfg.eps), p["in_proj"])
         xs, z = xz[..., : cfg.d_inner], xz[..., cfg.d_inner:]
         window = jnp.concatenate([tail, xs], axis=1)
         # the last d_conv - 1 REAL inputs: window rows lengths .. lengths + k - 2
@@ -587,14 +529,14 @@ def _mamba(cfg: JambaConfig, p: dict, x, tail, h0, lengths):
         dt, b, c = _ssm_inputs(cfg, p, xc)
         with jax.named_scope("jamba.scan"):
             y, h = selective_scan(xc, dt, b, c, -jnp.exp(p["A_log"]), p["D"], h0, lengths)
-        return x + _dot(y * jax.nn.silu(z), p["out_proj"]), new_tail, h
+        return x + dot(y * jax.nn.silu(z), p["out_proj"]), new_tail, h
 
 
 def _qkv(cfg: JambaConfig, p: dict, u):
     r, t = u.shape[0], u.shape[1]
-    q = _dot(u, p["wq"]).reshape(r, t, cfg.heads, cfg.head_dim)
-    k = _dot(u, p["wk"]).reshape(r, t, cfg.kv_heads, cfg.head_dim)
-    v = _dot(u, p["wv"]).reshape(r, t, cfg.kv_heads, cfg.head_dim)
+    q = dot(u, p["wq"]).reshape(r, t, cfg.heads, cfg.head_dim)
+    k = dot(u, p["wk"]).reshape(r, t, cfg.kv_heads, cfg.head_dim)
+    v = dot(u, p["wv"]).reshape(r, t, cfg.kv_heads, cfg.head_dim)
     return q, k, v
 
 
@@ -607,8 +549,8 @@ def init_state(cfg: JambaConfig, slots: int, dtype=jnp.bfloat16) -> dict:
     conv's last inputs, float32; k, v: an attention layer's keys and values.
     x_in: the next step's input embedding; z / row / step: the basket (for
     each position generated the hidden state, the view row chosen and the
-    step that chose it)."""
-    s, b = slots + 1, cfg.basket
+    step that chose it: ops/decoder.py `basket`)."""
+    s = slots + 1
     kv = (s, cfg.positions, cfg.kv_heads, cfg.head_dim)
     attn = [cfg.is_attention(l) for l in range(cfg.layers)]
     f32 = jnp.float32
@@ -617,10 +559,7 @@ def init_state(cfg: JambaConfig, slots: int, dtype=jnp.bfloat16) -> dict:
         "conv": [None if a else jnp.zeros((s, cfg.d_conv - 1, cfg.d_inner), f32) for a in attn],
         "k": [jnp.zeros(kv, dtype) if a else None for a in attn],
         "v": [jnp.zeros(kv, dtype) if a else None for a in attn],
-        "x_in": jnp.zeros((s, cfg.hidden), dtype),
-        "z": jnp.zeros((s, b, cfg.hidden), f32),
-        "row": jnp.full((s, b), -1, jnp.int32),
-        "step": jnp.full((s, b), -1, jnp.int32),
+        **basket(cfg, slots, dtype),
     }
 
 
@@ -658,22 +597,14 @@ def prefill(cfg: JambaConfig, params: dict, state: dict, tokens, lengths, slots,
                 q, k, v = _qkv(cfg, p, rms_norm(x, p["ln1"], cfg.eps))
                 new["k"][l] = new["k"][l].at[slots, :t].set(k.astype(new["k"][l].dtype))
                 new["v"][l] = new["v"][l].at[slots, :t].set(v.astype(new["v"][l].dtype))
-                x = x + _dot(_attend(cfg, q, k, v, allowed, dt), p["wo"])
+                x = x + dot(attend(cfg, q, k, v, allowed, dt), p["wo"])
         else:
             x, tail, h = _mamba(cfg, p, x, zero_tail, zero_h, lengths)
             new["conv"][l] = new["conv"][l].at[slots].set(tail)
             new["h"][l] = new["h"][l].at[slots].set(h)
         x = _mlp(cfg, p, x)
     hidden = x[jnp.arange(p_rows), jnp.maximum(lengths - 1, 0)]
-    b = cfg.basket
-    state = dict(
-        state, **new,
-        x_in=state["x_in"].at[slots].set(params["E_in"][last].astype(state["x_in"].dtype)),
-        z=state["z"].at[slots].set(jnp.zeros((b, cfg.hidden), f32)),
-        row=state["row"].at[slots].set(-1),
-        step=state["step"].at[slots].set(-1),
-    )
-    return state, hidden
+    return reset(state, slots, params["E_in"][last], **new), hidden
 
 
 def _mamba_step(cfg: JambaConfig, p: dict, x, conv, h, slots, live):
@@ -681,14 +612,14 @@ def _mamba_step(cfg: JambaConfig, p: dict, x, conv, h, slots, live):
     stream, conv and h the layer's WHOLE slot arrays -> (x + mixer, conv, h)
     with the live rows' slots moved on by the position."""
     with jax.named_scope("jamba.mamba"):
-        xz = _dot(rms_norm(x, p["ln1"], cfg.eps), p["in_proj"])
+        xz = dot(rms_norm(x, p["ln1"], cfg.eps), p["in_proj"])
         xs, z = xz[:, : cfg.d_inner], xz[:, cfg.d_inner:]
         with jax.named_scope("jamba.scan"):
             xc, conv = step_conv(p, xs, conv, slots, live)
         dt, b, c = _ssm_inputs(cfg, p, xc)
         with jax.named_scope("jamba.scan"):
             y, h = step_scan(xc, dt, b, c, -jnp.exp(p["A_log"]), p["D"], h, slots, live)
-        return x + _dot(y * jax.nn.silu(z), p["out_proj"]), conv, h
+        return x + dot(y * jax.nn.silu(z), p["out_proj"]), conv, h
 
 
 def _token_hidden(cfg: JambaConfig, params: dict, state: dict, slots, pos, live):
@@ -707,10 +638,10 @@ def _token_hidden(cfg: JambaConfig, params: dict, state: dict, slots, pos, live)
                 q, k, v = _qkv(cfg, p, rms_norm(x, p["ln1"], cfg.eps)[:, None, :])
                 new["k"][l] = new["k"][l].at[slots, pos].set(k[:, 0].astype(new["k"][l].dtype))
                 new["v"][l] = new["v"][l].at[slots, pos].set(v[:, 0].astype(new["v"][l].dtype))
-                o = _attend(
+                o = attend(
                     cfg, q, new["k"][l][slots].astype(f32), new["v"][l][slots].astype(f32), allowed, dt
                 )
-                x = x + _dot(o[:, 0], p["wo"])
+                x = x + dot(o[:, 0], p["wo"])
         else:
             x, new["conv"][l], new["h"][l] = _mamba_step(
                 cfg, p, x, new["conv"][l], new["h"][l], slots, live
@@ -732,108 +663,30 @@ def decode_step(cfg: JambaConfig, params: dict, state: dict, view, n_valid, slot
     generated so far, "row": [D,B] the view rows chosen, "step": [D,B] the
     steps that chose them}: what a finished request needs, and every row's,
     so one fetch serves whichever finished."""
-    b = cfg.basket
     z, new = _token_hidden(cfg, params, state, slots, lengths + step, live)
     with jax.named_scope("jamba.head"):
-        dt = view.dtype
-        zq = jnp.pad(z.astype(dt), ((0, 0), (0, view.shape[1] - cfg.hidden)))
-        _top, arg, _conf = catalog_head(zq, view, n_valid)
+        _top, arg, _conf = view_head(z, view, n_valid)
         fed = view[arg][:, : cfg.hidden]
-    here = (jnp.arange(b)[None, :] == step[:, None]) & live[:, None]             # [D,B]
-    new_z = jnp.where(here[:, :, None], z[:, None, :], state["z"][slots])
-    new_row = jnp.where(here, arg[:, None], state["row"][slots])
-    new_step = jnp.where(here, step[:, None], state["step"][slots])
-    state = dict(
-        state, **new,
-        x_in=state["x_in"].at[slots].set(fed.astype(state["x_in"].dtype)),
-        z=state["z"].at[slots].set(new_z),
-        row=state["row"].at[slots].set(new_row),
-        step=state["step"].at[slots].set(new_step),
-    )
-    return state, {"z": new_z, "row": new_row, "step": new_step}
+    return advance(state, slots, step, live, z, arg, fed, **new)
 
 
 # -- behind the encoder seam (ops/seq.py) ------------------------------------
 
-class JambaEncoder:
-    """The decoder behind the seam: `prefill` runs a request's events but the
-    last into its cache slot, `steps` one-token steps follow, and the request
-    hands the catalog scan `block` rows. Shapes are few and fixed: a prefill
-    is `prefill_rows` sessions padded to a length bucket, a step is
-    `step_rows` tokens."""
+class JambaEncoder(DecoderEncoder):
+    """A Jamba decoder behind the seam (ops/decoder.py DecoderEncoder)."""
 
-    name = "jamba"
-    own_input = True      # E_in: the tied embedding as the model was announced
-    step_kind = "decode"
-    step_tokens = 1       # a step runs one token a sequence
+    name, config, layout = "jamba", JambaConfig, LAYOUT
+    programs, slot_state = (prefill, decode_step), (init_state, state_bytes)
     unknown_token = None  # a step feeds back the view's own row: no token needed
     # 4 sessions x 100 positions is still about a pass over the weights' worth
     # of MXU time (12 ms beside 7); 8 x 100 was 32 ms for what is mostly padding
     prefill_rows = 4
-    step_rows = 32
 
-    def __init__(self, cfg: JambaConfig, dtype=jnp.bfloat16):
-        self.cfg = cfg
-        self.dtype = dtype
-        self.dim = cfg.hidden
-        self.steps = cfg.basket
-        self.block = cfg.basket
-        self.window = cfg.max_len
-        self.length_buckets = tuple(sorted({min(32, cfg.max_len), cfg.max_len}))
+    def prefill(self, params, state, *packed):
+        return (*super().prefill(params, state, *packed), None)  # no expert layer, no counts
 
-    @staticmethod
-    def from_extensions(ext) -> "JambaEncoder":
-        return JambaEncoder(
-            JambaConfig.from_extensions(ext), jnp.dtype(str(ext("dtype", "bfloat16")))
-        )
-
-    def load_params(self, tensors: dict) -> dict:
-        return params_of(self.cfg, tensors, self.dtype)
-
-    def device_params(self, params: dict) -> dict:
-        return params
-
-    def init_state(self, slots: int):
-        return init_state(self.cfg, slots, self.dtype)
-
-    def state_bytes(self, slots: int) -> dict[str, int]:
-        return state_bytes(self.cfg, slots, jnp.dtype(self.dtype).itemsize)
-
-    def prepare(self, seq_state, context_items):
-        """The E_in rows of the newest `max_len` context items that have
-        one (an item that arrived by UP since the model is skipped as
-        context until the next generation)."""
-        return announced_tokens(seq_state, context_items, self.cfg.max_len)
-
-    def length(self, prepared) -> int:
-        return int(prepared.shape[0]) - 1  # the last event is the first step's
-
-    def pack(self, prepared: list, bucket: int, slots, scratch: int):
-        tokens = np.zeros((self.prefill_rows, bucket), dtype=np.int32)
-        lengths = np.zeros((self.prefill_rows,), dtype=np.int32)
-        slot_of = np.full((self.prefill_rows,), scratch, dtype=np.int32)
-        last = np.zeros((self.prefill_rows,), dtype=np.int32)
-        for i, tok in enumerate(prepared):
-            tokens[i, : len(tok) - 1] = tok[:-1]
-            lengths[i], last[i], slot_of[i] = len(tok) - 1, tok[-1], slots[i]
-        return tokens, lengths, slot_of, last
-
-    # host operands ride the jitted call (the seam's comment, ops/seq.py)
-    def prefill(self, params, state, tokens, lengths, slots, last):
-        state, hidden = prefill(self.cfg, params, state, tokens, lengths, slots, last)
-        return state, hidden, None
-
-    def step(self, params, state, head, slots, lengths, live, step):
-        view, n_valid, _row_token = head
-        rows = (slots, lengths, live, step)
-        state, out = decode_step(self.cfg, params, state, view, np.int32(n_valid), *rows)
-        out["head_rows"] = head_rows(view.shape[0], int(n_valid))
-        return state, out
-
-    def train(self, *args, **kw):
-        raise NotImplementedError(
-            "a Jamba model reaches serving as an artifact; the batch layer trains the GRU"
-        )
+    def call_step(self, params, state, view, n_valid, row_token, rows):
+        return self.programs[1](self.cfg, params, state, view, n_valid, *rows)  # the tied head needs no row_token
 
 
 # -- the plain reference: float32, highest precision, no cache ---------------

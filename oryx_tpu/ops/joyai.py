@@ -62,15 +62,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from oryx_tpu.ops.decoder import (
+    DecoderEncoder, Layout, advance, basket, dot, fed_back, masked_softmax, reset, rms_norm,
+    router_bias, swiglu, view_head,
+)
 from oryx_tpu.ops.moe import moe_apply, moe_reference
-from oryx_tpu.ops.sdar import _normal, rms_norm
-from oryx_tpu.ops.seq import announced_tokens, catalog_head, head_rows
 
-# tensors of a JoyAI artifact, beside the catalog ("E", the FactorStore's):
-# "E_in", "final_norm" and, for layer l, "L<l>.<name>" of `layer_shapes`. A
-# layer's tensors are arrays of their own (ops/sdar.py says why)
 NORM_TENSORS = ("ln1", "ln2", "q_norm", "kv_norm")
-BIAS_INIT = 0.1  # the correction bias is drawn normal x this (a trained model carries one)
 # keys of the source that name a form, and the one form of each computed here
 # (as an artifact's extensions spell them, lower case)
 _COMPUTED = {
@@ -188,77 +186,16 @@ def layer_shapes(cfg: JoyaiConfig, layer: int) -> dict[str, tuple]:
     return out
 
 
-def tensor_shapes(cfg: JoyaiConfig) -> dict[str, tuple]:
-    """Every tensor of an artifact by its name."""
-    out = {"E_in": (cfg.vocab, cfg.hidden), "final_norm": (cfg.hidden,)}
-    for l in range(cfg.layers):
-        out.update({f"L{l}.{k}": v for k, v in layer_shapes(cfg, l).items()})
-    return out
+# the router's correction bias is float32 (ops/decoder.py `router_bias`)
+LAYOUT = Layout(
+    "JoyAI", layer_shapes, NORM_TENSORS, special={"router_bias": router_bias}, float32=("router_bias",),
+)
+tensor_shapes, param_count, init_tensors = LAYOUT.tensor_shapes, LAYOUT.param_count, LAYOUT.init_tensors
+params_of, init_params = LAYOUT.params_of, LAYOUT.init_params
 
 
-def param_count(cfg: JoyaiConfig) -> int:
-    return sum(int(np.prod(v)) for v in tensor_shapes(cfg).values())
-
-
-@partial(jax.jit, static_argnums=(1,))
-def _bias(key, shape):
-    return jax.random.normal(key, shape, dtype=jnp.float32) * BIAS_INIT
-
-
-def init_tensors(cfg: JoyaiConfig, seed: int, dtype=jnp.bfloat16) -> dict:
-    """An artifact's tensors from the seed, made on the device one at a time:
-    standard normal x 0.02, norm weights 1, and the router's correction bias
-    normal x `BIAS_INIT` in float32 (zeros, a fresh model's, would make it
-    invisible: at these widths the scores s spread by 0.2, so a bias of 0.1
-    changes which experts a token reaches and nothing drowns)."""
-    out = {}
-    for i, (name, shape) in enumerate(sorted(tensor_shapes(cfg).items())):
-        kind = name.split(".")[-1]
-        key = jax.random.fold_in(jax.random.PRNGKey(seed % (2**31)), i)
-        if kind == "final_norm" or kind in NORM_TENSORS:
-            out[name] = jnp.ones(shape, dtype=dtype)
-        elif kind == "router_bias":
-            out[name] = _bias(key, shape)
-        else:
-            out[name] = _normal(key, shape, dtype)
-    return out
-
-
-def params_of(cfg: JoyaiConfig, tensors: dict, dtype=None) -> dict:
-    """An artifact's tensors -> the parameters the forms below take:
-    {"E_in", "final_norm", "layers": [{name: array}, ...]}, checked against
-    the shapes the configuration states; cast to `dtype` where one is given
-    (the correction bias to float32 always)."""
-    for name, shape in tensor_shapes(cfg).items():
-        if name not in tensors:
-            raise ValueError(f"JoyAI model lacks tensor {name!r}")
-        if tuple(np.shape(tensors[name])) != shape:
-            raise ValueError(
-                f"JoyAI tensor {name!r} shaped {tuple(np.shape(tensors[name]))}, "
-                f"the extensions say {shape}"
-            )
-
-    def take(name):
-        return jnp.asarray(tensors[name], dtype=jnp.float32 if name.endswith("router_bias") else dtype)
-
-    return {
-        "E_in": take("E_in"), "final_norm": take("final_norm"),
-        "layers": [
-            {k: take(f"L{l}.{k}") for k in layer_shapes(cfg, l)} for l in range(cfg.layers)
-        ],
-    }
-
-
-def init_params(cfg: JoyaiConfig, seed: int, dtype=jnp.bfloat16) -> dict:
-    return params_of(cfg, init_tensors(cfg, seed, dtype))
-
-
-# -- pieces both served programs share (the dtype of the weights decides the
-# precision of a product's inputs) -----------------------------------------
-
-def _dot(x, w):
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
-
+# -- pieces both served programs share (ops/decoder.py `dot`: the dtype of the
+# weights decides the precision of a product's inputs) ----------------------
 
 def rope_interleaved(x, pos, theta):
     """x [..., d] float32, pos broadcastable to x's leading axes -> the pairs
@@ -275,7 +212,7 @@ def rope_interleaved(x, pos, theta):
 def _queries(cfg: JoyaiConfig, p: dict, u, pos):
     """u [..., H] float32 (normalised), pos [...] -> (q_nope [..., heads,
     nope], q_rope [..., heads, rope] rotated), float32."""
-    q = _dot(rms_norm(_dot(u, p["wq_a"]), p["q_norm"], cfg.eps), p["wq_b"])
+    q = dot(rms_norm(dot(u, p["wq_a"]), p["q_norm"], cfg.eps), p["wq_b"])
     q = q.reshape(*u.shape[:-1], cfg.heads, cfg.qk_dim)
     return q[..., : cfg.nope], rope_interleaved(q[..., cfg.nope:], pos[..., None], cfg.rope_theta)
 
@@ -283,18 +220,9 @@ def _queries(cfg: JoyaiConfig, p: dict, u, pos):
 def _latent(cfg: JoyaiConfig, p: dict, u, pos):
     """u [..., H] float32 (normalised), pos [...] -> what the cache keeps of
     each position: (c [..., kv_rank] normalised, k_rope [..., rope] rotated)."""
-    ckv = _dot(u, p["wkv_a"])
+    ckv = dot(u, p["wkv_a"])
     c = rms_norm(ckv[..., : cfg.kv_rank], p["kv_norm"], cfg.eps)
     return c, rope_interleaved(ckv[..., cfg.kv_rank:], pos, cfg.rope_theta)
-
-
-def _softmax(s, allowed):
-    """Scores float32 -> probabilities over the last axis where `allowed`; a
-    padding query may be allowed nothing: its row is zeros, not NaN."""
-    s = jnp.where(allowed, s, -jnp.inf)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - jnp.where(jnp.isfinite(m), m, 0.0))
-    return e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
 
 
 def _attend_written(cfg: JoyaiConfig, p: dict, q_nope, q_rope, c, k_rope, allowed):
@@ -305,11 +233,11 @@ def _attend_written(cfg: JoyaiConfig, p: dict, q_nope, q_rope, c, k_rope, allowe
     f32 = jnp.float32
     dt = p["wkv_b"].dtype
     r, s_len = c.shape[0], c.shape[1]
-    kv = _dot(c, p["wkv_b"]).reshape(r, s_len, cfg.heads, cfg.nope + cfg.v_dim)
+    kv = dot(c, p["wkv_b"]).reshape(r, s_len, cfg.heads, cfg.nope + cfg.v_dim)
     k_nope, v = kv[..., : cfg.nope], kv[..., cfg.nope:]
     s = jnp.einsum("rthd,rshd->rhts", q_nope.astype(dt), k_nope.astype(dt), preferred_element_type=f32)
     s = s + jnp.einsum("rthd,rsd->rhts", q_rope.astype(dt), k_rope.astype(dt), preferred_element_type=f32)
-    prob = _softmax(s / math.sqrt(cfg.qk_dim), allowed[:, None, :, :])
+    prob = masked_softmax(s / math.sqrt(cfg.qk_dim), allowed[:, None, :, :])
     o = jnp.einsum("rhts,rshd->rthd", prob.astype(dt), v.astype(dt), preferred_element_type=f32)
     return o.reshape(r, q_nope.shape[1], cfg.heads * cfg.v_dim)
 
@@ -326,20 +254,16 @@ def _attend_absorbed(cfg: JoyaiConfig, p: dict, q_nope, q_rope, c, k_rope, allow
     q_lat = jnp.einsum("dhn,chn->dhc", q_nope.astype(dt), w_uk, preferred_element_type=f32)
     s = jnp.einsum("dhc,dsc->dhs", q_lat.astype(dt), c.astype(dt), preferred_element_type=f32)
     s = s + jnp.einsum("dhr,dsr->dhs", q_rope.astype(dt), k_rope.astype(dt), preferred_element_type=f32)
-    prob = _softmax(s / math.sqrt(cfg.qk_dim), allowed[:, None, :])
+    prob = masked_softmax(s / math.sqrt(cfg.qk_dim), allowed[:, None, :])
     ctx = jnp.einsum("dhs,dsc->dhc", prob.astype(dt), c.astype(dt), preferred_element_type=f32)
     o = jnp.einsum("dhc,chv->dhv", ctx.astype(dt), w_uv, preferred_element_type=f32)
     return o.reshape(q_nope.shape[0], cfg.heads * cfg.v_dim)
 
 
-def _swiglu(u, wg, wu, wd):
-    return _dot(jax.nn.silu(_dot(u, wg)) * _dot(u, wu), wd)
-
-
 def _shared_expert(p: dict, u):
     """The shared expert's SwiGLU of every token's `u` [N,H] float32."""
     with jax.named_scope("joyai.shared"):
-        return _swiglu(u, p["shared_wg"], p["shared_wu"], p["shared_wd"])
+        return swiglu(u, p["shared_wg"], p["shared_wu"], p["shared_wd"])
 
 
 def _ffn(cfg: JoyaiConfig, p: dict, x, live):
@@ -348,7 +272,7 @@ def _ffn(cfg: JoyaiConfig, p: dict, x, live):
     if "router" not in p:
         with jax.named_scope("joyai.dense"):
             u = rms_norm(x, p["ln2"], cfg.eps)
-            return x + _swiglu(u, p["wg"], p["wu"], p["wd"]), jnp.zeros((3,), jnp.int32)
+            return x + swiglu(u, p["wg"], p["wu"], p["wd"]), jnp.zeros((3,), jnp.int32)
     with jax.named_scope("joyai.moe"):
         flat = rms_norm(x, p["ln2"], cfg.eps).reshape(-1, cfg.hidden)
         y, counts = moe_apply(
@@ -368,15 +292,12 @@ def init_state(cfg: JoyaiConfig, slots: int, dtype=jnp.bfloat16) -> dict:
     cache, one row a position: the normalised latent c and the rotated key.
     x_in: the next step's input embedding; z / row / step: the basket (for
     each position generated the hidden state, the view row chosen and the
-    step that chose it)."""
-    s, b = slots + 1, cfg.basket
+    step that chose it: ops/decoder.py `basket`)."""
+    s = slots + 1
     return {
         "latent": [jnp.zeros((s, cfg.positions, cfg.kv_rank), dtype) for _ in range(cfg.layers)],
         "rope_key": [jnp.zeros((s, cfg.positions, cfg.rope), dtype) for _ in range(cfg.layers)],
-        "x_in": jnp.zeros((s, cfg.hidden), dtype),
-        "z": jnp.zeros((s, b, cfg.hidden), jnp.float32),
-        "row": jnp.full((s, b), -1, jnp.int32),
-        "step": jnp.full((s, b), -1, jnp.int32),
+        **basket(cfg, slots, dtype),
     }
 
 
@@ -415,18 +336,12 @@ def prefill(cfg: JoyaiConfig, params: dict, state: dict, tokens, lengths, slots,
             latent[l] = latent[l].at[slots].set(jnp.pad(kept, behind))
             kept = jnp.where(live[:, :, None], k_rope, 0.0).astype(rope_key[l].dtype)
             rope_key[l] = rope_key[l].at[slots].set(jnp.pad(kept, behind))
-            x = x + _dot(_attend_written(cfg, p, q_nope, q_rope, c, k_rope, allowed), p["wo"])
+            x = x + dot(_attend_written(cfg, p, q_nope, q_rope, c, k_rope, allowed), p["wo"])
         x, n = _ffn(cfg, p, x, live)
         counts = counts + n
     with jax.named_scope("joyai.embed"):
         hidden = x[jnp.arange(p_rows), jnp.maximum(lengths - 1, 0)]
-        state = dict(
-            state, latent=latent, rope_key=rope_key,
-            x_in=state["x_in"].at[slots].set(params["E_in"][last].astype(state["x_in"].dtype)),
-            z=state["z"].at[slots].set(jnp.zeros((cfg.basket, cfg.hidden), f32)),
-            row=state["row"].at[slots].set(-1),
-            step=state["step"].at[slots].set(-1),
-        )
+        state = reset(state, slots, params["E_in"][last], latent=latent, rope_key=rope_key)
     return state, hidden, counts
 
 
@@ -448,7 +363,7 @@ def _token_hidden(cfg: JoyaiConfig, params: dict, state: dict, slots, pos, live)
             latent[l] = latent[l].at[slots, pos].set(c.astype(latent[l].dtype))
             rope_key[l] = rope_key[l].at[slots, pos].set(k_rope.astype(rope_key[l].dtype))
             o = _attend_absorbed(cfg, p, q_nope, q_rope, latent[l][slots], rope_key[l][slots], allowed)
-            x = x + _dot(o, p["wo"])
+            x = x + dot(o, p["wo"])
         x, n = _ffn(cfg, p, x, live)
         counts = counts + n
     with jax.named_scope("joyai.head"):
@@ -472,111 +387,25 @@ def decode_step(
     generated so far, "row": [D,B] the view rows chosen, "step": [D,B] the
     steps that chose them, "counts": int32[3]}: what a finished request
     needs, and every row's, so one fetch serves whichever finished."""
-    b = cfg.basket
     z, latent, rope_key, counts = _token_hidden(cfg, params, state, slots, lengths + step, live)
     with jax.named_scope("joyai.head"):
-        dt = view.dtype
-        zq = jnp.pad(z.astype(dt), ((0, 0), (0, view.shape[1] - cfg.hidden)))
-        _top, arg, _conf = catalog_head(zq, view, n_valid)
+        _top, arg, _conf = view_head(z, view, n_valid)
     with jax.named_scope("joyai.embed"):
-        token = row_token[arg]
-        fed = jnp.where((token >= 0)[:, None], params["E_in"][jnp.maximum(token, 0)], 0)
-        here = (jnp.arange(b)[None, :] == step[:, None]) & live[:, None]         # [D,B]
-        new_z = jnp.where(here[:, :, None], z[:, None, :], state["z"][slots])
-        new_row = jnp.where(here, arg[:, None], state["row"][slots])
-        new_step = jnp.where(here, step[:, None], state["step"][slots])
-        state = dict(
-            state, latent=latent, rope_key=rope_key,
-            x_in=state["x_in"].at[slots].set(fed.astype(state["x_in"].dtype)),
-            z=state["z"].at[slots].set(new_z),
-            row=state["row"].at[slots].set(new_row),
-            step=state["step"].at[slots].set(new_step),
-        )
-    return state, {"z": new_z, "row": new_row, "step": new_step, "counts": counts}
+        fed = fed_back(params, row_token, arg)
+        state, out = advance(state, slots, step, live, z, arg, fed, latent=latent, rope_key=rope_key)
+    return state, dict(out, counts=counts)
 
 
 # -- behind the encoder seam (ops/seq.py) ------------------------------------
 
-class JoyaiEncoder:
-    """The decoder behind the seam: `prefill` runs a request's events but the
-    last into its cache slot, `steps` one-token steps follow, and the request
-    hands the catalog scan `block` rows. Shapes are few and fixed: a prefill
-    is `prefill_rows` sessions padded to a length bucket, a step is
-    `step_rows` tokens."""
+class JoyaiEncoder(DecoderEncoder):
+    """A JoyAI decoder behind the seam (ops/decoder.py DecoderEncoder)."""
 
-    name = "joyai"
-    own_input = True      # E_in: an input embedding apart from the (untied) head
-    step_kind = "decode"
-    step_tokens = 1       # a step runs one token a sequence
-    # what a step feeds for a view row with no input embedding yet: the model
-    # has no id to stand for one, so `row_token` says -1 and the step feeds zeros
-    unknown_token = -1
+    name, config, layout = "joyai", JoyaiConfig, LAYOUT
+    programs, slot_state = (prefill, decode_step), (init_state, state_bytes)
     # a prefill's time is the experts its tokens reach: 4 sessions' 100-odd
     # real tokens already touch most of a layer's 256
     prefill_rows = 4
-    step_rows = 32
-
-    def __init__(self, cfg: JoyaiConfig, dtype=jnp.bfloat16):
-        self.cfg = cfg
-        self.dtype = dtype
-        self.dim = cfg.hidden
-        self.steps = cfg.basket
-        self.block = cfg.basket
-        self.window = cfg.max_len
-        self.length_buckets = tuple(sorted({min(32, cfg.max_len), cfg.max_len}))
-
-    @staticmethod
-    def from_extensions(ext) -> "JoyaiEncoder":
-        return JoyaiEncoder(
-            JoyaiConfig.from_extensions(ext), jnp.dtype(str(ext("dtype", "bfloat16")))
-        )
-
-    def load_params(self, tensors: dict) -> dict:
-        return params_of(self.cfg, tensors, self.dtype)
-
-    def device_params(self, params: dict) -> dict:
-        return params
-
-    def init_state(self, slots: int):
-        return init_state(self.cfg, slots, self.dtype)
-
-    def state_bytes(self, slots: int) -> dict[str, int]:
-        return state_bytes(self.cfg, slots, jnp.dtype(self.dtype).itemsize)
-
-    def prepare(self, seq_state, context_items):
-        """The E_in rows of the newest `max_len` context items that have
-        one (an item that arrived by UP since the model is skipped as
-        context until the next generation)."""
-        return announced_tokens(seq_state, context_items, self.cfg.max_len)
-
-    def length(self, prepared) -> int:
-        return int(prepared.shape[0]) - 1  # the last event is the first step's
-
-    def pack(self, prepared: list, bucket: int, slots, scratch: int):
-        tokens = np.zeros((self.prefill_rows, bucket), dtype=np.int32)
-        lengths = np.zeros((self.prefill_rows,), dtype=np.int32)
-        slot_of = np.full((self.prefill_rows,), scratch, dtype=np.int32)
-        last = np.zeros((self.prefill_rows,), dtype=np.int32)
-        for i, tok in enumerate(prepared):
-            tokens[i, : len(tok) - 1] = tok[:-1]
-            lengths[i], last[i], slot_of[i] = len(tok) - 1, tok[-1], slots[i]
-        return tokens, lengths, slot_of, last
-
-    # host operands ride the jitted call (the seam's comment, ops/seq.py)
-    def prefill(self, params, state, tokens, lengths, slots, last):
-        return prefill(self.cfg, params, state, tokens, lengths, slots, last)
-
-    def step(self, params, state, head, slots, lengths, live, step):
-        view, n_valid, row_token = head
-        rows = (slots, lengths, live, step)
-        state, out = decode_step(self.cfg, params, state, view, np.int32(n_valid), row_token, *rows)
-        out["head_rows"] = head_rows(view.shape[0], int(n_valid))
-        return state, out
-
-    def train(self, *args, **kw):
-        raise NotImplementedError(
-            "a JoyAI model reaches serving as an artifact; the batch layer trains the GRU"
-        )
 
 
 # -- the plain reference: float32, highest precision, no cache ---------------

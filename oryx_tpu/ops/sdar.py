@@ -45,18 +45,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from oryx_tpu.ops.decoder import DecoderEncoder, Layout, rms_norm, rope, view_head
+from oryx_tpu.ops.decoder import attend as _attend  # `sdar._attend`: the causal_block control patches it
 from oryx_tpu.ops.moe import moe_apply, moe_reference
-from oryx_tpu.ops.seq import announced_tokens, catalog_head, head_rows
 
-# tensors of an SDAR artifact, beside the catalog ("E", the FactorStore's):
-# "E_in", "final_norm" and, for layer l, "L<l>.<name>" of each of these. A
-# layer's tensors are arrays of their own and never slices of a stacked one:
-# the grouped kernel takes whole buffers, and a slice of 400 MB of experts
-# would be copied for it at every step
-LAYER_TENSORS = (
-    "ln1", "wq", "wk", "wv", "q_norm", "k_norm", "wo",
-    "ln2", "router", "wg", "wu", "wd",
-)
 NORM_TENSORS = ("ln1", "ln2", "q_norm", "k_norm")
 
 
@@ -118,7 +110,8 @@ class SdarConfig(NamedTuple):
         }
 
 
-def layer_shapes(cfg: SdarConfig) -> dict[str, tuple]:
+def layer_shapes(cfg: SdarConfig, layer: int = 0) -> dict[str, tuple]:
+    """A layer's tensors (ops/decoder.py Layout); every layer's are alike."""
     H, E, F = cfg.hidden, cfg.experts, cfg.expert_width
     q, kv = cfg.heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
     return {
@@ -129,80 +122,12 @@ def layer_shapes(cfg: SdarConfig) -> dict[str, tuple]:
     }
 
 
-def tensor_shapes(cfg: SdarConfig) -> dict[str, tuple]:
-    """Every tensor of an artifact by its name."""
-    out = {"E_in": (cfg.vocab, cfg.hidden), "final_norm": (cfg.hidden,)}
-    for l in range(cfg.layers):
-        out.update({f"L{l}.{k}": v for k, v in layer_shapes(cfg).items()})
-    return out
-
-
-def param_count(cfg: SdarConfig) -> int:
-    return sum(int(np.prod(v)) for v in tensor_shapes(cfg).values())
-
-
-def init_tensors(cfg: SdarConfig, seed: int, dtype=jnp.bfloat16) -> dict:
-    """An artifact's tensors, standard normal x 0.02 (norm weights 1) from
-    the seed, made on the device one tensor at a time."""
-    out = {}
-    for i, (name, shape) in enumerate(sorted(tensor_shapes(cfg).items())):
-        if name == "final_norm" or name.split(".")[-1] in NORM_TENSORS:
-            out[name] = jnp.ones(shape, dtype=dtype)
-        else:
-            key = jax.random.fold_in(jax.random.PRNGKey(seed % (2**31)), i)
-            out[name] = _normal(key, shape, dtype)
-    return out
-
-
-@partial(jax.jit, static_argnums=(1, 2))
-def _normal(key, shape, dtype):
-    return (jax.random.normal(key, shape, dtype=jnp.float32) * 0.02).astype(dtype)
-
-
-def params_of(cfg: SdarConfig, tensors: dict, dtype=None) -> dict:
-    """An artifact's tensors -> the parameters the forms below take:
-    {"E_in", "final_norm", "layers": [{name: array}, ...]}, checked against
-    the shapes the configuration states; cast to `dtype` where one is given
-    (an array already on the device in that dtype is taken as it is)."""
-    for name, shape in tensor_shapes(cfg).items():
-        if name not in tensors:
-            raise ValueError(f"SDAR model lacks tensor {name!r}")
-        if tuple(np.shape(tensors[name])) != shape:
-            raise ValueError(
-                f"SDAR tensor {name!r} shaped {tuple(np.shape(tensors[name]))}, "
-                f"the extensions say {shape}"
-            )
-    take = lambda name: jnp.asarray(tensors[name], dtype=dtype)
-    return {
-        "E_in": take("E_in"), "final_norm": take("final_norm"),
-        "layers": [
-            {k: take(f"L{l}.{k}") for k in LAYER_TENSORS} for l in range(cfg.layers)
-        ],
-    }
-
-
-def init_params(cfg: SdarConfig, seed: int, dtype=jnp.bfloat16) -> dict:
-    return params_of(cfg, init_tensors(cfg, seed, dtype))
+LAYOUT = Layout("SDAR", layer_shapes, NORM_TENSORS)
+tensor_shapes, param_count, init_tensors = LAYOUT.tensor_shapes, LAYOUT.param_count, LAYOUT.init_tensors
+params_of, init_params = LAYOUT.params_of, LAYOUT.init_params
 
 
 # -- pieces both forms share (the dtype of the inputs decides the precision) --
-
-def rms_norm(x, w, eps):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w.astype(jnp.float32)
-
-
-def rope(x, pos, theta):
-    """x [..., T, heads, d] float32, pos [..., T] -> rotated over the whole
-    head (the rotate-half form: dimension i pairs with i + d/2)."""
-    d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = pos.astype(jnp.float32)[..., None] * inv            # [..., T, d/2]
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)[..., None, :]
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)[..., None, :]
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
-
 
 def _qkv(cfg: SdarConfig, p: dict, u, pos):
     """u [R,T,H] float32 -> q [R,T,heads,d], k, v [R,T,kv,d] float32, q and k
@@ -217,25 +142,6 @@ def _qkv(cfg: SdarConfig, p: dict, u, pos):
     q = rope(rms_norm(q, p["q_norm"], cfg.eps), pos, cfg.rope_theta)
     k = rope(rms_norm(k, p["k_norm"], cfg.eps), pos, cfg.rope_theta)
     return q, k, v
-
-
-def _attend(cfg: SdarConfig, q, k, v, allowed, dt):
-    """q [R,T,heads,d], k/v [R,S,kv,d], allowed [R,T,S] bool -> [R,T,heads*d]
-    float32. Scores and softmax in float32; the products take q, k, the
-    probabilities and v in `dt`."""
-    f32 = jnp.float32
-    r, t = q.shape[0], q.shape[1]
-    group = cfg.heads // cfg.kv_heads
-    qg = q.reshape(r, t, cfg.kv_heads, group, cfg.head_dim).astype(dt)
-    s = jnp.einsum("rtgjd,rsgd->rgjts", qg, k.astype(dt), preferred_element_type=f32)
-    s = s / math.sqrt(cfg.head_dim)
-    s = jnp.where(allowed[:, None, None, :, :], s, -jnp.inf)
-    # a padding query may be allowed nothing: its row is zeros, not NaN
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - jnp.where(jnp.isfinite(m), m, 0.0))
-    prob = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
-    o = jnp.einsum("rgjts,rsgd->rtgjd", prob.astype(dt), v.astype(dt), preferred_element_type=f32)
-    return o.reshape(r, t, cfg.heads * cfg.head_dim)
 
 
 def _moe(cfg: SdarConfig, p: dict, x, live):
@@ -271,10 +177,9 @@ def init_state(cfg: SdarConfig, slots: int, dtype=jnp.bfloat16) -> dict:
     }
 
 
-def state_bytes(cfg: SdarConfig, slots: int, itemsize: int = 2) -> int:
-    s = slots + 1
-    kv = 2 * cfg.layers * s * cfg.max_len * cfg.kv_heads * cfg.head_dim * itemsize
-    return kv + s * cfg.block_length * (4 + 1 + 4 * cfg.hidden + 4 + 4)
+def state_bytes(cfg: SdarConfig, slots: int, itemsize: int = 2) -> dict[str, int]:
+    """The slots' keys and values (the block's own state is a few KiB)."""
+    return {"kv": 2 * cfg.layers * (slots + 1) * cfg.max_len * cfg.kv_heads * cfg.head_dim * itemsize}
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
@@ -364,11 +269,7 @@ def denoise_step(
     d_rows, b = slots.shape[0], cfg.block_length
     z, counts = _block_hidden(cfg, params, state, slots, lengths, live)
     with jax.named_scope("sdar.head"):
-        dt = view.dtype
-        zq = z.reshape(d_rows * b, cfg.hidden).astype(dt)
-        # a served view is lane-padded (ops/pallas_topk.py view_shape)
-        zq = jnp.pad(zq, ((0, 0), (0, view.shape[1] - cfg.hidden)))
-        _top, arg, conf = catalog_head(zq, view, n_valid)
+        _top, arg, conf = view_head(z.reshape(d_rows * b, cfg.hidden), view, n_valid)
         arg, conf = arg.reshape(d_rows, b), conf.reshape(d_rows, b)
     masked = state["masked"][slots]
     pick = jnp.argmax(jnp.where(masked, conf, -1.0), axis=-1)                  # [D]
@@ -391,92 +292,32 @@ def denoise_step(
 
 # -- behind the encoder seam (ops/seq.py) ------------------------------------
 
-class SdarEncoder:
-    """The block behind the seam: `prefill` fills a request's cache slot,
-    `steps` denoising steps follow, and the request hands the catalog scan
-    `block` rows. Shapes are few and fixed: a prefill is `prefill_rows`
-    sessions padded to a length bucket, a step is `step_rows` blocks."""
+class SdarEncoder(DecoderEncoder):
+    """The block behind the seam (ops/decoder.py DecoderEncoder): `prefill`
+    fills a request's cache slot with its whole session, `steps` denoising
+    steps follow, and the request hands the catalog scan its block's
+    positions, `block` rows."""
 
-    name = "sdar"
-    own_input = True  # E_in: an input embedding apart from the catalog
+    name, config, layout = "sdar", SdarConfig, LAYOUT
+    programs, slot_state = (prefill, denoise_step), (init_state, state_bytes)
     step_kind = "denoise"
+    feeds_last = False  # the whole session is the prefix; a block of [MASK] follows it
     prefill_rows = 8
-    step_rows = 32
 
-    def __init__(self, cfg: SdarConfig, dtype=jnp.bfloat16):
-        self.cfg = cfg
-        self.dtype = dtype
-        self.dim = cfg.hidden
-        self.steps = cfg.denoise_steps
-        self.block = cfg.block_length
-        self.step_tokens = cfg.block_length  # a step runs every position of a block
-        self.window = cfg.max_len
-        self.length_buckets = tuple(sorted({min(32, cfg.max_len), cfg.max_len}))
+    @property
+    def steps(self) -> int:
+        return self.cfg.denoise_steps
 
-    @staticmethod
-    def from_extensions(ext) -> "SdarEncoder":
-        return SdarEncoder(
-            SdarConfig.from_extensions(ext), jnp.dtype(str(ext("dtype", "bfloat16")))
-        )
+    @property
+    def block(self) -> int:
+        return self.cfg.block_length
 
-    def load_params(self, tensors: dict) -> dict:
-        """An artifact's tensors -> the parameters on the device in the
-        dtype the artifact states, checked against the shapes its
-        extensions state."""
-        return params_of(self.cfg, tensors, self.dtype)
-
-    def device_params(self, params: dict) -> dict:
-        return params
-
-    def init_state(self, slots: int):
-        return init_state(self.cfg, slots, self.dtype)
-
-    def state_bytes(self, slots: int) -> dict[str, int]:
-        """The slots' keys and values (the block's own state is a few KiB)."""
-        c = self.cfg
-        per_position = 2 * c.layers * c.kv_heads * c.head_dim * jnp.dtype(self.dtype).itemsize
-        return {"kv": (slots + 1) * c.max_len * per_position}
+    step_tokens = block  # a step runs every position of a block
 
     @property
     def unknown_token(self) -> int:
         """What a step feeds for a view row with no input embedding yet."""
         return self.cfg.mask_id
-
-    def prepare(self, seq_state, context_items):
-        """The E_in rows of the newest `max_len` context items that have
-        one; an item the model was not announced with (it arrived by UP
-        since) has a head row and no input embedding, and is skipped as
-        context until the next generation."""
-        return announced_tokens(seq_state, context_items, self.cfg.max_len)
-
-    def length(self, prepared) -> int:
-        return int(prepared.shape[0])
-
-    def pack(self, prepared: list, bucket: int, slots, scratch: int):
-        tokens = np.zeros((self.prefill_rows, bucket), dtype=np.int32)
-        lengths = np.zeros((self.prefill_rows,), dtype=np.int32)
-        slot_of = np.full((self.prefill_rows,), scratch, dtype=np.int32)
-        for i, tok in enumerate(prepared):
-            tokens[i, : len(tok)] = tok
-            lengths[i] = len(tok)
-            slot_of[i] = slots[i]
-        return tokens, lengths, slot_of
-
-    # host operands ride the jitted call (the seam's comment, ops/seq.py)
-    def prefill(self, params, state, tokens, lengths, slots):
-        return prefill(self.cfg, params, state, tokens, lengths, slots)
-
-    def step(self, params, state, head, slots, lengths, live, step):
-        view, n_valid, row_token = head
-        rows = (slots, lengths, live, step)
-        state, out = denoise_step(self.cfg, params, state, view, np.int32(n_valid), row_token, *rows)
-        out["head_rows"] = head_rows(view.shape[0], int(n_valid))
-        return state, out
-
-    def train(self, *args, **kw):
-        raise NotImplementedError(
-            "an SDAR model reaches serving as an artifact; the batch layer trains the GRU"
-        )
 
 
 # -- the plain reference: float32, highest precision, no cache ---------------

@@ -18,15 +18,16 @@ predictions no longer care about.
 
 from __future__ import annotations
 
+import importlib
 import math
-from typing import NamedTuple
+from typing import NamedTuple, Protocol
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-from oryx_tpu.ops.pallas_head import head_pallas, head_rows
+from oryx_tpu.ops.pallas_head import head_pallas
 
 # Non-embedding GRU parameter names, in artifact/tensor order. The
 # embedding matrix "E" rides separately: it is also the serving catalog
@@ -295,49 +296,68 @@ def catalog_head(z, view, n_valid):
 
 
 # -- the encoder seam ---------------------------------------------------------
-#
-# What turns a session into the vector(s) the catalog scan ranks is an
-# ENCODER. The three tiers reach it through one interface and never call a
-# model's functions directly; which encoder a model has is written in its
-# artifact (extension "encoder", absent: "gru"), never in a config key.
-#
-#   name, steps, block      `steps` device steps follow the prefill (0: the
-#                           prefill's hidden state is the answer); a request
-#                           hands the scan `block` rows
-#   own_input               the encoder has an input embedding apart from the
-#                           catalog (row-aligned with the announced ids)
-#   length_buckets          the padded context lengths a prefill compiles
-#   prefill_rows, step_rows rows of a prefill and of a step dispatch
-#   load_params(tensors)    an artifact's tensors -> parameters, checked
-#   device_params(params)   the parameters as the device calls take them
-#   init_state(slots)       per-request device state for `slots` requests
-#                           (None: the encoder keeps none)
-#   prepare(seq_state, items) -> one request's host input, or None when no
-#                           context item is known to the model
-#   length(prepared)        its context length (picks the bucket)
-#   pack(prepared, bucket, slots, scratch) -> the arrays of one prefill
-#   prefill(params, state, *packed) -> (state, hidden [rows, d], counts)
-#   step(params, state, head, slots, lengths, live, step) -> (state, out)
-#                           `out["head_rows"]`: (rows walked, rows skipped) of
-#                           the step's catalog head, host numbers
-#                           `packed` and `slots, lengths, live, step` are HOST
-#                           arrays (numpy, fresh every dispatch), and they and
-#                           the head's valid rows (an `np.int32`) are handed
-#                           to the jitted program as they are: the call's own
-#                           argument path transfers them. A wrapper uploads
-#                           nothing before it (no `jnp.asarray`, `jnp.int32`
-#                           or `device_put`: each is a trip through Python and
-#                           a transfer of its own, 0.27-0.38 ms on the serving
-#                           host where the call's own transfer of a numpy
-#                           operand is 0.14); a jitted program takes device
-#                           arrays too
-#   step_kind, step_tokens  the label a step dispatch counts under and the
-#                           tokens a row of it runs (a block's positions, or
-#                           one token a sequence)
-#   unknown_token           what a step feeds for a view row with no input
-#                           embedding yet (None: it feeds the view's own row)
-#   state_bytes(slots)      {kind of state: bytes} of the slots' state
-#   train(...) / loss       for the encoder that trains
+
+class Encoder(Protocol):
+    """What turns a session into the vector(s) the catalog scan ranks. The
+    three tiers reach it through this seam and never call a model's functions
+    directly; which encoder a model has is written in its artifact (extension
+    "encoder", absent: "gru"; `encoder_for`), never in a config key. The GRU
+    answers after its prefill; a generating decoder (ops/decoder.py
+    `DecoderEncoder`) runs `steps` device steps after it, and only an encoder
+    with steps has the members marked (steps).
+
+    Host operands ride the jitted call: `pack`'s arrays and `step`'s `slots,
+    lengths, live, step` are HOST arrays (numpy, fresh every dispatch), and
+    they and the head's valid rows (an `np.int32`) are handed to the jitted
+    program as they are: the call's own argument path transfers them. A
+    wrapper uploads nothing before it (no `jnp.asarray`, `jnp.int32` or
+    `device_put`: each is a trip through Python and a transfer of its own,
+    0.27-0.38 ms on the serving host where the call's own transfer of a numpy
+    operand is 0.14); a jitted program takes device arrays too.
+
+    The GRU's own: `train(...)` / `loss` (the batch layer trains it) and
+    `encode_host` (the speed layer's fold)."""
+
+    name: str
+    dim: int                   # the hidden state's width
+    window: int                # the newest context items a session keeps
+    own_input: bool            # an input embedding apart from the catalog, row-aligned with the announced ids
+    steps: int                 # device steps after the prefill (0: the prefill's hidden state is the answer)
+    block: int                 # rows a request hands the scan
+    length_buckets: tuple      # the padded context lengths a prefill compiles
+    prefill_rows: int          # rows of a prefill dispatch
+    step_rows: int             # rows of a step dispatch
+    step_kind: str             # (steps) the label a step dispatch counts under
+    step_tokens: int           # (steps) the tokens a row of a step runs: a block's positions, or one
+    unknown_token: int | None  # (steps) what a step feeds for a view row with no input embedding yet (None: the row)
+
+    def load_params(self, tensors: dict) -> dict:
+        """An artifact's tensors -> parameters, checked."""
+
+    def device_params(self, params: dict) -> dict:
+        """The parameters as the device calls take them."""
+
+    def init_state(self, slots: int):
+        """Per-request device state for `slots` requests (None: the encoder keeps none)."""
+
+    def state_bytes(self, slots: int) -> dict[str, int]:
+        """(steps) {kind of state: bytes} of the slots' state."""
+
+    def prepare(self, seq_state, context_items):
+        """One request's host input, or None when no context item is known to the model."""
+
+    def length(self, prepared) -> int:
+        """Its context length (picks the bucket)."""
+
+    def pack(self, prepared: list, bucket: int, slots, scratch: int) -> tuple:
+        """The host arrays of one prefill."""
+
+    def prefill(self, params, state, *packed):
+        """-> (state, hidden [rows, d], the expert layers' counts or None)."""
+
+    def step(self, params, state, head, slots, lengths, live, step):
+        """(steps) -> (state, out); `out["head_rows"]`: (rows walked, rows
+        skipped) of the step's catalog head, host numbers."""
 
 
 class GruEncoder:
@@ -355,6 +375,10 @@ class GruEncoder:
         self.dim = int(dim)
         self.window = int(window)
         self.length_buckets = (self.window,)
+
+    @classmethod
+    def from_extensions(cls, ext) -> "GruEncoder":
+        return cls(int(ext("dim")), int(ext("window", 8)))
 
     def state_spec(self) -> dict:
         return {"h": ((self.dim,), np.float32)}
@@ -439,25 +463,20 @@ def announced_tokens(seq_state, context_items, max_len: int):
     return np.asarray(tokens[-max_len:], dtype=np.int32) if tokens else None
 
 
-def encoder_for(name: str, ext):
+# an artifact's "encoder" -> (module, class), imported when a model names it
+ENCODERS = {
+    "gru": (__name__, "GruEncoder"),
+    "sdar": ("oryx_tpu.ops.sdar", "SdarEncoder"),
+    "jamba": ("oryx_tpu.ops.jamba", "JambaEncoder"),
+    "joyai": ("oryx_tpu.ops.joyai", "JoyaiEncoder"),
+    "trinity": ("oryx_tpu.ops.trinity", "TrinityEncoder"),
+}
+
+
+def encoder_for(name: str, ext) -> Encoder:
     """The encoder an artifact names; `ext(key, default)` reads the
     artifact's extensions."""
-    if name == "gru":
-        return GruEncoder(int(ext("dim")), int(ext("window", 8)))
-    if name == "sdar":
-        from oryx_tpu.ops.sdar import SdarEncoder
-
-        return SdarEncoder.from_extensions(ext)
-    if name == "jamba":
-        from oryx_tpu.ops.jamba import JambaEncoder
-
-        return JambaEncoder.from_extensions(ext)
-    if name == "joyai":
-        from oryx_tpu.ops.joyai import JoyaiEncoder
-
-        return JoyaiEncoder.from_extensions(ext)
-    if name == "trinity":
-        from oryx_tpu.ops.trinity import TrinityEncoder
-
-        return TrinityEncoder.from_extensions(ext)
-    raise ValueError(f"unknown seq encoder {name!r}")
+    if name not in ENCODERS:
+        raise ValueError(f"unknown seq encoder {name!r}")
+    module, cls = ENCODERS[name]
+    return getattr(importlib.import_module(module), cls).from_extensions(ext)

@@ -67,16 +67,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from oryx_tpu.ops.joyai import _dot, _swiglu
+from oryx_tpu.ops.decoder import (
+    DecoderEncoder, Layout, advance, attend, basket, dot, fed_back, reset, rms_norm, rope,
+    router_bias, swiglu, view_head,
+)
 from oryx_tpu.ops.moe import moe_apply, moe_reference
-from oryx_tpu.ops.sdar import _attend, _normal, rms_norm, rope
-from oryx_tpu.ops.seq import announced_tokens, catalog_head, head_rows
 
-# tensors of a Trinity artifact, beside the catalog ("E", the FactorStore's):
-# "E_in", "final_norm" and, for layer l, "L<l>.<name>" of `layer_shapes`. A
-# layer's tensors are arrays of their own (ops/sdar.py says why)
 NORM_TENSORS = ("ln1", "ln1_post", "ln2", "ln2_post", "q_norm", "k_norm")
-BIAS_INIT = 0.1  # the selecting bias is drawn normal x this (a trained model carries one)
 LAYER_TYPES = ("sliding_attention", "full_attention")
 # keys of the source that name a form, and the one form of each computed here
 # (as an artifact's extensions spell them, lower case)
@@ -217,82 +214,27 @@ def layer_shapes(cfg: TrinityConfig, layer: int) -> dict[str, tuple]:
     return out
 
 
-def tensor_shapes(cfg: TrinityConfig) -> dict[str, tuple]:
-    """Every tensor of an artifact by its name."""
-    out = {"E_in": (cfg.vocab, cfg.hidden), "final_norm": (cfg.hidden,)}
-    for l in range(cfg.layers):
-        out.update({f"L{l}.{k}": v for k, v in layer_shapes(cfg, l).items()})
-    return out
+# norm weights 1 (the published depth scaling of the sandwich norms is an
+# initialisation, not a form); the router's selecting bias float32
+# (ops/decoder.py `router_bias`)
+LAYOUT = Layout(
+    "Trinity", layer_shapes, NORM_TENSORS, special={"router_bias": router_bias}, float32=("router_bias",),
+)
+tensor_shapes, param_count, init_tensors = LAYOUT.tensor_shapes, LAYOUT.param_count, LAYOUT.init_tensors
+params_of, init_params = LAYOUT.params_of, LAYOUT.init_params
 
 
-def param_count(cfg: TrinityConfig) -> int:
-    return sum(int(np.prod(v)) for v in tensor_shapes(cfg).values())
-
-
-@partial(jax.jit, static_argnums=(1,))
-def _bias(key, shape):
-    return jax.random.normal(key, shape, dtype=jnp.float32) * BIAS_INIT
-
-
-def init_tensors(cfg: TrinityConfig, seed: int, dtype=jnp.bfloat16) -> dict:
-    """An artifact's tensors from the seed, made on the device one at a time:
-    standard normal x 0.02, norm weights 1 (the published depth scaling of
-    the sandwich norms is an initialisation, not a form), and the router's
-    selecting bias normal x `BIAS_INIT` in float32 (zeros, a fresh model's,
-    would make it invisible)."""
-    out = {}
-    for i, (name, shape) in enumerate(sorted(tensor_shapes(cfg).items())):
-        kind = name.split(".")[-1]
-        key = jax.random.fold_in(jax.random.PRNGKey(seed % (2**31)), i)
-        if kind == "final_norm" or kind in NORM_TENSORS:
-            out[name] = jnp.ones(shape, dtype=dtype)
-        elif kind == "router_bias":
-            out[name] = _bias(key, shape)
-        else:
-            out[name] = _normal(key, shape, dtype)
-    return out
-
-
-def params_of(cfg: TrinityConfig, tensors: dict, dtype=None) -> dict:
-    """An artifact's tensors -> the parameters the forms below take:
-    {"E_in", "final_norm", "layers": [{name: array}, ...]}, checked against
-    the shapes the configuration states; cast to `dtype` where one is given
-    (the selecting bias to float32 always)."""
-    for name, shape in tensor_shapes(cfg).items():
-        if name not in tensors:
-            raise ValueError(f"Trinity model lacks tensor {name!r}")
-        if tuple(np.shape(tensors[name])) != shape:
-            raise ValueError(
-                f"Trinity tensor {name!r} shaped {tuple(np.shape(tensors[name]))}, "
-                f"the extensions say {shape}"
-            )
-
-    def take(name):
-        return jnp.asarray(tensors[name], dtype=jnp.float32 if name.endswith("router_bias") else dtype)
-
-    return {
-        "E_in": take("E_in"), "final_norm": take("final_norm"),
-        "layers": [
-            {k: take(f"L{l}.{k}") for k in layer_shapes(cfg, l)} for l in range(cfg.layers)
-        ],
-    }
-
-
-def init_params(cfg: TrinityConfig, seed: int, dtype=jnp.bfloat16) -> dict:
-    return params_of(cfg, init_tensors(cfg, seed, dtype))
-
-
-# -- pieces both served programs share (the dtype of the weights decides the
-# precision of a product's inputs: ops/joyai.py `_dot`) ----------------------
+# -- pieces both served programs share (ops/decoder.py `dot`: the dtype of the
+# weights decides the precision of a product's inputs) ----------------------
 
 def _qkv(cfg: TrinityConfig, p: dict, a, pos, sliding: bool):
     """a [R,T,H] float32 (normalised), pos [R,T] -> q [R,T,heads,d], k, v
     [R,T,kv,d] float32; q and k normalised per head and, on a sliding layer
     alone, turned by their position."""
     r, t = a.shape[0], a.shape[1]
-    q = rms_norm(_dot(a, p["wq"]).reshape(r, t, cfg.heads, cfg.head_dim), p["q_norm"], cfg.eps)
-    k = rms_norm(_dot(a, p["wk"]).reshape(r, t, cfg.kv_heads, cfg.head_dim), p["k_norm"], cfg.eps)
-    v = _dot(a, p["wv"]).reshape(r, t, cfg.kv_heads, cfg.head_dim)
+    q = rms_norm(dot(a, p["wq"]).reshape(r, t, cfg.heads, cfg.head_dim), p["q_norm"], cfg.eps)
+    k = rms_norm(dot(a, p["wk"]).reshape(r, t, cfg.kv_heads, cfg.head_dim), p["k_norm"], cfg.eps)
+    v = dot(a, p["wv"]).reshape(r, t, cfg.kv_heads, cfg.head_dim)
     if sliding:
         q, k = rope(q, pos, cfg.rope_theta), rope(k, pos, cfg.rope_theta)
     return q, k, v
@@ -301,14 +243,14 @@ def _qkv(cfg: TrinityConfig, p: dict, a, pos, sliding: bool):
 def _attn_out(cfg: TrinityConfig, p: dict, x, a, attended):
     """The attention's end: `attended` [..., heads * d] float32 gated by
     sigmoid(a W_gate), through W_o and the norm after it, added to x."""
-    gated = attended * jax.nn.sigmoid(_dot(a, p["wgate"]))
-    return x + rms_norm(_dot(gated, p["wo"]), p["ln1_post"], cfg.eps)
+    gated = attended * jax.nn.sigmoid(dot(a, p["wgate"]))
+    return x + rms_norm(dot(gated, p["wo"]), p["ln1_post"], cfg.eps)
 
 
 def _shared_expert(p: dict, u):
     """The shared expert's SwiGLU of every token's `u` [N,H] float32."""
     with jax.named_scope("trinity.shared"):
-        return _swiglu(u, p["shared_wg"], p["shared_wu"], p["shared_wd"])
+        return swiglu(u, p["shared_wg"], p["shared_wu"], p["shared_wd"])
 
 
 def _ffn(cfg: TrinityConfig, p: dict, x, live):
@@ -318,7 +260,7 @@ def _ffn(cfg: TrinityConfig, p: dict, x, live):
     if "router" not in p:
         with jax.named_scope("trinity.dense"):
             m = rms_norm(x, p["ln2"], cfg.eps)
-            f = _swiglu(m, p["wg"], p["wu"], p["wd"])
+            f = swiglu(m, p["wg"], p["wu"], p["wd"])
             return x + rms_norm(f, p["ln2_post"], cfg.eps), jnp.zeros((4,), jnp.int32)
     with jax.named_scope("trinity.moe"):
         flat = rms_norm(x, p["ln2"], cfg.eps).reshape(-1, cfg.hidden)
@@ -339,16 +281,12 @@ def init_state(cfg: TrinityConfig, slots: int, dtype=jnp.bfloat16) -> dict:
     rows a slot in a full layer and `cache_rows` (the window, where it is
     shorter) in a sliding one. x_in: the next step's input embedding; z / row
     / step: the basket (for each position generated the hidden state, the view
-    row chosen and the step that chose it)."""
-    s, b = slots + 1, cfg.basket
-    kv = [(s, cfg.cache_rows(l), cfg.kv_heads, cfg.head_dim) for l in range(cfg.layers)]
+    row chosen and the step that chose it: ops/decoder.py `basket`)."""
+    kv = [(slots + 1, cfg.cache_rows(l), cfg.kv_heads, cfg.head_dim) for l in range(cfg.layers)]
     return {
         "k": [jnp.zeros(shape, dtype) for shape in kv],
         "v": [jnp.zeros(shape, dtype) for shape in kv],
-        "x_in": jnp.zeros((s, cfg.hidden), dtype),
-        "z": jnp.zeros((s, b, cfg.hidden), jnp.float32),
-        "row": jnp.full((s, b), -1, jnp.int32),
-        "step": jnp.full((s, b), -1, jnp.int32),
+        **basket(cfg, slots, dtype),
     }
 
 
@@ -402,18 +340,12 @@ def prefill(cfg: TrinityConfig, params: dict, state: dict, tokens, lengths, slot
             rows = cfg.cache_rows(l)
             k_cache[l] = k_cache[l].at[slots].set(_kept(k, lengths, rows).astype(k_cache[l].dtype))
             v_cache[l] = v_cache[l].at[slots].set(_kept(v, lengths, rows).astype(v_cache[l].dtype))
-            x = _attn_out(cfg, p, x, a, _attend(cfg, q, k, v, causal & in_window if sliding else causal, dt))
+            x = _attn_out(cfg, p, x, a, attend(cfg, q, k, v, causal & in_window if sliding else causal, dt))
         x, n = _ffn(cfg, p, x, live)
         counts = counts + n
     with jax.named_scope("trinity.embed"):
         hidden = x[jnp.arange(p_rows), jnp.maximum(lengths - 1, 0)]
-        state = dict(
-            state, k=k_cache, v=v_cache,
-            x_in=state["x_in"].at[slots].set(params["E_in"][last].astype(state["x_in"].dtype)),
-            z=state["z"].at[slots].set(jnp.zeros((cfg.basket, cfg.hidden), f32)),
-            row=state["row"].at[slots].set(-1),
-            step=state["step"].at[slots].set(-1),
-        )
+        state = reset(state, slots, params["E_in"][last], k=k_cache, v=v_cache)
     return state, hidden, counts
 
 
@@ -440,7 +372,7 @@ def _token_hidden(cfg: TrinityConfig, params: dict, state: dict, slots, pos, liv
             # it is not negative, and never a whole window behind
             c = jnp.arange(rows, dtype=jnp.int32)[None, :]
             allowed = (pos[:, None] - (pos[:, None] - c) % rows >= 0)[:, None, :]
-            o = _attend(cfg, q, k_cache[l][slots], v_cache[l][slots], allowed, dt)
+            o = attend(cfg, q, k_cache[l][slots], v_cache[l][slots], allowed, dt)
             x = _attn_out(cfg, p, x, a, o[:, 0])
         x, n = _ffn(cfg, p, x, live)
         counts = counts + n
@@ -465,111 +397,26 @@ def decode_step(
     generated so far, "row": [D,B] the view rows chosen, "step": [D,B] the
     steps that chose them, "counts": int32[4]}: what a finished request
     needs, and every row's, so one fetch serves whichever finished."""
-    b = cfg.basket
     z, k_cache, v_cache, counts = _token_hidden(cfg, params, state, slots, lengths + step, live)
     with jax.named_scope("trinity.head"):
-        dt = view.dtype
-        zq = jnp.pad(z.astype(dt), ((0, 0), (0, view.shape[1] - cfg.hidden)))
-        _top, arg, _conf = catalog_head(zq, view, n_valid)
+        _top, arg, _conf = view_head(z, view, n_valid)
     with jax.named_scope("trinity.embed"):
-        token = row_token[arg]
-        fed = jnp.where((token >= 0)[:, None], params["E_in"][jnp.maximum(token, 0)], 0)
-        here = (jnp.arange(b)[None, :] == step[:, None]) & live[:, None]         # [D,B]
-        new_z = jnp.where(here[:, :, None], z[:, None, :], state["z"][slots])
-        new_row = jnp.where(here, arg[:, None], state["row"][slots])
-        new_step = jnp.where(here, step[:, None], state["step"][slots])
-        state = dict(
-            state, k=k_cache, v=v_cache,
-            x_in=state["x_in"].at[slots].set(fed.astype(state["x_in"].dtype)),
-            z=state["z"].at[slots].set(new_z),
-            row=state["row"].at[slots].set(new_row),
-            step=state["step"].at[slots].set(new_step),
-        )
-    return state, {"z": new_z, "row": new_row, "step": new_step, "counts": counts}
+        fed = fed_back(params, row_token, arg)
+        state, out = advance(state, slots, step, live, z, arg, fed, k=k_cache, v=v_cache)
+    return state, dict(out, counts=counts)
 
 
 # -- behind the encoder seam (ops/seq.py) ------------------------------------
 
-class TrinityEncoder:
-    """The decoder behind the seam: `prefill` runs a request's events but the
-    last into its cache slot, `steps` one-token steps follow, and the request
-    hands the catalog scan `block` rows. Shapes are few and fixed: a prefill
-    is `prefill_rows` sessions padded to a length bucket, a step is
-    `step_rows` tokens."""
+class TrinityEncoder(DecoderEncoder):
+    """A Trinity decoder behind the seam (ops/decoder.py DecoderEncoder); its
+    `window` is the events of a session kept, not the attention's window."""
 
-    name = "trinity"
-    own_input = True      # E_in: an input embedding apart from the (untied) head
-    step_kind = "decode"
-    step_tokens = 1       # a step runs one token a sequence
-    # what a step feeds for a view row with no input embedding yet: the model
-    # has no id to stand for one, so `row_token` says -1 and the step feeds zeros
-    unknown_token = -1
+    name, config, layout = "trinity", TrinityConfig, LAYOUT
+    programs, slot_state = (prefill, decode_step), (init_state, state_bytes)
     # a prefill's time is the held experts its tokens reach, 56.6 MB each at
     # the published widths: 4 sessions' 100-odd tokens reach most of a share
     prefill_rows = 4
-    step_rows = 32
-
-    def __init__(self, cfg: TrinityConfig, dtype=jnp.bfloat16):
-        self.cfg = cfg
-        self.dtype = dtype
-        self.dim = cfg.hidden
-        self.steps = cfg.basket
-        self.block = cfg.basket
-        self.window = cfg.max_len  # the events of a session kept, not the attention's window
-        self.length_buckets = tuple(sorted({min(32, cfg.max_len), cfg.max_len}))
-
-    @staticmethod
-    def from_extensions(ext) -> "TrinityEncoder":
-        return TrinityEncoder(
-            TrinityConfig.from_extensions(ext), jnp.dtype(str(ext("dtype", "bfloat16")))
-        )
-
-    def load_params(self, tensors: dict) -> dict:
-        return params_of(self.cfg, tensors, self.dtype)
-
-    def device_params(self, params: dict) -> dict:
-        return params
-
-    def init_state(self, slots: int):
-        return init_state(self.cfg, slots, self.dtype)
-
-    def state_bytes(self, slots: int) -> dict[str, int]:
-        return state_bytes(self.cfg, slots, jnp.dtype(self.dtype).itemsize)
-
-    def prepare(self, seq_state, context_items):
-        """The E_in rows of the newest `max_len` context items that have
-        one (an item that arrived by UP since the model is skipped as
-        context until the next generation)."""
-        return announced_tokens(seq_state, context_items, self.cfg.max_len)
-
-    def length(self, prepared) -> int:
-        return int(prepared.shape[0]) - 1  # the last event is the first step's
-
-    def pack(self, prepared: list, bucket: int, slots, scratch: int):
-        tokens = np.zeros((self.prefill_rows, bucket), dtype=np.int32)
-        lengths = np.zeros((self.prefill_rows,), dtype=np.int32)
-        slot_of = np.full((self.prefill_rows,), scratch, dtype=np.int32)
-        last = np.zeros((self.prefill_rows,), dtype=np.int32)
-        for i, tok in enumerate(prepared):
-            tokens[i, : len(tok) - 1] = tok[:-1]
-            lengths[i], last[i], slot_of[i] = len(tok) - 1, tok[-1], slots[i]
-        return tokens, lengths, slot_of, last
-
-    # host operands ride the jitted call (the seam's comment, ops/seq.py)
-    def prefill(self, params, state, tokens, lengths, slots, last):
-        return prefill(self.cfg, params, state, tokens, lengths, slots, last)
-
-    def step(self, params, state, head, slots, lengths, live, step):
-        view, n_valid, row_token = head
-        rows = (slots, lengths, live, step)
-        state, out = decode_step(self.cfg, params, state, view, np.int32(n_valid), row_token, *rows)
-        out["head_rows"] = head_rows(view.shape[0], int(n_valid))
-        return state, out
-
-    def train(self, *args, **kw):
-        raise NotImplementedError(
-            "a Trinity model reaches serving as an artifact; the batch layer trains the GRU"
-        )
 
 
 # -- the plain reference: float32, highest precision, no cache ---------------

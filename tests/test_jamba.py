@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from oryx_tpu.ops import jamba, sdar
-from oryx_tpu.ops.seq import catalog_head, encoder_for
+from oryx_tpu.ops.seq import catalog_head
 
 CFG = jamba.JambaConfig(
     hidden=64, heads=4, kv_heads=1, intermediate=96, layers=4, vocab=300,
@@ -190,14 +190,16 @@ def test_one_head_serves_both_generating_encoders():
     p = np.exp(logits - logits.max(-1, keepdims=True))
     np.testing.assert_allclose(np.asarray(conf), (p / p.sum(-1, keepdims=True)).max(-1), rtol=1e-4)
     assert arg.dtype == jnp.int32
-    # all four generating programs call it, each under its own scope
+    # all four generating programs call it, each under its own scope, through
+    # the one padding of the hidden state to the view's width
     import inspect
 
-    from oryx_tpu.ops import joyai, trinity
+    from oryx_tpu.ops import decoder, joyai, trinity
 
-    assert "catalog_head(" in inspect.getsource(sdar.denoise_step.__wrapped__)
+    assert "catalog_head(" in inspect.getsource(decoder.view_head)
+    assert "view_head(" in inspect.getsource(sdar.denoise_step.__wrapped__)
     for mod in (jamba, joyai, trinity):
-        assert "catalog_head(" in inspect.getsource(mod.decode_step.__wrapped__)
+        assert "view_head(" in inspect.getsource(mod.decode_step.__wrapped__)
 
 
 # ---- prefill, then steps, against the full forward pass ---------------------------
@@ -490,27 +492,6 @@ def _jamba_message(seed=7):
     art.set_extension("dtype", "float32")
     art.set_extension("ItemIDs", [f"i{j}" for j in range(N_ITEMS)])
     return art.to_string()
-
-
-def test_the_artifact_chooses_the_encoder():
-    from oryx_tpu.apps.seq.state import apply_seq_update
-    from oryx_tpu.common.artifact import ModelArtifact
-
-    st = apply_seq_update(None, "MODEL", _jamba_message())
-    assert st.encoder.name == "jamba" and st.encoder.cfg == CFG
-    assert st.dim == CFG.hidden and st.token_of["i3"] == 3
-    enc = encoder_for("jamba", {k: str(v) for k, v in CFG.to_extensions().items()}.get)
-    assert (enc.steps, enc.block, enc.step_tokens, enc.step_kind) == (4, 4, 1, "decode")
-    assert enc.prefill_rows == 4 and enc.step_rows == 32 and enc.unknown_token is None
-    assert set(enc.state_bytes(32)) == {"recurrent", "kv"}
-    art = ModelArtifact.from_string(_jamba_message())
-    art.tensors["L0.A_log"] = art.tensors["L0.A_log"][:, :-1]
-    with pytest.raises(ValueError):
-        apply_seq_update(None, "MODEL", art.to_string())
-    art = ModelArtifact.from_string(_jamba_message())
-    del art.tensors["L1.wq"]  # the attention layer's, which a Mamba layer does not have
-    with pytest.raises(ValueError):
-        apply_seq_update(None, "MODEL", art.to_string())
 
 
 def test_jamba_artifact_answers_recommend_next_end_to_end():

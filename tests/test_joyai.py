@@ -20,8 +20,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from oryx_tpu.ops import joyai, moe
-from oryx_tpu.ops.seq import encoder_for
+from oryx_tpu.ops import decoder, joyai, moe
 
 CFG = joyai.JoyaiConfig(
     hidden=64, heads=4, q_rank=32, kv_rank=32, nope=16, rope=8, v_dim=16, intermediate=96,
@@ -134,7 +133,7 @@ def test_a_form_the_program_does_not_compute_is_refused(key, value):
 def test_the_weights_are_a_pure_function_of_the_seed_and_the_bias_is_visible():
     t = joyai.init_tensors(CFG, 5, jnp.bfloat16)
     bias = np.asarray(t["L1.router_bias"])
-    assert bias.dtype == np.float32 and bias.std() == pytest.approx(joyai.BIAS_INIT, rel=0.5)
+    assert bias.dtype == np.float32 and bias.std() == pytest.approx(decoder.BIAS_INIT, rel=0.5)
     assert t["L1.wg"].dtype == jnp.bfloat16 and t["L1.wg"].shape == (16, 64, 32)
     assert np.all(np.asarray(t["L0.kv_norm"].astype(jnp.float32)) == 1.0)
     assert "L0.router" not in t and "L0.wg" in t and t["L0.wg"].shape == (64, 96)  # the leading dense layer
@@ -445,13 +444,11 @@ def test_the_rotation_weighs_in_the_attention_at_the_published_widths():
     softmax whatever the keys hold; at the published widths (one attention
     layer of them, 24 positions) the rotated part of the score spreads by 0.4
     and a cached key left unrotated moves the attention's output by a third."""
-    from oryx_tpu.ops.sdar import _normal
-
     names = ("wq_a", "q_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b")
     shapes = joyai.layer_shapes(REAL, 1)
     p = {
         k: jnp.ones(shapes[k], jnp.float32) if k.endswith("norm")
-        else _normal(jax.random.PRNGKey(i), shapes[k], jnp.float32)
+        else decoder.normal(jax.random.PRNGKey(i), shapes[k], jnp.float32)
         for i, k in enumerate(names)
     }
     u = jax.random.normal(jax.random.PRNGKey(9), (1, 24, REAL.hidden))
@@ -482,27 +479,6 @@ def _joyai_message(seed=7):
     art.set_extension("dtype", "float32")
     art.set_extension("ItemIDs", [f"i{j}" for j in range(N_ITEMS)])
     return art.to_string()
-
-
-def test_the_artifact_chooses_the_encoder():
-    from oryx_tpu.apps.seq.state import apply_seq_update
-    from oryx_tpu.common.artifact import ModelArtifact
-
-    st = apply_seq_update(None, "MODEL", _joyai_message())
-    assert st.encoder.name == "joyai" and st.encoder.cfg == CFG
-    assert st.dim == CFG.hidden and st.token_of["i3"] == 3
-    enc = encoder_for("joyai", {k: str(v) for k, v in CFG.to_extensions().items()}.get)
-    assert (enc.steps, enc.block, enc.step_tokens, enc.step_kind) == (4, 4, 1, "decode")
-    assert enc.prefill_rows == 4 and enc.step_rows == 32 and enc.unknown_token == -1
-    assert set(enc.state_bytes(32)) == {"latent", "rope_key"}
-    art = ModelArtifact.from_string(_joyai_message())
-    art.tensors["L1.router_bias"] = art.tensors["L1.router_bias"][:-1]
-    with pytest.raises(ValueError):
-        apply_seq_update(None, "MODEL", art.to_string())
-    art = ModelArtifact.from_string(_joyai_message())
-    del art.tensors["L2.shared_wd"]  # the expert layers', which the dense layer does not have
-    with pytest.raises(ValueError):
-        apply_seq_update(None, "MODEL", art.to_string())
 
 
 def test_joyai_artifact_answers_recommend_next_end_to_end():

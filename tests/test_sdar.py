@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from oryx_tpu.ops import moe, sdar
-from oryx_tpu.ops.seq import GruEncoder, encode_vectors, encoder_for, init_gru_params
+from oryx_tpu.ops.seq import GruEncoder, encode_vectors, init_gru_params
 
 CFG = sdar.SdarConfig(
     hidden=64, heads=4, kv_heads=2, head_dim=16, experts=8, expert_width=32,
@@ -240,24 +240,6 @@ def _sdar_message(seed=7):
     art.set_extension("dtype", "float32")
     art.set_extension("ItemIDs", [f"i{j}" for j in range(N_ITEMS)])
     return art.to_string()
-
-
-def test_the_artifact_chooses_the_encoder():
-    from oryx_tpu.apps.seq.state import apply_seq_update
-
-    st = apply_seq_update(None, "MODEL", _sdar_message())
-    assert st.encoder.name == "sdar" and st.encoder.cfg == CFG
-    assert st.dim == CFG.hidden and st.token_of["i3"] == 3
-    assert encoder_for("gru", {"dim": "8", "window": "3"}.get).name == "gru"
-    with pytest.raises(ValueError):
-        encoder_for("lstm", {}.get)
-    # a tensor of the wrong shape is refused against the extensions
-    from oryx_tpu.common.artifact import ModelArtifact
-
-    art = ModelArtifact.from_string(_sdar_message())
-    art.tensors["L0.wq"] = art.tensors["L0.wq"][:, :-1]
-    with pytest.raises(ValueError):
-        apply_seq_update(None, "MODEL", art.to_string())
 
 
 def test_sdar_artifact_answers_recommend_next_end_to_end():

@@ -21,8 +21,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from oryx_tpu.ops import trinity
-from oryx_tpu.ops.seq import encoder_for
+from oryx_tpu.ops import decoder, trinity
 
 TYPES = ("sliding_attention", "sliding_attention", "full_attention", "sliding_attention")
 CFG = trinity.TrinityConfig(
@@ -173,7 +172,7 @@ def test_the_weights_are_a_pure_function_of_the_seed_and_the_bias_is_visible():
     t = trinity.init_tensors(CFG, 5, jnp.bfloat16)
     bias = np.asarray(t["L1.router_bias"])
     assert bias.shape == (16,) and bias.dtype == np.float32
-    assert bias.std() == pytest.approx(trinity.BIAS_INIT, rel=0.5)
+    assert bias.std() == pytest.approx(decoder.BIAS_INIT, rel=0.5)
     assert t["L1.wg"].dtype == jnp.bfloat16 and t["L1.wg"].shape == (4, 64, 32) and t["L1.router"].shape == (64, 16)
     for norm in trinity.NORM_TENSORS:
         assert np.all(np.asarray(t[f"L0.{norm}"].astype(jnp.float32)) == 1.0)
@@ -437,27 +436,6 @@ def _trinity_message(seed=7):
     art.set_extension("dtype", "float32")
     art.set_extension("ItemIDs", [f"i{j}" for j in range(N_ITEMS)])
     return art.to_string()
-
-
-def test_the_artifact_chooses_the_encoder():
-    from oryx_tpu.apps.seq.state import apply_seq_update
-    from oryx_tpu.common.artifact import ModelArtifact
-
-    st = apply_seq_update(None, "MODEL", _trinity_message())
-    assert st.encoder.name == "trinity" and st.encoder.cfg == CFG
-    assert st.dim == CFG.hidden and st.token_of["i3"] == 3
-    enc = encoder_for("trinity", {k: str(v) for k, v in CFG.to_extensions().items()}.get)
-    assert (enc.steps, enc.block, enc.step_tokens, enc.step_kind) == (4, 4, 1, "decode")
-    assert enc.prefill_rows == 4 and enc.step_rows == 32 and enc.unknown_token == -1
-    assert set(enc.state_bytes(32)) == {"window_kv", "full_kv"}
-    art = ModelArtifact.from_string(_trinity_message())
-    art.tensors["L1.wg"] = np.concatenate([art.tensors["L1.wg"]] * 4)  # all 16 experts where 4 are held
-    with pytest.raises(ValueError):
-        apply_seq_update(None, "MODEL", art.to_string())
-    art = ModelArtifact.from_string(_trinity_message())
-    del art.tensors["L2.wgate"]
-    with pytest.raises(ValueError):
-        apply_seq_update(None, "MODEL", art.to_string())
 
 
 def test_trinity_artifact_answers_recommend_next_end_to_end():
