@@ -1,9 +1,10 @@
 """What the generating decoders behind the encoder seam (ops/seq.py
 `Encoder`) share, written once: the layer pieces, the artifact's layout, the
 basket a one-token step fills, and the seam's class. An architecture's file
-(ops/sdar.py, jamba.py, joyai.py, trinity.py) keeps its Config, its layers,
-its slot state, its two jitted programs and its plain reference, and imports
-what it shares from here, never from another architecture.
+(ops/sdar.py, jamba.py, joyai.py, trinity.py, xing.py) keeps its Config, its
+layers, its slot state, its two jitted programs and its plain reference, and
+imports what it shares from here (and a family's shared layer, such as
+ops/mla.py's latent attention), never from another architecture.
 
 Precision: weights in their stored dtype; the activations enter every
 product in that dtype and accumulate in float32 (`dot`); the norms, the
@@ -309,7 +310,8 @@ class DecoderEncoder:
 
     # host operands ride the jitted call (the seam's docstring, ops/seq.py)
     def prefill(self, params, state, *packed):
-        return self.programs[0](self.cfg, params, state, *packed)
+        state, hidden, counts = self.programs[0](self.cfg, params, state, *packed)
+        return state, hidden, {"counts": counts}
 
     def step(self, params, state, head, slots, lengths, live, step):
         view, n_valid, row_token = head

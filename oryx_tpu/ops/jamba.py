@@ -683,7 +683,7 @@ class JambaEncoder(DecoderEncoder):
     prefill_rows = 4
 
     def prefill(self, params, state, *packed):
-        return (*super().prefill(params, state, *packed), None)  # no expert layer, no counts
+        return (*self.programs[0](self.cfg, params, state, *packed), {})  # no expert layer, no tallies
 
     def call_step(self, params, state, view, n_valid, row_token, rows):
         return self.programs[1](self.cfg, params, state, view, n_valid, *rows)  # the tied head needs no row_token

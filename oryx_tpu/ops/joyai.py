@@ -33,7 +33,9 @@ A slot holds, a layer and a position, the normalised latent c and the
 rotated key k_rope: `kv_lora_rank + qk_rope_head_dim` numbers for all heads
 where keys and values a head would be heads x (nope + rope + v).
 
-The attention has two forms, the same function (tests/test_joyai.py):
+The attention's pieces are ops/mla.py's, which ops/xing.py shares; this file
+hands them its plain rotation and divisor. It has two forms, the same
+function (tests/test_joyai.py):
 `prefill` computes it as written, keys and values decompressed for the
 bucket's positions; a step ABSORBS W_kvb: with W_kvb split a head into W_uk
 and W_uv, q'_h = q_nope_h W_uk_h^T scores the cached latent itself and o_h =
@@ -62,9 +64,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from oryx_tpu.ops import mla
 from oryx_tpu.ops.decoder import (
-    DecoderEncoder, Layout, advance, basket, dot, fed_back, masked_softmax, reset, rms_norm,
-    router_bias, swiglu, view_head,
+    DecoderEncoder, Layout, advance, basket, dot, fed_back, reset, rms_norm, router_bias, swiglu, view_head,
 )
 from oryx_tpu.ops.moe import moe_apply, moe_reference
 
@@ -194,70 +196,37 @@ tensor_shapes, param_count, init_tensors = LAYOUT.tensor_shapes, LAYOUT.param_co
 params_of, init_params = LAYOUT.params_of, LAYOUT.init_params
 
 
-# -- pieces both served programs share (ops/decoder.py `dot`: the dtype of the
-# weights decides the precision of a product's inputs) ----------------------
+# -- pieces both served programs share: the latent attention of ops/mla.py at
+# this model's plain rotation (theta^(-2i/d), no `rope_scaling`) and divisor
+# sqrt(nope + rope) (ops/decoder.py `dot`: the dtype of the weights decides the
+# precision of a product's inputs) ----------------------------------------------
 
 def rope_interleaved(x, pos, theta):
     """x [..., d] float32, pos broadcastable to x's leading axes -> the pairs
     (2i, 2i+1) turned by pos x theta^(-2i/d), in place."""
-    d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = pos.astype(jnp.float32)[..., None] * inv                              # [..., d/2]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    pairs = x.reshape(*x.shape[:-1], d // 2, 2)
-    even, odd = pairs[..., 0], pairs[..., 1]
-    return jnp.stack([even * cos - odd * sin, even * sin + odd * cos], axis=-1).reshape(x.shape)
+    return mla.rope_interleaved(x, pos, mla.plain_frequencies(theta, x.shape[-1]))
 
 
 def _queries(cfg: JoyaiConfig, p: dict, u, pos):
     """u [..., H] float32 (normalised), pos [...] -> (q_nope [..., heads,
     nope], q_rope [..., heads, rope] rotated), float32."""
-    q = dot(rms_norm(dot(u, p["wq_a"]), p["q_norm"], cfg.eps), p["wq_b"])
-    q = q.reshape(*u.shape[:-1], cfg.heads, cfg.qk_dim)
-    return q[..., : cfg.nope], rope_interleaved(q[..., cfg.nope:], pos[..., None], cfg.rope_theta)
+    return mla.queries(cfg, p, u, pos, mla.plain_frequencies(cfg.rope_theta, cfg.rope))
 
 
 def _latent(cfg: JoyaiConfig, p: dict, u, pos):
     """u [..., H] float32 (normalised), pos [...] -> what the cache keeps of
     each position: (c [..., kv_rank] normalised, k_rope [..., rope] rotated)."""
-    ckv = dot(u, p["wkv_a"])
-    c = rms_norm(ckv[..., : cfg.kv_rank], p["kv_norm"], cfg.eps)
-    return c, rope_interleaved(ckv[..., cfg.kv_rank:], pos, cfg.rope_theta)
+    return mla.latent(cfg, p, u, pos, mla.plain_frequencies(cfg.rope_theta, cfg.rope))
 
 
 def _attend_written(cfg: JoyaiConfig, p: dict, q_nope, q_rope, c, k_rope, allowed):
-    """The attention as written, over a prefill's own positions: q_nope
-    [R,T,heads,nope], q_rope [R,T,heads,rope], c [R,S,kv_rank], k_rope
-    [R,S,rope], allowed [R,T,S] -> [R,T,heads * v_dim] float32. Keys and
-    values are decompressed for every position."""
-    f32 = jnp.float32
-    dt = p["wkv_b"].dtype
-    r, s_len = c.shape[0], c.shape[1]
-    kv = dot(c, p["wkv_b"]).reshape(r, s_len, cfg.heads, cfg.nope + cfg.v_dim)
-    k_nope, v = kv[..., : cfg.nope], kv[..., cfg.nope:]
-    s = jnp.einsum("rthd,rshd->rhts", q_nope.astype(dt), k_nope.astype(dt), preferred_element_type=f32)
-    s = s + jnp.einsum("rthd,rsd->rhts", q_rope.astype(dt), k_rope.astype(dt), preferred_element_type=f32)
-    prob = masked_softmax(s / math.sqrt(cfg.qk_dim), allowed[:, None, :, :])
-    o = jnp.einsum("rhts,rshd->rthd", prob.astype(dt), v.astype(dt), preferred_element_type=f32)
-    return o.reshape(r, q_nope.shape[1], cfg.heads * cfg.v_dim)
+    """ops/mla.py `attend_written` over a prefill's own positions."""
+    return mla.attend_written(cfg, p, q_nope, q_rope, c, k_rope, allowed, math.sqrt(cfg.qk_dim))
 
 
 def _attend_absorbed(cfg: JoyaiConfig, p: dict, q_nope, q_rope, c, k_rope, allowed):
-    """The same attention for ONE query a row over its slot's cache, W_kvb
-    absorbed: q_nope [D,heads,nope], q_rope [D,heads,rope], c [D,S,kv_rank],
-    k_rope [D,S,rope] (as the cache holds them), allowed [D,S] -> [D, heads *
-    v_dim] float32. The latent is scored and summed as it lies."""
-    f32 = jnp.float32
-    dt = p["wkv_b"].dtype
-    w = p["wkv_b"].reshape(cfg.kv_rank, cfg.heads, cfg.nope + cfg.v_dim)
-    w_uk, w_uv = w[..., : cfg.nope], w[..., cfg.nope:]
-    q_lat = jnp.einsum("dhn,chn->dhc", q_nope.astype(dt), w_uk, preferred_element_type=f32)
-    s = jnp.einsum("dhc,dsc->dhs", q_lat.astype(dt), c.astype(dt), preferred_element_type=f32)
-    s = s + jnp.einsum("dhr,dsr->dhs", q_rope.astype(dt), k_rope.astype(dt), preferred_element_type=f32)
-    prob = masked_softmax(s / math.sqrt(cfg.qk_dim), allowed[:, None, :])
-    ctx = jnp.einsum("dhs,dsc->dhc", prob.astype(dt), c.astype(dt), preferred_element_type=f32)
-    o = jnp.einsum("dhc,chv->dhv", ctx.astype(dt), w_uv, preferred_element_type=f32)
-    return o.reshape(q_nope.shape[0], cfg.heads * cfg.v_dim)
+    """ops/mla.py `attend_absorbed`: one query a row over its slot's cache."""
+    return mla.attend_absorbed(cfg, p, q_nope, q_rope, c, k_rope, allowed, math.sqrt(cfg.qk_dim))
 
 
 def _shared_expert(p: dict, u):
@@ -289,22 +258,14 @@ def _ffn(cfg: JoyaiConfig, p: dict, x, live):
 def init_state(cfg: JoyaiConfig, slots: int, dtype=jnp.bfloat16) -> dict:
     """Per-request state for `slots` requests and one scratch slot (the last:
     padding rows of a dispatch write there). latent, rope_key: a layer's
-    cache, one row a position: the normalised latent c and the rotated key.
-    x_in: the next step's input embedding; z / row / step: the basket (for
-    each position generated the hidden state, the view row chosen and the
-    step that chose it: ops/decoder.py `basket`)."""
-    s = slots + 1
-    return {
-        "latent": [jnp.zeros((s, cfg.positions, cfg.kv_rank), dtype) for _ in range(cfg.layers)],
-        "rope_key": [jnp.zeros((s, cfg.positions, cfg.rope), dtype) for _ in range(cfg.layers)],
-        **basket(cfg, slots, dtype),
-    }
+    cache, one row a position (ops/mla.py `cache`). x_in: the next step's
+    input embedding; z / row / step: the basket (for each position generated
+    the hidden state, the view row chosen and the step that chose it:
+    ops/decoder.py `basket`)."""
+    return {**mla.cache(cfg, slots, dtype), **basket(cfg, slots, dtype)}
 
 
-def state_bytes(cfg: JoyaiConfig, slots: int, itemsize: int = 2) -> dict[str, int]:
-    """Bytes of the slots' cache by its kind, both a row a position."""
-    rows = cfg.layers * (slots + 1) * cfg.positions * itemsize
-    return {"latent": rows * cfg.kv_rank, "rope_key": rows * cfg.rope}
+state_bytes = mla.cache_bytes
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))

@@ -325,6 +325,11 @@ _GATE_CHUNKS = 8
 # Scoped-VMEM working-set budget the block rule sizes against (v5e
 # exposes ~16 MB; leave headroom for the compiler's own temporaries).
 _VMEM_BUDGET_BYTES = 12 << 20
+# The scoped VMEM the compiler gives a kernel that asks for none (v5e). A
+# view so wide that the smallest item block's working set passes it (3,584
+# bf16 features: 17.6 MiB at block_i 1,024) asks for its working set and
+# the budget's headroom beside it (`scoped_vmem_limit`).
+_SCOPED_VMEM_DEFAULT = 16 << 20
 
 
 # ---------------------------------------------------------------------------
@@ -689,7 +694,7 @@ def lane_pad(n_feat: int) -> int:
 
 
 def _working_set_bytes(
-    block_b: int, block_i: int, feat_pad: int, y_itemsize: int
+    block_b: int, block_i: int, feat_pad: int, y_itemsize: int, x_itemsize: int = 4
 ) -> int:
     """Scoped-VMEM estimate for one grid step: the 2-slot Y stream
     buffer, the (pipelined, so doubled) query, scale and output blocks
@@ -697,10 +702,11 @@ def _working_set_bytes(
     the gate's threshold block and one group's scores beside it, and one
     chunk's sort network temporaries. The score block is one group of
     128-item chunks at a time, so block_i enters only through the stream
-    buffer and the scales."""
+    buffer and the scales. The block rule sizes the query block at float32
+    whatever its dtype (`x_itemsize`): the widest a path stages."""
     return (
         2 * block_i * feat_pad * y_itemsize
-        + 2 * block_b * feat_pad * 4
+        + 2 * block_b * feat_pad * x_itemsize
         + 2 * block_i * 4
         + 6 * block_b * _LANE * 8
         + block_b * (1 + _GATE_CHUNKS) * _LANE * 4
@@ -725,6 +731,20 @@ def tuned_blocks(feat_pad: int, y_itemsize: int) -> tuple[int, int]:
     ) > _VMEM_BUDGET_BYTES:
         block_i //= 2
     return block_b, block_i
+
+
+def scoped_vmem_limit(
+    block_b: int, block_i: int, feat_pad: int, y_itemsize: int, x_itemsize: int
+) -> int | None:
+    """The scoped VMEM one dispatch's kernel asks the compiler for: None (the
+    compiler's default) where its working set at these blocks, the query
+    block in its own dtype, fits the default; else that working set and the
+    block rule's headroom beside it. Every view up to 3,072 bf16 features
+    asks for none."""
+    need = _working_set_bytes(block_b, block_i, feat_pad, y_itemsize, x_itemsize)
+    if need <= _SCOPED_VMEM_DEFAULT:
+        return None
+    return need + (_SCOPED_VMEM_DEFAULT - _VMEM_BUDGET_BYTES)
 
 
 def item_block(
@@ -886,6 +906,8 @@ def _topk_pallas_jit(
         operands.append(
             jnp.asarray(scales, dtype=jnp.float32).reshape(-1, _LANE)
         )
+    limit = scoped_vmem_limit(block_b, block_i, feat_pad, y.dtype.itemsize, xs_p.dtype.itemsize)
+    asked = {} if limit is None else {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
     vals, idx, tally = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -915,6 +937,7 @@ def _topk_pallas_jit(
             jax.ShapeDtypeStruct((nb * 8, _LANE), jnp.int32),
         ],
         interpret=interpret,
+        **asked,
     )(counts, *operands)
     vals, idx = vals[:n_b, :k], idx[:n_b, :k]
     if quantized:

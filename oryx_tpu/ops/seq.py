@@ -353,11 +353,14 @@ class Encoder(Protocol):
         """The host arrays of one prefill."""
 
     def prefill(self, params, state, *packed):
-        """-> (state, hidden [rows, d], the expert layers' counts or None)."""
+        """-> (state, hidden [rows, d], the tallies the dispatch made on the
+        device, by name: serving/stepper.py `TALLIES`; {} where it makes
+        none)."""
 
     def step(self, params, state, head, slots, lengths, live, step):
         """(steps) -> (state, out); `out["head_rows"]`: (rows walked, rows
-        skipped) of the step's catalog head, host numbers."""
+        skipped) of the step's catalog head, host numbers; the tallies of
+        serving/stepper.py `TALLIES` that the model makes, as `prefill`'s."""
 
 
 class GruEncoder:
@@ -431,7 +434,7 @@ class GruEncoder:
         return mats, masks
 
     def prefill(self, params, state, mats, masks):
-        return state, encode_vectors(params, mats, masks), None
+        return state, encode_vectors(params, mats, masks), {}
 
     def step(self, params, state, head, slots, lengths, live, step):
         raise NotImplementedError("the GRU answers after prefill")
@@ -470,6 +473,7 @@ ENCODERS = {
     "jamba": ("oryx_tpu.ops.jamba", "JambaEncoder"),
     "joyai": ("oryx_tpu.ops.joyai", "JoyaiEncoder"),
     "trinity": ("oryx_tpu.ops.trinity", "TrinityEncoder"),
+    "xing": ("oryx_tpu.ops.xing", "XingEncoder"),
 }
 
 

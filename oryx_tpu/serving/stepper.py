@@ -40,7 +40,9 @@ Counters (docs/observability.md): steps and their real and padded tokens by
 kind, blocks, denoising steps, slots in use; the expert layer's routed
 pairs, experts touched, busiest expert's pairs and (for a layer that holds a
 share of its experts) the pairs sent elsewhere are counted ON THE DEVICE by
-the step itself (ops/moe.py) and fetched with its result.
+the step itself (ops/moe.py) and fetched with its result, and so are a
+hyper-connected model's Sinkhorn tallies (ops/xing.py): its largest error
+since the last scrape and its matrices left unconverged.
 """
 
 from __future__ import annotations
@@ -114,6 +116,37 @@ class Engine:
         return not self.waiting and not self.active
 
 
+# what a dispatch tallies on the device, by name, in a prefill's third result
+# and in a step's `out` alike, where the model makes it: "counts" int32[3] or
+# [4] (ops/moe.py); "hc_error" float32[] the largest Sinkhorn error over its
+# real tokens and "hc_unconverged" int32[] its matrices left unconverged
+# (ops/xing.py)
+TALLIES = ("counts", "hc_error", "hc_unconverged")
+
+
+def _copy_tallies(result: dict) -> None:
+    for name in TALLIES:
+        if name in result:
+            result[name].copy_to_host_async()
+
+
+class _LargestSinceScrape:
+    """A gauge's value that is the largest observed since it was last read."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._largest = 0.0  # guarded-by: _lock
+
+    def observe(self, value: float) -> None:
+        with self._lock:
+            self._largest = max(self._largest, value)
+
+    def read(self) -> float:
+        with self._lock:
+            value, self._largest = self._largest, 0.0
+        return value
+
+
 class _Metrics:
     def __init__(self):
         reg = get_registry()
@@ -167,6 +200,17 @@ class _Metrics:
         self.busiest = reg.counter(
             "oryx_moe_expert_tokens_max_total",
             "The busiest expert's tokens, summed over steps and layers",
+        )
+        self.hc_error = _LargestSinceScrape()
+        reg.gauge(
+            "oryx_seq_hc_sinkhorn_error",
+            "Largest |row or column sum - 1| of a hyper-connected model's mixing matrices after its "
+            "Sinkhorn iterations, over the real tokens of the dispatches since the last scrape",
+        ).set_function(self.hc_error.read)
+        self.hc_unconverged = reg.counter(
+            "oryx_seq_hc_unconverged_total",
+            "A hyper-connected model's mixing matrices whose row or column sums miss 1 by more than "
+            "1e-3 after its Sinkhorn iterations, over the real tokens and sublayers of its dispatches",
         )
 
 
@@ -338,7 +382,7 @@ class SeqStepper:
         tr = _TRACER
         if not admitted and not engine.active:
             return None
-        hidden = counts_p = out = None
+        hidden = tallied = out = None
         finished: list[tuple[int, _Req]] = []
         if admitted:
             bucket = min(
@@ -356,14 +400,13 @@ class SeqStepper:
                         [r.slot for r in admitted], engine.slots,
                     )
                 with tr.region("stepper.prefill.call"):
-                    engine.state, hidden, counts_p = enc.prefill(
+                    engine.state, hidden, tallied = enc.prefill(
                         engine.params, engine.state, *packed
                     )
                 with tr.region("stepper.prefill.copy"):
                     if not enc.steps:
                         hidden.copy_to_host_async()
-                    if counts_p is not None:
-                        counts_p.copy_to_host_async()
+                    _copy_tallies(tallied)
                     self._first_use((enc.name, "prefill", bucket, id(engine.params)), t0)
                     self._m.steps.inc(kind="prefill")
                     self._m.tokens.inc(real, kind="prefill", tokens="real")
@@ -392,8 +435,7 @@ class SeqStepper:
                     )
                 with tr.region("stepper.step.copy"):
                     finished = [(i, r) for i, r in enumerate(rows) if r.step >= enc.steps]
-                    if "counts" in out:
-                        out["counts"].copy_to_host_async()
+                    _copy_tallies(out)
                     if finished:
                         for key in ("z", "row", "step"):
                             out[key].copy_to_host_async()
@@ -413,19 +455,24 @@ class SeqStepper:
                         engine.free.append(r.slot)
         self._m.slots.set(engine.slots - len(engine.free) if enc.steps else 0)
         self.cycles += 1
-        return n, enc, admitted, hidden, counts_p, finished, out
+        return n, enc, admitted, hidden, tallied, finished, out
 
     def _resolve(self, item: tuple) -> None:
-        n, enc, admitted, hidden_dev, counts_p, finished, out = item
+        n, enc, admitted, hidden_dev, tallied, finished, out = item
         tr = _TRACER
         try:
             with tr.region("stepper.fetch", cycle=n):
                 # (routed here, touched, busiest[, routed elsewhere]) of ops/moe.py
                 counts = np.zeros((4,), dtype=np.int64)
-                for c in (counts_p, out.get("counts") if out is not None else None):
-                    if c is not None:
-                        c = np.asarray(c)
+                hc_error, unconverged = None, 0
+                for result in (tallied or {}, out or {}):
+                    if "counts" in result:
+                        c = np.asarray(result["counts"])
                         counts[: len(c)] += c
+                    if "hc_error" in result:
+                        hc_error = max(hc_error or 0.0, float(np.asarray(result["hc_error"])))
+                    if "hc_unconverged" in result:
+                        unconverged += int(np.asarray(result["hc_unconverged"]))
                 hidden = np.asarray(hidden_dev) if not enc.steps else None
                 if finished:
                     z, row, step = (np.asarray(out[k]) for k in ("z", "row", "step"))
@@ -436,6 +483,9 @@ class SeqStepper:
                     self._m.touched.inc(float(counts[1]))
                     self._m.busiest.inc(float(counts[2]))
                     self._m.elsewhere.inc(float(counts[3]))
+                if hc_error is not None:
+                    self._m.hc_error.observe(hc_error)
+                    self._m.hc_unconverged.inc(float(unconverged))
                 for i, req in enumerate(admitted):
                     # its prefill (and its first step) ran in this cycle
                     req.t_first = t_fetch

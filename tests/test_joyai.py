@@ -72,8 +72,8 @@ def _generate(enc, params, head, sessions, slots_of=None, fill=(), bucket=None, 
         group = everyone[lo:lo + enc.prefill_rows]
         b = bucket or min(b for b in enc.length_buckets if b >= max(enc.length(p) for p in group))
         packed = enc.pack(group, b, slots_of[lo:lo + len(group)], enc.step_rows)
-        state, _, n = enc.prefill(params, state, *packed)
-        counts += np.asarray(n)
+        state, _, tallied = enc.prefill(params, state, *packed)
+        counts += np.asarray(tallied["counts"])
     slots = np.full(enc.step_rows, enc.step_rows, np.int32)
     lengths = np.zeros(enc.step_rows, np.int32)
     live = np.zeros(enc.step_rows, bool)
@@ -264,7 +264,8 @@ def test_prefill_then_step_is_the_full_pass_at_the_last_position(n):
     enc = joyai.JoyaiEncoder(CFG, jnp.float32)
     session = _sessions((n,), seed=n)[0]
     state = enc.init_state(enc.step_rows)
-    state, hidden, counts = enc.prefill(params, state, *enc.pack([session], 24, [3], enc.step_rows))
+    state, hidden, tallied = enc.prefill(params, state, *enc.pack([session], 24, [3], enc.step_rows))
+    counts = tallied["counts"]
     full = np.asarray(joyai.reference_forward(CFG, params, jnp.asarray(session)))
     z, latent, rope_key, step_counts = joyai._token_hidden(
         CFG, params, state, jnp.asarray([3]), jnp.asarray([n - 1]), jnp.asarray([True])

@@ -130,7 +130,8 @@ def test_prefill_hidden_is_the_references_last_position():
     enc = sdar.SdarEncoder(CFG, jnp.float32)
     prefixes = _prefixes((5, 24, 11))
     state = enc.init_state(enc.step_rows)
-    state, hidden, counts = enc.prefill(params, state, *enc.pack(prefixes, 24, [0, 1, 2], enc.step_rows))
+    state, hidden, tallied = enc.prefill(params, state, *enc.pack(prefixes, 24, [0, 1, 2], enc.step_rows))
+    counts = tallied["counts"]
     for i, p in enumerate(prefixes):
         tokens = np.zeros(CFG.positions, np.int32)
         tokens[:len(p)] = p

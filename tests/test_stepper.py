@@ -60,7 +60,7 @@ class _StubEncoder:
 
     def prefill(self, params, state, tokens):
         time.sleep(0.002)
-        return state, _Dev(np.zeros((self.prefill_rows, 3), np.float32)), None
+        return state, _Dev(np.zeros((self.prefill_rows, 3), np.float32)), {}
 
     def step(self, params, state, head, slots, lengths, live, step):
         time.sleep(0.002)
