@@ -41,7 +41,12 @@ JoyAI slot holds, a layer and a position the normalised latent c and the
 rotated key k_rope (ops/mla.py `cache`). A prefill computes the attention as
 written, a one-token step in its absorbed form. Streams are laid out
 [n, ..., H], the stream first, so each of the maps' small products and mixes
-runs over whole rows of tokens.
+runs over whole rows of tokens. Every boundary between two sublayers (the
+write of the one behind, the maps and h of the one ahead) is ONE Pallas
+kernel (ops/pallas_hc.py) that reads and writes each token's streams once
+and walks only the blocks of positions that hold a real token; inside it
+run this module's `_write`, `_maps` (and so `sinkhorn`) and
+`sinkhorn_error`, looked up as the program is traced.
 
 Each dispatch also tallies the Sinkhorn over its real tokens and every
 sublayer: `hc_error`, the largest |row or column sum of M - 1| after the
@@ -76,7 +81,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from oryx_tpu.ops import mla
+from oryx_tpu.ops import mla, pallas_hc
 from oryx_tpu.ops.decoder import (
     DecoderEncoder, Layout, advance, basket, dot, fed_back, reset, rms_norm, router_bias, swiglu, view_head,
 )
@@ -304,12 +309,20 @@ def sinkhorn_error(m):
 def _maps(cfg: XingConfig, p: dict, sub: str, x):
     """A sublayer's three maps from the streams x [n, ..., H] float32: (Hpre
     [n, ...], Hpost [n, ...], M [n, n, ...]), float32; `a` at highest
-    precision."""
-    n = cfg.hc_mult
+    precision, one product a stream. phi is [n H, w] as the artifact holds
+    it, or its transpose [w, n H] as the boundary kernel reads it
+    (ops/pallas_hc.py)."""
+    n, hidden, w = cfg.hc_mult, cfg.hidden, cfg.maps_width
     v = x * jax.lax.rsqrt(jnp.mean(x * x, axis=(0, -1)) + cfg.eps)[None, ..., None]
-    phi = p[f"hc_{sub}_phi"].reshape(n, cfg.hidden, cfg.maps_width)
-    a = jnp.einsum("i...h,ihk->k...", v, phi, precision=jax.lax.Precision.HIGHEST,
-                   preferred_element_type=jnp.float32)                            # [2n + n^2, ...]
+    phi = p[f"hc_{sub}_phi"]
+    phi_t = phi if phi.shape[0] == w else phi.T                                   # [w, n H]
+    a = sum(
+        jax.lax.dot_general(
+            phi_t[:, i * hidden:(i + 1) * hidden], v[i], (((1,), (v.ndim - 2,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+        )
+        for i in range(n)
+    )                                                                             # [2n + n^2, ...]
     alpha = p[f"hc_{sub}_alpha"]
     b = p[f"hc_{sub}_bias"].reshape(-1, *([1] * (a.ndim - 1)))
     pre = jax.nn.sigmoid(alpha[0] * a[:n] + b[:n])
@@ -325,15 +338,19 @@ def _maps(cfg: XingConfig, p: dict, sub: str, x):
 UNCONVERGED = 1e-3
 
 
-def _read(cfg: XingConfig, p: dict, sub: str, x, live):
-    """A sublayer's entry: (h [..., H] its input, the maps it writes back with,
-    the Sinkhorn's tally over the `live` [...] tokens: the largest error and
-    the matrices left unconverged)."""
+def _boundary(cfg: XingConfig, hc, x, y=None, maps=None, p=None, sub=None, h_over=None):
+    """A sublayer boundary over the streams x [n, R, T, H], one kernel
+    (ops/pallas_hc.py `boundary`; the dispatch's live tokens planned once,
+    `hc`): the sublayer behind closed where its `maps` and output y [R, T, H]
+    are given (`_write`), `sub` of layer `p` opened where `p` is given
+    (`_maps`, its h, the Sinkhorn's tally over the live tokens: the largest
+    error and the matrices left unconverged). The model's functions are
+    looked up here, as the program is traced."""
     with jax.named_scope("xing.hc"):
-        pre, post, m = _maps(cfg, p, sub, x)
-        err = sinkhorn_error(m)
-        tally = jnp.max(jnp.where(live, err, 0.0)), jnp.sum(live & (err > UNCONVERGED), dtype=jnp.int32)
-        return jnp.sum(pre[..., None] * x, axis=0), (post, m), tally
+        return pallas_hc.boundary(
+            cfg, hc, x, y, maps, p, sub, h_over=h_over, fns=(_maps, _write, sinkhorn_error, UNCONVERGED),
+            interpret=jax.default_backend() != "tpu",
+        )
 
 
 def _no_tallies() -> dict:
@@ -397,6 +414,30 @@ def _ffn(cfg: XingConfig, p: dict, h, live):
         return (y + shared).reshape(h.shape), counts
 
 
+def _hyper_connected(cfg: XingConfig, layers: list, e, live, attention):
+    """The layers over tokens laid out [R, T] (a prefill's rows and
+    positions; a step's tokens, one row) entering with embeddings e [R, T, H]
+    float32, `live` [R, T] the real ones; `attention(l, p, h)` is layer l's
+    attention sublayer of h [R, T, H]. Every boundary between sublayers is
+    one kernel (`_boundary`) over the blocks that hold a live token. ->
+    (the streams' sum [R, T, H] after the last layer, the tallies over the
+    live tokens)."""
+    with jax.named_scope("xing.embed"):
+        x = _streams(cfg, e)                                                      # [n,R,T,H]
+    with jax.named_scope("xing.hc"):
+        hc = pallas_hc.plan(live)
+    tallies = _no_tallies()
+    x, h, maps, opened = _boundary(cfg, hc, x, p=layers[0], sub="attn", h_over=e)
+    for l, p in enumerate(layers):
+        y = attention(l, p, h)
+        x, h, maps, ffn_tally = _boundary(cfg, hc, x, y, maps, p, "ffn")
+        y, counts = _ffn(cfg, p, h, live)
+        tallies = _tallied(tallies, counts, opened, ffn_tally)
+        if l + 1 < len(layers):
+            x, h, maps, opened = _boundary(cfg, hc, x, y, maps, layers[l + 1], "attn")
+    return _boundary(cfg, hc, x, y, maps), tallies
+
+
 # -- the served form: a slot cache of latents, fixed shapes --------------------
 
 def init_state(cfg: XingConfig, slots: int, dtype=jnp.bfloat16) -> dict:
@@ -429,12 +470,11 @@ def prefill(cfg: XingConfig, params: dict, state: dict, tokens, lengths, slots, 
     live = pos < lengths[:, None]
     allowed = (pos[:, None, :] <= pos[:, :, None]) & live[:, None, :]
     with jax.named_scope("xing.embed"):
-        x = _streams(cfg, params["E_in"][tokens].astype(f32))                    # [n,P,T,H]
+        e = params["E_in"][tokens].astype(f32)                                   # [P,T,H]
     latent, rope_key = list(state["latent"]), list(state["rope_key"])
-    tallies = _no_tallies()
     behind = ((0, 0), (0, cfg.positions - t), (0, 0))
-    for l, p in enumerate(params["layers"]):
-        h, maps, e = _read(cfg, p, "attn", x, live)
+
+    def attention(l, p, h):
         with jax.named_scope("xing.attn"):
             u = rms_norm(h, p["ln1"], cfg.eps)
             q_nope, q_rope = mla.queries(cfg, p, u, pos, cfg.frequencies)
@@ -444,14 +484,11 @@ def prefill(cfg: XingConfig, params: dict, state: dict, tokens, lengths, slots, 
             kept = jnp.where(live[:, :, None], k_rope, 0.0).astype(rope_key[l].dtype)
             rope_key[l] = rope_key[l].at[slots].set(jnp.pad(kept, behind))
             o = mla.attend_written(cfg, p, q_nope, q_rope, c, k_rope, allowed, cfg.divisor)
-            y = dot(o, p["wo"])
-        x = _write(x, maps, y)
-        h, maps, e2 = _read(cfg, p, "ffn", x, live)
-        y, n = _ffn(cfg, p, h, live)
-        x = _write(x, maps, y)
-        tallies = _tallied(tallies, n, e, e2)
+            return dot(o, p["wo"])
+
+    summed, tallies = _hyper_connected(cfg, params["layers"], e, live, attention)
     with jax.named_scope("xing.embed"):
-        hidden = jnp.sum(x, axis=0)[jnp.arange(p_rows), jnp.maximum(lengths - 1, 0)]
+        hidden = summed[jnp.arange(p_rows), jnp.maximum(lengths - 1, 0)]
         state = reset(state, slots, params["E_in"][last], latent=latent, rope_key=rope_key)
     return state, hidden, tallies
 
@@ -461,16 +498,14 @@ def _token_hidden(cfg: XingConfig, params: dict, state: dict, slots, pos, live):
     is the slot's `x_in`, its position `pos` [D]): the final-normed hidden
     state [D,H] float32, the caches with the token's (c, k_rope) written at
     `pos`, and the tallies over the `live` rows (as `prefill`'s)."""
-    f32 = jnp.float32
     with jax.named_scope("xing.embed"):
-        x = _streams(cfg, state["x_in"][slots].astype(f32))                       # [n,D,H]
+        e = state["x_in"][slots].astype(jnp.float32)[None]                       # [1,D,H]: one row
     latent, rope_key = list(state["latent"]), list(state["rope_key"])
     allowed = jnp.arange(cfg.positions, dtype=jnp.int32)[None, :] <= pos[:, None]
-    tallies = _no_tallies()
-    for l, p in enumerate(params["layers"]):
-        h, maps, e = _read(cfg, p, "attn", x, live)
+
+    def attention(l, p, h):
         with jax.named_scope("xing.attn"):
-            u = rms_norm(h, p["ln1"], cfg.eps)
+            u = rms_norm(h[0], p["ln1"], cfg.eps)
             q_nope, q_rope = mla.queries(cfg, p, u, pos, cfg.frequencies)
             c, k_rope = _latent(cfg, p, u, pos)
             latent[l] = latent[l].at[slots, pos].set(c.astype(latent[l].dtype))
@@ -478,14 +513,11 @@ def _token_hidden(cfg: XingConfig, params: dict, state: dict, slots, pos, live):
             o = mla.attend_absorbed(
                 cfg, p, q_nope, q_rope, latent[l][slots], rope_key[l][slots], allowed, cfg.divisor
             )
-            y = dot(o, p["wo"])
-        x = _write(x, maps, y)
-        h, maps, e2 = _read(cfg, p, "ffn", x, live)
-        y, n = _ffn(cfg, p, h, live)
-        x = _write(x, maps, y)
-        tallies = _tallied(tallies, n, e, e2)
+            return dot(o, p["wo"])[None]
+
+    summed, tallies = _hyper_connected(cfg, params["layers"], e, live[None], attention)
     with jax.named_scope("xing.head"):
-        return rms_norm(jnp.sum(x, axis=0), params["final_norm"], cfg.eps), latent, rope_key, tallies
+        return rms_norm(summed[0], params["final_norm"], cfg.eps), latent, rope_key, tallies
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
@@ -526,7 +558,24 @@ class XingEncoder(DecoderEncoder):
     prefill_rows = 4
 
     def prefill(self, params, state, *packed):
-        return self.programs[0](self.cfg, params, state, *packed)  # its tallies are a dict already
+        state, hidden, tallies = self.programs[0](self.cfg, params, state, *packed)  # a dict already
+        tokens, lengths = packed[0], packed[1]
+        live = np.arange(tokens.shape[1])[None, :] < np.asarray(lengths)[:, None]
+        return state, hidden, dict(tallies, hc_tokens=self.hc_tokens(live))
+
+    def step(self, params, state, head, slots, lengths, live, step):
+        state, out = super().step(params, state, head, slots, lengths, live, step)
+        out["hc_tokens"] = self.hc_tokens(np.asarray(live)[None, :])
+        return state, out
+
+    def hc_tokens(self, live) -> tuple[int, int]:
+        """(token slots walked, skipped) by a dispatch's sublayer boundaries
+        over its `live` [R, T] tokens (host booleans), summed over the
+        sublayers (ops/pallas_hc.py `hc_tokens`): what the stepper publishes
+        as `oryx_seq_hc_tokens_total`."""
+        walked, skipped = pallas_hc.hc_tokens(live)
+        sublayers = len(SUBLAYERS) * self.cfg.layers
+        return walked * sublayers, skipped * sublayers
 
 
 # -- the plain reference: float32, highest precision, no cache ---------------

@@ -167,6 +167,12 @@ class _Metrics:
             "(walked: the blocks that hold a valid row | skipped: the capacity behind them)",
             labeled=True,
         )
+        self.hc_tokens = reg.counter(
+            "oryx_seq_hc_tokens_total",
+            "Token slots of a hyper-connected model's sublayer boundaries, a dispatch's shape once a "
+            "sublayer, by tokens (walked: the blocks that hold a live token | skipped: the rest)",
+            labeled=True,
+        )
         self.blocks = reg.counter(
             "oryx_seq_blocks_total", "Blocks the seq stepper finished generating"
         )
@@ -453,6 +459,11 @@ class SeqStepper:
                     for _, r in finished:
                         engine.active.remove(r)
                         engine.free.append(r.slot)
+        for result in (tallied or {}, out or {}):
+            if "hc_tokens" in result:
+                walked, skipped = result["hc_tokens"]
+                self._m.hc_tokens.inc(walked, tokens="walked")
+                self._m.hc_tokens.inc(skipped, tokens="skipped")
         self._m.slots.set(engine.slots - len(engine.free) if enc.steps else 0)
         self.cycles += 1
         return n, enc, admitted, hidden, tallied, finished, out
