@@ -280,18 +280,17 @@ class SeqServingModel(ServingModel):
 
         def _scan(f):
             # the stepper's thread: enqueue only. One row a position goes
-            # to the shared batcher; the first carries the request's ledger
-            # (the rows ride one dispatch, so its phases are the request's)
+            # to the shared batcher, all in one enqueue so that they ride
+            # one dispatch; the first carries the request's ledger (its
+            # phases are the request's)
             try:
                 encoded = f.result()
                 prev = swap_ledger(ledger)
                 try:
-                    futs = []
-                    for h in encoded.hidden:
-                        futs.append(TopKBatcher.shared().submit_nowait(
-                            h, k, y_dev, host_mat=host_mat, valid_rows=n,
-                        ))
-                        swap_ledger(None)
+                    futs = TopKBatcher.shared().submit_many_nowait(
+                        encoded.hidden, k, y_dev, host_mat=host_mat,
+                        valid_rows=n,
+                    )
                 finally:
                     swap_ledger(prev)
             except BaseException as e:  # noqa: BLE001 - carried to caller

@@ -11,10 +11,10 @@ shape a fresh XLA compile.
 This batcher fixes both:
 
 - Concurrent requests are coalesced into ONE topk_dot_batch dispatch.
-  Coalescing is *natural backpressure*, not a timer: while the dispatcher
-  thread is busy scoring batch N, new arrivals queue up and become batch
-  N+1. An idle server dispatches a single request immediately — no added
-  latency floor.
+  Coalescing is *natural backpressure*, not a timer: while two dispatches
+  are unresolved (one on the device, one queued behind it), new arrivals
+  queue up and become the next dispatch. A server with fewer in flight
+  dispatches a request as it arrives — no added latency floor.
 - Shapes are bucketed: the row count pads up to a power of two (zero
   rows) and k rounds up to a fixed bucket, then results are trimmed
   host-side — so the jit cache holds a few dozen entries total instead of
@@ -41,6 +41,7 @@ degraded service can never pass for a working chip.
 
 from __future__ import annotations
 
+import collections
 import itertools
 import logging
 import threading
@@ -148,6 +149,14 @@ PROBE_INTERVAL = 20.0
 # is compute-bound per row: fine-grained pow2 padding keeps wasted rows
 # under 2x.
 BATCH_BUCKETS_ACCEL = (512, MAX_BATCH)
+
+# Dispatches launched and not yet retired (results fetched and handed to
+# their requests) at which the dispatcher stops launching: one runs on the
+# device and one is queued behind it, so the device finds the next scan
+# waiting when one ends and no request waits for the host to fetch the
+# one before. A third would only wait in the device's queue instead of the
+# host's, and would split the same arrivals into more, smaller dispatches.
+MAX_UNRESOLVED = 2
 
 
 def _next_pow2(n: int) -> int:
@@ -340,7 +349,10 @@ class TopKBatcher:
         self.max_queue = max_queue
         self.retry_after_sec = retry_after_sec
         self._lock = threading.Lock()
+        # two waiters on one lock: the dispatcher waits on _cond (a submit,
+        # a retire), the fetch thread on _fetch_cond (a launch)
         self._cond = threading.Condition(self._lock)
+        self._fetch_cond = threading.Condition(self._lock)
         # dispatch shapes that have completed at least once: their XLA
         # compiles are done, so the wedge watchdog needs no compile grace
         self._compiled_shapes: set[tuple] = set()  # guarded-by: _lock
@@ -357,11 +369,18 @@ class TopKBatcher:
         self._dispatch_seq = itertools.count()
         self._queue: list[_Pending] = []  # guarded-by: _lock
         self._thread: threading.Thread | None = None  # guarded-by: _lock
+        # the dispatcher's fetch thread: it resolves _unresolved in order
+        self._fetcher: threading.Thread | None = None  # guarded-by: _lock
         self._closed = False  # guarded-by: _lock
-        # watchdog state: _busy_since marks the start of the dispatcher's
-        # current device cycle; _inflight holds every request the (possibly
-        # wedged) dispatcher owns so the watchdog can fail them over
-        self._busy_since: float | None = None  # guarded-by: _lock
+        # the launched group handles not yet retired, in dispatch order;
+        # the dispatcher appends, the fetch thread resolves the head and
+        # pops it
+        self._unresolved: collections.deque = collections.deque()  # guarded-by: _lock
+        # watchdog state: _picking marks the pick the dispatcher is
+        # launching now (_busy_since reads it behind the oldest unresolved
+        # launch); _inflight holds every request the (possibly wedged)
+        # threads own so the watchdog can fail them over
+        self._picking: float | None = None  # guarded-by: _lock
         self._inflight: dict[int, _Pending] = {}  # guarded-by: _lock
         self._device_down = threading.Event()
         self._watchdog: threading.Thread | None = None  # guarded-by: _lock
@@ -373,9 +392,10 @@ class TopKBatcher:
         # device was last known busy (dispatch issued / results fetched).
         # What filled the idle time since is read off the dispatcher's own
         # regions at the next issue (_launch): the wall `batcher.idle`
-        # (empty queue) and `batcher.distribute` (host serialize) gained
-        # since the issue before, _gap_seen being their readings then and
-        # the thread they are of. Only the down-window backoff has no
+        # (empty queue) and `batcher.full` (host serialize: the wait for
+        # the fetch thread to retire a dispatch) gained since the issue
+        # before, _gap_seen being their readings then and the thread they
+        # are of. Only the down-window backoff has no
         # region (the probe's thread adds it) and keeps its accumulator.
         self._gap_mark = time.monotonic()  # guarded-by: _lock
         self._gap_seen = (0, 0.0, 0.0)  # guarded-by: _lock
@@ -389,6 +409,9 @@ class TopKBatcher:
         # (a superseded dispatcher racing its replacement) lose updates.
         self.dispatches = 0  # guarded-by: _lock (writes)
         self.coalesced = 0  # guarded-by: _lock (writes)
+        # dispatches launched while an earlier one was still unresolved:
+        # over `dispatches`, the share that did not wait for a fetch
+        self.launched_behind = 0  # guarded-by: _lock (writes)
         # item chunks the fused top-k kernel folded / walked, summed over
         # its dispatches: their ratio is how often its threshold gate let
         # a chunk through to the sort network (ops/pallas_topk.py)
@@ -434,6 +457,12 @@ class TopKBatcher:
             ("oryx_topk_coalesced",
              "requests coalesced into device dispatches",
              lambda: float(self.coalesced)),
+            ("oryx_topk_launched_behind_total",
+             "device top-k dispatches launched while an earlier dispatch "
+             "was unresolved (its results not yet fetched and handed out); "
+             "over oryx_topk_dispatches, the share that did not wait for "
+             "the fetch before them",
+             lambda: float(self.launched_behind)),
             ("oryx_topk_chunks_folded",
              "128-item chunks that fired in the fused top-k kernel: those "
              "holding a score above a row block's running k-th, folded "
@@ -598,35 +627,62 @@ class TopKBatcher:
         """submit() without the wait: returns the Future of (values,
         indices). Deferred endpoints chain post-processing onto it instead
         of parking a worker thread per in-flight request."""
-        vec = np.asarray(vec, dtype=np.float32)
-        fut: Future = Future()
-        p = _Pending(
-            vec, int(k), y, fut, host_mat, cosine, host_norms,
-            float(recall), valid_rows, score_mode,
-        )
+        return self.submit_many_nowait(
+            (vec,), k, y, host_mat=host_mat, cosine=cosine,
+            host_norms=host_norms, recall=recall, valid_rows=valid_rows,
+            score_mode=score_mode,
+        )[0]
+
+    def submit_many_nowait(
+        self,
+        vecs,
+        k: int,
+        y,
+        host_mat: np.ndarray | None = None,
+        cosine: bool = False,
+        host_norms: np.ndarray | None = None,
+        recall: float = 1.0,
+        valid_rows: int | None = None,
+        score_mode: str = "exact",
+    ) -> list[Future]:
+        """submit_nowait() of several rows of one caller, one Future each:
+        the rows are queued under one hold of the lock, so the dispatcher
+        picks them together and they ride one dispatch (a basket's
+        positions wait for one kernel, not two). The first row carries the
+        caller's ledger; its phases are the request's."""
         # queue-wait measures from here to the dispatcher picking the
         # batch up; the ledger is the submitting request's (thread-local,
         # installed by ServingApp.dispatch_nowait — None off the request
         # path, e.g. a test's or a probe's submits)
-        p.t_enq = time.monotonic()
-        p.ledger = current_ledger()
-        if p.ledger is not None:
+        t_enq = time.monotonic()
+        ledger = current_ledger()
+        if ledger is not None:
             # the slice between the last stamped phase (parse/auth) and
             # this enqueue is routing + handler pre-work building the
             # query (model lookup, user-vector fetch) — charge it to
             # parse so the budget keeps tiling the request wall-clock
             # instead of leaking it between auth and queue_wait
-            tail = p.ledger.last_end()
-            if tail is not None and tail < p.t_enq:
-                p.ledger.add("parse", p.t_enq - tail, start=tail)
-        if _TRACER.enabled:
-            # parent = the submitting request's span (thread-current, set
-            # by ServingApp.dispatch_nowait)
-            p.trace_parent = current_span()
+            tail = ledger.last_end()
+            if tail is not None and tail < t_enq:
+                ledger.add("parse", t_enq - tail, start=tail)
+        # parent = the submitting request's span (thread-current, set by
+        # ServingApp.dispatch_nowait)
+        parent = current_span() if _TRACER.enabled else None
+        rows = []
+        for vec in vecs:
+            p = _Pending(
+                np.asarray(vec, dtype=np.float32), int(k), y, Future(),
+                host_mat, cosine, host_norms, float(recall), valid_rows,
+                score_mode,
+            )
+            p.t_enq = t_enq
+            p.ledger = None if rows else ledger
+            p.trace_parent = parent
+            rows.append(p)
         with self._cond:
             if self._closed:
                 raise RuntimeError("batcher is closed")
-            if self.max_queue > 0 and len(self._queue) >= self.max_queue:
+            if self.max_queue > 0 and len(self._queue) + len(rows) > self.max_queue:
                 # saturation: refuse honestly instead of queueing without
                 # bound. Raised under the lock so the depth check and the
                 # refusal are one decision; the exception renders as
@@ -660,25 +716,42 @@ class TopKBatcher:
             if not down:
                 self._ensure_thread()
                 self._ensure_watchdog()
-                self._queue.append(p)
+                self._queue.extend(rows)
                 self._cond.notify()
         if down:
             self._maybe_probe()
-            if p.resolve_on_host():
+            n = sum(p.resolve_on_host() for p in rows)
+            if n:
                 with self._lock:
-                    self.host_fallbacks += 1
-                _PERF.note_fallback(1)
-        return fut
+                    self.host_fallbacks += n
+                _PERF.note_fallback(n)
+        return [p.future for p in rows]
 
     def close(self) -> None:
+        """Stop taking submits; the dispatcher launches what is queued and
+        the fetch thread resolves what is in flight. What a wedged thread
+        still holds after the joins is failed, so no Future stays
+        pending."""
         with self._cond:
             self._closed = True
             self._cond.notify_all()
-            t = self._thread
-        if t is not None:
-            t.join(timeout=5)
+            self._fetch_cond.notify_all()
+            threads = (self._thread, self._fetcher)
+        deadline = time.monotonic() + 5.0
+        for t in threads:
+            if t is not None:
+                t.join(timeout=max(0.0, deadline - time.monotonic()))
         with self._lock:
             self._last_y = None
+            left = list(self._inflight.values()) + self._queue
+            self._inflight.clear()
+            self._queue = []
+        err = RuntimeError("batcher closed with the request unresolved")
+        for p in left:
+            span = p.take_dev_span()
+            if span is not None:
+                _TRACER.finish(span, error="closed")
+            try_set_exception(p.future, err)
 
     # -- dispatcher --------------------------------------------------------
 
@@ -687,7 +760,14 @@ class TopKBatcher:
             self._thread = threading.Thread(
                 target=self._run, name="oryx-topk-batcher", daemon=True
             )
+            # the fetch thread serves this dispatcher alone: it ends when
+            # the dispatcher is superseded
+            self._fetcher = threading.Thread(
+                target=self._fetch_loop, args=(self._thread,),
+                name="oryx-topk-fetch", daemon=True,
+            )
             self._thread.start()
+            self._fetcher.start()
 
     def _ensure_watchdog(self) -> None:  # oryxlint: holds=_lock
         if self._watchdog is None or not self._watchdog.is_alive():
@@ -696,39 +776,68 @@ class TopKBatcher:
             )
             self._watchdog.start()
 
+    def _dispatcher_wait(self, me) -> str | None:  # oryxlint: holds=_lock
+        """The region the dispatcher waits in now, or None when it should
+        pick (or find that it must stop): `batcher.idle` with nothing
+        queued, `batcher.full` with MAX_UNRESOLVED dispatches unresolved."""
+        if self._thread is not me:
+            return None
+        if not self._queue:
+            return None if self._closed else "batcher.idle"
+        if len(self._unresolved) >= MAX_UNRESOLVED:
+            return "batcher.full"
+        return None
+
+    def _busy_since(self) -> float | None:  # oryxlint: holds=_lock
+        """Since when the device owes the dispatcher an answer: the launch
+        of the oldest unresolved dispatch, else the pick it is launching."""
+        if self._unresolved:
+            return self._unresolved[0][6][0]  # the handle's cost: t0 first
+        return self._picking
+
     def _run(self) -> None:  # oryxlint: offloop (dedicated dispatcher thread)
-        # Depth-1 pipeline: launch batch N+1's device work (with async
-        # device->host copies) BEFORE materializing batch N's results. A
-        # blocking fetch without a prior copy_to_host_async costs a full
-        # synchronous device round trip per group; with the copy already
-        # in flight, the fetch of batch N overlaps the scan of batch N+1.
+        # Two threads share the pipeline. This one (`oryx-topk`) picks and
+        # launches, in dispatch order, and is the only one that issues
+        # device work, so the device runs the scans in the order of their
+        # numbers. Each launch starts the async device->host copies of its
+        # results and hands its handles to the fetch thread
+        # (`oryx-topk-fetch`, _fetch_loop), which blocks in their fetch and
+        # distributes them. A queued request is launched at once while
+        # fewer than MAX_UNRESOLVED dispatches are unresolved: it does not
+        # wait for the fetch and distribute of the one before.
+        # This thread's top-level regions tile its life: `batcher.idle`
+        # (nothing queued, whatever is in flight), `batcher.full` (queued,
+        # and MAX_UNRESOLVED unresolved), `batcher.pick`, one
+        # `batcher.launch` a group; the hand-over is a few microseconds
+        # under the lock between them.
         me = threading.current_thread()
         name_thread("oryx-topk")
         tr = _TRACER
-        inflight: list[tuple[list[_Pending], int, object, object, object, tuple, tuple]] = []
-        # the top-level regions tile this thread's life: idle, pick, one
-        # launch a group, then fetch and distribute of each group of the
-        # cycle before, retire
         while True:
-            if not inflight:
-                with tr.region("batcher.idle"), self._cond:
-                    while not self._queue and not self._closed:
+            with self._cond:
+                wait = self._dispatcher_wait(me)
+            if wait is not None:
+                with tr.region(wait), self._cond:
+                    while self._dispatcher_wait(me) == wait:
                         self._cond.wait()
+                continue
             batch: list[_Pending] = []
+            behind = 0
             try:
                 with tr.region("batcher.pick"):
                     with self._cond:
-                        if self._closed and not self._queue and not inflight:
-                            return
-                        if self._thread is not me:
-                            # superseded after a wedge: a fresh dispatcher
+                        if self._thread is not me or not self._queue:
+                            # superseded after a wedge (a fresh dispatcher
                             # owns the queue now; whatever this one still
-                            # holds was already failed over by the watchdog
+                            # holds was already failed over by the
+                            # watchdog), or closed with nothing left to
+                            # launch (the fetch thread resolves the rest)
                             return
                         batch, self._queue = self._queue[: self.max_batch], self._queue[self.max_batch:]
                         for p in batch:
                             self._inflight[id(p)] = p
-                        self._busy_since = time.monotonic()
+                        self._picking = time.monotonic()
+                        behind = len(self._unresolved)
                     t_pick, groups = self._group(batch)
                 launched = self._launch_groups(groups, t_pick) if groups else []
             except Exception as e:  # pragma: no cover - defensive: a failure
@@ -738,22 +847,59 @@ class TopKBatcher:
                 for p in batch:
                     try_set_exception(p.future, e)
                 launched = []
-            for item in inflight:
-                self._resolve(item)
-            with tr.region("batcher.retire"), self._cond:
+            with self._cond:
                 if self._thread is not me:
-                    # superseded mid-cycle: the replacement dispatcher owns
-                    # _busy_since now — wiping it would blind the watchdog
-                    # to the replacement's own wedge
+                    # superseded mid-launch: the watchdog failed this
+                    # batch over and the replacement owns the pipeline
                     return
-                self._busy_since = None
-                for item in inflight:
-                    for p in item[0]:
-                        self._inflight.pop(id(p), None)
+                self._picking = None
+                self._unresolved.extend(launched)
+                # every launch after the pick's first is behind it, and
+                # the first too when an earlier pick's is unresolved
+                self.launched_behind += max(0, len(launched) - (0 if behind else 1))
                 for p in batch:
-                    if p.future.done():
+                    if p.future.done():  # its group failed over to the host
                         self._inflight.pop(id(p), None)
-            inflight = launched
+                # also when nothing launched: a closing fetch thread waits
+                # for the pick to end
+                self._fetch_cond.notify()
+
+    def _fetch_loop(self, owner: threading.Thread) -> None:  # oryxlint: offloop (dedicated fetch thread)
+        # The dispatcher's second thread: resolves the launched handles in
+        # dispatch order. Its fetch blocks in np.asarray, which releases
+        # the interpreter lock while the results are on their way, and its
+        # retire frees a place in the pipeline for the dispatcher. Its
+        # top-level regions tile its life: `batcher.await` (nothing
+        # launched to fetch), `batcher.fetch`, `batcher.distribute` (both
+        # in _resolve), `batcher.retire`.
+        name_thread("oryx-topk-fetch")
+        tr = _TRACER
+        while True:
+            with tr.region("batcher.await"), self._fetch_cond:
+                while (
+                    self._thread is owner
+                    and not self._unresolved
+                    # closed, and the dispatcher has nothing left to launch
+                    and not (
+                        self._closed and not self._queue
+                        and self._picking is None
+                    )
+                ):
+                    self._fetch_cond.wait()
+                if self._thread is not owner or not self._unresolved:
+                    return
+                item = self._unresolved[0]
+            self._resolve(item)
+            with tr.region("batcher.retire"), self._cond:
+                if self._thread is not owner:
+                    # superseded while the fetch sat on a wedged transport:
+                    # the watchdog failed this group over and cleared the
+                    # pipeline the replacement now fills
+                    return
+                self._unresolved.popleft()
+                for p in item[0]:
+                    self._inflight.pop(id(p), None)
+                self._cond.notify()
 
     def _launch(
         self, batch: list[_Pending]
@@ -1001,14 +1147,14 @@ class TopKBatcher:
         (of THIS thread: a superseding dispatcher starts from zero)."""
         me = threading.get_ident()
         idle = thread_region_seconds("batcher.idle")
-        distribute = thread_region_seconds("batcher.distribute")
+        full = thread_region_seconds("batcher.full")
         with self._lock:
             seen = self._gap_seen if self._gap_seen[0] == me else (me, 0.0, 0.0)
             causes = classify_idle_gap(
                 t_disp - self._gap_mark, wait_s=idle - seen[1],
-                serialize_s=distribute - seen[2], down_s=self._gap_down,
+                serialize_s=full - seen[2], down_s=self._gap_down,
             )
-            self._gap_seen = (me, idle, distribute)
+            self._gap_seen = (me, idle, full)
             self._gap_down = 0.0
             self._gap_mark = t_disp
         for cause, s in causes.items():
@@ -1145,7 +1291,7 @@ class TopKBatcher:
             with self._cond:
                 if self._closed:
                     return
-                busy = self._busy_since
+                busy = self._busy_since()
                 now = time.monotonic()
                 wedged = (
                     busy is not None
@@ -1167,9 +1313,14 @@ class TopKBatcher:
                 stuck = list(self._inflight.values()) + self._queue
                 self._inflight.clear()
                 self._queue = []
-                self._busy_since = None
+                self._unresolved.clear()
+                self._picking = None
                 self._compiling.clear()  # abandoned with the dispatcher
-                self._thread = None  # supersede the wedged dispatcher
+                # supersede the wedged dispatcher and its fetch thread;
+                # whichever of them waits wakes to leave
+                self._thread = self._fetcher = None
+                self._cond.notify_all()
+                self._fetch_cond.notify_all()
             log.error(
                 "device dispatch stuck > %.0fs — failing %d requests over "
                 "to host scoring and marking the device down",

@@ -583,9 +583,11 @@ class _Late:
 
 
 def test_the_dispatchers_regions_tile_its_life(y, monkeypatch):
-    """50 dispatches of a stub that sleeps 2 ms: the top-level regions (idle,
-    pick, launch, fetch, distribute, retire) cover the dispatcher thread's
-    time to within 5 %, and each parent's children cover the parent."""
+    """50 dispatches of a stub that sleeps 2 ms: the top-level regions of
+    each of the dispatcher's two threads cover its time to within 5 % (idle,
+    full, pick and launch the launching thread's; await, fetch, distribute
+    and retire the fetch thread's), and each parent's children cover the
+    parent."""
     from e2e_common import region_tiling
 
     from oryx_tpu.common.tracing import get_tracer, region_totals
@@ -613,23 +615,345 @@ def test_the_dispatchers_regions_tile_its_life(y, monkeypatch):
             assert list(idx) == [0, 1, 2, 3, 4]
             if i % 10 == 9:
                 time.sleep(0.01)  # let it reach its idle wait now and then
-        tid = b._thread.ident
+        tids = (b._thread.ident, b._fetcher.ident)
     finally:
         b.close()
         spans = tr.snapshot()
         tr.configure(enabled=False, capacity=2048)
-    top = {
-        "batcher.idle", "batcher.pick", "batcher.launch", "batcher.fetch",
-        "batcher.distribute", "batcher.retire",
-    }
-    covered, by_parent = region_tiling(spans, tid, top)
-    assert 0.95 <= covered <= 1.0001, covered
-    assert set(by_parent) == {"batcher.launch", "batcher.issue", "batcher.fetch"}
-    for parent, share in by_parent.items():
-        assert 0.95 <= share <= 1.0001, (parent, share)
+    tops = (
+        ({"batcher.idle", "batcher.full", "batcher.pick", "batcher.launch"},
+         {"batcher.launch", "batcher.issue"}),
+        ({"batcher.await", "batcher.fetch", "batcher.distribute", "batcher.retire"},
+         {"batcher.fetch"}),
+    )
+    for tid, (top, parents) in zip(tids, tops):
+        covered, by_parent = region_tiling(spans, tid, top)
+        assert 0.95 <= covered <= 1.0001, (top, covered)
+        assert set(by_parent) == parents
+        for parent, share in by_parent.items():
+            assert 0.95 <= share <= 1.0001, (parent, share)
     # the counters saw the same fifty, whatever the ring holds
     after = region_totals()
     for name in ("batcher.launch", "batcher.issue.call", "batcher.fetch.vals", "batcher.distribute"):
         assert after[name][2] - before.get(name, (0, 0, 0))[2] == 50, name
     call = after["batcher.issue.call"][0] - before.get("batcher.issue.call", (0.0,))[0]
     assert 0.1 <= call < 1.0  # 50 x 2 ms of the stub, inside the call region alone
+
+
+# ---------------------------------------------------------------------------
+# two dispatches in flight: the dispatcher launches while the fetch thread
+# waits for the results of the dispatch before
+# ---------------------------------------------------------------------------
+
+
+class _Gated:
+    """A device result that reaches the host only once its gate opens."""
+
+    def __init__(self, value, gate):
+        self._value = value
+        self._gate = gate
+
+    def __array__(self, dtype=None, copy=None):
+        assert self._gate.wait(timeout=30), "gate never opened"
+        return np.asarray(self._value)
+
+
+class _FetchHold:
+    """Stand-in for `topk_dot_batch` whose results reach the host when the
+    test lets them (in the style of `e2e_common.WedgeHook`, which holds the
+    call itself): the real function computes, and the fetch of call i
+    blocks in `np.asarray` until `gates[i]` is set. `rows[i]` is the real
+    row count call i was handed, `blocks[i]` its query block."""
+
+    def __init__(self, hold=True):
+        self.hold = hold
+        self.rows, self.blocks, self.gates = [], [], []
+
+    def __call__(self, xs, y, *, k, **kw):
+        gate = threading.Event()
+        if not self.hold:
+            gate.set()
+        vals, idx = _real_topk_dot_batch(xs, y, k=k, recall=kw.get("recall", 1.0))
+        self.blocks.append(np.array(xs))
+        self.gates.append(gate)
+        self.rows.append(kw.get("rows"))  # last: wait_calls reads it
+        return _Gated(vals, gate), _Gated(idx, gate), None
+
+    def wait_calls(self, n, timeout=10.0):
+        deadline = time.monotonic() + timeout
+        while len(self.rows) < n and time.monotonic() < deadline:
+            time.sleep(0.005)
+        return len(self.rows)
+
+
+def _until(cond, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    return cond()
+
+
+def _held_batcher(monkeypatch, **kw):
+    from oryx_tpu.ops import als
+
+    hook = _FetchHold()
+    monkeypatch.setattr(als, "topk_dot_batch", hook)
+    return hook, TopKBatcher(**kw)
+
+
+def _vecs(n, seed):
+    return np.random.default_rng(seed).normal(size=(n, 8)).astype(np.float32)
+
+
+def _assert_direct(fut, vec, k, y):
+    vals, idx = fut.result(timeout=10)
+    dvals, didx = _direct(vec, k, y)
+    assert list(idx) == list(didx)
+    np.testing.assert_allclose(vals, dvals, rtol=1e-5)
+
+
+def test_a_request_is_launched_while_the_previous_dispatchs_fetch_is_held(y, monkeypatch):
+    hook, b = _held_batcher(monkeypatch)
+    vecs = _vecs(2, 51)
+    try:
+        first = b.submit_nowait(vecs[0], 10, y)
+        assert hook.wait_calls(1) == 1
+        second = b.submit_nowait(vecs[1], 10, y)
+        # launched though the first dispatch's results have not landed
+        assert hook.wait_calls(2) == 2
+        assert not first.done() and not second.done()
+        assert hook.rows == [1, 1]
+        for gate in hook.gates:
+            gate.set()
+        _assert_direct(first, vecs[0], 10, y)
+        _assert_direct(second, vecs[1], 10, y)
+    finally:
+        for gate in hook.gates:
+            gate.set()
+        b.close()
+
+
+def test_with_two_dispatches_unresolved_the_next_waits_and_coalesces(y, monkeypatch):
+    hook, b = _held_batcher(monkeypatch)
+    vecs = _vecs(4, 52)
+    try:
+        futs = [b.submit_nowait(vecs[0], 10, y)]
+        assert hook.wait_calls(1) == 1
+        futs.append(b.submit_nowait(vecs[1], 10, y))
+        assert hook.wait_calls(2) == 2
+        futs += [b.submit_nowait(v, 10, y) for v in vecs[2:]]
+        time.sleep(0.2)
+        assert len(hook.rows) == 2  # nothing launched behind two unresolved
+        hook.gates[0].set()
+        assert hook.wait_calls(3) == 3
+        assert hook.rows == [1, 1, 2]  # what queued meanwhile: one dispatch
+        np.testing.assert_array_equal(hook.blocks[2][:2, :8], vecs[2:])
+        for gate in hook.gates:
+            gate.set()
+        for f, v in zip(futs, vecs):
+            _assert_direct(f, v, 10, y)
+    finally:
+        for gate in hook.gates:
+            gate.set()
+        b.close()
+
+
+def test_results_are_distributed_in_dispatch_order(y, monkeypatch):
+    from oryx_tpu.common.perfstats import get_perfstats
+
+    hook, b = _held_batcher(monkeypatch)
+    vecs = _vecs(2, 53)
+    order = []
+    t_mark = time.monotonic()
+    try:
+        futs = []
+        for i, v in enumerate(vecs):
+            futs.append(b.submit_nowait(v, 10, y))
+            futs[-1].add_done_callback(lambda f, i=i: order.append(i))
+            assert hook.wait_calls(i + 1) == i + 1
+        hook.gates[1].set()  # the second's results land first ...
+        time.sleep(0.2)
+        assert order == []  # ... and wait for the first's
+        hook.gates[0].set()
+        for f, v in zip(futs, vecs):
+            _assert_direct(f, v, 10, y)
+        assert order == [0, 1]
+    finally:
+        for gate in hook.gates:
+            gate.set()
+        b.close()
+    records = [r for r in get_perfstats().records_since(t_mark) if r.kind == "serving"]
+    assert [r.dispatch for r in records] == [0, 1]
+
+
+def test_launched_behind_counts_the_launches_behind_an_unresolved_dispatch(y, monkeypatch):
+    from oryx_tpu.common.metrics import get_registry
+
+    hook, b = _held_batcher(monkeypatch)
+    b.register_gauges()
+    vecs = _vecs(5, 54)
+
+    def gauge():
+        for line in get_registry().render_prometheus().splitlines():
+            if line.startswith("oryx_topk_launched_behind_total "):
+                return float(line.split()[1])
+
+    try:
+        futs = [b.submit_nowait(vecs[0], 10, y)]  # nothing unresolved
+        assert hook.wait_calls(1) == 1
+        futs.append(b.submit_nowait(vecs[1], 10, y))  # behind the first
+        assert hook.wait_calls(2) == 2
+        assert _until(lambda: len(b._unresolved) == 2)  # handed over
+        assert b.launched_behind == 1
+        # two groups (k-buckets 16 and 128) picked behind the second
+        futs.append(b.submit_nowait(vecs[2], 10, y))
+        futs.append(b.submit_nowait(vecs[3], 40, y))
+        hook.gates[0].set()
+        assert hook.wait_calls(4) == 4
+        assert _until(lambda: len(b._unresolved) == 3)  # the second and both groups
+        assert b.launched_behind == 3
+        for gate in hook.gates:
+            gate.set()
+        for f, v, k in zip(futs, vecs, (10, 10, 10, 40)):
+            _assert_direct(f, v, k, y)
+        assert _until(lambda: not b._inflight)  # the last retire follows its distribute
+        futs.append(b.submit_nowait(vecs[4], 10, y))  # nothing unresolved
+        assert hook.wait_calls(5) == 5
+        hook.gates[4].set()
+        _assert_direct(futs[-1], vecs[4], 10, y)
+        assert _until(lambda: not b._inflight)
+        assert (b.dispatches, b.launched_behind) == (5, 3)
+        assert gauge() == 3.0
+    finally:
+        for gate in hook.gates:
+            gate.set()
+        b.close()
+
+
+def test_the_rows_of_one_submit_many_call_ride_one_dispatch(y, monkeypatch):
+    """More callers than cores at once, the interpreter switching threads
+    every 10 us, ten calls of four rows each: every call's rows are in one
+    dispatch, all four, each row is counted once; only the first row
+    carries the caller's ledger."""
+    import os
+    import sys
+
+    from oryx_tpu.common.perfattr import PhaseLedger, swap_ledger
+    from oryx_tpu.ops import als
+
+    hook = _FetchHold(hold=False)
+    monkeypatch.setattr(als, "topk_dot_batch", hook)
+    b = TopKBatcher()
+    ledgers, errors = [], []
+    callers, calls = (os.cpu_count() or 8) + 2, 10
+
+    def caller(c):
+        rng = np.random.default_rng(c)
+        for n in range(calls):
+            rows = rng.normal(size=(4, 8)).astype(np.float32)
+            rows[:, 0] = 1000 * c + n  # the call's mark, on each of its rows
+            ledger = PhaseLedger()
+            ledgers.append(ledger)
+            prev = swap_ledger(ledger)
+            try:
+                futs = b.submit_many_nowait(rows, 10, y)
+            finally:
+                swap_ledger(prev)
+            try:
+                assert len(futs) == 4
+                for f, v in zip(futs, rows):
+                    _assert_direct(f, v, 10, y)
+            except AssertionError as e:
+                errors.append(e)
+
+    threads = [threading.Thread(target=caller, args=(c,)) for c in range(callers)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+        b.close()
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors[0]
+    dispatches_of = {}
+    for i, (block, n) in enumerate(zip(hook.blocks, hook.rows)):
+        for mark in block[:n, 0]:
+            dispatches_of.setdefault(float(mark), []).append(i)
+    assert len(dispatches_of) == callers * calls
+    assert all(len(d) == 4 and len(set(d)) == 1 for d in dispatches_of.values())
+    assert b.coalesced == 4 * callers * calls and b.dispatches == len(hook.rows)
+    assert b.launched_behind < b.dispatches
+    for ledger in ledgers:
+        assert [p for p, _, _ in ledger.items()].count("queue_wait") == 1
+
+
+def test_a_wedged_fetch_fails_over_to_host(y, monkeypatch):
+    """The fetch thread blocks on a transport that never answers: the
+    watchdog fails the request over to the host within `device_timeout`,
+    as `test_wedged_dispatch_fails_over_to_host` asserts for a wedged
+    launch, and the superseded fetch thread ends once the fetch returns."""
+    hook, b = _held_batcher(
+        monkeypatch, device_timeout=0.5, probe_interval=0.2, compile_timeout=0.5
+    )
+    vec = _vecs(1, 55)[0]
+    try:
+        t0 = time.monotonic()
+        fut = b.submit_nowait(vec, 10, y, host_mat=_host_mat(y))
+        assert hook.wait_calls(1) == 1  # launched: the wedge is in the fetch
+        fetcher = b._fetcher
+        vals, idx = fut.result(timeout=10)
+        assert time.monotonic() - t0 < 5.0 and not hook.gates[0].is_set()
+        assert b.device_failovers == 1 and b._device_down.is_set()
+        dvals, didx = _direct(vec, 10, y)
+        assert list(idx) == list(didx)
+        np.testing.assert_allclose(vals, dvals, rtol=1e-5)
+        assert b._fetcher is None and not b._unresolved
+        vals2, idx2 = b.submit(vec, 10, y, host_mat=_host_mat(y))  # host path
+        assert list(idx2) == list(didx) and b.host_fallbacks >= 2
+        assert fetcher.is_alive()  # still in the wedged fetch
+    finally:
+        for gate in hook.gates:
+            gate.set()
+        b.close()
+    fetcher.join(timeout=5)
+    assert not fetcher.is_alive()
+
+
+@pytest.mark.parametrize("released", [True, False], ids=["released", "wedged"])
+def test_close_with_dispatches_in_flight_leaves_no_future_pending(y, monkeypatch, released):
+    """Two dispatches held in their fetch and two requests queued behind
+    them: close() returns with every Future done, with its result where the
+    fetches come back during the close, with an error where they never
+    do."""
+    hook, b = _held_batcher(monkeypatch)
+    vecs = _vecs(4, 56)
+    futs = []
+    try:
+        for i in range(2):
+            futs.append(b.submit_nowait(vecs[i], 10, y))
+            assert hook.wait_calls(i + 1) == i + 1
+        futs += [b.submit_nowait(v, 10, y) for v in vecs[2:]]
+        if released:
+            def let_go():
+                time.sleep(0.3)
+                hook.hold = False
+                for gate in list(hook.gates):
+                    gate.set()
+            threading.Thread(target=let_go, daemon=True).start()
+        b.close()
+        assert all(f.done() for f in futs)
+        if released:
+            for f, v in zip(futs, vecs):
+                _assert_direct(f, v, 10, y)
+        else:
+            for f in futs:
+                with pytest.raises(RuntimeError, match="closed"):
+                    f.result(timeout=0)
+    finally:
+        hook.hold = False
+        for gate in hook.gates:
+            gate.set()
